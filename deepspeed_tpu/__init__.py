@@ -73,6 +73,10 @@ def initialize(args=None,
 
     from .runtime.config import load_config
     from .runtime.pipe.module import PipelineModule
+    from .utils.compile_cache import ensure_compile_cache
+    # the train step is the longest compile of a cold run: same
+    # persistent cache, same placement rule as the serving engine
+    ensure_compile_cache()
     if isinstance(model, PipelineModule):
         try:
             from .runtime.pipe.engine import PipelineEngine

@@ -1,8 +1,8 @@
 from .config import (FaultInjectionConfig, KVCacheUserConfig,
                      RaggedInferenceEngineConfig,
                      ServingOptimizationConfig, StateManagerConfig)
-from .compile_cache import (compile_config_digest, disable_compile_cache,
-                            enable_compile_cache)
+from ...utils.compile_cache import (disable_compile_cache,
+                                    ensure_compile_cache)
 from .engine import InferenceEngineV2, SchedulingError, SchedulingResult
 from .factory import build_hf_engine
 from .lattice import (BucketLattice, LatticeError, fit_buckets,
@@ -36,6 +36,5 @@ __all__ = [
     "NgramDrafter",
     "BucketLattice", "LatticeError", "fit_buckets", "mine_lattice",
     "resolve_lattice",
-    "compile_config_digest", "disable_compile_cache",
-    "enable_compile_cache",
+    "disable_compile_cache", "ensure_compile_cache",
 ]

@@ -126,12 +126,13 @@ class ServingOptimizationConfig:
     #: (changes compiled program signatures); default off
     keyed_sampling: bool = False
     # -- recompile-proof cold starts (ISSUE 14) -------------------------
-    #: persistent XLA compile cache directory ("" = off; DS_COMPILE_CACHE
-    #: env overrides).  Entries are namespaced by a (model config + KV
-    #: geometry + lattice + jaxlib) digest, so a second process
-    #: compiling the same step keys LOADS executables from disk —
-    #: restore()/scale_up cold starts become loads, not compiles.
-    #: Unwritable/corrupt dirs degrade to plain compiles with a warning
+    #: where the persistent XLA compile cache goes when
+    #: JAX_COMPILATION_CACHE_DIR is not set (the env var wins and JAX
+    #: places the cache itself; "" = the fixed <repo>/.jax_cache/ — see
+    #: utils/compile_cache.py).  A second process compiling the same
+    #: step keys LOADS executables from disk — restore()/scale_up cold
+    #: starts become loads, not compiles.  Unwritable/corrupt dirs
+    #: degrade to plain compiles with a warning
     compile_cache_dir: str = ""
     #: bucket lattice: "" = the power-of-two default;
     #: "auto:<path>" consumes a mined lattice artifact

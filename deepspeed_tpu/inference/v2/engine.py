@@ -304,26 +304,13 @@ class InferenceEngineV2:
                 else "<power>")
         model.lattice = self._lattice
         model._lattice_bound = True
-        # persistent compile cache (ISSUE 14): a second process
-        # compiling the same step keys loads executables from disk —
-        # restore()/scale_up cold starts become loads, not compiles
-        from .compile_cache import (cache_dir_from_env_or_config,
-                                    compile_config_digest,
-                                    enable_compile_cache)
-        cache_dir = cache_dir_from_env_or_config(
+        # persistent compile cache: a second process compiling the
+        # same step keys loads executables from disk — restore()/
+        # scale_up cold starts become loads, not compiles.  Placement
+        # is utils/compile_cache.py's one rule (shared with training)
+        from ...utils.compile_cache import ensure_compile_cache
+        self._compile_cache_dir = ensure_compile_cache(
             getattr(self._config.serving, "compile_cache_dir", "") or "")
-        self._compile_cache_dir = None
-        if cache_dir:
-            digest = compile_config_digest(
-                model.cfg, kv_cfg,
-                keyed_sampling=model.keyed_sampling,
-                lattice_digest=(self._lattice.digest
-                                if self._lattice is not None else ""),
-                draft_digest=self.draft_digest,
-                tp_degree=self._tp_degree,
-                tp_collective_quantization=tpq)
-            self._compile_cache_dir = enable_compile_cache(cache_dir,
-                                                           digest)
         sv = self._config.serving
         self._state = StateManager(
             kv_cfg,
@@ -347,10 +334,8 @@ class InferenceEngineV2:
         self._draft_seen: Dict[int, int] = {}
         if self._draft_enabled:
             import jax.numpy as jnp
-            shape = (self._draft_layers, kv_cfg.num_pages + 1,
-                     kv_cfg.page_size, 2, kv_cfg.kv_heads,
-                     kv_cfg.head_dim)
-            dkv = jnp.zeros(shape, kv_cfg.dtype)
+            dkv = jnp.zeros(kv_cfg.cache_shape(self._draft_layers),
+                            kv_cfg.dtype)
             sharding = model.kv_sharding()
             if sharding is not None:
                 dkv = jax.device_put(dkv, sharding)
@@ -870,17 +855,24 @@ class InferenceEngineV2:
                     "(%s: %s)", key, type(e).__name__, e)
         return done
 
-    @staticmethod
-    def _free_device_memory() -> Optional[int]:
-        """Free HBM on device 0, or None when the backend doesn't report
-        memory stats (CPU/CI)."""
-        try:
-            stats = jax.devices()[0].memory_stats()
-            if stats and "bytes_limit" in stats:
-                return stats["bytes_limit"] - stats.get("bytes_in_use", 0)
-        except Exception:
-            pass
-        return None
+    def _free_device_memory(self) -> Optional[int]:
+        """Free HBM the KV pool can use, or None when the backend doesn't
+        report memory stats (CPU/CI): the tightest device of the
+        serving mesh, times the number of shards a page is split into
+        (KV heads over tp) — every device holds 1/shards of each page."""
+        mesh = self._model.mesh
+        devices = (list(mesh.devices.flat) if mesh is not None
+                   else jax.devices()[:1])
+        free = []
+        for dev in devices:
+            stats = dev.memory_stats()
+            if not stats or "bytes_limit" not in stats:
+                return None
+            free.append(stats["bytes_limit"] - stats.get("bytes_in_use", 0))
+        sharding = self._model.kv_sharding()
+        shards = (len(devices) if sharding is not None
+                  and not sharding.is_fully_replicated else 1)
+        return min(free) * shards
 
     # -- introspection -------------------------------------------------------
     @property

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...models import transformer as T
-from ...ops.paged_attention import (gather_last, paged_attention,
+from ...ops.paged_attention import (KVPages, gather_last, paged_attention,
                                     rope_write_kv, token_positions,
                                     write_kv)
 from ...telemetry import metrics as tm
@@ -33,20 +33,23 @@ from ...telemetry.workload_trace import get_workload_trace
 from .ragged import KVCacheConfig, RaggedBatch
 
 
-def serving_peak_flops() -> float:
-    """Peak FLOP/s denominator for the serving MFU gauge:
-    ``DS_PEAK_FLOPS`` env wins, else the device table
-    (profiling.flops_profiler), else the TPU v5e bf16 number — the
-    gauge always has a denominator, and which one is a config fact the
-    operator controls."""
+def serving_peak_flops() -> Optional[float]:
+    """Peak FLOP/s denominator for the serving MFU gauges: an explicit
+    ``DS_PEAK_FLOPS`` (the operator's statement) wins, else the
+    ``device_kind`` table (profiling.flops_profiler).  A device with no
+    published peak has no denominator: None, and every utilization
+    derived from it reads 0 / None — "not measured", never a CPU rate
+    over an assumed chip's peak."""
     env = os.environ.get("DS_PEAK_FLOPS", "")
     if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
+        return float(env)
     from ...profiling.flops_profiler import _device_peak_flops
-    return _device_peak_flops() or 197e12
+    return _device_peak_flops()
+
+
+def utilization(flops_per_s: float, peak: Optional[float]) -> float:
+    """``flops_per_s / peak``; 0.0 where the device has no peak."""
+    return flops_per_s / peak if peak else 0.0
 
 
 def _rebox_from_cfg(cfg: T.TransformerConfig, params):
@@ -134,7 +137,8 @@ class RaggedInferenceModel:
                                                 cfg)
         except (KeyError, ValueError):
             self._fresh_attention = None
-        self._norm = instantiate("norm", cfg)
+        self._norm_impl = instantiate("norm", cfg)
+        self._norm = self._norm_impl
         self._embed = instantiate("embedding", cfg)
         self._unembed = instantiate("unembed", cfg)
         self.kv_config_explicit = kv_config is not None
@@ -323,6 +327,14 @@ class RaggedInferenceModel:
             is_leaf=lambda x: isinstance(x, T.meta.Partitioned))
         self.mesh = mesh
         self._tp_axis = axis
+        # the norm module is a Pallas custom call on a TPU, which GSPMD
+        # refuses to partition even over replicated operands: run it as
+        # a fully-replicated manual region (activations and norm params
+        # are replicated under tp)
+        from ...utils.jax_compat import shard_map
+        self._norm = shard_map(self._norm_impl, mesh=mesh,
+                               in_specs=(P(), P()), out_specs=P(),
+                               check_vma=False)
         cache = getattr(self, "_step_cache", None)
         if cache:
             cache.clear()
@@ -349,7 +361,7 @@ class RaggedInferenceModel:
     def kv_sharding(self) -> Optional[jax.sharding.Sharding]:
         if self.mesh is None:
             return None
-        # [L, pages, page, 2, K, D]: partition kv heads over the tp
+        # [L, pages, 2, K, page, D]: partition kv heads over the tp
         # axis — each shard's page slab holds only its head slice,
         # while page ids/tables (host-side int32) stay replicated, so
         # the allocator/prefix-cache/tiering view is shard-invariant
@@ -357,7 +369,7 @@ class RaggedInferenceModel:
         if axis is not None and self.kv_config.kv_heads % max(
                 self.mesh.shape.get(axis, 1), 1) == 0:
             return jax.sharding.NamedSharding(
-                self.mesh, P(None, None, None, None, axis, None))
+                self.mesh, P(None, None, None, axis, None, None))
         return jax.sharding.NamedSharding(self.mesh, P())
 
     # -- forward ------------------------------------------------------------
@@ -673,8 +685,8 @@ class RaggedInferenceModel:
         def rate(attr, scale=1.0):
             def _read(r=ref, a=attr, s=scale):
                 m = r()
-                if m is None or m._cost_t0 is None:
-                    return 0.0
+                if m is None or m._cost_t0 is None or not s:
+                    return 0.0    # not s: no published peak, no MFU
                 wall = max(time.perf_counter() - m._cost_t0, 1e-9)
                 return getattr(m, a) / wall / s
             return _read
@@ -687,7 +699,8 @@ class RaggedInferenceModel:
         # dispatched totals by the mesh degree (tp=1 ⇒ they read the
         # same as the global pair)
         tp = float(max(self.tp_degree, 1))
-        tm.FASTGEN_SHARD_MFU.bind(rate("_flops_dispatched", peak * tp))
+        tm.FASTGEN_SHARD_MFU.bind(
+            rate("_flops_dispatched", peak and peak * tp))
         tm.FASTGEN_SHARD_BYTES_PER_S.bind(
             rate("_bytes_dispatched", tp))
 
@@ -711,7 +724,8 @@ class RaggedInferenceModel:
             "bytes_dispatched": self._bytes_dispatched,
             "window_s": wall,
             "peak_flops": peak,
-            "mfu": (self._flops_dispatched / wall / peak if wall else 0.0),
+            "mfu": (utilization(self._flops_dispatched / wall, peak)
+                    if wall else 0.0),
             "bytes_per_s": (self._bytes_dispatched / wall if wall
                             else 0.0),
         }
@@ -803,6 +817,11 @@ class RaggedInferenceModel:
         compiled = fn.lower(*self._step_avals(key, kv_aval)).compile()
         self._note_program_cost(key, compiled)
         self._step_cache[key] = compiled
+
+    def compiled_programs(self) -> Dict[tuple, Any]:
+        """Step-cache key -> compiled executable, for inspection
+        (``as_text()`` shows a program's kernels and collectives)."""
+        return dict(self._step_cache)
 
     def _lm_head(self, params):
         cfg = self.cfg
@@ -1160,11 +1179,12 @@ class RaggedInferenceModel:
             # (reference blocked_flash prefill atoms); padding-tail rows
             # are garbage but only feed rows that logits_gather ignores
             # and KV slots the null page swallows
-            attn = self._fresh_attention(
+            attn = self._per_shard_heads(
+                self._fresh_attention, cfg, 3)(
                 q, k_rot if k_rot is not None else k, v)
         else:
-            attn = self._attention(q, kv_layer, page_table, start_pos,
-                                   q_lens)
+            attn = self._per_shard_heads(self._attention, cfg, 1)(
+                q, kv_layer, page_table, start_pos, q_lens)
         out = jnp.einsum("sqhd,hde->sqe", attn, T._wval(ap["wo"], dtype))
         if cfg.use_bias:
             out = out + ap["bo"].astype(dtype)
@@ -1180,6 +1200,46 @@ class RaggedInferenceModel:
         if isinstance(mlp_out, tuple):                      # MoE aux dropped
             mlp_out = mlp_out[0]
         return x + mlp_out.astype(x.dtype), kv_layer
+
+    def _per_shard_heads(self, attn_fn, cfg, n_head_args: int):
+        """Run an attention module per tp shard over its own head slice.
+
+        Attention is independent per KV head, and both its implementations
+        are Pallas custom calls on a TPU — which GSPMD cannot partition
+        (it would all-gather the KV pages onto every chip, every layer).
+        Under a tp mesh the module is therefore ``shard_map``-ped over the
+        axis: the first ``n_head_args`` arguments are ``[S, Q, heads, D]``
+        activations split on heads, a paged KV layer (payload and int8
+        scale alike) is split on its KV-head dim, and the host-built int32
+        batch vectors are replicated.  Head h = k * G + g, so contiguous
+        head shards line up with contiguous KV-head shards.  ALiBi slopes
+        are a closed-over per-head constant, so those models (and head
+        counts the axis does not divide) stay on the GSPMD path."""
+        axis = self._tp_axis
+        if self.mesh is None or axis is None:
+            return attn_fn
+        tp = self.mesh.shape[axis]
+        if (tp == 1 or cfg.pos_emb == "alibi" or cfg.kv_heads % tp
+                or cfg.num_heads % tp):
+            return attn_fn
+        from ...utils.jax_compat import shard_map
+        heads = P(None, None, axis, None)
+
+        def spec_of(i, arg):
+            if i < n_head_args:
+                return heads
+            if isinstance(arg, KVPages):     # [P+1, 2, K, page(, D)]
+                return KVPages(P(None, None, axis, None, None),
+                               P(None, None, axis, None))
+            if arg.ndim == 5:
+                return P(None, None, axis, None, None)
+            return P()
+
+        def run(*args):
+            specs = tuple(spec_of(i, a) for i, a in enumerate(args))
+            return shard_map(attn_fn, mesh=self.mesh, in_specs=specs,
+                             out_specs=heads, check_vma=False)(*args)
+        return run
 
     # -- KV requirements (engine contract) ----------------------------------
     def get_kv_requirements(self, seen_tokens: int, allocated_pages: int,

@@ -25,6 +25,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
+from ...accelerator import on_tpu
+
 
 @dataclasses.dataclass
 class _Impl:
@@ -89,7 +91,7 @@ def instantiate(op_class: str, config: Any = None,
 # ---------------------------------------------------------------------------
 
 def _on_tpu(_cfg) -> bool:
-    return jax.default_backend() == "tpu"
+    return on_tpu()
 
 
 @register("ragged_attention", "pallas_paged_decode", priority=10,

@@ -4,17 +4,22 @@ Reference: ``inference/v2/ragged/kv_cache.py:40`` (``BlockedKVCache``)
 — there, per-layer torch tensors + an allocator, with offload hooks.
 TPU-native layout: ONE stacked array per cache group
 
-    kv : [num_layers, num_pages + 1, page_size, 2, kv_heads, head_dim]
+    kv : [num_layers, num_pages + 1, 2, kv_heads, page_size, head_dim]
 
 so the per-layer slice falls out of the layer ``lax.scan`` naturally and
 the whole cache is a single donated buffer across forwards (XLA updates
 it in place; no allocator traffic on device).  Page 0 is the null page
-(see blocked_allocator.py) — real pages are 1..num_pages.
+(see blocked_allocator.py) — real pages are 1..num_pages.  The last two
+dims are one head's ``[page_size, head_dim]`` tile of one page: the
+block the Pallas paged-attention kernel DMAs per grid step (the TPU
+lowering only accepts blocks whose last two dims are tile-aligned array
+dims).  Every host codec (offload, snapshot, handoff, tier, fetch)
+addresses pages on axis 1 and is layout-agnostic past it.
 
 Quantized pages (ISSUE 16): with ``quantization="int8"`` the device
 store is an :class:`~deepspeed_tpu.ops.paged_attention.KVPages` pair —
 int8 codes at the layout above plus a per-(token, kv-head) fp32 scale
-sidecar ``[L, num_pages+1, page_size, 2, K]``.  Host-side page blobs
+sidecar ``[L, num_pages+1, 2, K, page_size]``.  Host-side page blobs
 become :class:`PageBlob` (payload + scales travel together through
 offload/snapshot/handoff), and ``bytes_per_page`` accounts the true
 quantized footprint so a byte budget buys ~2x the pages.
@@ -72,6 +77,13 @@ class KVCacheConfig:
     def total_bytes(self) -> int:
         return self.bytes_per_page * (self.num_pages + 1)
 
+    def cache_shape(self, num_layers: Optional[int] = None) -> tuple:
+        """The device page-store shape (module docstring); the int8
+        scale sidecar is this minus the trailing ``head_dim``."""
+        return (self.num_layers if num_layers is None else num_layers,
+                self.num_pages + 1, 2, self.kv_heads, self.page_size,
+                self.head_dim)
+
 
 def pages_for_memory(cfg: KVCacheConfig, budget_bytes: int) -> int:
     """How many pages fit in ``budget_bytes`` (reference sizes its cache
@@ -81,7 +93,7 @@ def pages_for_memory(cfg: KVCacheConfig, budget_bytes: int) -> int:
 
 class PageBlob:
     """Host-side blob of quantized pages: int8 payload
-    ``[L, n, page, 2, K, D]`` + fp32 scales ``[L, n, page, 2, K]``
+    ``[L, n, 2, K, page, D]`` + fp32 scales ``[L, n, 2, K, page]``
     traveling as one unit through offload / snapshot / handoff codecs.
     Mimics the ndarray surface those codecs touch (``shape`` and
     ``nbytes`` of the payload, axis-1 column selection), so the fp path
@@ -143,8 +155,7 @@ class BlockedKVCache:
                  sharding: Optional[jax.sharding.Sharding] = None):
         self.cfg = cfg
         self.allocator = BlockedAllocator(cfg.num_pages)
-        shape = (cfg.num_layers, cfg.num_pages + 1, cfg.page_size, 2,
-                 cfg.kv_heads, cfg.head_dim)
+        shape = cfg.cache_shape()
         if cfg.quantized:
             data = KVPages(jnp.zeros(shape, jnp.int8),
                            jnp.zeros(shape[:-1], jnp.float32))
@@ -165,14 +176,11 @@ class BlockedKVCache:
         """The scale sidecar drops the head_dim axis, so its sharding is
         the payload's minus the last entry (kv heads stay sharded
         identically); non-named shardings fall back to replication."""
-        try:
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
-            if isinstance(sharding, NamedSharding):
-                return NamedSharding(sharding.mesh,
-                                     P(*tuple(sharding.spec)[:-1]))
-        except Exception:
-            pass
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+        if isinstance(sharding, NamedSharding):
+            return NamedSharding(sharding.mesh,
+                                 P(*tuple(sharding.spec)[:5]))
         return None
 
     @property
@@ -212,7 +220,7 @@ class BlockedKVCache:
         """Copy the given pages to host WITHOUT freeing them — the
         page-transfer export half shared by serving snapshots (ISSUE 8)
         and the disagg handoff (ISSUE 13).  Returns the host blob
-        [L, n, page, 2, K, D] (a :class:`PageBlob` when quantized);
+        [L, n, 2, K, page, D] (a :class:`PageBlob` when quantized);
         ``restore_pages`` is the matching import."""
         import numpy as np
         pages = list(pages)
@@ -231,7 +239,7 @@ class BlockedKVCache:
         """Copy the given pages to HOST memory and free them on device —
         the preemption half of the reference's offload/restore hooks
         (evict a long sequence's KV under pressure, bring it back
-        later).  Returns the host blob [L, n, page, 2, K, D]."""
+        later).  Returns the host blob [L, n, 2, K, page, D]."""
         blob = self.read_pages(pages)
         self.release(list(pages))
         return blob

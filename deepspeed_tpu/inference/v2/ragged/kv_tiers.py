@@ -73,7 +73,7 @@ class TieredPageStore:
     """Bounded host ring + bounded disk spill for single-page KV blobs.
 
     ``put`` / ``take_many`` move whole single-page blobs (ndarray
-    ``[L, 1, page, 2, K, D]`` or :class:`PageBlob` when quantized) —
+    ``[L, 1, 2, K, page, D]`` or :class:`PageBlob` when quantized) —
     quantized payloads travel quantized; the tier never re-encodes.
     """
 

@@ -24,8 +24,8 @@ never a hang, never silent partial state.  XLA executables are
 process-local and never ride the bundle; instead (ISSUE 14) the meta
 carries the engine's **compiled-key manifest** + lattice digest, and
 ``restore()`` precompiles exactly those keys up front — against a warm
-persistent compile cache (``serving_optimization.compile_cache_dir`` /
-``DS_COMPILE_CACHE``) each one is a disk load, so restore-to-first-token
+persistent compile cache (``utils/compile_cache.py``) each one is a
+disk load, so restore-to-first-token
 stays ~flat vs a warm process.  Deliberately NOT captured: telemetry
 latency stamps (process-relative clocks).
 """

@@ -10,8 +10,12 @@ local rank with the distributed env contract:
 TPU default is **one process per host** (all local chips belong to that
 process; ``jax.distributed.initialize`` handles chip discovery), which is
 ``--proc_per_chip`` off.  With ``--proc_per_chip`` one process per slot is
-spawned — the mode used by the CPU virtual-mesh CI and by frameworks that
-want a process per device.
+spawned — the mode used by the CPU virtual-mesh CI.  On a host that holds
+TPU chips the flag is REFUSED for more than one slot: a chip belongs to one
+process at a time and nothing here hands each child its own chip, so the
+first child would claim every chip of the host and the rest would fail or
+hang.  This launcher never touches JAX itself (a parent that did would hold
+the chips its children need).
 
 Child exit codes propagate (reference launch.py:319); SIGTERM fans out to
 the process group on interrupt.
@@ -20,6 +24,7 @@ the process group on interrupt.
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -44,6 +49,14 @@ def parse_args(argv=None):
     p.add_argument("user_script")
     p.add_argument("user_args", nargs=argparse.REMAINDER)
     return p.parse_args(argv)
+
+
+def tpu_chips_on_host() -> int:
+    """TPU chips of this host, counted from their device nodes — without
+    importing JAX (v4 and older expose ``/dev/accel*``, v5e and newer
+    VFIO groups)."""
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
 
 
 def build_rank_envs(world: Dict[str, int], node_rank: int,
@@ -92,6 +105,16 @@ def main(argv=None) -> int:
 
     rank_envs = build_rank_envs(world, node_rank, args.master_addr,
                                 args.master_port, args.proc_per_chip)
+    chips = tpu_chips_on_host()
+    if args.proc_per_chip and len(rank_envs) > 1 and chips:
+        logger.error(
+            "--proc_per_chip asks for %d processes on a host with %d TPU "
+            "chip(s): a chip belongs to one process at a time and this "
+            "launcher does not give each child its own chip.  Drop the "
+            "flag — one process per host drives every local chip "
+            "(jax.devices()) — or run the CPU virtual mesh on a host "
+            "without chips.", len(rank_envs), chips)
+        return 2
     logger.info("node %d launching %d process(es) for %s",
                 node_rank, len(rank_envs), args.user_script)
 

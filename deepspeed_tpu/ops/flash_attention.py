@@ -11,8 +11,14 @@ Causal masking skips fully-masked k-blocks.  Backward is the standard
 two-kernel flash backward (dkv sweep over q-blocks, dq sweep over
 k-blocks) with the delta = rowsum(dO * O) precomputation.
 
-On non-TPU backends (CI) the public entry point falls back to a jnp
-reference implementation with identical semantics.
+On a TPU the kernel is the only path (a lowering error propagates);
+on CPU (the tests) the public entry point selects the jnp reference
+explicitly — see :func:`~deepspeed_tpu.accelerator.on_tpu`.
+
+The per-row log-sum-exp (and the backward's ``delta``) travel between
+the kernels as lane-major ``[B, H, 1, S]`` rows: the TPU lowering needs
+the last two block dims tile-aligned, which a ``[B, H, S]`` vector block
+is not, and a trailing unit dim would pad 128x in HBM.
 """
 
 from __future__ import annotations
@@ -26,19 +32,25 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..accelerator import on_tpu
+
 DEFAULT_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 
-def _band_keep(q_idx_base, k_idx_base, block_q, block_k, causal, window):
+def _band_keep(q_idx_base, k_idx_base, block_q, block_k, causal, window,
+               k_major=False):
     """Block-local keep mask for banded (causal / sliding-window)
     attention: q attends k iff q_pos >= k_pos (causal) and
     q_pos - k_pos < window (Mistral (t-window, t] semantics).  Shared by
-    all three kernels so the band definition cannot diverge."""
+    all three kernels so the band definition cannot diverge.
+    ``k_major`` builds the mask for a transposed ``[block_k, block_q]``
+    score tile (the dkv kernel)."""
+    shape = (block_k, block_q) if k_major else (block_q, block_k)
     q_pos = q_idx_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, shape, 1 if k_major else 0)
     k_pos = k_idx_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    keep = jnp.ones((block_q, block_k), bool)
+        jnp.int32, shape, 0 if k_major else 1)
+    keep = jnp.ones(shape, bool)
     if causal:
         keep &= q_pos >= k_pos
     if window is not None:
@@ -124,7 +136,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
     m, l, acc = jax.lax.fori_loop(k_lo, num_k, body, (m0, l0, acc0))
     l = jnp.maximum(l, 1e-30)
     o_ref[:] = (acc / l).astype(o_ref.dtype)
-    lse_ref[:] = (m + jnp.log(l))[:, 0]
+    lse_ref[:] = (m + jnp.log(l)).T  # [1, block_q] lane-major row
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
@@ -146,12 +158,14 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
         ],
         out_specs=[
             pl.BlockSpec((None, None, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, block_q), lambda bi, hi, qi: (bi, hi, qi)),
+            pl.BlockSpec((None, None, 1, block_q),
+                         lambda bi, hi, qi: (bi, hi, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, h, s_q), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, s_q), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -183,26 +197,29 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv0 = jnp.zeros((block_k, d), jnp.float32)
 
     def body(qi, carry):
+        # k-major (transposed) score tile [bk, bq]: the per-query lse /
+        # delta rows broadcast along sublanes and every matmul below is
+        # a plain (non-transposed-lhs) MXU product
         dk, dv = carry
         q = q_ref[pl.ds(qi * block_q, block_q), :]
         do = do_ref[pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[pl.ds(qi * block_q, block_q)]
-        delta = delta_ref[pl.ds(qi * block_q, block_q)]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
+        lse = lse_ref[:, pl.ds(qi * block_q, block_q)]      # [1, bq]
+        delta = delta_ref[:, pl.ds(qi * block_q, block_q)]
+        st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * sm_scale
         if causal or window is not None:
-            s = jnp.where(_band_keep(qi * block_q, k_idx * block_k, block_q,
-                                     block_k, causal, window),
-                          s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse[:, None])  # [bq, bk]
+            st = jnp.where(_band_keep(qi * block_q, k_idx * block_k, block_q,
+                                      block_k, causal, window, k_major=True),
+                           st, DEFAULT_MASK_VALUE)
+        pt = jnp.exp(st - lse)  # [bk, bq]
         dv = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
+        dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta) * sm_scale
         dk = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk, dv
 
@@ -218,8 +235,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     d = q_ref.shape[1]
     q = q_ref[:]
     do = do_ref[:]
-    lse = lse_ref[:]
-    delta = delta_ref[:]
+    lse = lse_ref[:].T      # [1, bq] row -> [bq, 1] column, once per block
+    delta = delta_ref[:].T
 
     num_k = pl.cdiv(seq_k, block_k)
     if causal:
@@ -241,10 +258,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jnp.where(_band_keep(q_idx * block_q, ki * block_k, block_q,
                                      block_k, causal, window),
                           s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale
+        ds = p * (dp - delta) * sm_scale
         return dq + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -259,7 +276,9 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret, window):
     s_k = k.shape[2]
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    # same lane-major [B, H, 1, S] row layout as lse
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
                                    causal=causal, block_q=block_q, seq_q=s_q,
@@ -272,8 +291,8 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret, window):
             pl.BlockSpec((None, None, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
             pl.BlockSpec((None, None, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
             pl.BlockSpec((None, None, s_q, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, s_q), lambda bi, hi, ki: (bi, hi, 0)),
-            pl.BlockSpec((None, None, s_q), lambda bi, hi, ki: (bi, hi, 0)),
+            pl.BlockSpec((None, None, 1, s_q), lambda bi, hi, ki: (bi, hi, 0, 0)),
+            pl.BlockSpec((None, None, 1, s_q), lambda bi, hi, ki: (bi, hi, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
@@ -283,6 +302,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret, window):
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q, k, v, g, lse, delta)
 
@@ -297,12 +317,13 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret, window):
             pl.BlockSpec((None, None, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
             pl.BlockSpec((None, None, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
             pl.BlockSpec((None, None, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, block_q), lambda bi, hi, qi: (bi, hi, qi)),
-            pl.BlockSpec((None, None, block_q), lambda bi, hi, qi: (bi, hi, qi)),
+            pl.BlockSpec((None, None, 1, block_q), lambda bi, hi, qi: (bi, hi, 0, qi)),
+            pl.BlockSpec((None, None, 1, block_q), lambda bi, hi, qi: (bi, hi, 0, qi)),
         ],
         out_specs=pl.BlockSpec((None, None, block_q, d),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
@@ -336,6 +357,19 @@ def _flash_attention_bwd(sm_scale, causal, block_q, block_k, interpret, window,
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
+def _fit_block(block: int, seq: int) -> int:
+    """Largest lane-aligned (multiple of 128) block <= ``block`` that
+    divides ``seq``, else the whole sequence as one block.  The kernels
+    slice whole-sequence K/V (forward, dq) and Q/lse rows (dkv) by block
+    index, so a ragged last block would read out of bounds."""
+    if seq <= block:
+        return seq
+    for b in range(block - block % 128, 0, -128):
+        if seq % b == 0:
+            return b
+    return seq
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     sm_scale: Optional[float] = None,
@@ -344,14 +378,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
     """Blockwise attention, [B,H,S,D].  GQA callers fold groups into H or
-    repeat kv.  Falls back to the jnp reference off-TPU."""
+    repeat kv.  ``interpret=None`` (the default) compiles the kernel on
+    a TPU and computes the jnp reference on CPU; an explicit bool always
+    runs the kernel (True = Pallas interpreter, the tests' parity mode)."""
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
     if interpret is None:
-        backend = jax.default_backend()
-        if backend != "tpu":
+        if not on_tpu():
             return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                                  window=window)
         interpret = False
-    return _flash_attention(q, k, v, sm_scale, causal, block_q, block_k,
+    return _flash_attention(q, k, v, sm_scale, causal,
+                            _fit_block(block_q, q.shape[2]),
+                            _fit_block(block_k, k.shape[2]),
                             interpret, window)

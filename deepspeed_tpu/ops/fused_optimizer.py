@@ -23,35 +23,46 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..accelerator import on_tpu
+
 _LANES = 1024  # rows are reshaped to [n // _LANES, _LANES] for VPU tiling
+#: rows per grid step: 7 double-buffered fp32 row blocks of AdamW at 128
+#: rows are 7 MiB of the 16 MiB scoped VMEM (256 rows do not fit)
+_BLOCK_ROWS = 128
 
 
 def _adamw_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
                   new_p_ref, new_m_ref, new_v_ref):
     """One elementwise pass: m, v, bias-corrected AdamW update.
-    sc_ref (SMEM, [6]): lr, b1, b2, eps, wd, step."""
+    sc_ref (SMEM, [7]): lr, b1, b2, eps, wd, bc1, bc2 — the bias
+    corrections ``1 - b**step`` are computed by the caller (Mosaic has
+    no ``powf``; they are two scalars per step, not per element)."""
     lr = sc_ref[0]
     b1 = sc_ref[1]
     b2 = sc_ref[2]
     eps = sc_ref[3]
     wd = sc_ref[4]
-    step = sc_ref[5]
+    bc1 = sc_ref[5]
+    bc2 = sc_ref[6]
 
     g = g_ref[:].astype(jnp.float32)
     p = p_ref[:].astype(jnp.float32)
     m = b1 * m_ref[:] + (1.0 - b1) * g
     v = b2 * v_ref[:] + (1.0 - b2) * g * g
-    bc1 = 1.0 - jnp.power(b1, step)
-    bc2 = 1.0 - jnp.power(b2, step)
     update = (m / bc1) / (jnp.sqrt(v / bc2) + eps) + wd * p
     new_p_ref[:] = (p - lr * update).astype(new_p_ref.dtype)
     new_m_ref[:] = m
     new_v_ref[:] = v
 
 
+def _bias_corrections(b1, b2, step):
+    step = jnp.asarray(step, jnp.float32)
+    return 1.0 - jnp.power(b1, step), 1.0 - jnp.power(b2, step)
+
+
 def fused_adamw_flat(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
                      lr, b1: float, b2: float, eps: float, wd: float, step,
-                     block_rows: int = 256, interpret: bool | None = None):
+                     block_rows: int = _BLOCK_ROWS, interpret: bool | None = None):
     """Apply fused AdamW to flat 1-D buffers; returns (p, m, v)."""
     n = p.shape[0]
     pad = (-n) % _LANES
@@ -60,10 +71,11 @@ def fused_adamw_flat(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
     rows = (n + pad) // _LANES
     shape2 = (rows, _LANES)
     p2, g2, m2, v2 = (x.reshape(shape2) for x in (p, g, m, v))
-    scalars = jnp.asarray([lr, b1, b2, eps, wd, step], jnp.float32)
+    scalars = jnp.asarray([lr, b1, b2, eps, wd, *_bias_corrections(
+        b1, b2, step)], jnp.float32)
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     block_rows = min(block_rows, rows)
     grid = (pl.cdiv(rows, block_rows),)
     row_spec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
@@ -76,6 +88,7 @@ def fused_adamw_flat(p: jax.Array, g: jax.Array, m: jax.Array, v: jax.Array,
         out_shape=[jax.ShapeDtypeStruct(shape2, p.dtype),
                    jax.ShapeDtypeStruct(shape2, jnp.float32),
                    jax.ShapeDtypeStruct(shape2, jnp.float32)],
+        name="fused_adamw",
         interpret=interpret,
     )(p2, g2, m2, v2, scalars)
     out = (new_p.ravel(), new_m.ravel(), new_v.ravel())
@@ -105,7 +118,7 @@ def _lion_kernel(p_ref, g_ref, m_ref, sc_ref, new_p_ref, new_m_ref):
 
 
 def fused_lion_flat(p, g, m, lr, b1: float, b2: float, wd: float,
-                    block_rows: int = 256, interpret: bool | None = None):
+                    block_rows: int = _BLOCK_ROWS, interpret: bool | None = None):
     """Apply fused Lion to flat 1-D buffers; returns (p, m)."""
     n = p.shape[0]
     pad = (-n) % _LANES
@@ -116,7 +129,7 @@ def fused_lion_flat(p, g, m, lr, b1: float, b2: float, wd: float,
     p2, g2, m2 = (x.reshape(shape2) for x in (p, g, m))
     scalars = jnp.asarray([lr, b1, b2, wd], jnp.float32)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     block_rows = min(block_rows, rows)
     row_spec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
     new_p, new_m = pl.pallas_call(
@@ -127,6 +140,7 @@ def fused_lion_flat(p, g, m, lr, b1: float, b2: float, wd: float,
         out_specs=[row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct(shape2, p.dtype),
                    jax.ShapeDtypeStruct(shape2, jnp.float32)],
+        name="fused_lion",
         interpret=interpret,
     )(p2, g2, m2, scalars)
     out = (new_p.ravel(), new_m.ravel())
@@ -183,30 +197,32 @@ def fused_lion(learning_rate, b1: float = 0.9, b2: float = 0.99,
 def _lamb_stage1_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
                         u_ref, new_m_ref, new_v_ref, norms_ref):
     """Elementwise Adam-style update u (incl. decoupled wd term) + this
-    block's partial squared norms of p and u (norms_ref [1, 2] per grid
-    row; summed on the host side of the call).
-    sc_ref (SMEM, [5]): b1, b2, eps, wd, step."""
+    block's partial squared norms of p and u (one (8, 128) fp32 tile per
+    grid step — the smallest block the TPU lowering accepts — holding
+    sum(p*p) in lane 0 and sum(u*u) in lane 1; summed by the caller).
+    sc_ref (SMEM, [6]): b1, b2, eps, wd, bc1, bc2 (bias corrections
+    from the caller, as in ``_adamw_kernel``)."""
     b1 = sc_ref[0]
     b2 = sc_ref[1]
     eps = sc_ref[2]
     wd = sc_ref[3]
-    step = sc_ref[4]
+    bc1 = sc_ref[4]
+    bc2 = sc_ref[5]
     g = g_ref[:].astype(jnp.float32)
     p = p_ref[:].astype(jnp.float32)
     m = b1 * m_ref[:] + (1.0 - b1) * g
     v = b2 * v_ref[:] + (1.0 - b2) * g * g
-    bc1 = 1.0 - jnp.power(b1, step)
-    bc2 = 1.0 - jnp.power(b2, step)
     u = (m / bc1) / (jnp.sqrt(v / bc2) + eps) + wd * p
     u_ref[:] = u
     new_m_ref[:] = m
     new_v_ref[:] = v
-    norms_ref[0, 0] = jnp.sum(p * p)
-    norms_ref[0, 1] = jnp.sum(u * u)
+    lane = jax.lax.broadcasted_iota(jnp.int32, norms_ref.shape, 1)
+    norms_ref[:] = jnp.where(lane == 0, jnp.sum(p * p),
+                             jnp.where(lane == 1, jnp.sum(u * u), 0.0))
 
 
 def fused_lamb_flat(p, g, m, v, lr, b1: float, b2: float, eps: float,
-                    wd: float, step, block_rows: int = 256,
+                    wd: float, step, block_rows: int = _BLOCK_ROWS,
                     interpret: bool | None = None):
     """Fused LAMB on flat 1-D buffers; returns (p, m, v).
 
@@ -221,9 +237,10 @@ def fused_lamb_flat(p, g, m, v, lr, b1: float, b2: float, eps: float,
     rows = (n + pad) // _LANES
     shape2 = (rows, _LANES)
     p2, g2, m2, v2 = (x.reshape(shape2) for x in (p, g, m, v))
-    scalars = jnp.asarray([b1, b2, eps, wd, step], jnp.float32)
+    scalars = jnp.asarray([b1, b2, eps, wd, *_bias_corrections(
+        b1, b2, step)], jnp.float32)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     block_rows = min(block_rows, rows)
     nblocks = pl.cdiv(rows, block_rows)
     row_spec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
@@ -233,16 +250,16 @@ def fused_lamb_flat(p, g, m, v, lr, b1: float, b2: float, eps: float,
         in_specs=[row_spec, row_spec, row_spec, row_spec,
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[row_spec, row_spec, row_spec,
-                   pl.BlockSpec((1, 2), lambda i: (i, 0),
-                                memory_space=pltpu.SMEM)],
+                   pl.BlockSpec((8, 128), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct(shape2, jnp.float32),
                    jax.ShapeDtypeStruct(shape2, jnp.float32),
                    jax.ShapeDtypeStruct(shape2, jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, 2), jnp.float32)],
+                   jax.ShapeDtypeStruct((nblocks * 8, 128), jnp.float32)],
+        name="fused_lamb_stage1",
         interpret=interpret,
     )(p2, g2, m2, v2, scalars)
-    pn = jnp.sqrt(norms[:, 0].sum())
-    un = jnp.sqrt(norms[:, 1].sum())
+    pn = jnp.sqrt(norms[::8, 0].sum())
+    un = jnp.sqrt(norms[::8, 1].sum())
     ratio = jnp.where((pn > 0) & (un > 0), pn / un, 1.0)
     new_p = (p2 - lr * ratio * u).astype(p.dtype)
     out = (new_p.ravel(), new_m.ravel(), new_v.ravel())

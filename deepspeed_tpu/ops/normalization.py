@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..accelerator import on_tpu
+
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[:].astype(jnp.float32)
@@ -41,9 +43,17 @@ def _layernorm_kernel(x_ref, w_ref, b_ref, o_ref, *, eps):
                 + b_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
+#: fp32 working set one grid step may hold across its row blocks — with
+#: double-buffered DMA this keeps the kernels inside the 16 MiB of scoped
+#: VMEM at any width (the fused-residual variant has four row blocks)
+_VMEM_BUDGET = 8 << 20
+
+
 def _row_call(kernel, args, out_shapes, d, block_rows, interpret):
     lead = args[0].shape[0]
-    block_rows = min(block_rows, lead)
+    n_row_blocks = sum(a.ndim > 1 for a in args) + len(out_shapes)
+    fit = _VMEM_BUDGET // (n_row_blocks * d * 4) // 8 * 8
+    block_rows = min(block_rows, lead, max(8, fit))
     grid = (pl.cdiv(lead, block_rows),)
     specs = []
     for a in args:
@@ -58,6 +68,7 @@ def _row_call(kernel, args, out_shapes, d, block_rows, interpret):
         kernel, grid=grid, in_specs=specs,
         out_specs=out_specs[0] if single else out_specs,
         out_shape=out_shapes[0] if single else out_shapes,
+        name=getattr(kernel, "func", kernel).__name__.strip("_"),
         interpret=interpret)(*args)
 
 
@@ -67,7 +78,7 @@ def rmsnorm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,
     """x: [..., D].  With ``residual``, computes the FastGen fused
     (residual-add -> norm) and returns (normed, new_residual)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     shape = x.shape
     d = shape[-1]
     x2 = x.reshape(-1, d)
@@ -90,7 +101,7 @@ def layernorm(x: jax.Array, weight: jax.Array, bias: jax.Array,
               eps: float = 1e-5, block_rows: int = 256,
               interpret: Optional[bool] = None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     shape = x.shape
     d = shape[-1]
     x2 = x.reshape(-1, d)

@@ -21,7 +21,6 @@ import hashlib
 import os
 import platform
 import subprocess
-import tempfile
 from pathlib import Path
 from typing import Dict, List, Optional, Type
 
@@ -32,8 +31,10 @@ CSRC_DIR = _REPO_ROOT / "csrc"
 
 
 def _cache_dir() -> Path:
+    # built beside the compile cache, inside the checkout (gitignored):
+    # a chip run gets the working tree and nothing around it
     root = os.environ.get("DS_TPU_OPS_CACHE",
-                          os.path.join(tempfile.gettempdir(), "ds_tpu_ops"))
+                          str(_REPO_ROOT / ".ds_ops_cache"))
     path = Path(root)
     path.mkdir(parents=True, exist_ok=True)
     return path

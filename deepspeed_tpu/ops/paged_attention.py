@@ -36,9 +36,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from ..accelerator import on_tpu
 
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
@@ -54,11 +52,12 @@ KV_QUANT_FORMATS = ("none", "int8")
 @jax.tree_util.register_pytree_node_class
 class KVPages:
     """Block-scaled int8 KV page store (ISSUE 16): the quantized twin of
-    the plain ``[..., page, 2, K, D]`` cache array.
+    the plain ``[..., 2, K, page, D]`` cache array.
 
     ``payload`` holds the int8 codes at the fp layout's exact shape;
     ``scale`` is the per-(token, kv-head) fp32 sidecar — one scale per
-    ``head_dim`` block (``payload.shape[:-1]``), the EQuARX block
+    ``head_dim`` block (``payload.shape[:-1]``, so a page's scales are
+    one lane-major ``[page]`` row per head), the EQuARX block
     discipline the comm path already uses.  Per-token scales mean a
     decode append never rescales previously-written content: each
     written row carries its own amax, so pages are immutable after
@@ -127,7 +126,10 @@ def write_kv(kv_layer: jax.Array, k_new: jax.Array, v_new: jax.Array,
              q_lens: jax.Array) -> jax.Array:
     """Scatter new KV into the cache pages of one layer.
 
-    kv_layer : [num_pages+1, page_size, 2, K, D] (or :class:`KVPages`)
+    kv_layer : [num_pages+1, 2, K, page_size, D] (or :class:`KVPages`)
+               — per (page, k/v, head) one ``[page_size, D]`` tile, the
+               block the Pallas kernel DMAs (the TPU lowering needs the
+               last two block dims to be tile-aligned array dims)
     k_new/v_new : [S, Q, K, D]
     Returns the updated kv_layer (functional; donate at jit boundary).
     A quantized layer quantizes at append: codes and scales scatter at
@@ -135,7 +137,7 @@ def write_kv(kv_layer: jax.Array, k_new: jax.Array, v_new: jax.Array,
     """
     S, Q = k_new.shape[:2]
     quantized = isinstance(kv_layer, KVPages)
-    page_size = (kv_layer.payload if quantized else kv_layer).shape[1]
+    page_size = (kv_layer.payload if quantized else kv_layer).shape[3]
     pos = token_positions(start_pos, Q)                     # [S, Q]
     valid = jnp.arange(Q, dtype=jnp.int32)[None, :] < q_lens[:, None]
     page_idx_in_seq = pos // page_size
@@ -147,13 +149,15 @@ def write_kv(kv_layer: jax.Array, k_new: jax.Array, v_new: jax.Array,
     kv_new = jnp.stack([k_new, v_new], axis=2)              # [S,Q,2,K,D]
     if quantized:
         codes, scales = quantize_kv_blocks(kv_new)
+        # split advanced indices (page, token slot) put the token dim
+        # first: the update is [S*Q, 2, K(, D)]
         return KVPages(
-            kv_layer.payload.at[pages_f, slot_f].set(
+            kv_layer.payload.at[pages_f, :, :, slot_f].set(
                 codes.reshape((S * Q,) + codes.shape[2:]), mode="drop"),
-            kv_layer.scale.at[pages_f, slot_f].set(
+            kv_layer.scale.at[pages_f, :, :, slot_f].set(
                 scales.reshape((S * Q,) + scales.shape[2:]), mode="drop"))
     kv_f = kv_new.reshape((S * Q,) + kv_new.shape[2:]).astype(kv_layer.dtype)
-    return kv_layer.at[pages_f, slot_f].set(kv_f, mode="drop")
+    return kv_layer.at[pages_f, :, :, slot_f].set(kv_f, mode="drop")
 
 
 def paged_attention(q: jax.Array, kv_layer: jax.Array,
@@ -167,7 +171,7 @@ def paged_attention(q: jax.Array, kv_layer: jax.Array,
     """Masked GQA attention of [S, Q] new tokens over their paged context.
 
     q        : [S, Q, H, D]    (H = K * groups)
-    kv_layer : [num_pages+1, page_size, 2, K, D] (new KV already written)
+    kv_layer : [num_pages+1, 2, K, page_size, D] (new KV already written)
     Returns  : [S, Q, H, D]
 
     Ragged buckets route to the Pallas kernel (``use_kernel`` None =
@@ -182,30 +186,27 @@ def paged_attention(q: jax.Array, kv_layer: jax.Array,
     S, Q, H, D = q.shape
     quantized = isinstance(kv_layer, KVPages)
     kv_arr = kv_layer.payload if quantized else kv_layer
-    K_heads = kv_arr.shape[3]
+    K_heads = kv_arr.shape[2]
     if use_kernel is None:
-        use_kernel = ((interpret or jax.default_backend() == "tpu")
+        use_kernel = ((interpret or on_tpu())
                       and Q * (H // K_heads) <= MAX_KERNEL_Q_ROWS)
     if use_kernel:
         return paged_decode_attention(
             q, kv_layer, page_table, start_pos,
             sm_scale=sm_scale, alibi_slopes=alibi_slopes,
             window=window, interpret=interpret)
-    page_size = kv_arr.shape[1]
-    K = kv_arr.shape[3]
+    K = K_heads
     G = H // K
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
 
-    pages = kv_arr[page_table]                  # [S, P, page, 2, K, D]
+    pages = kv_arr[page_table]                  # [S, P, 2, K, page, D]
     if quantized:
         # dequantize the gathered context only — the resident cache
-        # stays int8; [S, P, page, 2, K] scales broadcast over D
+        # stays int8; [S, P, 2, K, page] scales broadcast over D
         pages = dequantize_kv_blocks(
             pages, kv_layer.scale[page_table], dtype=q.dtype)
-    P = pages.shape[1]
-    C = P * page_size
-    k = pages[..., 0, :, :].reshape(S, C, K, D)
-    v = pages[..., 1, :, :].reshape(S, C, K, D)
+    k, v = _flatten_context(pages)              # [S, C, K, D]
+    C = k.shape[1]
 
     qg = q.reshape(S, Q, K, G, D)
     scores = jnp.einsum("sqkgd,sckd->skgqc", qg, k).astype(jnp.float32) * scale
@@ -246,11 +247,16 @@ def _decode_kernel(pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
                             ctx_len_r = start_pos + r // G + 1)
     k_ref/v_ref : [page_size, D]  (one cache page, DMA'd via the page
                             table — see the index maps in the caller)
-    ks_ref/vs_ref : [page_size, 1]  per-token block scales — present
-                            ONLY when ``has_scale`` (quantized int8
-                            pages, ISSUE 16): the page dequantizes in
-                            VMEM right after its one DMA, so HBM
-                            traffic stays int8-sized
+    ks_ref/vs_ref : [K, page_size]  per-token block scales of every
+                            head of this page (row ``k`` is this grid
+                            step's) — present ONLY when ``has_scale``
+                            (quantized int8 pages, ISSUE 16).  A scale
+                            is constant over ``D``, so it factors out
+                            of both matmuls and is applied to the
+                            ``[rows, page]`` score / probability tile
+                            as a lane-major row: int8 codes feed the
+                            MXU directly and HBM traffic stays
+                            int8-sized
     slopes_ref : [1, G]    per-q-head ALiBi slopes — present ONLY when
                             ``has_alibi`` (the kernel is specialized
                             statically so non-ALiBi models pay nothing)
@@ -271,6 +277,7 @@ def _decode_kernel(pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
         ks_ref = vs_ref = None
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = rest
     s = pl.program_id(0)
+    kh = pl.program_id(1)
     p = pl.program_id(2)
     rows = q_len * groups
 
@@ -292,15 +299,14 @@ def _decode_kernel(pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
     @pl.when(page_valid)
     def _attend():
         q = q_ref[:]                                   # [Q*G, D]
-        if has_scale:
-            # block dequant in VMEM: codes [page, D] * scales [page, 1]
-            k = (k_ref[:].astype(jnp.float32)
-                 * ks_ref[:]).astype(q_ref.dtype)
-        else:
-            k = k_ref[:]                               # [page, D]
+        # int8 codes are exact in the query dtype; their per-token
+        # scale multiplies the score column instead of the [page, D] tile
+        k = k_ref[:].astype(q_ref.dtype)               # [page, D]
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [Q*G, page]
+        if has_scale:
+            scores = scores * ks_ref[pl.ds(kh, 1), :]  # [1, page] row
         ctx = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
         if has_alibi:  # additive bias linear in the absolute key position
@@ -327,20 +333,24 @@ def _decode_kernel(pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
         m_scr[:] = m_new
         l_scr[:] = l_prev * alpha + jnp.sum(pexp, axis=1, keepdims=True)
         if has_scale:
-            vv = v_ref[:].astype(jnp.float32) * vs_ref[:]  # [page, D]
-            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-                pexp, vv, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-                pexp.astype(v_ref.dtype), v_ref[:],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            pexp = pexp * vs_ref[pl.ds(kh, 1), :]
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            pexp.astype(q_ref.dtype), v_ref[:].astype(q_ref.dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(p == num_pages_per_seq - 1)
     def _finish():
         o_ref[:] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
                     ).astype(o_ref.dtype)
+
+
+def _flatten_context(pages: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Gathered pages ``[S, P, 2, K, page, D]`` -> token-major K and V
+    contexts ``[S, P*page, K, D]`` (context row c IS position c)."""
+    S, P, _, K, page_size, D = pages.shape
+    kv = pages.transpose(2, 0, 1, 4, 3, 5).reshape(2, S, P * page_size, K, D)
+    return kv[0], kv[1]
 
 
 def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
@@ -360,14 +370,13 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
     prefill+decode ragged batch (the single-kernel serving formulation
     of Ragged Paged Attention, arxiv 2604.15464).
 
-    q: [S, Q, H, D]; kv_layer: [num_pages+1, page_size, 2, K, D];
+    q: [S, Q, H, D]; kv_layer: [num_pages+1, 2, K, page_size, D];
     page_table: [S, P]; start_pos: [S].  Returns [S, Q, H, D].
     """
     S, Q, H, D = q.shape
     has_scale = isinstance(kv_layer, KVPages)
     kv_arr = kv_layer.payload if has_scale else kv_layer
-    page_size = kv_arr.shape[1]
-    K = kv_arr.shape[3]
+    K, page_size = kv_arr.shape[2:4]
     G = H // K
     P_pages = page_table.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
@@ -381,21 +390,22 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
     # index maps receive (s, k, p, *scalar_prefetch_refs)
     q_spec = pl.BlockSpec((None, None, Q * G, D),
                           lambda s, k, p, pt, sp: (s, k, 0, 0))
-    k_spec = pl.BlockSpec((None, page_size, None, None, D),
-                          lambda s, k, p, pt, sp: (pt[s, p], 0, 0, k, 0))
-    v_spec = pl.BlockSpec((None, page_size, None, None, D),
-                          lambda s, k, p, pt, sp: (pt[s, p], 0, 1, k, 0))
+    k_spec = pl.BlockSpec((None, None, None, page_size, D),
+                          lambda s, k, p, pt, sp: (pt[s, p], 0, k, 0, 0))
+    v_spec = pl.BlockSpec((None, None, None, page_size, D),
+                          lambda s, k, p, pt, sp: (pt[s, p], 1, k, 0, 0))
     o_spec = pl.BlockSpec((None, None, Q * G, D),
                           lambda s, k, p, pt, sp: (s, k, 0, 0))
 
     if has_scale:
-        # scale sidecar [P+1, page, 2, K] -> [page, 1] block per (p, k):
-        # the same page-table indirection as k/v, 2-D refs (Mosaic
-        # rejects in-kernel gathers; the BlockSpec DMA does the gather)
-        ks_spec = pl.BlockSpec((None, page_size, None, 1),
-                               lambda s, k, p, pt, sp: (pt[s, p], 0, 0, k))
-        vs_spec = pl.BlockSpec((None, page_size, None, 1),
-                               lambda s, k, p, pt, sp: (pt[s, p], 0, 1, k))
+        # scale sidecar [P+1, 2, K, page] -> the page's full [K, page]
+        # tile (last two block dims = array dims, which the lowering
+        # requires); the kernel row-slices its own head.  Same
+        # page-table indirection as k/v: the BlockSpec DMA is the gather
+        ks_spec = pl.BlockSpec((None, None, K, page_size),
+                               lambda s, k, p, pt, sp: (pt[s, p], 0, 0, 0))
+        vs_spec = pl.BlockSpec((None, None, K, page_size),
+                               lambda s, k, p, pt, sp: (pt[s, p], 1, 0, 0))
         in_specs = [q_spec, k_spec, ks_spec, v_spec, vs_spec]
         inputs = (qg, kv_arr, kv_layer.scale, kv_arr, kv_layer.scale)
     else:
@@ -426,8 +436,9 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, K, Q * G, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(page_table.astype(jnp.int32), start_pos.astype(jnp.int32), *inputs)
     out = out.reshape(S, K, Q, G, D).transpose(0, 2, 1, 3, 4)
@@ -482,7 +493,4 @@ def paged_context(kv_layer: jax.Array, page_table: jax.Array
                                      kv_layer.scale[page_table])
     else:
         pages = kv_layer[page_table]
-    S, P, page_size = pages.shape[:3]
-    k = pages[..., 0, :, :].reshape(S, P * page_size, *pages.shape[4:])
-    v = pages[..., 1, :, :].reshape(S, P * page_size, *pages.shape[4:])
-    return k, v
+    return _flatten_context(pages)

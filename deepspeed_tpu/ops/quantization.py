@@ -23,6 +23,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..accelerator import on_tpu
 from ..utils.jax_compat import axis_size as _axis_size
 
 BLOCK = 512  # quantization group size (reference default 512/2048)
@@ -34,12 +35,29 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     scale = jnp.maximum(absmax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(x / scale), -127, 127)
     q_ref[:] = q.astype(jnp.int8)
-    s_ref[:] = scale[:, 0]
+    s_ref[:] = scale.T                          # [1, rows] lane-major
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
     o_ref[:] = (q_ref[:].astype(jnp.float32)
-                * s_ref[:][:, None]).astype(o_ref.dtype)
+                * s_ref[:].T).astype(o_ref.dtype)
+
+
+#: rows per grid step (int8 tiles are (32, 128); the [1, rows] scale
+#: block needs a multiple of 128 lanes)
+_BLOCK_ROWS = 256
+
+
+def _row_grid(rows: int, block: int):
+    """(grid, payload spec, scale spec) of the row-blocked quant calls:
+    a grid step sees ``[block_rows, block]`` values and the matching
+    ``[1, block_rows]`` slice of the lane-major scale row — the whole
+    tensor as ONE block neither fits VMEM nor compiles in bounded time
+    at real sizes."""
+    block_rows = min(_BLOCK_ROWS, rows)
+    return ((pl.cdiv(rows, block_rows),),
+            pl.BlockSpec((block_rows, block), lambda i: (i, 0)),
+            pl.BlockSpec((1, block_rows), lambda i: (0, i)))
 
 
 def quantize_blockwise(x: jax.Array, block: int = BLOCK,
@@ -47,7 +65,7 @@ def quantize_blockwise(x: jax.Array, block: int = BLOCK,
                        ) -> Tuple[jax.Array, jax.Array, int]:
     """Flat fp tensor -> (int8 values [rows, block], fp32 scales [rows], pad)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     flat = x.ravel()
     n = flat.shape[0]
     pad = (-n) % block
@@ -55,25 +73,31 @@ def quantize_blockwise(x: jax.Array, block: int = BLOCK,
         flat = jnp.pad(flat, (0, pad))
     rows = flat.shape[0] // block
     x2 = flat.reshape(rows, block)
+    grid, val_spec, scale_spec = _row_grid(rows, block)
     q, s = pl.pallas_call(
         _quant_kernel,
+        grid=grid, in_specs=[val_spec], out_specs=[val_spec, scale_spec],
         out_shape=[jax.ShapeDtypeStruct((rows, block), jnp.int8),
-                   jax.ShapeDtypeStruct((rows,), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, rows), jnp.float32)],
+        name="quantize_blockwise",
         interpret=interpret,
     )(x2)
-    return q, s, pad
+    return q, s.reshape(rows), pad
 
 
 def dequantize_blockwise(q: jax.Array, s: jax.Array, pad: int,
                          shape, dtype=jnp.float32,
                          interpret: Optional[bool] = None) -> jax.Array:
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
+    grid, val_spec, scale_spec = _row_grid(*q.shape)
     out = pl.pallas_call(
         _dequant_kernel,
+        grid=grid, in_specs=[val_spec, scale_spec], out_specs=val_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, dtype),
+        name="dequantize_blockwise",
         interpret=interpret,
-    )(q, s)
+    )(q, s.reshape(1, -1))
     flat = out.ravel()
     if pad:
         flat = flat[:-pad]

@@ -171,20 +171,8 @@ def ambient_mesh():
     """The physical Mesh active at trace time, or None.
 
     Single lookup point for trace-time mesh discovery (used by the
-    transformer's sharding constraints and comm.get_world_group).  Tries
-    the current private location first, then the deprecated public alias
-    — when JAX removes both, this one site needs the update."""
-    for locate in (
-        lambda: __import__("jax._src.mesh", fromlist=["thread_resources"]
-                           ).thread_resources.env.physical_mesh,
-        lambda: __import__("jax.interpreters.pxla", fromlist=["thread_resources"]
-                           ).thread_resources.env.physical_mesh,
-    ):
-        try:
-            m = locate()
-        except Exception:
-            continue
-        if m is not None and not m.empty:
-            return m
-        return None
-    return None
+    transformer's sharding constraints and comm.get_world_group) — when
+    JAX moves ``thread_resources``, this one site needs the update."""
+    from jax._src.mesh import thread_resources
+    m = thread_resources.env.physical_mesh
+    return None if m.empty else m

@@ -29,27 +29,37 @@ import numpy as np
 
 from ..utils.logging import logger
 
-# Peak dense bf16 FLOP/s per chip for utilization estimates (public specs;
-# extend as generations appear). Fallback: measured-only report.
+# Peak dense bf16 FLOP/s per chip (Google Cloud TPU documentation), keyed
+# by a substring of ``device_kind``; extend as generations appear.  A
+# device that is not here has NO peak: utilization is not reported for it.
 PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
     "TPU v5e": 197e12,
     "TPU v5p": 459e12,
     "TPU v6e": 918e12,
-    "cpu": None,
 }
 
 
-def _device_peak_flops() -> Optional[float]:
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return None
+def device_peak_flops() -> float:
+    """Published peak of the device JAX computes on; an unknown
+    ``device_kind`` is an error, never a default."""
+    kind = str(jax.devices()[0].device_kind)
     for name, peak in PEAK_FLOPS.items():
-        if name.lower() in str(kind).lower():
+        if name.lower() in kind.lower():
             return peak
-    return None
+    raise LookupError(
+        f"no published peak FLOP/s for device_kind {kind!r} — add it to "
+        "profiling.flops_profiler.PEAK_FLOPS with its source")
+
+
+def _device_peak_flops() -> Optional[float]:
+    """The peak, or None where the device has none (reports then carry
+    measured FLOP/s only, no utilization)."""
+    try:
+        return device_peak_flops()
+    except LookupError:
+        return None
 
 
 def _format_count(n: Optional[float], unit: str = "") -> str:

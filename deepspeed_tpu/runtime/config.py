@@ -507,9 +507,10 @@ class ServingOptimizationConfig(DeepSpeedConfigModel):
     #: sampled output survives handoff/migration tokenwise identical
     keyed_sampling: bool = False
     # -- recompile-proof cold starts (ISSUE 14) ------------------------
-    #: persistent XLA compile cache directory ("" = off;
-    #: DS_COMPILE_CACHE env overrides) — restored/spawned replicas load
-    #: executables from disk instead of re-compiling the lattice
+    #: where the persistent XLA compile cache goes when
+    #: JAX_COMPILATION_CACHE_DIR is not set ("" = <repo>/.jax_cache/) —
+    #: restored/spawned replicas load executables from disk instead of
+    #: re-compiling the lattice
     compile_cache_dir: str = ""
     #: bucket lattice: "" = power-of-two default; "auto:<path>" loads a
     #: mined lattice artifact (analyze_trace --emit-lattice) or mines a

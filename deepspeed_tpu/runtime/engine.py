@@ -1232,11 +1232,8 @@ class DeepSpeedEngine:
             return
         from ..profiling import FlopsProfiler
         prof = FlopsProfiler(params=self.state.params)
-        # fixed key: lowering must not consume the training RNG stream, or
-        # enabling the profiler changes every later step's randomness
-        lowered = self._train_step.lower(self.state, placed_batch,
-                                         jax.random.key(0))
-        cost = lowered.compile().cost_analysis() or {}
+        cost = self._lower_placed(placed_batch).compile().cost_analysis() \
+            or {}
         if isinstance(cost, (list, tuple)):
             cost = cost[0] if cost else {}
         prof._cost = {"flops": float(cost.get("flops", 0.0)),
@@ -1248,6 +1245,25 @@ class DeepSpeedEngine:
             top_modules=fp_cfg.top_modules,
             detailed=fp_cfg.detailed,
             output_file=fp_cfg.output_file)
+
+    def _lower_placed(self, placed_batch):
+        # fixed key: lowering must not consume the training RNG stream, or
+        # inspecting the step changes every later step's randomness
+        with self.topology.mesh:
+            return self._train_step.lower(self.state, placed_batch,
+                                          jax.random.key(0))
+
+    def lower_train_step(self, batch):
+        """The fused train step lowered for ``batch`` (a
+        ``jax.stages.Lowered``): ``.compile()`` gives the executable the
+        next ``train_batch`` on such a batch runs — ``as_text()`` shows
+        its kernels and collectives, ``memory_analysis()`` its
+        footprint.  Runs nothing and leaves the engine state alone."""
+        self._check_not_destroyed()
+        with self.topology.mesh:
+            placed = self._place_batch(self._shape_batch(batch),
+                                       microbatched=True)
+        return self._lower_placed(placed)
 
     def _apply_offload_step(self, off_grads, lr: float) -> None:
         """Host optimizer step over offloaded leaves + push updated weights

@@ -59,7 +59,7 @@ import threading
 from typing import Any, Dict, Mapping, Optional
 
 
-# -- fault taxonomy ----------------------------------------------------------
+# -- fault catalog ----------------------------------------------------------
 
 class InjectedFault(RuntimeError):
     """Base of every exception the registry raises on purpose."""

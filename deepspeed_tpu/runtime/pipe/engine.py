@@ -49,6 +49,7 @@ from flax.core import meta
 from jax.sharding import PartitionSpec as P
 
 from ...models import transformer as tfm
+from ...accelerator import on_tpu
 from ...utils.jax_compat import shard_map as _compat_shard_map
 from ...utils.logging import log_dist
 from ..engine import DeepSpeedEngine
@@ -140,7 +141,7 @@ def gpipe_spmd(mesh,
     # Batch-parallel axes go MANUAL alongside 'pipe' (fully-manual
     # region): differentiating a PARTIAL-auto region hits hard
     # partitioner bugs on this JAX version (scalar-residual _SpecError,
-    # unsupported PartitionId — see utils/jax_compat.py notes), while a
+    # unsupported PartitionId), while a
     # fully-manual region differentiates fine.  Leaves of x/consts whose
     # dim 1 is the global micro-batch width shard over these axes; the
     # activation's dim 0 is that batch dim by the first_fn/stage_fn
@@ -199,7 +200,7 @@ def gpipe_spmd(mesh,
     # over 'pipe', and XLA-CPU's all-reduce promotion pass miscompiles
     # sub-fp32 all-reduces.  On TPU the widening is skipped — an fp32
     # copy of the embedding/lm-head per stage would be real HBM.
-    widen = jax.default_backend() == "cpu"
+    widen = not on_tpu()
 
     def _to_f32(t):
         if not widen:

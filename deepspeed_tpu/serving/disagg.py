@@ -116,7 +116,7 @@ class DisaggPool:
             # same gate as ReplicaPool._warm_new_replica: without an
             # active persistent compile cache the manifest would be
             # synchronous TRUE compiles at pool birth — stay lazy then
-            from ..inference.v2.compile_cache import active_cache_dir
+            from ..utils.compile_cache import active_cache_dir
             if active_cache_dir() is None:
                 from ..utils.logging import logger
                 logger.info("DisaggPool: no active compile cache — "
@@ -515,13 +515,14 @@ class DisaggPool:
         the disaggregation thesis stands on.  The ONE implementation
         behind both the ``ds_disagg_*`` gauges and the bench/replay
         report."""
-        from ..inference.v2.model import serving_peak_flops
+        from ..inference.v2.model import serving_peak_flops, utilization
         pre = self.prefill._engine.cost_summary()
         dec = self.decode._engine.cost_summary()
         peak = serving_peak_flops()
         out = {
-            "prefill_mfu": (float(pre.get("flops_dispatched", 0.0))
-                            / max(self.prefill_busy_s, 1e-9) / peak),
+            "prefill_mfu": utilization(
+                float(pre.get("flops_dispatched", 0.0))
+                / max(self.prefill_busy_s, 1e-9), peak),
             "decode_hbm_gb_s": (float(dec.get("bytes_dispatched", 0.0))
                                 / max(self.decode_busy_s, 1e-9) / 1e9),
         }

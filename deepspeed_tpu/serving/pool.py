@@ -255,7 +255,7 @@ class ReplicaPool:
         keep the lazy prior behavior (join immediately, compile the
         keys traffic actually forms).  Best-effort — a failure warns
         and the replica joins cold rather than not at all."""
-        from ..inference.v2.compile_cache import active_cache_dir
+        from ..utils.compile_cache import active_cache_dir
         if active_cache_dir() is None:
             return
         manifest = self.compiled_manifest()

@@ -46,7 +46,7 @@ from typing import Any, Dict, List, Optional
 
 from .state import state
 
-#: the CLOSED segment taxonomy (docs/DESIGN.md "Request journeys").
+#: the CLOSED segment catalog (docs/DESIGN.md "Request journeys").
 #: Producers mark only these kinds; consumers (fleetctl, the CI smoke)
 #: may hard-fail on an unknown kind.
 SEGMENT_KINDS = (

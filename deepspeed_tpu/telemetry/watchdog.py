@@ -406,8 +406,8 @@ class Watchdog:
             "trace: `python tools/analyze_trace.py --trace %s "
             "--emit-lattice lattice.json` and rebuild the engine with "
             "serving_optimization.lattice=\"auto:lattice.json\" "
-            "(plus compile_cache_dir/DS_COMPILE_CACHE so later "
-            "processes load, not compile)",
+            "(the persistent compile cache then turns later "
+            "processes' compiles into loads)",
             len(recent), self.storm_window_s, keys, trace_hint)
 
     # -- health verdicts (/healthz) ------------------------------------------

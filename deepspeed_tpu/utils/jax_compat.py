@@ -1,142 +1,49 @@
-"""Portability shims over JAX APIs that moved between releases.
+"""The few JAX spellings this code base funnels through one place.
 
-``shard_map`` has lived in three places with two keyword spellings:
-
-* ``jax.experimental.shard_map.shard_map`` — the long-lived experimental
-  home; replication checking is ``check_rep`` and partial-manual mode is
-  ``auto`` (a frozenset of axis names left to GSPMD).
-* ``jax.shard_map`` — the stabilized API; replication checking became
-  ``check_vma`` and partial-manual mode inverted into ``axis_names``
-  (the MANUAL subset).
-
-Every in-repo call site imports :func:`shard_map` from here with the
-*new* keyword spellings (``check_vma``, ``auto``) and the shim adapts to
-whichever implementation the installed JAX provides.  One lookup point,
-same spirit as :func:`~deepspeed_tpu.parallel.topology.ambient_mesh`.
+Written for the one installation the repo runs on (jax 0.9.0): plain
+``jax.shard_map`` (``check_vma`` / ``axis_names``) and
+``jax.lax.axis_size``.  No version probing — when the installation
+moves, this file is the one to edit.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional
 
-
-def _locate():
-    try:  # stabilized location (newer JAX)
-        import jax
-        fn = getattr(jax, "shard_map", None)
-        if callable(fn):
-            return fn
-    except Exception:
-        pass
-    from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-_impl = _locate()
-_impl_params = frozenset(inspect.signature(_impl).parameters)
-
-
-def _install_scalar_residual_shim() -> None:
-    """Work around a jax 0.4.x shard_map partial-eval bug: residuals
-    crossing the known/staged split are named ``{0: all_mesh_axes}``
-    regardless of rank (``_pe_custom_params`` / ``_shard_map_partial_eval``
-    have no scalar promotion on this path), so a RANK-0 residual trips
-    ``_check_names`` (_SpecError on ``float32[]``) when differentiating
-    through a shard_map region under jit.  A rank-0 aval can never carry
-    dim names — stripping them is the only well-defined reading — and
-    doing so unblocks gradients through fully-manual pipeline regions.
-    Newer JAX (stabilized jax.shard_map) does not need or get the shim.
-    """
-    try:
-        from jax.experimental import shard_map as _smod
-    except Exception:
-        return
-    orig = getattr(_smod, "_check_names", None)
-    if orig is None or getattr(orig, "_ds_tpu_rank0_tolerant", False):
-        return
-
-    def _check_names(names, avals):
-        names = [{} if getattr(a, "ndim", None) == 0 else n
-                 for n, a in zip(names, avals)]
-        return orig(names, avals)
-
-    _check_names._ds_tpu_rank0_tolerant = True
-    _smod._check_names = _check_names
-
-
-if "check_rep" in _impl_params:  # old experimental implementation only
-    _install_scalar_residual_shim()
+import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None,
               auto: Any = None):
-    """Version-portable ``shard_map``.
-
-    ``check_vma``: replication checking (None = implementation default).
-    ``auto``: iterable of mesh axis names left to the compiler (GSPMD)
-    inside the region; the remaining axes are manual.  Partial-manual
-    regions require jit — eager partial-auto is unimplemented in the
-    experimental API.
-    """
+    """``jax.shard_map`` with the partial-manual mode spelled by the
+    axes left to the compiler: ``auto`` is an iterable of mesh axis
+    names GSPMD keeps inside the region (``jax.shard_map`` takes the
+    complement, the MANUAL subset, as ``axis_names``).  Partial-manual
+    regions require jit."""
     kwargs = {}
     if check_vma is not None:
-        if "check_vma" in _impl_params:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _impl_params:
-            kwargs["check_rep"] = check_vma
+        kwargs["check_vma"] = check_vma
     if auto:
-        auto = frozenset(auto)
-        if "auto" in _impl_params:
-            kwargs["auto"] = auto
-        elif "axis_names" in _impl_params:  # stabilized API: manual subset
-            kwargs["axis_names"] = frozenset(mesh.axis_names) - auto
-        else:
-            raise NotImplementedError(
-                "installed JAX supports neither 'auto' nor 'axis_names' "
-                "on shard_map; partial-manual regions unavailable")
-    return _impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 **kwargs)
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` shim: newer JAX has it; on older releases a
-    ``Mesh`` is its own context manager."""
-    import jax
-    fn = getattr(jax, "set_mesh", None)
-    if callable(fn):
-        return fn(mesh)
-    return mesh
+        kwargs["axis_names"] = frozenset(mesh.axis_names) - frozenset(auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def axis_size(axis_name) -> int:
     """Static size of (a tuple of) named mesh axes bound in the current
-    trace.  ``jax.lax.axis_size`` only exists in newer JAX; older
-    releases expose the same fact through the axis env."""
-    import jax.lax as lax
+    trace."""
     names = (axis_name if isinstance(axis_name, (tuple, list))
              else (axis_name,))
-    if hasattr(lax, "axis_size"):
-        size = 1
-        for a in names:
-            size *= int(lax.axis_size(a))
-        return size
-    from jax._src.core import get_axis_env
-    env = get_axis_env()
     size = 1
     for a in names:
-        size *= int(env.axis_size(a))
+        size *= int(jax.lax.axis_size(a))
     return size
 
 
 def manual_axis_names() -> frozenset:
     """Mesh axis names bound manually in the CURRENT trace (inside a
-    shard_map region), or an empty set outside one / when the private
-    axis-env API is unavailable.  Sharding constraints must not mention
-    manual axes — callers prune their specs with this."""
-    try:
-        from jax._src.core import get_axis_env
-        return frozenset(get_axis_env().axis_sizes)
-    except Exception:
-        return frozenset()
+    shard_map region); empty outside one.  Sharding constraints must
+    not mention manual axes — callers prune their specs with this."""
+    from jax._src.core import get_axis_env
+    return frozenset(get_axis_env().axis_sizes)

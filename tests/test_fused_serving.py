@@ -409,7 +409,7 @@ class TestRaggedKernelMixedQ:
         from deepspeed_tpu.inference.v2 import BlockedAllocator
         rng = np.random.default_rng(0)
         H = K * G
-        kv = jnp.zeros((pages + 1, page, 2, K, D), jnp.float32)
+        kv = jnp.zeros((pages + 1, 2, K, page, D), jnp.float32)
         alloc = BlockedAllocator(pages)
         table = np.zeros((S, 8), np.int32)
         start = np.zeros(S, np.int32)
@@ -422,7 +422,7 @@ class TestRaggedKernelMixedQ:
             start[s] = h
             q_lens[s] = Q
             for t in range(h):
-                kv = kv.at[pgs[t // page], t % page].set(
+                kv = kv.at[pgs[t // page], :, :, t % page].set(
                     jnp.asarray(rng.standard_normal((2, K, D)), jnp.float32))
         q = jnp.asarray(rng.standard_normal((S, Q, H, D)), jnp.float32)
         k_new = jnp.asarray(rng.standard_normal((S, Q, K, D)), jnp.float32)

@@ -121,6 +121,27 @@ def test_launch_propagates_child_failure(tmp_path):
     assert proc.returncode == 3
 
 
+def test_proc_per_chip_is_refused_on_a_tpu_host(tmp_path, monkeypatch):
+    """Nothing hands each child its own chip, so N processes on a host
+    that holds chips would fight over all of them: refuse, start none.
+    One slot (one process for the whole host) stays allowed."""
+    from deepspeed_tpu.launcher import launch
+    marker = tmp_path / "ran"
+    script = tmp_path / "child.py"
+    script.write_text(f"open(r'{marker}', 'a').write('x')")
+    monkeypatch.setattr(launch, "tpu_chips_on_host", lambda: 4)
+
+    def run(slots):
+        return launch.main([
+            f"--world_info={encode_world_info({'localhost': slots})}",
+            "--node_rank=0", "--proc_per_chip", str(script)])
+    assert run(4) == 2 and not marker.exists()
+    assert run(1) == 0 and marker.read_text() == "x"
+    # on a host without chips (the CPU virtual mesh) the flag works
+    monkeypatch.setattr(launch, "tpu_chips_on_host", lambda: 0)
+    assert run(2) == 0 and marker.read_text() == "xxx"
+
+
 def test_runner_cmd_construction():
     class Args:
         master_addr = "w0"

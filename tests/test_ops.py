@@ -66,6 +66,74 @@ class TestFlashAttention:
         ref = mha_reference(q, q, q, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
+    def test_reference_is_selected_by_the_device_not_by_fallthrough(
+            self, monkeypatch):
+        """interpret=None asks on_tpu(): the jnp reference on CPU, the
+        kernel (whose lowering error propagates) once the device says
+        TPU — and an explicit bool always runs the kernel."""
+        import importlib
+
+        from deepspeed_tpu.accelerator import real_accelerator
+        fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+        q = rand(1, 1, 32, 16)
+        called = []
+        monkeypatch.setattr(
+            fa, "_flash_attention",
+            lambda *a: called.append(a[7]) or a[0])
+        flash_attention(q, q, q)
+        assert called == []                      # CPU: reference
+        flash_attention(q, q, q, interpret=True)
+        assert called == [True]
+        monkeypatch.setattr(real_accelerator, "device_platform",
+                            lambda: "tpu")
+        flash_attention(q, q, q)
+        assert called == [True, False]           # TPU: compiled kernel
+
+    @pytest.mark.parametrize("seq,block", [(512, 256), (768, 512),
+                                           (96, 512), (1100, 512)])
+    def test_public_entry_never_leaves_a_ragged_block(self, seq, block):
+        from deepspeed_tpu.ops.flash_attention import _fit_block
+        fit = _fit_block(block, seq)
+        assert seq % fit == 0 and (fit == seq or fit % 128 == 0)
+        assert fit <= max(block, seq)
+
+    @pytest.mark.parametrize("seq,block_q", [(128, 64), (96, 32), (64, 512)])
+    def test_lse_is_a_lane_major_row(self, seq, block_q):
+        """The forward's log-sum-exp leaves as [B, H, 1, S] (the layout
+        the TPU lowering accepts) and equals the reference's."""
+        from deepspeed_tpu.ops.flash_attention import _flash_fwd
+        q, k, v = (rand(2, 3, seq, 32, seed=s) for s in (1, 2, 3))
+        scale = 32 ** -0.5
+        out, lse = _flash_fwd(q, k, v, scale, True, block_q, 32, True, None)
+        assert lse.shape == (2, 3, 1, seq) and lse.dtype == jnp.float32
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        scores = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), scores,
+                           -jnp.inf)
+        want = jax.scipy.special.logsumexp(scores, axis=-1)
+        np.testing.assert_allclose(np.asarray(lse[:, :, 0]),
+                                   np.asarray(want), atol=2e-3, rtol=2e-3)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(mha_reference(q, k, v)),
+            atol=2e-3, rtol=2e-3)
+
+    @pytest.mark.parametrize("window", [None, 40])
+    def test_backward_uneven_blocks_and_gqa_heads(self, window):
+        """dkv (k-major score tiles) and dq (row -> column lse) over
+        several unequal q/k blocks, batch and heads > 1."""
+        q, k, v, w = (rand(2, 2, 192, 32, seed=s) for s in (1, 2, 3, 4))
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) * w).sum()
+        g1 = jax.grad(loss(lambda q, k, v: _flash_attention(
+            q, k, v, 32 ** -0.5, True, 64, 32, True, window)),
+            argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(loss(lambda q, k, v: mha_reference(
+            q, k, v, causal=True, window=window)),
+            argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-3, rtol=5e-3)
+
 
 class TestFusedAdam:
     def test_flat_matches_optax(self):
@@ -143,6 +211,22 @@ class TestQuantization:
         err = np.abs(np.asarray(x) - np.asarray(y)).max()
         scale = np.abs(np.asarray(x)).max() / 127
         assert err <= scale * 1.01
+
+    def test_row_grid_matches_single_block_math(self):
+        """More rows than one grid step (256) and a ragged last step:
+        per-row scales and codes are what plain numpy computes."""
+        x = rand(700 * 512 + 100, seed=3)
+        q, s, pad = quantize_blockwise(x, block=512)
+        assert q.shape == (701, 512) and s.shape == (701,)
+        rows = np.pad(np.asarray(x), (0, pad)).reshape(701, 512)
+        want_s = np.maximum(np.abs(rows).max(axis=1), 1e-12) / 127.0
+        np.testing.assert_allclose(np.asarray(s), want_s, rtol=1e-6)
+        np.testing.assert_array_equal(
+            np.asarray(q), np.clip(np.round(rows / want_s[:, None]),
+                                   -127, 127).astype(np.int8))
+        y = dequantize_blockwise(q, s, pad, x.shape)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(x),
+                                   atol=float(want_s.max()) * 0.51)
 
     def test_quant_shapes(self):
         x = rand(1000, seed=1)  # pad to 2 blocks of 512

@@ -134,8 +134,7 @@ def test_gpipe_matches_sequential(pipe):
         out = gpipe_spmd(mesh, pipe, stage_fn, stages_ws, x)
         return (out ** 2).mean()
 
-    from deepspeed_tpu.utils.jax_compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pl, pg = jax.jit(jax.value_and_grad(pipe_loss))(stages_ws, x)
     sl, sg = jax.value_and_grad(seq_loss)(ws, x)
     np.testing.assert_allclose(float(pl), float(sl), rtol=1e-5)

@@ -128,22 +128,23 @@ def test_runtime_config_carries_tp_to_v2():
     assert v2.serving.tp_collective_quantization == "int8"
 
 
-def test_mesh_change_is_a_compile_cache_miss():
-    """tp in the digest: a mesh/encoding change namespaces DIFFERENT
-    cache entries — a miss, never a wrong executable."""
-    from deepspeed_tpu.inference.v2.compile_cache import (
-        compile_config_digest)
-    cfg, _ = _model_parts()
-    kv = KVCacheConfig(num_layers=cfg.num_layers, kv_heads=cfg.kv_heads,
-                       head_dim=cfg.dims_per_head, page_size=16,
-                       num_pages=8, dtype=jnp.float32)
-    base = compile_config_digest(cfg, kv)
-    assert compile_config_digest(cfg, kv, tp_degree=1,
-                                 tp_collective_quantization="none") == base
-    d2 = compile_config_digest(cfg, kv, tp_degree=2)
-    d2q = compile_config_digest(cfg, kv, tp_degree=2,
-                                tp_collective_quantization="int8")
-    assert len({base, d2, d2q}) == 3
+def test_tpu_kernels_run_per_shard_under_the_mesh():
+    """GSPMD refuses to partition a Mosaic custom call (even over
+    replicated operands), so under tp the attention modules run in a
+    shard_map over their head slice and the norm in a replicated manual
+    region; without a mesh, and for ALiBi (closed-over per-head slopes),
+    the module is called as is."""
+    import dataclasses
+    eng = _engine(serving=_sv(tp=2))
+    model = eng._model
+    fn = object()
+    assert model._per_shard_heads(fn, model.cfg, 1) is not fn
+    assert model._norm is not model._norm_impl
+    alibi = dataclasses.replace(model.cfg, pos_emb="alibi")
+    assert model._per_shard_heads(fn, alibi, 1) is fn
+    plain = _engine(serving=_sv(tp=1))._model
+    assert plain._per_shard_heads(fn, plain.cfg, 1) is fn
+    assert plain._norm is plain._norm_impl
 
 
 def test_engine_guards():
@@ -159,13 +160,13 @@ def test_mesh_and_kv_pages_are_head_partitioned():
     assert model.tp_degree == 2 and model._tp_axis == "tp"
     assert float(tm.FASTGEN_SHARD_COUNT.value) == 2.0
     data = eng.state_manager.kv_cache.data
-    # [L, pages, page, 2, K, D]: each shard holds only its head slice
+    # [L, pages, 2, K, page, D]: each shard holds only its head slice
     shards = data.addressable_shards
     assert len(shards) == 2
     k = model.kv_config.kv_heads
     for s in shards:
-        assert s.data.shape[4] == k // 2
-        assert s.data.shape[:4] == data.shape[:4]
+        assert s.data.shape[3] == k // 2
+        assert s.data.shape[:3] == data.shape[:3]
 
 
 # ---------------------------------------------------------------------------

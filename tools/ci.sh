@@ -71,10 +71,6 @@
 #                      and replays — asserting tokenwise parity,
 #                      compile_on_path_total == 0, and ZERO true
 #                      compiles (cache loads only)
-#   6. bench gate    — tools/check_bench.py --strict (latest vs
-#                      previous BENCH_r*.json; throughput -10% /
-#                      latency +15% tolerances, cross-backend rounds
-#                      downgraded to notes, fleet keys ±30/40%)
 #
 # Usage: tools/ci.sh [extra pytest args for the tier-1 leg]
 # Environment: JAX_PLATFORMS defaults to cpu (the CI mesh);
@@ -137,8 +133,5 @@ python tools/plan_capacity.py --trace tools/traces/sample_200.jsonl \
 # (the former standalone metric-lint leg is leg 0's metric-catalog
 # rule now; tools/check_metrics.py remains as a local/CI-transition
 # shim over the same implementation)
-
-echo "== bench regression gate =="
-python tools/check_bench.py --strict
 
 echo "ci.sh: all gates green"

@@ -169,7 +169,7 @@ def check_flight_events(project: Project,
                     f"flight event kind {kind!r} is not registered in "
                     f"{recorder_path}:EVENT_KINDS — postmortem "
                     "consumers grep by kind; register it (with the "
-                    "DESIGN.md event taxonomy) before recording it",
+                    "DESIGN.md event catalog) before recording it",
                     detail=f"unknown:{kind}"))
     for kind in sorted(kinds - used):
         out.append(Finding(

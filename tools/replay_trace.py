@@ -690,11 +690,13 @@ def run_disagg_bench(trace_path: Optional[str] = None,
     # SAME busy-window accounting as the disagg pools (seconds inside
     # scheduler steps), so the specialization inequalities compare
     # like with like
-    from deepspeed_tpu.inference.v2.model import serving_peak_flops
+    from deepspeed_tpu.inference.v2.model import (serving_peak_flops,
+                                                  utilization)
     fused_cost = fused_eng.model.cost_summary()
     fused_busy = max(float(fused_rep.get("busy_s") or 0.0), 1e-9)
-    fused_mfu = (float(fused_cost.get("flops_dispatched", 0.0))
-                 / fused_busy / serving_peak_flops())
+    fused_mfu = utilization(
+        float(fused_cost.get("flops_dispatched", 0.0)) / fused_busy,
+        serving_peak_flops())
     fused_hbm = (float(fused_cost.get("bytes_dispatched", 0.0))
                  / fused_busy / 1e9)
     fused_compiles = tm.FASTGEN_COMPILE_ON_PATH.value - comp0

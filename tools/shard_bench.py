@@ -21,14 +21,15 @@ Numbers are CPU-debug-relative — the simulated
 mesh times shard arithmetic on host cores, so tok/s across arms is a
 sanity band, not a speedup claim; the wire-byte ratio is exact.
 
-bench.py's jax is already initialized single-device by the time the
-BENCH_SHARD leg runs, so ``run_shard_bench`` re-execs this file as a
-``--worker`` subprocess with the forced device count in XLA_FLAGS and
-reads one JSON object from its stdout.
+This is a CPU tool: the parent never imports JAX (a process that has
+touched JAX holds the chip its child would need) and starts this file as
+a ``--worker`` subprocess pinned to the CPU platform with the forced
+device count in XLA_FLAGS; the worker labels its output
+``"platform": "cpu"``.  It is not a leg of ``bench.py``, which holds the
+chip.  The real tp path on chips is ``python chip_smoke.py --chips 4``.
 
 Usage::
 
-    BENCH_SHARD=1 python bench.py          # as a bench leg
     python tools/shard_bench.py            # standalone (spawns worker)
     python tools/shard_bench.py --worker   # in a forced-mesh process
 """
@@ -49,15 +50,14 @@ if REPO_ROOT not in sys.path:
 
 
 def run_shard_bench() -> Dict[str, Any]:
-    """Spawn the forced-mesh worker and return its ``fastgen_shard_*``
-    metrics.  A subprocess is not optional: the host device count is
-    read once at jax import, and the parent bench process imported jax
-    long ago with the default single device."""
+    """Spawn the forced-mesh CPU worker and return its
+    ``fastgen_shard_*`` facts (the host device count is read once at
+    jax import, so the mesh needs a process of its own)."""
     tp = max(2, int(os.environ.get("BENCH_SHARD_TP", "2")))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={tp}")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"     # a simulated mesh, never the chip
     budget = float(os.environ.get("BENCH_SHARD_TIMEOUT", "600"))
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--worker"],
@@ -131,6 +131,7 @@ def _worker() -> Dict[str, Any]:
             tp_collective_quantization="int8")),
     ]
     out: Dict[str, Any] = {
+        "platform": jax.devices()[0].platform,   # cpu: a simulated mesh
         "fastgen_shard_tp": tp,
         "fastgen_shard_reqs": n_req,
         "fastgen_shard_new_tokens": max_new,
