@@ -1018,8 +1018,9 @@ class InferenceEngineV2:
             batch = self._build_batch(
                 descs, [np.asarray(t) for t in batch_tokens])
             serving_counters.record_program()
-            logits, self._state.kv_cache.data = self._model.forward(
-                batch, self._state.kv_cache.data)
+            with trace_span("engine.dispatch"):
+                logits, self._state.kv_cache.data = self._model.forward(
+                    batch, self._state.kv_cache.data)
             logits = logits[:len(batch_uids)]
             self._commit_batch(descs)
             serving_counters.record_logits_exposed(int(logits.size) * 4)
@@ -1041,8 +1042,9 @@ class InferenceEngineV2:
             sub_tokens = [np.asarray(batch_tokens[i]) for i in idxs]
             batch = self._build_batch(sub_descs, sub_tokens)
             serving_counters.record_program()
-            logits, self._state.kv_cache.data = self._model.forward(
-                batch, self._state.kv_cache.data)
+            with trace_span("engine.dispatch"):
+                logits, self._state.kv_cache.data = self._model.forward(
+                    batch, self._state.kv_cache.data)
             for row, i in enumerate(idxs):
                 logits_rows[i] = logits[row]
 
@@ -1149,9 +1151,12 @@ class InferenceEngineV2:
             greedy_only = not bool((temps > 0.0).any())
             serving_counters.record_program(
                 h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-            tokens, self._state.kv_cache.data = self._model.sample_step(
-                batch, self._state.kv_cache.data, rng, temps, top_ks,
-                top_ps, greedy_only, row_uids=kuids, row_pos=kpos)
+            with trace_span("engine.dispatch"):
+                tokens, self._state.kv_cache.data = \
+                    self._model.sample_step(
+                        batch, self._state.kv_cache.data, rng, temps,
+                        top_ks, top_ps, greedy_only,
+                        row_uids=kuids, row_pos=kpos)
             self._commit_batch(descs)
             return tokens, list(range(len(batch_uids)))
 
@@ -1186,9 +1191,12 @@ class InferenceEngineV2:
         greedy_only = not bool((temps > 0.0).any())
         serving_counters.record_program(
             h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-        tokens, self._state.kv_cache.data = self._model.sample_step_mixed(
-            dec, pre, self._state.kv_cache.data, rng, temps, top_ks,
-            top_ps, greedy_only, row_uids=kuids, row_pos=kpos)
+        with trace_span("engine.dispatch"):
+            tokens, self._state.kv_cache.data = \
+                self._model.sample_step_mixed(
+                    dec, pre, self._state.kv_cache.data, rng, temps,
+                    top_ks, top_ps, greedy_only,
+                    row_uids=kuids, row_pos=kpos)
         self._commit_batch(descs)
         return tokens, row_of_input
 
@@ -1220,10 +1228,11 @@ class InferenceEngineV2:
         serving_counters.record_program(
             h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes
             + gather.nbytes)
-        tokens, self._state.kv_cache.data = self._model.chained_step(
-            batch, self._state.kv_cache.data, prev_tokens, gather, rng,
-            temps, top_ks, top_ps, greedy_only,
-            row_uids=kuids, row_pos=kpos)
+        with trace_span("engine.dispatch"):
+            tokens, self._state.kv_cache.data = self._model.chained_step(
+                batch, self._state.kv_cache.data, prev_tokens, gather,
+                rng, temps, top_ks, top_ps, greedy_only,
+                row_uids=kuids, row_pos=kpos)
         self._commit_batch(descs)
         return tokens
 
@@ -1256,9 +1265,10 @@ class InferenceEngineV2:
         greedy_only = not bool((temps > 0.0).any())
         serving_counters.record_program(
             h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-        out, self._state.kv_cache.data = self._model.spec_step(
-            batch, self._state.kv_cache.data, rng, temps, top_ks,
-            top_ps, greedy_only, row_uids=kuids, row_pos=kpos)
+        with trace_span("engine.dispatch"):
+            out, self._state.kv_cache.data = self._model.spec_step(
+                batch, self._state.kv_cache.data, rng, temps, top_ks,
+                top_ps, greedy_only, row_uids=kuids, row_pos=kpos)
         return out
 
     def step_draft_spec(self, batch_uids: Sequence[int],
@@ -1289,11 +1299,12 @@ class InferenceEngineV2:
         greedy_only = not bool((temps > 0.0).any())
         serving_counters.record_program(
             h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-        out, (self._state.kv_cache.data, self._draft_kv) = \
-            self._model.draft_spec_step(
-                batch, (self._state.kv_cache.data, self._draft_kv),
-                rng, temps, top_ks, top_ps, greedy_only,
-                row_uids=kuids, row_pos=kpos)
+        with trace_span("engine.dispatch"):
+            out, (self._state.kv_cache.data, self._draft_kv) = \
+                self._model.draft_spec_step(
+                    batch, (self._state.kv_cache.data, self._draft_kv),
+                    rng, temps, top_ks, top_ps, greedy_only,
+                    row_uids=kuids, row_pos=kpos)
         return out
 
     def step_draft_fill(self, batch_uids: Sequence[int],
@@ -1344,8 +1355,9 @@ class InferenceEngineV2:
         serving_counters.record_program(
             h2d_bytes=token_ids.nbytes + q_lens.nbytes
             + start_pos.nbytes + page_table.nbytes)
-        self._draft_kv = self._model.draft_fill_step(batch,
-                                                     self._draft_kv)
+        with trace_span("engine.dispatch"):
+            self._draft_kv = self._model.draft_fill_step(batch,
+                                                         self._draft_kv)
         for uid, start, n in zip(batch_uids, starts, lengths):
             self._draft_seen[uid] = start + n
 

@@ -27,9 +27,11 @@ from ...models import transformer as T
 from ...ops.paged_attention import (KVPages, gather_last, paged_attention,
                                     rope_write_kv, token_positions,
                                     write_kv)
+from ...telemetry import get_tracer
 from ...telemetry import metrics as tm
 from ...telemetry.watchdog import get_watchdog
 from ...telemetry.workload_trace import get_workload_trace
+from ...utils.compile_cache import thread_cache_counts
 from .ragged import KVCacheConfig, RaggedBatch
 
 
@@ -567,16 +569,7 @@ class RaggedInferenceModel:
             # so on-path compiles feed the same cost_analysis()
             # accounting as the precompiled lattice (ISSUE 9)
             def compile_on_call(*args, _key=key):
-                compiled = jax.jit(
-                    self._impl_of(_key),
-                    donate_argnums=(1,)).lower(*args).compile()
-                self._note_program_cost(_key, compiled)
-                # _get_step already accounted this dispatch, but the
-                # cost was unknown then — bill it now so on-path and
-                # precompiled keys agree from dispatch 1
-                self._account_cost(_key)
-                self._step_cache[_key] = compiled
-                return compiled(*args)
+                return self._form_program(_key, args, run=True)
 
             self._step_cache[key] = compile_on_call
             fn = compile_on_call
@@ -584,6 +577,44 @@ class RaggedInferenceModel:
             get_watchdog().note_step_cache(hit=True)
         self._account_dispatch(key)
         return fn
+
+    def _form_program(self, key, args, run: bool = False):
+        """Trace, lower and compile the step program of ``key`` for
+        ``args`` (arrays or avals) and put the executable into the step
+        cache: the one place a program forms, on the request path
+        (``run``: the waiting call is made here too and its result
+        returned) or ahead of it.  Every phase is a span, written
+        whether or not telemetry is on: a formation costs seconds and
+        set-up, where most of them happen, runs with telemetry off."""
+        tracer = get_tracer()
+        with tracer.span("engine.program",
+                         {"key": key, "on_path": run}) as prog:
+            before = thread_cache_counts()
+            with tracer.span("engine.program.trace"):
+                traced = jax.jit(self._impl_of(key),
+                                 donate_argnums=(1,)).trace(*args)
+            with tracer.span("engine.program.lower"):
+                lowered = traced.lower()
+            with tracer.span("engine.program.compile") as comp:
+                compiled = lowered.compile()
+                after = thread_cache_counts()
+                # XLA compiled it, or the persistent cache held it
+                cache = ("hit" if after["hits"] > before["hits"] else
+                         "miss" if after["misses"] > before["misses"]
+                         else "off")
+                comp.set("cache", cache)
+            prog.set("cache", cache)
+            with tracer.span("engine.program.cost"):
+                self._note_program_cost(key, compiled)
+            self._step_cache[key] = compiled
+            if not run:
+                return None
+            # _get_step already accounted this dispatch, but the cost
+            # was unknown then — bill it now so on-path and precompiled
+            # keys agree from dispatch 1
+            self._account_cost(key)
+            with tracer.span("engine.program.first_run"):
+                return compiled(*args)
 
     # -- per-program cost / MFU accounting (ISSUE 9) -------------------------
     def _note_program_cost(self, key, compiled) -> None:
@@ -810,13 +841,10 @@ class RaggedInferenceModel:
         bucket compiles on the request path)."""
         if key in self._step_cache:
             return
-        fn = jax.jit(self._impl_of(key), donate_argnums=(1,))
         # the COMPILED executable goes into the cache: later calls with
         # the bucket's exact shapes dispatch straight to it (jit's own
         # dispatch cache is not populated by AOT lowering)
-        compiled = fn.lower(*self._step_avals(key, kv_aval)).compile()
-        self._note_program_cost(key, compiled)
-        self._step_cache[key] = compiled
+        self._form_program(key, self._step_avals(key, kv_aval))
 
     def compiled_programs(self) -> Dict[tuple, Any]:
         """Step-cache key -> compiled executable, for inspection

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import time
 from collections import Counter
-from typing import Dict, List, Optional, Set
+from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -98,6 +98,21 @@ class StateManager:
     def offloaded_blob_bytes(self) -> int:
         """Host bytes held by offloaded (preempted) sequences' blobs."""
         return self._offload_bytes
+
+    def kv_occupancy(self) -> Tuple[int, int]:
+        """(pages in the live sequences' block tables, tokens written to
+        them): what is reserved against what is used.  A page shared by
+        several sequences counts once per table; a page the sliding
+        window gave back counts in neither.  Walks every sequence, so it
+        is taken only for a live span."""
+        page = self.kv_config.page_size
+        pages = tokens = 0
+        for sd in self._seqs.values():
+            live = sum(1 for p in sd.pages if p != NULL_PAGE)
+            pages += live
+            tokens += max(sd.seen_tokens
+                          - (len(sd.pages) - live) * page, 0)
+        return pages, tokens
 
     def get_sequence(self, uid: int) -> Optional[SequenceDescriptor]:
         return self._seqs.get(uid)

@@ -90,29 +90,24 @@ class Request:
     done: bool = False
     #: prefix-cache lookup already performed (exactly once per request)
     prefix_checked: bool = False
-    #: SLO stamps (ISSUE 4, perf_counter seconds; 0.0 = unset/telemetry
-    #: off at submit): submit time, first scheduled admission, and the
-    #: previous host-visible token.  ``slo_gen`` records the telemetry
-    #: generation ``last_token_s`` was taken in, so a stamp from before
-    #: a disabled gap can't observe the gap as one giant ITL sample
+    #: the request's one set of latency stamps (``time.perf_counter()``
+    #: seconds, the span ring's clock; 0.0 = not reached yet), taken
+    #: whether or not telemetry is on: submit, first scheduled
+    #: admission, first and newest host-visible token.  The latency
+    #: histograms, the ``request.*`` spans and the workload ledger's
+    #: facts are all derived from them, so a request that was submitted
+    #: before telemetry was switched on is still timed from its submit
     submit_s: float = 0.0
-    first_sched_s: float = 0.0
-    last_token_s: float = 0.0
-    slo_gen: int = 0
+    admit_s: float = 0.0
+    first_token_s: float = 0.0
+    token_s: float = 0.0
     #: absolute ``time.monotonic()`` deadline (ISSUE 7); None = no TTL.
     #: Past it the request drains with a structured "expired" error
     deadline: Optional[float] = None
-    #: ``time.monotonic()`` at submit — always stamped (unlike the
-    #: telemetry-gated SLO stamps): the shed valve needs the CURRENT
-    #: backlog age even with telemetry off
+    #: ``time.monotonic()`` at submit: the clock of the TTL deadlines
+    #: and of the shed valve (the CURRENT backlog's age), and the
+    #: workload ledger's arrival time
     submit_mono: float = 0.0
-    #: workload-trace stamps (ISSUE 9, monotonic seconds; 0.0 = unset /
-    #: capture off at the time): first scheduled admission and the
-    #: first/last host-visible token — the trace's queue-wait / TTFT /
-    #: mean-ITL facts, independent of the telemetry-gated SLO stamps
-    first_sched_mono: float = 0.0
-    first_token_mono: float = 0.0
-    last_token_mono: float = 0.0
     #: speculative decoding facts (ISSUE 10): tokens this request had
     #: drafted for it and tokens verification accepted — the workload
     #: ledger records both so the analyzer can recommend spec_max_draft
@@ -180,6 +175,11 @@ class RequestError:
     message: str
     tokens: List[int] = dataclasses.field(default_factory=list)
 
+
+#: what one step scheduled, for the ``fastgen.step`` span: (path, rows,
+#: prefill rows, prefill tokens, tokens charged to the budget); taken
+#: only while telemetry is on
+_IDLE_STEP = ("idle", 0, 0, 0, 0)
 
 #: bounded retention for FastGenScheduler.errors — a long-lived
 #: scheduler under sustained shedding must not grow without bound
@@ -285,6 +285,7 @@ class FastGenScheduler:
             rng = jax.random.wrap_key_data(rng)
         self._rng = rng
         self.last_step_scheduled = 0
+        self._step_shape = _IDLE_STEP
         #: one-way latch: a strict engine's sampling lattice, once seen,
         #: stays seen (avoids rescanning the step cache every step)
         self._fused_ready = False
@@ -450,13 +451,12 @@ class FastGenScheduler:
                                    "vocab_size", 0)),
             temperature=p.temperature, top_k=p.top_k, top_p=p.top_p,
             max_new_tokens=p.max_new_tokens, outcome=outcome,
-            ttft_ms=((req.first_token_mono - req.submit_mono) * 1e3
-                     if req.first_token_mono else None),
-            itl_ms=((req.last_token_mono - req.first_token_mono) * 1e3
-                    / (n - 1)
-                    if n > 1 and req.first_token_mono else None),
-            queue_wait_ms=((req.first_sched_mono - req.submit_mono) * 1e3
-                           if req.first_sched_mono else None),
+            ttft_ms=((req.first_token_s - req.submit_s) * 1e3
+                     if req.first_token_s else None),
+            itl_ms=((req.token_s - req.first_token_s) * 1e3 / (n - 1)
+                    if n > 1 and req.first_token_s else None),
+            queue_wait_ms=((req.admit_s - req.submit_s) * 1e3
+                           if req.admit_s else None),
             spec_drafted=req.spec_drafted,
             spec_accepted=req.spec_accepted,
             spec_drafter=req.spec_drafter,
@@ -488,13 +488,6 @@ class FastGenScheduler:
         j.mark("drain")
         _journey.get_journey_log().publish(j, outcome)
 
-    def _trace_token(self, req: Request) -> None:
-        """Stamp one host-visible token (capture-on path only)."""
-        mono = time.monotonic()
-        if req.first_token_mono == 0.0:
-            req.first_token_mono = mono
-        req.last_token_mono = mono
-
     # -- request lifecycle ---------------------------------------------------
     def submit(self, uid: int, prompt: Sequence[int],
                params: Optional[SamplingParams] = None,
@@ -521,6 +514,7 @@ class FastGenScheduler:
             else _journey.mint(uid)
         now = time.monotonic()
         req.submit_mono = now
+        req.submit_s = time.perf_counter()
         if self._closed:
             # a submit after close/drain-for-snapshot used to enqueue
             # silently — onto a scheduler that will never run it and
@@ -551,8 +545,6 @@ class FastGenScheduler:
         if ttl:
             req.deadline = now + float(ttl)
             self._has_deadlines = True
-        if _telemetry.enabled:
-            req.submit_s = time.perf_counter()
         if self._max_queue_depth and \
                 len(self._pending) >= self._max_queue_depth:
             return self._reject_submit(
@@ -643,6 +635,8 @@ class FastGenScheduler:
         get_flight_recorder().record(
             "request.error", uid=req.uid, code=code,
             message=message[:200], tokens=len(req.generated))
+        if _telemetry.enabled:
+            self._close_request_spans(req)
         # journey flush precedes the ledger record so the ledger's
         # journey_<bucket>_ms fields see the closed chain
         self._journey_finish(req, code)
@@ -735,20 +729,49 @@ class FastGenScheduler:
         self._rng, key = jax.random.split(self._rng)
         return key
 
-    # -- slo: per-request latency stamps (enabled path only) -----------------
-    def _note_token_slo(self, req: Request) -> None:
-        """One host-visible token: first token -> TTFT (submit to now),
-        later tokens -> inter-token latency.  Requests submitted while
-        telemetry was off (``submit_s == 0``) only feed the ITL stream
-        once they have a same-regime reference stamp."""
+    # -- per-request latency: one set of stamps, always taken ----------------
+    def _stamp_token(self, req: Request) -> None:
+        """One host-visible token: stamp it (one clock read, telemetry
+        on or off) and, with telemetry on, feed what ends here: the
+        first token closes TTFT and the ``request.prefill`` span, a later
+        one is an inter-token gap.  A request imported mid-life (handoff,
+        restore) has no earlier local token: its first gap is skipped."""
         now = time.perf_counter()
-        if len(req.generated) == 1:
-            if req.submit_s:
+        if _telemetry.enabled:
+            if len(req.generated) == 1:
                 tm.FASTGEN_TTFT_MS.observe((now - req.submit_s) * 1e3)
-        elif req.last_token_s and req.slo_gen == _telemetry.generation:
-            tm.FASTGEN_ITL_MS.observe((now - req.last_token_s) * 1e3)
-        req.last_token_s = now
-        req.slo_gen = _telemetry.generation
+                self._request_span(req, "request.prefill",
+                                   req.admit_s or req.submit_s, now)
+            elif req.token_s:
+                tm.FASTGEN_ITL_MS.observe((now - req.token_s) * 1e3)
+        if req.first_token_s == 0.0:
+            req.first_token_s = now
+        req.token_s = now
+
+    def _request_span(self, req: Request, name: str, start: float,
+                      end: float, attrs: Optional[dict] = None) -> None:
+        """One of the three spans that tile a request's life
+        (``request.queue_wait`` submit -> first admission,
+        ``request.prefill`` -> first token on the host,
+        ``request.decode`` -> done), written after the fact from the
+        request's stamps at the boundary where it ends.  Callers gate on
+        telemetry; the stamps do not, so the span is right for a request
+        submitted before telemetry was switched on."""
+        get_tracer().record(name, start, end - start, attrs, uid=req.uid)
+
+    def _close_request_spans(self, req: Request) -> None:
+        """The request ended (done or failed): close the span that was
+        open."""
+        now = time.perf_counter()
+        if req.first_token_s:
+            self._request_span(req, "request.decode",
+                               req.first_token_s, now,
+                               {"new_tokens": len(req.generated)})
+        elif req.admit_s:
+            self._request_span(req, "request.prefill", req.admit_s, now)
+        else:
+            self._request_span(req, "request.queue_wait",
+                               req.submit_s, now)
 
     # -- drain: sync a dispatched step's tokens ------------------------------
     def _deliver_token(self, req: Request, tok: int, out: Dict[int, int],
@@ -763,10 +786,7 @@ class FastGenScheduler:
         # tok/s the fleet view and SLO evaluator read must exist even
         # telemetry-off — one integer add per token
         tm.FASTGEN_TOKENS.inc()
-        if _telemetry.enabled:
-            self._note_token_slo(req)
-        if self._wtrace.active:
-            self._trace_token(req)
+        self._stamp_token(req)
         if req.journey is not None and len(req.generated) == 1:
             # the first committed token closes prefill; first_token
             # itself is the (~0 ms) delivery instant.  Handoff-imported
@@ -792,6 +812,8 @@ class FastGenScheduler:
         self._running.pop(req.uid, None)
         if self._drafter is not None:
             self._drafter.drop(req.uid)
+        if _telemetry.enabled:
+            self._close_request_spans(req)
         self._journey_finish(req, "ok")
         if self._wtrace.active:
             self._trace_finish(req, "ok")
@@ -805,17 +827,22 @@ class FastGenScheduler:
     # dslint: hot-path
     def _drain_impl(self, on_token) -> Dict[int, int]:
         inf, self._inflight = self._inflight, None
-        toks = np.asarray(inf.tokens_dev)   # dslint: d2h [S] int32
+        with trace_span("fastgen.drain.wait"):
+            # the host blocked on the device: not host work
+            toks = np.asarray(inf.tokens_dev)   # dslint: d2h [S] int32
         serving_counters.record_d2h(toks.nbytes)
         out: Dict[int, int] = {}
-        for uid, row, req in inf.rows:
-            if req.done:
-                # optimistically chained past a stop token — the extra
-                # sampled token is discarded (its KV write landed in
-                # pages the flush already returned to the pool)
-                continue
-            if self._deliver_token(req, int(toks[row]), out, on_token):
-                self._finish_request(req)
+        with trace_span("fastgen.drain.deliver"):
+            for uid, row, req in inf.rows:
+                if req.done:
+                    # optimistically chained past a stop token — the
+                    # extra sampled token is discarded (its KV write
+                    # landed in pages the flush already returned to the
+                    # pool)
+                    continue
+                if self._deliver_token(req, int(toks[row]), out,
+                                       on_token):
+                    self._finish_request(req)
         return out
 
     # -- double buffer: chained decode dispatch ------------------------------
@@ -871,6 +898,8 @@ class FastGenScheduler:
             uids, self._inflight.tokens_dev, gather, params,
             self._next_key(greedy_only), row_pos=row_pos)
         self.last_step_scheduled = len(uids)
+        if _telemetry.enabled:
+            self._step_shape = ("chain", len(uids), 0, 0, len(uids))
         return _Inflight(tokens_dev=toks,
                          rows=[(u, i, req)
                                for i, (u, _, req) in enumerate(rows)])
@@ -1214,33 +1243,38 @@ class FastGenScheduler:
                 uids, toks, params, self._next_key(greedy_only),
                 min_q=1 + self._spec_max_draft, row_pos=row_pos)
         self.last_step_scheduled = len(uids)
-        av = np.asarray(out_dev)            # dslint: d2h [S, 2] int32
+        if _telemetry.enabled:
+            self._step_shape = ("spec", len(uids), 0, 0,
+                                sum(len(t) for t in toks))
+        with trace_span("fastgen.drain.wait"):
+            av = np.asarray(out_dev)        # dslint: d2h [S, 2] int32
         serving_counters.record_d2h(av.nbytes)
         out: Dict[int, int] = {}
         committed: List[int] = []
         drafted = accepted = 0
-        for i, (uid, req, _t, draft) in enumerate(rows):
-            a = min(int(av[i, 0]), len(draft))
-            block = [int(t) for t in draft[:a]] + [int(av[i, 1])]
-            c = 0
-            for tok in block:
-                c += 1
-                if self._deliver_token(req, tok, out, on_token):
-                    # termination deferred: flush needs the descriptor
-                    # the variable-advance commit below still updates
-                    req.done = True
-                    break
-            committed.append(c)
-            # accepted counts COMMITTED drafts only: a stop-token
-            # truncation rolls back verifier-accepted tokens past it,
-            # and the accept-rate the analyzer mines must reflect what
-            # actually committed (c <= a: all c are drafts; c == a+1:
-            # the a drafts plus the correction)
-            drafted += len(draft)
-            accepted += min(a, c)
-            if len(draft):
-                self._note_spec_result(req, "ngram", len(draft),
-                                       min(a, c))
+        with trace_span("fastgen.drain.deliver"):
+            for i, (uid, req, _t, draft) in enumerate(rows):
+                a = min(int(av[i, 0]), len(draft))
+                block = [int(t) for t in draft[:a]] + [int(av[i, 1])]
+                c = 0
+                for tok in block:
+                    c += 1
+                    if self._deliver_token(req, tok, out, on_token):
+                        # termination deferred: flush needs the descriptor
+                        # the variable-advance commit below still updates
+                        req.done = True
+                        break
+                committed.append(c)
+                # accepted counts COMMITTED drafts only: a stop-token
+                # truncation rolls back verifier-accepted tokens past it,
+                # and the accept-rate the analyzer mines must reflect what
+                # actually committed (c <= a: all c are drafts; c == a+1:
+                # the a drafts plus the correction)
+                drafted += len(draft)
+                accepted += min(a, c)
+                if len(draft):
+                    self._note_spec_result(req, "ngram", len(draft),
+                                           min(a, c))
         self._engine.commit_spec(uids, committed)
         for uid, req, _t, _d in rows:
             if req.done:
@@ -1279,28 +1313,33 @@ class FastGenScheduler:
                 uids, toks, params, self._next_key(greedy_only),
                 min_q=1 + self._spec_max_draft, row_pos=row_pos)
         self.last_step_scheduled = len(uids)
-        av = np.asarray(out_dev)            # dslint: d2h [S, 2+k] int32
+        if _telemetry.enabled:
+            self._step_shape = ("draft", len(uids), 0, 0,
+                                sum(len(t) for t in toks))
+        with trace_span("fastgen.drain.wait"):
+            av = np.asarray(out_dev)        # dslint: d2h [S, 2+k] int32
         serving_counters.record_d2h(av.nbytes)
         out: Dict[int, int] = {}
         committed: List[int] = []
         drafted = accepted = 0
-        for i, (uid, req, _t, draft) in enumerate(rows):
-            room = len(draft)
-            a = min(int(av[i, 0]), room)
-            block = [int(t) for t in av[i, 2:2 + a]] + [int(av[i, 1])]
-            c = 0
-            for tok in block:
-                c += 1
-                if self._deliver_token(req, tok, out, on_token):
-                    # termination deferred: flush needs the descriptor
-                    # the variable-advance commit below still updates
-                    req.done = True
-                    break
-            committed.append(c)
-            drafted += room
-            accepted += min(a, c)
-            if room:
-                self._note_spec_result(req, "model", room, min(a, c))
+        with trace_span("fastgen.drain.deliver"):
+            for i, (uid, req, _t, draft) in enumerate(rows):
+                room = len(draft)
+                a = min(int(av[i, 0]), room)
+                block = [int(t) for t in av[i, 2:2 + a]] + [int(av[i, 1])]
+                c = 0
+                for tok in block:
+                    c += 1
+                    if self._deliver_token(req, tok, out, on_token):
+                        # termination deferred: flush needs the descriptor
+                        # the variable-advance commit below still updates
+                        req.done = True
+                        break
+                committed.append(c)
+                drafted += room
+                accepted += min(a, c)
+                if room:
+                    self._note_spec_result(req, "model", room, min(a, c))
         self._engine.commit_spec(uids, committed)
         self._engine.mark_draft_seen(uids)
         for uid, req, _t, _d in rows:
@@ -1335,6 +1374,8 @@ class FastGenScheduler:
             self._engine.step_draft_fill(uids, [t for _, t in rows])
         self.last_step_scheduled = len(uids)
         n = int(sum(len(t) for _, t in rows))
+        if _telemetry.enabled:
+            self._step_shape = ("draft", len(uids), 0, 0, n)
         tm.FASTGEN_SPEC_DRAFT_FILL.inc(n)
         get_flight_recorder().record("spec.draft_fill",
                                      rows=len(uids), tokens=n)
@@ -1369,8 +1410,10 @@ class FastGenScheduler:
                 self._step_ordinal += 1
                 get_tracer().set_step(self._step_ordinal)
                 t0 = time.perf_counter()
-                with trace_span("fastgen.step"):
+                with trace_span("fastgen.step") as span:
                     out = self._step_impl(on_token)
+                    if span.live:
+                        self._note_step(span)
                 step_ms = (time.perf_counter() - t0) * 1e3
                 tm.FASTGEN_STEP_MS.observe(step_ms)
                 # EWMA anomaly detector (ISSUE 5): a recompile or a KV
@@ -1398,6 +1441,22 @@ class FastGenScheduler:
         # sampling interval — peaks between ticks would be lost)
         self._mledger.sample()
         return out
+
+    def _note_step(self, span) -> None:
+        """What the step scheduled and what the KV pool holds at its
+        end, as attributes of its live ``fastgen.step`` span (the pool's
+        totals walk every sequence).  Each one is read by a per-layer
+        metric of the benchmark (PERF.md section 3)."""
+        path, rows, prefill_rows, prefill_tokens, tokens = \
+            self._step_shape
+        pages, held = self._engine.state_manager.kv_occupancy()
+        for key, value in (
+                ("path", path), ("rows", rows),
+                ("prefill_rows", prefill_rows),
+                ("prefill_tokens", prefill_tokens), ("tokens", tokens),
+                ("budget", self._budget),
+                ("kv_pages_reserved", pages), ("kv_tokens_held", held)):
+            span.set(key, value)
 
     def _match_prefix_once(self, req: Request, adm: _Admission) -> None:
         """One-shot prefix-cache lookup before first admission: cached
@@ -1449,6 +1508,7 @@ class FastGenScheduler:
                    ) -> Dict[int, int]:
         serving_counters.record_step()
         self._preempted_this_step = False
+        self._step_shape = _IDLE_STEP
         self._expire_requests()
 
         spec_drained: Optional[Dict[int, int]] = None
@@ -1594,19 +1654,19 @@ class FastGenScheduler:
                 req.prompt_sent += chunk
                 advances.append((req, chunk))
                 serving_counters.record_prefill(chunk)
-                if self._wtrace.active and req.first_sched_mono == 0.0:
-                    req.first_sched_mono = time.monotonic()
-                if _telemetry.enabled and req.first_sched_s == 0.0:
+                if req.admit_s == 0.0:
                     # first scheduled admission: close the queue-wait
                     # window opened at submit
-                    req.first_sched_s = time.perf_counter()
-                    if req.submit_s:
+                    req.admit_s = time.perf_counter()
+                    if _telemetry.enabled:
                         tm.FASTGEN_QUEUE_WAIT_MS.observe(
-                            (req.first_sched_s - req.submit_s) * 1e3)
-                    get_flight_recorder().record(
-                        "request.admit", uid=req.uid,
-                        prompt_tokens=len(req.prompt),
-                        cached_tokens=req.prompt_sent - chunk)
+                            (req.admit_s - req.submit_s) * 1e3)
+                        self._request_span(req, "request.queue_wait",
+                                           req.submit_s, req.admit_s)
+                        get_flight_recorder().record(
+                            "request.admit", uid=req.uid,
+                            prompt_tokens=len(req.prompt),
+                            cached_tokens=req.prompt_sent - chunk)
                 return True
 
             for req in list(self._running.values()):
@@ -1658,6 +1718,13 @@ class FastGenScheduler:
         if use_fused and strict and not self._strict_key_ok(
                 uids, tokens, ("sample", greedy_only)):
             use_fused = False
+        if _telemetry.enabled:
+            # every prompt piece of the step is one entry of ``advances``
+            prefill_tokens = sum(chunk for _, chunk in advances)
+            self._step_shape = (
+                "fused" if use_fused else "split", len(uids),
+                len(advances), prefill_tokens,
+                len(uids) - len(advances) + prefill_tokens)
 
         if use_fused:
             # ONE program: fused mixed-batch forward + on-device
@@ -2016,6 +2083,7 @@ class FastGenScheduler:
                 accounted_bytes=bd["accounted_bytes"],
                 subsystems=bd["subsystems"], rungs=rungs)
         self.last_step_scheduled = 0
+        self._step_shape = _IDLE_STEP
 
     # -- live engine snapshot / deterministic restore (ISSUE 8) --------------
     def close(self) -> None:
@@ -2102,9 +2170,10 @@ class FastGenScheduler:
             prompt_sent=int(d["prompt_sent"]),
             generated=[int(t) for t in d["generated"]],
             prefix_checked=bool(d["prefix_checked"]))
-        # latency/SLO stamps are process-relative and deliberately not
-        # captured; the shed valve's always-on stamp restarts here
+        # the latency stamps are process-relative and deliberately not
+        # captured: the request's clocks restart here
         req.submit_mono = now
+        req.submit_s = time.perf_counter()
         req.spec_drafted = int(d.get("spec_drafted", 0))
         req.spec_accepted = int(d.get("spec_accepted", 0))
         ss = d.get("spec_state")

@@ -438,7 +438,10 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
         out_shape=jax.ShapeDtypeStruct((S, K, Q * G, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="paged_attention",
+        # named by the kind of row it serves, so a trace splits the
+        # kernel's time between decoding rows and prefill chunks
+        name=("paged_attention_decode" if Q == 1
+              else "paged_attention_prefill"),
         interpret=interpret,
     )(page_table.astype(jnp.int32), start_pos.astype(jnp.int32), *inputs)
     out = out.reshape(S, K, Q, G, D).transpose(0, 2, 1, 3, 4)

@@ -67,10 +67,7 @@ def disable() -> None:
 
 
 def set_enabled(on: bool) -> None:
-    on = bool(on)
-    if on and not state.enabled:
-        state.generation += 1
-    state.enabled = on
+    state.enabled = bool(on)
 
 
 def apply_settings(enabled: "bool | None", metrics_port: int = 0,

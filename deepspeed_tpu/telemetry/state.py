@@ -15,16 +15,10 @@ import os
 
 
 class _TelemetryState:
-    __slots__ = ("enabled", "generation")
+    __slots__ = ("enabled",)
 
     def __init__(self, enabled: bool):
         self.enabled = enabled
-        #: bumped on every off->on transition (see
-        #: :func:`deepspeed_tpu.telemetry.set_enabled`) so SLO stamps
-        #: taken in an earlier enabled window can be recognized as
-        #: stale — an ITL reference from before a disabled gap must not
-        #: observe the whole gap as one giant inter-token latency
-        self.generation = 1
 
 
 state = _TelemetryState(

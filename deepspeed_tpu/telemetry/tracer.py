@@ -1,17 +1,28 @@
-"""Span tracer: bounded ring buffer of (name, start, dur, step, attrs)
-records, exportable as Chrome-trace JSON (Perfetto/chrome://tracing).
+"""Span tracer: bounded ring buffer of (name, start, dur, step, tid, attrs,
+id, parent, uid) records, exportable as Chrome-trace JSON
+(Perfetto/chrome://tracing).
 
 ``trace_span("fastgen.dispatch")`` is the only public entry point on hot
 paths.  Disabled (the default): one attribute read and a shared no-op
-context manager — no allocation, no clock read.  Enabled: a
-``jax.profiler.TraceAnnotation`` is entered under the same name, so when
-an XProf/Perfetto device profile is being captured the host spans line
-up with the device timeline (TraceAnnotation is a no-op outside an
-active profile — the gating lives in its C++ TraceMe).
+span — no allocation, no clock read.  Enabled: a
+``jax.profiler.TraceAnnotation`` is entered under the same name (the bare
+name: attributes stay in the ring), so when an XProf/Perfetto device
+profile is being captured the host spans line up with the device timeline
+(TraceAnnotation is a no-op outside an active profile — the gating lives
+in its C++ TraceMe).
+
+Every record carries a span id and the id of the span that was open on
+the same thread when it began (``None`` at a root), so a step's spans
+form a tree and a span's self time is its duration less the children
+that name it as parent.  ``SpanTracer.span`` opens a live span whatever
+the switch says (step-program formation: seconds each, tens a process);
+``SpanTracer.record`` writes a span after the fact from stamps taken
+elsewhere (the per-request spans, which share the request's ``uid``).
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -22,8 +33,15 @@ import jax
 
 from .state import state
 
-#: record = (name, start_s, dur_s, step, thread_id, attrs-or-None)
-Record = Tuple[str, float, float, int, int, Optional[Dict[str, Any]]]
+#: record = (name, start_s, dur_s, step, thread_id, attrs-or-None,
+#:           span id, parent span id or None, request uid or None)
+Record = Tuple[str, float, float, int, int, Optional[Dict[str, Any]],
+               int, Optional[int], Optional[int]]
+
+#: span ids are process-wide; ``next`` on a count is atomic under the GIL
+_IDS = itertools.count(1)
+#: per thread: the ids of the spans open right now, outermost first
+_OPEN = threading.local()
 
 #: thread-local replica/component label (ISSUE 19 satellite): pool
 #: stepper threads interleave anonymously in the one process-wide span
@@ -82,16 +100,30 @@ class SpanTracer:
             self._n = 0
 
     def record(self, name: str, start: float, dur: float,
-               attrs: Optional[Dict[str, Any]] = None) -> None:
+               attrs: Optional[Dict[str, Any]] = None, *,
+               parent: Optional[int] = None, uid: Optional[int] = None,
+               span_id: Optional[int] = None) -> int:
+        """Write one span (``start``/``dur`` in ``perf_counter``
+        seconds); returns its id.  Spans written after the fact name
+        their ``parent`` and the request's ``uid`` themselves."""
         comp = getattr(_COMPONENT, "value", "")
         if comp:
             # merged, not mutated: the caller's attrs dict may be shared
             attrs = {"component": comp, **(attrs or {})}
+        if span_id is None:
+            span_id = next(_IDS)
         rec = (name, start, dur, self.step,
-               threading.get_ident(), attrs)
+               threading.get_ident(), attrs, span_id, parent, uid)
         with self._lock:
             self._buf[self._n % self._cap] = rec
             self._n += 1
+        return span_id
+
+    def span(self, name: str,
+             attrs: Optional[Dict[str, Any]] = None) -> "_Span":
+        """A live span whether or not telemetry is on (``trace_span`` is
+        the switched entry point)."""
+        return _Span(name, attrs)
 
     def records(self) -> List[Record]:
         """Retained records, oldest first.  The critical section is
@@ -124,9 +156,11 @@ class SpanTracer:
             "dur": dur * 1e6,
             "pid": os.getpid(),
             "tid": tid,
-            "args": ({"step": step, **attrs} if attrs
-                     else {"step": step}),
-        } for name, start, dur, step, tid, attrs in self.records()]
+            "args": {"step": step, "id": sid, "parent": parent,
+                     **({"uid": uid} if uid is not None else {}),
+                     **(attrs or {})},
+        } for name, start, dur, step, tid, attrs, sid, parent, uid
+            in self.records()]
         events.sort(key=lambda e: e["ts"])
         return events
 
@@ -149,8 +183,10 @@ def get_tracer() -> SpanTracer:
 
 
 class _NullSpan:
-    """Shared disabled-path context manager: no state, no allocation."""
+    """Shared disabled-path span: no state, no allocation."""
     __slots__ = ()
+    #: False: counts that cost something to take are skipped
+    live = False
 
     def __enter__(self):
         return self
@@ -158,18 +194,37 @@ class _NullSpan:
     def __exit__(self, exc_type, exc, tb):
         return False
 
+    def set(self, key: str, value: Any) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "t0", "_ann")
+    __slots__ = ("name", "attrs", "t0", "id", "parent", "_ann", "_stack")
+    live = True
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]]):
         self.name = name
-        self.attrs = attrs
+        # copied: the dict handed in may be shared between calls
+        self.attrs = dict(attrs) if attrs else None
+
+    def set(self, key: str, value: Any) -> None:
+        """An attribute known only once the work is done (a count, the
+        path taken); lands on the record at exit."""
+        if self.attrs is None:
+            self.attrs = {}
+        self.attrs[key] = value
 
     def __enter__(self):
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        self._stack = stack
+        self.parent = stack[-1] if stack else None
+        self.id = next(_IDS)
+        stack.append(self.id)
         ann = jax.profiler.TraceAnnotation(self.name)
         ann.__enter__()
         self._ann = ann
@@ -179,7 +234,9 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self.t0
         self._ann.__exit__(exc_type, exc, tb)
-        _TRACER.record(self.name, self.t0, dur, self.attrs)
+        self._stack.pop()
+        _TRACER.record(self.name, self.t0, dur, self.attrs,
+                       parent=self.parent, span_id=self.id)
         return False
 
 
@@ -187,7 +244,9 @@ class _Span:
 def trace_span(name: str, attrs: Optional[Dict[str, Any]] = None):
     """Context manager recording a named host span when telemetry is
     enabled.  ``attrs`` (an optional plain dict — not kwargs, so the
-    disabled call allocates nothing) lands in the Chrome-trace ``args``.
+    disabled call allocates nothing) lands in the Chrome-trace ``args``;
+    ``with trace_span(...) as sp: ...; sp.set(key, value)`` adds what is
+    known only at the end (a no-op on the disabled path).
     """
     if not state.enabled:
         return _NULL_SPAN

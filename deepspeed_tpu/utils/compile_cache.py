@@ -36,6 +36,7 @@ re-compiled (``jax_raise_persistent_cache_errors`` stays False).
 from __future__ import annotations
 
 import os
+import threading
 from typing import Optional
 
 from .logging import logger
@@ -46,6 +47,10 @@ DEFAULT_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 _listener_installed = False
+#: the same events counted per thread: JAX calls its listeners on the
+#: thread that compiles, so one formation's hit or miss can be told from
+#: those of the programs forming beside it on other threads
+_thread_counts = threading.local()
 #: the active cache path (None = disabled)
 _active_dir: Optional[str] = None
 
@@ -63,8 +68,10 @@ def _install_listener() -> None:
     def _on_event(event: str, **kwargs) -> None:
         if event == "/jax/compilation_cache/cache_hits":
             tm.FASTGEN_COMPILE_CACHE_HIT.inc()
+            _thread_counts.hits = getattr(_thread_counts, "hits", 0) + 1
         elif event == "/jax/compilation_cache/cache_misses":
             tm.FASTGEN_COMPILE_CACHE_MISS.inc()
+            _thread_counts.misses = getattr(_thread_counts, "misses", 0) + 1
 
     monitoring.register_event_listener(_on_event)
     _listener_installed = True
@@ -132,6 +139,12 @@ def _reset_jax_cache() -> None:
 
 def active_cache_dir() -> Optional[str]:
     return _active_dir
+
+
+def thread_cache_counts() -> dict:
+    """Hit/miss counts of the calling thread's own compiles so far."""
+    return {"hits": getattr(_thread_counts, "hits", 0),
+            "misses": getattr(_thread_counts, "misses", 0)}
 
 
 def cache_counts() -> dict:

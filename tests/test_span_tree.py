@@ -1,0 +1,467 @@
+"""The span tree (ISSUE 24): span ids and parents in the tracer, one whole
+tree per scheduler step with its counts, three spans per request from one
+set of always-on stamps, and a record of every step program formed —
+written whether or not telemetry is on."""
+
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.telemetry import get_registry, get_tracer, trace_span
+from deepspeed_tpu.telemetry import metrics as tm
+from deepspeed_tpu.telemetry.tracer import (_NULL_SPAN, SpanTracer,
+                                            set_component)
+
+from test_telemetry import _slo_engine
+
+
+@pytest.fixture(autouse=True)
+def _telemetry_hygiene():
+    telemetry.disable()
+    get_tracer().clear()
+    # a pool test that ran earlier in this worker may have left its
+    # replica label on the thread: it would join every span's attrs
+    set_component("")
+    yield
+    telemetry.disable()
+    get_tracer().clear()
+    get_registry().reset()
+
+
+def by_id(records):
+    return {r[6]: r for r in records}
+
+
+def children_of(records, span_id):
+    return [r for r in records if r[7] == span_id]
+
+
+def self_s(records, rec):
+    return rec[2] - sum(c[2] for c in children_of(records, rec[6]))
+
+
+# ---------------------------------------------------------------------------
+# tracer
+# ---------------------------------------------------------------------------
+
+class TestSpanIds:
+    def test_ids_and_parents_nest_on_one_thread(self):
+        telemetry.enable()
+        with trace_span("a"):
+            with trace_span("b"):
+                with trace_span("c"):
+                    pass
+            with trace_span("d"):
+                pass
+        recs = {r[0]: r for r in get_tracer().records()}
+        assert recs["a"][7] is None
+        assert recs["b"][7] == recs["a"][6] == recs["d"][7]
+        assert recs["c"][7] == recs["b"][6]
+        assert len({r[6] for r in recs.values()}) == 4
+
+    def test_parents_do_not_cross_threads(self):
+        telemetry.enable()
+        ready, release = threading.Event(), threading.Event()
+
+        def other():
+            with trace_span("other.root"):
+                ready.set()
+                release.wait(5)
+                with trace_span("other.child"):
+                    pass
+
+        t = threading.Thread(target=other)
+        with trace_span("main.root"):
+            t.start()
+            ready.wait(5)
+            with trace_span("main.child"):
+                pass
+            release.set()
+            t.join()
+        recs = {r[0]: r for r in get_tracer().records()}
+        assert recs["other.root"][7] is None
+        assert recs["main.root"][7] is None
+        assert recs["other.child"][7] == recs["other.root"][6]
+        assert recs["main.child"][7] == recs["main.root"][6]
+        assert recs["other.root"][4] != recs["main.root"][4]
+
+    def test_set_lands_on_the_record_and_not_on_the_shared_dict(self):
+        telemetry.enable()
+        shared = {"k": 1}
+        with trace_span("x", shared) as sp:
+            sp.set("rows", 3)
+        rec = get_tracer().records()[-1]
+        assert rec[5] == {"k": 1, "rows": 3} and shared == {"k": 1}
+
+    def test_null_span_has_the_same_interface(self):
+        assert not telemetry.enabled()
+        sp = trace_span("ghost")
+        assert sp is _NULL_SPAN and sp.live is False
+        with sp as inner:
+            assert inner.set("rows", 3) is None
+        assert get_tracer().records() == []
+        telemetry.enable()
+        with trace_span("real") as live:
+            assert live.live is True
+            for name in ("set", "live", "__enter__", "__exit__"):
+                assert hasattr(sp, name) and hasattr(live, name)
+
+    def test_disabled_call_allocates_nothing(self):
+        """Counted, not timed: the interpreter's live block count does
+        not grow over a hundred thousand disabled spans."""
+        assert not telemetry.enabled()
+
+        def spin(n):
+            for _ in range(n):
+                with trace_span("hot") as sp:
+                    sp.set("k", 1)
+
+        spin(1000)                       # warm the code object's caches
+        before = sys.getallocatedblocks()
+        spin(100_000)
+        assert sys.getallocatedblocks() - before < 50
+
+    def test_record_after_the_fact_takes_parent_and_uid(self):
+        tr = SpanTracer(capacity=8)
+        root = tr.record("step", 1.0, 2.0)
+        kid = tr.record("request.decode", 1.5, 0.5, {"new_tokens": 4},
+                        parent=root, uid=17)
+        recs = by_id(tr.records())
+        assert recs[kid][7] == root and recs[kid][8] == 17
+        assert recs[root][7] is None and recs[root][8] is None
+        args = {e["name"]: e["args"] for e in tr.chrome_events()}
+        assert args["request.decode"]["parent"] == root
+        assert args["request.decode"]["uid"] == 17
+        assert args["request.decode"]["id"] == kid
+        assert "uid" not in args["step"]
+
+    def test_span_is_live_with_telemetry_off(self):
+        assert not telemetry.enabled()
+        with get_tracer().span("always", {"key": (1, 2)}) as sp:
+            sp.set("cache", "hit")
+        rec = get_tracer().records()[-1]
+        assert rec[0] == "always" and rec[5]["cache"] == "hit"
+
+
+# ---------------------------------------------------------------------------
+# the serving step's tree on the tiny model
+# ---------------------------------------------------------------------------
+
+STEP_CHILDREN = {"fastgen.drain", "fastgen.admission"}
+DISPATCH_CHILDREN = ["engine.admit", "engine.build_batch",
+                     "engine.dispatch", "engine.commit"]
+
+
+def serve(n_req=3, prompt=12, new=5, before_enable=0):
+    """A scheduler on the debug model; ``before_enable`` requests are
+    submitted and stepped once with telemetry off, then it is switched
+    on and the rest follow."""
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    sched = FastGenScheduler(_slo_engine())
+    rng = np.random.default_rng(0)
+
+    def submit(uid):
+        sched.submit(uid, rng.integers(0, 32, size=prompt).tolist(),
+                     SamplingParams(max_new_tokens=new, temperature=0.0))
+
+    for uid in range(before_enable):
+        submit(uid)
+    if before_enable:
+        sched.step()
+    telemetry.enable()
+    for uid in range(before_enable, n_req):
+        submit(uid)
+    out = sched.run_to_completion()
+    assert all(len(out[u]) == new for u in range(n_req))
+    return sched, [r for r in get_tracer().records()
+                   if not r[0].startswith("engine.program")]
+
+
+class TestStepTree:
+    def test_a_host_path_step_has_exactly_the_tree(self):
+        # two requests are decoding when two more arrive: the step
+        # drains the step in flight, admits, and dispatches one mixed
+        # program (one batch segment per kind of row)
+        _, recs = serve(n_req=4, prompt=20, new=4, before_enable=2)
+        steps = [r for r in recs if r[0] == "fastgen.step"]
+        fused = next(r for r in steps if r[5]["path"] == "fused")
+        kids = children_of(recs, fused[6])
+        names = [k[0] for k in kids]
+        assert set(names) == STEP_CHILDREN | {"fastgen.dispatch.fused"}
+        assert len(names) == 3
+        dispatch = next(k for k in kids if k[0] == "fastgen.dispatch.fused")
+        under = [k[0] for k in sorted(children_of(recs, dispatch[6]),
+                                      key=lambda r: r[1])]
+        assert under == ["engine.admit", "engine.build_batch",
+                         "engine.build_batch", "engine.dispatch",
+                         "engine.commit"]
+        drain = next(k for k in kids if k[0] == "fastgen.drain")
+        assert [k[0] for k in sorted(children_of(recs, drain[6]),
+                                     key=lambda r: r[1])] == [
+            "fastgen.drain.wait", "fastgen.drain.deliver"]
+        adm = next(k for k in kids if k[0] == "fastgen.admission")
+        assert {k[0] for k in children_of(recs, adm[6])} == {
+            "fastgen.prefix_match"}
+        # the call of the program is a leaf: nothing forms on a warm key
+        call = next(k for k in children_of(recs, dispatch[6])
+                    if k[0] == "engine.dispatch")
+        assert call[5] is None and children_of(recs, call[6]) == []
+
+    def test_a_chained_step_dispatches_then_drains(self):
+        _, recs = serve()
+        chain = next(r for r in recs if r[0] == "fastgen.step"
+                     and r[5]["path"] == "chain")
+        kids = sorted(children_of(recs, chain[6]), key=lambda r: r[1])
+        assert [k[0] for k in kids] == ["fastgen.dispatch.chain",
+                                        "fastgen.drain"]
+        assert [k[0] for k in sorted(children_of(recs, kids[0][6]),
+                                     key=lambda r: r[1])] \
+            == DISPATCH_CHILDREN
+        assert [k[0] for k in sorted(children_of(recs, kids[1][6]),
+                                     key=lambda r: r[1])] \
+            == ["fastgen.drain.wait", "fastgen.drain.deliver"]
+
+    def test_step_counts_add_up(self):
+        sched, recs = serve(n_req=4, prompt=20, new=4, before_enable=2)
+        steps = [r[5] for r in recs if r[0] == "fastgen.step"]
+        assert {s["path"] for s in steps} >= {"fused", "chain", "idle"}
+        for s in steps:
+            # a row decodes one token or carries a prompt piece
+            assert s["tokens"] == (s["rows"] - s["prefill_rows"]
+                                   + s["prefill_tokens"])
+            assert s["budget"] == 256 and s["tokens"] <= s["budget"]
+            assert s["kv_tokens_held"] <= s["kv_pages_reserved"] * 16
+        mixed = next(s for s in steps if s["prefill_rows"])
+        # two decoding rows from before, two 20-token prompts admitted
+        assert (mixed["rows"], mixed["prefill_rows"],
+                mixed["prefill_tokens"], mixed["tokens"]) == (4, 2, 40, 42)
+        assert mixed["kv_pages_reserved"] > 0
+        idle = next(s for s in steps if s["path"] == "idle")
+        assert idle["rows"] == idle["tokens"] == 0
+        # last_step_scheduled keeps its meaning: sequences, not tokens
+        assert sched.last_step_scheduled == 0
+
+    def test_self_times_sum_to_the_step(self):
+        _, recs = serve()
+        ids = by_id(recs)
+
+        def root_of(r):
+            while r[7] is not None and r[7] in ids:
+                r = ids[r[7]]
+            return r[6]
+
+        for step in (r for r in recs if r[0] == "fastgen.step"):
+            tree = [r for r in recs if root_of(r) == step[6]]
+            assert len(tree) > 1
+            total = sum(self_s(recs, r) for r in tree)
+            assert total == pytest.approx(step[2], rel=0.01)
+            # properly nested: a child lies inside its parent
+            for r in tree:
+                if r[7] is not None:
+                    p = ids[r[7]]
+                    assert p[1] <= r[1] and r[1] + r[2] <= p[1] + p[2]
+
+
+def test_every_attribute_on_the_serving_path_has_a_metric_that_reads_it():
+    """What a span of the serving step or of a request carries, some
+    metric file of the benchmark names (``attr:<key>`` or a ``where``):
+    nothing is recorded there for nobody."""
+    import glob
+    import json
+    import os
+    import re
+    _, recs = serve(n_req=4, prompt=20, new=4, before_enable=2)
+    carried = {key for r in recs if r[5] for key in r[5]}
+    assert carried == {"path", "rows", "prefill_rows", "prefill_tokens",
+                       "tokens", "budget", "kv_pages_reserved",
+                       "kv_tokens_held", "new_tokens"}
+    read = set()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for path in glob.glob(os.path.join(root, "benchmark", "metrics",
+                                       "*.json")):
+        with open(path) as f:
+            args = json.load(f).get("args", {})
+        for value in (args.get("value", ""), args.get("of_value", "")):
+            if value.startswith("attr:"):
+                read.add(value[5:])
+        read.update(re.split("!?=", w)[0] for w in args.get("where", []))
+    assert carried <= read
+
+
+def test_a_step_with_telemetry_off_takes_no_counts():
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    from deepspeed_tpu.inference.v2.scheduler import _IDLE_STEP
+    assert not telemetry.enabled()
+    sched = FastGenScheduler(_slo_engine())
+    for uid in range(2):
+        sched.submit(uid, list(range(12)),
+                     SamplingParams(max_new_tokens=3, temperature=0.0))
+    while sched.has_work:
+        sched.step()
+        assert sched._step_shape is _IDLE_STEP
+    assert [r for r in get_tracer().records()
+            if not r[0].startswith("engine.program")] == []
+
+
+class TestRequestSpans:
+    def test_three_spans_tile_a_request_submitted_before_enable(self):
+        for h in (tm.FASTGEN_TTFT_MS, tm.FASTGEN_ITL_MS,
+                  tm.FASTGEN_QUEUE_WAIT_MS):
+            h.reset()
+        from deepspeed_tpu.inference.v2 import (FastGenScheduler,
+                                                SamplingParams)
+        sched = FastGenScheduler(_slo_engine())
+        t0 = time.perf_counter()
+        sched.submit(7, list(range(12)),
+                     SamplingParams(max_new_tokens=4, temperature=0.0))
+        t1 = time.perf_counter()
+        assert get_tracer().records() == []      # nothing while off
+        telemetry.enable()
+        sched.run_to_completion()
+        t2 = time.perf_counter()
+        spans = {r[0]: r for r in get_tracer().records()
+                 if r[0].startswith("request.")}
+        assert set(spans) == {"request.queue_wait", "request.prefill",
+                              "request.decode"}
+        q, p, d = (spans["request." + n]
+                   for n in ("queue_wait", "prefill", "decode"))
+        assert all(r[8] == 7 and r[7] is None for r in (q, p, d))
+        # they start at the submit (taken with telemetry off) and tile
+        # the request's life without a gap
+        assert t0 <= q[1] <= t1
+        assert q[1] + q[2] == pytest.approx(p[1], abs=1e-9)
+        assert p[1] + p[2] == pytest.approx(d[1], abs=1e-9)
+        assert d[1] + d[2] <= t2
+        assert q[5] is None and p[5] is None
+        assert d[5] == {"new_tokens": 4}
+        # the histograms read the same stamps
+        assert tm.FASTGEN_QUEUE_WAIT_MS.count == 1
+        assert tm.FASTGEN_TTFT_MS.count == 1
+        assert tm.FASTGEN_ITL_MS.count == 3
+
+    def test_request_keeps_one_set_of_stamps(self):
+        from deepspeed_tpu.inference.v2.scheduler import Request
+        fields = set(Request.__dataclass_fields__)
+        assert {"submit_s", "admit_s", "first_token_s", "token_s",
+                "submit_mono"} <= fields
+        assert not fields & {"first_sched_s", "last_token_s", "slo_gen",
+                             "first_sched_mono", "first_token_mono",
+                             "last_token_mono"}
+
+    def test_stamps_are_taken_with_telemetry_off(self):
+        from deepspeed_tpu.inference.v2 import (FastGenScheduler,
+                                                SamplingParams)
+        sched = FastGenScheduler(_slo_engine())
+        sched.submit(1, list(range(12)),
+                     SamplingParams(max_new_tokens=3, temperature=0.0))
+        req = sched._pending[0]
+        sched.run_to_completion()
+        assert 0 < req.submit_s <= req.admit_s <= req.first_token_s \
+            <= req.token_s
+        assert [r for r in get_tracer().records()
+                if not r[0].startswith("engine.program")] == []
+
+    def test_a_failed_request_closes_the_span_that_was_open(self):
+        from deepspeed_tpu.inference.v2 import (FastGenScheduler,
+                                                SamplingParams)
+        telemetry.enable()
+        sched = FastGenScheduler(_slo_engine())
+        sched.submit(3, list(range(12)),
+                     SamplingParams(max_new_tokens=3, temperature=0.0))
+        sched._fail_request(sched._pending[0], "expired", "test")
+        spans = [r for r in get_tracer().records()
+                 if r[0].startswith("request.")]
+        assert [r[0] for r in spans] == ["request.queue_wait"]
+        assert spans[0][8] == 3
+
+
+# ---------------------------------------------------------------------------
+# step-program formation
+# ---------------------------------------------------------------------------
+
+PROGRAM_CHILDREN = ["engine.program.trace", "engine.program.lower",
+                    "engine.program.compile", "engine.program.cost"]
+
+
+class TestProgramFormation:
+    def test_a_new_key_is_recorded_with_telemetry_off(self):
+        from deepspeed_tpu.inference.v2 import (FastGenScheduler,
+                                                SamplingParams)
+        assert not telemetry.enabled()
+        eng = _slo_engine()
+        sched = FastGenScheduler(eng)
+        sched.submit(0, list(range(12)),
+                     SamplingParams(max_new_tokens=2, temperature=0.0))
+        sched.step()
+        recs = get_tracer().records()
+        progs = [r for r in recs if r[0] == "engine.program"]
+        assert len(progs) == 1
+        prog = progs[0]
+        assert prog[5]["on_path"] is True
+        assert prog[5]["key"] in eng.model._step_cache
+        assert prog[5]["cache"] in ("hit", "miss", "off")
+        kids = sorted(children_of(recs, prog[6]), key=lambda r: r[1])
+        assert [k[0] for k in kids] == PROGRAM_CHILDREN + [
+            "engine.program.first_run"]
+        assert sum(k[2] for k in kids) <= prog[2]
+        assert kids[2][5]["cache"] == prog[5]["cache"]
+        # a second dispatch of the key forms nothing
+        n = len(get_tracer().records())
+        key = prog[5]["key"]
+        eng.model._get_step(key)
+        assert len(get_tracer().records()) == n
+
+    def test_precompile_records_the_same_tree_off_the_path(self):
+        eng = _slo_engine()
+        key = (4, 1, 8, False, "sample", True)
+        assert eng.precompile_keys([key]) == 1
+        recs = get_tracer().records()
+        prog = next(r for r in recs if r[0] == "engine.program")
+        assert prog[5]["on_path"] is False and prog[5]["key"] == key
+        assert [k[0] for k in sorted(children_of(recs, prog[6]),
+                                     key=lambda r: r[1])] \
+            == PROGRAM_CHILDREN
+        # already compiled: nothing forms again
+        assert eng.precompile_keys([key]) == 1
+        assert sum(r[0] == "engine.program"
+                   for r in get_tracer().records()) == 1
+
+    def test_an_on_path_formation_nests_under_the_dispatch(self):
+        from deepspeed_tpu.inference.v2 import (FastGenScheduler,
+                                                SamplingParams)
+        telemetry.enable()
+        sched = FastGenScheduler(_slo_engine())
+        sched.submit(0, list(range(12)),
+                     SamplingParams(max_new_tokens=2, temperature=0.0))
+        sched.step()
+        recs = get_tracer().records()
+        prog = next(r for r in recs if r[0] == "engine.program")
+        assert by_id(recs)[prog[7]][0] == "engine.dispatch"
+
+
+def test_paged_attention_kernel_is_named_by_kind_of_row():
+    """The Pallas call is named from the static query width, so a trace
+    splits the kernel's time without arithmetic; ``^paged_attention``
+    still matches both."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.paged_attention import paged_attention
+
+    def text(q_rows):
+        q = jnp.zeros((2, q_rows, 4, 16), jnp.float32)
+        kv = jnp.zeros((9, 2, 2, 16, 16), jnp.float32)
+        table = jnp.zeros((2, 4), jnp.int32)
+        pos = jnp.zeros((2,), jnp.int32)
+        return str(jax.make_jaxpr(
+            lambda *a: paged_attention(*a, use_kernel=True,
+                                       interpret=True))(
+            q, kv, table, pos, pos + q_rows))
+
+    assert "paged_attention_decode" in text(1)
+    assert "paged_attention_prefill" in text(8)
+    assert "paged_attention_prefill" not in text(1)

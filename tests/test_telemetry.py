@@ -528,7 +528,10 @@ class TestSchedulerTelemetry:
         sched.run_to_completion()
         assert tm.FASTGEN_TTFT_MS.count == 0
         assert tm.FASTGEN_STEP_MS.count == 0
-        assert get_tracer().records() == []
+        # the only records a disabled scheduler leaves are the step
+        # programs it formed (ISSUE 24: written whatever the switch says)
+        assert all(r[0].startswith("engine.program")
+                   for r in get_tracer().records())
 
     def test_train_batch_spans_and_monitor_snapshot(self, tmp_path):
         """Training side of the spine: train.* spans nest, the step-time
