@@ -100,15 +100,18 @@ def run_kernel_parity(seed: int, heads: int = 32, kv_heads: int = 8,
     seeded input, each against the repo's own plain reference computed on
     the same device: flash forward and gradients vs ``mha_reference``
     (full causal and banded), the paged kernel vs the dense-gather path
-    (decode and chunk rows, bf16 and int8 pages).  Returns the largest
-    error of each comparison relative to the reference's largest value."""
+    and the cache-write kernel vs the XLA scatter (decode and chunk rows,
+    bf16 and int8 pages; layer 1 of a two-layer pool).  Returns the
+    largest error of each comparison relative to the reference's largest
+    value."""
     import jax
     import jax.numpy as jnp
 
     from deepspeed_tpu.ops.flash_attention import (flash_attention,
                                                    mha_reference)
     from deepspeed_tpu.ops.paged_attention import (KVPages, paged_attention,
-                                                   quantize_kv_blocks)
+                                                   quantize_kv_blocks,
+                                                   write_kv)
 
     def rel_err(got, want):
         got, want = (jnp.asarray(x, jnp.float32) for x in (got, want))
@@ -138,8 +141,8 @@ def run_kernel_parity(seed: int, heads: int = 32, kv_heads: int = 8,
             errors[f"flash_{name}_d{n}"] = rel_err(g, r)
 
     slots, pages_per_seq = 4, seq // page
-    kv = jax.random.normal(keys[4], (slots * pages_per_seq + 1, 2, kv_heads,
-                                     page, head_dim), jnp.bfloat16)
+    kv = jax.random.normal(keys[4], (2, slots * pages_per_seq + 1, 2,
+                                     kv_heads, page, head_dim), jnp.bfloat16)
     codes, scale = quantize_kv_blocks(kv)
     table = (1 + jnp.arange(slots * pages_per_seq, dtype=jnp.int32)
              ).reshape(slots, pages_per_seq)
@@ -148,13 +151,25 @@ def run_kernel_parity(seed: int, heads: int = 32, kv_heads: int = 8,
                                jnp.bfloat16)
         start = jnp.asarray([seq - rows, seq // 2, page + 3, 0], jnp.int32)
         lens = jnp.full((slots,), rows, jnp.int32)
-        for fmt, layer in (("bf16", kv), ("int8", KVPages(codes, scale))):
+        new = jax.random.normal(keys[6], (2, slots, rows, kv_heads, head_dim),
+                                jnp.bfloat16)
+        for fmt, pool in (("bf16", kv), ("int8", KVPages(codes, scale))):
             for name, window in (("", None), ("_window", seq // 4)):
-                got, want = (jax.jit(lambda q, l, use=use: paged_attention(
-                    q, l, table, start, lens, use_kernel=use, window=window,
-                    interpret=interpret and use))(qp, layer)
+                got, want = (jax.jit(lambda q, p, use=use: paged_attention(
+                    q, p, 1, table, start, lens, use_kernel=use,
+                    window=window, interpret=interpret and use))(qp, pool)
                     for use in (True, False))
                 errors[f"paged_q{rows}_{fmt}{name}"] = rel_err(got, want)
+            # a chunk's last token is padding: it must land nowhere real
+            new_lens = lens - 1 if rows > 1 else lens
+            got, want = (jax.jit(lambda p, k, v, use=use: write_kv(
+                p, 1, k, v, table, start, new_lens, use_kernel=use,
+                interpret=interpret and use))(pool, *new)
+                for use in (True, False))
+            # page 0 is the null page: the scatter parks padding there
+            errors[f"kv_write_q{rows}_{fmt}"] = max(
+                rel_err(g[:, 1:], w[:, 1:]) for g, w in zip(
+                    jax.tree.leaves(got), jax.tree.leaves(want)))
     worst = max(errors, key=errors.get)
     if not errors[worst] <= tol:
         raise RuntimeError(f"kernel {worst} is {errors[worst]:.4f} off its "

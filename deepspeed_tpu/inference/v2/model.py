@@ -6,7 +6,12 @@ mistral, mixtral, …).  There, a from-scratch module layer re-implements
 every op class against CUDA kernels.  Here the *training* transformer
 core (models/transformer.py) is reused: the same params, norms and
 projections, with attention swapped for the paged ragged formulation
-(ops/paged_attention.py) and the layer scan threading KV pages through.
+(ops/paged_attention.py).  The layer loop CARRIES the whole KV pool
+beside the activations and scans only the weights and a layer counter:
+every layer writes and reads ``pool[layer]`` in place, so inside a step
+program the pool never leaves its donated buffer — no per-layer slice
+is taken out, nothing is stacked back, no op changes its layout
+(``tests/test_chip_compile.py`` holds the compiled programs to that).
 
 Every distinct batch bucket shape ``(S, Q, P)`` compiles exactly once;
 the KV cache is donated so decoding is allocation-free on device.
@@ -24,8 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...models import transformer as T
-from ...ops.paged_attention import (KVPages, gather_last, paged_attention,
-                                    rope_write_kv, token_positions,
+from ...ops.paged_attention import (KVPages, gather_last, token_positions,
                                     write_kv)
 from ...telemetry import get_tracer
 from ...telemetry import metrics as tm
@@ -52,6 +56,12 @@ def serving_peak_flops() -> Optional[float]:
 def utilization(flops_per_s: float, peak: Optional[float]) -> float:
     """``flops_per_s / peak``; 0.0 where the device has no peak."""
     return flops_per_s / peak if peak else 0.0
+
+
+def _write_new_kv(k, v, kv, layer, page_table, start_pos, q_lens):
+    """``write_kv`` with its head-split arguments first, the order
+    ``RaggedInferenceModel._per_shard_heads`` builds its specs from."""
+    return write_kv(kv, layer, k, v, page_table, start_pos, q_lens)
 
 
 def _rebox_from_cfg(cfg: T.TransformerConfig, params):
@@ -934,18 +944,18 @@ class RaggedInferenceModel:
         body = functools.partial(self._layer_body, pos=pos, sin=sin, cos=cos,
                                  q_lens=q_lens, start_pos=start_pos,
                                  page_table=page_table, fresh=fresh, cfg=cfg)
+        # the pool (plain array or KVPages pair) is the loop's CARRY:
+        # as scanned xs/ys it would be sliced out and stacked back, two
+        # layer-sized copies a layer and a pool-sized one after the loop
+        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         if cfg.scan_layers:
-            x, kv = jax.lax.scan(
-                lambda carry, xs: (body(carry, xs[0], xs[1])),
-                x, (params["layers"], kv))
+            (x, kv), _ = jax.lax.scan(
+                lambda carry, xs: (body(*carry, *xs), None),
+                (x, kv), (params["layers"], layers))
         else:
-            kv_layers = []
             for i in range(cfg.num_layers):
-                x, kv_i = body(x, params["layers"][f"layer_{i}"], kv[i])
-                kv_layers.append(kv_i)
-            # tree-aware stack: kv may be a KVPages (payload, scale)
-            # pytree (ISSUE 16 quantized pages) as well as a plain array
-            kv = jax.tree.map(lambda *xs: jnp.stack(xs), *kv_layers)
+                x, kv = body(x, kv, params["layers"][f"layer_{i}"],
+                             layers[i])
 
         return self._norm(params["final_norm"], x), kv
 
@@ -1171,8 +1181,11 @@ class RaggedInferenceModel:
                 [tokens, jnp.zeros((pad,), jnp.int32)])
         return tokens, kv
 
-    def _layer_body(self, x, lp, kv_layer, *, pos, sin, cos, q_lens,
+    def _layer_body(self, x, kv, lp, layer, *, pos, sin, cos, q_lens,
                     start_pos, page_table, fresh: bool = False, cfg=None):
+        """One transformer layer over ``x`` and the whole pool ``kv``,
+        of which it writes and reads layer ``layer`` (an int32 scalar)
+        in place.  Returns (x, kv)."""
         cfg = cfg if cfg is not None else self.cfg
         dtype = cfg.dtype
         h = self._norm(lp["norm1"], x)
@@ -1184,23 +1197,11 @@ class RaggedInferenceModel:
             q = q + ap["bq"].astype(dtype)
             k = k + ap["bk"].astype(dtype)
             v = v + ap["bv"].astype(dtype)
-        k_rot = None
         if cfg.pos_emb == "rope":
             q = T.apply_rope(q, sin, cos)
-            if fresh and self._fresh_attention is not None:
-                # fresh path reads the rotated K directly: rotate once,
-                # write unfused (the fused rope_write_kv would force a
-                # second rotate for the flash read)
-                k_rot = T.apply_rope(k, sin, cos)
-                kv_layer = write_kv(kv_layer, k_rot, v, page_table,
-                                    start_pos, q_lens)
-            else:
-                kv_layer = rope_write_kv(kv_layer, k, v, sin, cos,
-                                         page_table, start_pos, q_lens)
-        else:
-            k_rot = k
-            kv_layer = write_kv(kv_layer, k, v, page_table, start_pos,
-                                q_lens)
+            k = T.apply_rope(k, sin, cos)
+        kv = self._per_shard_heads(_write_new_kv, cfg, 2, pool_out=True)(
+            k, v, kv, layer, page_table, start_pos, q_lens)
         if fresh and self._fresh_attention is not None:
             # pure prefill: every slot's context IS its own new tokens —
             # flash over [S(batch), H, Q, D], no paged gather at all
@@ -1208,11 +1209,10 @@ class RaggedInferenceModel:
             # are garbage but only feed rows that logits_gather ignores
             # and KV slots the null page swallows
             attn = self._per_shard_heads(
-                self._fresh_attention, cfg, 3)(
-                q, k_rot if k_rot is not None else k, v)
+                self._fresh_attention, cfg, 3)(q, k, v)
         else:
             attn = self._per_shard_heads(self._attention, cfg, 1)(
-                q, kv_layer, page_table, start_pos, q_lens)
+                q, kv, layer, page_table, start_pos, q_lens)
         out = jnp.einsum("sqhd,hde->sqe", attn, T._wval(ap["wo"], dtype))
         if cfg.use_bias:
             out = out + ap["bo"].astype(dtype)
@@ -1221,52 +1221,58 @@ class RaggedInferenceModel:
             mlp_out = (self.mlp_fn or T._mlp_block)(cfg, lp["mlp"], h2)
             if isinstance(mlp_out, tuple):                  # MoE aux dropped
                 mlp_out = mlp_out[0]
-            return x + out.astype(x.dtype) + mlp_out.astype(x.dtype), kv_layer
+            return x + out.astype(x.dtype) + mlp_out.astype(x.dtype), kv
         x = x + out.astype(x.dtype)
         h = self._norm(lp["norm2"], x)
         mlp_out = (self.mlp_fn or T._mlp_block)(cfg, lp["mlp"], h)
         if isinstance(mlp_out, tuple):                      # MoE aux dropped
             mlp_out = mlp_out[0]
-        return x + mlp_out.astype(x.dtype), kv_layer
+        return x + mlp_out.astype(x.dtype), kv
 
-    def _per_shard_heads(self, attn_fn, cfg, n_head_args: int):
-        """Run an attention module per tp shard over its own head slice.
+    def _per_shard_heads(self, fn, cfg, n_head_args: int,
+                         pool_out: bool = False):
+        """Run a cache op or an attention module per tp shard over its
+        own head slice.
 
-        Attention is independent per KV head, and both its implementations
-        are Pallas custom calls on a TPU — which GSPMD cannot partition
-        (it would all-gather the KV pages onto every chip, every layer).
-        Under a tp mesh the module is therefore ``shard_map``-ped over the
-        axis: the first ``n_head_args`` arguments are ``[S, Q, heads, D]``
-        activations split on heads, a paged KV layer (payload and int8
-        scale alike) is split on its KV-head dim, and the host-built int32
-        batch vectors are replicated.  Head h = k * G + g, so contiguous
-        head shards line up with contiguous KV-head shards.  ALiBi slopes
-        are a closed-over per-head constant, so those models (and head
-        counts the axis does not divide) stay on the GSPMD path."""
+        Attention and the cache write are independent per KV head, and on
+        a TPU they are Pallas custom calls — which GSPMD cannot partition
+        (it would all-gather the KV pages onto every chip, every layer;
+        the write's scatter indexes the head dim, which GSPMD may answer
+        the same way).  Under a tp mesh ``fn`` is therefore
+        ``shard_map``-ped over the axis: its first ``n_head_args``
+        arguments are ``[S, Q, heads, D]`` activations split on heads, the
+        KV pool (payload and int8 scale alike) is split on its KV-head
+        dim, and the layer index and the host-built int32 batch vectors
+        are replicated.  The result is head-split activations, or with
+        ``pool_out`` the pool.  Head h = k * G + g, so contiguous head
+        shards line up with contiguous KV-head shards.  ALiBi slopes are a
+        per-head constant the attention modules close over, so attention
+        of those models (and everything of head counts the axis does not
+        divide) stays on the GSPMD path."""
         axis = self._tp_axis
         if self.mesh is None or axis is None:
-            return attn_fn
+            return fn
         tp = self.mesh.shape[axis]
-        if (tp == 1 or cfg.pos_emb == "alibi" or cfg.kv_heads % tp
-                or cfg.num_heads % tp):
-            return attn_fn
+        if tp == 1 or cfg.kv_heads % tp or cfg.num_heads % tp:
+            return fn
+        if cfg.pos_emb == "alibi" and not pool_out:
+            return fn
         from ...utils.jax_compat import shard_map
         heads = P(None, None, axis, None)
+        pool = P(None, None, None, axis, None, None)    # [L,P+1,2,K,page,D]
 
         def spec_of(i, arg):
             if i < n_head_args:
                 return heads
-            if isinstance(arg, KVPages):     # [P+1, 2, K, page(, D)]
-                return KVPages(P(None, None, axis, None, None),
-                               P(None, None, axis, None))
-            if arg.ndim == 5:
-                return P(None, None, axis, None, None)
-            return P()
+            if isinstance(arg, KVPages):
+                return KVPages(pool, P(*pool[:-1]))
+            return pool if arg.ndim == 6 else P()
 
         def run(*args):
             specs = tuple(spec_of(i, a) for i, a in enumerate(args))
-            return shard_map(attn_fn, mesh=self.mesh, in_specs=specs,
-                             out_specs=heads, check_vma=False)(*args)
+            out = specs[n_head_args] if pool_out else heads
+            return shard_map(fn, mesh=self.mesh, in_specs=specs,
+                             out_specs=out, check_vma=False)(*args)
         return run
 
     # -- KV requirements (engine contract) ----------------------------------

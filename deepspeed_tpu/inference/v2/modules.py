@@ -103,8 +103,8 @@ def _pallas_decode(cfg):
     slopes = _alibi_for(cfg)
     window = getattr(cfg, "sliding_window", None)
 
-    def attn(q, kv_layer, page_table, start_pos, q_lens):
-        return paged_attention(q, kv_layer, page_table, start_pos, q_lens,
+    def attn(q, kv, layer, page_table, start_pos, q_lens):
+        return paged_attention(q, kv, layer, page_table, start_pos, q_lens,
                                use_kernel=None, alibi_slopes=slopes,
                                window=window)
     return attn
@@ -117,8 +117,8 @@ def _dense_gather(cfg):
     slopes = _alibi_for(cfg)
     window = getattr(cfg, "sliding_window", None)
 
-    def attn(q, kv_layer, page_table, start_pos, q_lens):
-        return paged_attention(q, kv_layer, page_table, start_pos, q_lens,
+    def attn(q, kv, layer, page_table, start_pos, q_lens):
+        return paged_attention(q, kv, layer, page_table, start_pos, q_lens,
                                use_kernel=False, alibi_slopes=slopes,
                                window=window)
     return attn

@@ -6,9 +6,13 @@ TPU-native layout: ONE stacked array per cache group
 
     kv : [num_layers, num_pages + 1, 2, kv_heads, page_size, head_dim]
 
-so the per-layer slice falls out of the layer ``lax.scan`` naturally and
-the whole cache is a single donated buffer across forwards (XLA updates
-it in place; no allocator traffic on device).  Page 0 is the null page
+and the whole cache is a single donated buffer across forwards.  Inside
+a step program it is the layer loop's carry, never a per-layer slice:
+the cache write and the attention kernels take the pool and a layer
+index and address ``pool[layer, page]`` themselves, so XLA updates the
+buffer in place and nothing pool-sized is copied (a slice scanned out
+and stacked back was 62 % of the serve step's device time, PERF.md PR
+25; no allocator traffic on device either).  Page 0 is the null page
 (see blocked_allocator.py) — real pages are 1..num_pages.  The last two
 dims are one head's ``[page_size, head_dim]`` tile of one page: the
 block the Pallas paged-attention kernel DMAs per grid step (the TPU

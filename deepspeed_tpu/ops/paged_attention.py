@@ -14,6 +14,15 @@ to thread blocks; on TPU the ragged batch is instead padded to a static
                         dense einsum -> MXU, raggedness lives in masks.
 * ``gather_last``     — last-token hidden-state gather for logits.
 
+Both cache ops take the WHOLE pool ``[L, P+1, 2, K, page, D]`` and a
+layer index, never one layer's slice: inside a step program the pool
+stays in its donated buffer (it is the layer loop's carry), the write
+addresses ``(layer, page, k/v, head, slot)`` directly and the kernels
+read ``pool[layer]`` through their BlockSpec index maps.  A per-layer
+slice taken out and stacked back costs two layer-sized copies a layer,
+and a scatter with window dims between its indexed dims a re-layout of
+the layer each way (PERF.md, PR 25).
+
 ``paged_decode_attention`` is the Pallas ragged kernel: a
 ``(slot, kv_head, page)`` grid whose BlockSpec index map reads the page
 table via scalar prefetch, so each KV page is DMA'd HBM->VMEM exactly
@@ -64,10 +73,9 @@ class KVPages:
     write exactly like the fp path (the prefix-sharing contract).
 
     Registered as a pytree so it rides every existing seam unchanged:
-    ``lax.scan`` slices both leaves along the layer axis, ``jit``
-    donation donates both, and the engine's opaque ``kv_cache.data``
-    threading never looks inside.  ``__getitem__`` mirrors the
-    per-layer indexing of the non-scan model path."""
+    the layer loop carries both leaves, ``jit`` donation donates both,
+    and the engine's opaque ``kv_cache.data`` threading never looks
+    inside."""
 
     __slots__ = ("payload", "scale")
 
@@ -81,9 +89,6 @@ class KVPages:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
-
-    def __getitem__(self, idx):
-        return KVPages(self.payload[idx], self.scale[idx])
 
     @property
     def shape(self):
@@ -121,46 +126,204 @@ def token_positions(start_pos: jax.Array, q_len_max: int) -> jax.Array:
     return start_pos[:, None] + jnp.arange(q_len_max, dtype=jnp.int32)[None, :]
 
 
-def write_kv(kv_layer: jax.Array, k_new: jax.Array, v_new: jax.Array,
+def write_kv(kv: jax.Array, layer, k_new: jax.Array, v_new: jax.Array,
              page_table: jax.Array, start_pos: jax.Array,
-             q_lens: jax.Array) -> jax.Array:
-    """Scatter new KV into the cache pages of one layer.
+             q_lens: jax.Array, *, use_kernel: Optional[bool] = None,
+             interpret: bool = False) -> jax.Array:
+    """Write new KV into the cache pages of layer ``layer`` of the pool.
 
-    kv_layer : [num_pages+1, 2, K, page_size, D] (or :class:`KVPages`)
-               — per (page, k/v, head) one ``[page_size, D]`` tile, the
-               block the Pallas kernel DMAs (the TPU lowering needs the
-               last two block dims to be tile-aligned array dims)
+    kv    : [L, num_pages+1, 2, K, page_size, D] (or :class:`KVPages`)
+            — per (layer, page, k/v, head) one ``[page_size, D]`` tile,
+            the block the Pallas kernels DMA (the TPU lowering needs
+            the last two block dims to be tile-aligned array dims)
+    layer : int32 scalar (the layer loop's counter, or a constant)
     k_new/v_new : [S, Q, K, D]
-    Returns the updated kv_layer (functional; donate at jit boundary).
-    A quantized layer quantizes at append: codes and scales scatter at
-    the same (page, slot), so a row is always self-consistent.
+    Returns the updated pool (functional; the pool is donated at the
+    jit boundary and carried through the layer loop, and both forms
+    below update it in place).  A quantized pool quantizes at append:
+    codes and scales land at the same (layer, page, head, slot), so a
+    row is always self-consistent.
+
+    ``use_kernel`` None = auto (on TPU, or anywhere with
+    ``interpret=True``): the aliased tile kernel :func:`kv_write_pages`.
+    Otherwise — the CPU path and the semantics ground truth — one XLA
+    scatter that indexes EVERY leading dim ``(layer, page, k/v, head,
+    slot)`` and leaves the ``D`` row as its only window: no window dim
+    sits between indexed dims, so the compiler needs no other layout
+    for the pool than the one it has.  The chip runs that scatter in
+    place too, but row by row: 0.078 ms a layer for a 64-row decode
+    step and 0.585 ms for a 4 x 128 prefill piece, against the kernel's
+    0.061 and 0.016 (0.33 / 0.82 against 0.053 / 0.019 with int8 pages;
+    v5e, Mistral-7B head geometry, PERF.md PR 25).
     """
-    S, Q = k_new.shape[:2]
-    quantized = isinstance(kv_layer, KVPages)
-    page_size = (kv_layer.payload if quantized else kv_layer).shape[3]
+    if use_kernel is None:
+        use_kernel = interpret or on_tpu()
+    if use_kernel:
+        return kv_write_pages(kv, layer, k_new, v_new, page_table,
+                              start_pos, q_lens, interpret=interpret)
+    S, Q, K, D = k_new.shape
+    quantized = isinstance(kv, KVPages)
+    page_size = (kv.payload if quantized else kv).shape[4]
     pos = token_positions(start_pos, Q)                     # [S, Q]
     valid = jnp.arange(Q, dtype=jnp.int32)[None, :] < q_lens[:, None]
-    page_idx_in_seq = pos // page_size
-    slot = pos % page_size
-    pages = jnp.take_along_axis(page_table, page_idx_in_seq, axis=1)
+    pages = jnp.take_along_axis(page_table, pos // page_size, axis=1)
     pages = jnp.where(valid, pages, 0)                      # null page
-    pages_f = pages.reshape(-1)
-    slot_f = slot.reshape(-1)
-    kv_new = jnp.stack([k_new, v_new], axis=2)              # [S,Q,2,K,D]
+    kv_new = jnp.stack([k_new, v_new], axis=2).reshape(S * Q, 2, K, D)
+    # index arrays broadcast to the update's leading [S*Q, 2, K]
+    idx = (jnp.asarray(layer, jnp.int32), pages.reshape(-1, 1, 1),
+           jnp.arange(2, dtype=jnp.int32)[None, :, None],
+           jnp.arange(K, dtype=jnp.int32)[None, None, :],
+           (pos % page_size).reshape(-1, 1, 1))
     if quantized:
         codes, scales = quantize_kv_blocks(kv_new)
-        # split advanced indices (page, token slot) put the token dim
-        # first: the update is [S*Q, 2, K(, D)]
-        return KVPages(
-            kv_layer.payload.at[pages_f, :, :, slot_f].set(
-                codes.reshape((S * Q,) + codes.shape[2:]), mode="drop"),
-            kv_layer.scale.at[pages_f, :, :, slot_f].set(
-                scales.reshape((S * Q,) + scales.shape[2:]), mode="drop"))
-    kv_f = kv_new.reshape((S * Q,) + kv_new.shape[2:]).astype(kv_layer.dtype)
-    return kv_layer.at[pages_f, :, :, slot_f].set(kv_f, mode="drop")
+        return KVPages(kv.payload.at[idx].set(codes, mode="drop"),
+                       kv.scale.at[idx].set(scales, mode="drop"))
+    return kv.at[idx].set(kv_new.astype(kv.dtype), mode="drop")
 
 
-def paged_attention(q: jax.Array, kv_layer: jax.Array,
+def _kv_write_kernel(l_ref, pid_ref, off_ref, ql_ref, *refs, has_scale):
+    """One (row, touched page) grid step of the in-place cache write:
+    read the page's ``[2, K, page, D]`` tiles, replace the slots this
+    row's new tokens own, write the tiles back to the same address (the
+    pool is aliased input -> output, so nothing else of it moves).
+
+    Slot ``t`` of the page holds token ``i = off + t`` of the row
+    (``off`` < 0 where the row starts mid-page); it is replaced iff
+    ``0 <= i < q_lens``.  Mosaic has no gather and no unaligned
+    dynamic sublane slice, so the new rows are shifted into place by a
+    one-hot ``[page, Q]`` matmul — exact: every output row sums ONE
+    product by 1.0 (bf16 and int8 codes in bf16, anything else in fp32
+    at HIGHEST precision).  Q = 1 needs no shift, only a broadcast.
+    """
+    if has_scale:
+        new_ref, nscale_ref, tile_ref, stile_ref, out_ref, sout_ref = refs
+    else:
+        new_ref, tile_ref, out_ref = refs
+    s, j = pl.program_id(0), pl.program_id(1)
+    off, q_lens = off_ref[s, j], ql_ref[s]
+    _, K, page, _ = tile_ref.shape
+    q_pad = new_ref.shape[2]                # 1: a decode row, no shift
+    exact = new_ref.dtype in (jnp.bfloat16, jnp.int8)
+    mm_dtype = jnp.bfloat16 if exact else jnp.float32
+    precision = None if exact else jax.lax.Precision.HIGHEST
+
+    # payload: tokens along sublanes
+    tok = off + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
+    own = (tok >= 0) & (tok < q_lens)                      # [page, 1]
+    if q_pad > 1:
+        pick = (jax.lax.broadcasted_iota(jnp.int32, (page, q_pad), 1)
+                == tok).astype(mm_dtype)                   # [page, Q]
+    for kv in range(2):
+        for k in range(K):
+            if q_pad == 1:
+                new = new_ref[kv, k]                       # [1, D]
+            else:
+                new = jax.lax.dot_general(
+                    pick, new_ref[kv, k].astype(mm_dtype),
+                    (((1,), (0,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32
+                ).astype(out_ref.dtype)                    # [page, D]
+            out_ref[kv, k] = jnp.where(own, new, tile_ref[kv, k])
+    if not has_scale:
+        return
+    # scale sidecar: tokens along lanes
+    tok = off + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+    own = (tok >= 0) & (tok < q_lens)                      # [1, page]
+    if q_pad > 1:
+        pick = (jax.lax.broadcasted_iota(jnp.int32, (q_pad, page), 0)
+                == tok).astype(jnp.float32)                # [Q, page]
+    for kv in range(2):
+        if q_pad == 1:
+            new = nscale_ref[kv]                           # [K, 1]
+        else:
+            new = jax.lax.dot_general(
+                nscale_ref[kv], pick, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)        # [K, page]
+        sout_ref[kv] = jnp.where(own, new, stile_ref[kv])
+
+
+def kv_write_pages(kv: jax.Array, layer, k_new: jax.Array,
+                   v_new: jax.Array, page_table: jax.Array,
+                   start_pos: jax.Array, q_lens: jax.Array, *,
+                   interpret: bool = False) -> jax.Array:
+    """Pallas cache write, in place: a ``(row, touched page)`` grid that
+    read-modify-writes only the page tiles a row's new tokens land in
+    (:func:`_kv_write_kernel`), the pool aliased input -> output.  The
+    page ids ride the BlockSpec index maps through scalar prefetch, as
+    in the attention kernel.  Same contract as :func:`write_kv`; a
+    padding token is written nowhere (a row with nothing to write
+    rewrites the null page with its own content)."""
+    S, Q, K, D = k_new.shape
+    has_scale = isinstance(kv, KVPages)
+    kv_arr = kv.payload if has_scale else kv
+    page_size = kv_arr.shape[4]
+    # pages a row of Q tokens can touch from an arbitrary first slot
+    J = min((Q + 2 * page_size - 2) // page_size, page_table.shape[1])
+    first = start_pos // page_size                          # [S]
+    pj = first[:, None] + jnp.arange(J, dtype=jnp.int32)[None, :]
+    touched = (pj * page_size < (start_pos + q_lens)[:, None])
+    touched &= (q_lens > 0)[:, None]
+    pids = jnp.take_along_axis(
+        page_table, jnp.minimum(pj, page_table.shape[1] - 1), axis=1)
+    pids = jnp.where(touched, pids, 0).astype(jnp.int32)    # null page
+    offs = (pj * page_size - start_pos[:, None]).astype(jnp.int32)
+
+    # [S, Q, 2, K, D] -> per row [2, K, Q, D], Q padded to whole lanes
+    # of the one-hot (activation-sized; the pool itself is not touched)
+    q_pad = Q if Q == 1 else -(-Q // 128) * 128
+    kv_new = jnp.stack([k_new, v_new], axis=2)
+    if has_scale:
+        kv_new, scales = quantize_kv_blocks(kv_new)
+        scales = jnp.pad(scales.transpose(0, 2, 3, 1),      # [S,2,K,Q]
+                         ((0, 0),) * 3 + ((0, q_pad - Q),))
+    kv_new = jnp.pad(kv_new.astype(kv_arr.dtype).transpose(0, 2, 3, 1, 4),
+                     ((0, 0),) * 3 + ((0, q_pad - Q), (0, 0)))
+
+    def at_row(*block):
+        return pl.BlockSpec((None,) + block,
+                            lambda s, j, l, pid, off, ql:
+                            (s,) + (0,) * len(block))
+
+    def at_page(*block):
+        return pl.BlockSpec((None, None) + block,
+                            lambda s, j, l, pid, off, ql:
+                            (l[0], pid[s, j]) + (0,) * len(block))
+
+    tiles = at_page(2, K, page_size, D)
+    if has_scale:
+        rows = at_page(2, K, page_size)
+        in_specs = [at_row(2, K, q_pad, D), at_row(2, K, q_pad), tiles, rows]
+        inputs = (kv_new, scales, kv.payload, kv.scale)
+        out_specs, out_shape = [tiles, rows], [
+            jax.ShapeDtypeStruct(kv.payload.shape, kv.payload.dtype),
+            jax.ShapeDtypeStruct(kv.scale.shape, kv.scale.dtype)]
+        aliases = {6: 0, 7: 1}      # operands count the 4 prefetched
+    else:
+        in_specs = [at_row(2, K, q_pad, D), tiles]
+        inputs = (kv_new, kv)
+        out_specs = tiles
+        out_shape = jax.ShapeDtypeStruct(kv.shape, kv.dtype)
+        aliases = {5: 0}
+    out = pl.pallas_call(
+        functools.partial(_kv_write_kernel, has_scale=has_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(S, J),
+            in_specs=in_specs, out_specs=out_specs),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        # not ``paged_attention*``: that pattern is the attention
+        # kernels' share and roofline (benchmark/metrics)
+        name="kv_write_decode" if Q == 1 else "kv_write_prefill",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), pids, offs,
+      q_lens.astype(jnp.int32), *inputs)
+    return KVPages(*out) if has_scale else out
+
+
+def paged_attention(q: jax.Array, kv: jax.Array, layer,
                     page_table: jax.Array, start_pos: jax.Array,
                     q_lens: jax.Array, *,
                     sm_scale: float | None = None,
@@ -170,9 +333,10 @@ def paged_attention(q: jax.Array, kv_layer: jax.Array,
                     interpret: bool = False) -> jax.Array:
     """Masked GQA attention of [S, Q] new tokens over their paged context.
 
-    q        : [S, Q, H, D]    (H = K * groups)
-    kv_layer : [num_pages+1, 2, K, page_size, D] (new KV already written)
-    Returns  : [S, Q, H, D]
+    q       : [S, Q, H, D]    (H = K * groups)
+    kv      : [L, num_pages+1, 2, K, page_size, D] (new KV already written)
+    layer   : int32 scalar, the layer of the pool to attend over
+    Returns : [S, Q, H, D]
 
     Ragged buckets route to the Pallas kernel (``use_kernel`` None =
     auto: on TPU, or anywhere with ``interpret=True``) — the kernel
@@ -184,28 +348,23 @@ def paged_attention(q: jax.Array, kv_layer: jax.Array,
     Pallas interpret mode (CPU testing), independent of path selection.
     """
     S, Q, H, D = q.shape
-    quantized = isinstance(kv_layer, KVPages)
-    kv_arr = kv_layer.payload if quantized else kv_layer
-    K_heads = kv_arr.shape[2]
+    quantized = isinstance(kv, KVPages)
+    K_heads = (kv.payload if quantized else kv).shape[3]
     if use_kernel is None:
         use_kernel = ((interpret or on_tpu())
                       and Q * (H // K_heads) <= MAX_KERNEL_Q_ROWS)
     if use_kernel:
         return paged_decode_attention(
-            q, kv_layer, page_table, start_pos,
+            q, kv, layer, page_table, start_pos,
             sm_scale=sm_scale, alibi_slopes=alibi_slopes,
             window=window, interpret=interpret)
     K = K_heads
     G = H // K
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
 
-    pages = kv_arr[page_table]                  # [S, P, 2, K, page, D]
-    if quantized:
-        # dequantize the gathered context only — the resident cache
-        # stays int8; [S, P, 2, K, page] scales broadcast over D
-        pages = dequantize_kv_blocks(
-            pages, kv_layer.scale[page_table], dtype=q.dtype)
-    k, v = _flatten_context(pages)              # [S, C, K, D]
+    # dequantizes the gathered context only — a resident int8 cache
+    # stays int8
+    k, v = paged_context(kv, layer, page_table, dtype=q.dtype)
     C = k.shape[1]
 
     qg = q.reshape(S, Q, K, G, D)
@@ -238,7 +397,7 @@ def paged_attention(q: jax.Array, kv_layer: jax.Array,
 # Pallas ragged kernel (any Q: decode rows AND prefill-chunk rows)
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
+def _decode_kernel(l_ref, pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
                    sm_scale, has_alibi, has_scale, window, q_len, groups):
     """One (slot, kv_head, page) grid step of flash-style ragged attention.
 
@@ -353,7 +512,7 @@ def _flatten_context(pages: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return kv[0], kv[1]
 
 
-def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
+def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
                            page_table: jax.Array, start_pos: jax.Array, *,
                            sm_scale: float | None = None,
                            alibi_slopes: Optional[jax.Array] = None,
@@ -370,13 +529,16 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
     prefill+decode ragged batch (the single-kernel serving formulation
     of Ragged Paged Attention, arxiv 2604.15464).
 
-    q: [S, Q, H, D]; kv_layer: [num_pages+1, 2, K, page_size, D];
-    page_table: [S, P]; start_pos: [S].  Returns [S, Q, H, D].
+    q: [S, Q, H, D]; kv: [L, num_pages+1, 2, K, page_size, D] with
+    ``layer`` an int32 scalar (a third scalar-prefetch operand: the
+    index maps address ``pool[layer, page]``, so no layer is ever
+    sliced out of the pool); page_table: [S, P]; start_pos: [S].
+    Returns [S, Q, H, D].
     """
     S, Q, H, D = q.shape
-    has_scale = isinstance(kv_layer, KVPages)
-    kv_arr = kv_layer.payload if has_scale else kv_layer
-    K, page_size = kv_arr.shape[2:4]
+    has_scale = isinstance(kv, KVPages)
+    kv_arr = kv.payload if has_scale else kv
+    K, page_size = kv_arr.shape[3:5]
     G = H // K
     P_pages = page_table.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
@@ -389,32 +551,34 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
     grid = (S, K, P_pages)
     # index maps receive (s, k, p, *scalar_prefetch_refs)
     q_spec = pl.BlockSpec((None, None, Q * G, D),
-                          lambda s, k, p, pt, sp: (s, k, 0, 0))
-    k_spec = pl.BlockSpec((None, None, None, page_size, D),
-                          lambda s, k, p, pt, sp: (pt[s, p], 0, k, 0, 0))
-    v_spec = pl.BlockSpec((None, None, None, page_size, D),
-                          lambda s, k, p, pt, sp: (pt[s, p], 1, k, 0, 0))
+                          lambda s, k, p, l, pt, sp: (s, k, 0, 0))
+    tile = (None, None, None, None, page_size, D)
+    k_spec = pl.BlockSpec(
+        tile, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 0, k, 0, 0))
+    v_spec = pl.BlockSpec(
+        tile, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 1, k, 0, 0))
     o_spec = pl.BlockSpec((None, None, Q * G, D),
-                          lambda s, k, p, pt, sp: (s, k, 0, 0))
+                          lambda s, k, p, l, pt, sp: (s, k, 0, 0))
 
     if has_scale:
-        # scale sidecar [P+1, 2, K, page] -> the page's full [K, page]
-        # tile (last two block dims = array dims, which the lowering
-        # requires); the kernel row-slices its own head.  Same
+        # scale sidecar [L, P+1, 2, K, page] -> the page's full
+        # [K, page] tile (last two block dims = array dims, which the
+        # lowering requires); the kernel row-slices its own head.  Same
         # page-table indirection as k/v: the BlockSpec DMA is the gather
-        ks_spec = pl.BlockSpec((None, None, K, page_size),
-                               lambda s, k, p, pt, sp: (pt[s, p], 0, 0, 0))
-        vs_spec = pl.BlockSpec((None, None, K, page_size),
-                               lambda s, k, p, pt, sp: (pt[s, p], 1, 0, 0))
+        rows = (None, None, None, K, page_size)
+        ks_spec = pl.BlockSpec(
+            rows, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 0, 0, 0))
+        vs_spec = pl.BlockSpec(
+            rows, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 1, 0, 0))
         in_specs = [q_spec, k_spec, ks_spec, v_spec, vs_spec]
-        inputs = (qg, kv_arr, kv_layer.scale, kv_arr, kv_layer.scale)
+        inputs = (qg, kv_arr, kv.scale, kv_arr, kv.scale)
     else:
         in_specs = [q_spec, k_spec, v_spec]
         inputs = (qg, kv_arr, kv_arr)
     if has_alibi:
         slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(K, 1, G)
         sl_spec = pl.BlockSpec((None, 1, G),
-                               lambda s, k, p, pt, sp: (k, 0, 0))
+                               lambda s, k, p, l, pt, sp: (k, 0, 0))
         in_specs = [sl_spec] + in_specs
         inputs = (slopes,) + inputs
 
@@ -425,7 +589,7 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=in_specs,
             out_specs=o_spec,
@@ -443,21 +607,10 @@ def paged_decode_attention(q: jax.Array, kv_layer: jax.Array,
         name=("paged_attention_decode" if Q == 1
               else "paged_attention_prefill"),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), start_pos.astype(jnp.int32), *inputs)
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32), start_pos.astype(jnp.int32), *inputs)
     out = out.reshape(S, K, Q, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(S, Q, H, D)
-
-
-def rope_write_kv(kv_layer: jax.Array, k_new: jax.Array, v_new: jax.Array,
-                  sin: jax.Array, cos: jax.Array, page_table: jax.Array,
-                  start_pos: jax.Array, q_lens: jax.Array) -> jax.Array:
-    """Fused rotary-embed + cache write (reference
-    ``linear_blocked_kv_rotary``, inference/v2/kernels/ragged_ops/
-    linear_blocked_kv_copy): one traced region XLA fuses into a single
-    rotate-and-scatter, so the rotated K never round-trips HBM."""
-    from ..models.transformer import apply_rope
-    return write_kv(kv_layer, apply_rope(k_new, sin, cos), v_new,
-                    page_table, start_pos, q_lens)
 
 
 def gather_last(x: jax.Array, q_lens: jax.Array) -> jax.Array:
@@ -487,13 +640,17 @@ def attention_reference(q, k_ctx, v_ctx, start_pos, q_lens,
     return out.reshape(S, Q, H, D)
 
 
-def paged_context(kv_layer: jax.Array, page_table: jax.Array
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """Materialize a slot's context (testing helper); a quantized layer
-    dequantizes to fp32."""
-    if isinstance(kv_layer, KVPages):
-        pages = dequantize_kv_blocks(kv_layer.payload[page_table],
-                                     kv_layer.scale[page_table])
+def paged_context(kv: jax.Array, layer, page_table: jax.Array,
+                  dtype=jnp.float32) -> Tuple[jax.Array, jax.Array]:
+    """Token-major K and V contexts ``[S, C, K, D]`` of one layer of the
+    pool: ONE gather of ``pool[layer, page_table]`` (``[S, P, 2, K,
+    page, D]``; the layer is an index of the gather, not a slice taken
+    first).  A quantized pool dequantizes to ``dtype``."""
+    layer = jnp.asarray(layer, jnp.int32)     # an index of the gather
+    if isinstance(kv, KVPages):
+        # [S, P, 2, K, page] scales broadcast over D
+        pages = dequantize_kv_blocks(kv.payload[layer, page_table],
+                                     kv.scale[layer, page_table], dtype)
     else:
-        pages = kv_layer[page_table]
+        pages = kv[layer, page_table]
     return _flatten_context(pages)

@@ -1,4 +1,5 @@
-"""Every Pallas kernel compiled for the chip, without the chip.
+"""Every Pallas kernel, and the serve step programs, compiled for the chip
+without the chip.
 
 The TPU's compiler is installed here and compiles for a chip that is
 described and not attached (guide ``on-chip-measurement`` section 2):
@@ -16,8 +17,11 @@ cache is off around them (an entry written for a described chip cannot be
 read back without one).  Keep every such test in THIS file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -27,7 +31,7 @@ from deepspeed_tpu.ops.fused_optimizer import (fused_adamw_flat,
                                                fused_lion_flat)
 from deepspeed_tpu.ops.normalization import layernorm, rmsnorm
 from deepspeed_tpu.ops.paged_attention import (MAX_KERNEL_Q_ROWS, KVPages,
-                                               paged_attention)
+                                               paged_attention, write_kv)
 from deepspeed_tpu.ops.quantization import (dequantize_blockwise,
                                             quantize_blockwise)
 
@@ -106,13 +110,17 @@ def test_flash_short_unaligned_sequence(chip):
 
 # -- paged attention (every serving step) -----------------------------------
 
+def _pool(chip, int8, layers=2, pages=POOL):
+    shape = (layers, pages + 1, 2, KV_HEADS, PAGE, HEAD_DIM)
+    return (KVPages(chip(shape, jnp.int8), chip(shape[:-1], jnp.float32))
+            if int8 else chip(shape, jnp.bfloat16))
+
+
 def _paged_args(chip, slots, rows, int8):
-    pages_per_seq = SEQ // PAGE
-    shape = (POOL + 1, 2, KV_HEADS, PAGE, HEAD_DIM)
-    kv = (KVPages(chip(shape, jnp.int8), chip(shape[:-1], jnp.float32))
-          if int8 else chip(shape, jnp.bfloat16))
-    return (chip((slots, rows, HEADS, HEAD_DIM), jnp.bfloat16), kv,
-            chip((slots, pages_per_seq), jnp.int32),
+    """(q, pool, layer, page table, start_pos, q_lens)"""
+    return (chip((slots, rows, HEADS, HEAD_DIM), jnp.bfloat16),
+            _pool(chip, int8), chip((), jnp.int32),
+            chip((slots, SEQ // PAGE), jnp.int32),
             chip((slots,), jnp.int32), chip((slots,), jnp.int32))
 
 
@@ -122,9 +130,9 @@ def _paged_args(chip, slots, rows, int8):
                          ids=["decode", "mixed", "chunk"])
 def test_paged_attention(chip, slots, rows, window, int8):
     compile_for_chip(
-        lambda q, kv, table, start, lens: paged_attention(
-            q, kv, table, start, lens, use_kernel=True, window=window,
-            interpret=False),
+        lambda q, kv, layer, table, start, lens: paged_attention(
+            q, kv, layer, table, start, lens, use_kernel=True,
+            window=window, interpret=False),
         *_paged_args(chip, slots, rows, int8), kernel="paged_attention")
 
 
@@ -134,15 +142,130 @@ def test_paged_attention_largest_query_block(chip):
     MiB and are refused, so larger blocks take the dense-gather path)."""
     rows = MAX_KERNEL_Q_ROWS // (HEADS // KV_HEADS)
     compile_for_chip(
-        lambda q, kv, table, start, lens: paged_attention(
-            q, kv, table, start, lens, use_kernel=True, interpret=False),
+        lambda q, kv, layer, table, start, lens: paged_attention(
+            q, kv, layer, table, start, lens, use_kernel=True,
+            interpret=False),
         *_paged_args(chip, 2, rows, int8=False), kernel="paged_attention")
     with pytest.raises(Exception, match="vmem"):
         compile_for_chip(
-            lambda q, kv, table, start, lens: paged_attention(
-                q, kv, table, start, lens, use_kernel=True, interpret=False),
+            lambda q, kv, layer, table, start, lens: paged_attention(
+                q, kv, layer, table, start, lens, use_kernel=True,
+                interpret=False),
             *_paged_args(chip, 1, 2 * rows, int8=False),
             kernel="paged_attention")
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("slots,rows", [(64, 1), (8, 128), (2, 512), (16, 5)],
+                         ids=["decode", "mixed", "chunk", "spec"])
+def test_kv_write(chip, slots, rows, int8):
+    """The cache write's tile kernel: a whole page of every head per grid
+    step, the one-hot shift as a matmul, the pool aliased in -> out."""
+    new = chip((slots, rows, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+    _, kv, layer, table, start, lens = _paged_args(chip, slots, rows, int8)
+    compile_for_chip(
+        lambda kv, layer, k, v, table, start, lens: write_kv(
+            kv, layer, k, v, table, start, lens, use_kernel=True,
+            interpret=False),
+        kv, layer, new, new, table, start, lens, kernel="kv_write")
+
+
+# -- whole serve step programs: the KV pool never leaves its buffer ---------
+
+#: step-cache keys of the benchmark's serving cell (64 decoding rows, one
+#: 4 x 128 prefill piece, 8 pages a row), at two layers
+STEP_KEYS = {
+    "chain": (64, 1, 8, False, "chain", 64, True),
+    "sample-fresh": (4, 128, 8, True, "sample", True),
+    "mixed": (64, 1, 8, False, "mixed", 4, 128, 8, True, True),
+}
+#: opcodes (and the fusions XLA names after them) that move data
+MOVERS = ("copy", "transpose", "dynamic-slice", "dynamic-update-slice")
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+             "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+             "u64": 8}
+_HLO_LINE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*([a-z0-9]+)"
+                       r"\[([\d,]*)\](?:\{[^}]*\})?\s+([\w\-]+)\(")
+
+
+def pool_sized_movers(text: str, floor: int) -> list:
+    """(name, opcode, shape) of every instruction of a compiled program,
+    inside fused computations too, that moves data and yields an array of
+    ``floor`` bytes or more."""
+    found = []
+    for line in text.splitlines():
+        m = _HLO_LINE.match(line)
+        if not m or m.group(2) not in _ITEMSIZE:
+            continue
+        name, dtype, dims, opcode = m.groups()
+        size = _ITEMSIZE[dtype] * int(np.prod(
+            [int(d) for d in dims.split(",") if d] or [1]))
+        moves = opcode in MOVERS or (
+            opcode == "fusion" and any(w in name for w in MOVERS))
+        if moves and size >= floor:
+            found.append((name, opcode, f"{dtype}[{dims}]"))
+    return found
+
+
+def test_pool_sized_movers_reads_a_program_text():
+    text = """
+  %copy.98 = bf16[1025,2,8,64,128]{4,2,3,1,0} copy(%x)
+  ROOT %copy_dynamic-update-slice_fusion.2 = bf16[8,1025,2,8,64,128]{5,4,3,2,1,0:T(8,128)(2,1)} fusion(%a, %b), kind=kLoop
+  %kv_write_decode.9 = bf16[8,1025,2,8,64,128]{5,4,3,2,1,0} custom-call(%p)
+  %copy.3 = bf16[64,1,8,128]{3,2,1,0} copy(%y)
+  %get-tuple-element.7 = bf16[8,1025,2,8,64,128]{5,4,3,2,1,0} get-tuple-element(%w), index=1
+"""
+    assert [m[0] for m in pool_sized_movers(text, 268_697_600)] == [
+        "copy.98", "copy_dynamic-update-slice_fusion.2"]
+
+
+@pytest.mark.parametrize("kind,int8,scan_layers", [
+    # the cell's three kinds; then the mixed program, which runs both
+    # passes, for the quantized pool and the unrolled layer loop
+    ("chain", False, True), ("sample-fresh", False, True),
+    ("mixed", False, True), ("mixed", True, True), ("mixed", False, False),
+    ("mixed", True, False)],
+    ids=lambda v: {True: "y", False: "n"}.get(v, v))
+def test_step_program_leaves_the_pool_in_place(chip, monkeypatch, kind, int8,
+                                               scan_layers):
+    """Inside a compiled step program the KV pool never leaves its donated
+    buffer (PR 25): (a) no copy, transpose, dynamic-slice or
+    dynamic-update-slice, alone or as a fusion, yields one pool layer's
+    bytes or more; (b) the program's temporaries stay under one pool
+    layer.  Mistral-7B widths, two layers, a 512-page pool (1024 int8
+    pages: a layer of the pool outweighs every weight matrix, so the floor
+    of (a) catches the pool alone)."""
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        MistralInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.llama import LlamaForCausalLM
+
+    # trace the paths the chip takes (Pallas kernels), not the CPU's
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    layers, pages = 2, POOL * (2 if int8 else 1)
+    model = LlamaForCausalLM(
+        "7b", intermediate_size=14336, num_kv_heads=KV_HEADS,
+        sliding_window=4096, num_layers=layers, max_seq_len=4096,
+        scan_layers=scan_layers)
+    params = jax.eval_shape(lambda key: jax.tree.map(
+        lambda x: x.astype(model.cfg.dtype), model.init_params(key)),
+        jax.random.key(0))
+    serve = MistralInferenceModel(model.cfg, params, kv_config=KVCacheConfig(
+        num_layers=layers, kv_heads=KV_HEADS, head_dim=HEAD_DIM,
+        page_size=PAGE, num_pages=pages,
+        quantization="int8" if int8 else "none"))
+    pool = _pool(chip, int8, layers, pages)
+    key = STEP_KEYS[kind]
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        serve._step_avals(key, pool))
+    compiled = jax.jit(serve._impl_of(key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    payload = jax.tree.leaves(pool)[0]
+    layer_bytes = int(np.prod(payload.shape[1:])) * payload.dtype.itemsize
+    assert pool_sized_movers(compiled.as_text(), layer_bytes) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 # -- norms, fused optimizers, block quantization ------------------------------

@@ -403,6 +403,9 @@ class TestGreedyRng:
 # ragged Pallas kernel: Q > 1 rows (prefill chunks) in one launch
 # ---------------------------------------------------------------------------
 
+LAYER = 1
+
+
 class TestRaggedKernelMixedQ:
     def _setup(self, S=3, Q=4, K=2, G=2, D=128, page=8, pages=32,
                hist=(5, 0, 11)):
@@ -427,24 +430,26 @@ class TestRaggedKernelMixedQ:
         q = jnp.asarray(rng.standard_normal((S, Q, H, D)), jnp.float32)
         k_new = jnp.asarray(rng.standard_normal((S, Q, K, D)), jnp.float32)
         v_new = jnp.asarray(rng.standard_normal((S, Q, K, D)), jnp.float32)
-        kv = pa.write_kv(kv, k_new, v_new, jnp.asarray(table),
-                         jnp.asarray(start), jnp.asarray(q_lens))
+        # the ops take the whole pool and a layer index: layer 1 of two
+        kv = pa.write_kv(jnp.stack([kv[::-1], kv]), LAYER, k_new, v_new,
+                         jnp.asarray(table), jnp.asarray(start),
+                         jnp.asarray(q_lens))
         return (q, kv, jnp.asarray(table), jnp.asarray(start),
                 jnp.asarray(q_lens))
 
     def test_q4_matches_jnp(self):
         q, kv, table, start, q_lens = self._setup()
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  use_kernel=False)
-        out = pa.paged_decode_attention(q, kv, table, start, interpret=True)
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
     def test_q4_window_matches_jnp(self):
         q, kv, table, start, q_lens = self._setup(hist=(5, 0, 11))
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  use_kernel=False, window=6)
-        out = pa.paged_decode_attention(q, kv, table, start, window=6,
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start, window=6,
                                         interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -453,9 +458,9 @@ class TestRaggedKernelMixedQ:
         from deepspeed_tpu.models.transformer import alibi_slopes
         q, kv, table, start, q_lens = self._setup()
         slopes = alibi_slopes(q.shape[2])
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  use_kernel=False, alibi_slopes=slopes)
-        out = pa.paged_decode_attention(q, kv, table, start,
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start,
                                         alibi_slopes=slopes, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -463,9 +468,9 @@ class TestRaggedKernelMixedQ:
     def test_q8_gqa_groups_match_jnp(self):
         q, kv, table, start, q_lens = self._setup(S=2, Q=8, K=2, G=4,
                                                   hist=(7, 16))
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  use_kernel=False)
-        out = pa.paged_decode_attention(q, kv, table, start, interpret=True)
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
@@ -478,7 +483,7 @@ class TestRaggedKernelMixedQ:
         with mock.patch.object(pa, "MAX_KERNEL_Q_ROWS", 4):
             with mock.patch.object(pa, "paged_decode_attention",
                                    side_effect=AssertionError) as m:
-                pa.paged_attention(q, kv, table, start, q_lens,
+                pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                    interpret=True)
                 assert not m.called
 

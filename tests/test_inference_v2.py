@@ -55,6 +55,11 @@ class TestBlockedAllocator:
 # paged attention numerics
 # ---------------------------------------------------------------------------
 
+#: the ops take the whole pool and a layer index; these tests write and
+#: read layer 1 of a two-layer pool whose layer 0 is noise
+LAYER = 1
+
+
 class TestPagedAttention:
     def _setup(self, S=3, Q=4, K=2, G=2, D=16, page=8, pages=32, hist=(5, 0, 11)):
         rng = np.random.default_rng(0)
@@ -89,6 +94,8 @@ class TestPagedAttention:
         q = jnp.asarray(rng.standard_normal((S, Q, H, D)), jnp.float32)
         k_new = jnp.asarray(rng.standard_normal((S, Q, K, D)), jnp.float32)
         v_new = jnp.asarray(rng.standard_normal((S, Q, K, D)), jnp.float32)
+        noise = jnp.asarray(rng.standard_normal(kv.shape), jnp.float32)
+        kv = jnp.stack([noise, kv])           # [L=2, P+1, 2, K, page, D]
         return (q, k_new, v_new, kv, jnp.asarray(table), jnp.asarray(start),
                 jnp.asarray(q_lens), ctx_k, ctx_v, page)
 
@@ -97,8 +104,8 @@ class TestPagedAttention:
          ctx_k, ctx_v, page) = self._setup()
         S, Q, H, D = q.shape
         K = k_new.shape[2]
-        kv = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
-        out = pa.paged_attention(q, kv, table, start, q_lens)
+        kv = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
+        out = pa.paged_attention(q, kv, LAYER, table, start, q_lens)
 
         # dense reference: per-slot history + new tokens, aligned to C rows
         C = table.shape[1] * page
@@ -119,10 +126,10 @@ class TestPagedAttention:
         """Q=1 Pallas decode (interpret mode on CPU) == jnp gather path."""
         (q, k_new, v_new, kv, table, start, q_lens,
          _, _, _) = self._setup(Q=1, D=128, hist=(5, 0, 11))
-        kv = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        kv = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  interpret=False)  # jnp path off-TPU
-        out = pa.paged_decode_attention(q, kv, table, start, interpret=True)
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
@@ -135,8 +142,8 @@ class TestPagedAttention:
          ctx_k, ctx_v, page) = self._setup(hist=(5, 0, 11))
         S, Q, H, D = q.shape
         K = k_new.shape[2]
-        kv = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
-        out = pa.paged_attention(q, kv, table, start, q_lens,
+        kv = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
+        out = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  use_kernel=False, window=window)
         C = table.shape[1] * page
         k_ctx = np.zeros((S, C, K, D), np.float32)
@@ -153,7 +160,7 @@ class TestPagedAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
         # window must change the answer where history exceeds it
-        full = pa.paged_attention(q, kv, table, start, q_lens,
+        full = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                   use_kernel=False)
         assert not np.allclose(np.asarray(out)[2], np.asarray(full)[2])
 
@@ -161,10 +168,10 @@ class TestPagedAttention:
         window = 4
         (q, k_new, v_new, kv, table, start, q_lens,
          _, _, _) = self._setup(Q=1, D=128, hist=(5, 0, 11))
-        kv = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        kv = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  use_kernel=False, window=window)
-        out = pa.paged_decode_attention(q, kv, table, start,
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start,
                                         window=window, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -177,10 +184,10 @@ class TestPagedAttention:
          _, _, _) = self._setup(Q=1, D=128, hist=(5, 0, 11))
         H = q.shape[2]
         slopes = alibi_slopes(H)
-        kv = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        kv = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  use_kernel=False, alibi_slopes=slopes)
-        out = pa.paged_decode_attention(q, kv, table, start,
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start,
                                         alibi_slopes=slopes, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -189,14 +196,16 @@ class TestPagedAttention:
         (q, k_new, v_new, kv, table, start, q_lens,
          _, _, _) = self._setup(S=4, Q=1, K=2, G=4, D=128,
                                 hist=(0, 7, 16, 40))
-        kv = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
-        ref = pa.paged_attention(q, kv, table, start, q_lens,
+        kv = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
+        ref = pa.paged_attention(q, kv, LAYER, table, start, q_lens,
                                  interpret=False)
-        out = pa.paged_decode_attention(q, kv, table, start, interpret=True)
+        out = pa.paged_decode_attention(q, kv, LAYER, table, start, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
-    def test_rope_write_kv_matches_separate(self):
+    def test_rotated_keys_written_alike_by_scatter_and_kernel(self):
+        """The model rotates K and then writes it: the XLA scatter and
+        the tile kernel (interpret mode) store the same rotated rows."""
         from deepspeed_tpu.models.transformer import apply_rope, rope_table
         from deepspeed_tpu.models.llama import llama_config
         (q, k_new, v_new, kv, table, start, q_lens,
@@ -204,45 +213,46 @@ class TestPagedAttention:
         cfg = llama_config("debug", head_dim=16)
         pos = pa.token_positions(start, k_new.shape[1])
         sin, cos = rope_table(cfg, pos)
-        fused = pa.rope_write_kv(kv, k_new, v_new, sin, cos, table, start,
-                                 q_lens)
-        manual = pa.write_kv(kv, apply_rope(k_new, sin, cos), v_new, table,
-                             start, q_lens)
-        np.testing.assert_allclose(np.asarray(fused), np.asarray(manual),
-                                   rtol=1e-6, atol=1e-6)
+        k_rot = apply_rope(k_new, sin, cos)
+        scatter = pa.write_kv(kv, LAYER, k_rot, v_new, table, start, q_lens,
+                              use_kernel=False)
+        kernel = pa.write_kv(kv, LAYER, k_rot, v_new, table, start, q_lens,
+                             interpret=True)
+        np.testing.assert_array_equal(np.asarray(kernel), np.asarray(scatter))
 
     def test_padding_slot_writes_go_to_null_page(self):
         q, k_new, v_new, kv, table, start, q_lens = self._setup()[:7]
         q_lens = q_lens.at[1].set(0)  # slot 1 becomes padding
-        kv2 = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
+        kv2 = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
         # slot 1's pages must be untouched
         pages_1 = np.asarray(table[1])
         pages_1 = pages_1[pages_1 > 0]
-        np.testing.assert_array_equal(np.asarray(kv2[pages_1]),
-                                      np.asarray(kv[pages_1]))
+        np.testing.assert_array_equal(np.asarray(kv2[LAYER, pages_1]),
+                                      np.asarray(kv[LAYER, pages_1]))
+        np.testing.assert_array_equal(np.asarray(kv2[0]), np.asarray(kv[0]))
 
 
     def test_cache_layout_is_one_page_tile_per_head(self):
-        """[P+1, 2, K, page, D]: token t of a sequence lands in its page
-        at [page_id, k/v, :, t % page] — the last two dims are the
-        (page, D) tile the Pallas kernel DMAs per (page, head)."""
+        """[L, P+1, 2, K, page, D]: token t of a sequence lands in its page
+        at [layer, page_id, k/v, :, t % page] — the last two dims are the
+        (page, D) tile the Pallas kernels DMA per (page, head)."""
         (q, k_new, v_new, kv, table, start, q_lens,
          _, _, page) = self._setup()
         S, Q, K, D = k_new.shape
-        assert kv.shape[1:] == (2, K, page, D)
-        kv2 = pa.write_kv(kv, k_new, v_new, table, start, q_lens)
+        assert kv.shape[2:] == (2, K, page, D)
+        kv2 = pa.write_kv(kv, LAYER, k_new, v_new, table, start, q_lens)
         for s in range(S):
             for i in range(Q):
                 t = int(start[s]) + i
                 pid = int(table[s, t // page])
                 np.testing.assert_array_equal(
-                    np.asarray(kv2[pid, 0, :, t % page]),
+                    np.asarray(kv2[LAYER, pid, 0, :, t % page]),
                     np.asarray(k_new[s, i]))
                 np.testing.assert_array_equal(
-                    np.asarray(kv2[pid, 1, :, t % page]),
+                    np.asarray(kv2[LAYER, pid, 1, :, t % page]),
                     np.asarray(v_new[s, i]))
         # and the testing helper reads it back token-major
-        k_ctx, v_ctx = pa.paged_context(kv2, table)
+        k_ctx, v_ctx = pa.paged_context(kv2, LAYER, table)
         assert k_ctx.shape == (S, table.shape[1] * page, K, D)
         s, t = 2, int(start[2]) + 1
         np.testing.assert_array_equal(np.asarray(k_ctx[s, t]),
@@ -251,7 +261,7 @@ class TestPagedAttention:
     @pytest.mark.parametrize("window", [None, 6])
     @pytest.mark.parametrize("q_rows", [1, 4])
     def test_int8_pages_kernel_matches_dense_gather(self, q_rows, window):
-        """Quantized pages: scale sidecar [P+1, 2, K, page], applied by
+        """Quantized pages: scale sidecar [L, P+1, 2, K, page], applied by
         the kernel to the score / probability tile instead of the
         [page, D] payload — same answers as the dense-gather path that
         dequantizes the gathered context."""
@@ -262,15 +272,141 @@ class TestPagedAttention:
         # history rows quantize through the same append path
         codes, scales = pa.quantize_kv_blocks(kv)
         layer = pa.KVPages(codes, scales)
-        layer = pa.write_kv(layer, k_new, v_new, table, start, q_lens)
+        layer = pa.write_kv(layer, LAYER, k_new, v_new, table, start, q_lens)
         assert layer.scale.shape == layer.payload.shape[:-1]
-        dense = pa.paged_attention(q, layer, table, start, q_lens,
+        dense = pa.paged_attention(q, layer, LAYER, table, start, q_lens,
                                    use_kernel=False, window=window)
-        kernel = pa.paged_attention(q, layer, table, start, q_lens,
+        kernel = pa.paged_attention(q, layer, LAYER, table, start, q_lens,
                                     use_kernel=True, window=window,
                                     interpret=True)
         np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense),
                                    rtol=2e-5, atol=2e-5)
+
+
+def _placed_by_hand(pool, layer, k_new, v_new, table, start, q_lens):
+    """The per-layer reference of the cache write: numpy loops that put
+    token i of row s at ``[layer, table[s, pos // page], k/v, :, pos %
+    page]`` and touch nothing else (a padding token lands nowhere)."""
+    out = np.array(pool)
+    page = out.shape[4]
+    for s in range(k_new.shape[0]):
+        for i in range(int(q_lens[s])):
+            pos = int(start[s]) + i
+            pid = int(table[s, pos // page])
+            out[layer, pid, 0, :, pos % page] = np.asarray(k_new[s, i])
+            out[layer, pid, 1, :, pos % page] = np.asarray(v_new[s, i])
+    return out
+
+
+#: name -> (Q, page, start_pos per row, q_lens per row, window, int8)
+POOL_CASES = {
+    "decode": (1, 8, (5, 0, 11), (1, 1, 1), None, False),
+    "chunk_crossing_a_page": (6, 8, (5, 13, 3), (6, 6, 4), None, False),
+    "fresh_128_token_prefill": (128, 16, (0, 0), (128, 97), None, False),
+    "padded_rows": (4, 8, (5, 0, 11), (4, 0, 2), None, False),
+    "sliding_window": (4, 8, (5, 0, 11), (4, 4, 4), 6, False),
+    "int8_pages": (4, 8, (5, 0, 11), (4, 3, 4), None, True),
+    "int8_decode": (1, 8, (5, 0, 11), (1, 1, 0), None, True),
+}
+
+
+class TestPoolAndLayerOps:
+    """``write_kv`` and ``paged_attention`` take the whole pool and a
+    layer index (PR 25); both of their forms — the XLA scatter with the
+    dense gather, and the two Pallas kernels in interpret mode — equal
+    the per-layer reference, at a layer other than 0, and leave every
+    other layer, page and slot of the pool bit-identical."""
+
+    @pytest.mark.parametrize("form", ["jnp", "kernel"])
+    @pytest.mark.parametrize("case", sorted(POOL_CASES))
+    def test_write_and_attend_match_per_layer_reference(self, case, form):
+        Q, page, start, q_lens, window, int8 = POOL_CASES[case]
+        S, K, G, D, L, layer = len(start), 2, 2, 16, 3, 1
+        per_seq = -(-(max(start) + Q) // page)
+        rng = np.random.default_rng(7)
+        table = (1 + rng.permutation(S * per_seq)).reshape(S, per_seq)
+        table = jnp.asarray(table, jnp.int32)
+        start, q_lens = (jnp.asarray(x, jnp.int32) for x in (start, q_lens))
+        shape = (L, S * per_seq + 1, 2, K, page, D)
+        pool = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        q, k_new, v_new = (jnp.asarray(rng.standard_normal((S, Q, n, D)),
+                                       jnp.float32) for n in (K * G, K, K))
+        kernel = dict(use_kernel=True, interpret=True)
+        how = kernel if form == "kernel" else dict(use_kernel=False)
+
+        if int8:
+            codes, scales = pa.quantize_kv_blocks(pool)
+            got = pa.write_kv(pa.KVPages(codes, scales), layer, k_new,
+                              v_new, table, start, q_lens, **how)
+            # codes and scales of a row sit at the same address
+            new_codes, new_scales = pa.quantize_kv_blocks(
+                jnp.stack([k_new, v_new]))
+            want = _placed_by_hand(codes, layer, *new_codes, table, start,
+                                   q_lens)
+            want_scale = _placed_by_hand(
+                scales[..., None], layer, *new_scales[..., None], table,
+                start, q_lens)[..., 0]
+            np.testing.assert_array_equal(
+                np.asarray(got.scale)[:, 1:], want_scale[:, 1:])
+            got_payload = got.payload
+            want_pool = pa.KVPages(jnp.asarray(want),
+                                   jnp.asarray(want_scale))
+        else:
+            got = pa.write_kv(pool, layer, k_new, v_new, table, start,
+                              q_lens, **how)
+            want = _placed_by_hand(pool, layer, k_new, v_new, table, start,
+                                   q_lens)
+            got_payload, want_pool = got, jnp.asarray(want)
+        # every real page of every layer: the new rows, and nothing else
+        # (page 0 is the null page, where the scatter parks padding)
+        np.testing.assert_array_equal(np.asarray(got_payload)[:, 1:],
+                                      want[:, 1:])
+        np.testing.assert_array_equal(np.asarray(got_payload)[layer - 1],
+                                      want[layer - 1])
+
+        out = pa.paged_attention(q, got, layer, table, start, q_lens,
+                                 window=window, **how)
+        k_ctx, v_ctx = pa.paged_context(want_pool, layer, table)
+        ref = pa.attention_reference(q, k_ctx, v_ctx, start, q_lens,
+                                     window=window)
+        rows = np.arange(Q)[None, :] < np.asarray(q_lens)[:, None]
+        np.testing.assert_allclose(np.asarray(out)[rows],
+                                   np.asarray(ref)[rows],
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scanned", "unrolled"])
+    def test_layer_loop_carries_the_pool(self, scan_layers):
+        """Both layer loops hand every layer the whole pool: a chunked
+        prefill plus one decode step equals the full-sequence forward,
+        and each layer's pages hold that layer's own K/V."""
+        model_def = LlamaForCausalLM("debug", max_seq_len=256,
+                                     dtype=jnp.float32,
+                                     scan_layers=scan_layers)
+        params = meta.unbox(model_def.init_params(jax.random.key(0)))
+        cfg = model_def.cfg
+        kv_cfg = KVCacheConfig(num_layers=cfg.num_layers,
+                               kv_heads=cfg.kv_heads,
+                               head_dim=cfg.dims_per_head, page_size=16,
+                               num_pages=16, dtype=jnp.float32)
+        eng = InferenceEngineV2(
+            RaggedInferenceModel(cfg, params, kv_config=kv_cfg),
+            RaggedInferenceEngineConfig(state_manager=StateManagerConfig(
+                max_tracked_sequences=4, max_ragged_sequence_count=4,
+                max_ragged_batch_size=64)))
+        prompt = np.random.default_rng(4).integers(0, 128, 24).astype(
+            np.int32)
+        eng.put([0], [prompt[:10]])
+        logits = eng.put([0], [prompt[10:]])
+        full = forward(cfg, params, prompt[None, :])
+        np.testing.assert_allclose(np.asarray(logits[0]),
+                                   np.asarray(full[0, -1]),
+                                   rtol=5e-2, atol=5e-2)
+        kv = np.asarray(eng._state.kv_cache.data)
+        assert kv.shape[0] == cfg.num_layers
+        written = np.abs(kv).sum(axis=(2, 3, 4, 5)) > 0      # [L, P+1]
+        assert (written[:, 1:].sum(axis=1) == 2).all()       # 24 tokens
+        assert not np.allclose(kv[0], kv[1])
 
 
 class TestPageCodecsRoundTripTheLayout:

@@ -133,7 +133,8 @@ def test_tpu_kernels_run_per_shard_under_the_mesh():
     replicated operands), so under tp the attention modules run in a
     shard_map over their head slice and the norm in a replicated manual
     region; without a mesh, and for ALiBi (closed-over per-head slopes),
-    the module is called as is."""
+    the module is called as is.  The cache write closes over nothing and
+    indexes the sharded head dim: it runs per shard for ALiBi models too."""
     import dataclasses
     eng = _engine(serving=_sv(tp=2))
     model = eng._model
@@ -142,6 +143,7 @@ def test_tpu_kernels_run_per_shard_under_the_mesh():
     assert model._norm is not model._norm_impl
     alibi = dataclasses.replace(model.cfg, pos_emb="alibi")
     assert model._per_shard_heads(fn, alibi, 1) is fn
+    assert model._per_shard_heads(fn, alibi, 2, pool_out=True) is not fn
     plain = _engine(serving=_sv(tp=1))._model
     assert plain._per_shard_heads(fn, plain.cfg, 1) is fn
     assert plain._norm is plain._norm_impl

@@ -454,13 +454,13 @@ def test_paged_attention_kernel_is_named_by_kind_of_row():
 
     def text(q_rows):
         q = jnp.zeros((2, q_rows, 4, 16), jnp.float32)
-        kv = jnp.zeros((9, 2, 2, 16, 16), jnp.float32)
+        kv = jnp.zeros((2, 9, 2, 2, 16, 16), jnp.float32)
         table = jnp.zeros((2, 4), jnp.int32)
         pos = jnp.zeros((2,), jnp.int32)
         return str(jax.make_jaxpr(
             lambda *a: paged_attention(*a, use_kernel=True,
                                        interpret=True))(
-            q, kv, table, pos, pos + q_rows))
+            q, kv, jnp.int32(1), table, pos, pos + q_rows))
 
     assert "paged_attention_decode" in text(1)
     assert "paged_attention_prefill" in text(8)
