@@ -165,6 +165,11 @@ class InferenceEngineV2:
                 f"serving_optimization.tp_collective_quantization={tpq!r}"
                 " is not a supported encoding — choose 'none' (fp "
                 "all-gather) or 'int8' (block-scaled codes + scales)")
+        if model.kv_config.latent and max(tp, model.tp_degree) > 1:
+            raise ValueError(
+                "a latent page pool cannot be served under tp_degree > 1 "
+                "yet: a latent plane has no heads to divide (data-parallel "
+                "attention is the deployment's answer) — use tp_degree=1")
         if tp > 1 and model.mesh is None:
             devs = jax.devices()
             if len(devs) < tp:
@@ -205,6 +210,12 @@ class InferenceEngineV2:
                 "(per-request adaptive selection)")
         self._draft_enabled = (bool(getattr(sv0, "speculative", False))
                                and drafter in ("model", "auto"))
+        if self._draft_enabled and model.cfg.latent_dim:
+            raise ValueError(
+                "model-drafted speculation is not built for the latent "
+                "kind (its layers are two stacks, and a draft module fed "
+                "the target's hidden state is another program): use "
+                "spec_drafter='ngram'")
         want_layers = int(getattr(sv0, "spec_draft_layers", 0) or 0)
         n_layers = int(model.cfg.num_layers)
         # 0 = self-draft: share EVERY target layer (pure dispatch
@@ -217,10 +228,10 @@ class InferenceEngineV2:
             # user config wins over the model's default cache geometry;
             # num_pages=None is sized from free-memory fraction (reference
             # sizes its blocked KV pool the same way)
-            kv_cfg = KVCacheConfig(
-                num_layers=model.kv_config.num_layers,
-                kv_heads=model.kv_config.kv_heads,
-                head_dim=model.kv_config.head_dim,
+            # the layout (planes, heads, width) is the model's, which is
+            # what its attention kind declares of its cache
+            kv_cfg = dataclasses.replace(
+                model.kv_config,
                 page_size=kv_user.page_size,
                 num_pages=kv_user.num_pages or 1, dtype=kv_user.dtype,
                 quantization=(

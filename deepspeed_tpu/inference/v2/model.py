@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...models import transformer as T
+from ...ops.mla_attention import (latent_write, mla_fresh_attention,
+                                  mla_paged_attention, plane_width)
 from ...ops.paged_attention import (KVPages, gather_last, token_positions,
                                     write_kv)
 from ...telemetry import get_tracer
@@ -142,21 +144,32 @@ class RaggedInferenceModel:
         # (reference heuristics.instantiate_attention); attention_impl
         # pins a named implementation, None lets the heuristic pick
         from .modules import instantiate
-        self._attention = instantiate("ragged_attention", cfg,
-                                      name=attention_impl)
-        try:
-            self._fresh_attention = instantiate("fresh_prefill_attention",
-                                                cfg)
-        except (KeyError, ValueError):
-            self._fresh_attention = None
+        if cfg.latent_dim:
+            # the latent kind: its cache plane and its step
+            # (_attend_latent) are its own; the K/V modules do not apply
+            self._attention = None
+            self._fresh_attention = mla_fresh_attention
+        else:
+            self._attention = instantiate("ragged_attention", cfg,
+                                          name=attention_impl)
+            try:
+                self._fresh_attention = instantiate(
+                    "fresh_prefill_attention", cfg)
+            except (KeyError, ValueError):
+                self._fresh_attention = None
         self._norm_impl = instantiate("norm", cfg)
         self._norm = self._norm_impl
         self._embed = instantiate("embedding", cfg)
         self._unembed = instantiate("unembed", cfg)
         self.kv_config_explicit = kv_config is not None
-        self.kv_config = kv_config or KVCacheConfig(
+        # what the attention kind declares of its cache: K and V by
+        # head, or one latent plane a token
+        self.kv_config = kv_config or (KVCacheConfig(
+            num_layers=cfg.num_layers, kv_heads=1,
+            head_dim=plane_width(cfg.latent_dim), planes=1,
+            dtype=cfg.dtype) if cfg.latent_dim else KVCacheConfig(
             num_layers=cfg.num_layers, kv_heads=cfg.kv_heads,
-            head_dim=cfg.dims_per_head, dtype=cfg.dtype)
+            head_dim=cfg.dims_per_head, dtype=cfg.dtype))
         #: which mesh axis shards heads/ffn/vocab (and the KV head dim):
         #: the serving ``tp`` axis when present, else the training-side
         #: ``tensor`` axis.  None until a mesh is applied.
@@ -239,6 +252,10 @@ class RaggedInferenceModel:
         if fmt not in SUPPORTED_FORMATS:
             raise ValueError(f"unknown quantization format {fmt!r} "
                              f"(supported: {sorted(SUPPORTED_FORMATS)})")
+        if self.cfg.latent_dim:
+            raise ValueError(
+                "weight-only quantization does not cover the latent-"
+                "attention / held-experts block yet")
         prior = getattr(self, "_quantized_fmt", None)
         if prior is not None:
             if prior != fmt:
@@ -531,7 +548,8 @@ class RaggedInferenceModel:
         still in flight, with no host sync in between."""
         S, Q, P, _ = self._normalize_key(batch.shape_key)
         assert Q == 1, "chained steps are decode-only"
-        key = (S, 1, P, False, "chain", int(prev_tokens.shape[0]),
+        key = (S, 1, P, False, "chain",
+               int(prev_tokens.shape[0]) - self.step_tail,
                bool(greedy_only))
         step = self._get_step(key)
         return step(self.params, kv, prev_tokens,
@@ -541,6 +559,17 @@ class RaggedInferenceModel:
                     jnp.asarray(top_ks, jnp.int32),
                     jnp.asarray(top_ps, jnp.float32),
                     *self._keyed_args(row_uids, row_pos))
+
+    @property
+    def step_tail(self) -> int:
+        """int32 counts a sampled-token vector carries past its rows: a
+        model with held experts appends (token-expert pairs that fell to
+        experts held here, summed over the routed layers; the fullest
+        held expert's pairs in one layer; held experts with a pair, summed
+        over the routed layers), so the counts ride the step's one d2h.
+        A mixed step adds its two passes' counts, the fullest experts'
+        too.  The chain key's ``prev_len`` stays the row bucket."""
+        return 3 if self.cfg.n_routed_experts else 0
 
     def _normalize_key(self, key) -> Tuple[int, int, int, bool]:
         if getattr(self, "_fresh_attention", None) is None \
@@ -839,7 +868,7 @@ class RaggedInferenceModel:
             return ([self.params, kv_aval] + batch_avals + pre_avals
                     + sample_avals(S + S_p))
         # chain: prev_tokens [S_prev] + gather_idx [S] replace token_ids
-        prev_s = key[5]
+        prev_s = key[5] + self.step_tail
         return ([self.params, kv_aval, sds((prev_s,), i32), sds((S,), i32)]
                 + batch_avals[1:] + sample_avals(S))
 
@@ -920,15 +949,22 @@ class RaggedInferenceModel:
         return logits
 
     def _forward_hidden(self, params, kv, token_ids, q_lens, start_pos,
-                        page_table, fresh: bool = False, cfg=None):
+                        page_table, fresh: bool = False, cfg=None,
+                        stats_out: Optional[list] = None):
         """The shared trunk of every step kind: embed -> layers -> final
         norm.  Returns (x [S, Q, E], new kv) — the step kinds differ
         only in which positions they unembed (last-token gather for the
         logits/sample kinds, EVERY position for the spec verify).
         ``cfg`` overrides the trunk geometry (the model-drafted spec
         path runs the DRAFT trunk — same family, fewer layers — through
-        the same embed/norm/attention modules); None = the target."""
+        the same embed/norm/attention modules); None = the target.
+        ``stats_out``: a list that receives the held-experts counts
+        (:attr:`step_tail`) of this pass, for the kinds that carry them."""
         cfg = cfg if cfg is not None else self.cfg
+        if cfg.latent_dim:
+            return self._forward_hidden_latent(
+                params, kv, token_ids, q_lens, start_pos, page_table,
+                fresh, cfg, stats_out)
         S, Q = token_ids.shape
         x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
                         token_ids)
@@ -961,10 +997,12 @@ class RaggedInferenceModel:
 
     # dslint: hot-path
     def _step_impl(self, params, kv, token_ids, q_lens, start_pos,
-                   page_table, fresh: bool = False):
+                   page_table, fresh: bool = False,
+                   stats_out: Optional[list] = None):
         cfg = self.cfg
         x, kv = self._forward_hidden(params, kv, token_ids, q_lens,
-                                     start_pos, page_table, fresh=fresh)
+                                     start_pos, page_table, fresh=fresh,
+                                     stats_out=stats_out)
         bias = params.get("lm_head_bias")  # phi family ships one
         if self._tp_quant_active():
             # int8 collective path mirrors the default unembed module
@@ -999,10 +1037,14 @@ class RaggedInferenceModel:
                           fresh: bool = False, greedy_only: bool = False):
         """Forward + on-device sampling in ONE traced program: the [S, V]
         logits never leave the device — only int32 tokens do."""
+        stats = [] if self.step_tail else None
         logits, kv = self._step_impl(params, kv, token_ids, q_lens,
-                                     start_pos, page_table, fresh=fresh)
+                                     start_pos, page_table, fresh=fresh,
+                                     stats_out=stats)
         tokens = self._sample_tokens(logits, rng, temps, top_ks, top_ps,
                                      row_uids, row_pos, greedy_only)
+        if stats:
+            tokens = jnp.concatenate([tokens, stats[0]])
         return tokens, kv
 
     # dslint: hot-path
@@ -1159,10 +1201,12 @@ class RaggedInferenceModel:
         (distinct sequences, so segment order is free), logits
         concatenated, sampled once — one compiled program, no
         cross-geometry padding."""
+        stats = [] if self.step_tail else None
         logits_d, kv = self._step_impl(params, kv, d_tok, d_ql, d_sp,
-                                       d_pt, fresh=False)
+                                       d_pt, fresh=False, stats_out=stats)
         logits_p, kv = self._step_impl(params, kv, p_tok, p_ql, p_sp,
-                                       p_pt, fresh=fresh_p)
+                                       p_pt, fresh=fresh_p,
+                                       stats_out=stats)
         logits = jnp.concatenate([logits_d, logits_p], axis=0)
         tokens = self._sample_tokens(logits, rng, temps, top_ks, top_ps,
                                      row_uids, row_pos, greedy_only)
@@ -1179,6 +1223,11 @@ class RaggedInferenceModel:
         if pad:
             tokens = jnp.concatenate(
                 [tokens, jnp.zeros((pad,), jnp.int32)])
+        if stats:
+            # each pass streams its experts and has a fullest one: all
+            # three counts are sums over the passes, so that fullest /
+            # mean stays a ratio of like sums
+            tokens = jnp.concatenate([tokens, stats[0] + stats[1]])
         return tokens, kv
 
     def _layer_body(self, x, kv, lp, layer, *, pos, sin, cos, q_lens,
@@ -1228,6 +1277,132 @@ class RaggedInferenceModel:
         if isinstance(mlp_out, tuple):                      # MoE aux dropped
             mlp_out = mlp_out[0]
         return x + mlp_out.astype(x.dtype), kv
+
+    def _forward_hidden_latent(self, params, kv, token_ids, q_lens,
+                               start_pos, page_table, fresh, cfg,
+                               stats_out):
+        """The trunk of the latent kind: a stack of dense layers, then a
+        stack of routed ones, each its own scan, the pool's layer index
+        running on through both.  The carry holds the held-experts
+        counts beside the activations and the pool."""
+        S, Q = token_ids.shape
+        x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
+                        token_ids)
+        pos = token_positions(start_pos, Q)
+        d = cfg.qk_rope_head_dim
+        freqs = cfg.rope_theta ** (
+            -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        angles = pos[..., None].astype(jnp.float32) * freqs
+        valid = (jnp.arange(Q, dtype=jnp.int32)[None, :]
+                 < q_lens[:, None]).reshape(-1)
+        body = functools.partial(
+            self._layer_body_latent, sin=jnp.sin(angles),
+            cos=jnp.cos(angles), q_lens=q_lens, start_pos=start_pos,
+            page_table=page_table, fresh=fresh, cfg=cfg, valid=valid)
+        carry = (x, kv, jnp.zeros((3,), jnp.int32))
+        base = 0
+        for name in ("dense_layers", "layers"):
+            if name not in params:
+                continue
+            stack = params[name]
+            n = jax.tree.leaves(stack)[0].shape[0]
+            layers = base + jnp.arange(n, dtype=jnp.int32)
+            experts = None
+            if "moe" in stack:
+                # the held experts' weights stay one stack, addressed by
+                # the kernel through the layer's index: scanned, each
+                # layer's 1.5 GB would be sliced out for the custom call
+                experts = stack["moe"]["experts"]
+                stack = dict(stack, moe={k: v for k, v in
+                                         stack["moe"].items()
+                                         if k != "experts"})
+            carry, _ = jax.lax.scan(
+                lambda c, xs, experts=experts, base=base: (
+                    body(*c, *xs, experts=experts, stack_base=base), None),
+                carry, (stack, layers))
+            base += n
+        x, kv, stats = carry
+        if stats_out is not None:
+            stats_out.append(stats)
+        return self._norm(params["final_norm"], x), kv
+
+    def _layer_body_latent(self, x, kv, stats, lp, layer, *, sin, cos,
+                           q_lens, start_pos, page_table, fresh, cfg,
+                           valid, experts=None, stack_base=0):
+        """One layer of the latent kind over ``x``, the pool and the
+        counts: sandwich norms, latent attention, then the dense MLP or
+        the routed layer's held share plus the shared expert."""
+        attn, kv = self._attend_latent(
+            self._norm(lp["norm1"], x), kv, lp["attn"], layer, sin=sin,
+            cos=cos, q_lens=q_lens, start_pos=start_pos,
+            page_table=page_table, fresh=fresh, cfg=cfg)
+        if cfg.sandwich_norm:
+            attn = self._norm(lp["norm1_post"], attn)
+        x = x + attn.astype(x.dtype)
+        h = self._norm(lp["norm2"], x)
+        if "moe" in lp:
+            from ...moe.held import held_experts_ffn, route_sigmoid_topk
+            mp = lp["moe"]
+            S, Q, E = h.shape
+            h2 = h.reshape(S * Q, E)
+            chosen, weights = route_sigmoid_topk(
+                h2, mp["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
+                cfg.norm_topk_prob)
+            out, counts = held_experts_ffn(
+                h2, chosen, weights, experts, cfg.experts_first,
+                layer=layer - stack_base, valid=valid)
+            out = out.reshape(S, Q, E)
+            if "shared" in mp:
+                out = out + T._mlp_block(cfg, mp["shared"], h)
+            stats = jnp.stack([stats[0] + jnp.sum(counts),
+                               jnp.maximum(stats[1], jnp.max(counts)),
+                               stats[2] + jnp.sum(counts > 0)])
+        else:
+            out = T._mlp_block(cfg, lp["mlp"], h)
+        if cfg.sandwich_norm:
+            out = self._norm(lp["norm2_post"], out.astype(x.dtype))
+        return x + out.astype(x.dtype), kv, stats
+
+    def _attend_latent(self, h, kv, ap, layer, *, sin, cos, q_lens,
+                       start_pos, page_table, fresh, cfg):
+        """Latent attention of ``h`` [S, Q, E]: writes the new tokens'
+        ``[c ; k_r]`` planes into ``pool[layer]`` and attends — expanded
+        (192-wide scores, 128-wide values) for a pure prefill, absorbed
+        over the paged planes otherwise.  Returns (output [S, Q, E],
+        pool)."""
+        dtype = cfg.dtype
+        dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        scale = float(dn + cfg.qk_rope_head_dim) ** -0.5
+        cq = self._norm(ap["q_norm"], jnp.einsum(
+            "sqe,er->sqr", h, ap["wq_a"].astype(dtype)))
+        q = jnp.einsum("sqr,rhd->sqhd", cq, ap["wq_b"].astype(dtype))
+        q_n, q_r = q[..., :dn], T.apply_rope(q[..., dn:], sin, cos)
+        ckr = jnp.einsum("sqe,er->sqr", h, ap["wkv_a"].astype(dtype))
+        c = self._norm(ap["kv_norm"], ckr[..., :rkv])
+        k_r = T.apply_rope(ckr[:, :, None, rkv:], sin, cos)[:, :, 0]
+        S, Q = h.shape[:2]
+        pad = kv.shape[-1] - rkv - k_r.shape[-1]
+        kv = latent_write(
+            kv, layer,
+            jnp.concatenate([c, k_r, jnp.zeros((S, Q, pad), dtype)], -1),
+            page_table, start_pos, q_lens)
+        w_k, w_v = ap["wkv_b_k"].astype(dtype), ap["wkv_b_v"].astype(dtype)
+        if fresh:
+            k_n = jnp.einsum("sqr,rhd->sqhd", c, w_k)
+            k = jnp.concatenate([k_n, jnp.broadcast_to(
+                k_r[:, :, None, :], k_n.shape[:3] + k_r.shape[-1:])], -1)
+            out = mla_fresh_attention(
+                jnp.concatenate([q_n, q_r], -1), k,
+                jnp.einsum("sqr,rhd->sqhd", c, w_v), sm_scale=scale)
+        else:
+            q_abs = jnp.concatenate(
+                [jnp.einsum("sqhd,rhd->sqhr", q_n, w_k), q_r,
+                 jnp.zeros(q_r.shape[:3] + (pad,), dtype)], -1)
+            ctx = mla_paged_attention(q_abs, kv, layer, page_table,
+                                      start_pos, q_lens, rank=rkv,
+                                      sm_scale=scale)
+            out = jnp.einsum("sqhr,rhd->sqhd", ctx, w_v)
+        return jnp.einsum("sqhd,hde->sqe", out, ap["wo"].astype(dtype)), kv
 
     def _per_shard_heads(self, fn, cfg, n_head_args: int,
                          pool_out: bool = False):

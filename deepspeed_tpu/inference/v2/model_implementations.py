@@ -1,7 +1,8 @@
 """Per-architecture inference-v2 model implementations.
 
 Reference: ``inference/v2/model_implementations/`` — one directory per
-arch (llama_v2, mistral, mixtral, falcon, opt, phi, qwen, qwen_v2), each
+arch (llama_v2, mistral, mixtral, falcon, opt, phi, qwen, qwen_v2; here
+also bloom, gpt_neox, gpt2, gptj and pangu_ultra_moe), each
 a ``DSTransformerModelBase`` subclass hard-coding that family's
 invariants (llama_v2/model.py:22, mistral/model.py, ...), chosen by
 ``engine_factory`` from the checkpoint's ``model_type``.
@@ -99,6 +100,30 @@ class BloomInferenceModel(RaggedInferenceModel):
         super().__init__(cfg, params, **kw)
 
 
+class PanguUltraMoEInferenceModel(RaggedInferenceModel):
+    """openPangu-Ultra-MoE (``models/pangu_moe.py``; no counterpart in the
+    reference): latent (MLA) attention over a latent page pool, sandwich
+    norms, leading dense layers, then routed layers of which this
+    process holds ``experts_held`` experts (one chip of an
+    expert-parallel group) beside the shared expert."""
+    MODEL_TYPES = ("pangu_ultra_moe",)
+
+    def __init__(self, cfg, params, **kw):
+        assert cfg.kv_lora_rank > 0 and cfg.qk_rope_head_dim > 0, \
+            "pangu_ultra_moe is a latent-attention family: kv_lora_rank"
+        assert cfg.norm == "rmsnorm" and cfg.pos_emb == "rope"
+        assert cfg.n_routed_experts >= cfg.moe_top_k >= 1
+        held = cfg.held_experts
+        assert 0 <= cfg.experts_first \
+            and cfg.experts_first + held <= cfg.n_routed_experts, \
+            "the experts held here lie outside the router's outputs"
+        assert 0 <= cfg.first_k_dense <= cfg.num_layers
+        super().__init__(cfg, params, **kw)
+        routed = self.params.get("layers")
+        assert routed is None or routed["moe"]["experts"]["wg"].shape[1] \
+            == held, "expert weights do not match experts_held"
+
+
 class GPTNeoXInferenceModel(RaggedInferenceModel):
     MODEL_TYPES = ("gpt_neox",)
 
@@ -114,7 +139,7 @@ class GPTJInferenceModel(RaggedInferenceModel):
 _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LlamaV2InferenceModel, MistralInferenceModel, MixtralInferenceModel,
     FalconInferenceModel, OPTInferenceModel, PhiInferenceModel,
-    Qwen2InferenceModel, BloomInferenceModel,
+    Qwen2InferenceModel, BloomInferenceModel, PanguUltraMoEInferenceModel,
     GPTNeoXInferenceModel, GPT2InferenceModel, GPTJInferenceModel,
 )
 

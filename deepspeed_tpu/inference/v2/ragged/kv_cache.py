@@ -6,7 +6,8 @@ TPU-native layout: ONE stacked array per cache group
 
     kv : [num_layers, num_pages + 1, 2, kv_heads, page_size, head_dim]
 
-and the whole cache is a single donated buffer across forwards.  Inside
+(a latent pool, ``planes=1``, holds ``[..., 1, 1, page_size, plane]``: one
+plane a token instead of K and V by head) and the whole cache is a single donated buffer across forwards.  Inside
 a step program it is the layer loop's carry, never a per-layer slice:
 the cache write and the attention kernels take the pool and a layer
 index and address ``pool[layer, page]`` themselves, so XLA updates the
@@ -53,12 +54,25 @@ class KVCacheConfig:
     #: "none" (fp pages at ``dtype``) or "int8" (block-scaled codes +
     #: fp32 scale per head_dim block)
     quantization: str = "none"
+    #: cache planes a token holds in a layer, which is what an attention
+    #: kind declares of its cache: 2 = K and V by head; 1 = one latent
+    #: plane ``[c ; k_r]`` (``ops/mla_attention.py``), held as a single
+    #: "head" of ``head_dim`` = the plane padded to whole 128-lane tiles
+    planes: int = 2
 
     def __post_init__(self):
         if self.quantization not in KV_QUANT_FORMATS:
             raise ValueError(
                 f"unknown kv quantization {self.quantization!r} "
                 f"(supported: {KV_QUANT_FORMATS})")
+        if self.latent and self.quantized:
+            raise ValueError(
+                "a latent page pool has no int8 page format yet: "
+                "kv_quantization must be 'none' for this model")
+
+    @property
+    def latent(self) -> bool:
+        return self.planes == 1
 
     @property
     def quantized(self) -> bool:
@@ -66,13 +80,13 @@ class KVCacheConfig:
 
     @property
     def bytes_per_page(self) -> int:
-        elems = (self.num_layers * self.page_size * 2 * self.kv_heads
-                 * self.head_dim)
+        elems = (self.num_layers * self.page_size * self.planes
+                 * self.kv_heads * self.head_dim)
         if self.quantized:
             # 1 byte per code + one fp32 scale per head_dim block: the
             # honest footprint, so pages_for_memory converts a byte
             # budget into ~2x resident pages (the ISSUE 16 lever)
-            scales = (self.num_layers * self.page_size * 2
+            scales = (self.num_layers * self.page_size * self.planes
                       * self.kv_heads)
             return elems + scales * 4
         itemsize = jnp.dtype(self.dtype).itemsize
@@ -85,8 +99,8 @@ class KVCacheConfig:
         """The device page-store shape (module docstring); the int8
         scale sidecar is this minus the trailing ``head_dim``."""
         return (self.num_layers if num_layers is None else num_layers,
-                self.num_pages + 1, 2, self.kv_heads, self.page_size,
-                self.head_dim)
+                self.num_pages + 1, self.planes, self.kv_heads,
+                self.page_size, self.head_dim)
 
 
 def pages_for_memory(cfg: KVCacheConfig, budget_bytes: int) -> int:

@@ -542,7 +542,7 @@ class StateManager:
         return {"num_layers": cfg.num_layers, "kv_heads": cfg.kv_heads,
                 "head_dim": cfg.head_dim, "page_size": cfg.page_size,
                 "dtype": np.dtype(cfg.dtype).name,
-                "quantization": cfg.quantization}
+                "quantization": cfg.quantization, "planes": cfg.planes}
 
     def _check_kv_meta(self, meta: dict) -> None:
         from ..snapshot import SnapshotError
@@ -550,6 +550,7 @@ class StateManager:
         # are fp by construction, so normalize instead of refusing
         kv = dict(meta["kv"])
         kv.setdefault("quantization", "none")
+        kv.setdefault("planes", 2)      # bundles older than latent pools
         ours = self._kv_meta()
         if kv != ours:
             raise SnapshotError(

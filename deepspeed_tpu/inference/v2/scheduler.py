@@ -286,6 +286,15 @@ class FastGenScheduler:
         self._rng = rng
         self.last_step_scheduled = 0
         self._step_shape = _IDLE_STEP
+        #: counts past the rows of a sampled-token vector (a model with
+        #: held experts: RaggedInferenceModel.step_tail), the last ones
+        #: drained, and the tokens of the step they belong to
+        self._token_tail = int(getattr(engine.model, "step_tail", 0))
+        self._moe_counts = None
+        self._moe_tokens = 0
+        #: (pairs here, fullest expert's pairs, experts touched) of the
+        #: last step drained, for a caller that checks them (None: none)
+        self.last_moe_counts = None
         #: one-way latch: a strict engine's sampling lattice, once seen,
         #: stays seen (avoids rescanning the step cache every step)
         self._fused_ready = False
@@ -831,6 +840,10 @@ class FastGenScheduler:
             # the host blocked on the device: not host work
             toks = np.asarray(inf.tokens_dev)   # dslint: d2h [S] int32
         serving_counters.record_d2h(toks.nbytes)
+        if self._token_tail:
+            # the held-experts counts ride the token vector's tail
+            self._moe_counts = self.last_moe_counts = \
+                toks[-self._token_tail:]
         out: Dict[int, int] = {}
         with trace_span("fastgen.drain.deliver"):
             for uid, row, req in inf.rows:
@@ -877,7 +890,8 @@ class FastGenScheduler:
         one = np.zeros(1, np.int32)
         if not self._strict_key_ok(
                 [u for u, _, _ in rows], [one] * len(rows),
-                ("chain", int(self._inflight.tokens_dev.shape[0]),
+                ("chain", int(self._inflight.tokens_dev.shape[0])
+                 - self._token_tail,
                  all(req.params.temperature <= 0.0
                      for _, _, req in rows))):
             return None
@@ -1457,6 +1471,15 @@ class FastGenScheduler:
                 ("budget", self._budget),
                 ("kv_pages_reserved", pages), ("kv_tokens_held", held)):
             span.set(key, value)
+        if self._moe_counts is not None:
+            # counts of the step drained inside this one (the step
+            # before), with that step's tokens as their divisor
+            span.set("moe_pairs_here", int(self._moe_counts[0]))
+            span.set("moe_expert_load_max", int(self._moe_counts[1]))
+            span.set("moe_experts_touched", int(self._moe_counts[2]))
+            span.set("moe_tokens", self._moe_tokens)
+            self._moe_counts = None
+        self._moe_tokens = tokens
 
     def _match_prefix_once(self, req: Request, adm: _Admission) -> None:
         """One-shot prefix-cache lookup before first admission: cached

@@ -75,6 +75,33 @@ class TransformerConfig:
     # expert weights and forward needs a routed mlp_fn
     moe_num_experts: int = 0
     moe_top_k: int = 2
+    # latent attention (MLA; models/pangu_moe.py): kv_lora_rank > 0
+    # selects it.  The cache holds one [c ; k_r] plane of kv_lora_rank +
+    # qk_rope_head_dim values a token; scores are qk_nope_head_dim +
+    # qk_rope_head_dim wide, values v_head_dim
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # x + N2(Attn(N1 x)), x + N4(FFN(N3 x)): a norm after each sub-layer
+    # too, inside the residual branch
+    sandwich_norm: bool = False
+    # a routed layer of another kind than moe_num_experts' (moe/held.py):
+    # sigmoid scores over n_routed_experts, the moe_top_k largest
+    # normalised and scaled, every expert a gated MLP of
+    # moe_intermediate_size beside n_shared_experts always-on ones.  This
+    # process holds experts [experts_first, experts_first + experts_held)
+    # (one chip of an expert-parallel group; 0 = all).  The first
+    # first_k_dense layers keep the dense MLP.
+    n_routed_experts: int = 0
+    experts_held: int = 0
+    experts_first: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    first_k_dense: int = 0
     tie_embeddings: bool = False
     use_bias: bool = False
     dropout: float = 0.0
@@ -111,12 +138,41 @@ class TransformerConfig:
     def dims_per_head(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    @property
+    def latent_dim(self) -> int:
+        """Values one token's latent cache plane holds (0: K/V heads)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_routed_experts
+
     def n_params(self) -> int:
+        """Matmul parameters held by this process (norm gains left out):
+        of a routed layer the experts held here, not the layer's."""
         e, f, l, v = self.hidden_size, self.intermediate_size, self.num_layers, self.vocab_size
         h, k, d = self.num_heads, self.kv_heads, self.dims_per_head
         attn = e * h * d + 2 * e * k * d + h * d * e
+        if self.kv_lora_rank:
+            attn = (e * self.q_lora_rank
+                    + self.q_lora_rank * h * (self.qk_nope_head_dim
+                                              + self.qk_rope_head_dim)
+                    + e * self.latent_dim
+                    + self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                               + self.v_head_dim)
+                    + h * self.v_head_dim * e)
         mlp = e * f * (3 if "gated" in self.activation else 2)
-        return l * (attn + mlp) + v * e * (1 if self.tie_embeddings else 2)
+        dense = l
+        routed = 0
+        if self.n_routed_experts:
+            dense = min(self.first_k_dense, l)
+            routed = (l - dense) * (
+                e * self.n_routed_experts
+                + 3 * e * self.moe_intermediate_size
+                * (self.held_experts + self.n_shared_experts))
+        return (l * attn + dense * mlp + routed
+                + v * e * (1 if self.tie_embeddings else 2))
 
 
 # ---------------------------------------------------------------------------
@@ -133,6 +189,10 @@ def _dense_init(rng, shape, fan_in, dtype=jnp.float32):
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     """Initialize (boxed) parameters; stacked over layers when scanning."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            "a latent-attention family builds its own parameters: "
+            "models/pangu_moe.py::PanguUltraMoEForCausalLM.init_params")
     e, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     h, k, d = cfg.num_heads, cfg.kv_heads, cfg.dims_per_head
     L = cfg.num_layers
@@ -649,6 +709,11 @@ def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
             mlp_fn=None, return_aux: bool = False) -> jax.Array:
     """Token ids [B,S] -> logits [B,S,V] (fp32); with ``return_aux``,
     returns (logits, accumulated MoE aux loss)."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            "the training forward pass has no latent-attention block yet: "
+            "this family is served (inference/v2) and held to the plain "
+            "reference models/pangu_moe_reference.py")
     params = meta.unbox(params) if _has_boxes(params) else params
     b, s = input_ids.shape
 

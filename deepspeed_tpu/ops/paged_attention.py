@@ -121,6 +121,14 @@ def dequantize_kv_blocks(codes: jax.Array, scale: jax.Array,
     return (codes.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
+def _stack_planes(k_new: jax.Array, v_new: Optional[jax.Array]) -> jax.Array:
+    """New cache rows ``[S, Q, planes, K, D]``: K and V, or the one plane
+    of a pool that holds one."""
+    if v_new is None:
+        return k_new[:, :, None]
+    return jnp.stack([k_new, v_new], axis=2)
+
+
 def token_positions(start_pos: jax.Array, q_len_max: int) -> jax.Array:
     """pos[s, i] = start_pos[s] + i  (int32, [S, Q])."""
     return start_pos[:, None] + jnp.arange(q_len_max, dtype=jnp.int32)[None, :]
@@ -137,7 +145,9 @@ def write_kv(kv: jax.Array, layer, k_new: jax.Array, v_new: jax.Array,
             the block the Pallas kernels DMA (the TPU lowering needs
             the last two block dims to be tile-aligned array dims)
     layer : int32 scalar (the layer loop's counter, or a constant)
-    k_new/v_new : [S, Q, K, D]
+    k_new/v_new : [S, Q, K, D]; ``v_new`` None for a pool of ONE plane a
+            token (``[L, num_pages+1, 1, K, page_size, D]``: the latent
+            cache of ``ops/mla_attention.py``)
     Returns the updated pool (functional; the pool is donated at the
     jit boundary and carried through the layer loop, and both forms
     below update it in place).  A quantized pool quantizes at append:
@@ -168,10 +178,12 @@ def write_kv(kv: jax.Array, layer, k_new: jax.Array, v_new: jax.Array,
     valid = jnp.arange(Q, dtype=jnp.int32)[None, :] < q_lens[:, None]
     pages = jnp.take_along_axis(page_table, pos // page_size, axis=1)
     pages = jnp.where(valid, pages, 0)                      # null page
-    kv_new = jnp.stack([k_new, v_new], axis=2).reshape(S * Q, 2, K, D)
-    # index arrays broadcast to the update's leading [S*Q, 2, K]
+    kv_new = _stack_planes(k_new, v_new)
+    planes = kv_new.shape[2]
+    kv_new = kv_new.reshape(S * Q, planes, K, D)
+    # index arrays broadcast to the update's leading [S*Q, planes, K]
     idx = (jnp.asarray(layer, jnp.int32), pages.reshape(-1, 1, 1),
-           jnp.arange(2, dtype=jnp.int32)[None, :, None],
+           jnp.arange(planes, dtype=jnp.int32)[None, :, None],
            jnp.arange(K, dtype=jnp.int32)[None, None, :],
            (pos % page_size).reshape(-1, 1, 1))
     if quantized:
@@ -201,7 +213,7 @@ def _kv_write_kernel(l_ref, pid_ref, off_ref, ql_ref, *refs, has_scale):
         new_ref, tile_ref, out_ref = refs
     s, j = pl.program_id(0), pl.program_id(1)
     off, q_lens = off_ref[s, j], ql_ref[s]
-    _, K, page, _ = tile_ref.shape
+    planes, K, page, _ = tile_ref.shape
     q_pad = new_ref.shape[2]                # 1: a decode row, no shift
     exact = new_ref.dtype in (jnp.bfloat16, jnp.int8)
     mm_dtype = jnp.bfloat16 if exact else jnp.float32
@@ -213,7 +225,7 @@ def _kv_write_kernel(l_ref, pid_ref, off_ref, ql_ref, *refs, has_scale):
     if q_pad > 1:
         pick = (jax.lax.broadcasted_iota(jnp.int32, (page, q_pad), 1)
                 == tok).astype(mm_dtype)                   # [page, Q]
-    for kv in range(2):
+    for kv in range(planes):
         for k in range(K):
             if q_pad == 1:
                 new = new_ref[kv, k]                       # [1, D]
@@ -232,7 +244,7 @@ def _kv_write_kernel(l_ref, pid_ref, off_ref, ql_ref, *refs, has_scale):
     if q_pad > 1:
         pick = (jax.lax.broadcasted_iota(jnp.int32, (q_pad, page), 0)
                 == tok).astype(jnp.float32)                # [Q, page]
-    for kv in range(2):
+    for kv in range(planes):
         if q_pad == 1:
             new = nscale_ref[kv]                           # [K, 1]
         else:
@@ -246,14 +258,17 @@ def _kv_write_kernel(l_ref, pid_ref, off_ref, ql_ref, *refs, has_scale):
 def kv_write_pages(kv: jax.Array, layer, k_new: jax.Array,
                    v_new: jax.Array, page_table: jax.Array,
                    start_pos: jax.Array, q_lens: jax.Array, *,
-                   interpret: bool = False) -> jax.Array:
+                   interpret: bool = False,
+                   name: str = "kv_write") -> jax.Array:
     """Pallas cache write, in place: a ``(row, touched page)`` grid that
     read-modify-writes only the page tiles a row's new tokens land in
     (:func:`_kv_write_kernel`), the pool aliased input -> output.  The
     page ids ride the BlockSpec index maps through scalar prefetch, as
     in the attention kernel.  Same contract as :func:`write_kv`; a
     padding token is written nowhere (a row with nothing to write
-    rewrites the null page with its own content)."""
+    rewrites the null page with its own content).  ``name`` is the
+    kernel's name in a trace less its ``_decode`` / ``_prefill`` ending
+    (a pool of another kind writes under a name of its own)."""
     S, Q, K, D = k_new.shape
     has_scale = isinstance(kv, KVPages)
     kv_arr = kv.payload if has_scale else kv
@@ -272,7 +287,8 @@ def kv_write_pages(kv: jax.Array, layer, k_new: jax.Array,
     # [S, Q, 2, K, D] -> per row [2, K, Q, D], Q padded to whole lanes
     # of the one-hot (activation-sized; the pool itself is not touched)
     q_pad = Q if Q == 1 else -(-Q // 128) * 128
-    kv_new = jnp.stack([k_new, v_new], axis=2)
+    kv_new = _stack_planes(k_new, v_new)
+    planes = kv_new.shape[2]
     if has_scale:
         kv_new, scales = quantize_kv_blocks(kv_new)
         scales = jnp.pad(scales.transpose(0, 2, 3, 1),      # [S,2,K,Q]
@@ -290,17 +306,18 @@ def kv_write_pages(kv: jax.Array, layer, k_new: jax.Array,
                             lambda s, j, l, pid, off, ql:
                             (l[0], pid[s, j]) + (0,) * len(block))
 
-    tiles = at_page(2, K, page_size, D)
+    tiles = at_page(planes, K, page_size, D)
     if has_scale:
-        rows = at_page(2, K, page_size)
-        in_specs = [at_row(2, K, q_pad, D), at_row(2, K, q_pad), tiles, rows]
+        rows = at_page(planes, K, page_size)
+        in_specs = [at_row(planes, K, q_pad, D), at_row(planes, K, q_pad),
+                    tiles, rows]
         inputs = (kv_new, scales, kv.payload, kv.scale)
         out_specs, out_shape = [tiles, rows], [
             jax.ShapeDtypeStruct(kv.payload.shape, kv.payload.dtype),
             jax.ShapeDtypeStruct(kv.scale.shape, kv.scale.dtype)]
         aliases = {6: 0, 7: 1}      # operands count the 4 prefetched
     else:
-        in_specs = [at_row(2, K, q_pad, D), tiles]
+        in_specs = [at_row(planes, K, q_pad, D), tiles]
         inputs = (kv_new, kv)
         out_specs = tiles
         out_shape = jax.ShapeDtypeStruct(kv.shape, kv.dtype)
@@ -316,7 +333,7 @@ def kv_write_pages(kv: jax.Array, layer, k_new: jax.Array,
             dimension_semantics=("arbitrary", "arbitrary")),
         # not ``paged_attention*``: that pattern is the attention
         # kernels' share and roofline (benchmark/metrics)
-        name="kv_write_decode" if Q == 1 else "kv_write_prefill",
+        name=name + ("_decode" if Q == 1 else "_prefill"),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), pids, offs,
       q_lens.astype(jnp.int32), *inputs)

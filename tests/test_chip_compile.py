@@ -318,3 +318,123 @@ def test_blockwise_quantization(chip):
                                           jnp.bfloat16, interpret=False),
         chip((rows, 512), jnp.int8), chip((rows,), jnp.float32),
         kernel="dequantize_blockwise")
+
+
+# -- the latent kind: MLA kernels, held experts, its step programs -----------
+
+MLA_HEADS, MLA_PLANE, MLA_RANK = 128, 640, 512      # openPangu-Ultra-MoE
+MLA_POOL = 8192
+
+
+def _latent_pool(chip, layers=5, pages=MLA_POOL):
+    return chip((layers, pages + 1, 1, 1, PAGE, MLA_PLANE), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("rows,pages", [(256, 32), (256, 8), (64, 64)])
+def test_mla_decode_kernel(chip, rows, pages):
+    from deepspeed_tpu.ops.mla_attention import mla_paged_attention
+    compile_for_chip(
+        lambda q, kv, pt, sp, ql: mla_paged_attention(
+            q, kv, jnp.int32(2), pt, sp, ql, rank=MLA_RANK, sm_scale=0.07,
+            use_kernel=True),
+        chip((rows, 1, MLA_HEADS, MLA_PLANE), jnp.bfloat16),
+        _latent_pool(chip), chip((rows, pages), jnp.int32),
+        chip((rows,), jnp.int32), chip((rows,), jnp.int32),
+        kernel="mla_attention_decode")
+
+
+@pytest.mark.parametrize("rows,q,kernel", [
+    (256, 1, "latent_write_decode"), (4, 128, "latent_write_prefill")])
+def test_latent_write_kernel(chip, rows, q, kernel):
+    from deepspeed_tpu.ops.mla_attention import latent_write
+    compile_for_chip(
+        lambda plane, kv, pt, sp, ql: latent_write(
+            kv, jnp.int32(1), plane, pt, sp, ql, use_kernel=True),
+        chip((rows, q, MLA_PLANE), jnp.bfloat16), _latent_pool(chip),
+        chip((rows, 32), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.int32), kernel=kernel)
+
+
+@pytest.mark.parametrize("rows,q", [(4, 128), (1, 1024)])
+def test_mla_prefill_kernel(chip, rows, q):
+    """192-wide scores, 128-wide values: two head sizes in one kernel."""
+    from deepspeed_tpu.ops.mla_attention import mla_fresh_attention
+    compile_for_chip(
+        lambda q_, k, v: mla_fresh_attention(q_, k, v, sm_scale=0.07,
+                                             use_kernel=True),
+        chip((rows, q, MLA_HEADS, 192), jnp.bfloat16),
+        chip((rows, q, MLA_HEADS, 192), jnp.bfloat16),
+        chip((rows, q, MLA_HEADS, 128), jnp.bfloat16),
+        kernel="mla_attention_prefill")
+
+
+@pytest.mark.parametrize("tokens", [256, 512])
+def test_moe_expert_ffn_kernel(chip, tokens):
+    """16 held experts of width 2048 over hidden 7680, the layers' stack
+    addressed by the layer's index."""
+    from deepspeed_tpu.moe.held import held_experts_ffn
+    stack = {n: chip((4, 16, 2048, 7680), jnp.bfloat16)
+             for n in ("wg", "wu", "wd")}
+    compile_for_chip(
+        lambda x, e, w, p, l: held_experts_ffn(x, e, w, p, 32, layer=l,
+                                               use_kernel=True),
+        chip((tokens, 7680), jnp.bfloat16), chip((tokens, 8), jnp.int32),
+        chip((tokens, 8), jnp.float32), stack, chip((), jnp.int32),
+        kernel="moe_expert_ffn")
+
+
+PANGU_STEP_KEYS = {
+    "chain": (256, 1, 32, False, "chain", 256, True),
+    "mixed": (256, 1, 64, False, "mixed", 4, 128, 8, True, True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(PANGU_STEP_KEYS))
+def test_pangu_step_program_moves_no_pool_and_no_expert_stack(
+        chip, monkeypatch, kind):
+    """The benchmark's cell at published widths (1 dense + 2 routed
+    layers here): inside a compiled step program neither the latent pool
+    nor a layer of the held experts' stack (503 MB: scanned, it would be
+    sliced out for the custom call) is copied, and the temporaries stay
+    under that too."""
+    import json
+    import os
+
+    from flax.core import meta
+
+    from benchmark.builders.serve_pangu_moe import source_of
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        PanguUltraMoEInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.pangu_moe import PanguUltraMoEForCausalLM
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "pangu-ultra-moe-serve-5l-ep16.json")) as f:
+        config = json.load(f)
+    layers = 3
+    model = PanguUltraMoEForCausalLM(
+        dict(source_of(config, False), num_hidden_layers=layers))
+    params = jax.eval_shape(lambda k: meta.unbox(model.init_params(k)),
+                            jax.random.key(0))
+    serve = PanguUltraMoEInferenceModel(model.cfg, params, kv_config=(
+        KVCacheConfig(num_layers=layers, kv_heads=1, head_dim=MLA_PLANE,
+                      planes=1, page_size=PAGE, num_pages=MLA_POOL)))
+    pool = _latent_pool(chip, layers)
+    key = PANGU_STEP_KEYS[kind]
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        serve._step_avals(key, pool))
+    compiled = jax.jit(serve._impl_of(key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    text = compiled.as_text()
+    for kernel in ("mla_attention_decode", "latent_write_decode",
+                   "moe_expert_ffn"):
+        assert any('custom_call_target="tpu_custom_call"' in line
+                   and kernel in line for line in text.splitlines()), kernel
+    expert_layer = 16 * 2048 * 7680 * 2
+    assert expert_layer < int(np.prod(pool.shape[1:])) * 2
+    assert pool_sized_movers(text, expert_layer) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < expert_layer
