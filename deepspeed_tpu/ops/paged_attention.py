@@ -23,13 +23,13 @@ slice taken out and stacked back costs two layer-sized copies a layer,
 and a scatter with window dims between its indexed dims a re-layout of
 the layer each way (PERF.md, PR 25).
 
-``paged_decode_attention`` is the Pallas ragged kernel: a
-``(slot, kv_head, page)`` grid whose BlockSpec index map reads the page
-table via scalar prefetch, so each KV page is DMA'd HBM->VMEM exactly
-once and the gathered ``[S, C, K, D]`` context never materializes in
-HBM.  Q=1 is the classic decode step; Q>1 rows carry prefill chunks
-with per-row causal limits, so ONE launch serves a fused mixed
-prefill+decode ragged batch (Ragged Paged Attention, arxiv 2604.15464).
+``paged_decode_attention`` is the Pallas ragged kernel: a ``(slot, block
+of kv heads, group of pages)`` grid whose index maps read the page table
+via scalar prefetch: each live KV page is DMA'd HBM->VMEM once, whole (K
+and V of all its heads are one contiguous block), the gathered context
+never materializes, and ``kernel_blocks`` reads a step's heads and page
+slots from the call's shapes.  Q=1 is the decode step; Q>1 rows carry
+chunks with per-row causal limits (Ragged Paged Attention, 2604.15464).
 The jnp formulation is the semantics ground truth and the CPU/CI path;
 ``paged_attention`` auto-selects.
 """
@@ -141,9 +141,9 @@ def write_kv(kv: jax.Array, layer, k_new: jax.Array, v_new: jax.Array,
     """Write new KV into the cache pages of layer ``layer`` of the pool.
 
     kv    : [L, num_pages+1, 2, K, page_size, D] (or :class:`KVPages`)
-            — per (layer, page, k/v, head) one ``[page_size, D]`` tile,
-            the block the Pallas kernels DMA (the TPU lowering needs
-            the last two block dims to be tile-aligned array dims)
+            — per (layer, page) one contiguous ``[2, K, page_size, D]``
+            block, which the Pallas kernels DMA whole or by head (the
+            lowering needs the last two block dims tile-aligned)
     layer : int32 scalar (the layer loop's counter, or a constant)
     k_new/v_new : [S, Q, K, D]; ``v_new`` None for a pool of ONE plane a
             token (``[L, num_pages+1, 1, K, page_size, D]``: the latent
@@ -414,26 +414,49 @@ def paged_attention(q: jax.Array, kv: jax.Array, layer,
 # Pallas ragged kernel (any Q: decode rows AND prefill-chunk rows)
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(l_ref, pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
-                   sm_scale, has_alibi, has_scale, window, q_len, groups):
-    """One (slot, kv_head, page) grid step of flash-style ragged attention.
+# (these sit below the write kernel: Mosaic keeps a kernel's source lines
+# in the program's text, so lines added above ``kv_write_pages`` change
+# every program that writes pages, the latent kind's too, and miss their
+# cached executables)
 
-    q_ref : [Q*G, D]       (this slot's queries for one kv head; row
+#: page slots one grid step of the ragged kernel attends over, at most
+#: (8 x 64 tokens): a page a step leaves the step's fixed cost larger
+#: than its work
+PAGES_PER_STEP = 8
+
+#: what :func:`kernel_blocks` lets a grid step take of the DEFAULT scoped
+#: VMEM limit (16 MiB on v5e; a kernel that asks for more than the default
+#: can hang the chip inside a mixed step program: PERF.md, PR 27), and the
+#: float32 score tiles it counts a step as holding, plain and under ALiBi
+VMEM_BUDGET = 14 * 2 ** 20
+SCORE_TILES = 1
+ALIBI_TILES = 2
+
+
+def _decode_kernel(l_ref, pt_ref, sp_ref, *refs, page_size, group, heads,
+                   sm_scale, has_alibi, has_scale, window, q_len, groups):
+    """One (slot, block of kv heads, group of pages) grid step of
+    flash-style ragged attention: ``heads`` KV heads of the row against
+    ``group`` whole pages at once, the heads a batched contraction.
+
+    q_ref : [heads, Q*G, D]  (this slot's queries by kv head; row
                             r = q_idx * G + g, so per-row causal limit
                             ctx_len_r = start_pos + r // G + 1)
-    k_ref/v_ref : [page_size, D]  (one cache page, DMA'd via the page
-                            table — see the index maps in the caller)
-    ks_ref/vs_ref : [K, page_size]  per-token block scales of every
-                            head of this page (row ``k`` is this grid
-                            step's) — present ONLY when ``has_scale``
-                            (quantized int8 pages, ISSUE 16).  A scale
-                            is constant over ``D``, so it factors out
-                            of both matmuls and is applied to the
-                            ``[rows, page]`` score / probability tile
-                            as a lane-major row: int8 codes feed the
-                            MXU directly and HBM traffic stays
-                            int8-sized
-    slopes_ref : [1, G]    per-q-head ALiBi slopes — present ONLY when
+    page refs (``group`` of them) : [2, heads, page_size, D]  one cache
+                            page each, the K and V planes of the block's
+                            heads in ONE fetch, DMA'd via the page table
+                            (see the index maps in the caller); a head
+                            attends the group's pages as one
+                            ``[group * page_size, D]`` context
+    scale refs (``group``) : [2, K, page_size]  per-token block scales of
+                            EVERY head of the page — present ONLY when
+                            ``has_scale`` (quantized int8 pages, ISSUE
+                            16).  A scale is constant over ``D``, so it
+                            factors out of both matmuls and is applied to
+                            the ``[rows, span]`` score / probability tile
+                            as a lane-major row: int8 codes feed the MXU
+                            directly and HBM traffic stays int8-sized
+    slopes_ref : [K, G]    per-q-head ALiBi slopes — present ONLY when
                             ``has_alibi`` (the kernel is specialized
                             statically so non-ALiBi models pay nothing)
     Q = 1 is the decode specialization; Q > 1 rows are prefill chunks
@@ -441,81 +464,90 @@ def _decode_kernel(l_ref, pt_ref, sp_ref, *refs, page_size, num_pages_per_seq,
     attention), so the causal mask is exactly the jnp path's
     ``ctx <= pos``.  Rows beyond a slot's q_len compute garbage that the
     caller's logits gather / KV null page ignore.
-    Scratch m/l/acc carry the running max / denominator / weighted sum
-    across the page axis (the innermost, sequential grid dim).
+    Scratch m/l/acc ``[heads, Q*G, 1 | D]`` carry the running max /
+    denominator / weighted sum across the page-group axis (the innermost,
+    sequential grid dim).
     """
-    rest = list(refs)
-    slopes_ref = rest.pop(0) if has_alibi else None
-    if has_scale:
-        q_ref, k_ref, ks_ref, v_ref, vs_ref = rest[:5]
-        o_ref, m_scr, l_scr, acc_scr = rest[5:]
-    else:
-        ks_ref = vs_ref = None
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    s = pl.program_id(0)
-    kh = pl.program_id(1)
-    p = pl.program_id(2)
-    rows = q_len * groups
+    refs = list(refs)
+    slopes_ref = refs.pop(0) if has_alibi else None
+    q_ref = refs.pop(0)
+    page_refs = [refs.pop(0) for _ in range(group)]
+    scale_refs = [refs.pop(0) for _ in range(group)] if has_scale else ()
+    o_ref, m_scr, l_scr, acc_scr = refs
+    s, kh, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    rows, span = q_len * groups, group * page_size
 
-    @pl.when(p == 0)
+    @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # the LAST query row sees the longest context; earlier rows mask
-    ctx_len_max = sp_ref[s] + q_len
-    page_valid = p * page_size < ctx_len_max
+    start = sp_ref[s]
+    live = j * span < start + q_len
     if window is not None:
-        # pages wholly below the FIRST row's window start contribute
-        # nothing: skip their DMA compute (the banded-decode analogue of
-        # the flash kernel's k_lo bound)
-        page_valid &= (p + 1) * page_size > sp_ref[s] + 1 - window
+        # groups wholly below the FIRST row's window start contribute
+        # nothing (the banded-decode analogue of the flash kernel's k_lo)
+        live &= (j + 1) * span > start + 1 - window
 
-    @pl.when(page_valid)
+    def side_by_side(tiles, axis):
+        return tiles[0] if group == 1 else jnp.concatenate(tiles, axis=axis)
+
+    def plane(i):
+        """The group's pages of K (0) or V (1): ``[heads, span, D]``."""
+        # int8 codes are exact in the query dtype; their per-token scale
+        # multiplies the score column instead of the [span, D] tile
+        return side_by_side([p[i] for p in page_refs], 1).astype(q_ref.dtype)
+
+    def by_head(of_head):
+        """``[heads, ...]`` of what ``of_head(k)`` yields for each of the
+        step's heads, ``k`` its index among all K (a leading dim is not
+        tiled: stacking along it moves nothing)."""
+        return jnp.stack([of_head(kh * heads + h) for h in range(heads)])
+
+    def scales(i):
+        """The pages' scale rows of the step's heads: ``[heads, 1, span]``."""
+        return by_head(lambda k: side_by_side(
+            [sc[i, pl.ds(k, 1), :] for sc in scale_refs], 1))
+
+    @pl.when(live)
     def _attend():
-        q = q_ref[:]                                   # [Q*G, D]
-        # int8 codes are exact in the query dtype; their per-token
-        # scale multiplies the score column instead of the [page, D] tile
-        k = k_ref[:].astype(q_ref.dtype)               # [page, D]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [Q*G, page]
+        q = q_ref[:]                                       # [heads, Q*G, D]
+        scores = jax.lax.dot_general(                      # [heads, Q*G, span]
+            q, plane(0), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale
         if has_scale:
-            scores = scores * ks_ref[pl.ds(kh, 1), :]  # [1, page] row
-        ctx = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
+            scores = scores * scales(0)
+        ctx = j * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
         if has_alibi:  # additive bias linear in the absolute key position
             # row r = q_idx * G + g: split the row dim so the per-head
             # slope is a plain broadcast (Mosaic lowers reshapes and
             # rank-2 iota; it rejects 1-D iota and in-kernel gathers)
-            page = scores.shape[1]
-            bias = (slopes_ref[0, :][None, :, None]
-                    * ctx.astype(jnp.float32).reshape(
-                        q_len, groups, page))
-            scores = scores + bias.reshape(rows, page)
+            pos = ctx.astype(jnp.float32).reshape(q_len, groups, span)
+            scores = scores + by_head(lambda k: (
+                slopes_ref[pl.ds(k, 1), :][:, :, None] * pos
+            ).reshape(rows, span))
         # per-row causal limit: row r is query index r // G
-        ctx_len = (sp_ref[s] + 1 + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 0) // groups)
+        ctx_len = start + 1 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, span), 0) // groups
         keep = ctx < ctx_len
         if window is not None:
             keep &= ctx >= ctx_len - window
-        scores = jnp.where(keep, scores, MASK_VALUE)
-        m_prev = m_scr[:]                              # [Q*G, 1]
-        l_prev = l_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        scores = jnp.where(keep[None], scores, MASK_VALUE)
+        m_prev = m_scr[:]                                  # [heads, Q*G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
         pexp = jnp.exp(scores - m_new)
         alpha = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
-        l_scr[:] = l_prev * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(pexp, axis=2, keepdims=True)
         if has_scale:
-            pexp = pexp * vs_ref[pl.ds(kh, 1), :]
+            pexp = pexp * scales(1)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            pexp.astype(q_ref.dtype), v_ref[:].astype(q_ref.dtype),
-            (((1,), (0,)), ((), ())),
+            pexp.astype(q.dtype), plane(1), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    @pl.when(p == num_pages_per_seq - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         o_ref[:] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
                     ).astype(o_ref.dtype)
@@ -529,6 +561,61 @@ def _flatten_context(pages: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return kv[0], kv[1]
 
 
+def _round_up(n: int, tile: int) -> int:
+    return -(-n // tile) * tile
+
+
+def kernel_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
+                  page_slots: int, q_itemsize: int, kv_itemsize: int,
+                  has_scale: bool = False,
+                  has_alibi: bool = False) -> Tuple[int, int]:
+    """``(heads, group)``: how many KV heads and how many page slots one
+    grid step of the ragged kernel holds, read from the call's shapes.
+
+    ``group`` is the largest of ``PAGES_PER_STEP``, 4, 2, 1 that divides
+    the page bucket, ``heads`` the largest of K, K/2, ... 1, such that
+    the step fits ``VMEM_BUDGET``.  A wide group comes before many heads:
+    a grid step's fixed cost is paid per group, and what does not fit as
+    heads of one step comes back as a grid dim.  A decode row or a
+    speculative row (a few dozen query rows) takes every head and 8
+    pages, a 128-token chunk of 4 query heads a KV head half the heads,
+    and the largest block ``MAX_KERNEL_Q_ROWS`` admits one head and one
+    page a step: the kernel's form before PR 28, which is also what a
+    shape that fits nowhere gets.
+
+    The account, fitted to what the chip's compiler takes for the
+    batched form (found by lowering ``vmem_limit_bytes`` until it
+    refuses; v5e, PERF.md PR 28) and erring high: the pages and their
+    scale rows, double-buffered; a query row of a head ``row_bytes``
+    (query and output blocks in two buffers, the float32 accumulator and
+    its quotient, the running max and denominator at a lane tile each);
+    and ``SCORE_TILES`` float32 ``[heads, rows, span]`` score tiles
+    (``ALIBI_TILES`` more under a bias).
+    """
+    rows = _round_up(rows, 8)
+    lanes = _round_up(head_dim, 128)
+    row_bytes = 2 * 2 * lanes * q_itemsize + 2 * lanes * 4 + 3 * 128 * 4
+    tiles = SCORE_TILES + (ALIBI_TILES if has_alibi else 0)
+    # one page slot of one head, K and V, in two buffers; the slot's
+    # scale rows come whole whatever the step's heads
+    slot_bytes = 2 * 2 * page_size * lanes * kv_itemsize
+    scale_bytes = (2 * 2 * _round_up(kv_heads, 8) * _round_up(page_size, 128)
+                   * 4 if has_scale else 0)
+    for group in (g for g in (PAGES_PER_STEP, 4, 2, 1)
+                  if page_slots % g == 0):
+        span = _round_up(group * page_size, 128)
+        heads = kv_heads
+        while True:
+            if (group * (heads * slot_bytes + scale_bytes)
+                    + heads * rows * (row_bytes + tiles * span * 4)
+                    <= VMEM_BUDGET):
+                return heads, group
+            if heads % 2:
+                break
+            heads //= 2
+    return 1, 1
+
+
 def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
                            page_table: jax.Array, start_pos: jax.Array, *,
                            sm_scale: float | None = None,
@@ -539,18 +626,31 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
 
     TPU-native counterpart of the reference's blocked_flash atoms
     (``inference/v2/kernels/ragged_ops/atom_builder/`` splits sequences
-    into KV blocks per thread block; here the page IS the block and the
-    page table drives the BlockSpec index map through scalar prefetch).
-    Q = 1 is the classic decode step; Q > 1 rows carry prefill chunks
-    with per-row causal limits, so one launch serves a fused mixed
-    prefill+decode ragged batch (the single-kernel serving formulation
-    of Ragged Paged Attention, arxiv 2604.15464).
+    into KV blocks per thread block; here a group of whole pages IS the
+    block and the page table drives the BlockSpec index maps through
+    scalar prefetch).  Q = 1 is the classic decode step; Q > 1 rows carry
+    prefill chunks with per-row causal limits, so one launch serves a
+    fused mixed prefill+decode ragged batch (the single-kernel serving
+    formulation of Ragged Paged Attention, arxiv 2604.15464).
 
     q: [S, Q, H, D]; kv: [L, num_pages+1, 2, K, page_size, D] with
     ``layer`` an int32 scalar (a third scalar-prefetch operand: the
     index maps address ``pool[layer, page]``, so no layer is ever
     sliced out of the pool); page_table: [S, P]; start_pos: [S].
     Returns [S, Q, H, D].
+
+    Grid ``(S, K // heads, P // group)`` with ``heads`` and ``group``
+    from :func:`kernel_blocks`.  In the pool's layout one page's K and V
+    of all heads are one contiguous block, so a page is ONE fetch; the
+    pool is passed once per page slot of a group, each with its own
+    index map, so the pipeline fetches a group's pages side by side.  A
+    group wholly past the row's context (or under its window) is skipped,
+    and costs a grid step and no bytes where its slots hold the null
+    page, as the engine's tables do past a row's pages and under its
+    window (``SequenceDescriptor.page_table``, ``evict_pages_below``):
+    consecutive steps that name the same block fetch nothing.  A table
+    that held real pages there would be attended as correctly, and
+    fetched for nothing.
     """
     S, Q, H, D = q.shape
     has_scale = isinstance(kv, KVPages)
@@ -559,61 +659,61 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
     G = H // K
     P_pages = page_table.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
+    has_alibi = alibi_slopes is not None
+    heads, group = kernel_blocks(Q * G, K, D, page_size, P_pages,
+                                 q.dtype.itemsize, kv_arr.dtype.itemsize,
+                                 has_scale, has_alibi)
 
     # fold GQA per kv head: [S, K, Q*G, D], row r = q_idx * G + g
     qg = q.reshape(S, Q, K, G, D).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(S, K, Q * G, D)
-    has_alibi = alibi_slopes is not None
 
-    grid = (S, K, P_pages)
-    # index maps receive (s, k, p, *scalar_prefetch_refs)
-    q_spec = pl.BlockSpec((None, None, Q * G, D),
-                          lambda s, k, p, l, pt, sp: (s, k, 0, 0))
-    tile = (None, None, None, None, page_size, D)
-    k_spec = pl.BlockSpec(
-        tile, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 0, k, 0, 0))
-    v_spec = pl.BlockSpec(
-        tile, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 1, k, 0, 0))
-    o_spec = pl.BlockSpec((None, None, Q * G, D),
-                          lambda s, k, p, l, pt, sp: (s, k, 0, 0))
+    # index maps receive (s, kh, j, *scalar_prefetch_refs)
+    row_spec = pl.BlockSpec((None, heads, Q * G, D),
+                            lambda s, kh, j, l, pt, sp: (s, kh, 0, 0))
 
-    if has_scale:
+    def page_spec(i):
+        return pl.BlockSpec(
+            (None, None, 2, heads, page_size, D),
+            lambda s, kh, j, l, pt, sp:
+            (l[0], pt[s, j * group + i], 0, kh, 0, 0))
+
+    def scale_spec(i):
         # scale sidecar [L, P+1, 2, K, page] -> the page's full
-        # [K, page] tile (last two block dims = array dims, which the
-        # lowering requires); the kernel row-slices its own head.  Same
-        # page-table indirection as k/v: the BlockSpec DMA is the gather
-        rows = (None, None, None, K, page_size)
-        ks_spec = pl.BlockSpec(
-            rows, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 0, 0, 0))
-        vs_spec = pl.BlockSpec(
-            rows, lambda s, k, p, l, pt, sp: (l[0], pt[s, p], 1, 0, 0))
-        in_specs = [q_spec, k_spec, ks_spec, v_spec, vs_spec]
-        inputs = (qg, kv_arr, kv.scale, kv_arr, kv.scale)
-    else:
-        in_specs = [q_spec, k_spec, v_spec]
-        inputs = (qg, kv_arr, kv_arr)
+        # [2, K, page] block (last two block dims = array dims, which the
+        # lowering requires); the kernel row-slices a head's.  Same
+        # page-table indirection as the payload: the BlockSpec DMA is
+        # the gather
+        return pl.BlockSpec(
+            (None, None, 2, K, page_size),
+            lambda s, kh, j, l, pt, sp: (l[0], pt[s, j * group + i], 0, 0, 0))
+
+    in_specs = [row_spec] + [page_spec(i) for i in range(group)]
+    inputs = (qg,) + (kv_arr,) * group
+    if has_scale:
+        in_specs += [scale_spec(i) for i in range(group)]
+        inputs += (kv.scale,) * group
     if has_alibi:
-        slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(K, 1, G)
-        sl_spec = pl.BlockSpec((None, 1, G),
-                               lambda s, k, p, l, pt, sp: (k, 0, 0))
-        in_specs = [sl_spec] + in_specs
-        inputs = (slopes,) + inputs
+        in_specs = [pl.BlockSpec((K, G), lambda s, kh, j, l, pt, sp: (0, 0))
+                    ] + in_specs
+        inputs = (jnp.asarray(alibi_slopes, jnp.float32).reshape(K, G),
+                  ) + inputs
 
     kernel = functools.partial(
-        _decode_kernel, page_size=page_size, num_pages_per_seq=P_pages,
+        _decode_kernel, page_size=page_size, group=group, heads=heads,
         sm_scale=scale, has_alibi=has_alibi, has_scale=has_scale,
         window=window, q_len=Q, groups=G)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
+            grid=(S, K // heads, P_pages // group),
             in_specs=in_specs,
-            out_specs=o_spec,
+            out_specs=row_spec,
             scratch_shapes=[
-                pltpu.VMEM((Q * G, 1), jnp.float32),
-                pltpu.VMEM((Q * G, 1), jnp.float32),
-                pltpu.VMEM((Q * G, D), jnp.float32),
+                pltpu.VMEM((heads, Q * G, 1), jnp.float32),
+                pltpu.VMEM((heads, Q * G, 1), jnp.float32),
+                pltpu.VMEM((heads, Q * G, D), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, K, Q * G, D), q.dtype),

@@ -31,6 +31,7 @@ from deepspeed_tpu.ops.fused_optimizer import (fused_adamw_flat,
                                                fused_lion_flat)
 from deepspeed_tpu.ops.normalization import layernorm, rmsnorm
 from deepspeed_tpu.ops.paged_attention import (MAX_KERNEL_Q_ROWS, KVPages,
+                                               kernel_blocks,
                                                paged_attention, write_kv)
 from deepspeed_tpu.ops.quantization import (dequantize_blockwise,
                                             quantize_blockwise)
@@ -116,23 +117,47 @@ def _pool(chip, int8, layers=2, pages=POOL):
             if int8 else chip(shape, jnp.bfloat16))
 
 
-def _paged_args(chip, slots, rows, int8):
+def _paged_args(chip, slots, rows, int8, page_slots=SEQ // PAGE):
     """(q, pool, layer, page table, start_pos, q_lens)"""
     return (chip((slots, rows, HEADS, HEAD_DIM), jnp.bfloat16),
             _pool(chip, int8), chip((), jnp.int32),
-            chip((slots, SEQ // PAGE), jnp.int32),
+            chip((slots, page_slots), jnp.int32),
             chip((slots,), jnp.int32), chip((slots,), jnp.int32))
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
-@pytest.mark.parametrize("slots,rows", [(64, 1), (8, 128), (2, 512)],
-                         ids=["decode", "mixed", "chunk"])
-def test_paged_attention(chip, slots, rows, window, int8):
+@pytest.mark.parametrize("slots,rows,page_slots,heads", [
+    (64, 1, 32, 8), (64, 1, 8, 8), (64, 1, 64, 8), (8, 128, 32, 4),
+    (2, 512, 32, 1),
+    # K x Q*G on each side of where a step stops holding every head
+    (8, 32, 32, 8), (8, 96, 32, 4)],
+    ids=["decode", "decode-8", "decode-64", "mixed", "chunk", "all-heads",
+         "half-the-heads"])
+def test_paged_attention(chip, slots, rows, page_slots, heads, window, int8):
+    assert kernel_blocks(rows * (HEADS // KV_HEADS), KV_HEADS, HEAD_DIM,
+                         PAGE, page_slots, 2, 1 if int8 else 2,
+                         int8) == (heads, 8)
     compile_for_chip(
         lambda q, kv, layer, table, start, lens: paged_attention(
             q, kv, layer, table, start, lens, use_kernel=True,
             window=window, interpret=False),
+        *_paged_args(chip, slots, rows, int8, page_slots),
+        kernel="paged_attention")
+
+
+@pytest.mark.parametrize("slots,rows,int8", [
+    (64, 1, False), (16, 5, True), (8, 128, False), (8, 128, True)],
+    ids=["decode", "spec-int8", "mixed", "mixed-int8"])
+def test_paged_attention_under_alibi(chip, slots, rows, int8):
+    """The bias costs a step score tiles of its own: ``kernel_blocks``
+    counts them, so a chunk holds fewer heads a step than without."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+    slopes = alibi_slopes(HEADS)
+    compile_for_chip(
+        lambda q, kv, layer, table, start, lens: paged_attention(
+            q, kv, layer, table, start, lens, use_kernel=True,
+            alibi_slopes=slopes, interpret=False),
         *_paged_args(chip, slots, rows, int8), kernel="paged_attention")
 
 

@@ -234,8 +234,8 @@ class TestPagedAttention:
 
     def test_cache_layout_is_one_page_tile_per_head(self):
         """[L, P+1, 2, K, page, D]: token t of a sequence lands in its page
-        at [layer, page_id, k/v, :, t % page] — the last two dims are the
-        (page, D) tile the Pallas kernels DMA per (page, head)."""
+        at [layer, page_id, k/v, :, t % page] — a page's [2, K, page, D]
+        block is contiguous, and the Pallas kernels DMA it whole."""
         (q, k_new, v_new, kv, table, start, q_lens,
          _, _, page) = self._setup()
         S, Q, K, D = k_new.shape
@@ -281,6 +281,150 @@ class TestPagedAttention:
                                     interpret=True)
         np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense),
                                    rtol=2e-5, atol=2e-5)
+
+
+def _ragged_inputs(Q, K, G, D, page, P, ctxs, fmt, seed=0):
+    """A two-layer pool of noise (the null page and layer 0 included), a
+    query block and a page table whose row ``s`` holds ``ceil(ctxs[s] /
+    page)`` distinct pages and the null page in every slot after them;
+    row ``s`` attends ``ctxs[s]`` tokens, its ``Q`` new ones the last."""
+    rng = np.random.default_rng(seed)
+    S = len(ctxs)
+    dtype = jnp.bfloat16 if fmt == "bf16" else jnp.float32
+    pool = jnp.asarray(rng.standard_normal(
+        (2, S * P + 1, 2, K, page, D)), dtype)
+    if fmt == "int8":
+        pool = pa.KVPages(*pa.quantize_kv_blocks(pool))
+    table = np.zeros((S, P), np.int32)
+    free = iter(rng.permutation(S * P) + 1)
+    for s, ctx in enumerate(ctxs):
+        assert Q <= ctx <= P * page, (Q, ctx, P * page)
+        for slot in range(-(-ctx // page)):
+            table[s, slot] = next(free)
+    q = jnp.asarray(rng.standard_normal((S, Q, K * G, D)), dtype)
+    start = jnp.asarray([ctx - Q for ctx in ctxs], jnp.int32)
+    return q, pool, jnp.asarray(table), start
+
+
+def _row_contexts(Q, page, P):
+    """Contexts of one batch: ending mid-page, exactly on a page boundary,
+    inside the first page (or as near as ``Q`` allows) and in the
+    bucket's last page."""
+    cap = P * page
+    mid = min(max(Q + page // 2 + 1, (P // 2) * page + page // 2 + 1),
+              cap - 1)
+    edge = max(-(-Q // page), P // 2, 1) * page
+    return (mid, edge, max(Q, 3), cap - 2 if cap - 2 >= Q else cap)
+
+
+def _assert_kernel_matches_gather(q, pool, table, start, fmt, **kw):
+    lens = jnp.full(start.shape, q.shape[1], jnp.int32)
+    want = pa.paged_attention(q, pool, LAYER, table, start, lens,
+                              use_kernel=False, **kw)
+    got = pa.paged_attention(q, pool, LAYER, table, start, lens,
+                             use_kernel=True, interpret=True, **kw)
+    # bfloat16 rounds the probabilities before the second matmul in both
+    # forms, normalised in one and not in the other
+    tol = 2e-2 if fmt == "bf16" else 5e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def _blocks_of(q, pool, P, alibi=False):
+    """``kernel_blocks`` as ``paged_decode_attention`` calls it."""
+    arr = pool.payload if isinstance(pool, pa.KVPages) else pool
+    K, page, D = arr.shape[3:]
+    return pa.kernel_blocks(q.shape[1] * (q.shape[2] // K), K, D, page, P,
+                            q.dtype.itemsize, arr.dtype.itemsize,
+                            isinstance(pool, pa.KVPages), alibi)
+
+
+class TestRaggedKernelParity:
+    """The ragged Pallas kernel (interpret mode) against the dense-gather
+    ``jnp`` form, over the block shapes ``kernel_blocks`` picks (PR 28):
+    all KV heads of a group of pages a grid step, fewer heads for a large
+    query block, nothing for a slot past a row's context."""
+
+    @pytest.mark.parametrize("alibi", [False, True], ids=["rope", "alibi"])
+    @pytest.mark.parametrize("window", [False, True], ids=["full", "window"])
+    @pytest.mark.parametrize("fmt", ["bf16", "int8"])
+    @pytest.mark.parametrize("Q", [1, 5, 128])
+    def test_rows_formats_masks_and_bias(self, Q, fmt, window, alibi):
+        from deepspeed_tpu.models.transformer import alibi_slopes
+        K, G, D, P = 2, 2, 32, 8
+        page = 32 if Q > 8 else 8
+        ctxs = _row_contexts(Q, page, P)
+        q, pool, table, start = _ragged_inputs(Q, K, G, D, page, P, ctxs, fmt)
+        assert _blocks_of(q, pool, P, alibi) == (K, 8)
+        _assert_kernel_matches_gather(
+            q, pool, table, start, fmt,
+            # shorter than three of the four contexts, and than a group
+            window=(page * 2 + 3) if window else None,
+            alibi_slopes=alibi_slopes(K * G) if alibi else None)
+
+    @pytest.mark.parametrize("fmt", ["bf16", "int8"])
+    @pytest.mark.parametrize("Q", [1, 5])
+    @pytest.mark.parametrize("P,group", [(1, 1), (2, 2), (4, 4), (8, 8),
+                                         (5, 1), (12, 4), (16, 8)])
+    def test_page_buckets(self, P, group, Q, fmt):
+        K, G, D, page = 2, 2, 32, 8
+        ctxs = _row_contexts(Q, page, P)
+        q, pool, table, start = _ragged_inputs(Q, K, G, D, page, P, ctxs, fmt)
+        assert _blocks_of(q, pool, P) == (K, group)
+        _assert_kernel_matches_gather(q, pool, table, start, fmt)
+
+    @pytest.mark.parametrize("fmt", ["bf16", "int8"])
+    @pytest.mark.parametrize("Q", [1, 5])
+    @pytest.mark.parametrize("K,G", [(1, 4), (8, 2), (8, 1)],
+                             ids=["multi-query", "grouped", "one-to-one"])
+    def test_head_layouts(self, K, G, Q, fmt):
+        D, page, P = 32, 8, 4
+        ctxs = _row_contexts(Q, page, P)
+        q, pool, table, start = _ragged_inputs(Q, K, G, D, page, P, ctxs, fmt)
+        assert _blocks_of(q, pool, P) == (K, 4)
+        _assert_kernel_matches_gather(q, pool, table, start, fmt,
+                                      window=page + 3)
+
+    @pytest.mark.parametrize("fmt,window,alibi,heads", [
+        ("bf16", False, False, 4), ("int8", True, False, 2),
+        ("int8", False, True, 2)])
+    def test_fewer_heads_a_step(self, fmt, window, alibi, heads):
+        """A 128-token chunk at Mistral's head geometry: the K x Q*G rows
+        do not fit beside 8 pages, so the head axis comes back as a grid
+        dim and a step holds half the heads (fewer for the float32
+        queries of the int8 cases, fewer again under a bias)."""
+        from deepspeed_tpu.models.transformer import alibi_slopes
+        Q, K, G, D, page, P = 128, 8, 4, 128, 64, 8
+        q, pool, table, start = _ragged_inputs(
+            Q, K, G, D, page, P, (128 + 37, 510), fmt)
+        assert _blocks_of(q, pool, P, alibi) == (heads, 8)
+        _assert_kernel_matches_gather(
+            q, pool, table, start, fmt, window=150 if window else None,
+            alibi_slopes=alibi_slopes(K * G) if alibi else None)
+
+    @pytest.mark.parametrize("rows,K,D,page,P,int8,blocks", [
+        (4, 8, 128, 64, 8, False, (8, 8)),        # the cell's decode step
+        (4, 8, 128, 64, 64, True, (8, 8)),
+        (4, 8, 128, 64, 24, False, (8, 8)),
+        (4, 8, 128, 64, 12, False, (8, 4)),
+        (4, 8, 128, 64, 6, False, (8, 2)),
+        (4, 8, 128, 64, 5, False, (8, 1)),
+        (20, 8, 128, 64, 8, False, (8, 8)),       # speculative rows, Q = 5
+        (128, 8, 128, 64, 8, False, (8, 8)),      # a 32-token chunk
+        (512, 8, 128, 64, 8, False, (4, 8)),      # a 128-token chunk
+        (1024, 8, 128, 64, 8, False, (2, 8)),
+        (2048, 8, 128, 64, 8, False, (1, 8)),
+        (4096, 8, 128, 64, 8, False, (1, 1)),     # MAX_KERNEL_Q_ROWS
+        (32, 1, 128, 64, 8, False, (1, 8)),       # multi-query
+        (1, 32, 128, 64, 8, False, (16, 8)),      # K = H: 32 heads' pages
+        (2, 8, 256, 64, 8, False, (8, 8)),
+        (4, 8, 128, 256, 8, False, (4, 8)),       # 1 MiB pages
+    ])
+    def test_blocks_follow_the_shapes(self, rows, K, D, page, P, int8,
+                                      blocks):
+        assert pa.kernel_blocks(rows, K, D, page, P, 2, 1 if int8 else 2,
+                                int8) == blocks
 
 
 def _placed_by_hand(pool, layer, k_new, v_new, table, start, q_lens):
