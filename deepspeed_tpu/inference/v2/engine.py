@@ -29,9 +29,13 @@ from ...telemetry import metrics as tm
 from ...telemetry import trace_span
 from ...utils.comms_logging import serving_counters
 from .config import RaggedInferenceEngineConfig
+from .lattice import (POWER_LATTICE, BucketLattice, enumerate_lattice_keys,
+                      resolve_lattice)
 from .model import RaggedInferenceModel
 from .ragged import (KVCacheConfig, StateManager, build_batch,
                      pages_for_memory, placeholder)
+from .step_key import (LATTICE_KINDS, STEP_KINDS, StepKey,
+                       lattice_kind_of)
 
 
 class SchedulingResult(enum.Enum):
@@ -48,15 +52,6 @@ class SchedulingError(RuntimeError):
         self.result = result
 
 
-#: key classes a role-shrunk lattice filters on (ISSUE 13): "prefill"
-#: = Q>1 logits/sample buckets (incl. fresh variants), "decode" = Q==1
-#: logits/sample buckets, "chain" = the double-buffer continuation
-#: family, "spec" = the speculative families (verification buckets
-#: plus the ISSUE 17 model-drafted draft_spec/draft_fill programs —
-#: speculation is a decode-pool activity, so they class together)
-LATTICE_KINDS = ("prefill", "decode", "chain", "spec")
-
-
 def _validate_kinds(kinds: Sequence[str]) -> None:
     unknown = set(kinds) - set(LATTICE_KINDS)
     if unknown:
@@ -65,35 +60,17 @@ def _validate_kinds(kinds: Sequence[str]) -> None:
             f"(expected a subset of {LATTICE_KINDS})")
 
 
-def lattice_kind_of(key: Tuple) -> str:
-    """Which :data:`LATTICE_KINDS` class one step-cache key belongs
-    to — the shared classifier behind ``lattice_keys(kinds=...)``."""
-    kind = key[4] if len(key) > 4 else "logits"
-    if kind == "chain":
-        return "chain"
-    if kind in ("spec", "draft_spec", "draft_fill"):
-        return "spec"
-    if kind == "mixed":
-        # a mixed two-segment key carries a prefill segment — only a
-        # role that prefills can ever form one (mined-lattice artifacts
-        # may carry observed mixed keys; the power enumeration never
-        # emits them)
-        return "prefill"
-    return "prefill" if key[1] > 1 else "decode"
-
-
 def lattice_keys(max_prompt: int, max_new_tokens: int,
                  max_concurrency: int, page_size: int,
                  max_ragged_batch_size: int, has_fresh: bool,
                  sampling: bool, spec_max_draft: int = 0,
                  kinds: Optional[Sequence[str]] = None,
                  draft: bool = False) -> List[Tuple]:
-    """Every (S, Q, P[, fresh[, kind, ...]]) step-cache key the default
-    power-of-two bucket lattice contains for this geometry — the ONE
+    """Every step key (``docs/DESIGN.md``, "The step program's key") the
+    default power-of-two lattice contains for this geometry — the ONE
     enumeration shared by ``InferenceEngineV2.precompile`` (which
     compiles it) and ``tools/analyze_trace.py`` (which reports observed
-    traffic's coverage against it), so the two can't drift (ROADMAP
-    item 5's single lattice authority).
+    traffic's coverage against it), so the two can't drift.
 
     ``kinds`` (ISSUE 13) restricts the enumeration to a subset of
     :data:`LATTICE_KINDS` so a disaggregated pool compiles only its
@@ -108,26 +85,21 @@ def lattice_keys(max_prompt: int, max_new_tokens: int,
     ``lattice.enumerate_lattice_keys`` — shared with mined
     :class:`~..lattice.BucketLattice` artifacts (ISSUE 14), so the
     power-of-two default and an auto lattice can't drift."""
-    from .lattice import enumerate_lattice_keys
-    from .ragged.batch import MIN_PAGES, MIN_SLOTS, _bucket
     if kinds is not None:
         _validate_kinds(kinds)
+    lat = POWER_LATTICE
 
-    s_vals, q_vals, p_vals = [], [1], []
-    s = _bucket(1, MIN_SLOTS)
-    while s <= _bucket(max_concurrency, MIN_SLOTS):
-        s_vals.append(s)
-        s *= 2
-    q = 2
-    while q <= _bucket(max_prompt):
-        q_vals.append(q)
-        q *= 2
+    def doubling(lo: int, hi: int) -> List[int]:
+        vals = []
+        while lo <= hi:
+            vals.append(lo)
+            lo *= 2
+        return vals
+
+    s_vals = doubling(lat.bucket_s(1), lat.bucket_s(max_concurrency))
+    q_vals = [1] + doubling(2, lat.bucket_q(max_prompt))
     total = max_prompt + max_new_tokens  # decode growth headroom
-    max_pages_needed = _bucket(-(-total // page_size), MIN_PAGES)
-    p = _bucket(1, MIN_PAGES)
-    while p <= max_pages_needed:
-        p_vals.append(p)
-        p *= 2
+    p_vals = doubling(lat.bucket_p(1), lat.bucket_p(-(-total // page_size)))
 
     # speculative verification buckets (ISSUE 10): decode rows
     # dispatched as ragged Q = 1 + spec_max_draft segments.  One Q
@@ -135,7 +107,7 @@ def lattice_keys(max_prompt: int, max_new_tokens: int,
     # same S*Q <= batch-size skip rule applies — a spec superbucket
     # the scheduler can't form under strict shapes drops to the
     # normal decode path, exactly like the mixed-step keys.
-    spec_q = _bucket(1 + spec_max_draft) if spec_max_draft > 0 else 0
+    spec_q = lat.bucket_q(1 + spec_max_draft) if spec_max_draft > 0 else 0
     keys = enumerate_lattice_keys(
         s_vals, q_vals, p_vals, page_size=page_size,
         max_ragged_batch_size=max_ragged_batch_size,
@@ -285,18 +257,15 @@ class InferenceEngineV2:
         # digest-validated against THIS engine's geometry (a mismatch
         # raises LatticeError — never a silent cold lattice).  Fixed at
         # build: it shapes every compiled program the engine serves.
-        from .lattice import resolve_lattice
-        self._lattice = resolve_lattice(
+        self._lattice: BucketLattice = resolve_lattice(
             getattr(self._config.serving, "lattice", "") or "",
             page_size=kv_cfg.page_size,
             vocab_size=int(getattr(model.cfg, "vocab_size", 0)),
             max_ragged_batch_size=(
                 self._config.state_manager.max_ragged_batch_size))
-        prior = getattr(model, "lattice", None)
+        prior = model.lattice
         if getattr(model, "_lattice_bound", False) and (
-                (prior.digest if prior is not None else None)
-                != (self._lattice.digest
-                    if self._lattice is not None else None)):
+                prior.digest != self._lattice.digest):
             # the lattice is a MODEL attribute (the mixed-step token
             # pad is traced against it): two engines over one model
             # with different lattice configs would desync the earlier
@@ -310,9 +279,8 @@ class InferenceEngineV2:
                 "mixed-step pad follows the NEWEST engine's lattice; "
                 "engines sharing one model must share one lattice "
                 "config",
-                prior.digest if prior is not None else "<power>",
-                self._lattice.digest if self._lattice is not None
-                else "<power>")
+                prior.digest or "<power>",
+                self._lattice.digest or "<power>")
         model.lattice = self._lattice
         model._lattice_bound = True
         # persistent compile cache: a second process compiling the
@@ -657,7 +625,7 @@ class InferenceEngineV2:
             sv = self._config.serving
             spec_max_draft = (int(getattr(sv, "spec_max_draft", 0) or 0)
                               if getattr(sv, "speculative", False) else 0)
-        if self._lattice is not None:
+        if self._lattice.mined:
             # mined auto lattice (ISSUE 14): the artifact's key set IS
             # the precompile target — filtered to what THIS engine can
             # actually form/serve
@@ -670,8 +638,7 @@ class InferenceEngineV2:
                                  or sm.max_ragged_sequence_count),
                 page_size=self._model.kv_config.page_size,
                 max_ragged_batch_size=sm.max_ragged_batch_size,
-                has_fresh=getattr(self._model, "_fresh_attention",
-                                  None) is not None,
+                has_fresh=self._model.has_fresh,
                 sampling=sampling, spec_max_draft=spec_max_draft,
                 draft=(self._draft_enabled and sampling
                        and spec_max_draft > 0))
@@ -687,30 +654,37 @@ class InferenceEngineV2:
                         "pools' programs defeats disaggregation's "
                         "compile-time win)")
         for key in keys:
-            self._model.precompile_step(key, self._kv_aval_for(key))
+            self._precompile_key(key)
         if strict:
             self._model.strict_shapes = True
         return keys
 
-    def _kv_aval_for(self, key: Tuple):
-        """The KV argument one step-cache key's program takes: the
-        target pool, the draft pool (draft_fill), or the donated
-        (target, draft) pair (draft_spec)."""
-        kind = key[4] if len(key) > 4 else "logits"
+    def _precompile_key(self, key: StepKey) -> None:
+        self._model.precompile_step(
+            key, self._pool(STEP_KINDS[key.kind].trunk))
+
+    def _pool(self, trunk: str):
+        """The KV operand of a program over ``trunk`` (``STEP_KINDS``):
+        the target pool, the draft pool, or the (target, draft) pair."""
         kv = self._state.kv_cache.data
-        if kind == "draft_spec":
-            if self._draft_kv is None:
-                raise ValueError(
-                    f"step key {key} needs the draft pool but this "
-                    "engine was built without spec_drafter=model/auto")
-            return (kv, self._draft_kv)
-        if kind == "draft_fill":
-            if self._draft_kv is None:
-                raise ValueError(
-                    f"step key {key} needs the draft pool but this "
-                    "engine was built without spec_drafter=model/auto")
-            return self._draft_kv
-        return kv
+        if trunk == "target":
+            return kv
+        if self._draft_kv is None:
+            raise ValueError(
+                f"a step program over the {trunk!r} trunk needs the draft "
+                "pool but this engine was built without "
+                "spec_drafter=model/auto")
+        return self._draft_kv if trunk == "draft" else (kv, self._draft_kv)
+
+    def _put_pool(self, trunk: str, pool) -> None:
+        """Put back what a program over ``trunk`` returned for the
+        pool(s) it was given, which it donated."""
+        if trunk == "target":
+            self._state.kv_cache.data = pool
+        elif trunk == "draft":
+            self._draft_kv = pool
+        else:
+            self._state.kv_cache.data, self._draft_kv = pool
 
     def _auto_lattice_keys(self, sampling: bool, spec_max_draft: int,
                            kinds: Optional[Sequence[str]],
@@ -725,12 +699,11 @@ class InferenceEngineV2:
         would spend precompile wall + cache disk on programs that can
         never dispatch."""
         sm = self._config.state_manager
-        has_fresh = getattr(self._model, "_fresh_attention",
-                            None) is not None
+        has_fresh = self._model.has_fresh
         lat = self._lattice
-        keys: List[Tuple] = []
+        keys: List[StepKey] = []
         for key in lat.keys:
-            kind = key[4] if len(key) > 4 else "logits"
+            kind = key.kind
             if not sampling and kind != "logits":
                 continue
             if strict and kind == "mixed":
@@ -741,7 +714,7 @@ class InferenceEngineV2:
                 # the spec bucket this engine will form: Q = the
                 # lattice bucket of 1 + spec_max_draft, not whatever
                 # draft depth the trace ran with
-                if key[1] != lat.bucket_q(1 + spec_max_draft):
+                if key.Q != lat.bucket_q(1 + spec_max_draft):
                     continue
             if kind in ("draft_spec", "draft_fill"):
                 # artifact mined on a model-drafted engine serving an
@@ -750,30 +723,23 @@ class InferenceEngineV2:
                 if not (self._draft_enabled and spec_max_draft > 0):
                     continue
                 if (kind == "draft_spec"
-                        and key[1] != lat.bucket_q(1 + spec_max_draft)):
+                        and key.Q != lat.bucket_q(1 + spec_max_draft)):
                     continue
-            if not has_fresh and (bool(key[3]) or (
-                    kind == "mixed" and bool(key[8]))):
+            if not has_fresh and key.fresh:
                 continue    # fresh variants normalize to False anyway
-            if kind == "mixed":
-                if key[0] * 1 + key[6] * key[5] \
-                        > 2 * sm.max_ragged_batch_size:
-                    continue
-            elif key[0] * key[1] > sm.max_ragged_batch_size:
+            if key.padded_tokens > sm.max_ragged_batch_size * (
+                    2 if kind == "mixed" else 1):
                 continue
             keys.append(key)
-            if has_fresh and not lat.has_fresh:
+            if has_fresh and not lat.has_fresh and not key.fresh and (
+                    kind == "mixed"
+                    or (key.Q > 1 and kind in ("logits", "sample"))):
                 # artifact mined on a fresh-less model (ALiBi capture)
                 # serving a fresh-capable engine: live all-new prefills
                 # WILL form the True variant — twin it so coverage
-                # holds instead of recompiling on path (mixed keys
-                # twin on the prefill segment's fresh_p at index 8)
-                if (key[1] > 1 and kind in ("logits", "sample")
-                        and not bool(key[3])):
-                    keys.append((key[0], key[1], key[2], True)
-                                + key[4:])
-                elif kind == "mixed" and not bool(key[8]):
-                    keys.append(key[:8] + (True,) + key[9:])
+                # holds instead of recompiling on path (a mixed key
+                # twins on its prefill segment)
+                keys.append(key.with_fresh(True))
         if sampling and spec_max_draft > 0:
             # a lattice mined from a spec-free trace still serves an
             # engine with speculation on: generate the spec family
@@ -791,10 +757,11 @@ class InferenceEngineV2:
                     if P * page < spec_q:
                         continue
                     for greedy in (True, False):
-                        for kk in (("spec", greedy),) + (
-                                (("draft_spec", greedy),)
+                        for kk in ("spec",) + (
+                                ("draft_spec",)
                                 if self._draft_enabled else ()):
-                            key = (S, spec_q, P, False) + kk
+                            key = StepKey.form(
+                                kk, [(S, spec_q, P, False)], greedy)
                             if key not in have:
                                 keys.append(key)
                                 have.add(key)
@@ -806,7 +773,7 @@ class InferenceEngineV2:
                         for P in lat.p_tops:
                             if P * page < Q:
                                 continue
-                            key = (S, Q, P, False, "draft_fill")
+                            key = StepKey.draft_fill((S, Q, P, False))
                             if key not in have:
                                 keys.append(key)
                                 have.add(key)
@@ -830,7 +797,8 @@ class InferenceEngineV2:
         return keys
 
     # -- compiled-key manifests (ISSUE 14: warm-born replicas) ---------------
-    def compiled_keys(self, dispatched_only: bool = True) -> List[Tuple]:
+    def compiled_keys(self, dispatched_only: bool = True
+                      ) -> List[StepKey]:
         """The compiled-key manifest a snapshot bundle / replica
         factory carries so a fresh engine can precompile EXACTLY the
         programs traffic actually needs — against a warm persistent
@@ -855,16 +823,24 @@ class InferenceEngineV2:
         block a restore.  Returns the number of keys now compiled."""
         done = 0
         for k in keys:
-            key = tuple(k)
             try:
-                self._model.precompile_step(key, self._kv_aval_for(key))
+                self._precompile_key(StepKey.parse(k))
                 done += 1
             except Exception as e:  # noqa: BLE001 — per-key isolation
                 from ...utils.logging import logger
                 logger.warning(
                     "precompile_keys: skipping manifest key %r "
-                    "(%s: %s)", key, type(e).__name__, e)
+                    "(%s: %s)", k, type(e).__name__, e)
         return done
+
+    def has_program(self, key: Sequence) -> bool:
+        """Whether the step program of ``key`` is formed (the strict
+        scheduler's gate on a predicted key)."""
+        return key in self._model._step_cache
+
+    def has_kind(self, *kinds: str) -> bool:
+        """Whether a program of one of ``kinds`` is formed."""
+        return any(k.kind in kinds for k in list(self._model._step_cache))
 
     def _free_device_memory(self) -> Optional[int]:
         """Free HBM the KV pool can use, or None when the backend doesn't
@@ -990,25 +966,86 @@ class InferenceEngineV2:
                     self._state.evict_window(sd, window)
 
     def _build_batch(self, descs, tokens, h2d_tokens: bool = True,
-                     min_q: int = 1):
+                     min_q: int = 1, start_pos=None):
         """Pack one segment; h2d bytes accrue here, program dispatches
-        are recorded by the caller (a mixed step feeds TWO segments to
+        are recorded by ``_dispatch`` (a mixed step feeds TWO segments to
         ONE program).  ``h2d_tokens=False`` for chained steps, whose
         token ids never leave the device (the placeholder token_ids
         array is not an input of the chained program); ``min_q`` floors
-        the Q bucket (spec steps pad to the one spec bucket)."""
+        the Q bucket (spec steps pad to the one spec bucket);
+        ``start_pos`` as ``build_batch`` takes it."""
         with trace_span("engine.build_batch"):
             batch = build_batch(
                 descs, tokens, self._model.kv_config.page_size,
-                fresh_supported=getattr(self._model, "_fresh_attention",
-                                        None) is not None,
-                min_q=min_q, lattice=self._lattice)
+                self._lattice,
+                fresh_supported=self._model.has_fresh, min_q=min_q,
+                start_pos=start_pos)
             nbytes = (batch.q_lens.nbytes + batch.start_pos.nbytes
                       + batch.page_table.nbytes)
             if h2d_tokens:
                 nbytes += batch.token_ids.nbytes
             serving_counters.record_h2d(nbytes)
             return batch
+
+    def _prev_len(self, prev_tokens) -> int:
+        """A chain key's ``prev_len`` for the previous step's token
+        vector: its length without the model's ``step_tail``."""
+        return int(prev_tokens.shape[0]) - self._model.step_tail
+
+    # dslint: hot-path
+    def _dispatch(self, kind: str, batches, row_params=None, rng=None,
+                  row_pos=None, prev=None):
+        """Run ONE step program of ``kind`` over ``batches`` (its
+        segments, built and in order): the one place a program is
+        dispatched.  For a sampling kind, ``row_params`` (one
+        SamplingParams a live row) and ``row_pos`` (each row's
+        generation position, read under keyed sampling) follow the
+        segments' row order and are padded here to the slot buckets;
+        padding rows are greedy and sample garbage nobody reads.  A
+        keyed engine stepped without positions passes no keyed rows on,
+        so that the model's guard raises instead of this padding
+        silently pinning every draw to position 0.  ``prev``: a chain
+        step's ``(prev_tokens, gather index padded to the slot
+        bucket)``.  Counts the program and the h2d bytes of what is
+        made here, puts the returned pool(s) back, and returns the
+        program's output (None where the kind has none)."""
+        model = self._model
+        row = STEP_KINDS[kind]
+        sampling, greedy, h2d = None, False, 0
+        if row.samples:
+            n = sum(b.num_slots for b in batches)
+            temps = np.zeros(n, np.float32)
+            top_ks = np.zeros(n, np.int32)
+            top_ps = np.ones(n, np.float32)
+            uids = pos = None
+            if model.keyed_sampling and row_pos is not None:
+                uids, pos = np.zeros(n, np.int32), np.zeros(n, np.int32)
+            at = off = 0
+            for b in batches:
+                live = len(b.uids)
+                seg, params = slice(off, off + live), row_params[at:at + live]
+                temps[seg] = [p.temperature for p in params]
+                top_ks[seg] = [p.top_k for p in params]
+                top_ps[seg] = [p.top_p for p in params]
+                if uids is not None:
+                    uids[seg] = np.fromiter(b.uids, np.int64,
+                                            live).astype(np.int32)
+                    pos[seg] = row_pos[at:at + live]
+                at, off = at + live, off + b.num_slots
+            greedy = not bool((temps > 0.0).any())
+            sampling = (rng, temps, top_ks, top_ps, uids, pos)
+            h2d = temps.nbytes + top_ks.nbytes + top_ps.nbytes
+        if prev is not None:
+            h2d += prev[1].nbytes
+        key = StepKey.form(kind, [b.shape_key for b in batches], greedy,
+                           self._prev_len(prev[0]) if prev else 0)
+        serving_counters.record_program(h2d_bytes=h2d)
+        pool = self._pool(row.trunk)
+        with trace_span("engine.dispatch"):
+            out = model.run_step(key, pool, batches, sampling, prev)
+            out, pool = out if row.output else (None, out)
+            self._put_pool(row.trunk, pool)
+        return out
 
     def put(self, batch_uids: Sequence[int],
             batch_tokens: Sequence[np.ndarray],
@@ -1028,11 +1065,7 @@ class InferenceEngineV2:
             # slot order == input order, so no host re-assembly
             batch = self._build_batch(
                 descs, [np.asarray(t) for t in batch_tokens])
-            serving_counters.record_program()
-            with trace_span("engine.dispatch"):
-                logits, self._state.kv_cache.data = self._model.forward(
-                    batch, self._state.kv_cache.data)
-            logits = logits[:len(batch_uids)]
+            logits = self._dispatch("logits", [batch])[:len(batch_uids)]
             self._commit_batch(descs)
             serving_counters.record_logits_exposed(int(logits.size) * 4)
             return logits
@@ -1049,13 +1082,10 @@ class InferenceEngineV2:
         logits_rows: List[Optional[jax.Array]] = [None] * len(batch_uids)
         for q_bucket in sorted(groups):
             idxs = groups[q_bucket]
-            sub_descs = [descs[i] for i in idxs]
-            sub_tokens = [np.asarray(batch_tokens[i]) for i in idxs]
-            batch = self._build_batch(sub_descs, sub_tokens)
-            serving_counters.record_program()
-            with trace_span("engine.dispatch"):
-                logits, self._state.kv_cache.data = self._model.forward(
-                    batch, self._state.kv_cache.data)
+            batch = self._build_batch(
+                [descs[i] for i in idxs],
+                [np.asarray(batch_tokens[i]) for i in idxs])
+            logits = self._dispatch("logits", [batch])
             for row, i in enumerate(idxs):
                 logits_rows[i] = logits[row]
 
@@ -1066,18 +1096,17 @@ class InferenceEngineV2:
         return out
 
     def predict_step_key(self, batch_uids: Sequence[int],
-                         batch_tokens: Sequence, suffix: tuple = (),
-                         min_q: int = 1) -> tuple:
-        """The step-cache key a single-geometry dispatch of this batch
+                         batch_tokens: Sequence, kind: str = "logits",
+                         greedy: bool = False, prev_tokens=None,
+                         min_q: int = 1) -> StepKey:
+        """The key a single-segment dispatch of ``kind`` over this batch
         will form, BEFORE admission — the strict-shapes scheduler gates
-        fused dispatch on lattice membership of this prediction.  Must
-        mirror ``build_batch``'s bucketing exactly (which is why it
-        lives here, next to the live path, not in the scheduler).
-        ``suffix`` extends the (S, Q, P, fresh) base: ``("sample",
-        greedy)``, ``("chain", prev_len, greedy)`` or ``("spec",
-        greedy)`` (the latter with ``min_q`` = the spec bucket floor,
-        and fresh pinned False — spec rows always have history)."""
-        from .ragged.batch import MIN_PAGES, MIN_SLOTS, _bucket
+        fused dispatch on this prediction having a program.  Same
+        ``lattice.shape`` and same constructor as the live path
+        (``build_batch``, ``_dispatch``); what is predicted is only what
+        admission will do to the page counts.  ``greedy`` for a sampling
+        kind, ``prev_tokens`` (the in-flight token vector) for a chain
+        step, ``min_q`` = the spec bucket floor for the spec kinds."""
         model = self._model
         page = model.kv_config.page_size
         pages, all_new = [], True
@@ -1088,50 +1117,15 @@ class InferenceEngineV2:
             pages.append(max(cap, -(-(seen + len(toks)) // page)))
             if seen:
                 all_new = False
-        if self._lattice is not None:
-            S = self._lattice.bucket_s(len(batch_uids))
-            Q = self._lattice.bucket_q(
-                max(max(len(t) for t in batch_tokens), min_q))
-            P = self._lattice.bucket_p(max(pages))
-        else:
-            S = _bucket(len(batch_uids), MIN_SLOTS)
-            Q = _bucket(max(max(len(t) for t in batch_tokens), min_q))
-            P = _bucket(max(pages), MIN_PAGES)
-        fresh = (all_new and Q > 1
-                 and suffix[:1] not in (("spec",), ("draft_spec",),
-                                        ("draft_fill",))
-                 and getattr(model, "_fresh_attention", None) is not None)
-        return (S, Q, P, fresh) + suffix
+        S, Q, P = self._lattice.shape(
+            len(batch_uids), max(len(t) for t in batch_tokens),
+            max(pages), min_q)
+        fresh = all_new and Q > 1 and model.has_fresh
+        return StepKey.form(
+            kind, [(S, Q, P, fresh)], greedy,
+            self._prev_len(prev_tokens) if prev_tokens is not None else 0)
 
     # -- fused forward+sampling steps (serving_optimization hot path) -------
-    def _pad_sample_params(self, row_params, S):
-        """Per-row sampling params padded to the slot bucket.  Padding
-        rows are greedy (argmax over garbage logits nobody reads)."""
-        temps = np.zeros(S, np.float32)
-        top_ks = np.zeros(S, np.int32)
-        top_ps = np.ones(S, np.float32)
-        for i, p in enumerate(row_params):
-            temps[i] = p.temperature
-            top_ks[i] = p.top_k
-            top_ps[i] = p.top_p
-        return temps, top_ks, top_ps
-
-    def _pad_keyed(self, batch_uids, row_pos, S):
-        """Keyed-sampling inputs padded to the slot bucket: [S] int32
-        uid + generation-position arrays (padding rows sample garbage
-        nobody reads, like the padded sampling params).  (None, None)
-        when the mode is off — and ALSO when a keyed engine was
-        stepped without positions, so the model's guard raises instead
-        of this padding silently pinning every draw to position 0."""
-        if not self._model.keyed_sampling or row_pos is None:
-            return None, None
-        uids = np.zeros(S, np.int32)
-        pos = np.zeros(S, np.int32)
-        uids[:len(batch_uids)] = np.asarray(batch_uids, np.int64) \
-            .astype(np.int32)
-        pos[:len(row_pos)] = np.asarray(row_pos, np.int32)
-        return uids, pos
-
     def step_sample(self, batch_uids: Sequence[int],
                     batch_tokens: Sequence[np.ndarray],
                     row_params: Sequence, rng: jax.Array,
@@ -1145,8 +1139,9 @@ class InferenceEngineV2:
         likes (JAX async dispatch makes this the double-buffer overlap
         point).  A step mixing decode rows with prefill chunks runs as
         ONE program over TWO segment geometries ([S_d, 1] + [S_p, Q]) so
-        decode rows never pad to the chunk width.  ``row_params`` is one
-        SamplingParams per row; rows mid-prefill sample garbage the
+        decode rows never pad to the chunk width (a [S, Qmax] superbucket
+        would compute Qmax positions per decode row).  ``row_params`` is
+        one SamplingParams per row; rows mid-prefill sample garbage the
         caller ignores."""
         descs = self._admit_batch(batch_uids, batch_tokens, do_checks)
         dec_idx = [i for i, t in enumerate(batch_tokens) if len(t) == 1]
@@ -1155,19 +1150,8 @@ class InferenceEngineV2:
         if not dec_idx or not pre_idx:       # single-geometry step
             batch = self._build_batch(
                 descs, [np.asarray(t) for t in batch_tokens])
-            temps, top_ks, top_ps = self._pad_sample_params(
-                row_params, batch.num_slots)
-            kuids, kpos = self._pad_keyed(batch_uids, row_pos,
-                                          batch.num_slots)
-            greedy_only = not bool((temps > 0.0).any())
-            serving_counters.record_program(
-                h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-            with trace_span("engine.dispatch"):
-                tokens, self._state.kv_cache.data = \
-                    self._model.sample_step(
-                        batch, self._state.kv_cache.data, rng, temps,
-                        top_ks, top_ps, greedy_only,
-                        row_uids=kuids, row_pos=kpos)
+            tokens = self._dispatch("sample", [batch], row_params, rng,
+                                    row_pos)
             self._commit_batch(descs)
             return tokens, list(range(len(batch_uids)))
 
@@ -1177,37 +1161,17 @@ class InferenceEngineV2:
         pre = self._build_batch([descs[i] for i in pre_idx],
                                 [np.asarray(batch_tokens[i])
                                  for i in pre_idx])
-        # tokens come back [S_d + S_p] in segment order
+        # tokens come back [S_d + S_p] in segment order, and the sampling
+        # rows go in that order
+        order = dec_idx + pre_idx
         row_of_input = [0] * len(batch_uids)
-        ordered_params = [None] * (dec.num_slots + pre.num_slots)
         for row, i in enumerate(dec_idx):
             row_of_input[i] = row
-            ordered_params[row] = row_params[i]
         for row, i in enumerate(pre_idx):
             row_of_input[i] = dec.num_slots + row
-            ordered_params[dec.num_slots + row] = row_params[i]
-        from .sampling import SamplingParams as _SP
-        ordered_params = [p if p is not None else _SP()
-                          for p in ordered_params]
-        temps, top_ks, top_ps = self._pad_sample_params(
-            ordered_params, len(ordered_params))
-        # keyed inputs follow the same segment order as the params
-        kuids = kpos = None
-        if self._model.keyed_sampling and row_pos is not None:
-            kuids = np.zeros(len(ordered_params), np.int32)
-            kpos = np.zeros(len(ordered_params), np.int32)
-            for i, row in enumerate(row_of_input):
-                kuids[row] = np.int64(batch_uids[i]).astype(np.int32)
-                kpos[row] = int(row_pos[i])
-        greedy_only = not bool((temps > 0.0).any())
-        serving_counters.record_program(
-            h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-        with trace_span("engine.dispatch"):
-            tokens, self._state.kv_cache.data = \
-                self._model.sample_step_mixed(
-                    dec, pre, self._state.kv_cache.data, rng, temps,
-                    top_ks, top_ps, greedy_only,
-                    row_uids=kuids, row_pos=kpos)
+        tokens = self._dispatch(
+            "mixed", [dec, pre], [row_params[i] for i in order], rng,
+            None if row_pos is None else [row_pos[i] for i in order])
         self._commit_batch(descs)
         return tokens, row_of_input
 
@@ -1229,21 +1193,10 @@ class InferenceEngineV2:
                                   do_checks=False)
         batch = self._build_batch(descs, placeholder_toks,
                                   h2d_tokens=False)
-        temps, top_ks, top_ps = self._pad_sample_params(
-            row_params, batch.num_slots)
-        greedy_only = not bool((temps > 0.0).any())
         gather = np.zeros(batch.num_slots, np.int32)
         gather[:len(batch_uids)] = np.asarray(gather_idx, np.int32)
-        kuids, kpos = self._pad_keyed(batch_uids, row_pos,
-                                      batch.num_slots)
-        serving_counters.record_program(
-            h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes
-            + gather.nbytes)
-        with trace_span("engine.dispatch"):
-            tokens, self._state.kv_cache.data = self._model.chained_step(
-                batch, self._state.kv_cache.data, prev_tokens, gather,
-                rng, temps, top_ks, top_ps, greedy_only,
-                row_uids=kuids, row_pos=kpos)
+        tokens = self._dispatch("chain", [batch], row_params, rng, row_pos,
+                                prev=(prev_tokens, gather))
         self._commit_batch(descs)
         return tokens
 
@@ -1255,13 +1208,20 @@ class InferenceEngineV2:
         """Speculative verification step (ISSUE 10): each row's tokens
         are ``[last_committed, draft_1..draft_k]`` (k may differ per
         row, k = 0 allowed) and ONE compiled program verifies every
-        draft through the ragged Q>1 path, returning a device [S, 2]
-        int32 array of (accepted_count, corrected_token) per row — the
-        only d2h of the step.  The commit is DEFERRED: the caller reads
-        the accepts and then calls :meth:`commit_spec` with each row's
-        committed token count (a step may commit 0..Q tokens per row,
-        which the one-shot ``post_forward`` bookkeeping can't express).
-        """
+        draft through the ragged Q>1 path (per-row causal limits),
+        returning a device [S, 2] int32 array of (accepted_count,
+        corrected_token) per row — the only d2h of the step (the host
+        knows the drafts it proposed, so counts + one correction
+        reconstruct the committed block).  The commit is DEFERRED: the
+        caller reads the accepts and then calls :meth:`commit_spec` with
+        each row's committed token count (a step may commit 0..Q tokens
+        per row, which the one-shot ``post_forward`` bookkeeping can't
+        express)."""
+        return self._step_verify("spec", batch_uids, batch_tokens,
+                                 row_params, rng, min_q, row_pos)
+
+    def _step_verify(self, kind, batch_uids, batch_tokens, row_params,
+                     rng, min_q, row_pos):
         descs = self._admit_batch(batch_uids, batch_tokens,
                                   do_checks=False)
         # pad every spec dispatch to the ONE spec Q bucket (min_q =
@@ -1269,18 +1229,7 @@ class InferenceEngineV2:
         # not form a smaller off-lattice key
         batch = self._build_batch(
             descs, [np.asarray(t) for t in batch_tokens], min_q=min_q)
-        temps, top_ks, top_ps = self._pad_sample_params(
-            row_params, batch.num_slots)
-        kuids, kpos = self._pad_keyed(batch_uids, row_pos,
-                                      batch.num_slots)
-        greedy_only = not bool((temps > 0.0).any())
-        serving_counters.record_program(
-            h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-        with trace_span("engine.dispatch"):
-            out, self._state.kv_cache.data = self._model.spec_step(
-                batch, self._state.kv_cache.data, rng, temps, top_ks,
-                top_ps, greedy_only, row_uids=kuids, row_pos=kpos)
-        return out
+        return self._dispatch(kind, [batch], row_params, rng, row_pos)
 
     def step_draft_spec(self, batch_uids: Sequence[int],
                         batch_tokens: Sequence[np.ndarray],
@@ -1292,46 +1241,35 @@ class InferenceEngineV2:
         :meth:`step_spec`, but the host only knows each row's LAST
         COMMITTED token — ``batch_tokens[i] = [last, 0...0]`` with
         ``len == 1 + room`` (room = drafts this row may commit), and
-        the draft trunk proposes the rest inside the compiled program.
-        Returns a device [S, 2+k] int32 array: accepted count,
-        corrected token, then the k drafted tokens (the host slices the
-        first ``accepted`` to reconstruct the committed block).  The
-        commit is deferred to :meth:`commit_spec` exactly like the
-        n-gram path; call :meth:`mark_draft_seen` after it so lag
-        tracking knows the draft pool kept up."""
-        descs = self._admit_batch(batch_uids, batch_tokens,
-                                  do_checks=False)
-        batch = self._build_batch(
-            descs, [np.asarray(t) for t in batch_tokens], min_q=min_q)
-        temps, top_ks, top_ps = self._pad_sample_params(
-            row_params, batch.num_slots)
-        kuids, kpos = self._pad_keyed(batch_uids, row_pos,
-                                      batch.num_slots)
-        greedy_only = not bool((temps > 0.0).any())
-        serving_counters.record_program(
-            h2d_bytes=temps.nbytes + top_ks.nbytes + top_ps.nbytes)
-        with trace_span("engine.dispatch"):
-            out, (self._state.kv_cache.data, self._draft_kv) = \
-                self._model.draft_spec_step(
-                    batch, (self._state.kv_cache.data, self._draft_kv),
-                    rng, temps, top_ks, top_ps, greedy_only,
-                    row_uids=kuids, row_pos=kpos)
-        return out
+        the draft trunk proposes the rest inside the compiled program
+        (a ``lax.scan`` of Q=1 draft forwards against the draft pool,
+        feeding the target's verify: draft tokens never cross d2h
+        mid-step).  Returns a device [S, 2+k] int32 array: accepted
+        count, corrected token, then the k drafted tokens (the host
+        slices the first ``accepted`` to reconstruct the committed
+        block).  The commit is deferred to :meth:`commit_spec` exactly
+        like the n-gram path; call :meth:`mark_draft_seen` after it so
+        lag tracking knows the draft pool kept up."""
+        return self._step_verify("draft_spec", batch_uids, batch_tokens,
+                                 row_params, rng, min_q, row_pos)
 
     def step_draft_fill(self, batch_uids: Sequence[int],
                         batch_tokens: Sequence[np.ndarray]) -> None:
         """Draft-KV catch-up (ISSUE 17): write the DRAFT pool's KV for
         already-committed history the host still knows —
         ``batch_tokens[i]`` is the slice
-        ``history[draft_seen : draft_seen + chunk]`` for uid i.  The
-        target pool, seen counts and the allocator are untouched (this
-        must NOT ride ``_admit_batch``: the tokens are committed, not
-        new), pages are the sequence's existing table, and NOTHING
-        crosses d2h.  Advances the engine's per-uid draft-seen mark."""
-        from .ragged.batch import MIN_PAGES, MIN_SLOTS, _bucket
-        from .ragged import RaggedBatch
-        page = self._model.kv_config.page_size
-        sds, starts, caps = [], [], []
+        ``history[draft_seen : draft_seen + chunk]`` for uid i — with one
+        draft-trunk-only forward (prompt prefill, non-spec commits,
+        prefix-cache hits and snapshot restores all advance the target
+        without touching the draft pool).  The target pool, seen counts
+        and the allocator are untouched (this must NOT ride
+        ``_admit_batch``: the tokens are committed, not new), pages are
+        the sequence's existing table, and NOTHING crosses d2h.
+        Correctness never depends on this running (the verify step gates
+        every commit); it only restores the draft's context so its
+        proposals are worth accepting.  Advances the engine's per-uid
+        draft-seen mark."""
+        sds, starts = [], []
         for uid in batch_uids:
             sd = self._state.get_sequence(uid)
             if sd is None:
@@ -1339,38 +1277,10 @@ class InferenceEngineV2:
                     f"step_draft_fill: unknown sequence uid {uid}")
             sds.append(sd)
             starts.append(self._draft_seen.get(uid, 0))
-            caps.append(max(sd.allocated_capacity, 1))
-        lengths = [len(t) for t in batch_tokens]
-        if self._lattice is not None:
-            S = self._lattice.bucket_s(len(batch_uids))
-            Q = self._lattice.bucket_q(max(lengths))
-            P = self._lattice.bucket_p(max(caps))
-        else:
-            S = _bucket(len(batch_uids), MIN_SLOTS)
-            Q = _bucket(max(lengths))
-            P = _bucket(max(caps), MIN_PAGES)
-        token_ids = np.zeros((S, Q), np.int32)
-        q_lens = np.zeros(S, np.int32)
-        start_pos = np.zeros(S, np.int32)
-        page_table = np.zeros((S, P), np.int32)
-        for i, (sd, toks, start) in enumerate(
-                zip(sds, batch_tokens, starts)):
-            toks = np.asarray(toks, np.int32).reshape(-1)
-            token_ids[i, :len(toks)] = toks
-            q_lens[i] = len(toks)
-            start_pos[i] = start
-            page_table[i] = sd.page_table(P)
-        batch = RaggedBatch(token_ids=token_ids, q_lens=q_lens,
-                            start_pos=start_pos, page_table=page_table,
-                            uids=list(batch_uids), fresh=False)
-        serving_counters.record_program(
-            h2d_bytes=token_ids.nbytes + q_lens.nbytes
-            + start_pos.nbytes + page_table.nbytes)
-        with trace_span("engine.dispatch"):
-            self._draft_kv = self._model.draft_fill_step(batch,
-                                                         self._draft_kv)
-        for uid, start, n in zip(batch_uids, starts, lengths):
-            self._draft_seen[uid] = start + n
+        batch = self._build_batch(sds, batch_tokens, start_pos=starts)
+        self._dispatch("draft_fill", [batch])
+        for uid, start, toks in zip(batch_uids, starts, batch_tokens):
+            self._draft_seen[uid] = start + len(toks)
 
     # dslint: hot-path
     def commit_spec(self, batch_uids: Sequence[int],

@@ -33,9 +33,10 @@ import dataclasses
 import hashlib
 import json
 import os
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Sequence, Tuple
 
 from .ragged.batch import MIN_PAGES, MIN_SLOTS, _bucket
+from .step_key import StepKey
 
 LATTICE_ARTIFACT_VERSION = 1
 LATTICE_ARTIFACT_KIND = "ds_lattice"
@@ -159,15 +160,15 @@ def enumerate_lattice_keys(s_vals: Sequence[int], q_vals: Sequence[int],
                 # would duplicate every prefill executable)
                 for fresh in ((False, True) if Q > 1 and has_fresh
                               else (False,)):
-                    key = (S, Q, P, fresh)
-                    keys.append(key)
+                    shape = (S, Q, P, fresh)
+                    keys.append(StepKey.logits(shape))
                     if draft and not fresh:
                         # catch-up writes paged draft KV — never fresh
-                        keys.append((S, Q, P, False, "draft_fill"))
+                        keys.append(StepKey.draft_fill(shape))
                     if not sampling:
                         continue
                     for greedy in (True, False):
-                        keys.append(key + ("sample", greedy))
+                        keys.append(StepKey.sample(shape, greedy))
                         if Q == 1 and not fresh:
                             # double-buffer chain: the previous step's
                             # slot bucket can only be >= this one's
@@ -176,8 +177,8 @@ def enumerate_lattice_keys(s_vals: Sequence[int], q_vals: Sequence[int],
                             for prev_s in s_vals:
                                 if prev_s < S:
                                     continue
-                                keys.append((S, 1, P, False, "chain",
-                                             prev_s, greedy))
+                                keys.append(StepKey.chain(shape, prev_s,
+                                                          greedy))
     if sampling and spec_q > 0:
         for S in s_vals:
             if S * spec_q > max_ragged_batch_size:
@@ -185,22 +186,25 @@ def enumerate_lattice_keys(s_vals: Sequence[int], q_vals: Sequence[int],
             for P in p_vals:
                 if P * page_size < spec_q:
                     continue
+                shape = (S, spec_q, P, False)
                 for greedy in (True, False):
-                    keys.append((S, spec_q, P, False, "spec", greedy))
+                    keys.append(StepKey.spec(shape, greedy))
                     if draft:
-                        keys.append((S, spec_q, P, False, "draft_spec",
-                                     greedy))
+                        keys.append(StepKey.draft_spec(shape, greedy))
     return keys
 
 
 @dataclasses.dataclass(frozen=True)
 class BucketLattice:
-    """Bucket tops + precompile key set an engine serves under.  The
-    three ``bucket_*`` methods are the live-path bucketing functions
-    ``build_batch`` / ``predict_step_key`` / the mixed-step pad use in
-    place of the power-of-two ``_bucket`` — keeping bucketing and the
-    precompiled key set derived from the SAME tops is what makes
-    ``compile_on_path == 0`` hold by construction."""
+    """Bucket tops + precompile key set an engine serves under: THE
+    bucket rule.  :meth:`shape` is what ``build_batch`` and
+    ``predict_step_key`` bucket a batch with and :meth:`bucket_s` what
+    the mixed step pads its token vector with — keeping bucketing and
+    the precompiled key set derived from the SAME tops is what makes
+    ``compile_on_path == 0`` hold by construction.  A lattice with no
+    tops (:data:`POWER_LATTICE`, the default) buckets every dimension
+    to a power of two over its floor, which is also where a mined
+    lattice sends traffic past its largest top."""
     s_tops: Tuple[int, ...]
     q_tops: Tuple[int, ...]
     p_tops: Tuple[int, ...]
@@ -216,10 +220,12 @@ class BucketLattice:
             {int(q) for q in self.q_tops} | {1})))
         object.__setattr__(self, "p_tops", tuple(sorted(
             {max(int(p), MIN_PAGES) for p in self.p_tops})))
-        if not (self.s_tops and self.p_tops):
-            raise LatticeError(
-                "lattice needs at least one S and one P bucket top "
-                f"(got s={self.s_tops}, p={self.p_tops})")
+
+    @property
+    def mined(self) -> bool:
+        """Whether the tops (and ``keys``, the precompile target) come
+        from observed traffic; False: the power-of-two default."""
+        return bool(self.s_tops)
 
     def bucket_s(self, n: int) -> int:
         return _pick(n, self.s_tops, MIN_SLOTS)
@@ -229,6 +235,33 @@ class BucketLattice:
 
     def bucket_p(self, n: int) -> int:
         return _pick(n, self.p_tops, MIN_PAGES)
+
+    def shape(self, rows: int, max_q: int, max_pages: int,
+              min_q: int = 1) -> Tuple[int, int, int]:
+        """The bucketed ``(S, Q, P)`` of a batch of ``rows`` sequences,
+        the longest bringing ``max_q`` new tokens and the largest
+        holding ``max_pages`` pages.  ``min_q`` floors the Q bucket:
+        speculative steps pad every dispatch to the ONE ``1 +
+        spec_max_draft`` bucket, so that a short-draft step cannot form
+        a smaller off-lattice key."""
+        return (self.bucket_s(rows), self.bucket_q(max(max_q, min_q)),
+                self.bucket_p(max_pages))
+
+
+#: the default: no mined tops, every dimension a power of two
+POWER_LATTICE = BucketLattice(s_tops=(), q_tops=(), p_tops=())
+
+
+def _mined_lattice(s_tops, q_tops, p_tops, **facts) -> BucketLattice:
+    """A lattice from an artifact's or a trace's tops, which must name
+    at least one S and one P bucket."""
+    lat = BucketLattice(s_tops=tuple(s_tops), q_tops=tuple(q_tops),
+                        p_tops=tuple(p_tops), **facts)
+    if not (lat.s_tops and lat.p_tops):
+        raise LatticeError(
+            "lattice needs at least one S and one P bucket top "
+            f"(got s={lat.s_tops}, p={lat.p_tops})")
+    return lat
 
 
 def _prune_q_tops(tops: List[int], ratio: float, s_tops: List[int],
@@ -280,10 +313,14 @@ def mine_lattice(trace: Dict[str, Any], ratio: float = 1.3,
     page = int(meta.get("page_size", 16) or 16)
     vocab = int(meta.get("vocab_size", 0) or 0)
 
-    occ: Dict[tuple, int] = {tuple(k): int(n) for k, n in
-                             trace.get("key_counts", {}).items()}
-    for k in trace.get("compiles", []):
-        occ.setdefault(tuple(k), 1)
+    try:
+        occ: Dict[StepKey, int] = {
+            StepKey.parse(k): int(n)
+            for k, n in trace.get("key_counts", {}).items()}
+        for k in trace.get("compiles", []):
+            occ.setdefault(StepKey.parse(k), 1)
+    except ValueError as e:
+        raise LatticeError(f"trace {source or '<in memory>'}: {e}")
     if not occ and not requests:
         raise LatticeError(
             "trace has no step-key occupancy and no requests — nothing "
@@ -294,30 +331,25 @@ def mine_lattice(trace: Dict[str, Any], ratio: float = 1.3,
     fresh_seen = False
     draft_seen = False
     for k in occ:
-        s_set.add(int(k[0]))
-        p_set.add(int(k[2]))
-        if len(k) > 3 and bool(k[3]):
-            fresh_seen = True
-        kind = k[4] if len(k) > 4 else "logits"
-        if kind == "chain":
-            s_set.add(int(k[5]))
-        elif kind in ("spec", "draft_spec"):
-            spec_draft = max(spec_draft, int(k[1]) - 1)
-            draft_seen = draft_seen or kind == "draft_spec"
-        elif kind == "draft_fill":
-            q_obs.add(int(k[1]))
+        s_set.add(k.S)
+        p_set.add(k.P)
+        fresh_seen = fresh_seen or k.fresh
+        if k.kind == "chain":
+            s_set.add(k.prev_len)
+        elif k.kind in ("spec", "draft_spec"):
+            spec_draft = max(spec_draft, k.Q - 1)
+            draft_seen = draft_seen or k.kind == "draft_spec"
+        elif k.kind == "draft_fill":
+            q_obs.add(k.Q)
             draft_seen = True
-        elif kind == "mixed":
-            # (S_d, 1, P_d, False, "mixed", S_p, Q_p, P_p, fresh_p, g)
-            s_set.add(int(k[5]))
-            p_set.add(int(k[7]))
-            q_obs.add(int(k[6]))
-            if bool(k[8]):
-                fresh_seen = True
-            mixed_combos.add((int(k[0]), int(k[2]), int(k[5]),
-                              int(k[7]), bool(k[8]), bool(k[9])))
+        elif k.kind == "mixed":
+            S_p, Q_p, P_p, fresh_p = k.prefill
+            s_set.add(S_p)
+            p_set.add(P_p)
+            q_obs.add(Q_p)
+            mixed_combos.add((k.S, k.P, S_p, P_p, fresh_p, k.greedy))
         else:
-            q_obs.add(int(k[1]))
+            q_obs.add(k.Q)
 
     prompt_lens = [int(r["prompt_len"]) for r in requests]
     if not s_set:
@@ -351,8 +383,7 @@ def mine_lattice(trace: Dict[str, Any], ratio: float = 1.3,
     q_tops = _prune_q_tops(q_union, ratio, sorted(s_set), sorted(p_set),
                            page, max_ragged_batch_size)
 
-    lat = BucketLattice(s_tops=tuple(s_set), q_tops=tuple(q_tops),
-                        p_tops=tuple(p_set), has_fresh=fresh_seen)
+    lat = _mined_lattice(s_set, q_tops, p_set, has_fresh=fresh_seen)
     spec_q = lat.bucket_q(1 + spec_draft) if spec_draft else 0
     keys = enumerate_lattice_keys(
         lat.s_tops, lat.q_tops, lat.p_tops, page_size=page,
@@ -366,8 +397,8 @@ def mine_lattice(trace: Dict[str, Any], ratio: float = 1.3,
         for q in lat.q_tops:
             if q <= 1 or sd + sp * q > max_ragged_batch_size * 2:
                 continue
-            keys.append((sd, 1, pd, False, "mixed",
-                         sp, q, pp, fresh_p, greedy))
+            keys.append(StepKey.mixed((sd, 1, pd, False),
+                                      (sp, q, pp, fresh_p), greedy))
 
     return {
         "kind": LATTICE_ARTIFACT_KIND,
@@ -451,17 +482,16 @@ def _validate_artifact(doc: Any, path: str) -> Dict[str, Any]:
         if field not in doc:
             raise LatticeError(
                 f"lattice artifact {path} is missing {field!r}")
-    # per-kind key arity: a truncated/hand-edited key would otherwise
-    # surface as a raw IndexError deep inside engine precompile
-    kind_len = {"logits": 4, "sample": 6, "chain": 7, "spec": 6,
-                "draft_spec": 6, "draft_fill": 5, "mixed": 10}
+    # a truncated/hand-edited key would otherwise surface as a raw
+    # IndexError deep inside engine precompile
     for i, key in enumerate(doc["keys"]):
-        n = len(key) if isinstance(key, (list, tuple)) else 0
-        kind = key[4] if n > 4 else ("logits" if n == 4 else None)
-        if kind not in kind_len or n != kind_len[kind]:
+        try:
+            StepKey.parse(key)
+        except ValueError as e:
             raise LatticeError(
                 f"lattice artifact {path}: keys[{i}] = {key!r} is not "
-                "a valid (S, Q, P, fresh[, kind, ...]) step-cache key")
+                f"a valid (S, Q, P, fresh[, kind, ...]) step-cache key "
+                f"({e})")
     return doc
 
 
@@ -480,11 +510,9 @@ def load_artifact(path: str) -> Dict[str, Any]:
 
 def _lattice_from_artifact(doc: Dict[str, Any],
                            source: str) -> BucketLattice:
-    return BucketLattice(
-        s_tops=tuple(doc["s_buckets"]),
-        q_tops=tuple(doc["q_buckets"]),
-        p_tops=tuple(doc["p_buckets"]),
-        keys=tuple(tuple(k) for k in doc["keys"]),
+    return _mined_lattice(
+        doc["s_buckets"], doc["q_buckets"], doc["p_buckets"],
+        keys=tuple(StepKey.parse(k) for k in doc["keys"]),
         # identity, not just geometry: two lattices mined on the same
         # (page, vocab) from different traces must NOT compare equal
         digest=lattice_content_digest(doc),
@@ -494,10 +522,10 @@ def _lattice_from_artifact(doc: Dict[str, Any],
 
 def resolve_lattice(spec: str, *, page_size: int, vocab_size: int,
                     max_ragged_batch_size: int = 768
-                    ) -> Optional[BucketLattice]:
+                    ) -> BucketLattice:
     """Resolve a ``serving_optimization.lattice`` spec at engine build.
 
-    ``""`` -> None (the power-of-two default).  ``"auto:<path>"`` loads
+    ``""`` -> :data:`POWER_LATTICE` (the default).  ``"auto:<path>"`` loads
     a lattice artifact (JSON) or mines one on the fly from a raw
     workload-trace ledger (JSONL), then validates the artifact's config
     digest against THIS engine's (page_size, vocab_size) — a mismatch
@@ -505,7 +533,7 @@ def resolve_lattice(spec: str, *, page_size: int, vocab_size: int,
     cold lattice."""
     spec = (spec or "").strip()
     if not spec:
-        return None
+        return POWER_LATTICE
     if not spec.startswith("auto:"):
         raise LatticeError(
             f"unknown lattice spec {spec!r} (expected \"\" for the "

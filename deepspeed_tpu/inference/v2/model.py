@@ -22,7 +22,7 @@ from __future__ import annotations
 import functools
 import os
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +38,10 @@ from ...telemetry import metrics as tm
 from ...telemetry.watchdog import get_watchdog
 from ...telemetry.workload_trace import get_workload_trace
 from ...utils.compile_cache import thread_cache_counts
+from .lattice import POWER_LATTICE
 from .ragged import KVCacheConfig, RaggedBatch
+from .step_key import (STEP_KINDS, StepKey, step_avals, step_program,
+                       trunk_params)
 
 
 def serving_peak_flops() -> Optional[float]:
@@ -188,7 +191,7 @@ class RaggedInferenceModel:
         if mesh is not None:
             self.mesh = None        # apply_mesh owns the assignment
             self.apply_mesh(mesh)
-        self._step_cache: Dict[Tuple[int, int, int], Callable] = {}
+        self._step_cache: Dict[StepKey, Callable] = {}
         #: schedule-invariant sampling (ISSUE 13): when True every
         #: sampling-capable step kind takes two extra [S] int32 inputs
         #: (row uid, generation position) and draws each row's token
@@ -201,13 +204,13 @@ class RaggedInferenceModel:
         #: precompile — it changes the traced program signatures, so it
         #: is an engine-build-time fact, not a per-step toggle.
         self.keyed_sampling = False
-        #: mined bucket lattice (ISSUE 14): when set (by the engine,
-        #: from ``serving.lattice = "auto:<path>"``), batch bucketing —
-        #: including the mixed step's traced-in token-vector pad below —
-        #: uses its (possibly non-power-of-two) tops instead of the
-        #: power-of-two default.  Engine-build-time, like
-        #: ``keyed_sampling``: it shapes the compiled program set.
-        self.lattice = None
+        #: the bucket lattice (ISSUE 14): the engine sets the one it
+        #: serves under (mined tops from ``serving.lattice =
+        #: "auto:<path>"``, else this power-of-two default), and the
+        #: mixed step's traced-in token-vector pad below buckets with
+        #: it.  Engine-build-time, like ``keyed_sampling``: it shapes
+        #: the compiled program set.
+        self.lattice = POWER_LATTICE
         #: model-drafted speculation (ISSUE 17): the draft trunk's
         #: config + param tree, set by the engine BEFORE any precompile
         #: (like ``keyed_sampling`` — they shape the traced "draft_spec"
@@ -402,163 +405,47 @@ class RaggedInferenceModel:
         return jax.sharding.NamedSharding(self.mesh, P())
 
     # -- forward ------------------------------------------------------------
-    def forward(self, batch: RaggedBatch, kv: jax.Array
-                ) -> Tuple[jax.Array, jax.Array]:
-        """Run one ragged forward; returns (logits [S_live, V], new kv)."""
-        step = self._get_step(batch.shape_key)
-        logits, kv = step(self.params, kv, batch.token_ids, batch.q_lens,
-                          batch.start_pos, batch.page_table)
-        return logits, kv
+    @property
+    def has_fresh(self) -> bool:
+        """Whether pure-prefill batches have their own attention path
+        (without one the key's fresh flag is inert: ALiBi)."""
+        return self._fresh_attention is not None
 
-    def _keyed_args(self, row_uids, row_pos) -> list:
-        """The two extra [S] int32 inputs of keyed-sampling programs
-        (empty list when the mode is off).  Callers that never sample a
-        row the host reads (padding, mid-prefill) may pass anything for
-        it — its draw is garbage nobody consumes."""
-        if not self.keyed_sampling:
-            return []
-        if row_uids is None or row_pos is None:
-            raise ValueError(
-                "keyed_sampling model requires row_uids/row_pos for "
-                "every sampling-capable step")
-        return [jnp.asarray(row_uids, jnp.int32),
-                jnp.asarray(row_pos, jnp.int32)]
-
-    def sample_step(self, batch: RaggedBatch, kv: jax.Array,
-                    rng: jax.Array, temps, top_ks, top_ps,
-                    greedy_only: bool, row_uids=None, row_pos=None
-                    ) -> Tuple[jax.Array, jax.Array]:
-        """One compiled program: forward + on-device sampling.  Returns
-        (tokens [S] int32, new kv) — only the token array ever needs to
-        cross device->host (ISSUE 2 tentpole b).  ``greedy_only`` is a
-        STATIC specialization: all-greedy steps compile to plain argmax
-        with the vocab sort/cumsum machinery dead-code-eliminated."""
-        key = self._normalize_key(batch.shape_key) + (
-            "sample", bool(greedy_only))
+    # dslint: hot-path
+    def run_step(self, key: StepKey, kv, batches: Sequence[RaggedBatch],
+                 sampling: Optional[tuple] = None,
+                 prev: Optional[tuple] = None):
+        """Run the step program of ``key``: the one call every dispatch
+        makes.  ``kv`` is the pool (or pair) of the kind's trunk, donated;
+        ``batches`` the key's segments in order; ``prev`` a chain key's
+        ``(prev_tokens, gather_idx)``, which take the token ids' place;
+        ``sampling`` a sampling kind's ``(rng, temps, top_ks, top_ps,
+        row_uids, row_pos)``, the last two read only under
+        ``keyed_sampling``.  Callers that never sample a row the host
+        reads (padding, mid-prefill) may pass anything for it — its draw
+        is garbage nobody consumes.  Returns what the program returns:
+        ``(output, new kv)``, or the new pool alone where the kind has
+        no output (``STEP_KINDS``)."""
         step = self._get_step(key)
-        return step(self.params, kv, batch.token_ids, batch.q_lens,
-                    batch.start_pos, batch.page_table, rng,
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(top_ks, jnp.int32),
-                    jnp.asarray(top_ps, jnp.float32),
-                    *self._keyed_args(row_uids, row_pos))
-
-    def sample_step_mixed(self, dec_batch: RaggedBatch,
-                          pre_batch: RaggedBatch, kv: jax.Array,
-                          rng: jax.Array, temps, top_ks, top_ps,
-                          greedy_only: bool, row_uids=None, row_pos=None
-                          ) -> Tuple[jax.Array, jax.Array]:
-        """Mixed SplitFuse step as ONE compiled program over TWO batch
-        geometries: a decode segment [S_d, 1] and a prefill segment
-        [S_p, Q], KV threaded through both.  This keeps the one-program
-        one-dispatch property WITHOUT padding decode rows to the prefill
-        chunk width (a [S, Qmax] superbucket would compute Qmax
-        positions per decode row — Qmax× wasted FLOPs on the serving
-        hot path).  Tokens come back as [S_d + S_p] in segment order;
-        the sampling-param arrays follow that order."""
-        dk = self._normalize_key(dec_batch.shape_key)
-        pk = self._normalize_key(pre_batch.shape_key)
-        assert dk[1] == 1, "segment A of a mixed step is decode-only"
-        key = dk + ("mixed",) + pk + (bool(greedy_only),)
-        step = self._get_step(key)
-        return step(self.params, kv,
-                    dec_batch.token_ids, dec_batch.q_lens,
-                    dec_batch.start_pos, dec_batch.page_table,
-                    pre_batch.token_ids, pre_batch.q_lens,
-                    pre_batch.start_pos, pre_batch.page_table, rng,
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(top_ks, jnp.int32),
-                    jnp.asarray(top_ps, jnp.float32),
-                    *self._keyed_args(row_uids, row_pos))
-
-    def spec_step(self, batch: RaggedBatch, kv: jax.Array,
-                  rng: jax.Array, temps, top_ks, top_ps,
-                  greedy_only: bool, row_uids=None, row_pos=None
-                  ) -> Tuple[jax.Array, jax.Array]:
-        """Speculative verification step (ISSUE 10): each decode row
-        carries ``[last_committed, draft_1..draft_k]`` as a ragged
-        Q = 1+k segment; ONE compiled program runs the forward over
-        every position (the existing Q>1 kernel path with per-row causal
-        limits), computes the model's own emission at each position,
-        and reduces per row to ``[accepted_count, corrected_token]`` —
-        a [S, 2] int32 array, the ONLY thing that ever crosses d2h (the
-        host already knows the draft tokens it proposed, so counts +
-        one correction reconstruct the committed block)."""
-        key = self._normalize_key(batch.shape_key)[:3] + (
-            False, "spec", bool(greedy_only))
-        step = self._get_step(key)
-        return step(self.params, kv, batch.token_ids, batch.q_lens,
-                    batch.start_pos, batch.page_table, rng,
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(top_ks, jnp.int32),
-                    jnp.asarray(top_ps, jnp.float32),
-                    *self._keyed_args(row_uids, row_pos))
-
-    def draft_spec_step(self, batch: RaggedBatch, kv_pair, rng: jax.Array,
-                        temps, top_ks, top_ps, greedy_only: bool,
-                        row_uids=None, row_pos=None):
-        """Model-drafted speculative step (ISSUE 17): the DRAFT trunk
-        autoregressively proposes up to k = Q-1 tokens inside the
-        compiled program (``lax.scan`` over Q draft iterations, each a
-        Q=1 paged forward against the draft KV pool), and the proposals
-        feed straight into the target's ``_spec_step_impl``
-        verification — draft tokens never cross d2h mid-step.  The host
-        only supplies ``token_ids[:, 0]`` (the last committed token per
-        row); the rest of the row is ignored.  ``kv_pair`` is the
-        (target_kv, draft_kv) tuple — donated together.  Returns
-        ([S, 2+k] int32, (target_kv, draft_kv)): accepted count,
-        corrected token, then the k drafted tokens the host has never
-        seen (it slices the first ``accepted`` of them to reconstruct
-        the committed block)."""
-        key = self._normalize_key(batch.shape_key)[:3] + (
-            False, "draft_spec", bool(greedy_only))
-        step = self._get_step(key)
-        return step({"target": self.params, "draft": self.draft_params},
-                    kv_pair, batch.token_ids, batch.q_lens,
-                    batch.start_pos, batch.page_table, rng,
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(top_ks, jnp.int32),
-                    jnp.asarray(top_ps, jnp.float32),
-                    *self._keyed_args(row_uids, row_pos))
-
-    def draft_fill_step(self, batch: RaggedBatch, draft_kv):
-        """Catch the draft KV pool up over ALREADY-COMMITTED history
-        (prompt prefill, non-spec decode commits, prefix-cache hits and
-        snapshot restores all advance the target without touching the
-        draft pool): one draft-trunk-only forward that writes draft KV
-        for the batch's positions and returns the new pool — nothing
-        crosses d2h.  Correctness never depends on this running (the
-        verify step gates every commit); it only restores the draft's
-        context so its proposals are worth accepting."""
-        key = self._normalize_key(batch.shape_key)[:3] + (
-            False, "draft_fill")
-        step = self._get_step(key)
-        return step(self.draft_params, draft_kv, batch.token_ids,
-                    batch.q_lens, batch.start_pos, batch.page_table)
-
-    def chained_step(self, batch: RaggedBatch, kv: jax.Array,
-                     prev_tokens: jax.Array, gather_idx, rng: jax.Array,
-                     temps, top_ks, top_ps, greedy_only: bool,
-                     row_uids=None, row_pos=None
-                     ) -> Tuple[jax.Array, jax.Array]:
-        """Decode-continuation step whose token ids come from the
-        PREVIOUS step's on-device token output (``prev_tokens``) via a
-        host-known slot gather — the device-side half of the scheduler's
-        double buffering: step k+1 dispatches while step k's tokens are
-        still in flight, with no host sync in between."""
-        S, Q, P, _ = self._normalize_key(batch.shape_key)
-        assert Q == 1, "chained steps are decode-only"
-        key = (S, 1, P, False, "chain",
-               int(prev_tokens.shape[0]) - self.step_tail,
-               bool(greedy_only))
-        step = self._get_step(key)
-        return step(self.params, kv, prev_tokens,
-                    jnp.asarray(gather_idx, jnp.int32), batch.q_lens,
-                    batch.start_pos, batch.page_table, rng,
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(top_ks, jnp.int32),
-                    jnp.asarray(top_ps, jnp.float32),
-                    *self._keyed_args(row_uids, row_pos))
+        operands = []
+        for b in batches:
+            operands += (b.token_ids, b.q_lens, b.start_pos, b.page_table)
+        if prev is not None:
+            operands[:1] = (prev[0], jnp.asarray(prev[1], jnp.int32))
+        if sampling is not None:
+            rng, temps, top_ks, top_ps, row_uids, row_pos = sampling
+            operands += (rng, jnp.asarray(temps, jnp.float32),
+                         jnp.asarray(top_ks, jnp.int32),
+                         jnp.asarray(top_ps, jnp.float32))
+            if self.keyed_sampling:
+                if row_uids is None or row_pos is None:
+                    raise ValueError(
+                        "keyed_sampling model requires row_uids/row_pos "
+                        "for every sampling-capable step")
+                operands += (jnp.asarray(row_uids, jnp.int32),
+                             jnp.asarray(row_pos, jnp.int32))
+        return step(trunk_params(self, STEP_KINDS[key.kind].trunk), kv,
+                    *operands)
 
     @property
     def step_tail(self) -> int:
@@ -568,21 +455,21 @@ class RaggedInferenceModel:
         held expert's pairs in one layer; held experts with a pair, summed
         over the routed layers), so the counts ride the step's one d2h.
         A mixed step adds its two passes' counts, the fullest experts'
-        too.  The chain key's ``prev_len`` stays the row bucket."""
+        too.  The chain key's ``prev_len`` stays the row bucket
+        (``step_key.step_avals`` adds the tail)."""
         return 3 if self.cfg.n_routed_experts else 0
 
-    def _normalize_key(self, key) -> Tuple[int, int, int, bool]:
-        if getattr(self, "_fresh_attention", None) is None \
-                and len(key) > 3 and key[3]:
+    def _get_step(self, key) -> Callable:
+        """The executable of ``key`` (a :class:`StepKey`, or its bare
+        tuple), forming it on the request path where it is missing."""
+        if type(key) is not StepKey:
+            key = StepKey.parse(key)
+        if not self.has_fresh and key.fresh:
             # no fresh-prefill implementation (ALiBi): the flag is inert,
             # so normalize the cache key to the False variant the
-            # precompiled lattice contains (direct-forward callers may
-            # hand us a batch built without fresh_supported=False)
-            key = key[:3] + (False,)
-        return key
-
-    def _get_step(self, key) -> Callable:
-        key = self._normalize_key(key[:4]) + tuple(key[4:])
+            # precompiled lattice contains (direct callers may hand us a
+            # batch built without fresh_supported=False)
+            key = key.with_fresh(False)
         fn = self._step_cache.get(key)
         if fn is None:
             # recompile accounting (ISSUE 5): a miss here IS the
@@ -630,7 +517,7 @@ class RaggedInferenceModel:
                          {"key": key, "on_path": run}) as prog:
             before = thread_cache_counts()
             with tracer.span("engine.program.trace"):
-                traced = jax.jit(self._impl_of(key),
+                traced = jax.jit(step_program(self, key),
                                  donate_argnums=(1,)).trace(*args)
             with tracer.span("engine.program.lower"):
                 lowered = traced.lower()
@@ -684,25 +571,6 @@ class RaggedInferenceModel:
         self._account_tp_collective(key)
         self._account_cost(key)
 
-    def _tp_logits_rows(self, key) -> int:
-        """Logits rows one dispatch of ``key`` assembles cross-shard
-        (the [N, V] arrays behind the in-program all-gather): last-token
-        kinds gather S rows, the spec verify gathers every position
-        (S*Q), draft_spec adds one [S] draft gather per scan iteration
-        on top of its verify, mixed sums its two segments, and
-        draft_fill has no unembed consumer at all."""
-        kind = key[4] if len(key) > 4 else "logits"
-        S = int(key[0])
-        if kind in ("logits", "sample", "chain"):
-            return S
-        if kind == "spec":
-            return S * int(key[1])
-        if kind == "draft_spec":
-            return 2 * S * int(key[1])
-        if kind == "mixed":
-            return S + int(key[5])
-        return 0                                         # draft_fill
-
     def _account_tp_collective(self, key) -> None:
         """Analytic interconnect accounting for the logits collective
         (host-side adds — nothing touches the device).  Wire bytes are
@@ -715,7 +583,7 @@ class RaggedInferenceModel:
         tp = self.tp_degree
         if tp <= 1:
             return
-        n = self._tp_logits_rows(key)
+        n = STEP_KINDS[key.kind].logits_rows(key)
         if not n:
             return
         v = int(self.cfg.vocab_size)
@@ -800,90 +668,19 @@ class RaggedInferenceModel:
                             else 0.0),
         }
 
-    def _fresh_of(self, key) -> bool:
-        return bool(key[3]) if len(key) > 3 else False
-
-    def _impl_of(self, key) -> Callable:
-        """The python callable a step-cache key compiles to."""
-        kind = key[4] if len(key) > 4 else "logits"
-        if kind == "logits":
-            return functools.partial(self._step_impl,
-                                     fresh=self._fresh_of(key))
-        if kind == "sample":
-            return functools.partial(self._sample_step_impl,
-                                     fresh=self._fresh_of(key),
-                                     greedy_only=key[5])
-        if kind == "chain":
-            return functools.partial(self._chained_step_impl,
-                                     greedy_only=key[6])
-        if kind == "spec":
-            return functools.partial(self._spec_step_impl,
-                                     greedy_only=key[5])
-        if kind == "draft_spec":
-            return functools.partial(self._draft_spec_step_impl,
-                                     greedy_only=key[5])
-        if kind == "draft_fill":
-            return self._draft_fill_step_impl
-        if kind == "mixed":
-            # key = (S_d, 1, P_d, False, "mixed",
-            #        S_p, Q, P_p, fresh_p, greedy_only)
-            return functools.partial(self._mixed_sample_step_impl,
-                                     fresh_p=key[8], greedy_only=key[9])
-        raise ValueError(f"unknown step kind in cache key {key}")
-
-    def _step_avals(self, key, kv_aval) -> list:
-        """Abstract argument list for AOT-lowering one cache key."""
-        S, Q, P = key[:3]
-        i32, f32 = jnp.int32, jnp.float32
-        sds = jax.ShapeDtypeStruct
-        batch_avals = [sds((S, Q), i32), sds((S,), i32), sds((S,), i32),
-                       sds((S, P), i32)]
-        kind = key[4] if len(key) > 4 else "logits"
-
-        def sample_avals(n):
-            avals = [jax.eval_shape(lambda: jax.random.key(0)),
-                     sds((n,), f32), sds((n,), i32), sds((n,), f32)]
-            if self.keyed_sampling:
-                # keyed sampling (ISSUE 13): row uid + generation
-                # position feed the on-device per-row key derivation
-                avals += [sds((n,), i32), sds((n,), i32)]
-            return avals
-
-        if kind == "logits":
-            return [self.params, kv_aval] + batch_avals
-        if kind in ("sample", "spec"):
-            return [self.params, kv_aval] + batch_avals + sample_avals(S)
-        if kind == "draft_spec":
-            # kv_aval is the (target_kv, draft_kv) pair the engine hands
-            # precompile for draft keys; params is the matching pair
-            pair = {"target": self.params, "draft": self.draft_params}
-            return [pair, kv_aval] + batch_avals + sample_avals(S)
-        if kind == "draft_fill":
-            # draft-trunk only: draft params + draft kv, no sampling
-            return [self.draft_params, kv_aval] + batch_avals
-        if kind == "mixed":
-            S_p, Q_p, P_p = key[5:8]
-            pre_avals = [sds((S_p, Q_p), i32), sds((S_p,), i32),
-                         sds((S_p,), i32), sds((S_p, P_p), i32)]
-            return ([self.params, kv_aval] + batch_avals + pre_avals
-                    + sample_avals(S + S_p))
-        # chain: prev_tokens [S_prev] + gather_idx [S] replace token_ids
-        prev_s = key[5] + self.step_tail
-        return ([self.params, kv_aval, sds((prev_s,), i32), sds((S,), i32)]
-                + batch_avals[1:] + sample_avals(S))
-
-    def precompile_step(self, key: Tuple[int, int, int],
-                        kv_aval) -> None:
-        """AOT-compile one (S, Q, P[, fresh[, kind, ...]]) bucket
+    def precompile_step(self, key: Sequence, kv_aval) -> None:
+        """AOT-compile one (S, Q, P, fresh[, kind, ...]) bucket
         (reference: FastGen's CUDA graphs are captured at engine build;
         under XLA the analogue is lower().compile() before serving so no
-        bucket compiles on the request path)."""
+        bucket compiles on the request path).  ``ValueError`` for a key
+        that names no program."""
+        key = StepKey.parse(key)
         if key in self._step_cache:
             return
         # the COMPILED executable goes into the cache: later calls with
         # the bucket's exact shapes dispatch straight to it (jit's own
         # dispatch cache is not populated by AOT lowering)
-        self._form_program(key, self._step_avals(key, kv_aval))
+        self._form_program(key, step_avals(self, key, kv_aval))
 
     def compiled_programs(self) -> Dict[tuple, Any]:
         """Step-cache key -> compiled executable, for inspection
@@ -1215,11 +1012,7 @@ class RaggedInferenceModel:
         # prev-token length — bucketing here collapses the chain-key
         # space back to the lattice's slot tops (one compile, not one
         # per segment-sum); a mined lattice supplies its own tops
-        from .ragged.batch import MIN_SLOTS, _bucket
-        if self.lattice is not None:
-            pad = self.lattice.bucket_s(tokens.shape[0]) - tokens.shape[0]
-        else:
-            pad = _bucket(tokens.shape[0], MIN_SLOTS) - tokens.shape[0]
+        pad = self.lattice.bucket_s(tokens.shape[0]) - tokens.shape[0]
         if pad:
             tokens = jnp.concatenate(
                 [tokens, jnp.zeros((pad,), jnp.int32)])

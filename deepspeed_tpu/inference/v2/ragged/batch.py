@@ -12,26 +12,23 @@ buckets** so every distinct shape compiles exactly once:
     page_table  : [S, P] int32   KV page indices (0 = null page)
 
 ``S`` (sequence slots), ``Q`` (max new tokens per sequence) and ``P``
-(max pages per sequence) are bucketed powers of two; a pure-decode batch
-compiles with Q=1, a prefill chunk with Q=chunk.  Padding slots write
+(max pages per sequence) are bucketed by the engine's lattice (powers of
+two by default); a pure-decode batch compiles with Q=1, a prefill chunk
+with Q=chunk.  Padding slots write
 their KV into the null page and are masked out of attention and logits.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .sequence import SequenceDescriptor
 
 
-#: bucket-lattice floors shared by ``build_batch`` and
-#: ``InferenceEngineV2.precompile`` — exported constants so the AOT
-#: lattice can never silently drift from the live batching path (the
-#: previous ``inspect.signature`` introspection broke if the defaults
-#: moved into a wrapper or got keyword-only shuffled)
+#: the floors of the slot and page buckets (``lattice.BucketLattice``)
 MIN_SLOTS = 1
 MIN_PAGES = 8
 
@@ -76,15 +73,17 @@ class RaggedBatch:
 def build_batch(seqs: Sequence[SequenceDescriptor],
                 tokens: Sequence[np.ndarray],
                 page_size: int,
-                min_slots: int = MIN_SLOTS,
-                min_pages: int = MIN_PAGES,
+                lattice,
                 fresh_supported: bool = True,
                 min_q: int = 1,
-                lattice=None) -> RaggedBatch:
+                start_pos: Optional[Sequence[int]] = None) -> RaggedBatch:
     """Pack (descriptor, new-token) pairs into a bucketed RaggedBatch.
 
     Callers must already have reserved KV pages on each descriptor
     (engine's ``maybe_allocate_kv``) and called ``pre_forward``.
+
+    ``lattice``: the engine's :class:`..lattice.BucketLattice`, whose
+    ``shape`` is the bucket rule (``min_q`` floors its Q bucket).
 
     ``fresh_supported``: whether the model has a dedicated fresh-prefill
     attention path.  Models without one (ALiBi) ignore the flag, so it
@@ -94,44 +93,29 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
     has ``_fresh_attention``), spuriously raising under ``strict_shapes``
     or recompiling on the request path.
 
-    ``min_q`` floors the Q bucket: speculative verification steps pad
-    every dispatch to the ONE ``1 + spec_max_draft`` bucket so a
-    short-draft step can't form a smaller off-lattice Q key (one
-    compiled spec program per (S, P), not one per draft-length mix).
-
-    ``lattice`` (ISSUE 14): a mined :class:`..lattice.BucketLattice`
-    whose (possibly non-power-of-two) bucket tops replace the
-    power-of-two defaults; traffic past its largest top falls back to
-    power-of-two growth, so the lattice changes padding, never
-    correctness.  Must match what ``predict_step_key`` and
-    ``precompile`` used — the engine threads one object through all
-    three.
+    ``start_pos``: each row's start position where it is not the
+    descriptor's committed length (the draft catch-up re-feeds committed
+    history from where the draft pool stopped).
     """
     n = len(seqs)
     assert n == len(tokens) and n >= 1
-    if lattice is not None:
-        S = lattice.bucket_s(n)
-        Q = lattice.bucket_q(max(max(len(t) for t in tokens), min_q))
-        P = lattice.bucket_p(max(s.allocated_capacity for s in seqs))
-    else:
-        S = _bucket(n, min_slots)
-        Q = _bucket(max(max(len(t) for t in tokens), min_q))
-        P = _bucket(max(max(s.allocated_capacity for s in seqs), 1),
-                    min_pages)
+    if start_pos is None:
+        start_pos = [s.seen_tokens for s in seqs]
+    S, Q, P = lattice.shape(n, max(len(t) for t in tokens),
+                            max(s.allocated_capacity for s in seqs), min_q)
 
     token_ids = np.zeros((S, Q), dtype=np.int32)
     q_lens = np.zeros(S, dtype=np.int32)
-    start_pos = np.zeros(S, dtype=np.int32)
+    starts = np.zeros(S, dtype=np.int32)
     page_table = np.zeros((S, P), dtype=np.int32)
     uids = []
     for i, (sd, toks) in enumerate(zip(seqs, tokens)):
         toks = np.asarray(toks, dtype=np.int32).reshape(-1)
         token_ids[i, :len(toks)] = toks
         q_lens[i] = len(toks)
-        start_pos[i] = sd.seen_tokens
+        starts[i] = start_pos[i]
         page_table[i] = sd.page_table(P)
         uids.append(sd.uid)
-    fresh = fresh_supported and Q > 1 and all(s.seen_tokens == 0
-                                              for s in seqs)
-    return RaggedBatch(token_ids, q_lens, start_pos, page_table, uids,
+    fresh = fresh_supported and Q > 1 and not any(start_pos)
+    return RaggedBatch(token_ids, q_lens, starts, page_table, uids,
                        fresh=fresh)

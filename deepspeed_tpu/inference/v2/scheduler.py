@@ -709,7 +709,7 @@ class FastGenScheduler:
             return True
         if self._warned_strict_fallback:
             return False    # negative latch: don't rescan the cache
-        if any(len(k) > 4 and k[4] == "sample" for k in model._step_cache):
+        if self._engine.has_kind("sample"):
             self._fused_ready = True
             return True
         from ...utils.logging import logger
@@ -889,11 +889,10 @@ class FastGenScheduler:
         # steps take over
         one = np.zeros(1, np.int32)
         if not self._strict_key_ok(
-                [u for u, _, _ in rows], [one] * len(rows),
-                ("chain", int(self._inflight.tokens_dev.shape[0])
-                 - self._token_tail,
-                 all(req.params.temperature <= 0.0
-                     for _, _, req in rows))):
+                [u for u, _, _ in rows], [one] * len(rows), "chain",
+                greedy=all(req.params.temperature <= 0.0
+                           for _, _, req in rows),
+                prev_tokens=self._inflight.tokens_dev):
             return None
         return rows
 
@@ -918,23 +917,19 @@ class FastGenScheduler:
                          rows=[(u, i, req)
                                for i, (u, _, req) in enumerate(rows)])
 
-    def _strict_key_ok(self, uids, tokens, suffix: tuple,
-                       min_q: int = 1) -> bool:
+    def _strict_key_ok(self, uids, tokens, kind: str = "logits",
+                       **fields) -> bool:
         """Under strict shapes, fused dispatch requires the predicted
         step-cache key to be AOT-compiled.  Slot/Q bucketing can push
         bucket(S) * bucket(Q) past max_ragged_batch_size even when the
         actual token count fits the budget — exactly the superbuckets
         the precompile lattice skips — so membership, not arithmetic, is
-        the gate.  ``suffix`` is () for a logits key,
-        ("sample", greedy_only), ("spec", greedy_only) /
-        ("draft_spec", greedy_only) with ``min_q`` the spec Q-bucket
-        floor, or ("draft_fill",) for the draft catch-up program."""
-        model = self._engine.model
-        if not getattr(model, "strict_shapes", False):
+        the gate.  ``kind`` and ``fields`` as
+        ``engine.predict_step_key`` takes them."""
+        if not getattr(self._engine.model, "strict_shapes", False):
             return True
-        key = self._engine.predict_step_key(uids, tokens, suffix,
-                                            min_q=min_q)
-        return key in model._step_cache
+        return self._engine.has_program(self._engine.predict_step_key(
+            uids, tokens, kind, **fields))
 
     # -- speculative decoding (ISSUE 10 / ISSUE 17) --------------------------
     #: dry-spell backoff ceiling: after N consecutive fruitless
@@ -959,15 +954,13 @@ class FastGenScheduler:
         key-membership check, a permanent throughput tax."""
         if self._drafter is None or not self._fused:
             return False
-        model = self._engine.model
-        if not getattr(model, "strict_shapes", False):
+        if not getattr(self._engine.model, "strict_shapes", False):
             return True
         if self._spec_strict_ready:
             return True
         if self._warned_strict_spec:
             return False    # negative latch: don't rescan the cache
-        if any(len(k) > 4 and k[4] in ("spec", "draft_spec")
-               for k in model._step_cache):
+        if self._engine.has_kind("spec", "draft_spec"):
             self._spec_strict_ready = True
             return True
         from ...utils.logging import logger
@@ -1192,12 +1185,11 @@ class FastGenScheduler:
             return None
         greedy_only = all(req.params.temperature <= 0.0
                           for _, req, _, _ in rows)
-        suffix = (("draft_spec", greedy_only) if mode == "model"
-                  else ("spec", greedy_only))
         if not self._strict_key_ok(
                 [u for u, _, _, _ in rows],
-                [t for _, _, t, _ in rows], suffix,
-                min_q=1 + self._spec_max_draft):
+                [t for _, _, t, _ in rows],
+                "draft_spec" if mode == "model" else "spec",
+                greedy=greedy_only, min_q=1 + self._spec_max_draft):
             return None
         return rows
 
@@ -1224,8 +1216,7 @@ class FastGenScheduler:
                 break               # the rest fills next step
         while rows:
             if self._strict_key_ok([u for u, _ in rows],
-                                   [t for _, t in rows],
-                                   ("draft_fill",)):
+                                   [t for _, t in rows], "draft_fill"):
                 return rows
             cap = max(len(t) for _, t in rows) // 2
             if cap < 1:
@@ -1739,7 +1730,7 @@ class FastGenScheduler:
             for i in range(len(reqs)))
         use_fused = self._fused and not strict_mixed
         if use_fused and strict and not self._strict_key_ok(
-                uids, tokens, ("sample", greedy_only)):
+                uids, tokens, "sample", greedy=greedy_only):
             use_fused = False
         if _telemetry.enabled:
             # every prompt piece of the step is one entry of ``advances``
@@ -1788,7 +1779,7 @@ class FastGenScheduler:
         # lattice-covered or put() falls back to per-bucket programs
         put_fused = self._serving.fused_step and not strict_mixed
         if put_fused and strict:
-            put_fused = self._strict_key_ok(uids, tokens, ())
+            put_fused = self._strict_key_ok(uids, tokens)
         # dslint: disable=hot-path-sync -- split escape hatch: host-side
         # sampling over put() logits is the documented seed fallback; its
         # d2h is counted by serving_counters.record_d2h and surfaced as
@@ -2282,9 +2273,7 @@ class FastGenScheduler:
                 "compiled": {
                     "keys": [list(k)
                              for k in self._engine.compiled_keys()],
-                    "lattice_digest": (
-                        self._engine._lattice.digest
-                        if self._engine._lattice is not None else ""),
+                    "lattice_digest": self._engine._lattice.digest,
                 },
                 # model-drafted spec (ISSUE 17): draft KV deliberately
                 # does NOT ride the bundle (catch-up refills it — the
@@ -2361,8 +2350,7 @@ class FastGenScheduler:
             compiled = meta.get("compiled") or {}
             manifest = compiled.get("keys") or []
             if manifest:
-                have = (self._engine._lattice.digest
-                        if self._engine._lattice is not None else "")
+                have = self._engine._lattice.digest
                 want = str(compiled.get("lattice_digest", "") or "")
                 if have != want:
                     from ...utils.logging import logger

@@ -22,7 +22,7 @@ Two implementations:
   the scheduler ships ``[last, draft...]`` and the device only
   verifies.
 - :class:`ModelDrafter` — device-resident draft model (ISSUE 17): the
-  drafting loop runs INSIDE the fused step (``model.draft_spec_step``),
+  drafting loop runs INSIDE the fused step (the ``draft_spec`` program),
   so ``propose`` returns placeholders and the real draft tokens come
   back with the verification verdict in the ``[S, 2+k]`` transfer.
   The class exists to make the seam explicit and to carry the

@@ -17,7 +17,7 @@ pairs ``(x[2i], x[2i+1])`` (a permutation of seeded weights against the
 half-split form).  NOT BUILT: the multi-token-prediction module
 (``num_nextn_predict_layers``): it adds nothing to the next-token
 logits, and a draft module fed the target's hidden state is something
-``draft_spec_step`` cannot run yet.  The training forward pass has no
+the ``draft_spec`` program cannot run yet.  The training forward pass has no
 such block either (``models/transformer.py::forward`` refuses).
 
 Parameter tree (stacked over the layers of a kind)::

@@ -166,7 +166,7 @@ class MonitorMaster(Monitor):
     def write_registry_snapshot(self, step: int) -> None:
         """Publish the telemetry registry's ``snapshot()`` through every
         enabled writer under ``Telemetry/<metric>`` tags — the SAME
-        names (and values) the /metrics endpoint and bench.py read, so
+        names (and values) the /metrics endpoint and the tests read, so
         monitor artifacts stop being a fifth metrics namespace.  Called
         by the engine at the ``steps_per_print`` cadence.  Metrics that
         have never recorded anything (zero counters, never-observed

@@ -1216,7 +1216,7 @@ class DeepSpeedEngine:
             if self.global_steps % self.config.steps_per_print == 0:
                 # full telemetry-registry snapshot rides the monitor fan-
                 # out at the print cadence (one source of truth: the same
-                # names the /metrics endpoint and bench.py read)
+                # names the /metrics endpoint and the tests read)
                 self._monitor_write(self.monitor.write_registry_snapshot,
                                     self.global_samples)
         if self.config.wall_clock_breakdown and \

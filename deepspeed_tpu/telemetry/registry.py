@@ -3,7 +3,7 @@
 One process-wide named namespace (``ds_<area>_<name>``) that the serving
 counters, the CollectiveScheduler wire plan, the KV-pool page states,
 the training throughput timer, and the serving SLO histograms all write
-into — so bench.py, tests, the monitor writers, and the Prometheus
+into — so the benchmark, tests, the monitor writers, and the Prometheus
 endpoint read a single source of truth instead of four ad-hoc
 mechanisms.
 

@@ -47,7 +47,7 @@ class ServingCounters:
     ISSUE 4: the storage is the telemetry registry's ``ds_serving_*``
     counters — this class is now a facade (record methods + legacy field
     names as properties + the derived per-step snapshot) over the one
-    source of truth that bench.py, the /metrics endpoint, and the
+    source of truth that the benchmark, the /metrics endpoint, and the
     monitor all read."""
 
     def __init__(self):

@@ -25,6 +25,8 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from deepspeed_tpu.inference.v2.step_key import (StepKey, step_avals,
+                                                 step_program)
 from deepspeed_tpu.ops.flash_attention import flash_attention
 from deepspeed_tpu.ops.fused_optimizer import (fused_adamw_flat,
                                                fused_lamb_flat,
@@ -281,11 +283,11 @@ def test_step_program_leaves_the_pool_in_place(chip, monkeypatch, kind, int8,
         page_size=PAGE, num_pages=pages,
         quantization="int8" if int8 else "none"))
     pool = _pool(chip, int8, layers, pages)
-    key = STEP_KEYS[kind]
+    key = StepKey.parse(STEP_KEYS[kind])
     avals = jax.tree.map(
         lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
-        serve._step_avals(key, pool))
-    compiled = jax.jit(serve._impl_of(key),
+        step_avals(serve, key, pool))
+    compiled = jax.jit(step_program(serve, key),
                        donate_argnums=(1,)).lower(*avals).compile()
     payload = jax.tree.leaves(pool)[0]
     layer_bytes = int(np.prod(payload.shape[1:])) * payload.dtype.itemsize
@@ -448,11 +450,11 @@ def test_pangu_step_program_moves_no_pool_and_no_expert_stack(
         KVCacheConfig(num_layers=layers, kv_heads=1, head_dim=MLA_PLANE,
                       planes=1, page_size=PAGE, num_pages=MLA_POOL)))
     pool = _latent_pool(chip, layers)
-    key = PANGU_STEP_KEYS[kind]
+    key = StepKey.parse(PANGU_STEP_KEYS[kind])
     avals = jax.tree.map(
         lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
-        serve._step_avals(key, pool))
-    compiled = jax.jit(serve._impl_of(key),
+        step_avals(serve, key, pool))
+    compiled = jax.jit(step_program(serve, key),
                        donate_argnums=(1,)).lower(*avals).compile()
     text = compiled.as_text()
     for kernel in ("mla_attention_decode", "latent_write_decode",

@@ -89,11 +89,10 @@ def test_runtime_config_block_flows_to_v2():
 # satellite: lattice floors are exported constants, not introspection
 # ---------------------------------------------------------------------------
 
-def test_bucket_floor_constants_match_build_batch_defaults():
-    import inspect
-    params = inspect.signature(rb.build_batch).parameters
-    assert params["min_slots"].default == rb.MIN_SLOTS
-    assert params["min_pages"].default == rb.MIN_PAGES
+def test_bucket_floor_constants_are_the_default_lattice_floors():
+    from deepspeed_tpu.inference.v2.lattice import POWER_LATTICE
+    assert POWER_LATTICE.shape(1, 1, 1) == (rb.MIN_SLOTS, 1, rb.MIN_PAGES)
+    assert not POWER_LATTICE.mined and POWER_LATTICE.digest == ""
 
 
 # ---------------------------------------------------------------------------

@@ -167,7 +167,7 @@ class TestQuantOps:
         """bytes_per_page with int8 + fp32 scale sidecar vs fp32 pages:
         4D/(D+4) — 3.2x at D=16, and >= 1.7x for every D >= 3, which is
         what turns a fixed byte budget into >= 1.7x resident
-        sequences (the check_bench gate measures the same ratio)."""
+        sequences (a count, not a time)."""
         fp = KVCacheConfig(num_layers=2, kv_heads=2, head_dim=16,
                            page_size=PAGE, num_pages=1,
                            dtype=jnp.float32)

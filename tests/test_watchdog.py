@@ -7,16 +7,14 @@ hit/miss/compile-on-path counters on a deliberately un-precompiled
 bucket, the postmortem bundle (five artifacts, all loadable, written
 automatically when an exception escapes ``train_batch`` / the FastGen
 step loop), the ``/healthz`` endpoint — plus the satellites: the
-monitor-write drop counter, the ``DS_POSTMORTEM_ON_EXIT`` handler, the
-``tools/check_bench.py`` regression gate, and the disabled-path
-overhead bound for every new instrumentation site.
+monitor-write drop counter, the ``DS_POSTMORTEM_ON_EXIT`` handler, and
+the disabled-path overhead bound for every new instrumentation site.
 """
 
 import json
 import math
 import os
 import signal
-import sys
 import time
 import urllib.error
 import urllib.request
@@ -540,42 +538,6 @@ def test_telemetry_config_block_configures_watchdog():
     finally:
         rec.resize(1024)
         wd.configure(enabled=True, threshold=3.0, warmup=8)
-
-
-def test_check_bench_gate(tmp_path):
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "tools"))
-    import check_bench
-
-    def write(n, parsed):
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(
-            json.dumps({"parsed": parsed}))
-
-    write(1, {"value": 100.0, "fastgen_decode_tok_s": 400.0,
-              "fastgen_ttft_p50_ms": 30.0})
-    write(2, {"value": 95.0, "fastgen_decode_tok_s": 390.0,
-              "fastgen_ttft_p50_ms": 33.0})
-    # within tolerances: clean under --strict
-    assert check_bench.main(["--dir", str(tmp_path), "--strict"]) == 0
-    # throughput drop >10% and latency growth >15%: warn-only passes,
-    # --strict fails
-    write(3, {"value": 80.0, "fastgen_decode_tok_s": 390.0,
-              "fastgen_ttft_p50_ms": 40.0})
-    assert check_bench.main(["--dir", str(tmp_path)]) == 0
-    assert check_bench.main(["--dir", str(tmp_path), "--strict"]) == 1
-    # a failed round (parsed: null) is skipped as the comparison base
-    write(4, None)
-    write(5, {"value": 81.0, "fastgen_ttft_p50_ms": 41.0})
-    assert check_bench.main(["--dir", str(tmp_path), "--strict"]) == 0
-    # cross-backend rounds downgrade regressions to notes
-    write(6, {"value": 30.0, "cpu_fallback": True,
-              "fastgen_ttft_p50_ms": 300.0})
-    assert check_bench.main(["--dir", str(tmp_path), "--strict"]) == 0
-    # classification: totals/compile_s/error keys are never gated
-    assert check_bench.classify("fastgen_step_cache_miss_total") is None
-    assert check_bench.classify("fastgen_compile_s") is None
-    assert check_bench.classify("train_goodput_ratio") == "throughput"
-    assert check_bench.classify("fastgen_step_p99_ms") == "latency"
 
 
 def test_disabled_path_overhead_for_new_sites():

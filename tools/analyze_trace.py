@@ -144,9 +144,11 @@ def observed_keys(trace: Dict[str, Any]) -> Dict[tuple, int]:
     """Occupancy union: step-key summaries plus on-path compiles (a
     compiled key was dispatched at least once even if the process died
     before its ``keys`` summary flushed)."""
-    occ = {tuple(k): int(n) for k, n in trace["key_counts"].items()}
+    from deepspeed_tpu.inference.v2.step_key import StepKey
+    occ = {StepKey.parse(k): int(n)
+           for k, n in trace["key_counts"].items()}
     for k in trace["compiles"]:
-        occ.setdefault(tuple(k), 1)
+        occ.setdefault(StepKey.parse(k), 1)
     return occ
 
 
@@ -175,13 +177,11 @@ def analyze(trace: Dict[str, Any], max_concurrency: int = 0,
     # spec keys in the traffic imply speculation was on: widen the
     # current lattice with the observed spec Q bucket so enabled
     # speculation isn't misreported as uncovered
-    spec_q = max((int(k[1]) for k in occ
-                  if len(k) > 4 and k[4] in ("spec", "draft_spec")),
-                 default=0)
+    spec_q = max((k.Q for k in occ
+                  if k.kind in ("spec", "draft_spec")), default=0)
     # draft_spec/draft_fill keys imply a draft trunk was live: widen
     # the current lattice with the draft twins (ISSUE 17)
-    draft_seen = any(len(k) > 4 and k[4] in ("draft_spec", "draft_fill")
-                     for k in occ)
+    draft_seen = any(k.kind in ("draft_spec", "draft_fill") for k in occ)
     current = set(lattice_keys(
         max_prompt=max(prompt_lens), max_new_tokens=max(
             max(int(r["gen_len"]) for r in requests), 1),
@@ -197,7 +197,7 @@ def analyze(trace: Dict[str, Any], max_concurrency: int = 0,
                             max_buckets=max_buckets)
     p_buckets = fit_buckets([-(-t // page) for t in total_lens],
                             ratio=ratio, max_buckets=max_buckets)
-    s_buckets = sorted({int(k[0]) for k in occ}) or [mc]
+    s_buckets = sorted({k.S for k in occ}) or [mc]
     # the recommended precompile set: every key traffic actually formed
     # — which the fitted boundaries above would re-generate once
     # build_batch learns non-power lattices (ROADMAP item 5).  The

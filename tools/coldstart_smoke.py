@@ -29,8 +29,7 @@ CLI::
 
 ``--check`` exits non-zero unless parity holds and the warm-cache
 resume is recompile-free (the ``tools/ci.sh`` smoke mode); ``--full``
-adds the no-cache cold leg (the BENCH_COLDSTART mode, via
-:func:`run_coldstart_bench`).
+adds the no-cache cold leg.
 """
 
 from __future__ import annotations
@@ -400,20 +399,6 @@ def coldstart_gates(report: Dict[str, Any]) -> List[str]:
         problems.append("warm-cache restore loaded nothing from the "
                         "persistent cache")
     return problems
-
-
-def run_coldstart_bench() -> Dict[str, Any]:
-    """The BENCH_COLDSTART=1 leg: full three-way comparison + the
-    25%-of-warm restore-to-first-token gate (soft: emitted as a
-    finding key, hard-gated by tools/check_bench.py in-round)."""
-    report = run_coldstart(full=True)
-    warm = report.get("coldstart_restore_ttft_warm_ms")
-    cached = report.get("coldstart_restore_ttft_warmcache_ms")
-    if warm and cached:
-        report["coldstart_ttft_warmcache_over_warm"] = round(
-            cached / warm, 3)
-    report["coldstart_gates_failed"] = len(coldstart_gates(report))
-    return report
 
 
 def main(argv=None) -> int:

@@ -50,7 +50,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 #: the production tree dslint walks (tests are deliberately excluded —
 #: contracts bind shipped code; tools/dslint itself is excluded so the
 #: linter's own pattern tables stay out of its jurisdiction)
-SCAN_ROOTS = ("deepspeed_tpu", "tools", "bench.py")
+SCAN_ROOTS = ("deepspeed_tpu", "tools")
 EXCLUDE_DIRS = ("__pycache__", os.path.join("tools", "dslint"))
 
 #: every rule id a ``disable=`` may name (passes register theirs at

@@ -6,8 +6,8 @@ Builds a CPU-debug engine, starts the telemetry endpoint on an
 EPHEMERAL port (the ``DS_METRICS_PORT=0`` satellite — N replicas on a
 host never collide), enables the time-series sampler, and drives a
 deterministic synthetic workload in rounds.  The parent (a federation
-test, ``tools/fleetctl.py --smoke``, or bench.py's ``BENCH_FLEET``
-leg) reads the handshake line::
+test or ``tools/fleetctl.py --smoke`` / ``--kill-demo``) reads the
+handshake line::
 
     FLEET_REPLICA ready label=<label> port=<port> pid=<pid>
 

@@ -7,10 +7,9 @@ A stdlib-only CLI over the federation layer
 ``/snapshot?raw=1``, merge (counters sum, gauges roll up min/max/sum,
 log-bucketed histograms merge EXACTLY), and print status / JSON /
 Prometheus text.  Also hosts the two-replica smoke used by
-``tools/ci.sh``, the replica-kill fleet bench behind bench.py's
-``BENCH_FLEET=1`` leg, and the ISSUE 12 replica-pool legs: the CI
-pool smoke (two in-process replicas behind the prefix-affinity router,
-one migrated mid-replay) and the ``BENCH_POOL=1`` kill/add demo.
+``tools/ci.sh``, the replica-kill fleet demo, and the ISSUE 12
+replica-pool legs: the CI pool smoke (two in-process replicas behind the
+prefix-affinity router, one migrated mid-replay) and the kill/add demo.
 
 Usage::
 
@@ -28,13 +27,12 @@ Usage::
                                            # (ISSUE 20)
     python tools/fleetctl.py --smoke       # CI: two debug replicas,
                                            # merged counters == sum
-    python tools/fleetctl.py --kill-demo   # bench: two replicas, one
+    python tools/fleetctl.py --kill-demo   # demo: two replicas, one
                                            # killed mid-replay via the
                                            # serving.preempt chaos site
     python tools/fleetctl.py --pool-smoke  # CI: replica pool, affinity
                                            # router, migrate mid-replay
-    python tools/fleetctl.py --pool-demo   # bench: pool kill/add demo
-                                           # (BENCH_POOL keys)
+    python tools/fleetctl.py --pool-demo   # demo: pool kill/add
 
 ``digests`` prints each target's ``/snapshot?digests=1`` prefix-cache
 affinity hint — the subprocess-mode routing input (ISSUE 12).
@@ -170,7 +168,7 @@ def run_smoke() -> int:
             r.terminate()
 
 
-# -- replica-kill fleet event (BENCH_FLEET) ----------------------------------
+# -- replica-kill fleet event (--kill-demo) -----------------------------------
 def run_kill_demo(step_sleep_s: float = 0.05, rounds: int = 150,
                   kill_at_step: int = 90,
                   sample_every_s: float = 0.2,
@@ -288,7 +286,7 @@ def run_kill_demo(step_sleep_s: float = 0.05, rounds: int = 150,
             r.terminate()
 
 
-# -- replica pool (ISSUE 12): CI smoke + BENCH_POOL kill/add demo ------------
+# -- replica pool (ISSUE 12): CI smoke + kill/add demo ------------------------
 SAMPLE_TRACE = os.path.join(REPO_ROOT, "tools", "traces",
                             "sample_200.jsonl")
 
@@ -496,7 +494,7 @@ def _pool_run_pass(meta, requests, prompts, params, engines,
 def run_pool_demo(limit: int = 24, pace_s: float = 0.01,
                   wave: int = 4, wave_gap_s: float = 0.15
                   ) -> Dict[str, Any]:
-    """The BENCH_POOL leg (ISSUE 12): the replayed shared-prefix trace
+    """The ``--pool-demo`` leg (ISSUE 12): the replayed shared-prefix trace
     driven through (a) one replica, (b) two replicas under round-robin
     routing (the affinity control arm), (c) two replicas under the
     prefix-affinity router, and (d) the affinity pool with an abrupt
@@ -725,8 +723,7 @@ def main(argv=None) -> int:
                     "behind the affinity router, one drain-migrated "
                     "mid-replay; assert parity and zero lost requests")
     ap.add_argument("--pool-demo", action="store_true",
-                    help="replica pool kill/add demo; print the "
-                    "BENCH_POOL keys")
+                    help="replica pool kill/add demo; print its report")
     ap.add_argument("--limit", type=int, default=0,
                     help="pool legs: replay only the first N trace "
                     "requests (0 = leg default)")

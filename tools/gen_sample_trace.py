@@ -11,8 +11,7 @@ change::
 
     python tools/gen_sample_trace.py [--out tools/traces/sample_200.jsonl]
 
-The trace is the fixture for the ``BENCH_REPLAY=1`` bench leg and the
-``tools/ci.sh`` replay smoke.
+The trace is the fixture for the ``tools/ci.sh`` replay smoke.
 """
 
 from __future__ import annotations

@@ -582,8 +582,10 @@ def replay_disagg(prefill_engine, decode_engine,
         "prefill_busy_s": round(pool.prefill_busy_s, 4),
         "decode_hbm_gb_s": float(cost["decode_hbm_gb_s"]),
         "decode_busy_s": round(pool.decode_busy_s, 4),
-        "programs_prefill": len(prefill_engine.model._step_cache),
-        "programs_decode": len(decode_engine.model._step_cache),
+        "programs_prefill": len(
+            prefill_engine.compiled_keys(dispatched_only=False)),
+        "programs_decode": len(
+            decode_engine.compiled_keys(dispatched_only=False)),
         "journeys": journeys_report,
     }
 
@@ -596,7 +598,7 @@ def run_replay_disagg(trace_path: str, limit: int = 0,
                       journeys: bool = False) -> Dict[str, Any]:
     """load → synthesize → (shape-warmup) → measured two-pool replay →
     structural diff: the disagg counterpart of :func:`run_replay`,
-    behind the CI disagg smoke and bench.py's BENCH_DISAGG leg."""
+    behind the CI disagg smoke."""
     trace = load_trace(trace_path)
     requests = trace["requests"]
     if not include_errors:
@@ -627,147 +629,6 @@ def run_replay_disagg(trace_path: str, limit: int = 0,
             "replay": report, "diff": verdict}
 
 
-def run_disagg_bench(trace_path: Optional[str] = None,
-                     limit: Optional[int] = None) -> Dict[str, Any]:
-    """The BENCH_DISAGG leg (ISSUE 13): the same replayed mixed trace
-    through (a) the fused single-pool scheduler and (b) the two-pool
-    disaggregated scheduler, both with keyed sampling so the
-    output-identity claim covers the trace's SAMPLED requests too.
-    Both passes run SINGLE-threaded: the step/handoff sequence is then
-    deterministic (warmup covers exactly the measured keys — 0
-    on-path compiles by construction) and the per-pool MFU/HBM
-    numbers come from busy-window accounting, so they measure program-
-    mix specialization, not thread overlap (the threaded serve path is
-    covered by tests/test_disagg.py).  Emits the acceptance numbers:
-    prefill-pool MFU and decode-pool HBM GB/s vs the fused baseline's
-    corresponding gauges, per-pool compiled/enumerated program counts
-    vs the fused lattice's, handoff p50 ms, aggregate tok/s ratio,
-    on-path compiles, lost requests, and tokenwise identity."""
-    from deepspeed_tpu.inference.v2 import ServingOptimizationConfig
-    from deepspeed_tpu.inference.v2.engine import lattice_keys
-    from deepspeed_tpu.telemetry import metrics as tm
-
-    if trace_path is None:
-        trace_path = os.environ.get(
-            "BENCH_DISAGG_TRACE",
-            os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         "traces", "sample_200.jsonl"))
-    if limit is None:
-        limit = int(os.environ.get("BENCH_DISAGG_LIMIT", "64"))
-    trace = load_trace(trace_path)
-    requests = [r for r in trace["requests"]
-                if r.get("outcome") == "ok"]
-    if limit:
-        requests = requests[:limit]
-    # decode-weighted variant of the trace: disaggregation is built
-    # for workloads with a real steady-state decode phase, and the
-    # captured sample's gen lengths (~4 tokens) end before the decode
-    # pool's chain warms up — scale them (prompts/sharing/arrivals
-    # untouched; both arms serve the SAME scaled workload)
-    gen_scale = int(os.environ.get("BENCH_DISAGG_GEN_SCALE", "4"))
-    if gen_scale > 1:
-        requests = [dict(r, gen_len=int(r["gen_len"]) * gen_scale)
-                    for r in requests]
-    meta = trace["meta"]
-    page = int(meta.get("page_size", 16))
-
-    # -- fused single-pool baseline (keyed, like the disagg pools) ----
-    fused_eng = build_replay_engine(
-        meta, requests,
-        serving=ServingOptimizationConfig(keyed_sampling=True))
-    vocab = min(int(meta.get("vocab_size", 0))
-                or fused_eng.model.cfg.vocab_size,
-                fused_eng.model.cfg.vocab_size)
-    prompts = synthesize_prompts(requests, page, vocab)
-    replay(fused_eng, requests, prompts)            # shape warmup
-    _reset_engine(fused_eng)
-    fused_eng.model.reset_cost_window()
-    comp0 = tm.FASTGEN_COMPILE_ON_PATH.value
-    fused_tokens: Dict[int, List[int]] = {}
-    fused_rep = replay(
-        fused_eng, requests, prompts,
-        on_token=lambda u, t: fused_tokens.setdefault(u, []).append(t))
-    # SAME busy-window accounting as the disagg pools (seconds inside
-    # scheduler steps), so the specialization inequalities compare
-    # like with like
-    from deepspeed_tpu.inference.v2.model import (serving_peak_flops,
-                                                  utilization)
-    fused_cost = fused_eng.model.cost_summary()
-    fused_busy = max(float(fused_rep.get("busy_s") or 0.0), 1e-9)
-    fused_mfu = utilization(
-        float(fused_cost.get("flops_dispatched", 0.0)) / fused_busy,
-        serving_peak_flops())
-    fused_hbm = (float(fused_cost.get("bytes_dispatched", 0.0))
-                 / fused_busy / 1e9)
-    fused_compiles = tm.FASTGEN_COMPILE_ON_PATH.value - comp0
-
-    # -- two-pool disaggregated run -----------------------------------
-    pre_eng, dec_eng = build_disagg_engines(meta, requests)
-    replay_disagg(pre_eng, dec_eng, requests, prompts)  # shape warmup
-    _reset_engine(pre_eng)
-    _reset_engine(dec_eng)
-    pre_eng.model.reset_cost_window()
-    dec_eng.model.reset_cost_window()
-    # measured pass single-threaded: the step/handoff sequence is then
-    # DETERMINISTIC, so the warmup compiled exactly the keys the
-    # measured run forms (0 on-path compiles by construction, the
-    # acceptance bar) and the busy-window MFU/HBM numbers are stable
-    disagg_tokens: Dict[int, List[int]] = {}
-    rep = replay_disagg(
-        pre_eng, dec_eng, requests, prompts,
-        on_token=lambda u, t: disagg_tokens.setdefault(u, []).append(t))
-
-    identical = all(fused_tokens.get(i) == disagg_tokens.get(i)
-                    for i in range(len(requests)))
-    # enumerated (not just exercised) lattice sizes, each with ITS
-    # engine's geometry (the decode pool's wider slot range included):
-    # the compile-time claim each pool's kinds= filter buys
-    def lat(engine):
-        sm = engine._config.state_manager
-        return dict(
-            max_prompt=max(int(r["prompt_len"]) for r in requests),
-            max_new_tokens=max(int(r["gen_len"]) for r in requests),
-            max_concurrency=sm.max_ragged_sequence_count,
-            page_size=page,
-            max_ragged_batch_size=sm.max_ragged_batch_size,
-            has_fresh=getattr(engine.model, "_fresh_attention",
-                              None) is not None,
-            sampling=True, spec_max_draft=0)
-    out = {
-        "disagg_requests": len(requests),
-        "disagg_agg_tok_s": rep["decode_tok_s"],
-        "disagg_fused_tok_s": fused_rep["decode_tok_s"],
-        "disagg_speedup_vs_fused": (
-            round(rep["decode_tok_s"] / fused_rep["decode_tok_s"], 3)
-            if fused_rep["decode_tok_s"] else None),
-        "disagg_prefill_mfu": round(rep["prefill_mfu"], 9),
-        "disagg_fused_mfu": round(fused_mfu, 9),
-        "disagg_decode_hbm_gb_s": round(rep["decode_hbm_gb_s"], 4),
-        "disagg_fused_hbm_gb_s": round(fused_hbm, 4),
-        "disagg_handoff_p50_ms": rep["handoff_p50_ms"],
-        "disagg_handoffs": rep["handoffs"],
-        "disagg_handoff_bytes": rep["handoff_bytes"],
-        "disagg_pages_streamed": rep["pages_streamed"],
-        "disagg_pages_shared": rep["pages_shared"],
-        "disagg_programs_prefill": rep["programs_prefill"],
-        "disagg_programs_decode": rep["programs_decode"],
-        "disagg_programs_fused": len(fused_eng.model._step_cache),
-        "disagg_lattice_prefill": len(lattice_keys(
-            kinds=("prefill", "decode"), **lat(pre_eng))),
-        "disagg_lattice_decode": len(lattice_keys(
-            kinds=("decode", "chain", "spec"), **lat(dec_eng))),
-        "disagg_lattice_fused": len(lattice_keys(**lat(fused_eng))),
-        "disagg_compile_on_path_total": rep["compile_on_path"],
-        "disagg_fused_compile_on_path_total": fused_compiles,
-        "disagg_lost_requests": rep["lost"],
-        "disagg_tokenwise_identical": int(identical),
-        "disagg_ttft_p50_ms": rep["ttft_p50_ms"],
-        "disagg_fused_ttft_p50_ms": fused_rep["ttft_p50_ms"],
-    }
-    return out
-
-
-# -- the tiered-KV replay legs (ISSUE 16) ------------------------------------
 def build_tier_engine(meta: Dict[str, Any],
                       requests: List[Dict[str, Any]],
                       device_pages: int = 4,
@@ -894,246 +755,6 @@ def run_tier_smoke(trace_path: str, limit: int = 0,
         shutil.rmtree(tier_dir, ignore_errors=True)
 
 
-def run_tier_bench(trace_path: Optional[str] = None,
-                   limit: Optional[int] = None) -> Dict[str, Any]:
-    """The BENCH_TIER leg (ISSUE 16), three sub-legs over one replayed
-    multi-user trace:
-
-    1. **Capacity + quantization overhead**: int8 pages at the SAME
-       device byte budget as the fp pool — resident-sequence counts
-       from the honest ``bytes_per_page`` accounting (the >= 1.7x
-       check_bench gate) — and a measured fp-vs-int8 replay for the
-       TTFT p99 before/after comparison (the flat-within-15% gate).
-    2. **Host/disk tier**: a device-starved tiered engine replays the
-       trace twice; wave 2's per-request tier attribution is captured
-       into a private workload ledger and mined for the fleet-wide
-       prefix hit rate split by tier, plus promote-batch p50 ms.
-    3. **Cross-replica fetch**: a 2-replica pool serves the same
-       warm-prefix request once with page fetch on (affinity loses to
-       least-backlog, pages stream replica-to-replica) and once cold
-       with fetch off under an identical backlog shape — fetch TTFT
-       must beat recompute-prefill TTFT."""
-    import dataclasses as _dc
-    import shutil
-    import tempfile
-
-    import jax.numpy as jnp
-    from deepspeed_tpu.inference.v2 import (FastGenScheduler,
-                                            SamplingParams,
-                                            ServingOptimizationConfig)
-    from deepspeed_tpu.inference.v2.lattice import load_trace_facts
-    from deepspeed_tpu.inference.v2.ragged.kv_cache import (
-        KVCacheConfig, pages_for_memory)
-    from deepspeed_tpu.serving import ReplicaPool
-    from deepspeed_tpu.telemetry import metrics as tm
-    from deepspeed_tpu.telemetry.workload_trace import get_workload_trace
-
-    if trace_path is None:
-        trace_path = os.environ.get(
-            "BENCH_TIER_TRACE",
-            os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         "traces", "sample_200.jsonl"))
-    if limit is None:
-        limit = int(os.environ.get("BENCH_TIER_LIMIT", "48"))
-    trace = load_trace(trace_path)
-    requests = [r for r in trace["requests"]
-                if r.get("outcome") == "ok"]
-    if limit:
-        requests = requests[:limit]
-    if not requests:
-        raise ValueError(f"{trace_path}: no replayable requests")
-    meta = trace["meta"]
-    cfg, params, page, need = _replay_model_parts(meta, requests)
-    per_seq = -(-need // page)
-    max_seqs = 32
-
-    # -- capacity at equal device bytes (the honest accounting the
-    # allocator itself sizes pools with — pages_for_memory).  The
-    # byte budget is deliberately CONSTRAINED (8 worst-case fp
-    # sequences for a 32-request wave): KV capacity, not FLOPs, is
-    # what caps concurrency, so the before/after TTFT comparison must
-    # run where that constraint binds — the fp pool queues on pages
-    # while the int8 pool holds ~3x the sequences in the same bytes
-    fp_pages = 8 * (per_seq + 1)
-    fp_kv = KVCacheConfig(num_layers=cfg.num_layers,
-                          kv_heads=cfg.kv_heads,
-                          head_dim=cfg.dims_per_head, page_size=page,
-                          num_pages=fp_pages, dtype=jnp.float32)
-    budget = fp_pages * fp_kv.bytes_per_page
-    q_pages = pages_for_memory(_dc.replace(fp_kv, quantization="int8"),
-                               budget)
-    out: Dict[str, Any] = {
-        "tier_requests": len(requests),
-        "tier_device_budget_mb": round(budget / 1e6, 2),
-        "tier_resident_seqs_fp": fp_pages // per_seq,
-        "tier_resident_seqs_int8": q_pages // per_seq,
-        "tier_resident_seq_ratio": round(
-            (q_pages // per_seq) / max(fp_pages // per_seq, 1), 3),
-    }
-
-    vocab = min(int(meta.get("vocab_size", 0)) or cfg.vocab_size,
-                cfg.vocab_size)
-    prompts = synthesize_prompts(requests, page, vocab)
-
-    # -- leg 1: fp baseline vs int8 at the same byte budget ----------
-    fp_eng = _build_engine(cfg, params, page, need, fp_pages, max_seqs)
-    replay(fp_eng, requests, prompts)            # shape warmup
-    _reset_engine(fp_eng)
-    before = replay(fp_eng, requests, prompts)
-    q_eng = _build_engine(
-        cfg, params, page, need, q_pages, max_seqs,
-        serving=ServingOptimizationConfig(kv_quantization="int8"))
-    replay(q_eng, requests, prompts)             # shape warmup
-    _reset_engine(q_eng)
-    after = replay(q_eng, requests, prompts)
-    out.update({
-        "tier_ttft_p99_before_ms": before["ttft_p99_ms"],
-        "tier_ttft_p99_after_ms": after["ttft_p99_ms"],
-        "tier_fp_decode_tok_s": before["decode_tok_s"],
-        "tier_int8_decode_tok_s": after["decode_tok_s"],
-        "tier_fp_compile_on_path": before["compile_on_path"],
-        "tier_int8_compile_on_path": after["compile_on_path"],
-        "tier_compile_on_path_total": (before["compile_on_path"]
-                                       + after["compile_on_path"]),
-    })
-
-    # -- leg 2: host/disk tier, warm wave mined from its own ledger --
-    tier_dir = tempfile.mkdtemp(prefix="ds_tier_bench_")
-    t_eng = None
-    try:
-        t_eng = build_tier_engine(meta, requests, device_pages=4,
-                                  host_pages=max(8, per_seq),
-                                  disk_pages=4096, tier_dir=tier_dir)
-        cold = replay(t_eng, requests, prompts)  # wave 1: demotes
-        # wave 2 is the WARM-shape warmup: promotion-warmed requests
-        # form mixed-kind step keys a cold wave never dispatches, so
-        # measuring wave 2 would eat their XLA compiles on-path.  The
-        # tier state cycles (promote -> park -> demote again), so wave
-        # 3 re-forms the same matched-page counts = the same keys.
-        replay(t_eng, requests, prompts)
-        # wave 3 measured, into a PRIVATE ledger: the per-request
-        # tier-hit attribution is then mined exactly the way
-        # tools/analyze_trace.py mines a production capture
-        ledger = os.path.join(tier_dir, "tier_warm_wave.jsonl")
-        wt = get_workload_trace()
-        wt.configure(ledger)
-        try:
-            warm = replay(t_eng, requests, prompts, capture=True)
-        finally:
-            wt.close()
-        stats = t_eng.state_manager.tiers.stats()
-        recs = load_trace_facts(ledger)["requests"]
-        prompt_tokens = sum(int(r["prompt_len"]) for r in recs) or 1
-        hits = {t: sum(int(r.get(f"hit_{t}", 0)) for r in recs)
-                for t in ("device", "host", "disk", "remote")}
-        out.update({
-            "tier_prefix_hit_rate": round(
-                sum(hits.values()) / prompt_tokens, 4),
-            "tier_device_hit_rate": round(
-                hits["device"] / prompt_tokens, 4),
-            "tier_host_hit_rate": round(
-                hits["host"] / prompt_tokens, 4),
-            "tier_disk_hit_rate": round(
-                hits["disk"] / prompt_tokens, 4),
-            "tier_remote_hit_rate": round(
-                hits["remote"] / prompt_tokens, 4),
-            "tier_demoted_pages": stats["demoted_pages"],
-            "tier_promoted_pages": stats["promoted_pages"],
-            "tier_spilled_pages": stats["spilled_pages"],
-            "tier_io_errors": stats["io_errors"],
-            "tier_cold_ttft_p99_ms": cold["ttft_p99_ms"],
-            "tier_warm_ttft_p99_ms": warm["ttft_p99_ms"],
-            "tier_promote_p50_ms": (
-                round(tm.KV_TIER_PROMOTE_MS.percentile(50), 3)
-                if tm.KV_TIER_PROMOTE_MS.count else None),
-            "tier_warm_compile_on_path": warm["compile_on_path"],
-        })
-        out["tier_compile_on_path_total"] += warm["compile_on_path"]
-    finally:
-        if t_eng is not None:
-            t_eng.state_manager.close()
-        shutil.rmtree(tier_dir, ignore_errors=True)
-
-    # -- leg 3: cross-replica page fetch vs recompute-prefill --------
-    # fetch exists to dodge LONG prefix recomputes, so the measured
-    # prefix is long (20 pages) — streaming 20 committed pages is a
-    # host-side copy, recomputing them is a full-width prefill
-    # dispatch.  Own model geometry: the trace-sized engines above
-    # cannot seat a 20-page prompt.
-    fetch_prefix_pages = 20
-    fetch_need = (fetch_prefix_pages + 2) * page + 16
-    fetch_fake = [{"prompt_len": fetch_need - page, "gen_len": 8}]
-    fcfg, fparams, _, _ = _replay_model_parts(meta, fetch_fake)
-    engines: Dict[str, Any] = {}
-
-    def factory(label):
-        eng = engines.get(label)
-        if eng is None:
-            eng = _build_engine(fcfg, fparams, page, fetch_need, 0, 8)
-            engines[label] = eng
-        return FastGenScheduler(eng)
-
-    def _p(seed_, n):
-        rng = np.random.default_rng(seed_)
-        return rng.integers(0, vocab, n,
-                            dtype=np.int64).astype(np.int32)
-
-    warm_prefix = _p(1, fetch_prefix_pages * page)
-    full = np.concatenate([warm_prefix, _p(2, page // 2)])
-    sp = SamplingParams(max_new_tokens=8, temperature=0.0)
-
-    def scenario(margin, warm):
-        """One placement scenario; both arms see the SAME backlog
-        shape (2 queued on r0, 1 on r1) so the measured request's
-        TTFT differs only by fetch-vs-recompute, not queue depth."""
-        for eng in engines.values():
-            for uid in list(eng.state_manager._seqs):
-                eng.flush(uid)
-            eng.reset_prefix_cache()
-        pool = ReplicaPool(factory, replicas=2,
-                           page_fetch_margin=margin)
-        if warm:
-            pool.submit(1, warm_prefix, sp)
-            pool.run_to_completion()
-            pool.publish_hints()
-        for uid, s in ((2, 7), (3, 8), (4, 9)):
-            pool.submit(uid, _p(s, 3 * page), sp)
-        pool.submit(100, full, sp)
-        pool.run_to_completion()
-        req = pool.request(100)
-        return ((req.first_token_mono - req.submit_mono) * 1e3,
-                req.replica)
-
-    # the warmup must include an actual FETCH: the import side's
-    # restore program is a compiled shape of its own, and eating that
-    # XLA compile inside the measured fetch TTFT would swamp the
-    # transfer-vs-recompute comparison
-    scenario(0, True)
-    scenario(-1, False)
-    f0, fp0 = tm.POOL_PAGE_FETCHES.value, tm.POOL_PAGE_FETCH_PAGES.value
-    # best-of-3 per arm: single-request TTFT on a shared CPU carries
-    # ms-scale scheduler jitter that would drown a transfer-vs-prefill
-    # delta measured once
-    fetch_ttft, fetch_rep = min(
-        scenario(0, True) for _ in range(3))
-    fetches = tm.POOL_PAGE_FETCHES.value - f0
-    recompute_ttft = min(
-        scenario(-1, False)[0] for _ in range(3))
-    out.update({
-        "tier_fetch_prefix_tokens": len(warm_prefix),
-        "tier_fetch_ttft_ms": round(fetch_ttft, 3),
-        "tier_recompute_ttft_ms": round(recompute_ttft, 3),
-        "tier_fetch_speedup_vs_recompute": (
-            round(recompute_ttft / fetch_ttft, 3) if fetch_ttft
-            else None),
-        "tier_fetch_count": fetches,
-        "tier_fetch_pages": tm.POOL_PAGE_FETCH_PAGES.value - fp0,
-        "tier_fetch_replica": fetch_rep,
-    })
-    return out
-
-
-# -- recorded-vs-replayed diff -----------------------------------------------
 def recorded_percentiles(requests: List[Dict[str, Any]]
                          ) -> Dict[str, Optional[float]]:
     ttfts = [r.get("ttft_ms") for r in requests]
@@ -1211,9 +832,8 @@ def run_replay(trace_path: str, limit: int = 0,
                drafter: str = "ngram",
                tp: int = 1) -> Dict[str, Any]:
     """The one load → filter → build → synthesize → (shape-warmup) →
-    measured-replay → diff sequence, shared by the CLI, the CI smoke,
-    and bench.py's BENCH_REPLAY leg — so the three can't drift on the
-    warmup convention or the vocab clamp.  With ``spec`` the same
+    measured-replay → diff sequence, shared by the CLI and the CI smoke
+    — so the two can't drift on the warmup convention or the vocab clamp.  With ``spec`` the same
     workload is replayed a second time with speculative decoding
     enabled and the report gains a ``spec`` block: accept rate, tok/s
     on/off, and the spec pass's own structural-parity diff (ISSUE 10 —
