@@ -43,8 +43,16 @@ SERVE_PAGES = 1024          # x 64 tokens: a 65k-token pool beside the weights
 #: often under one bf16 ulp, so two correct attention paths part ways at a
 #: few percent of the tokens (0.56 measured kernel vs dense gather); a wrong
 #: path agrees on ~1/vocab of them.  The first token of every request, which
-#: has no earlier divergence to inherit, must match exactly.
+#: has no earlier divergence to inherit, must match, except at a near-tie.
 MIN_REST_AGREEMENT = 0.3
+#: two runs' first tokens may differ where they are the two largest logits
+#: of the prompt's plain forward and closer than this: the margin under
+#: which the benchmark's probe skips a prompt (``probe.margin`` of
+#: benchmark/configs/mistral-7b-serve-8l.json), because bf16 rounding alone
+#: flips it.  Since a mixed step runs one trunk pass over decode rows and
+#: prefill tokens (PR 30), a prompt's rounding follows what shares its step;
+#: the flips measured then had float32 gaps of 0.005 and 0.027.
+NEAR_TIE = 0.1
 #: largest step-loss gap between the {fsdp: 4} mesh and one chip (bf16)
 FSDP_LOSS_TOLERANCE = 0.05
 #: pallas_call names as they appear in a compiled program's text
@@ -371,10 +379,45 @@ def agreement(a: dict, b: dict) -> dict:
             "rest_positionwise": round(same / max(total, 1), 4)}
 
 
-def require_agreement(agree: dict, what: str) -> None:
-    if (not agree["first_token_exact"]
-            or agree["rest_agreement"] < MIN_REST_AGREEMENT):
-        raise RuntimeError(f"{what} tokens disagree: {agree}")
+def first_token_ties(cfg, params, prompts, a: dict, b: dict, devices) -> dict:
+    """For every request whose first tokens differ between two runs: the
+    gap between the two tokens' logits in a plain forward of its prompt
+    (``engine.put``: no cache behind it, nothing beside it) and whether
+    they are that row's two largest.  {} where all first tokens agree."""
+    differ = [u for u in a if a[u][0] != b[u][0]]
+    if not differ:
+        return {}
+    import numpy as np
+    from deepspeed_tpu.inference.v2 import (
+        InferenceEngineV2, RaggedInferenceEngineConfig, StateManagerConfig)
+    from deepspeed_tpu.inference.v2.config import KVCacheUserConfig
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        MistralInferenceModel)
+    engine = InferenceEngineV2(
+        MistralInferenceModel(cfg, params), RaggedInferenceEngineConfig(
+            state_manager=StateManagerConfig(
+                max_tracked_sequences=len(differ),
+                max_ragged_batch_size=max(len(p) for p in prompts)),
+            kv_cache=KVCacheUserConfig(num_pages=SERVE_PAGES,
+                                       dtype=cfg.dtype)))
+    ties = {}
+    for u in differ:
+        row = np.asarray(engine.put([u], [np.asarray(prompts[u], np.int32)]),
+                         np.float32)[0]
+        pair = sorted((int(a[u][0]), int(b[u][0])))
+        ties[u] = {"tokens": pair,
+                   "gap": round(float(abs(row[pair[0]] - row[pair[1]])), 4),
+                   "top2": sorted(np.argsort(-row)[:2].tolist()) == pair}
+    return ties
+
+
+def require_agreement(agree: dict, what: str, ties=None) -> None:
+    """``ties``: :func:`first_token_ties` of the two runs; a first token
+    may differ only at a near-tie (:data:`NEAR_TIE`)."""
+    firsts = agree["first_token_exact"] or (ties and all(
+        t["top2"] and t["gap"] < NEAR_TIE for t in ties.values()))
+    if not firsts or agree["rest_agreement"] < MIN_REST_AGREEMENT:
+        raise RuntimeError(f"{what} tokens disagree: {agree} {ties or ''}")
 
 
 # ---------------------------------------------------------------------------
@@ -403,8 +446,9 @@ def phase_serve_one_chip(args, devices):
     ref, ref_facts = run_serve(cfg, params, prompts, news,
                                attention_impl="dense_gather", **common)
     agree = agreement(out, ref)
-    emit("serve_reference", **ref_facts, **agree)
-    require_agreement(agree, "kernel and dense-gather")
+    ties = first_token_ties(cfg, params, prompts, out, ref, devices[:1])
+    emit("serve_reference", **ref_facts, **agree, first_token_ties=ties)
+    require_agreement(agree, "kernel and dense-gather", ties)
 
 
 def phase_cache():
@@ -455,8 +499,9 @@ def phase_four_chips(args, devices):
     emit("serve_tp1", **facts1)
     tp4, facts4 = run_serve(cfg, params, prompts, news, tp_degree=4, **serve)
     agree = agreement(tp1, tp4)
-    emit("serve_tp4", **facts4, **agree)
-    require_agreement(agree, "tp=4 and tp=1")
+    ties = first_token_ties(cfg, params, prompts, tp1, tp4, devices[:1])
+    emit("serve_tp4", **facts4, **agree, first_token_ties=ties)
+    require_agreement(agree, "tp=4 and tp=1", ties)
     if (facts4["kv_device_set"] != 4 or facts4["params_device_set"] != 4
             or min(facts4["bytes_per_device"]) <= 0):
         raise RuntimeError(f"serving state is not on all four chips: "

@@ -21,8 +21,10 @@ from __future__ import annotations
 
 import functools
 import os
+import threading
 import time
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +69,49 @@ def _write_new_kv(k, v, kv, layer, page_table, start_pos, q_lens):
     """``write_kv`` with its head-split arguments first, the order
     ``RaggedInferenceModel._per_shard_heads`` builds its specs from."""
     return write_kv(kv, layer, k, v, page_table, start_pos, q_lens)
+
+
+class Segment(NamedTuple):
+    """One segment of a step: rows of one geometry (a ``RaggedBatch``'s
+    device vectors) and whether every row starts at position 0, which
+    lets attention skip the pages (``fresh``, static).  A mixed step has
+    two, every other kind one."""
+    token_ids: jax.Array        # [S, Q]
+    q_lens: jax.Array           # [S]
+    start_pos: jax.Array        # [S]
+    page_table: jax.Array       # [S, P]
+    fresh: bool = False
+
+
+def _end_to_end(parts: Sequence[jax.Array]) -> jax.Array:
+    """The segments' per-token arrays ``[S_i, Q_i, ...]`` as the one array
+    the token-wise operations run over: several segments' tokens laid end
+    to end as rows of one token each, ``[T, 1, ...]``; a lone segment's
+    array as it is (its rows are its tokens end to end already)."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate([p.reshape((-1, 1) + p.shape[2:]) for p in parts])
+
+
+def _per_segment(x: jax.Array, segments: Sequence[Segment]
+                 ) -> List[jax.Array]:
+    """:func:`_end_to_end` undone: each segment's tokens of ``x`` as
+    ``[S, Q, ...]``, for what differs by kind of row (the page write,
+    attention, the last-token gather)."""
+    if len(segments) == 1:
+        return [x]
+    out, at = [], 0
+    for seg in segments:
+        S, Q = seg.token_ids.shape
+        out.append(x[at:at + S * Q].reshape((S, Q) + x.shape[2:]))
+        at += S * Q
+    return out
+
+
+def _positions(segments: Sequence[Segment]) -> jax.Array:
+    """Every token's position in its sequence, :func:`_end_to_end`."""
+    return _end_to_end([token_positions(seg.start_pos, seg.token_ids.shape[1])
+                        for seg in segments])
 
 
 def _rebox_from_cfg(cfg: T.TransformerConfig, params):
@@ -163,7 +208,6 @@ class RaggedInferenceModel:
         self._norm_impl = instantiate("norm", cfg)
         self._norm = self._norm_impl
         self._embed = instantiate("embedding", cfg)
-        self._unembed = instantiate("unembed", cfg)
         self.kv_config_explicit = kv_config is not None
         # what the attention kind declares of its cache: K and V by
         # head, or one latent plane a token
@@ -233,6 +277,12 @@ class RaggedInferenceModel:
         #: and replica factories carry (ISSUE 14): a restored/spawned
         #: engine precompiles exactly these, not the whole lattice
         self._dispatched_keys: set = set()
+        #: per step-cache key, how many times its program runs a trunk
+        #: (the most any one weight stack is streamed: 1 for every kind),
+        #: counted while the program is traced on the forming thread
+        self._trunk_passes: Dict[StepKey, int] = {}
+        self._forming = threading.local()
+        self._last_key: Optional[StepKey] = None
         self._flops_dispatched = 0.0
         self._bytes_dispatched = 0.0
         self._cost_t0: Optional[float] = None
@@ -448,14 +498,20 @@ class RaggedInferenceModel:
                     *operands)
 
     @property
+    def last_trunk_passes(self) -> int:
+        """Trunk passes of the newest dispatch's program (the
+        ``fastgen.step`` span's ``trunk_passes``)."""
+        return self._trunk_passes.get(self._last_key, 0)
+
+    @property
     def step_tail(self) -> int:
         """int32 counts a sampled-token vector carries past its rows: a
         model with held experts appends (token-expert pairs that fell to
         experts held here, summed over the routed layers; the fullest
         held expert's pairs in one layer; held experts with a pair, summed
         over the routed layers), so the counts ride the step's one d2h.
-        A mixed step adds its two passes' counts, the fullest experts'
-        too.  The chain key's ``prev_len`` stays the row bucket
+        A mixed step's are those of its one pass over both segments'
+        tokens.  The chain key's ``prev_len`` stays the row bucket
         (``step_key.step_avals`` adds the tail)."""
         return 3 if self.cfg.n_routed_experts else 0
 
@@ -517,8 +573,12 @@ class RaggedInferenceModel:
                          {"key": key, "on_path": run}) as prog:
             before = thread_cache_counts()
             with tracer.span("engine.program.trace"):
+                self._forming.passes = {}
                 traced = jax.jit(step_program(self, key),
                                  donate_argnums=(1,)).trace(*args)
+                self._trunk_passes[key] = max(
+                    self._forming.passes.values(), default=0)
+                del self._forming.passes
             with tracer.span("engine.program.lower"):
                 lowered = traced.lower()
             with tracer.span("engine.program.compile") as comp:
@@ -565,6 +625,7 @@ class RaggedInferenceModel:
         behind the ds_fastgen_program_flops / _mfu gauges.  Always-on
         (ServingCounters convention): a dict lookup + float adds."""
         self._dispatched_keys.add(key)
+        self._last_key = key
         wt = get_workload_trace()
         if wt.active:
             wt.note_step_key(key)
@@ -745,27 +806,31 @@ class RaggedInferenceModel:
             logits = logits + bias.astype(jnp.float32)
         return logits
 
-    def _forward_hidden(self, params, kv, token_ids, q_lens, start_pos,
-                        page_table, fresh: bool = False, cfg=None,
-                        stats_out: Optional[list] = None):
+    def _forward_hidden(self, params, kv, segments: Sequence[Segment],
+                        cfg=None, stats_out: Optional[list] = None):
         """The shared trunk of every step kind: embed -> layers -> final
-        norm.  Returns (x [S, Q, E], new kv) — the step kinds differ
-        only in which positions they unembed (last-token gather for the
-        logits/sample kinds, EVERY position for the spec verify).
+        norm, ONE pass of the weights over all tokens of all
+        ``segments`` (:func:`_end_to_end`); only the page write and
+        attention run segment by segment, inside each layer.  Returns
+        (x, new kv), ``x`` in :func:`_end_to_end`'s layout ([S, Q, E]
+        for one segment) — the step kinds differ only in which positions
+        they unembed (last-token gather for the logits/sample kinds,
+        EVERY position for the spec verify).
         ``cfg`` overrides the trunk geometry (the model-drafted spec
         path runs the DRAFT trunk — same family, fewer layers — through
         the same embed/norm/attention modules); None = the target.
         ``stats_out``: a list that receives the held-experts counts
-        (:attr:`step_tail`) of this pass, for the kinds that carry them."""
+        (:attr:`step_tail`) of the pass, for the kinds that carry them."""
         cfg = cfg if cfg is not None else self.cfg
+        passes = getattr(self._forming, "passes", None)
+        if passes is not None:          # a program is being traced
+            passes[id(cfg)] = passes.get(id(cfg), 0) + 1
         if cfg.latent_dim:
-            return self._forward_hidden_latent(
-                params, kv, token_ids, q_lens, start_pos, page_table,
-                fresh, cfg, stats_out)
-        S, Q = token_ids.shape
+            return self._forward_hidden_latent(params, kv, segments, cfg,
+                                               stats_out)
         x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
-                        token_ids)
-        pos = token_positions(start_pos, Q)
+                        _end_to_end([seg.token_ids for seg in segments]))
+        pos = _positions(segments)
         if cfg.pos_emb == "learned":
             safe = jnp.minimum(pos, cfg.max_seq_len - 1)
             x = x + params["embed"]["positions"].astype(cfg.dtype)[safe]
@@ -774,9 +839,8 @@ class RaggedInferenceModel:
         sin, cos = (T.rope_table(cfg, pos) if cfg.pos_emb == "rope"
                     else (None, None))
 
-        body = functools.partial(self._layer_body, pos=pos, sin=sin, cos=cos,
-                                 q_lens=q_lens, start_pos=start_pos,
-                                 page_table=page_table, fresh=fresh, cfg=cfg)
+        body = functools.partial(self._layer_body, segments=segments,
+                                 sin=sin, cos=cos, cfg=cfg)
         # the pool (plain array or KVPages pair) is the loop's CARRY:
         # as scanned xs/ys it would be sliced out and stacked back, two
         # layer-sized copies a layer and a pool-sized one after the loop
@@ -794,23 +858,25 @@ class RaggedInferenceModel:
 
     # dslint: hot-path
     def _step_impl(self, params, kv, token_ids, q_lens, start_pos,
-                   page_table, fresh: bool = False,
-                   stats_out: Optional[list] = None):
-        cfg = self.cfg
-        x, kv = self._forward_hidden(params, kv, token_ids, q_lens,
-                                     start_pos, page_table, fresh=fresh,
+                   page_table, fresh: bool = False):
+        return self._last_token_logits(
+            params, kv, [Segment(token_ids, q_lens, start_pos, page_table,
+                                 fresh)])
+
+    def _last_token_logits(self, params, kv, segments: Sequence[Segment],
+                           stats_out: Optional[list] = None):
+        """The trunk over ``segments``, then fp32 logits of each row's
+        last token, the segments' rows in order: (one [rows, V] lm-head
+        product, new kv)."""
+        x, kv = self._forward_hidden(params, kv, segments,
                                      stats_out=stats_out)
-        bias = params.get("lm_head_bias")  # phi family ships one
-        if self._tp_quant_active():
-            # int8 collective path mirrors the default unembed module
-            # (last-token gather + matmul) with the gather quantized
-            logits = self._assemble_logits(gather_last(x, q_lens),
-                                           self._lm_head(params), bias)
-            return logits, kv
-        logits = self._unembed(x, q_lens, self._lm_head(params))  # [S, V]
-        if bias is not None:
-            logits = logits + bias.astype(cfg.dtype)
-        return logits.astype(jnp.float32), kv
+        last = jnp.concatenate(
+            [gather_last(xs, seg.q_lens)
+             for xs, seg in zip(_per_segment(x, segments), segments)])
+        logits = self._assemble_logits(
+            last, self._lm_head(params),
+            params.get("lm_head_bias"))  # phi family ships one
+        return logits, kv
 
     def _sample_tokens(self, logits, rng, temps, top_ks, top_ps,
                        row_uids, row_pos, greedy_only: bool):
@@ -834,12 +900,32 @@ class RaggedInferenceModel:
                           fresh: bool = False, greedy_only: bool = False):
         """Forward + on-device sampling in ONE traced program: the [S, V]
         logits never leave the device — only int32 tokens do."""
+        return self._sample_last_tokens(
+            params, kv, [Segment(token_ids, q_lens, start_pos, page_table,
+                                 fresh)],
+            rng, temps, top_ks, top_ps, row_uids, row_pos, greedy_only)
+
+    def _sample_last_tokens(self, params, kv, segments, rng, temps, top_ks,
+                            top_ps, row_uids, row_pos, greedy_only: bool):
+        """One pass of the trunk over ``segments``, one sampling
+        reduction over their rows: (tokens [rows, padded to the slot
+        bucket where there are several segments] + :attr:`step_tail`,
+        new kv)."""
         stats = [] if self.step_tail else None
-        logits, kv = self._step_impl(params, kv, token_ids, q_lens,
-                                     start_pos, page_table, fresh=fresh,
-                                     stats_out=stats)
+        logits, kv = self._last_token_logits(params, kv, segments,
+                                             stats_out=stats)
         tokens = self._sample_tokens(logits, rng, temps, top_ks, top_ps,
                                      row_uids, row_pos, greedy_only)
+        # pad the token vector to the slot bucket: the segments' rows are
+        # an arbitrary sum, and a later chained step keys on the EXACT
+        # prev-token length — bucketing here collapses the chain-key
+        # space back to the lattice's slot tops (one compile, not one
+        # per segment-sum); a mined lattice supplies its own tops
+        pad = (self.lattice.bucket_s(tokens.shape[0]) - tokens.shape[0]
+               if len(segments) > 1 else 0)
+        if pad:
+            tokens = jnp.concatenate(
+                [tokens, jnp.zeros((pad,), jnp.int32)])
         if stats:
             tokens = jnp.concatenate([tokens, stats[0]])
         return tokens, kv
@@ -877,8 +963,8 @@ class RaggedInferenceModel:
         sample, drafts only decide how many positions commit at once),
         plus the correction/bonus token at position ``accepted``.
         Returns [S, 2] int32: (accepted_count, corrected_token)."""
-        x, kv = self._forward_hidden(params, kv, token_ids, q_lens,
-                                     start_pos, page_table, fresh=False)
+        x, kv = self._forward_hidden(
+            params, kv, [Segment(token_ids, q_lens, start_pos, page_table)])
         # EVERY position unembeds (the verify reads all of them) —
         # flattened through the shared assembly so the tp collective
         # (fp or int8) covers the spec kinds too
@@ -951,8 +1037,9 @@ class RaggedInferenceModel:
             dkv, tok = carry
             qj = jnp.where(j < q_lens, 1, 0).astype(jnp.int32)
             x, dkv = self._forward_hidden(
-                dparams, dkv, tok[:, None], qj, start_pos + j,
-                page_table, fresh=False, cfg=dcfg)
+                dparams, dkv,
+                [Segment(tok[:, None], qj, start_pos + j, page_table)],
+                cfg=dcfg)
             # shared assembly: the per-iteration [S, V] draft logits
             # ride the same tp collective (fp or int8) as the verify
             logits = self._assemble_logits(x[:, 0, :], lm_head, bias)
@@ -981,9 +1068,9 @@ class RaggedInferenceModel:
         batch's positions (``params`` = draft params, ``kv`` = the
         draft pool, donated).  No unembed consumer, no output but the
         pool — the catch-up path moves ZERO bytes device->host."""
-        _, kv = self._forward_hidden(params, kv, token_ids, q_lens,
-                                     start_pos, page_table, fresh=False,
-                                     cfg=self.draft_cfg)
+        _, kv = self._forward_hidden(
+            params, kv, [Segment(token_ids, q_lens, start_pos, page_table)],
+            cfg=self.draft_cfg)
         return kv
 
     # dslint: hot-path
@@ -993,41 +1080,23 @@ class RaggedInferenceModel:
                                 row_uids=None, row_pos=None,
                                 fresh_p: bool = False,
                                 greedy_only: bool = False):
-        """Two-segment fused step: decode [S_d, 1] then prefill [S_p, Q]
-        through the same layers with the KV cache threaded between them
-        (distinct sequences, so segment order is free), logits
-        concatenated, sampled once — one compiled program, no
-        cross-geometry padding."""
-        stats = [] if self.step_tail else None
-        logits_d, kv = self._step_impl(params, kv, d_tok, d_ql, d_sp,
-                                       d_pt, fresh=False, stats_out=stats)
-        logits_p, kv = self._step_impl(params, kv, p_tok, p_ql, p_sp,
-                                       p_pt, fresh=fresh_p,
-                                       stats_out=stats)
-        logits = jnp.concatenate([logits_d, logits_p], axis=0)
-        tokens = self._sample_tokens(logits, rng, temps, top_ks, top_ps,
-                                     row_uids, row_pos, greedy_only)
-        # pad the token vector to the slot bucket: S_d + S_p is an
-        # arbitrary sum, and a later chained step keys on the EXACT
-        # prev-token length — bucketing here collapses the chain-key
-        # space back to the lattice's slot tops (one compile, not one
-        # per segment-sum); a mined lattice supplies its own tops
-        pad = self.lattice.bucket_s(tokens.shape[0]) - tokens.shape[0]
-        if pad:
-            tokens = jnp.concatenate(
-                [tokens, jnp.zeros((pad,), jnp.int32)])
-        if stats:
-            # each pass streams its experts and has a fullest one: all
-            # three counts are sums over the passes, so that fullest /
-            # mean stays a ratio of like sums
-            tokens = jnp.concatenate([tokens, stats[0] + stats[1]])
-        return tokens, kv
+        """Two-segment fused step, decode rows [S_d, 1] and prefill rows
+        [S_p, Q]: ONE pass of the trunk over the tokens of both (distinct
+        sequences, so segment order is free), each layer writing and
+        attending segment by segment, one lm-head product over the
+        S_d + S_p last tokens, sampled once — one compiled program, no
+        cross-geometry padding, every weight streamed once."""
+        return self._sample_last_tokens(
+            params, kv, [Segment(d_tok, d_ql, d_sp, d_pt),
+                         Segment(p_tok, p_ql, p_sp, p_pt, fresh_p)],
+            rng, temps, top_ks, top_ps, row_uids, row_pos, greedy_only)
 
-    def _layer_body(self, x, kv, lp, layer, *, pos, sin, cos, q_lens,
-                    start_pos, page_table, fresh: bool = False, cfg=None):
-        """One transformer layer over ``x`` and the whole pool ``kv``,
-        of which it writes and reads layer ``layer`` (an int32 scalar)
-        in place.  Returns (x, kv)."""
+    def _layer_body(self, x, kv, lp, layer, *, segments, sin, cos,
+                    cfg=None):
+        """One transformer layer over ``x`` (all tokens of
+        ``segments``, :func:`_end_to_end`) and the whole pool ``kv``, of
+        which each segment in turn writes and reads layer ``layer`` (an
+        int32 scalar) in place.  Returns (x, kv)."""
         cfg = cfg if cfg is not None else self.cfg
         dtype = cfg.dtype
         h = self._norm(lp["norm1"], x)
@@ -1042,19 +1111,27 @@ class RaggedInferenceModel:
         if cfg.pos_emb == "rope":
             q = T.apply_rope(q, sin, cos)
             k = T.apply_rope(k, sin, cos)
-        kv = self._per_shard_heads(_write_new_kv, cfg, 2, pool_out=True)(
-            k, v, kv, layer, page_table, start_pos, q_lens)
-        if fresh and self._fresh_attention is not None:
-            # pure prefill: every slot's context IS its own new tokens —
-            # flash over [S(batch), H, Q, D], no paged gather at all
-            # (reference blocked_flash prefill atoms); padding-tail rows
-            # are garbage but only feed rows that logits_gather ignores
-            # and KV slots the null page swallows
-            attn = self._per_shard_heads(
-                self._fresh_attention, cfg, 3)(q, k, v)
-        else:
-            attn = self._per_shard_heads(self._attention, cfg, 1)(
-                q, kv, layer, page_table, start_pos, q_lens)
+        attn = []
+        for seg, qs, ks, vs in zip(segments, *(
+                _per_segment(a, segments) for a in (q, k, v))):
+            kv = self._per_shard_heads(
+                _write_new_kv, cfg, 2, pool_out=True)(
+                ks, vs, kv, layer, seg.page_table, seg.start_pos,
+                seg.q_lens)
+            if seg.fresh and self._fresh_attention is not None:
+                # pure prefill: every slot's context IS its own new
+                # tokens — flash over [S(batch), H, Q, D], no paged
+                # gather at all (reference blocked_flash prefill atoms);
+                # padding-tail rows are garbage but only feed rows that
+                # logits_gather ignores and KV slots the null page
+                # swallows
+                attn.append(self._per_shard_heads(
+                    self._fresh_attention, cfg, 3)(qs, ks, vs))
+            else:
+                attn.append(self._per_shard_heads(self._attention, cfg, 1)(
+                    qs, kv, layer, seg.page_table, seg.start_pos,
+                    seg.q_lens))
+        attn = _end_to_end(attn)
         out = jnp.einsum("sqhd,hde->sqe", attn, T._wval(ap["wo"], dtype))
         if cfg.use_bias:
             out = out + ap["bo"].astype(dtype)
@@ -1071,27 +1148,24 @@ class RaggedInferenceModel:
             mlp_out = mlp_out[0]
         return x + mlp_out.astype(x.dtype), kv
 
-    def _forward_hidden_latent(self, params, kv, token_ids, q_lens,
-                               start_pos, page_table, fresh, cfg,
-                               stats_out):
+    def _forward_hidden_latent(self, params, kv, segments, cfg, stats_out):
         """The trunk of the latent kind: a stack of dense layers, then a
         stack of routed ones, each its own scan, the pool's layer index
         running on through both.  The carry holds the held-experts
         counts beside the activations and the pool."""
-        S, Q = token_ids.shape
         x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
-                        token_ids)
-        pos = token_positions(start_pos, Q)
+                        _end_to_end([seg.token_ids for seg in segments]))
+        pos = _positions(segments)
         d = cfg.qk_rope_head_dim
         freqs = cfg.rope_theta ** (
             -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
         angles = pos[..., None].astype(jnp.float32) * freqs
-        valid = (jnp.arange(Q, dtype=jnp.int32)[None, :]
-                 < q_lens[:, None]).reshape(-1)
+        valid = _end_to_end([
+            jnp.arange(seg.token_ids.shape[1], dtype=jnp.int32)[None, :]
+            < seg.q_lens[:, None] for seg in segments]).reshape(-1)
         body = functools.partial(
-            self._layer_body_latent, sin=jnp.sin(angles),
-            cos=jnp.cos(angles), q_lens=q_lens, start_pos=start_pos,
-            page_table=page_table, fresh=fresh, cfg=cfg, valid=valid)
+            self._layer_body_latent, segments=segments,
+            sin=jnp.sin(angles), cos=jnp.cos(angles), cfg=cfg, valid=valid)
         carry = (x, kv, jnp.zeros((3,), jnp.int32))
         base = 0
         for name in ("dense_layers", "layers"):
@@ -1119,16 +1193,15 @@ class RaggedInferenceModel:
             stats_out.append(stats)
         return self._norm(params["final_norm"], x), kv
 
-    def _layer_body_latent(self, x, kv, stats, lp, layer, *, sin, cos,
-                           q_lens, start_pos, page_table, fresh, cfg,
-                           valid, experts=None, stack_base=0):
+    def _layer_body_latent(self, x, kv, stats, lp, layer, *, segments,
+                           sin, cos, cfg, valid, experts=None,
+                           stack_base=0):
         """One layer of the latent kind over ``x``, the pool and the
         counts: sandwich norms, latent attention, then the dense MLP or
         the routed layer's held share plus the shared expert."""
         attn, kv = self._attend_latent(
-            self._norm(lp["norm1"], x), kv, lp["attn"], layer, sin=sin,
-            cos=cos, q_lens=q_lens, start_pos=start_pos,
-            page_table=page_table, fresh=fresh, cfg=cfg)
+            self._norm(lp["norm1"], x), kv, lp["attn"], layer,
+            segments=segments, sin=sin, cos=cos, cfg=cfg)
         if cfg.sandwich_norm:
             attn = self._norm(lp["norm1_post"], attn)
         x = x + attn.astype(x.dtype)
@@ -1156,13 +1229,13 @@ class RaggedInferenceModel:
             out = self._norm(lp["norm2_post"], out.astype(x.dtype))
         return x + out.astype(x.dtype), kv, stats
 
-    def _attend_latent(self, h, kv, ap, layer, *, sin, cos, q_lens,
-                       start_pos, page_table, fresh, cfg):
-        """Latent attention of ``h`` [S, Q, E]: writes the new tokens'
-        ``[c ; k_r]`` planes into ``pool[layer]`` and attends — expanded
-        (192-wide scores, 128-wide values) for a pure prefill, absorbed
-        over the paged planes otherwise.  Returns (output [S, Q, E],
-        pool)."""
+    def _attend_latent(self, h, kv, ap, layer, *, segments, sin, cos, cfg):
+        """Latent attention of ``h`` (all tokens of ``segments``): the
+        projections once over all of them, then each segment writes its
+        new tokens' ``[c ; k_r]`` planes into ``pool[layer]`` and
+        attends — expanded (192-wide scores, 128-wide values) for a pure
+        prefill, absorbed over the paged planes otherwise.  Returns
+        (output in ``h``'s layout, pool)."""
         dtype = cfg.dtype
         dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
         scale = float(dn + cfg.qk_rope_head_dim) ** -0.5
@@ -1175,26 +1248,31 @@ class RaggedInferenceModel:
         k_r = T.apply_rope(ckr[:, :, None, rkv:], sin, cos)[:, :, 0]
         S, Q = h.shape[:2]
         pad = kv.shape[-1] - rkv - k_r.shape[-1]
-        kv = latent_write(
-            kv, layer,
-            jnp.concatenate([c, k_r, jnp.zeros((S, Q, pad), dtype)], -1),
-            page_table, start_pos, q_lens)
+        plane = jnp.concatenate([c, k_r, jnp.zeros((S, Q, pad), dtype)], -1)
         w_k, w_v = ap["wkv_b_k"].astype(dtype), ap["wkv_b_v"].astype(dtype)
-        if fresh:
-            k_n = jnp.einsum("sqr,rhd->sqhd", c, w_k)
-            k = jnp.concatenate([k_n, jnp.broadcast_to(
-                k_r[:, :, None, :], k_n.shape[:3] + k_r.shape[-1:])], -1)
-            out = mla_fresh_attention(
-                jnp.concatenate([q_n, q_r], -1), k,
-                jnp.einsum("sqr,rhd->sqhd", c, w_v), sm_scale=scale)
-        else:
-            q_abs = jnp.concatenate(
-                [jnp.einsum("sqhd,rhd->sqhr", q_n, w_k), q_r,
-                 jnp.zeros(q_r.shape[:3] + (pad,), dtype)], -1)
-            ctx = mla_paged_attention(q_abs, kv, layer, page_table,
-                                      start_pos, q_lens, rank=rkv,
-                                      sm_scale=scale)
-            out = jnp.einsum("sqhr,rhd->sqhd", ctx, w_v)
+        out = []
+        for seg, plane_s, cs, k_rs, q_ns, q_rs in zip(segments, *(
+                _per_segment(a, segments)
+                for a in (plane, c, k_r, q_n, q_r))):
+            kv = latent_write(kv, layer, plane_s, seg.page_table,
+                              seg.start_pos, seg.q_lens)
+            if seg.fresh:
+                k_n = jnp.einsum("sqr,rhd->sqhd", cs, w_k)
+                k = jnp.concatenate([k_n, jnp.broadcast_to(
+                    k_rs[:, :, None, :],
+                    k_n.shape[:3] + k_rs.shape[-1:])], -1)
+                out.append(mla_fresh_attention(
+                    jnp.concatenate([q_ns, q_rs], -1), k,
+                    jnp.einsum("sqr,rhd->sqhd", cs, w_v), sm_scale=scale))
+            else:
+                q_abs = jnp.concatenate(
+                    [jnp.einsum("sqhd,rhd->sqhr", q_ns, w_k), q_rs,
+                     jnp.zeros(q_rs.shape[:3] + (pad,), dtype)], -1)
+                ctx = mla_paged_attention(q_abs, kv, layer, seg.page_table,
+                                          seg.start_pos, seg.q_lens,
+                                          rank=rkv, sm_scale=scale)
+                out.append(jnp.einsum("sqhr,rhd->sqhd", ctx, w_v))
+        out = _end_to_end(out)
         return jnp.einsum("sqhd,hde->sqe", out, ap["wo"].astype(dtype)), kv
 
     def _per_shard_heads(self, fn, cfg, n_head_args: int,

@@ -188,13 +188,3 @@ def _embedding(cfg):
     def embed(table, token_ids):
         return table[token_ids]
     return embed
-
-
-@register("unembed", "last_token_gather", priority=0)
-def _unembed(cfg):
-    from ...ops.paged_attention import gather_last
-
-    def unembed(x, q_lens, lm_head):
-        import jax.numpy as jnp
-        return jnp.einsum("se,ev->sv", gather_last(x, q_lens), lm_head)
-    return unembed

@@ -1460,7 +1460,12 @@ class FastGenScheduler:
                 ("prefill_rows", prefill_rows),
                 ("prefill_tokens", prefill_tokens), ("tokens", tokens),
                 ("budget", self._budget),
-                ("kv_pages_reserved", pages), ("kv_tokens_held", held)):
+                ("kv_pages_reserved", pages), ("kv_tokens_held", held),
+                # how often the step's (last) program streams its
+                # weights: 1 for every kind; 2 would be a mixed step
+                # that runs a pass a segment again
+                ("trunk_passes", self._engine.model.last_trunk_passes
+                 if path != "idle" else 0)):
             span.set(key, value)
         if self._moe_counts is not None:
             # counts of the step drained inside this one (the step

@@ -234,6 +234,20 @@ def pool_sized_movers(text: str, floor: int) -> list:
     return found
 
 
+def stack_shaped_movers(text: str, params) -> list:
+    """The movers of a compiled program that yield an array of a whole
+    weight stack's shape (a leaf ``[layers > 1, ...]`` of three or more
+    dimensions: the scanned projections, the held experts).  A program
+    that ran the trunk a second time re-laid the stacked ``wq``, ``wk``
+    and ``wv`` out before its second layer loop (``copy.91-93``, 0.8 ms
+    of every mixed step at the benchmark's widths; PERF.md, PR 30)."""
+    stacks = {",".join(str(d) for d in leaf.shape)
+              for leaf in jax.tree.leaves(params)
+              if leaf.ndim >= 3 and leaf.shape[0] > 1}
+    return [m for m in pool_sized_movers(text, 0)
+            if m[2][m[2].index("[") + 1:-1] in stacks]
+
+
 def test_pool_sized_movers_reads_a_program_text():
     text = """
   %copy.98 = bf16[1025,2,8,64,128]{4,2,3,1,0} copy(%x)
@@ -244,11 +258,15 @@ def test_pool_sized_movers_reads_a_program_text():
 """
     assert [m[0] for m in pool_sized_movers(text, 268_697_600)] == [
         "copy.98", "copy_dynamic-update-slice_fusion.2"]
+    weights = {"wq": np.zeros((8, 1025, 2, 8, 64, 128), np.int8),
+               "norm": np.zeros((8, 128)), "one": np.zeros((1, 8, 64, 128))}
+    assert [m[0] for m in stack_shaped_movers(text, weights)] == [
+        "copy_dynamic-update-slice_fusion.2"]
 
 
 @pytest.mark.parametrize("kind,int8,scan_layers", [
-    # the cell's three kinds; then the mixed program, which runs both
-    # passes, for the quantized pool and the unrolled layer loop
+    # the cell's three kinds; then the mixed program (both segments in
+    # every layer), for the quantized pool and the unrolled layer loop
     ("chain", False, True), ("sample-fresh", False, True),
     ("mixed", False, True), ("mixed", True, True), ("mixed", False, False),
     ("mixed", True, False)],
@@ -291,8 +309,12 @@ def test_step_program_leaves_the_pool_in_place(chip, monkeypatch, kind, int8,
                        donate_argnums=(1,)).lower(*avals).compile()
     payload = jax.tree.leaves(pool)[0]
     layer_bytes = int(np.prod(payload.shape[1:])) * payload.dtype.itemsize
-    assert pool_sized_movers(compiled.as_text(), layer_bytes) == []
+    text = compiled.as_text()
+    assert pool_sized_movers(text, layer_bytes) == []
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    if scan_layers:
+        # one trunk pass: no stacked weight is re-laid out for a second loop
+        assert stack_shaped_movers(text, params) == []
 
 
 # -- norms, fused optimizers, block quantization ------------------------------
@@ -464,4 +486,5 @@ def test_pangu_step_program_moves_no_pool_and_no_expert_stack(
     expert_layer = 16 * 2048 * 7680 * 2
     assert expert_layer < int(np.prod(pool.shape[1:])) * 2
     assert pool_sized_movers(text, expert_layer) == []
+    assert stack_shaped_movers(text, params) == []
     assert compiled.memory_analysis().temp_size_in_bytes < expert_layer

@@ -55,6 +55,56 @@ def test_agreement_counts_tokens_before_the_first_divergence():
     assert got["rest_positionwise"] == round(5 / 6, 4)
 
 
+@pytest.mark.parametrize("ties,ok", [
+    (None, False), ({}, False),
+    ({3: {"tokens": [5, 9], "gap": 0.03, "top2": True}}, True),
+    ({3: {"tokens": [5, 9], "gap": 0.03, "top2": False}}, False),
+    ({3: {"tokens": [5, 9], "gap": 0.2, "top2": True}}, False),
+    ({3: {"tokens": [5, 9], "gap": 0.03, "top2": True},
+      4: {"tokens": [1, 2], "gap": 0.5, "top2": True}}, False)],
+    ids=["not-looked-at", "none-found", "near-tie", "not-the-top-two",
+         "wide-gap", "one-of-two-wide"])
+def test_first_tokens_may_differ_only_at_a_near_tie(ties, ok):
+    agree = {"first_token_exact": False, "rest_agreement": 0.5}
+    if ok:
+        chip_smoke.require_agreement(agree, "a and b", ties)
+    else:
+        with pytest.raises(RuntimeError, match="a and b tokens disagree"):
+            chip_smoke.require_agreement(agree, "a and b", ties)
+    chip_smoke.require_agreement(dict(agree, first_token_exact=True), "x")
+    with pytest.raises(RuntimeError):       # the later tokens still count
+        chip_smoke.require_agreement(dict(agree, rest_agreement=0.1), "x",
+                                     {3: {"gap": 0.0, "top2": True}})
+
+
+def test_first_token_ties_reads_the_prompts_plain_forward():
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from deepspeed_tpu.models.llama import LlamaForCausalLM
+    from deepspeed_tpu.models.transformer import forward
+    model = LlamaForCausalLM("debug", max_seq_len=256, dtype=jnp.float32)
+    params = meta.unbox(model.init_params(jax.random.key(0)))
+    prompts = [list(range(3, 40)), list(range(50, 70))]
+    rows = [np.asarray(forward(model.cfg, params, jnp.asarray([p]))[0, -1])
+            for p in prompts]
+    top = [np.argsort(-r)[:3].tolist() for r in rows]
+    a = {0: [top[0][0], 1], 1: [top[1][0], 1]}
+    assert chip_smoke.first_token_ties(model.cfg, params, prompts, a, a,
+                                       jax.devices()[:1]) == {}
+    b = {0: [top[0][0], 2], 1: [top[1][2], 1]}
+    ties = chip_smoke.first_token_ties(model.cfg, params, prompts, a, b,
+                                       jax.devices()[:1])
+    assert list(ties) == [1] and ties[1]["top2"] is False
+    assert ties[1]["tokens"] == sorted([top[1][0], top[1][2]])
+    assert ties[1]["gap"] == pytest.approx(
+        rows[1][top[1][0]] - rows[1][top[1][2]], abs=2e-4)
+    b[1][0] = top[1][1]
+    assert chip_smoke.first_token_ties(
+        model.cfg, params, prompts, a, b, jax.devices()[:1])[1]["top2"]
+
+
 def test_custom_call_counting_needs_the_kernel_on_the_call_line():
     text = ('%a = custom-call(), custom_call_target="tpu_custom_call", '
             'metadata={op_name="jit(f)/paged_attention/pallas_call"}\n'

@@ -242,6 +242,10 @@ class TestStepTree:
         assert mixed["kv_pages_reserved"] > 0
         idle = next(s for s in steps if s["path"] == "idle")
         assert idle["rows"] == idle["tokens"] == 0
+        # every program streams its weights once, the mixed step's too
+        assert mixed["path"] == "fused" and idle["trunk_passes"] == 0
+        assert {s["trunk_passes"] for s in steps
+                if s["path"] != "idle"} == {1}
         # last_step_scheduled keeps its meaning: sequences, not tokens
         assert sched.last_step_scheduled == 0
 
@@ -278,7 +282,10 @@ def test_every_attribute_on_the_serving_path_has_a_metric_that_reads_it():
     carried = {key for r in recs if r[5] for key in r[5]}
     assert carried == {"path", "rows", "prefill_rows", "prefill_tokens",
                        "tokens", "budget", "kv_pages_reserved",
-                       "kv_tokens_held", "new_tokens"}
+                       "kv_tokens_held", "new_tokens", "trunk_passes"}
+    # the engagement counter of the one-pass mixed step (PR 30): held in
+    # the span ring for whoever reads a trace, by decision no metric
+    carried.remove("trunk_passes")
     read = set()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for path in glob.glob(os.path.join(root, "benchmark", "metrics",
