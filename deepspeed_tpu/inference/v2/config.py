@@ -25,6 +25,10 @@ class KVCacheUserConfig:
     page_size: int = 64
     num_pages: Optional[int] = None        # None -> sized from memory_fraction
     dtype: Any = jnp.bfloat16
+    #: pages of the window group's pool, for a model with two page groups
+    #: (ragged/manager.py); None -> what every tracked sequence can hold
+    #: live at once, ``max_tracked_sequences x (window / page_size + 2)``
+    window_num_pages: Optional[int] = None
 
 
 @dataclasses.dataclass

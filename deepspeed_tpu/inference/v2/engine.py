@@ -142,6 +142,25 @@ class InferenceEngineV2:
                 "a latent page pool cannot be served under tp_degree > 1 "
                 "yet: a latent plane has no heads to divide (data-parallel "
                 "attention is the deployment's answer) — use tp_degree=1")
+        if model.window_kv_config is not None:
+            # a model with two page groups: what is not built for it
+            # raises here, before anything is sized
+            sv_ = self._config.serving
+            if max(tp, model.tp_degree) > 1:
+                raise ValueError(
+                    "a window page group cannot be served under "
+                    "tp_degree > 1 yet (its pool and its kind's head "
+                    "count are not sharded) — use tp_degree=1")
+            if (getattr(sv_, "kv_quantization", "none") or "none") != "none":
+                raise ValueError(
+                    "a window page group has no int8 page format yet: "
+                    "kv_quantization must be 'none' for this model")
+            if getattr(sv_, "speculative", False) and (
+                    getattr(sv_, "spec_drafter", "ngram") or "ngram") \
+                    in ("model", "auto"):
+                raise ValueError(
+                    "model-drafted speculation is not built for a model "
+                    "of two attention kinds: use spec_drafter='ngram'")
         if tp > 1 and model.mesh is None:
             devs = jax.devices()
             if len(devs) < tp:
@@ -227,6 +246,18 @@ class InferenceEngineV2:
                     kv_cfg = dataclasses.replace(
                         kv_cfg, num_pages=model.kv_config.num_pages)
             model.kv_config = kv_cfg
+            if model.window_kv_config is not None:
+                # the window group follows the full group's page size and
+                # dtype; its pool holds what the tracked sequences can
+                # hold live unless the user sizes it
+                sm_ = self._config.state_manager
+                model.window_kv_config = dataclasses.replace(
+                    model.window_kv_config, page_size=kv_cfg.page_size,
+                    dtype=kv_cfg.dtype,
+                    num_pages=kv_user.window_num_pages or (
+                        sm_.max_tracked_sequences
+                        * (model.cfg.sliding_window // kv_cfg.page_size
+                           + 2)))
         else:
             kv_cfg = model.kv_config
             # an explicit model kv_config still honors the serving
@@ -298,7 +329,10 @@ class InferenceEngineV2:
             prefix_caching=self._config.serving.prefix_caching,
             tier_host_pages=int(getattr(sv, "kv_tier_host_pages", 0) or 0),
             tier_disk_pages=int(getattr(sv, "kv_tier_disk_pages", 0) or 0),
-            tier_dir=getattr(sv, "kv_tier_dir", None))
+            tier_dir=getattr(sv, "kv_tier_dir", None),
+            window_kv_config=model.window_kv_config,
+            window=(model.cfg.sliding_window
+                    if model.window_kv_config is not None else 0))
         # draft KV pool (ISSUE 17): a parallel plain-dtype page array
         # addressed by the TARGET's page ids/page tables — allocation,
         # commit and rollback all ride the existing allocator (the
@@ -311,6 +345,7 @@ class InferenceEngineV2:
         # that degrades accept rate until catch-up, never correctness.
         self._draft_kv = None
         self._draft_seen: Dict[int, int] = {}
+        self._attended = (0, 0)
         if self._draft_enabled:
             import jax.numpy as jnp
             dkv = jnp.zeros(kv_cfg.cache_shape(self._draft_layers),
@@ -472,7 +507,9 @@ class InferenceEngineV2:
         led = get_memory_ledger()
         wbytes = self._params_resident_bytes(self._model.params)
         led.register_object("weights", self, lambda e, b=wbytes: b)
-        kv_bytes = self._model.kv_config.total_bytes()
+        kv_bytes = self._model.kv_config.total_bytes() + (
+            self._model.window_kv_config.total_bytes()
+            if self._model.window_kv_config is not None else 0)
         led.register_object("kv_pages", self._state,
                             lambda st, b=kv_bytes: b)
         draft_bytes = (int(self._draft_kv.nbytes)
@@ -667,6 +704,9 @@ class InferenceEngineV2:
         """The KV operand of a program over ``trunk`` (``STEP_KINDS``):
         the target pool, the draft pool, or the (target, draft) pair."""
         kv = self._state.kv_cache.data
+        if self._state.window_cache is not None:
+            # two page groups: the pair (full group, window group)
+            kv = (kv, self._state.window_cache.data)
         if trunk == "target":
             return kv
         if self._draft_kv is None:
@@ -679,7 +719,9 @@ class InferenceEngineV2:
     def _put_pool(self, trunk: str, pool) -> None:
         """Put back what a program over ``trunk`` returned for the
         pool(s) it was given, which it donated."""
-        if trunk == "target":
+        if trunk == "target" and self._state.window_cache is not None:
+            self._state.kv_cache.data, self._state.window_cache.data = pool
+        elif trunk == "target":
             self._state.kv_cache.data = pool
         elif trunk == "draft":
             self._draft_kv = pool
@@ -867,6 +909,20 @@ class InferenceEngineV2:
         return self._state.free_pages
 
     @property
+    def free_window_blocks(self) -> int:
+        """Free pages of the window group (0 for a model of one group)."""
+        return self._state.free_window_pages
+
+    def window_blocks_needed(self, uid: int, n_tokens: int) -> int:
+        """Pages of the window group that ``n_tokens`` more tokens of
+        ``uid`` need (0 for a model of one group): what admission holds
+        against :attr:`free_window_blocks` beside :meth:`query`."""
+        if self._state.window_cache is None:
+            return 0
+        return self._state.window_pages_needed(
+            self._state.get_sequence(uid) or placeholder(), n_tokens)
+
+    @property
     def model(self) -> RaggedInferenceModel:
         return self._model
 
@@ -910,6 +966,7 @@ class InferenceEngineV2:
             return SchedulingResult.BatchSequenceLimitExceeded
         cur_seqs = self._state.n_tracked_sequences
         free = self._state.free_pages
+        free_w = self._state.free_window_pages
         batch_tokens = 0
         for uid, length in zip(uids, lengths):
             sd = self._state.get_sequence(uid)
@@ -918,7 +975,8 @@ class InferenceEngineV2:
                 sd = placeholder()
             tokens, pages = self._model.get_kv_requirements(
                 sd.seen_tokens, sd.allocated_capacity, length, free)
-            if tokens != length:
+            free_w -= self._state.window_pages_needed(sd, length)
+            if tokens != length or free_w < 0:
                 return SchedulingResult.KVCacheLimitExceeded
             batch_tokens += length
             free -= pages
@@ -955,6 +1013,13 @@ class InferenceEngineV2:
         the window then releases stays cache-retained)."""
         with trace_span("engine.commit"):
             window = getattr(self._model.cfg, "sliding_window", None)
+            if self._state.window_cache is not None:
+                # two page groups: nothing is indexed, and the window
+                # group's tables give their pages back under one span
+                for sd in descs:
+                    sd.post_forward()
+                self._evict_window_group(descs)
+                return
             for sd in descs:
                 sd.post_forward()
                 self._state.index_prefix(sd)
@@ -964,6 +1029,14 @@ class InferenceEngineV2:
                     # them to the pool so live KV is O(window), not
                     # O(context)
                     self._state.evict_window(sd, window)
+
+    def _evict_window_group(self, descs) -> None:
+        """Release, from each sequence's window table, the pages its
+        window has passed (``StateManager.evict_window``)."""
+        with trace_span("kv.evict_window"):
+            window = self._state.window
+            for sd in descs:
+                self._state.evict_window(sd, window)
 
     def _build_batch(self, descs, tokens, h2d_tokens: bool = True,
                      min_q: int = 1, start_pos=None):
@@ -979,13 +1052,31 @@ class InferenceEngineV2:
                 descs, tokens, self._model.kv_config.page_size,
                 self._lattice,
                 fresh_supported=self._model.has_fresh, min_q=min_q,
-                start_pos=start_pos)
+                start_pos=start_pos,
+                window_slots=(self._model.window_slots
+                              if self._state.window_cache is not None
+                              else None))
+            if self._state.window_cache is not None and batch.max_q == 1:
+                # what the decode rows attend in a layer of each kind
+                # (the scheduler's live span carries it: take_attended)
+                ctx = batch.start_pos[:len(batch.uids)] + 1
+                self._attended = (
+                    int(ctx.sum()),
+                    int(np.minimum(ctx, self._state.window).sum()))
             nbytes = (batch.q_lens.nbytes + batch.start_pos.nbytes
                       + batch.page_table.nbytes)
             if h2d_tokens:
                 nbytes += batch.token_ids.nbytes
             serving_counters.record_h2d(nbytes)
             return batch
+
+    def take_attended(self) -> Tuple[int, int]:
+        """(tokens, tokens inside the window) that the decode rows of the
+        steps built since the last call attend, summed over rows: a full
+        layer's and a window layer's context (a model of two page
+        groups; (0, 0) otherwise)."""
+        out, self._attended = self._attended, (0, 0)
+        return out
 
     def _prev_len(self, prev_tokens) -> int:
         """A chain key's ``prev_len`` for the previous step's token
@@ -1294,14 +1385,20 @@ class InferenceEngineV2:
         poison a shared cache page."""
         with trace_span("engine.commit"):
             window = getattr(self._model.cfg, "sliding_window", None)
+            done = []
             for uid, n in zip(batch_uids, committed):
                 sd = self._state.get_sequence(uid)
                 if sd is None:
                     continue    # failed/evicted mid-step
                 sd.commit_tokens(int(n))
+                if self._state.window_cache is not None:
+                    done.append(sd)
+                    continue
                 self._state.index_prefix(sd)
                 if window:
                     self._state.evict_window(sd, window)
+            if done:
+                self._evict_window_group(done)
 
     # -- prefix cache (ISSUE 3) ---------------------------------------------
     def match_prefix(self, uid: int, prompt: Sequence[int]) -> int:

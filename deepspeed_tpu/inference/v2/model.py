@@ -43,7 +43,7 @@ from ...utils.compile_cache import thread_cache_counts
 from .lattice import POWER_LATTICE
 from .ragged import KVCacheConfig, RaggedBatch
 from .step_key import (STEP_KINDS, StepKey, step_avals, step_program,
-                       trunk_params)
+                       trunk_params, window_slots)
 
 
 def serving_peak_flops() -> Optional[float]:
@@ -173,7 +173,8 @@ class RaggedInferenceModel:
                  kv_config: Optional[KVCacheConfig] = None,
                  mesh: Optional[jax.sharding.Mesh] = None,
                  mlp_fn: Optional[Callable] = None,
-                 attention_impl: Optional[str] = None):
+                 attention_impl: Optional[str] = None,
+                 window_kv_config: Optional[KVCacheConfig] = None):
         self.cfg = cfg
         self.mesh = mesh
         if mlp_fn is None and cfg.moe_num_experts > 0:
@@ -217,6 +218,35 @@ class RaggedInferenceModel:
             dtype=cfg.dtype) if cfg.latent_dim else KVCacheConfig(
             num_layers=cfg.num_layers, kv_heads=cfg.kv_heads,
             head_dim=cfg.dims_per_head, dtype=cfg.dtype))
+        #: the window group's cache (a model of two attention kinds: its
+        #: full layers' K/V in ``kv_config``'s pool, its window layers' in
+        #: this one, ``_forward_hidden_kinds``); None: one page group
+        self.window_kv_config: Optional[KVCacheConfig] = None
+        if cfg.layer_kinds:
+            import dataclasses
+
+            from ...models.laguna import group_layers
+            layers, heads = group_layers(cfg), dict(cfg.heads_by_kind)
+            assert layers["full"] and layers["window"], \
+                "layer_kinds names both kinds, or the model has one group"
+            self.kv_config = dataclasses.replace(
+                self.kv_config, num_layers=layers["full"])
+            self.window_kv_config = window_kv_config or dataclasses.replace(
+                self.kv_config, num_layers=layers["window"])
+            # each kind's attention modules, over its own head count,
+            # window and kernel name
+            self._kind_cfg = {
+                "full": dataclasses.replace(
+                    cfg, num_heads=heads["full"], sliding_window=None),
+                "window": dataclasses.replace(
+                    cfg, num_heads=heads["window"])}
+            self._attention_of = {
+                kind: instantiate("ragged_attention", kc,
+                                  name=attention_impl)
+                for kind, kc in self._kind_cfg.items()}
+            self._fresh_of = {
+                kind: instantiate("fresh_prefill_attention", kc)
+                for kind, kc in self._kind_cfg.items()}
         #: which mesh axis shards heads/ffn/vocab (and the KV head dim):
         #: the serving ``tp`` axis when present, else the training-side
         #: ``tensor`` axis.  None until a mesh is applied.
@@ -305,10 +335,10 @@ class RaggedInferenceModel:
         if fmt not in SUPPORTED_FORMATS:
             raise ValueError(f"unknown quantization format {fmt!r} "
                              f"(supported: {sorted(SUPPORTED_FORMATS)})")
-        if self.cfg.latent_dim:
+        if self.cfg.latent_dim or self.cfg.layer_kinds:
             raise ValueError(
                 "weight-only quantization does not cover the latent-"
-                "attention / held-experts block yet")
+                "attention / held-experts / two-kind blocks yet")
         prior = getattr(self, "_quantized_fmt", None)
         if prior is not None:
             if prior != fmt:
@@ -496,6 +526,14 @@ class RaggedInferenceModel:
                              jnp.asarray(row_pos, jnp.int32))
         return step(trunk_params(self, STEP_KINDS[key.kind].trunk), kv,
                     *operands)
+
+    def window_slots(self, Q: int) -> int:
+        """Slots of the window group's table in a segment of ``Q`` tokens
+        a row (``step_key.window_slots``); 0 for a model of one group."""
+        if self.window_kv_config is None:
+            return 0
+        return window_slots(self.cfg.sliding_window,
+                            self.kv_config.page_size, Q)
 
     @property
     def last_trunk_passes(self) -> int:
@@ -828,6 +866,9 @@ class RaggedInferenceModel:
         if cfg.latent_dim:
             return self._forward_hidden_latent(params, kv, segments, cfg,
                                                stats_out)
+        if cfg.layer_kinds:
+            return self._forward_hidden_kinds(params, kv, segments, cfg,
+                                              stats_out)
         x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
                         _end_to_end([seg.token_ids for seg in segments]))
         pos = _positions(segments)
@@ -1274,6 +1315,151 @@ class RaggedInferenceModel:
                 out.append(jnp.einsum("sqhr,rhd->sqhd", ctx, w_v))
         out = _end_to_end(out)
         return jnp.einsum("sqhd,hde->sqe", out, ap["wo"].astype(dtype)), kv
+
+    def _by_group(self, seg: Segment) -> Dict[str, Segment]:
+        """A segment of a model with two page groups, as each group's
+        layers take it: the wide table (``ragged/batch.py``) apart.  The
+        window group's short table starts at the page of absolute index
+        ``base``, so its rows are REBASED by ``base`` pages: the cache
+        write and attention use positions only to find a token's slot
+        and to mask, which depend on differences of positions alone (the
+        rope is applied before, from the absolute positions)."""
+        W = self.window_slots(seg.token_ids.shape[1])
+        P = seg.page_table.shape[1] - W - 1
+        base = seg.page_table[:, -1]
+        return {"full": seg._replace(page_table=seg.page_table[:, :P]),
+                "window": seg._replace(
+                    page_table=seg.page_table[:, P:P + W],
+                    start_pos=seg.start_pos
+                    - base * self.kv_config.page_size)}
+
+    def _forward_hidden_kinds(self, params, kv, segments, cfg, stats_out):
+        """The trunk of a model whose layers are of two attention kinds
+        (``models/laguna.py``): the leading dense layers, then ONE scan
+        over the whole periods of the layer pattern whose body is the
+        period's layers in order, then the tail; still one pass of the
+        weights over all tokens.  ``kv`` is the pair (full group's pool,
+        window group's pool): the carry holds both beside the activations
+        and the held-experts counts, and a layer writes and reads its own
+        group's pool at its index in the group."""
+        from ...models.laguna import layer_plan, rope_table
+        x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
+                        _end_to_end([seg.token_ids for seg in segments]))
+        pos = _positions(segments)
+        valid = _end_to_end([
+            jnp.arange(seg.token_ids.shape[1], dtype=jnp.int32)[None, :]
+            < seg.q_lens[:, None] for seg in segments]).reshape(-1)
+        groups = [self._by_group(seg) for seg in segments]
+        kinds = cfg.layer_kinds
+        body = functools.partial(
+            self._layer_body_kinds, cfg=cfg, valid=valid,
+            segments={kind: [g[kind] for g in groups]
+                      for kind in ("full", "window")},
+            ropes={kind: rope_table(cfg, kind, pos)
+                   for kind in ("full", "window")},
+            experts=params.get("experts"))
+        dense, period, periods = layer_plan(cfg)
+        carry = (x, *kv, jnp.zeros((3,), jnp.int32))
+        at = {"full": 0, "window": 0}    # the next layer's index in its group
+        for i in range(dense):
+            carry = body(carry, params["dense_layers"][f"l{i}"],
+                         kind=kinds[i], at=at[kinds[i]])
+            at[kinds[i]] += 1
+        if periods:
+            pattern = kinds[dense:dense + period]
+            per = {kind: pattern.count(kind) for kind in at}
+
+            def one_period(carry, xs, at=dict(at)):
+                lps, p = xs
+                met = dict.fromkeys(per, 0)
+                for j, kind in enumerate(pattern):
+                    carry = body(carry, lps[f"l{j}"], kind=kind,
+                                 at=at[kind] + p * per[kind] + met[kind],
+                                 routed=p * period + j)
+                    met[kind] += 1
+                return carry, None
+
+            carry, _ = jax.lax.scan(
+                one_period, carry,
+                (params["periods"], jnp.arange(periods, dtype=jnp.int32)))
+            for kind in at:
+                at[kind] += periods * per[kind]
+        first = dense + periods * period
+        for i in range(first, cfg.num_layers):
+            carry = body(carry, params["tail"][f"l{i - first}"],
+                         kind=kinds[i], at=at[kinds[i]], routed=i - dense)
+            at[kinds[i]] += 1
+        x, full, window, stats = carry
+        if stats_out is not None:
+            stats_out.append(stats)
+        return self._norm(params["final_norm"], x), (full, window)
+
+    def _layer_body_kinds(self, carry, lp, *, kind, at, routed=None,
+                          segments, ropes, cfg, valid, experts):
+        """One layer of kind ``kind`` over (x, both pools, the counts):
+        attention with the kind's head count, rope and page group (layer
+        ``at`` of the group's pool), each head's output through its
+        sigmoid gate, then the dense MLP or routed layer ``routed``'s held
+        share plus the shared expert."""
+        x, full, window, stats = carry
+        pool = full if kind == "full" else window
+        dtype = cfg.dtype
+        h = self._norm(lp["norm1"], x)
+        ap = lp["attn"]
+        sin, cos = ropes[kind]
+        d = cfg.dims_per_head
+
+        def heads(w):
+            """``h W`` by head, ``[S, Q, heads, d]`` (the weights are
+            stored with the heads folded into their columns)."""
+            y = jnp.einsum("sqe,ef->sqf", h, w.astype(dtype))
+            return y.reshape(y.shape[:2] + (-1, d))
+
+        q = T.apply_rope(heads(ap["wq"]), sin, cos)
+        k = T.apply_rope(heads(ap["wk"]), sin, cos)
+        v = heads(ap["wv"])
+        segs, attn = segments[kind], []
+        for seg, qs, ks, vs in zip(segs, *(
+                _per_segment(a, segs) for a in (q, k, v))):
+            pool = write_kv(pool, at, ks, vs, seg.page_table, seg.start_pos,
+                            seg.q_lens)
+            if seg.fresh:
+                attn.append(self._fresh_of[kind](qs, ks, vs))
+            else:
+                attn.append(self._attention_of[kind](
+                    qs, pool, at, seg.page_table, seg.start_pos,
+                    seg.q_lens))
+        attn = _end_to_end(attn)
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "sqe,eh->sqh", h, ap["wgate"].astype(dtype),
+            preferred_element_type=jnp.float32))
+        attn = (attn.astype(jnp.float32) * gate[..., None]).astype(dtype)
+        x = x + jnp.einsum(
+            "sqf,fe->sqe", attn.reshape(attn.shape[:2] + (-1,)),
+            ap["wo"].astype(dtype)).astype(x.dtype)
+        h = self._norm(lp["norm2"], x)
+        if "moe" in lp:
+            from ...moe.held import ROUTERS, held_experts_ffn
+            mp = lp["moe"]
+            S, Q, E = h.shape
+            h2 = h.reshape(S * Q, E)
+            chosen, weights = ROUTERS[cfg.router_scoring](
+                h2, mp["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
+                cfg.norm_topk_prob)
+            out, counts = held_experts_ffn(
+                h2, chosen, weights, experts, cfg.experts_first,
+                layer=routed, valid=valid)
+            out = out.reshape(S, Q, E)
+            if "shared" in mp:
+                out = out + T._mlp_block(cfg, mp["shared"], h)
+            stats = jnp.stack([stats[0] + jnp.sum(counts),
+                               jnp.maximum(stats[1], jnp.max(counts)),
+                               stats[2] + jnp.sum(counts > 0)])
+        else:
+            out = T._mlp_block(cfg, lp["mlp"], h)
+        x = x + out.astype(x.dtype)
+        return ((x, pool, window, stats) if kind == "full"
+                else (x, full, pool, stats))
 
     def _per_shard_heads(self, fn, cfg, n_head_args: int,
                          pool_out: bool = False):

@@ -2,7 +2,7 @@
 
 Reference: ``inference/v2/model_implementations/`` — one directory per
 arch (llama_v2, mistral, mixtral, falcon, opt, phi, qwen, qwen_v2; here
-also bloom, gpt_neox, gpt2, gptj and pangu_ultra_moe), each
+also bloom, gpt_neox, gpt2, gptj, pangu_ultra_moe and laguna), each
 a ``DSTransformerModelBase`` subclass hard-coding that family's
 invariants (llama_v2/model.py:22, mistral/model.py, ...), chosen by
 ``engine_factory`` from the checkpoint's ``model_type``.
@@ -124,6 +124,35 @@ class PanguUltraMoEInferenceModel(RaggedInferenceModel):
             == held, "expert weights do not match experts_held"
 
 
+class LagunaInferenceModel(RaggedInferenceModel):
+    """Laguna (``models/laguna.py``; no counterpart in the reference):
+    full and window attention layers in one model over two page groups,
+    a head count a kind, a per-head output gate, leading dense layers,
+    then routed layers of which this process holds ``experts_held``
+    experts beside the shared expert."""
+    MODEL_TYPES = ("laguna",)
+
+    def __init__(self, cfg, params, **kw):
+        assert set(cfg.layer_kinds) == {"full", "window"} \
+            and len(cfg.layer_kinds) == cfg.num_layers, \
+            "laguna names a kind for every layer, and has both"
+        assert cfg.sliding_window and cfg.head_gate
+        assert cfg.norm == "rmsnorm" and cfg.pos_emb == "rope"
+        heads = dict(cfg.heads_by_kind)
+        assert all(h % cfg.kv_heads == 0 for h in heads.values())
+        assert cfg.n_routed_experts >= cfg.moe_top_k >= 1
+        held = cfg.held_experts
+        assert 0 <= cfg.experts_first \
+            and cfg.experts_first + held <= cfg.n_routed_experts, \
+            "the experts held here lie outside the router's outputs"
+        assert 0 <= cfg.first_k_dense <= cfg.num_layers
+        super().__init__(cfg, params, **kw)
+        experts = self.params.get("experts")
+        assert experts is None or experts["wg"].shape[:2] \
+            == (cfg.num_layers - cfg.first_k_dense, held), \
+            "expert weights do not match the routed layers or experts_held"
+
+
 class GPTNeoXInferenceModel(RaggedInferenceModel):
     MODEL_TYPES = ("gpt_neox",)
 
@@ -140,6 +169,7 @@ _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LlamaV2InferenceModel, MistralInferenceModel, MixtralInferenceModel,
     FalconInferenceModel, OPTInferenceModel, PhiInferenceModel,
     Qwen2InferenceModel, BloomInferenceModel, PanguUltraMoEInferenceModel,
+    LagunaInferenceModel,
     GPTNeoXInferenceModel, GPT2InferenceModel, GPTJInferenceModel,
 )
 

@@ -102,11 +102,12 @@ def _pallas_decode(cfg):
     from ...ops.paged_attention import paged_attention
     slopes = _alibi_for(cfg)
     window = getattr(cfg, "sliding_window", None)
+    name = _kernel_name(cfg)
 
     def attn(q, kv, layer, page_table, start_pos, q_lens):
         return paged_attention(q, kv, layer, page_table, start_pos, q_lens,
                                use_kernel=None, alibi_slopes=slopes,
-                               window=window)
+                               window=window, name=name)
     return attn
 
 
@@ -122,6 +123,15 @@ def _dense_gather(cfg):
                                use_kernel=False, alibi_slopes=slopes,
                                window=window)
     return attn
+
+
+def _kernel_name(cfg) -> str:
+    """The paged kernel's name in a trace: the window kind of a model
+    with two attention kinds (its own page group) runs under a name of
+    its own, which still starts ``paged_attention``."""
+    return ("paged_attention_window"
+            if getattr(cfg, "layer_kinds", ()) and cfg.sliding_window
+            else "paged_attention")
 
 
 def _alibi_for(cfg):

@@ -11,6 +11,13 @@ buckets** so every distinct shape compiles exactly once:
     start_pos   : [S]    int32   committed history length per slot
     page_table  : [S, P] int32   KV page indices (0 = null page)
 
+For a model with two page groups (``StateManager.window_cache``) the
+table is WIDE: ``[S, P + W + 1]``, the full group's table, then the
+window group's short table (``W = step_key.window_slots(Q)`` slots, slot
+j = the page of absolute index ``base + j``), then ``base`` itself: the
+second table rides the operand the programs already take, and
+``RaggedInferenceModel`` takes it apart (``_by_group``).
+
 ``S`` (sequence slots), ``Q`` (max new tokens per sequence) and ``P``
 (max pages per sequence) are bucketed by the engine's lattice (powers of
 two by default); a pure-decode batch compiles with Q=1, a prefill chunk
@@ -64,10 +71,14 @@ class RaggedBatch:
     def current_sequences(self) -> int:
         return len(self.uids)
 
+    #: pages a row of the FULL group's table (the key's ``P``); the
+    #: table's width where it is the only one
+    pages: int = 0
+
     @property
     def shape_key(self) -> Tuple[int, int, int, bool]:
         return (self.token_ids.shape[0], self.token_ids.shape[1],
-                self.page_table.shape[1], self.fresh)
+                self.pages or self.page_table.shape[1], self.fresh)
 
 
 def build_batch(seqs: Sequence[SequenceDescriptor],
@@ -76,7 +87,8 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
                 lattice,
                 fresh_supported: bool = True,
                 min_q: int = 1,
-                start_pos: Optional[Sequence[int]] = None) -> RaggedBatch:
+                start_pos: Optional[Sequence[int]] = None,
+                window_slots=None) -> RaggedBatch:
     """Pack (descriptor, new-token) pairs into a bucketed RaggedBatch.
 
     Callers must already have reserved KV pages on each descriptor
@@ -96,6 +108,11 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
     ``start_pos``: each row's start position where it is not the
     descriptor's committed length (the draft catch-up re-feeds committed
     history from where the draft pool stopped).
+
+    ``window_slots``: for a model with two page groups, the bucket rule
+    ``Q -> slots of the window group's table`` (``step_key.window_slots``
+    bound to the model); the table is then the wide one of the module
+    docstring.
     """
     n = len(seqs)
     assert n == len(tokens) and n >= 1
@@ -107,15 +124,25 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
     token_ids = np.zeros((S, Q), dtype=np.int32)
     q_lens = np.zeros(S, dtype=np.int32)
     starts = np.zeros(S, dtype=np.int32)
-    page_table = np.zeros((S, P), dtype=np.int32)
+    W = window_slots(Q) if window_slots is not None else 0
+    page_table = np.zeros((S, P + W + 1 if W else P), dtype=np.int32)
     uids = []
     for i, (sd, toks) in enumerate(zip(seqs, tokens)):
         toks = np.asarray(toks, dtype=np.int32).reshape(-1)
         token_ids[i, :len(toks)] = toks
         q_lens[i] = len(toks)
         starts[i] = start_pos[i]
-        page_table[i] = sd.page_table(P)
+        page_table[i, :P] = sd.page_table(P)
+        if W:
+            live = sd.window_pages
+            if len(live) > W:
+                raise ValueError(
+                    f"sequence {sd.uid} holds {len(live)} window-group "
+                    f"pages > the table's {W} slots (eviction has not "
+                    "kept up with the context)")
+            page_table[i, P:P + len(live)] = live
+            page_table[i, -1] = sd.window_base
         uids.append(sd.uid)
     fresh = fresh_supported and Q > 1 and not any(start_pos)
     return RaggedBatch(token_ids, q_lens, starts, page_table, uids,
-                       fresh=fresh)
+                       fresh=fresh, pages=P)

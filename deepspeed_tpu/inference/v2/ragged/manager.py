@@ -10,6 +10,20 @@ it AND the prefix cache no longer retains it.  ``free_pages`` reports
 free-list pages plus cache-parked pages: the cache is exactly the
 otherwise-idle pool, reclaimed LRU on allocator pressure, so admission
 accounting and steady-state capacity are unchanged.
+
+Page groups: a model whose layers are of two attention kinds (full and
+window) keeps each kind's K/V in a pool of its own, with its own
+allocator (``window_cache``) and a second block table a sequence
+(``SequenceDescriptor.window_pages``).  The unit is the group and not the
+layer: layers of one kind need the same pages at the same positions, so
+they share one table.  The full group's table grows with the context;
+the window group's holds the pages the window still reaches and gives
+the others back (``evict_window``).  Every codec below that walks a
+sequence's pages (flush, preempt offload / restore, snapshot, the
+handoff) walks both lists.  The prefix cache is OFF with a window group:
+a matched prefix would skip the prefill of tokens whose window-group K/V
+nobody holds, so nothing is indexed.  A model with one window or none
+has ONE group and this module's paths are what they were.
 """
 
 from __future__ import annotations
@@ -40,10 +54,24 @@ class StateManager:
                  prefix_caching: bool = True,
                  tier_host_pages: int = 0,
                  tier_disk_pages: int = 0,
-                 tier_dir: Optional[str] = None):
+                 tier_dir: Optional[str] = None,
+                 window_kv_config: Optional[KVCacheConfig] = None,
+                 window: int = 0):
         self.kv_config = kv_config
         self.max_tracked_sequences = max_tracked_sequences
         self.kv_cache = BlockedKVCache(kv_config, sharding=kv_sharding)
+        #: the window group's pool and allocator (module docstring), and
+        #: the window its layers attend; None for a model of one group
+        self.window_cache: Optional[BlockedKVCache] = None
+        self.window = int(window)
+        #: pages the window group's eviction has given back, ever
+        self.window_pages_released = 0
+        if window_kv_config is not None:
+            assert window > 0 \
+                and window_kv_config.page_size == kv_config.page_size
+            self.window_cache = BlockedKVCache(window_kv_config,
+                                               sharding=kv_sharding)
+            prefix_caching = False      # module docstring
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(kv_config.page_size) if prefix_caching else None)
         # host/disk prefix tier (ISSUE 16): only meaningful under the
@@ -112,6 +140,23 @@ class StateManager:
             pages += live
             tokens += max(sd.seen_tokens
                           - (len(sd.pages) - live) * page, 0)
+        return pages, tokens
+
+    @property
+    def free_window_pages(self) -> int:
+        """Free pages of the window group (0 for a model of one group)."""
+        return (self.window_cache.free_pages
+                if self.window_cache is not None else 0)
+
+    def window_occupancy(self) -> Tuple[int, int]:
+        """:meth:`kv_occupancy` of the window group: (pages in the live
+        sequences' window tables, tokens those pages hold)."""
+        page = self.kv_config.page_size
+        pages = tokens = 0
+        for sd in self._seqs.values():
+            pages += len(sd.window_pages)
+            if sd.window_pages:
+                tokens += max(sd.seen_tokens - sd.window_base * page, 0)
         return pages, tokens
 
     def get_sequence(self, uid: int) -> Optional[SequenceDescriptor]:
@@ -366,6 +411,18 @@ class StateManager:
         sd.host_blob = None
         sd.live_slots = []
 
+    def _hold_window_blob(self, sd: SequenceDescriptor, blob) -> None:
+        """Account a host blob of the window group's pages (counted as a
+        blob of its own beside the full group's)."""
+        sd.window_blob = blob
+        self._offload_blobs += 1
+        self._offload_bytes += blob.nbytes
+
+    def _release_window_blob(self, sd: SequenceDescriptor) -> None:
+        self._offload_blobs -= 1
+        self._offload_bytes -= sd.window_blob.nbytes
+        sd.window_blob = None
+
     def flush_sequence(self, uid: int) -> None:
         sd = self._seqs.pop(uid, None)
         if sd is not None:
@@ -380,6 +437,10 @@ class StateManager:
                     # device pages (the blob accounting audit would
                     # otherwise report the leak forever)
                     self._release_blob(sd)
+                if sd.window_pages:
+                    self.window_cache.release(sd.window_pages)
+                if sd.window_blob is not None:
+                    self._release_window_blob(sd)
 
     def offload_sequence(self, uid: int) -> None:
         """Preempt: move a sequence's PRIVATE live KV pages to host
@@ -390,10 +451,18 @@ class StateManager:
         point of preemption is reclaiming memory).  The sequence stays
         tracked; it cannot be scheduled until restore_sequence."""
         sd = self._seqs.get(uid)
-        if sd is None or sd.host_blob is not None:
+        if sd is None or sd.host_blob is not None \
+                or sd.window_blob is not None:
             return  # unknown/flushed uids tolerated like flush_sequence
         with trace_span("kv.offload"):
             self._offload_impl(sd)
+            if sd.window_pages:
+                # the window group's pages are all private (nothing of
+                # it is shared or indexed): every one moves, and comes
+                # back in order under the same window_base
+                self._hold_window_blob(
+                    sd, self.window_cache.offload_pages(sd.window_pages))
+                sd.window_pages = []
 
     def _offload_impl(self, sd: SequenceDescriptor) -> None:
         sd.live_slots = self.offloadable_slots(sd)
@@ -421,14 +490,27 @@ class StateManager:
         """Bring a preempted sequence's KV back onto device (reference
         restore hook).  Raises if the pool lacks free pages."""
         sd = self._seqs.get(uid)
-        if sd is None or sd.host_blob is None:
+        if sd is None or (sd.host_blob is None and sd.window_blob is None):
             return
         with trace_span("kv.restore"):
-            self.ensure_free(int(sd.host_blob.shape[1]))
-            pages = self.kv_cache.restore_pages(sd.host_blob)
-            for slot, p in zip(sd.live_slots, pages):
-                sd.pages[slot] = int(p)
-            self._release_blob(sd)
+            need_w = (int(sd.window_blob.shape[1])
+                      if sd.window_blob is not None else 0)
+            if need_w > self.free_window_pages:
+                # before the full group is touched: a restore fails whole
+                raise KVAllocationError(
+                    f"restore needs {need_w} window-group pages, "
+                    f"{self.free_window_pages} free")
+            if sd.host_blob is not None:
+                self.ensure_free(int(sd.host_blob.shape[1]))
+                pages = self.kv_cache.restore_pages(sd.host_blob)
+                for slot, p in zip(sd.live_slots, pages):
+                    sd.pages[slot] = int(p)
+                self._release_blob(sd)
+            if sd.window_blob is not None:
+                sd.window_pages = [int(p) for p in
+                                   self.window_cache.restore_pages(
+                                       sd.window_blob)]
+                self._release_window_blob(sd)
         # restored pages are private again; if offload unindexed any of
         # them it also disabled this sequence's indexing (broken chain),
         # otherwise the digest chain is intact and indexing continues
@@ -443,6 +525,14 @@ class StateManager:
         if min_attended <= 0:
             return 0
         first_live = min_attended // self.kv_config.page_size
+        if self.window_cache is not None:
+            # two groups: the window group's table alone gives pages
+            # back; the full group's layers need theirs for good
+            freed = sd.evict_window_pages(first_live)
+            if freed:
+                self.window_cache.release(freed)
+                self.window_pages_released += len(freed)
+            return len(freed)
         freed = sd.evict_pages_below(first_live)
         if freed:
             self._release_pages(freed)
@@ -525,7 +615,19 @@ class StateManager:
                                                      np.int32)
             if sd.host_blob is not None:
                 self._pack_blob(arrays, f"hostblob_{uid}", sd.host_blob)
+            if self.window_cache is not None:
+                m.update(window_pages=[int(p) for p in sd.window_pages],
+                         window_base=int(sd.window_base),
+                         has_window_blob=sd.window_blob is not None)
+                if sd.window_blob is not None:
+                    self._pack_blob(arrays, f"windowblob_{uid}",
+                                    sd.window_blob)
             seqs.append(m)
+        window_ids = [int(p) for sd in export_seqs.values()
+                      for p in sd.window_pages]
+        if window_ids:
+            self._pack_blob(arrays, "window_page_blob",
+                            self.window_cache.read_pages(window_ids))
         meta = {
             "kv": self._kv_meta(),
             "prefix_caching": self.prefix_cache is not None,
@@ -533,16 +635,55 @@ class StateManager:
             "sequences": seqs,
             "prefix": [[d.hex(), int(p)] for d, p in prefix_entries],
         }
+        if self.window_cache is not None:
+            meta["window_page_ids"] = window_ids
         if seq_ids is not None:
             meta["selective"] = True
         return meta, arrays
 
     def _kv_meta(self) -> dict:
         cfg = self.kv_config
-        return {"num_layers": cfg.num_layers, "kv_heads": cfg.kv_heads,
+        meta = {"num_layers": cfg.num_layers, "kv_heads": cfg.kv_heads,
                 "head_dim": cfg.head_dim, "page_size": cfg.page_size,
                 "dtype": np.dtype(cfg.dtype).name,
                 "quantization": cfg.quantization, "planes": cfg.planes}
+        if self.window_cache is not None:
+            meta["window_layers"] = self.window_cache.cfg.num_layers
+        return meta
+
+    def _window_mapping(self, meta: dict,
+                        arrays: Dict[str, np.ndarray]) -> Dict[int, int]:
+        """Import half of the window group: the bundle's window pages on
+        fresh pages of this manager's window pool -> {old id: new id}.
+        Raises the retryable :class:`KVAllocationError` before anything
+        is written where the pool lacks room."""
+        from ..snapshot import SnapshotError
+        old_ids = [int(p) for p in meta.get("window_page_ids", [])]
+        if not old_ids:
+            return {}
+        blob = self._unpack_blob(arrays, "window_page_blob")
+        if blob is None or blob.shape[1] != len(old_ids):
+            raise SnapshotError(
+                "window page blob missing or inconsistent with "
+                "window_page_ids")
+        if len(old_ids) > self.free_window_pages:
+            raise KVAllocationError(
+                f"bundle needs {len(old_ids)} window-group pages, pool "
+                f"has {self.free_window_pages} free")
+        new = self.window_cache.restore_pages(blob)
+        return {o: int(n) for o, n in zip(old_ids, new)}
+
+    def _import_window(self, sd: SequenceDescriptor, m: dict,
+                       mapping: Dict[int, int],
+                       arrays: Dict[str, np.ndarray]) -> None:
+        """One imported sequence's window table and window blob."""
+        if self.window_cache is None:
+            return
+        sd.window_pages = [mapping[int(p)] for p in m["window_pages"]]
+        sd.window_base = int(m["window_base"])
+        if m.get("has_window_blob"):
+            self._hold_window_blob(
+                sd, self._unpack_blob(arrays, f"windowblob_{sd.uid}"))
 
     def _check_kv_meta(self, meta: dict) -> None:
         from ..snapshot import SnapshotError
@@ -617,6 +758,10 @@ class StateManager:
             raise SnapshotError(
                 f"bundle needs {len(old_ids)} KV pages, pool has "
                 f"{alloc.free_pages} free")
+        try:
+            window_mapping = self._window_mapping(meta, arrays)
+        except KVAllocationError as e:
+            raise SnapshotError(str(e)) from None
         mapping = {NULL_PAGE: NULL_PAGE}
         if old_ids:
             blob = self._unpack_blob(arrays, "page_blob")
@@ -658,6 +803,7 @@ class StateManager:
                                                  f"hostblob_{uid}")
                 self._offload_blobs += 1
                 self._offload_bytes += sd.host_blob.nbytes
+            self._import_window(sd, m, window_mapping, arrays)
             self._seqs[uid] = sd
         if self.prefix_cache is not None:
             for d_hex, p in meta["prefix"]:
@@ -736,6 +882,9 @@ class StateManager:
                 f"handoff import needs {len(stream)} streamed pages, "
                 f"pool has {available} schedulable — retry after "
                 "the decode pool drains")
+        # the window group's pages stream whole (nothing of it is shared);
+        # a refusal here is still before any mutation
+        window_mapping = self._window_mapping(meta, arrays)
         # true refcounts per exported page = appearances in the
         # imported block tables (selective bundles carry no parked
         # pages, so every exported page is referenced at least once)
@@ -782,6 +931,7 @@ class StateManager:
                                                  f"hostblob_{uid}")
                 self._offload_blobs += 1
                 self._offload_bytes += sd.host_blob.nbytes
+            self._import_window(sd, m, window_mapping, arrays)
             self._seqs[uid] = sd
         if self.prefix_cache is not None:
             for d_hex, p in meta["prefix"]:
@@ -886,14 +1036,33 @@ class StateManager:
         need = -(-total // page)  # ceil
         return max(0, need - sd.allocated_capacity)
 
+    def window_pages_needed(self, sd: SequenceDescriptor,
+                            n_new_tokens: int) -> int:
+        """:meth:`pages_needed` of the window group (0 without one): its
+        short table has to reach the page of the last new token."""
+        if self.window_cache is None:
+            return 0
+        last = (sd.seen_tokens + n_new_tokens - 1) \
+            // self.kv_config.page_size
+        return max(0, last + 1 - sd.window_base - len(sd.window_pages))
+
     def allocate_for(self, sd: SequenceDescriptor, n_new_tokens: int) -> None:
         extra = self.pages_needed(sd, n_new_tokens)
+        extra_w = self.window_pages_needed(sd, n_new_tokens)
+        if extra_w > self.free_window_pages:
+            # admission reserves in both groups or in neither
+            raise KVAllocationError(
+                f"window group: {extra_w} pages requested, "
+                f"{self.free_window_pages} free")
         if extra:
             get_fault_injector().maybe_raise(
                 "kv.alloc_oom", KVAllocationError,
                 f"injected KV allocator OOM ({extra} pages requested)")
             self.ensure_free(extra)
             sd.extend_pages(self.kv_cache.reserve(extra))
+        if extra_w:
+            sd.window_pages.extend(
+                int(p) for p in self.window_cache.reserve(extra_w))
 
     # -- invariants (DS_KV_DEBUG) -------------------------------------------
     def check_invariants(self) -> None:
@@ -931,9 +1100,42 @@ class StateManager:
             raise RuntimeError(
                 f"KV invariant: free({alloc.free_pages}) + live({live}) "
                 f"+ cached({parked}) != total({alloc.total_pages})")
-        blobs = [sd for sd in self._seqs.values()
-                 if sd.host_blob is not None]
-        blob_bytes = sum(sd.host_blob.nbytes for sd in blobs)
+        if self.window_cache is not None:
+            # the window group: every page of a window table is held
+            # once (nothing of it is shared, parked or indexed), a table
+            # never ends short of the sequence's committed tokens, and
+            # it holds no page wholly under the window
+            walloc = self.window_cache.allocator
+            page = self.kv_config.page_size
+            held = Counter(p for sd in self._seqs.values()
+                           for p in sd.window_pages)
+            for p, n in held.items():
+                if n != 1 or p == NULL_PAGE or not walloc.is_allocated(p) \
+                        or walloc.ref_count(p) != 1:
+                    raise RuntimeError(
+                        f"KV invariant: window-group page {p} is in {n} "
+                        "window tables or not held once by the allocator")
+            if walloc.live_pages != len(held) or walloc.parked_pages \
+                    or walloc.free_pages + len(held) != walloc.total_pages:
+                raise RuntimeError(
+                    f"KV invariant: window group free"
+                    f"({walloc.free_pages}) + tables({len(held)}) != "
+                    f"total({walloc.total_pages}), or parked pages")
+            for sd in self._seqs.values():
+                if sd.window_blob is not None or not sd.seen_tokens:
+                    continue
+                end = (sd.window_base + len(sd.window_pages)) * page
+                if end < sd.seen_tokens or sd.window_base * page \
+                        > max(sd.seen_tokens - self.window + 1, 0):
+                    raise RuntimeError(
+                        f"KV invariant: sequence {sd.uid}'s window table "
+                        f"covers [{sd.window_base * page}, {end}) with "
+                        f"{sd.seen_tokens} tokens committed")
+        blobs = [sd.host_blob for sd in self._seqs.values()
+                 if sd.host_blob is not None] + [
+                     sd.window_blob for sd in self._seqs.values()
+                     if sd.window_blob is not None]
+        blob_bytes = sum(b.nbytes for b in blobs)
         if (len(blobs) != self._offload_blobs
                 or blob_bytes != self._offload_bytes):
             raise RuntimeError(

@@ -31,6 +31,16 @@ class SequenceDescriptor:
     #: table slots the blob's pages belonged to (window-evicted slots
     #: stay null through an offload/restore cycle)
     live_slots: List[int] = dataclasses.field(default_factory=list)
+    #: the second block table of a model with two page groups (its
+    #: window layers' pool): only the LIVE pages, in order, table slot j
+    #: holding the page of absolute index ``window_base + j``.  Pages
+    #: wholly under the window are released and leave the table
+    #: (``evict_window_pages``), so the table stays short
+    window_pages: List[int] = dataclasses.field(default_factory=list)
+    #: absolute page index of ``window_pages[0]``
+    window_base: int = 0
+    #: host blob of the window group's pages while preempted
+    window_blob: object = None
     #: full prompt token ids, registered at admission when prefix
     #: caching is on — the indexer hashes full prompt pages from these
     #: (generated tokens are never indexed: their values are only
@@ -81,6 +91,20 @@ class SequenceDescriptor:
             if self.pages[i] != 0:
                 freed.append(self.pages[i])
                 self.pages[i] = 0
+        return freed
+
+    def evict_window_pages(self, first_live_page: int) -> List[int]:
+        """The window group's form of :meth:`evict_pages_below`: the
+        pages wholly below the window leave the short table, which then
+        starts at ``first_live_page``; their ids are returned for the
+        allocator."""
+        drop = min(first_live_page - self.window_base,
+                   len(self.window_pages))
+        if drop <= 0:
+            return []
+        freed = self.window_pages[:drop]
+        del self.window_pages[:drop]
+        self.window_base += drop
         return freed
 
     def page_table(self, max_pages: int) -> np.ndarray:

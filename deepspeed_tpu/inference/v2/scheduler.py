@@ -202,6 +202,8 @@ class _Admission:
         sm = engine._config.state_manager
         self.engine = engine
         self.free_pages = engine.free_blocks
+        #: the window group's free pages (a model of two page groups)
+        self.free_window_pages = engine.free_window_blocks
         self.tokens_left = min(token_budget, sm.max_ragged_batch_size)
         self.seqs_left = sm.max_ragged_sequence_count
         self.tracked_left = (sm.max_tracked_sequences
@@ -214,6 +216,11 @@ class _Admission:
         tokens, pages = self.engine.query(uid, n_tokens, self.free_pages)
         if tokens != n_tokens:
             return False
+        # both groups or neither (0 of 0 for a model of one group)
+        pages_w = self.engine.window_blocks_needed(uid, n_tokens)
+        if pages_w > self.free_window_pages:
+            return False
+        self.free_window_pages -= pages_w
         self.free_pages -= pages
         self.tokens_left -= n_tokens
         self.seqs_left -= 1
@@ -292,6 +299,8 @@ class FastGenScheduler:
         self._token_tail = int(getattr(engine.model, "step_tail", 0))
         self._moe_counts = None
         self._moe_tokens = 0
+        #: ``StateManager.window_pages_released`` at the last live span
+        self._window_released = 0
         #: (pairs here, fullest expert's pairs, experts touched) of the
         #: last step drained, for a caller that checks them (None: none)
         self.last_moe_counts = None
@@ -1467,6 +1476,20 @@ class FastGenScheduler:
                 ("trunk_passes", self._engine.model.last_trunk_passes
                  if path != "idle" else 0)):
             span.set(key, value)
+        state = self._engine.state_manager
+        if state.window_cache is not None:
+            # the window group of a model with two page groups: what its
+            # tables hold beside the full group's, and what this step's
+            # eviction gave back
+            pages_w, held_w = state.window_occupancy()
+            span.set("kv_pages_reserved_window", pages_w)
+            span.set("kv_tokens_held_window", held_w)
+            span.set("kv_pages_released_window",
+                     state.window_pages_released - self._window_released)
+            self._window_released = state.window_pages_released
+            full, in_window = self._engine.take_attended()
+            span.set("attn_tokens_full", full)
+            span.set("attn_tokens_window", in_window)
         if self._moe_counts is not None:
             # counts of the step drained inside this one (the step
             # before), with that step's tokens as their divisor
@@ -1590,7 +1613,10 @@ class FastGenScheduler:
                     continue
                 need = (sd.host_blob.shape[1]
                         if sd.host_blob is not None else 0)
-                if self._engine.free_blocks >= need + 1:
+                need_w = (sd.window_blob.shape[1] + 1
+                          if sd.window_blob is not None else 0)
+                if self._engine.free_blocks >= need + 1 \
+                        and self._engine.free_window_blocks >= need_w:
                     self._engine.restore_sequence(uid)
                     get_flight_recorder().record("request.restore",
                                                  uid=uid)

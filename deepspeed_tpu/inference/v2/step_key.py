@@ -30,6 +30,18 @@ LATTICE_KINDS = ("prefill", "decode", "chain", "spec")
 _BOOL_FIELDS = frozenset({"fresh", "fresh_p", "greedy"})
 
 
+def window_slots(window: int, page_size: int, Q: int) -> int:
+    """Slots of the window group's table (a model with two page groups)
+    in a segment of ``Q`` tokens a row: the pages a row can hold live,
+    from the page of the first position its first new token attends
+    (``seen - window + 1``) to the page of its last new token, in whole
+    groups of 8 slots (the attention kernel's step).  It follows from the
+    key's ``Q`` alone, so the second table's bucket is no field of the
+    key: 16 slots for a decode row and for a 128-token chunk at a window
+    of 512 and pages of 64."""
+    return -(-((window + Q - 2) // page_size + 2) // 8) * 8
+
+
 class StepKey(tuple):
     """A step-cache key.  Its VALUE is the bare tuple it always was —
     ``StepKey.chain((64, 1, 8, False), 64, True) == (64, 1, 8, False,
@@ -293,8 +305,11 @@ def step_avals(model, key: StepKey, kv_aval) -> list:
     row = STEP_KINDS[key.kind]
 
     def segment(S, Q, P, _fresh=None):
+        # a model with two page groups takes the wide table
+        # (ragged/batch.py): the window group's slots and base follow
+        W = model.window_slots(Q)
         return [sds((S, Q), i32), sds((S,), i32), sds((S,), i32),
-                sds((S, P), i32)]
+                sds((S, P + W + 1 if W else P), i32)]
 
     S = rows = key.S
     avals = segment(*key[:3])

@@ -102,6 +102,23 @@ class TransformerConfig:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     first_k_dense: int = 0
+    # "sigmoid" (the scores themselves) or "softmax" over all experts,
+    # before the top-k, its normalisation and the scaling
+    router_scoring: str = "sigmoid"
+    # layers of two attention kinds in one model (models/laguna.py): one
+    # entry a layer, "full" or "window" (sliding_window wide); () = every
+    # layer of one kind.  Each kind has its own count of query heads
+    # (heads_by_kind, num_heads = the full kind's), its own rope (the
+    # window kind: window_rope_theta over all dims; the full kind:
+    # rope_theta over rope_pct of them, under rope_yarn = (factor,
+    # original positions, beta_fast, beta_slow, attention_factor) if set)
+    # and its own page group in the cache (inference/v2/ragged).
+    layer_kinds: Tuple[str, ...] = ()
+    heads_by_kind: Tuple[Tuple[str, int], ...] = ()
+    window_rope_theta: float = 0.0
+    rope_yarn: Tuple[float, ...] = ()
+    # o = concat_n(sigmoid(h Wg)_n * a_n) Wo: one gate a query head
+    head_gate: bool = False
     tie_embeddings: bool = False
     use_bias: bool = False
     dropout: float = 0.0
@@ -162,6 +179,12 @@ class TransformerConfig:
                     + self.kv_lora_rank * h * (self.qk_nope_head_dim
                                                + self.v_head_dim)
                     + h * self.v_head_dim * e)
+        attn *= l
+        if self.layer_kinds:        # a head count (and a gate) a kind
+            heads = dict(self.heads_by_kind)
+            attn = sum(2 * e * heads[kind] * d + 2 * e * k * d
+                       + (e * heads[kind] if self.head_gate else 0)
+                       for kind in self.layer_kinds)
         mlp = e * f * (3 if "gated" in self.activation else 2)
         dense = l
         routed = 0
@@ -171,7 +194,7 @@ class TransformerConfig:
                 e * self.n_routed_experts
                 + 3 * e * self.moe_intermediate_size
                 * (self.held_experts + self.n_shared_experts))
-        return (l * attn + dense * mlp + routed
+        return (attn + dense * mlp + routed
                 + v * e * (1 if self.tie_embeddings else 2))
 
 
@@ -714,6 +737,11 @@ def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
             "the training forward pass has no latent-attention block yet: "
             "this family is served (inference/v2) and held to the plain "
             "reference models/pangu_moe_reference.py")
+    if cfg.layer_kinds:
+        raise NotImplementedError(
+            "the training forward pass has one head count and one attention "
+            "kind for every layer: this family is served (inference/v2) and "
+            "held to the plain reference models/laguna_reference.py")
     params = meta.unbox(params) if _has_boxes(params) else params
     b, s = input_ids.shape
 

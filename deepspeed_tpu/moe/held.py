@@ -277,3 +277,24 @@ def dense_held_reference(x2d, experts, weights, params, first: int):
         jnp.where((experts - first)[..., None] == jnp.arange(held),
                   weights[..., None], 0.0), axis=1)         # [T, held]
     return jnp.einsum("tx,xte->te", gate, out)
+
+
+def route_softmax_topk(x2d: jax.Array, w_router: jax.Array, top_k: int,
+                       scaling: float, norm_topk: bool = True
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`route_sigmoid_topk` with the other scoring function: a
+    float32 softmax over ALL experts, the ``top_k`` largest, normalised
+    over the chosen ones and scaled (the qwen2_moe lineage's router).
+    The experts chosen are those of the largest logits either way; only
+    the weights differ."""
+    scores = jax.nn.softmax(jnp.dot(
+        x2d.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    top, experts = _largest(scores, top_k)
+    if norm_topk:
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), top * scaling
+
+
+#: the scoring functions a configuration names (``router_scoring``)
+ROUTERS = {"sigmoid": route_sigmoid_topk, "softmax": route_softmax_topk}

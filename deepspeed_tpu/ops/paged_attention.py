@@ -347,7 +347,8 @@ def paged_attention(q: jax.Array, kv: jax.Array, layer,
                     use_kernel: Optional[bool] = None,
                     alibi_slopes: Optional[jax.Array] = None,
                     window: Optional[int] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    name: str = "paged_attention") -> jax.Array:
     """Masked GQA attention of [S, Q] new tokens over their paged context.
 
     q       : [S, Q, H, D]    (H = K * groups)
@@ -374,7 +375,7 @@ def paged_attention(q: jax.Array, kv: jax.Array, layer,
         return paged_decode_attention(
             q, kv, layer, page_table, start_pos,
             sm_scale=sm_scale, alibi_slopes=alibi_slopes,
-            window=window, interpret=interpret)
+            window=window, interpret=interpret, name=name)
     K = K_heads
     G = H // K
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(D)
@@ -621,7 +622,8 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
                            sm_scale: float | None = None,
                            alibi_slopes: Optional[jax.Array] = None,
                            window: Optional[int] = None,
-                           interpret: bool = False) -> jax.Array:
+                           interpret: bool = False,
+                           name: str = "paged_attention") -> jax.Array:
     """Pallas ragged paged attention: [S, Q] queries over paged KV.
 
     TPU-native counterpart of the reference's blocked_flash atoms
@@ -720,9 +722,10 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         # named by the kind of row it serves, so a trace splits the
-        # kernel's time between decoding rows and prefill chunks
-        name=("paged_attention_decode" if Q == 1
-              else "paged_attention_prefill"),
+        # kernel's time between decoding rows and prefill chunks (and,
+        # through ``name``, between the page groups of a model that has
+        # two: the window group's calls are ``paged_attention_window_*``)
+        name=name + ("_decode" if Q == 1 else "_prefill"),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       page_table.astype(jnp.int32), start_pos.astype(jnp.int32), *inputs)

@@ -488,3 +488,83 @@ def test_pangu_step_program_moves_no_pool_and_no_expert_stack(
     assert pool_sized_movers(text, expert_layer) == []
     assert stack_shaped_movers(text, params) == []
     assert compiled.memory_analysis().temp_size_in_bytes < expert_layer
+
+
+LAGUNA_STEP_KEYS = {
+    "chain": (256, 1, 32, False, "chain", 256, True),
+    "mixed": (256, 1, 64, False, "mixed", 4, 128, 8, True, True),
+    # a prefill that is no fresh one (a later chunk of a prompt): the
+    # ragged kernel's 128-token block at 6 and 9 query heads a KV head
+    "chunk": (4, 128, 16, False, "sample", True),
+    # the cell's own page bucket (its lattice's top of 40 pages, no power
+    # of two: five groups of 8 page slots a decode row)
+    "chain-p40": (256, 1, 40, False, "chain", 256, True),
+    "mixed-p40": (256, 1, 40, False, "mixed", 2, 128, 8, True, True),
+    "drain-p40": (16, 1, 40, False, "chain", 32, True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(LAGUNA_STEP_KEYS))
+def test_laguna_step_program_moves_no_pool_and_no_expert_stack(
+        chip, monkeypatch, kind):
+    """The benchmark's cell at published widths (all five layers: the
+    dense one and a whole period): the step programs lower for the chip
+    with GQA groups of 6 and 9 (a decode block of 9 rows a KV head, a
+    prefill block of 1,152), the window group's calls run under a name of
+    their own, and neither group's pool nor the held experts' stack (four
+    layers of 302 MB) is copied, sliced out or re-laid out."""
+    import json
+    import os
+
+    from flax.core import meta
+
+    from benchmark.builders.serve_laguna import source_of
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        LagunaInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.laguna import LagunaForCausalLM
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-s-serve-5l-ep16.json")) as f:
+        config = json.load(f)
+    model = LagunaForCausalLM(source_of(config, False))
+    params = jax.eval_shape(lambda k: meta.unbox(model.init_params(k)),
+                            jax.random.key(0))
+    pages = {"full": 1024, "window": 512}
+    serve = LagunaInferenceModel(
+        model.cfg, params,
+        kv_config=KVCacheConfig(num_layers=2, kv_heads=8, head_dim=128,
+                                page_size=PAGE, num_pages=pages["full"]),
+        window_kv_config=KVCacheConfig(
+            num_layers=3, kv_heads=8, head_dim=128, page_size=PAGE,
+            num_pages=pages["window"]))
+    pool = (chip((2, pages["full"] + 1, 2, 8, PAGE, 128), jnp.bfloat16),
+            chip((3, pages["window"] + 1, 2, 8, PAGE, 128), jnp.bfloat16))
+    key = StepKey.parse(LAGUNA_STEP_KEYS[kind])
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        step_avals(serve, key, pool))
+    # the window group's table rides the page table: 16 slots and a base
+    assert (key.S, key.P + 16 + 1) in [a.shape for a in avals[2:]]
+    compiled = jax.jit(step_program(serve, key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    text = compiled.as_text()
+    row = "decode" if key.Q == 1 else "prefill"
+    for kernel in (f"paged_attention_{row}", f"paged_attention_window_{row}",
+                   f"kv_write_{row}", "moe_expert_ffn"):
+        assert any('custom_call_target="tpu_custom_call"' in line
+                   and kernel in line for line in text.splitlines()), kernel
+    # the smallest thing that must not move: a layer of the window pool
+    # (134 MB here; a layer of the experts' stack is 302 MB, the full
+    # pool's layers 268 MB)
+    layer_bytes = (pages["window"] + 1) * 2 * 8 * PAGE * 128 * 2
+    expert_layer = 16 * 3 * 3072 * 1024 * 2
+    assert layer_bytes < expert_layer
+    assert pool_sized_movers(text, layer_bytes) == []
+    assert stack_shaped_movers(text, params) == []
+    # the mixed step's 768 tokens hold 180 MB of temporaries (its 8,704
+    # expert rows alone 53 MB): under a layer of the experts' stack
+    assert compiled.memory_analysis().temp_size_in_bytes < expert_layer

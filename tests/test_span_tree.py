@@ -472,3 +472,81 @@ def test_paged_attention_kernel_is_named_by_kind_of_row():
     assert "paged_attention_decode" in text(1)
     assert "paged_attention_prefill" in text(8)
     assert "paged_attention_prefill" not in text(1)
+
+
+def test_a_model_of_two_page_groups_carries_the_window_groups_names():
+    """What the step of a model with two page groups (PR 31) adds to the
+    tree: on ``fastgen.step`` the window group's pages, tokens and
+    releases and what the decode rows attend in a layer of each kind, each
+    read by a metric file of the benchmark or held for a trace by decision;
+    ``kv.evict_window`` under ``engine.commit`` (a ``kv.*`` span, so
+    ``kv_host_ms_per_step`` counts it); and the window kind's kernel calls
+    under a name of their own that ``^paged_attention`` still matches."""
+    import glob
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    from deepspeed_tpu.ops.paged_attention import paged_attention
+    from test_laguna import engine_of, family, sequences_of
+    cfg, params = family()
+    sched = FastGenScheduler(engine_of(cfg, params))
+    telemetry.enable()
+    for uid, p in enumerate(sequences_of((21, 30), seed=2)):
+        sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=20))
+    sched.run_to_completion()
+    recs = [r for r in get_tracer().records()
+            if not r[0].startswith("engine.program")]
+    carried = {key for r in recs if r[0] == "fastgen.step" and r[5]
+               for key in r[5]}
+    new = {"kv_pages_reserved_window", "kv_tokens_held_window",
+           "kv_pages_released_window", "attn_tokens_full",
+           "attn_tokens_window"}
+    assert new <= carried
+    assert carried - new == {
+        "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
+        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "moe_pairs_here", "moe_expert_load_max", "moe_experts_touched",
+        "moe_tokens"}
+    read = set()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for path in glob.glob(os.path.join(root, "benchmark", "metrics",
+                                       "*.json")):
+        with open(path) as f:
+            args = json.load(f).get("args", {})
+        read |= {v[5:] for v in (args.get("value", ""),
+                                 args.get("of_value", ""))
+                 if v.startswith("attr:")}
+    # the roofline's reader names its two attributes in code
+    with open(os.path.join(root, "benchmark", "readers",
+                           "mixed_attention_roofline.py")) as f:
+        text = f.read()
+    read |= {key for key in ("attn_tokens_full", "attn_tokens_window")
+             if f'"{key}"' in text}
+    # pages given back a step: held in the span ring for whoever reads a
+    # trace (the eviction's pace), by decision no metric
+    assert new - read == {"kv_pages_released_window"}
+    ids = by_id(recs)
+    evicts = [r for r in recs if r[0] == "kv.evict_window"]
+    assert evicts and {ids[r[7]][0] for r in evicts} == {"engine.commit"}
+
+    def text(name, q_rows):
+        q = jnp.zeros((2, q_rows, 4, 16), jnp.float32)
+        kv = jnp.zeros((2, 9, 2, 2, 16, 16), jnp.float32)
+        table = jnp.zeros((2, 4), jnp.int32)
+        pos = jnp.zeros((2,), jnp.int32)
+        return str(jax.make_jaxpr(lambda *a: paged_attention(
+            *a, use_kernel=True, interpret=True, window=32, name=name))(
+            q, kv, jnp.int32(1), table, pos, pos + q_rows))
+
+    assert "paged_attention_window_decode" in text("paged_attention_window", 1)
+    assert "paged_attention_window_prefill" in text("paged_attention_window",
+                                                    8)
+    assert "paged_attention_decode" in text("paged_attention", 1)
+    from deepspeed_tpu.inference.v2.modules import _kernel_name
+    kinds = engine_of(cfg, params).model._kind_cfg
+    assert _kernel_name(kinds["window"]) == "paged_attention_window"
+    assert _kernel_name(kinds["full"]) == "paged_attention"
