@@ -346,6 +346,9 @@ class InferenceEngineV2:
         self._draft_kv = None
         self._draft_seen: Dict[int, int] = {}
         self._attended = (0, 0)
+        #: (previous token vector's length, slots) -> the compiled gather
+        #: of a decode segment's token ids (``_form_gathers``)
+        self._gathers: Dict[Tuple[int, int], object] = {}
         if self._draft_enabled:
             import jax.numpy as jnp
             dkv = jnp.zeros(kv_cfg.cache_shape(self._draft_layers),
@@ -699,6 +702,10 @@ class InferenceEngineV2:
     def _precompile_key(self, key: StepKey) -> None:
         self._model.precompile_step(
             key, self._pool(STEP_KINDS[key.kind].trunk))
+        if key.kind == "mixed" or (key.kind == "sample" and key.Q == 1):
+            # dispatched ahead of the drain, such a step takes its
+            # one-token rows' ids from the step in flight
+            self._form_gathers(key.S)
 
     def _pool(self, trunk: str):
         """The KV operand of a program over ``trunk`` (``STEP_KINDS``):
@@ -1083,6 +1090,54 @@ class InferenceEngineV2:
         vector: its length without the model's ``step_tail``."""
         return int(prev_tokens.shape[0]) - self._model.step_tail
 
+    def _form_gather(self, n_prev: int, S: int) -> None:
+        """Compile the ``[S, 1]`` token ids of a decode segment of ``S``
+        slots from one int32 code a slot: a row of a previous token
+        vector of ``n_prev`` values, or ``-1 - id`` for an id the host
+        holds.  The operand of a program that is no chain program, formed
+        on the device like the chain program's own, in one h2d."""
+        import jax.numpy as jnp
+        mesh = self._model.mesh
+        rep = (jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+               if mesh is not None else None)
+        self._gathers[(n_prev, S)] = jax.jit(
+            lambda tokens, code: jnp.where(
+                code >= 0, jnp.take(tokens, jnp.maximum(code, 0)),
+                -1 - code)[:, None],
+            out_shardings=rep).lower(
+            jax.ShapeDtypeStruct((n_prev,), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((S,), jnp.int32, sharding=rep)).compile()
+
+    def _form_gathers(self, S: int) -> None:
+        """Form the gather of a decode segment of ``S`` slots for EVERY
+        length the previous step's token vector can have: a slot bucket
+        (one segment) or the bucket of two (a mixed step's pad), never
+        under ``S`` (the rows are a subset of that step's), plus the
+        model's ``step_tail``.  A closed set, formed where the first of
+        its programs forms or first runs, so that a warm window stays
+        warm: JAX counts these compiles like any other."""
+        tail, bucket = self._model.step_tail, self._lattice.bucket_s
+        top = bucket(2 * bucket(
+            self._config.state_manager.max_ragged_sequence_count))
+        prev = bucket(S)
+        while prev <= top:
+            if (prev + tail, S) not in self._gathers:
+                self._form_gather(prev + tail, S)
+            prev = bucket(prev + 1)
+
+    def _gather_tokens(self, prev_tokens, gather: np.ndarray,
+                       token_ids: np.ndarray):
+        """``token_ids`` ([S, 1]) with each row that sat in ``gather`` of
+        ``prev_tokens`` taken from there (-1: the row keeps its id), on
+        the device."""
+        shape = (int(prev_tokens.shape[0]), len(gather))
+        if shape not in self._gathers:
+            self._form_gathers(shape[1])
+            if shape not in self._gathers:   # a vector no step returned
+                self._form_gather(*shape)
+        return self._gathers[shape](
+            prev_tokens, np.where(gather >= 0, gather, -1 - token_ids[:, 0]))
+
     # dslint: hot-path
     def _dispatch(self, kind: str, batches, row_params=None, rng=None,
                   row_pos=None, prev=None):
@@ -1095,11 +1150,14 @@ class InferenceEngineV2:
         padding rows are greedy and sample garbage nobody reads.  A
         keyed engine stepped without positions passes no keyed rows on,
         so that the model's guard raises instead of this padding
-        silently pinning every draw to position 0.  ``prev``: a chain
-        step's ``(prev_tokens, gather index padded to the slot
-        bucket)``.  Counts the program and the h2d bytes of what is
-        made here, puts the returned pool(s) back, and returns the
-        program's output (None where the kind has none)."""
+        silently pinning every draw to position 0.  ``prev``:
+        ``(prev_tokens, gather index padded to the slot bucket)`` where
+        the first segment's token ids are rows of the previous step's
+        token vector: operands of a chain program, gathered here ahead
+        of any other (``_gather_tokens``), whose key they leave as it
+        is.  Counts the program and the h2d bytes of what is made here,
+        puts the returned pool(s) back, and returns the program's
+        output (None where the kind has none)."""
         model = self._model
         row = STEP_KINDS[kind]
         sampling, greedy, h2d = None, False, 0
@@ -1133,6 +1191,10 @@ class InferenceEngineV2:
         serving_counters.record_program(h2d_bytes=h2d)
         pool = self._pool(row.trunk)
         with trace_span("engine.dispatch"):
+            if prev is not None and not row.chained:
+                batches[0].token_ids = self._gather_tokens(
+                    *prev, batches[0].token_ids)
+                prev = None
             out = model.run_step(key, pool, batches, sampling, prev)
             out, pool = out if row.output else (None, out)
             self._put_pool(row.trunk, pool)
@@ -1221,7 +1283,8 @@ class InferenceEngineV2:
                     batch_tokens: Sequence[np.ndarray],
                     row_params: Sequence, rng: jax.Array,
                     do_checks: bool = True,
-                    row_pos: Optional[Sequence[int]] = None
+                    row_pos: Optional[Sequence[int]] = None,
+                    prev: Optional[Tuple[jax.Array, Sequence[int]]] = None
                     ) -> Tuple[jax.Array, List[int]]:
         """One compiled program for a mixed SplitFuse step: fused
         forward + on-device sampling.  Returns (device token array
@@ -1233,25 +1296,51 @@ class InferenceEngineV2:
         decode rows never pad to the chunk width (a [S, Qmax] superbucket
         would compute Qmax positions per decode row).  ``row_params`` is
         one SamplingParams per row; rows mid-prefill sample garbage the
-        caller ignores."""
+        caller ignores.
+
+        ``prev = (prev_tokens, row of each input in it)``: a one-token
+        input whose row is not -1 continues a sequence whose token id is
+        that row of the previous step's sampled tokens (``prev_tokens``,
+        possibly still in flight) and is gathered from it ON DEVICE; its
+        ``batch_tokens`` entry is a placeholder, and a longer input's
+        row is not read.  No host sync anywhere on this path: the
+        double-buffered scheduler drains step k's tokens while step k+1
+        executes.  A step of such rows alone runs the chain program, any
+        other the program it would run anyway."""
         descs = self._admit_batch(batch_uids, batch_tokens, do_checks)
         dec_idx = [i for i, t in enumerate(batch_tokens) if len(t) == 1]
         pre_idx = [i for i, t in enumerate(batch_tokens) if len(t) > 1]
 
+        # the one-token inputs' rows in the previous step's vector; such
+        # rows alone, all of that step: the chain program gathers them
+        # itself and their ids never leave the device
+        rows = [prev[1][i] for i in dec_idx] if prev is not None else []
+        chain = bool(rows) and not pre_idx and min(rows) >= 0
+
+        def build(idx):
+            return self._build_batch(
+                [descs[i] for i in idx],
+                [np.asarray(batch_tokens[i]) for i in idx],
+                h2d_tokens=not chain)
+
+        def gathered(batch):
+            # the one-token rows are the first segment: their rows,
+            # padded to the slot bucket (the chain program reads a row)
+            if not rows:
+                return None
+            gather = np.full(batch.num_slots, 0 if chain else -1, np.int32)
+            gather[:len(rows)] = rows
+            return prev[0], gather
+
         if not dec_idx or not pre_idx:       # single-geometry step
-            batch = self._build_batch(
-                descs, [np.asarray(t) for t in batch_tokens])
-            tokens = self._dispatch("sample", [batch], row_params, rng,
-                                    row_pos)
+            batch = build(range(len(batch_uids)))
+            tokens = self._dispatch("chain" if chain else "sample",
+                                    [batch], row_params, rng, row_pos,
+                                    prev=gathered(batch))
             self._commit_batch(descs)
             return tokens, list(range(len(batch_uids)))
 
-        dec = self._build_batch([descs[i] for i in dec_idx],
-                                [np.asarray(batch_tokens[i])
-                                 for i in dec_idx])
-        pre = self._build_batch([descs[i] for i in pre_idx],
-                                [np.asarray(batch_tokens[i])
-                                 for i in pre_idx])
+        dec, pre = build(dec_idx), build(pre_idx)
         # tokens come back [S_d + S_p] in segment order, and the sampling
         # rows go in that order
         order = dec_idx + pre_idx
@@ -1262,7 +1351,8 @@ class InferenceEngineV2:
             row_of_input[i] = dec.num_slots + row
         tokens = self._dispatch(
             "mixed", [dec, pre], [row_params[i] for i in order], rng,
-            None if row_pos is None else [row_pos[i] for i in order])
+            None if row_pos is None else [row_pos[i] for i in order],
+            prev=gathered(dec))
         self._commit_batch(descs)
         return tokens, row_of_input
 
@@ -1273,23 +1363,12 @@ class InferenceEngineV2:
                             rng: jax.Array,
                             row_pos: Optional[Sequence[int]] = None
                             ) -> jax.Array:
-        """Decode-continuation step whose input token ids are gathered ON
-        DEVICE from the previous step's sampled tokens (``prev_tokens``,
-        possibly still in flight): row i continues the sequence that sat
-        in ``gather_idx[i]`` of the previous step's output.  No host
-        sync anywhere on this path — the double-buffered scheduler
-        drains step k's tokens while step k+1 executes."""
-        placeholder_toks = [np.zeros(1, np.int32)] * len(batch_uids)
-        descs = self._admit_batch(batch_uids, placeholder_toks,
-                                  do_checks=False)
-        batch = self._build_batch(descs, placeholder_toks,
-                                  h2d_tokens=False)
-        gather = np.zeros(batch.num_slots, np.int32)
-        gather[:len(batch_uids)] = np.asarray(gather_idx, np.int32)
-        tokens = self._dispatch("chain", [batch], row_params, rng, row_pos,
-                                prev=(prev_tokens, gather))
-        self._commit_batch(descs)
-        return tokens
+        """:meth:`step_sample` over decode rows alone, row i continuing
+        the sequence that sat in ``gather_idx[i]`` of ``prev_tokens``."""
+        return self.step_sample(
+            batch_uids, [np.zeros(1, np.int32)] * len(batch_uids),
+            row_params, rng, do_checks=False, row_pos=row_pos,
+            prev=(prev_tokens, gather_idx))[0]
 
     def step_spec(self, batch_uids: Sequence[int],
                   batch_tokens: Sequence[np.ndarray],

@@ -542,6 +542,12 @@ class RaggedInferenceModel:
         return self._trunk_passes.get(self._last_key, 0)
 
     @property
+    def last_program(self) -> str:
+        """Kind of the newest dispatch's program (the ``fastgen.step``
+        span's ``program``)."""
+        return self._last_key.kind if self._last_key is not None else ""
+
+    @property
     def step_tail(self) -> int:
         """int32 counts a sampled-token vector carries past its rows: a
         model with held experts appends (token-expert pairs that fell to

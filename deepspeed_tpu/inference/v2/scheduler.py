@@ -15,14 +15,29 @@ candidate) rather than re-validating the whole batch through
 Serving-optimization paths (engine config ``serving``, ISSUE 2): with
 ``fused_step + on_device_sampling`` a step dispatches ONE compiled
 program (forward + sampling) and only int32 tokens cross device->host;
-with ``async_scheduling`` on top, steady-state decode double-buffers —
-step k+1 is dispatched through a device-side token gather
-(``step_decode_chained``) while step k's tokens are still in flight, so
-token values reach the host one step late (``step()`` returns the
-PREVIOUS step's tokens).  Requests that hit a stop token are detected at
-drain time; the one optimistically-dispatched extra token is discarded
-and its KV write is harmless (the flushed pages return to the pool and
-every page position is write-before-read for its next owner).
+with ``async_scheduling`` on top the step double-buffers.  THE RULE
+(ISSUE 33; ``_inflight_rows``, ``_plan_step``): whenever every decode
+row of step k+1 has its input token in the step in flight (it sat in a
+sampled row of step k: a decode row, or a prompt whose last piece ran in
+k), step k+1 is planned and dispatched FIRST and step k drains while the
+device runs it, whichever program k+1 runs (``chain``, ``sample``,
+``mixed``): the engine gathers those token ids on the device from the
+in-flight vector (``step_sample(prev=...)``), so token values reach the
+host one step late (``step()`` returns the PREVIOUS step's tokens).  A
+row whose in-flight token is its last by ``max_new_tokens`` is left out
+from host counts; requests that hit a stop token are detected at drain
+time; the one optimistically-dispatched extra token is discarded and its
+KV write is harmless (the flushed pages return to the pool and every
+page position is write-before-read for its next owner).  THE DRAIN COMES
+FIRST, by what the step is and never by a switch, when: nothing is in
+flight or ``async_scheduling`` is off; the speculation gate is open (the
+drafter needs committed tokens); a preempted sequence waits, or a
+running row finds no page (the preemption ladder needs the drain); a
+decode row's token is on the host (restored, handed-off or imported
+sequence, a row the step in flight skipped); the plan finds nothing to
+run; strict shapes would send the step to the split path, whose
+host-side sampling needs logits.  A ``KVAllocationError`` on a dispatch
+ahead of the drain drains, rolls the plan back and degrades.
 
 Speculative decoding (ISSUE 10, ``serving_optimization.speculative``,
 default off): on steady-state decode steps a host-side prompt-lookup
@@ -192,6 +207,30 @@ class _Inflight:
     the (uid, output row, request) triples of its SAMPLED rows."""
     tokens_dev: jax.Array
     rows: List[Tuple[int, int, Request]]
+
+
+#: a decode row's place in ``batch_tokens`` where its token id is a row of
+#: the step in flight (``engine.step_sample``'s ``prev``)
+_TOKEN_IN_FLIGHT = np.zeros(1, np.int32)
+
+
+@dataclasses.dataclass
+class _StepPlan:
+    """What admission put into one step, a row an entry."""
+    uids: List[int] = dataclasses.field(default_factory=list)
+    #: a prompt piece; a decode row's last token, or the placeholder
+    #: where that token is in flight
+    tokens: List[np.ndarray] = dataclasses.field(default_factory=list)
+    reqs: List[Request] = dataclasses.field(default_factory=list)
+    #: a decode row's row in the step in flight (-1: no such row)
+    gather: List[int] = dataclasses.field(default_factory=list)
+    #: (req, chunk) prompt advances this step — rolled back if the
+    #: dispatch fails, so no prompt token is skipped
+    advances: List[Tuple[Request, int]] = dataclasses.field(
+        default_factory=list)
+    #: requests moved pending -> running this step — returned to pending
+    #: on a failed dispatch (their engine sequence may not exist yet)
+    new_admits: List[Request] = dataclasses.field(default_factory=list)
 
 
 class _Admission:
@@ -867,64 +906,34 @@ class FastGenScheduler:
                     self._finish_request(req)
         return out
 
-    # -- double buffer: chained decode dispatch ------------------------------
-    def _plan_chain(self) -> Optional[List[Tuple[int, int, Request]]]:
-        """Rows for a device-chained decode step, or None when this step
-        can't chain (admissions pending, mid-prefill rows, restored or
-        unknown membership, KV pressure) and must take the host path."""
-        if not self._async or self._inflight is None:
-            return None
-        if self._pending or self._preempted:
+    # -- double buffer: which steps are dispatched ahead of the drain -------
+    def _inflight_rows(self) -> Optional[Dict[int, int]]:
+        """uid -> row of the step in flight, where the next step can be
+        planned and dispatched BEFORE that one drains: every decode row's
+        input token is one of that step's sampled rows (a decode row of
+        it, or a prompt whose last piece ran in it), so the host has
+        nothing to wait for.  None where the drain comes first: nothing
+        is in flight, a preempted sequence waits (restoring needs the
+        pool as the drain leaves it), a decode row's token is on the
+        host (a restored, imported or skipped sequence), or strict
+        shapes might send a step with a prompt to the split path, whose
+        host-side sampling needs logits."""
+        if not self._async or self._inflight is None or self._preempted:
             return None
         slot = {uid: row for uid, row, _ in self._inflight.rows}
-        adm = _Admission(self._engine, self._budget)
-        rows = []
-        for uid, req in self._running.items():
-            if req.prefill_remaining > 0:
-                return None
-            if uid not in slot:
-                return None
-            if len(req.generated) + 1 >= req.params.max_new_tokens:
-                # the in-flight token is its last — finishes at drain
-                continue
-            if not adm.try_admit(uid, 1, is_new=False):
-                return None     # host path handles preemption
-            rows.append((uid, slot[uid], req))
-        if not rows:
+        decoding = [uid for uid, req in self._running.items()
+                    if req.prefill_remaining == 0]
+        if not all(uid in slot for uid in decoding):
             return None
-        # strict mode serves only precompiled programs: chain only when
-        # the EXACT key (incl. the previous step's token-array length)
-        # was AOT-lowered; otherwise the host path's lattice-covered
-        # steps take over
-        one = np.zeros(1, np.int32)
-        if not self._strict_key_ok(
-                [u for u, _, _ in rows], [one] * len(rows), "chain",
-                greedy=all(req.params.temperature <= 0.0
-                           for _, _, req in rows),
-                prev_tokens=self._inflight.tokens_dev):
+        if self._strict and (self._pending
+                             or len(decoding) < len(self._running)):
             return None
-        return rows
+        return slot
 
-    # dslint: hot-path
-    def _dispatch_chain(self, rows) -> _Inflight:
-        uids = [u for u, _, _ in rows]
-        gather = [r for _, r, _ in rows]
-        params = [req.params for _, _, req in rows]
-        greedy_only = all(p.temperature <= 0.0 for p in params)
-        # keyed sampling: the chained step samples the token AFTER the
-        # in-flight one (generation index len(generated) + 1 — the
-        # in-flight token, not yet drained, is index len(generated))
-        row_pos = ([len(req.generated) + 1 for _, _, req in rows]
-                   if self._keyed else None)
-        toks = self._engine.step_decode_chained(
-            uids, self._inflight.tokens_dev, gather, params,
-            self._next_key(greedy_only), row_pos=row_pos)
-        self.last_step_scheduled = len(uids)
-        if _telemetry.enabled:
-            self._step_shape = ("chain", len(uids), 0, 0, len(uids))
-        return _Inflight(tokens_dev=toks,
-                         rows=[(u, i, req)
-                               for i, (u, _, req) in enumerate(rows)])
+    @property
+    def _strict(self) -> bool:
+        """The engine serves only precompiled programs."""
+        return getattr(self._engine.model, "strict_shapes", False)
 
     def _strict_key_ok(self, uids, tokens, kind: str = "logits",
                        **fields) -> bool:
@@ -935,7 +944,7 @@ class FastGenScheduler:
         the precompile lattice skips — so membership, not arithmetic, is
         the gate.  ``kind`` and ``fields`` as
         ``engine.predict_step_key`` takes them."""
-        if not getattr(self._engine.model, "strict_shapes", False):
+        if not self._strict:
             return True
         return self._engine.has_program(self._engine.predict_step_key(
             uids, tokens, kind, **fields))
@@ -1474,7 +1483,12 @@ class FastGenScheduler:
                 # weights: 1 for every kind; 2 would be a mixed step
                 # that runs a pass a segment again
                 ("trunk_passes", self._engine.model.last_trunk_passes
-                 if path != "idle" else 0)):
+                 if path != "idle" else 0),
+                # the kind of that program: ``path`` says when the step
+                # was dispatched (``chain``: ahead of the drain), this
+                # what ran it
+                ("program", self._engine.model.last_program
+                 if path != "idle" else "idle")):
             span.set(key, value)
         state = self._engine.state_manager
         if state.window_cache is not None:
@@ -1546,62 +1560,18 @@ class FastGenScheduler:
                                   + alloc.parked_pages))
 
     # dslint: hot-path
-    def _step_impl(self, on_token: Optional[Callable[[int, int], None]]
-                   ) -> Dict[int, int]:
-        serving_counters.record_step()
-        self._preempted_this_step = False
-        self._step_shape = _IDLE_STEP
-        self._expire_requests()
-
-        spec_drained: Optional[Dict[int, int]] = None
-        if self._spec_gate():
-            # speculation needs the committed token stream on the host
-            # (the drafter's n-gram key ends at the LAST token; the
-            # draft trunk's catch-up reads committed history), so the
-            # in-flight chained step drains first; if nothing drafts,
-            # fall through to the normal admission path with the drain
-            # already done (the chain plan needs an in-flight step)
-            spec_drained = self._drain(on_token)
-            plan = self._plan_spec()
-            if plan is not None:
-                mode, rows = plan
-                if mode == "fill":
-                    # token-less draft-KV catch-up: model drafting
-                    # resumes once the trunk reaches the frontier
-                    self._dispatch_draft_fill(rows)
-                    return spec_drained
-                try:
-                    out = (self._dispatch_draft_spec(rows, on_token)
-                           if mode == "model"
-                           else self._dispatch_spec(rows, on_token))
-                except KVAllocationError as e:
-                    self._degrade_oom(e, [], [])
-                    return spec_drained
-                self._oom_streak = 0
-                spec_drained.update(out)
-                return spec_drained
-
-        chain = self._plan_chain() if spec_drained is None else None
-        if chain is not None:
-            # dispatch k+1 FIRST, then drain k: the host sync below
-            # overlaps the device executing the new step
-            try:
-                with trace_span("fastgen.dispatch.chain"):
-                    new_inflight = self._dispatch_chain(chain)
-            except KVAllocationError as e:
-                # degraded step: drain what's in flight, run the
-                # ladder, retry through the host path next step
-                out = self._drain(on_token)
-                self._degrade_oom(e, [], [])
-                return out
-            self._oom_streak = 0
-            out = self._drain(on_token)
-            self._inflight = new_inflight
-            return out
-
-        out_prev = (spec_drained if spec_drained is not None
-                    else self._drain(on_token))
-
+    def _plan_step(self, slot: Optional[Dict[int, int]]
+                   ) -> Optional[_StepPlan]:
+        """Admission, the one plan of a step: every running decode (one
+        token each), then partial prefills, then pending requests,
+        chunked to the budget.  ``slot`` None: the step in flight has
+        drained and a decode row's input token is its last on the host.
+        Else (``_inflight_rows``) the plan runs AHEAD of the drain: a
+        decode row's token is row ``slot[uid]`` of the step in flight
+        and the row is left out where that token is its last by count;
+        None comes back, with nothing changed, where a running row finds
+        no page (the preemption ladder needs the drain) or strict
+        shapes hold no chain program for the rows."""
         with trace_span("fastgen.admission"):
             # resume preempted sequences first when the pool has room
             # again (restore cost = their live page count, plus decode
@@ -1623,16 +1593,8 @@ class FastGenScheduler:
                     self._running[uid] = self._preempted.pop(uid)
 
             adm = _Admission(self._engine, self._budget)
-            uids: List[int] = []
-            tokens: List[np.ndarray] = []
-            reqs: List[Request] = []
-            #: (req, chunk) prompt advances this step — rolled back if
-            #: the dispatch below fails, so no prompt token is skipped
-            advances: List[Tuple[Request, int]] = []
-            #: requests moved pending -> running this step — returned
-            #: to pending on a failed dispatch (their engine sequence
-            #: may not exist yet)
-            new_admits: List[Request] = []
+            plan = _StepPlan()
+            uids, tokens, reqs = plan.uids, plan.tokens, plan.reqs
             _faults = get_fault_injector()
 
             # 1. all running decodes (one token each).  Per-request
@@ -1642,22 +1604,42 @@ class FastGenScheduler:
             for uid, req in list(self._running.items()):
                 if req.prefill_remaining > 0:
                     continue  # mid-prefill requests handled below
+                if slot is not None and (len(req.generated) + 1
+                                         >= req.params.max_new_tokens):
+                    continue  # the in-flight token is its last
                 try:
                     if _faults.armed and \
                             _faults.fire("fastgen.poison_request"):
                         raise PoisonedRequestFault(
                             f"injected poisoned request {uid}")
                     if not adm.try_admit(uid, 1, is_new=False):
+                        if slot is not None:
+                            return None
                         continue
                 except Exception as e:
                     self._fail_request(req, "poisoned",
                                        f"{type(e).__name__}: {e}")
                     continue
-                last = (req.generated[-1] if req.generated
-                        else int(req.prompt[-1]))
+                if slot is None:
+                    last = (req.generated[-1] if req.generated
+                            else int(req.prompt[-1]))
+                    tokens.append(np.array([last], dtype=np.int32))
+                    plan.gather.append(-1)
+                else:
+                    tokens.append(_TOKEN_IN_FLIGHT)
+                    plan.gather.append(slot[uid])
                 uids.append(uid)
-                tokens.append(np.array([last], dtype=np.int32))
                 reqs.append(req)
+            if slot is not None and uids and self._strict \
+                    and not self._strict_key_ok(
+                        uids, tokens, "chain",
+                        greedy=all(r.params.temperature <= 0.0
+                                   for r in reqs),
+                        prev_tokens=self._inflight.tokens_dev):
+                # strict mode serves only precompiled programs: chain
+                # only when the EXACT key (incl. the previous step's
+                # token-array length) was AOT-lowered
+                return None
 
             # 2. continue partial prefills, then admit pending, chunked
             # to budget
@@ -1695,9 +1677,10 @@ class FastGenScheduler:
                 piece = req.prompt[req.prompt_sent:req.prompt_sent + chunk]
                 uids.append(req.uid)
                 tokens.append(piece.astype(np.int32))
+                plan.gather.append(-1)
                 reqs.append(req)
                 req.prompt_sent += chunk
-                advances.append((req, chunk))
+                plan.advances.append((req, chunk))
                 serving_counters.record_prefill(chunk)
                 if req.admit_s == 0.0:
                     # first scheduled admission: close the queue-wait
@@ -1732,7 +1715,59 @@ class FastGenScheduler:
                     break
                 self._pending.pop(0)
                 self._running[req.uid] = req
-                new_admits.append(req)
+                plan.new_admits.append(req)
+        return plan
+
+    # dslint: hot-path
+    def _step_impl(self, on_token: Optional[Callable[[int, int], None]]
+                   ) -> Dict[int, int]:
+        serving_counters.record_step()
+        self._preempted_this_step = False
+        self._step_shape = _IDLE_STEP
+        self._expire_requests()
+
+        spec_drained: Optional[Dict[int, int]] = None
+        if self._spec_gate():
+            # speculation needs the committed token stream on the host
+            # (the drafter's n-gram key ends at the LAST token; the
+            # draft trunk's catch-up reads committed history), so the
+            # in-flight chained step drains first; if nothing drafts,
+            # fall through to the normal admission path with the drain
+            # already done (the chain plan needs an in-flight step)
+            spec_drained = self._drain(on_token)
+            plan = self._plan_spec()
+            if plan is not None:
+                mode, rows = plan
+                if mode == "fill":
+                    # token-less draft-KV catch-up: model drafting
+                    # resumes once the trunk reaches the frontier
+                    self._dispatch_draft_fill(rows)
+                    return spec_drained
+                try:
+                    out = (self._dispatch_draft_spec(rows, on_token)
+                           if mode == "model"
+                           else self._dispatch_spec(rows, on_token))
+                except KVAllocationError as e:
+                    self._degrade_oom(e, [], [])
+                    return spec_drained
+                self._oom_streak = 0
+                spec_drained.update(out)
+                return spec_drained
+
+        # THE double buffer: where every decode row's token is in the
+        # step in flight, step k+1 is planned and dispatched FIRST and
+        # step k drains while the device runs it; the plan falls back to
+        # the drain where it finds nothing to run or no page for a row
+        slot = self._inflight_rows() if spec_drained is None else None
+        plan = self._plan_step(slot) if slot is not None else None
+        ahead = plan is not None and bool(plan.uids)
+        out_prev = spec_drained     # step k's tokens, once it drained
+        if not ahead:
+            if out_prev is None:
+                out_prev = self._drain(on_token)
+            plan = self._plan_step(None)
+        uids, tokens, reqs = plan.uids, plan.tokens, plan.reqs
+        advances, new_admits = plan.advances, plan.new_admits
 
         self.last_step_scheduled = len(uids)
         if not uids:
@@ -1751,23 +1786,23 @@ class FastGenScheduler:
         # even single-geometry superbuckets can fall outside it (slot/Q
         # bucket rounding past max_ragged_batch_size) — gate the fused
         # dispatch on predicted-key membership and drop to the seed
-        # split path otherwise.
-        strict = getattr(self._engine.model, "strict_shapes", False)
+        # split path otherwise.  (A step planned ahead of the drain is
+        # past this gate: ``_inflight_rows``, ``_plan_step``.)
+        strict = self._strict
         strict_mixed = (strict and any(len(t) == 1 for t in tokens)
                         and any(len(t) > 1 for t in tokens))
-        greedy_only = all(
-            (reqs[i].params.temperature <= 0.0
-             if reqs[i].prefill_remaining == 0 else True)
-            for i in range(len(reqs)))
-        use_fused = self._fused and not strict_mixed
-        if use_fused and strict and not self._strict_key_ok(
-                uids, tokens, "sample", greedy=greedy_only):
-            use_fused = False
+        greedy_only = all(reqs[i].params.temperature <= 0.0
+                          for i in sampled_rows)
+        use_fused = ahead or (
+            self._fused and not strict_mixed
+            and (not strict or self._strict_key_ok(
+                uids, tokens, "sample", greedy=greedy_only)))
         if _telemetry.enabled:
             # every prompt piece of the step is one entry of ``advances``
             prefill_tokens = sum(chunk for _, chunk in advances)
             self._step_shape = (
-                "fused" if use_fused else "split", len(uids),
+                "chain" if ahead else "fused" if use_fused else "split",
+                len(uids),
                 len(advances), prefill_tokens,
                 len(uids) - len(advances) + prefill_tokens)
 
@@ -1781,23 +1816,37 @@ class FastGenScheduler:
             row_params = [r.params if r.prefill_remaining == 0
                           else SamplingParams() for r in reqs]
             # keyed: a sampled row emits generation index
-            # len(generated) (mid-prefill rows' draws are ignored)
-            row_pos = ([len(r.generated) for r in reqs]
+            # len(generated), one more where its input token is still
+            # in flight and not yet counted (mid-prefill rows' draws
+            # are ignored)
+            row_pos = ([len(r.generated) + (g >= 0)
+                        for r, g in zip(reqs, plan.gather)]
                        if self._keyed else None)
             try:
-                with trace_span("fastgen.dispatch.fused"):
+                with trace_span("fastgen.dispatch.chain" if ahead
+                                else "fastgen.dispatch.fused"):
                     toks, rowmap = self._engine.step_sample(
                         uids, tokens, row_params,
                         self._next_key(greedy_only), do_checks=False,
-                        row_pos=row_pos)
+                        row_pos=row_pos,
+                        prev=((self._inflight.tokens_dev, plan.gather)
+                              if ahead else None))
             except KVAllocationError as e:
+                # degraded step: drain what's in flight, run the
+                # ladder, retry next step
+                if ahead:
+                    out_prev = self._drain(on_token)
                 self._degrade_oom(e, advances, new_admits)
                 return out_prev
             self._oom_streak = 0
-            self._inflight = _Inflight(
+            inflight = _Inflight(
                 tokens_dev=toks,
                 rows=[(uids[i], rowmap[i], reqs[i])
                       for i in sampled_rows])
+            if ahead:
+                # the host sync overlaps the device executing the new step
+                out_prev = self._drain(on_token)
+            self._inflight = inflight
             if not self._async:
                 out_prev.update(self._drain(on_token))
             return out_prev

@@ -151,7 +151,6 @@ class TestSpanIds:
 # the serving step's tree on the tiny model
 # ---------------------------------------------------------------------------
 
-STEP_CHILDREN = {"fastgen.drain", "fastgen.admission"}
 DISPATCH_CHILDREN = ["engine.admit", "engine.build_batch",
                      "engine.dispatch", "engine.commit"]
 
@@ -183,52 +182,74 @@ def serve(n_req=3, prompt=12, new=5, before_enable=0):
 
 class TestStepTree:
     def test_a_host_path_step_has_exactly_the_tree(self):
-        # two requests are decoding when two more arrive: the step
-        # drains the step in flight, admits, and dispatches one mixed
-        # program (one batch segment per kind of row)
+        # two requests are decoding, their tokens in the step in flight,
+        # when two more arrive: the step admits, dispatches one mixed
+        # program (one batch segment per kind of row) AHEAD of the drain
+        # (PR 33: ``path=chain`` means "dispatched ahead of the drain",
+        # whatever program ran; ``program`` names that), then drains
         _, recs = serve(n_req=4, prompt=20, new=4, before_enable=2)
         steps = [r for r in recs if r[0] == "fastgen.step"]
-        fused = next(r for r in steps if r[5]["path"] == "fused")
-        kids = children_of(recs, fused[6])
-        names = [k[0] for k in kids]
-        assert set(names) == STEP_CHILDREN | {"fastgen.dispatch.fused"}
-        assert len(names) == 3
-        dispatch = next(k for k in kids if k[0] == "fastgen.dispatch.fused")
+        mixed = next(r for r in steps if r[5]["prefill_rows"])
+        assert (mixed[5]["path"], mixed[5]["program"]) == ("chain", "mixed")
+        kids = sorted(children_of(recs, mixed[6]), key=lambda r: r[1])
+        assert [k[0] for k in kids] == ["fastgen.admission",
+                                        "fastgen.dispatch.chain",
+                                        "fastgen.drain"]
+        adm, dispatch, drain = kids
         under = [k[0] for k in sorted(children_of(recs, dispatch[6]),
                                       key=lambda r: r[1])]
         assert under == ["engine.admit", "engine.build_batch",
                          "engine.build_batch", "engine.dispatch",
                          "engine.commit"]
-        drain = next(k for k in kids if k[0] == "fastgen.drain")
         assert [k[0] for k in sorted(children_of(recs, drain[6]),
                                      key=lambda r: r[1])] == [
             "fastgen.drain.wait", "fastgen.drain.deliver"]
-        adm = next(k for k in kids if k[0] == "fastgen.admission")
         assert {k[0] for k in children_of(recs, adm[6])} == {
             "fastgen.prefix_match"}
         # the call of the program is a leaf: nothing forms on a warm key
+        # (the token gather of the decode segment is a warm call too)
         call = next(k for k in children_of(recs, dispatch[6])
                     if k[0] == "engine.dispatch")
         assert call[5] is None and children_of(recs, call[6]) == []
 
+    def test_a_step_with_nothing_in_flight_keeps_the_fused_path(self):
+        # the first step has no step to run ahead of: ``path=fused``,
+        # and no drain under it
+        _, recs = serve()
+        first = next(r for r in recs if r[0] == "fastgen.step")
+        assert (first[5]["path"], first[5]["program"]) == ("fused",
+                                                           "sample")
+        kids = sorted(children_of(recs, first[6]), key=lambda r: r[1])
+        assert [k[0] for k in kids] == ["fastgen.admission",
+                                        "fastgen.dispatch.fused"]
+        assert [k[0] for k in sorted(children_of(recs, kids[1][6]),
+                                     key=lambda r: r[1])] \
+            == DISPATCH_CHILDREN
+
     def test_a_chained_step_dispatches_then_drains(self):
         _, recs = serve()
         chain = next(r for r in recs if r[0] == "fastgen.step"
-                     and r[5]["path"] == "chain")
+                     and r[5]["program"] == "chain")
+        assert chain[5]["path"] == "chain"
+        # the one plan of a step runs here too, and finds no prompt
         kids = sorted(children_of(recs, chain[6]), key=lambda r: r[1])
-        assert [k[0] for k in kids] == ["fastgen.dispatch.chain",
+        assert [k[0] for k in kids] == ["fastgen.admission",
+                                        "fastgen.dispatch.chain",
                                         "fastgen.drain"]
-        assert [k[0] for k in sorted(children_of(recs, kids[0][6]),
+        assert children_of(recs, kids[0][6]) == []
+        assert [k[0] for k in sorted(children_of(recs, kids[1][6]),
                                      key=lambda r: r[1])] \
             == DISPATCH_CHILDREN
-        assert [k[0] for k in sorted(children_of(recs, kids[1][6]),
+        assert [k[0] for k in sorted(children_of(recs, kids[2][6]),
                                      key=lambda r: r[1])] \
             == ["fastgen.drain.wait", "fastgen.drain.deliver"]
 
     def test_step_counts_add_up(self):
         sched, recs = serve(n_req=4, prompt=20, new=4, before_enable=2)
         steps = [r[5] for r in recs if r[0] == "fastgen.step"]
-        assert {s["path"] for s in steps} >= {"fused", "chain", "idle"}
+        # every step here has its decode rows' tokens in flight
+        assert {s["path"] for s in steps} == {"chain", "idle"}
+        assert {s["program"] for s in steps} == {"mixed", "chain", "idle"}
         for s in steps:
             # a row decodes one token or carries a prompt piece
             assert s["tokens"] == (s["rows"] - s["prefill_rows"]
@@ -243,7 +264,7 @@ class TestStepTree:
         idle = next(s for s in steps if s["path"] == "idle")
         assert idle["rows"] == idle["tokens"] == 0
         # every program streams its weights once, the mixed step's too
-        assert mixed["path"] == "fused" and idle["trunk_passes"] == 0
+        assert mixed["program"] == "mixed" and idle["trunk_passes"] == 0
         assert {s["trunk_passes"] for s in steps
                 if s["path"] != "idle"} == {1}
         # last_step_scheduled keeps its meaning: sequences, not tokens
@@ -282,10 +303,15 @@ def test_every_attribute_on_the_serving_path_has_a_metric_that_reads_it():
     carried = {key for r in recs if r[5] for key in r[5]}
     assert carried == {"path", "rows", "prefill_rows", "prefill_tokens",
                        "tokens", "budget", "kv_pages_reserved",
-                       "kv_tokens_held", "new_tokens", "trunk_passes"}
+                       "kv_tokens_held", "new_tokens", "trunk_passes",
+                       "program"}
     # the engagement counter of the one-pass mixed step (PR 30): held in
     # the span ring for whoever reads a trace, by decision no metric
     carried.remove("trunk_passes")
+    # which program ran the step (PR 33): ``path=chain`` is "dispatched
+    # ahead of the drain" whatever ran it, and ``chained_step_share``
+    # reads that; the kind is held beside it, by decision no metric
+    carried.remove("program")
     read = set()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for path in glob.glob(os.path.join(root, "benchmark", "metrics",
@@ -509,8 +535,8 @@ def test_a_model_of_two_page_groups_carries_the_window_groups_names():
     assert carried - new == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
         "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
-        "moe_pairs_here", "moe_expert_load_max", "moe_experts_touched",
-        "moe_tokens"}
+        "program", "moe_pairs_here", "moe_expert_load_max",
+        "moe_experts_touched", "moe_tokens"}
     read = set()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for path in glob.glob(os.path.join(root, "benchmark", "metrics",

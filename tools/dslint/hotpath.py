@@ -38,7 +38,7 @@ register_rules("hot-path-sync", "hot-path-d2h-shape", "hot-path-missing")
 #: (file, function-name regex): every match must be hot-path annotated
 REQUIRED_HOT_PATHS: Tuple[Tuple[str, str], ...] = (
     ("deepspeed_tpu/inference/v2/scheduler.py",
-     r"^(_drain_impl|_step_impl|_dispatch_chain|_dispatch_spec"
+     r"^(_drain_impl|_step_impl|_plan_step|_dispatch_spec"
      r"|_dispatch_draft_spec)$"),
     ("deepspeed_tpu/inference/v2/model.py",
      r"^(_\w*step_impl|_assemble_logits)$"),
