@@ -161,6 +161,38 @@ class InferenceEngineV2:
                 raise ValueError(
                     "model-drafted speculation is not built for a model "
                     "of two attention kinds: use spec_drafter='ngram'")
+        if model.state_config is not None:
+            # a model with a state pool (sequence state that is not
+            # pages): what is not built for it raises here, before
+            # anything is sized.  The prefix cache is built off
+            # (StateManager), as for a window page group
+            sv_ = self._config.serving
+            if max(tp, model.tp_degree) > 1:
+                raise ValueError(
+                    "inference/v2/engine.py: a state pool cannot be served "
+                    "under tp_degree > 1 yet (ops/ssm.py's pool and its "
+                    "kernels are not sharded over d_inner) — use "
+                    "tp_degree=1")
+            if (getattr(sv_, "kv_quantization", "none") or "none") != "none":
+                raise ValueError(
+                    "inference/v2/engine.py: a model with a state pool has "
+                    "no int8 page format yet (ragged/kv_cache.py: its "
+                    "attention layers' pool is carried beside the state "
+                    "pool unquantized): kv_quantization must be 'none'")
+            if int(getattr(sv_, "kv_tier_host_pages", 0) or 0) \
+                    or int(getattr(sv_, "kv_tier_disk_pages", 0) or 0):
+                raise ValueError(
+                    "inference/v2/engine.py: KV tiers (ragged/kv_tiers.py) "
+                    "hold prefix pages by digest, and a model with a state "
+                    "pool has no prefix index: kv_tier_host_pages and "
+                    "kv_tier_disk_pages must be 0")
+            if getattr(sv_, "speculative", False):
+                raise ValueError(
+                    "inference/v2/engine.py: speculation (inference/v2/"
+                    "spec.py, n-gram or model-drafted) is not built for a "
+                    "model with a state pool: a rejected draft would need "
+                    "the recurrent state rolled back — use "
+                    "speculative=False")
         if tp > 1 and model.mesh is None:
             devs = jax.devices()
             if len(devs) < tp:
@@ -267,6 +299,12 @@ class InferenceEngineV2:
             if quant != kv_cfg.quantization:
                 kv_cfg = dataclasses.replace(kv_cfg, quantization=quant)
                 model.kv_config = kv_cfg
+        if model.state_config is not None:
+            # one slot a tracked sequence: the slots, not the pages, are
+            # what bounds the batch of such a model
+            model.state_config = dataclasses.replace(
+                model.state_config, num_slots=int(
+                    self._config.state_manager.max_tracked_sequences))
         if kv_cfg.quantization != prev_quant:
             # the kv leaf's pytree TYPE changed (ndarray <-> KVPages):
             # programs traced for the old encoding cannot be called
@@ -332,7 +370,8 @@ class InferenceEngineV2:
             tier_dir=getattr(sv, "kv_tier_dir", None),
             window_kv_config=model.window_kv_config,
             window=(model.cfg.sliding_window
-                    if model.window_kv_config is not None else 0))
+                    if model.window_kv_config is not None else 0),
+            state_config=model.state_config)
         # draft KV pool (ISSUE 17): a parallel plain-dtype page array
         # addressed by the TARGET's page ids/page tables — allocation,
         # commit and rollback all ride the existing allocator (the
@@ -343,6 +382,9 @@ class InferenceEngineV2:
         # never prefix-indexed (index_prefix only sees the target
         # pool), so a shared prefix page can hold stale draft KV —
         # that degrades accept rate until catch-up, never correctness.
+        #: the wide table's layout (ragged/cache_kinds.py); None for a
+        #: model of one page group, whose table is the [S, P] it was
+        self._table = model.table if model.table.extra(1) else None
         self._draft_kv = None
         self._draft_seen: Dict[int, int] = {}
         self._attended = (0, 0)
@@ -515,6 +557,10 @@ class InferenceEngineV2:
             if self._model.window_kv_config is not None else 0)
         led.register_object("kv_pages", self._state,
                             lambda st, b=kv_bytes: b)
+        state_bytes = (self._model.state_config.total_bytes()
+                       if self._model.state_config is not None else 0)
+        led.register_object("state_pool", self._state,
+                            lambda st, b=state_bytes: b)
         draft_bytes = (int(self._draft_kv.nbytes)
                        if self._draft_kv is not None else 0)
         led.register_object("draft_kv", self,
@@ -714,6 +760,9 @@ class InferenceEngineV2:
         if self._state.window_cache is not None:
             # two page groups: the pair (full group, window group)
             kv = (kv, self._state.window_cache.data)
+        if self._state.state_pool is not None:
+            # pages and the state pool's two arrays
+            kv = (kv, *self._state.state_pool.data)
         if trunk == "target":
             return kv
         if self._draft_kv is None:
@@ -726,7 +775,10 @@ class InferenceEngineV2:
     def _put_pool(self, trunk: str, pool) -> None:
         """Put back what a program over ``trunk`` returned for the
         pool(s) it was given, which it donated."""
-        if trunk == "target" and self._state.window_cache is not None:
+        if trunk == "target" and self._state.state_pool is not None:
+            self._state.kv_cache.data, *state = pool
+            self._state.state_pool.data = tuple(state)
+        elif trunk == "target" and self._state.window_cache is not None:
             self._state.kv_cache.data, self._state.window_cache.data = pool
         elif trunk == "target":
             self._state.kv_cache.data = pool
@@ -930,6 +982,18 @@ class InferenceEngineV2:
             self._state.get_sequence(uid) or placeholder(), n_tokens)
 
     @property
+    def free_state_slots(self) -> int:
+        """Free slots of the state pool (0 for a model without one)."""
+        return self._state.free_state_slots
+
+    def state_slots_needed(self, uid: int) -> int:
+        """Slots of the state pool a step of ``uid`` has to reserve (0
+        for a model without one, and for a sequence that holds its
+        slot): what admission holds against :attr:`free_state_slots`."""
+        return self._state.state_slots_needed(
+            self._state.get_sequence(uid) or placeholder())
+
+    @property
     def model(self) -> RaggedInferenceModel:
         return self._model
 
@@ -974,6 +1038,7 @@ class InferenceEngineV2:
         cur_seqs = self._state.n_tracked_sequences
         free = self._state.free_pages
         free_w = self._state.free_window_pages
+        free_s = self._state.free_state_slots
         batch_tokens = 0
         for uid, length in zip(uids, lengths):
             sd = self._state.get_sequence(uid)
@@ -983,7 +1048,8 @@ class InferenceEngineV2:
             tokens, pages = self._model.get_kv_requirements(
                 sd.seen_tokens, sd.allocated_capacity, length, free)
             free_w -= self._state.window_pages_needed(sd, length)
-            if tokens != length or free_w < 0:
+            free_s -= self._state.state_slots_needed(sd)
+            if tokens != length or free_w < 0 or free_s < 0:
                 return SchedulingResult.KVCacheLimitExceeded
             batch_tokens += length
             free -= pages
@@ -1060,9 +1126,9 @@ class InferenceEngineV2:
                 self._lattice,
                 fresh_supported=self._model.has_fresh, min_q=min_q,
                 start_pos=start_pos,
-                window_slots=(self._model.window_slots
-                              if self._state.window_cache is not None
-                              else None))
+                table=self._table,
+                scratch_slot=(self._state.state_pool.scratch
+                              if self._state.state_pool is not None else 0))
             if self._state.window_cache is not None and batch.max_q == 1:
                 # what the decode rows attend in a layer of each kind
                 # (the scheduler's live span carries it: take_attended)

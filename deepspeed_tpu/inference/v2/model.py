@@ -19,6 +19,7 @@ the KV cache is donated so decoding is allocation-free on device.
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
 import threading
@@ -42,8 +43,10 @@ from ...telemetry.workload_trace import get_workload_trace
 from ...utils.compile_cache import thread_cache_counts
 from .lattice import POWER_LATTICE
 from .ragged import KVCacheConfig, RaggedBatch
+from .ragged.cache_kinds import CACHE_KINDS, TableLayout
+from .ragged.kv_cache import StatePoolConfig
 from .step_key import (STEP_KINDS, StepKey, step_avals, step_program,
-                       trunk_params, window_slots)
+                       trunk_params)
 
 
 def serving_peak_flops() -> Optional[float]:
@@ -222,15 +225,27 @@ class RaggedInferenceModel:
         #: full layers' K/V in ``kv_config``'s pool, its window layers' in
         #: this one, ``_forward_hidden_kinds``); None: one page group
         self.window_kv_config: Optional[KVCacheConfig] = None
-        if cfg.layer_kinds:
-            import dataclasses
-
-            from ...models.laguna import group_layers
-            layers, heads = group_layers(cfg), dict(cfg.heads_by_kind)
-            assert layers["full"] and layers["window"], \
-                "layer_kinds names both kinds, or the model has one group"
+        #: the state pool (a model with state-space layers: a slot a
+        #: sequence instead of pages, ``ops/ssm.py``); None: every layer
+        #: kind caches pages.  The engine sizes ``num_slots``
+        self.state_config: Optional[StatePoolConfig] = None
+        import dataclasses
+        # layers by what their kind caches (cache_kinds.py): a page
+        # group's name, or "slot"
+        layers = collections.Counter(
+            "slot" if CACHE_KINDS[kind].slot else CACHE_KINDS[kind].group
+            for kind in cfg.layer_kinds)
+        if layers:
+            assert layers["full"], "a model of kinds has full layers"
             self.kv_config = dataclasses.replace(
                 self.kv_config, num_layers=layers["full"])
+        if layers["slot"]:
+            self.state_config = StatePoolConfig(
+                num_layers=layers["slot"], d_state=cfg.ssm_state_dim,
+                d_inner=cfg.ssm_inner, d_conv=cfg.ssm_conv,
+                state_dtype=cfg.ssm_state_dtype, conv_dtype=cfg.dtype)
+        if layers["window"]:
+            heads = dict(cfg.heads_by_kind)
             self.window_kv_config = window_kv_config or dataclasses.replace(
                 self.kv_config, num_layers=layers["window"])
             # each kind's attention modules, over its own head count,
@@ -338,7 +353,8 @@ class RaggedInferenceModel:
         if self.cfg.latent_dim or self.cfg.layer_kinds:
             raise ValueError(
                 "weight-only quantization does not cover the latent-"
-                "attention / held-experts / two-kind blocks yet")
+                "attention / held-experts / two-kind / state-space "
+                "blocks yet")
         prior = getattr(self, "_quantized_fmt", None)
         if prior is not None:
             if prior != fmt:
@@ -527,13 +543,18 @@ class RaggedInferenceModel:
         return step(trunk_params(self, STEP_KINDS[key.kind].trunk), kv,
                     *operands)
 
+    @property
+    def table(self) -> TableLayout:
+        """The columns of this model's segment tables, from what its
+        layer kinds cache (``ragged/cache_kinds.py``)."""
+        return TableLayout.of(self.cfg.layer_kinds or ("full",),
+                              self.cfg.sliding_window,
+                              self.kv_config.page_size)
+
     def window_slots(self, Q: int) -> int:
         """Slots of the window group's table in a segment of ``Q`` tokens
         a row (``step_key.window_slots``); 0 for a model of one group."""
-        if self.window_kv_config is None:
-            return 0
-        return window_slots(self.cfg.sliding_window,
-                            self.kv_config.page_size, Q)
+        return self.table.window_slots(Q)
 
     @property
     def last_trunk_passes(self) -> int:
@@ -872,6 +893,8 @@ class RaggedInferenceModel:
         if cfg.latent_dim:
             return self._forward_hidden_latent(params, kv, segments, cfg,
                                                stats_out)
+        if cfg.ssm_state_dim:
+            return self._forward_hidden_state(params, kv, segments, cfg)
         if cfg.layer_kinds:
             return self._forward_hidden_kinds(params, kv, segments, cfg,
                                               stats_out)
@@ -1330,14 +1353,12 @@ class RaggedInferenceModel:
         write and attention use positions only to find a token's slot
         and to mask, which depend on differences of positions alone (the
         rope is applied before, from the absolute positions)."""
-        W = self.window_slots(seg.token_ids.shape[1])
-        P = seg.page_table.shape[1] - W - 1
-        base = seg.page_table[:, -1]
-        return {"full": seg._replace(page_table=seg.page_table[:, :P]),
+        parts = self.table.split(seg.page_table, seg.token_ids.shape[1])
+        return {"full": seg._replace(page_table=parts["full"]),
                 "window": seg._replace(
-                    page_table=seg.page_table[:, P:P + W],
+                    page_table=parts["window"],
                     start_pos=seg.start_pos
-                    - base * self.kv_config.page_size)}
+                    - parts["base"] * self.kv_config.page_size)}
 
     def _forward_hidden_kinds(self, params, kv, segments, cfg, stats_out):
         """The trunk of a model whose layers are of two attention kinds
@@ -1466,6 +1487,172 @@ class RaggedInferenceModel:
         x = x + out.astype(x.dtype)
         return ((x, pool, window, stats) if kind == "full"
                 else (x, full, pool, stats))
+
+    def _forward_hidden_state(self, params, kv, segments, cfg):
+        """The trunk of a model with state-space layers beside attention
+        layers (a third layer kind): ONE scan over the whole periods of
+        the layer pattern whose body is the period's RUNS of like layers,
+        each run of several a scan of its own (7 Mamba, the attention
+        layer, 6 Mamba: a program holds two Mamba bodies and one
+        attention body, not fourteen), then the tail; still one pass of
+        the weights over all tokens.  Every scan runs over a COUNTER and
+        takes its layer out of the kind's one flat stack by index, as a
+        scan takes its operand's: a stack of periods scanned as an
+        operand would have each period's run sliced out whole.  ``kv`` is
+        (the attention layers' page pool, the state pool's ``h``, its
+        ``conv``): the carry holds all three beside the activations, and
+        a layer writes and reads its own kind's pool at its index among
+        the layers of its kind.
+        A row's slot rides the table's last column."""
+        layer_runs = T.layer_runs
+        x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
+                        _end_to_end([seg.token_ids for seg in segments]))
+        scratch = kv[1].shape[1] - 1
+        paged, rows = [], []
+        for seg in segments:
+            Q = seg.token_ids.shape[1]
+            parts = self.table.split(seg.page_table, Q)
+            paged.append(seg._replace(page_table=parts["full"]))
+            # (slot, starts from zeros, which of its Q positions are true)
+            rows.append((jnp.clip(parts["slot"], 0, scratch),
+                         seg.start_pos == 0,
+                         jnp.arange(Q, dtype=jnp.int32)[None, :]
+                         < seg.q_lens[:, None]))
+        body = functools.partial(self._layer_body_state, cfg=cfg,
+                                 segments=paged, rows=rows)
+        runs, periods, tail = layer_runs(cfg)
+        per = {kind: sum(n for k, n in runs if k == kind)
+               for kind in ("full", "ssm")}
+        carry = (x, *kv)
+
+        stacks = params["layers"]
+
+        def layer(carry, kind, at):
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, at, 0, keepdims=False), stacks[kind])
+            return body(carry, lp, kind=kind, at=at)
+
+        def one_period(carry, p):
+            met = dict.fromkeys(per, 0)
+            for kind, n in runs:
+                first = p * per[kind] + met[kind]
+                if n == 1:
+                    carry = layer(carry, kind, first)
+                else:
+                    carry, _ = jax.lax.scan(
+                        lambda c, m, kind=kind, first=first: (
+                            layer(c, kind, first + m), None),
+                        carry, jnp.arange(n, dtype=jnp.int32))
+                met[kind] += n
+            return carry, None
+
+        if periods:
+            carry, _ = jax.lax.scan(
+                one_period, carry, jnp.arange(periods, dtype=jnp.int32))
+        at = {kind: periods * n for kind, n in per.items()}
+        for m in range(tail):
+            kind = cfg.layer_kinds[cfg.num_layers - tail + m]
+            carry = layer(carry, kind, jnp.int32(at[kind]))
+            at[kind] += 1
+        x, *kv = carry
+        return self._norm(params["final_norm"], x), tuple(kv)
+
+    def _layer_body_state(self, carry, lp, *, kind, at, segments, rows,
+                          cfg):
+        """One pre-norm layer of kind ``kind`` over (x, the page pool, the
+        state pool's two arrays): the kind's mixer at layer ``at`` of its
+        own pool, then the llama block's SwiGLU."""
+        x, pages, h_pool, conv_pool = carry
+        h = self._norm(lp["norm1"], x)
+        if kind == "ssm":
+            out, h_pool, conv_pool = self._ssm_mixer(
+                h, h_pool, conv_pool, lp["mixer"], at, segments=segments,
+                rows=rows, cfg=cfg)
+        else:
+            out, pages = self._attend_plain(h, pages, lp["attn"], at,
+                                            segments=segments, cfg=cfg)
+        x = x + out.astype(x.dtype)
+        out = T._mlp_block(cfg, lp["mlp"], self._norm(lp["norm2"], x))
+        return x + out.astype(x.dtype), pages, h_pool, conv_pool
+
+    def _attend_plain(self, h, pool, ap, layer, *, segments, cfg):
+        """Attention with no positional encoding over ``pool[layer]``:
+        the projections once over all tokens (the weights stored as the
+        matrices the products take, heads folded into the columns), the
+        cache write and the paged kernel segment by segment."""
+        dtype, d = cfg.dtype, cfg.dims_per_head
+
+        def heads(w):
+            y = jnp.einsum("sqe,ef->sqf", h, w.astype(dtype))
+            return y.reshape(y.shape[:2] + (-1, d))
+
+        q, k, v = heads(ap["wq"]), heads(ap["wk"]), heads(ap["wv"])
+        attn = []
+        for seg, qs, ks, vs in zip(segments, *(
+                _per_segment(a, segments) for a in (q, k, v))):
+            pool = write_kv(pool, layer, ks, vs, seg.page_table,
+                            seg.start_pos, seg.q_lens)
+            if seg.fresh and self._fresh_attention is not None:
+                attn.append(self._fresh_attention(qs, ks, vs))
+            else:
+                attn.append(self._attention(
+                    qs, pool, layer, seg.page_table, seg.start_pos,
+                    seg.q_lens))
+        attn = _end_to_end(attn)
+        return jnp.einsum("sqf,fe->sqe",
+                          attn.reshape(attn.shape[:2] + (-1,)),
+                          ap["wo"].astype(dtype)), pool
+
+    def _ssm_mixer(self, u, h_pool, conv_pool, mp, layer, *, segments,
+                   rows, cfg):
+        """The Mamba mixer of ``u`` (all tokens of ``segments``): its four
+        projections once over all of them; the convolution and the
+        recurrence segment by segment, each row from and to its slot of
+        ``pool[layer]`` (``ops/ssm.py``).  Returns (output in ``u``'s
+        layout, h pool, conv pool)."""
+        from ...ops.ssm import conv_step, ssm_scan
+        dtype, f32 = cfg.dtype, jnp.float32
+        d, n, r = cfg.ssm_inner, cfg.ssm_state_dim, cfg.ssm_dt_rank
+        xz = jnp.einsum("sqe,ef->sqf", u, mp["w_in"].astype(dtype))
+        x, z = xz[..., :d], xz[..., d:]
+        conv, tails = [], []
+        for xs, seg, (slots, fresh, _) in zip(
+                _per_segment(x, segments), segments, rows):
+            out, tail = conv_step(conv_pool, layer, slots, fresh,
+                                  seg.q_lens, xs, mp["conv_w"],
+                                  mp["conv_b"])
+            conv.append(jax.nn.silu(out))
+            tails.append(tail)
+        x = _end_to_end(conv)                               # float32
+        dbc = jnp.einsum("sqd,rd->sqr", x.astype(dtype),
+                         mp["w_x"].astype(dtype),
+                         preferred_element_type=f32)
+
+        def rms(a, gain):
+            return a * jax.lax.rsqrt(
+                jnp.mean(a * a, -1, keepdims=True) + cfg.norm_eps) \
+                * gain["scale"].astype(f32)
+
+        dt = rms(dbc[..., :r], mp["dt_norm"])
+        B = rms(dbc[..., r:r + n], mp["b_norm"])
+        C = rms(dbc[..., r + n:], mp["c_norm"])
+        dt = jax.nn.softplus(jnp.einsum(
+            "sqr,rd->sqd", dt.astype(dtype), mp["w_dt"].astype(dtype),
+            preferred_element_type=f32) + mp["b_dt"].astype(f32))
+        A_t = -jnp.exp(mp["A_log_t"].astype(f32))
+        ys = []
+        for (slots, fresh, valid), tail, dts, xs, Bs, Cs in zip(
+                rows, tails, *(_per_segment(a, segments)
+                               for a in (dt, x, B, C))):
+            # a padded position moves nothing: exp(0) = 1, dt x = 0
+            y, h_pool, conv_pool = ssm_scan(
+                h_pool, conv_pool, layer, slots, fresh,
+                jnp.where(valid[..., None], dts, 0.0), xs, Bs, Cs, A_t,
+                mp["D"], tail)
+            ys.append(y)
+        y = _end_to_end(ys) * jax.nn.silu(z.astype(f32))
+        return jnp.einsum("sqd,de->sqe", y.astype(dtype),
+                          mp["w_out"].astype(dtype)), h_pool, conv_pool
 
     def _per_shard_heads(self, fn, cfg, n_head_args: int,
                          pool_out: bool = False):

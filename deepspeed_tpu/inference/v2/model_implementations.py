@@ -2,7 +2,7 @@
 
 Reference: ``inference/v2/model_implementations/`` — one directory per
 arch (llama_v2, mistral, mixtral, falcon, opt, phi, qwen, qwen_v2; here
-also bloom, gpt_neox, gpt2, gptj, pangu_ultra_moe and laguna), each
+also bloom, gpt_neox, gpt2, gptj, pangu_ultra_moe, laguna and jamba), each
 a ``DSTransformerModelBase`` subclass hard-coding that family's
 invariants (llama_v2/model.py:22, mistral/model.py, ...), chosen by
 ``engine_factory`` from the checkpoint's ``model_type``.
@@ -153,6 +153,27 @@ class LagunaInferenceModel(RaggedInferenceModel):
             "expert weights do not match the routed layers or experts_held"
 
 
+class JambaInferenceModel(RaggedInferenceModel):
+    """Jamba (``models/jamba.py``; no counterpart in the reference):
+    Mamba-1 layers and attention layers in one model, the attention
+    layers' K/V in pages and the Mamba layers' recurrent state and
+    convolution tail in one slot of the state pool a sequence, no
+    positional encoding, the llama block's SwiGLU in every layer."""
+    MODEL_TYPES = ("jamba",)
+
+    def __init__(self, cfg, params, **kw):
+        assert set(cfg.layer_kinds) == {"full", "ssm"} \
+            and len(cfg.layer_kinds) == cfg.num_layers, \
+            "jamba names a kind for every layer, and has both"
+        assert cfg.ssm_state_dim > 0 and cfg.ssm_dt_rank > 0 \
+            and cfg.ssm_conv > 1
+        assert cfg.norm == "rmsnorm" and cfg.pos_emb == "none"
+        assert cfg.num_heads % cfg.kv_heads == 0
+        assert not cfg.n_routed_experts and not cfg.moe_num_experts, \
+            "the routed form of the family is not built"
+        super().__init__(cfg, params, **kw)
+
+
 class GPTNeoXInferenceModel(RaggedInferenceModel):
     MODEL_TYPES = ("gpt_neox",)
 
@@ -169,7 +190,7 @@ _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LlamaV2InferenceModel, MistralInferenceModel, MixtralInferenceModel,
     FalconInferenceModel, OPTInferenceModel, PhiInferenceModel,
     Qwen2InferenceModel, BloomInferenceModel, PanguUltraMoEInferenceModel,
-    LagunaInferenceModel,
+    LagunaInferenceModel, JambaInferenceModel,
     GPTNeoXInferenceModel, GPT2InferenceModel, GPTJInferenceModel,
 )
 

@@ -11,11 +11,12 @@ buckets** so every distinct shape compiles exactly once:
     start_pos   : [S]    int32   committed history length per slot
     page_table  : [S, P] int32   KV page indices (0 = null page)
 
-For a model with two page groups (``StateManager.window_cache``) the
-table is WIDE: ``[S, P + W + 1]``, the full group's table, then the
-window group's short table (``W = step_key.window_slots(Q)`` slots, slot
-j = the page of absolute index ``base + j``), then ``base`` itself: the
-second table rides the operand the programs already take, and
+For a model of more than one cache (a second page group,
+``StateManager.window_cache``; a state pool, ``StateManager.state_pool``)
+the table is WIDE (``cache_kinds.TableLayout``): the full group's table,
+then the window group's short table (``W`` slots, slot j = the page of
+absolute index ``base + j``) and ``base`` itself, then the row's slot of
+the state pool: they ride the operand the programs already take, and
 ``RaggedInferenceModel`` takes it apart (``_by_group``).
 
 ``S`` (sequence slots), ``Q`` (max new tokens per sequence) and ``P``
@@ -88,7 +89,7 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
                 fresh_supported: bool = True,
                 min_q: int = 1,
                 start_pos: Optional[Sequence[int]] = None,
-                window_slots=None) -> RaggedBatch:
+                table=None, scratch_slot: int = 0) -> RaggedBatch:
     """Pack (descriptor, new-token) pairs into a bucketed RaggedBatch.
 
     Callers must already have reserved KV pages on each descriptor
@@ -109,10 +110,10 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
     descriptor's committed length (the draft catch-up re-feeds committed
     history from where the draft pool stopped).
 
-    ``window_slots``: for a model with two page groups, the bucket rule
-    ``Q -> slots of the window group's table`` (``step_key.window_slots``
-    bound to the model); the table is then the wide one of the module
-    docstring.
+    ``table``: the model's :class:`..cache_kinds.TableLayout` where it
+    has more than one cache; the table is then the wide one of the module
+    docstring.  ``scratch_slot``: the state pool's scratch slot, which the
+    padding rows name.
     """
     n = len(seqs)
     assert n == len(tokens) and n >= 1
@@ -124,8 +125,11 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
     token_ids = np.zeros((S, Q), dtype=np.int32)
     q_lens = np.zeros(S, dtype=np.int32)
     starts = np.zeros(S, dtype=np.int32)
-    W = window_slots(Q) if window_slots is not None else 0
-    page_table = np.zeros((S, P + W + 1 if W else P), dtype=np.int32)
+    W = table.window_slots(Q) if table is not None else 0
+    page_table = np.zeros((S, P + (table.extra(Q) if table is not None
+                                   else 0)), dtype=np.int32)
+    if table is not None and table.state:
+        page_table[:, -1] = scratch_slot
     uids = []
     for i, (sd, toks) in enumerate(zip(seqs, tokens)):
         toks = np.asarray(toks, dtype=np.int32).reshape(-1)
@@ -141,7 +145,9 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
                     f"pages > the table's {W} slots (eviction has not "
                     "kept up with the context)")
             page_table[i, P:P + len(live)] = live
-            page_table[i, -1] = sd.window_base
+            page_table[i, P + W] = sd.window_base
+        if table is not None and table.state:
+            page_table[i, -1] = sd.state_slot
         uids.append(sd.uid)
     fresh = fresh_supported and Q > 1 and not any(start_pos)
     return RaggedBatch(token_ids, q_lens, starts, page_table, uids,

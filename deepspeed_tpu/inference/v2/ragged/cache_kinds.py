@@ -1,0 +1,89 @@
+"""What a layer kind caches, declared in one place.
+
+A model names a kind for every layer (``TransformerConfig.layer_kinds``;
+none: every layer is "full").  A kind keeps, a sequence, either a PAGE
+PLANE of a page group (its K/V at every position the kind still attends)
+or a SLOT of the state pool (a recurrent state that does not grow with the
+context).  The three places that have to agree on that read it here: the
+model when it takes a segment's table apart (``model.py::_by_group``), the
+host when it builds the table (``batch.py::build_batch``) and the state
+manager when it decides which resources a sequence reserves
+(``manager.py::StateManager``).
+
+The wide table of a segment, for a model of more than one cache::
+
+    [S, P | W | 1 | 1]   full group's pages | window group's live pages
+                         | the window table's base page | the state slot
+
+each part present only where the model has a kind that needs it, so a
+model of one page group takes the ``[S, P]`` table it always took.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict, Optional, Sequence
+
+from ..step_key import window_slots
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """What one layer kind caches."""
+    #: the page group whose pool holds the kind's K/V; "" = none
+    group: str = ""
+    #: the group's tables give back the pages the window has passed
+    windowed: bool = False
+    #: the kind holds a slot of the state pool instead of pages
+    slot: bool = False
+
+
+CACHE_KINDS: Dict[str, CacheKind] = {
+    "full": CacheKind(group="full"),
+    "window": CacheKind(group="window", windowed=True),
+    "ssm": CacheKind(slot=True),
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class TableLayout:
+    """The columns of a model's segment table (module docstring)."""
+    #: the window the windowed group's layers attend (0: no such group)
+    window: int = 0
+    page_size: int = 0
+    #: the model has a state pool: the last column is the row's slot
+    state: bool = False
+
+    @classmethod
+    def of(cls, kinds: Sequence[str], window: Optional[int],
+           page_size: int) -> "TableLayout":
+        caches = [CACHE_KINDS[k] for k in dict.fromkeys(kinds)]
+        return cls(window=int(window or 0)
+                   if any(c.windowed for c in caches) else 0,
+                   page_size=page_size,
+                   state=any(c.slot for c in caches))
+
+    def window_slots(self, Q: int) -> int:
+        """Slots of the window group's table in a segment of ``Q`` tokens
+        a row (``step_key.window_slots``); 0 without such a group."""
+        return window_slots(self.window, self.page_size, Q) \
+            if self.window else 0
+
+    def extra(self, Q: int) -> int:
+        """Columns past the full group's ``P``."""
+        W = self.window_slots(Q)
+        return (W + 1 if W else 0) + int(self.state)
+
+    def split(self, table, Q: int) -> dict:
+        """A table's parts by name: ``full`` ``[S, P]``, and where the
+        model has them ``window`` ``[S, W]``, ``base`` ``[S]``, ``slot``
+        ``[S]``."""
+        W = self.window_slots(Q)
+        P = table.shape[1] - self.extra(Q)
+        parts = {"full": table[:, :P]}
+        if W:
+            parts["window"] = table[:, P:P + W]
+            parts["base"] = table[:, P + W]
+        if self.state:
+            parts["slot"] = table[:, -1]
+        return parts

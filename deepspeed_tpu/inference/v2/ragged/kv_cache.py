@@ -103,6 +103,41 @@ class KVCacheConfig:
                 self.page_size, self.head_dim)
 
 
+@dataclasses.dataclass(frozen=True)
+class StatePoolConfig:
+    """The state pool of a model with state-space layers (``ops/ssm.py``):
+    what such a layer kind declares of its cache.  One slot a sequence
+    holds, for every such layer, the recurrent state ``[d_state, d_inner]``
+    in ``state_dtype`` and the convolution's tail ``[d_conv - 1, d_inner]``
+    in ``conv_dtype``, its rows laid end to end and cut into
+    ``ops/ssm.py::conv_rows`` rows; slot ``num_slots`` is the scratch
+    slot padding rows write to."""
+    num_layers: int
+    d_state: int
+    d_inner: int
+    d_conv: int
+    num_slots: int = 1
+    state_dtype: Any = jnp.float32
+    conv_dtype: Any = jnp.bfloat16
+
+    def shapes(self) -> tuple:
+        from ....ops.ssm import conv_rows
+        lead = (self.num_layers, self.num_slots + 1)
+        width = (self.d_conv - 1) * self.d_inner
+        rows = conv_rows(width)
+        return (lead + (self.d_state, self.d_inner),
+                lead + (rows, width // rows))
+
+    @property
+    def bytes_per_slot(self) -> int:
+        return self.num_layers * self.d_inner * (
+            self.d_state * jnp.dtype(self.state_dtype).itemsize
+            + (self.d_conv - 1) * jnp.dtype(self.conv_dtype).itemsize)
+
+    def total_bytes(self) -> int:
+        return self.bytes_per_slot * (self.num_slots + 1)
+
+
 def pages_for_memory(cfg: KVCacheConfig, budget_bytes: int) -> int:
     """How many pages fit in ``budget_bytes`` (reference sizes its cache
     from a memory fraction the same way)."""
@@ -301,3 +336,78 @@ class BlockedKVCache:
             dev_blob = pad_cols(blob, self.cfg.dtype)
         self.data = _scatter_pages(self.data, jnp.asarray(idx), dev_blob)
         return np.asarray(pages)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_slot(data, slot, rows):
+    return tuple(d.at[:, slot].set(r.astype(d.dtype))
+                 for d, r in zip(data, rows))
+
+
+class StateSlotBlob:
+    """Host copy of one slot: the rows ``[L, d_state, d_inner]`` and
+    ``[L, rows, (d_conv - 1) * d_inner / rows]`` it held, in the pool's
+    dtypes."""
+
+    __slots__ = ("h", "conv")
+
+    def __init__(self, h, conv):
+        import numpy as np
+        self.h, self.conv = np.asarray(h), np.asarray(conv)
+
+    @property
+    def nbytes(self) -> int:
+        return self.h.nbytes + self.conv.nbytes
+
+
+class StatePool:
+    """The device state pool ``(h, conv)`` (:class:`StatePoolConfig`) and
+    its host slot allocator.  ``data`` is donated to every step program
+    and put back, as a page pool is."""
+
+    def __init__(self, cfg: StatePoolConfig):
+        self.cfg = cfg
+        h, conv = cfg.shapes()
+        self.data = (jnp.zeros(h, cfg.state_dtype),
+                     jnp.zeros(conv, cfg.conv_dtype))
+        self._free = list(range(cfg.num_slots - 1, -1, -1))
+        self._held = set()
+
+    @property
+    def scratch(self) -> int:
+        return self.cfg.num_slots
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free)
+
+    @property
+    def held_slots(self) -> int:
+        return len(self._held)
+
+    def is_held(self, slot: int) -> bool:
+        return slot in self._held
+
+    def reserve(self) -> int:
+        """One free slot.  Its rows hold what the last holder left: the
+        program zeroes them on the sequence's first step, not the host."""
+        if not self._free:
+            from .blocked_allocator import KVAllocationError
+            raise KVAllocationError("state pool: no free slot")
+        slot = self._free.pop()
+        self._held.add(slot)
+        return slot
+
+    def release(self, slot: int) -> None:
+        if slot not in self._held:
+            raise ValueError(f"state slot {slot} released but not held")
+        self._held.remove(slot)
+        self._free.append(slot)
+
+    def read_slot(self, slot: int) -> StateSlotBlob:
+        return StateSlotBlob(*(d[:, slot] for d in self.data))
+
+    def write_slot(self, slot: int, blob: StateSlotBlob) -> None:
+        self.data = _write_slot(self.data, jnp.int32(slot),
+                                (jnp.asarray(blob.h),
+                                 jnp.asarray(blob.conv)))

@@ -24,6 +24,19 @@ handoff) walks both lists.  The prefix cache is OFF with a window group:
 a matched prefix would skip the prefill of tokens whose window-group K/V
 nobody holds, so nothing is indexed.  A model with one window or none
 has ONE group and this module's paths are what they were.
+
+Sequence state that is not pages: a model with state-space layers
+(``ops/ssm.py``) keeps, a sequence, ONE SLOT of a fixed state pool
+(``kv_cache.StatePool``; ``SequenceDescriptor.state_slot``).  A slot is
+reserved with the sequence's first pages or not at all, released at
+flush, moved to a host copy and back by preempt offload / restore, and
+written beside the pages by ``export_state`` / ``import_state`` (the
+snapshot and the handoff), which map it onto a fresh slot.  A reserved
+slot is not cleared by the host: the program starts a row at position 0
+from zeros.  The prefix cache is OFF with a state pool too (a matched
+prefix would skip the prefill of tokens whose recurrent state nobody
+holds), and there is no tier.  What a layer kind caches is declared in
+``cache_kinds.py``.
 """
 
 from __future__ import annotations
@@ -40,8 +53,9 @@ from ....telemetry import trace_span
 from ....telemetry.flight_recorder import get_flight_recorder
 from ....utils.comms_logging import serving_counters
 from .blocked_allocator import KVAllocationError, NULL_PAGE
-from .kv_cache import (BlockedKVCache, KVCacheConfig, PageBlob,
-                       blob_columns, concat_blobs)
+from .kv_cache import (BlockedKVCache, KVCacheConfig, PageBlob, StatePool,
+                       StatePoolConfig, StateSlotBlob, blob_columns,
+                       concat_blobs)
 from .kv_tiers import TieredPageStore
 from .prefix_cache import PrefixCache
 from .sequence import SequenceDescriptor
@@ -56,7 +70,8 @@ class StateManager:
                  tier_disk_pages: int = 0,
                  tier_dir: Optional[str] = None,
                  window_kv_config: Optional[KVCacheConfig] = None,
-                 window: int = 0):
+                 window: int = 0,
+                 state_config: Optional[StatePoolConfig] = None):
         self.kv_config = kv_config
         self.max_tracked_sequences = max_tracked_sequences
         self.kv_cache = BlockedKVCache(kv_config, sharding=kv_sharding)
@@ -71,6 +86,12 @@ class StateManager:
                 and window_kv_config.page_size == kv_config.page_size
             self.window_cache = BlockedKVCache(window_kv_config,
                                                sharding=kv_sharding)
+            prefix_caching = False      # module docstring
+        #: the state pool and its slots (module docstring); None for a
+        #: model whose every layer kind caches pages
+        self.state_pool: Optional[StatePool] = None
+        if state_config is not None:
+            self.state_pool = StatePool(state_config)
             prefix_caching = False      # module docstring
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(kv_config.page_size) if prefix_caching else None)
@@ -147,6 +168,17 @@ class StateManager:
         """Free pages of the window group (0 for a model of one group)."""
         return (self.window_cache.free_pages
                 if self.window_cache is not None else 0)
+
+    @property
+    def free_state_slots(self) -> int:
+        """Free slots of the state pool (0 for a model without one)."""
+        return (self.state_pool.free_slots
+                if self.state_pool is not None else 0)
+
+    def state_slots_needed(self, sd: SequenceDescriptor) -> int:
+        """Slots a step of ``sd`` has to reserve: 1 for a sequence of a
+        model with a state pool that holds none yet."""
+        return int(self.state_pool is not None and sd.state_slot < 0)
 
     def window_occupancy(self) -> Tuple[int, int]:
         """:meth:`kv_occupancy` of the window group: (pages in the live
@@ -411,17 +443,20 @@ class StateManager:
         sd.host_blob = None
         sd.live_slots = []
 
-    def _hold_window_blob(self, sd: SequenceDescriptor, blob) -> None:
-        """Account a host blob of the window group's pages (counted as a
-        blob of its own beside the full group's)."""
-        sd.window_blob = blob
+    def _hold_side_blob(self, sd: SequenceDescriptor, field: str,
+                        blob) -> None:
+        """Account a host blob held beside the full group's: the window
+        group's pages (``window_blob``) or the state slot's rows
+        (``state_blob``), each counted as a blob of its own."""
+        setattr(sd, field, blob)
         self._offload_blobs += 1
         self._offload_bytes += blob.nbytes
 
-    def _release_window_blob(self, sd: SequenceDescriptor) -> None:
+    def _release_side_blob(self, sd: SequenceDescriptor,
+                           field: str) -> None:
         self._offload_blobs -= 1
-        self._offload_bytes -= sd.window_blob.nbytes
-        sd.window_blob = None
+        self._offload_bytes -= getattr(sd, field).nbytes
+        setattr(sd, field, None)
 
     def flush_sequence(self, uid: int) -> None:
         sd = self._seqs.pop(uid, None)
@@ -440,7 +475,12 @@ class StateManager:
                 if sd.window_pages:
                     self.window_cache.release(sd.window_pages)
                 if sd.window_blob is not None:
-                    self._release_window_blob(sd)
+                    self._release_side_blob(sd, "window_blob")
+                if sd.state_slot >= 0:
+                    self.state_pool.release(sd.state_slot)
+                    sd.state_slot = -1
+                if sd.state_blob is not None:
+                    self._release_side_blob(sd, "state_blob")
 
     def offload_sequence(self, uid: int) -> None:
         """Preempt: move a sequence's PRIVATE live KV pages to host
@@ -452,7 +492,7 @@ class StateManager:
         tracked; it cannot be scheduled until restore_sequence."""
         sd = self._seqs.get(uid)
         if sd is None or sd.host_blob is not None \
-                or sd.window_blob is not None:
+                or sd.window_blob is not None or sd.state_blob is not None:
             return  # unknown/flushed uids tolerated like flush_sequence
         with trace_span("kv.offload"):
             self._offload_impl(sd)
@@ -460,9 +500,17 @@ class StateManager:
                 # the window group's pages are all private (nothing of
                 # it is shared or indexed): every one moves, and comes
                 # back in order under the same window_base
-                self._hold_window_blob(
-                    sd, self.window_cache.offload_pages(sd.window_pages))
+                self._hold_side_blob(
+                    sd, "window_blob",
+                    self.window_cache.offload_pages(sd.window_pages))
                 sd.window_pages = []
+            if sd.state_slot >= 0:
+                # the slot's rows move with the pages: both or neither
+                self._hold_side_blob(
+                    sd, "state_blob",
+                    self.state_pool.read_slot(sd.state_slot))
+                self.state_pool.release(sd.state_slot)
+                sd.state_slot = -1
 
     def _offload_impl(self, sd: SequenceDescriptor) -> None:
         sd.live_slots = self.offloadable_slots(sd)
@@ -490,9 +538,14 @@ class StateManager:
         """Bring a preempted sequence's KV back onto device (reference
         restore hook).  Raises if the pool lacks free pages."""
         sd = self._seqs.get(uid)
-        if sd is None or (sd.host_blob is None and sd.window_blob is None):
+        if sd is None or (sd.host_blob is None and sd.window_blob is None
+                          and sd.state_blob is None):
             return
         with trace_span("kv.restore"):
+            if sd.state_blob is not None and not self.free_state_slots:
+                # before any page is touched: a restore fails whole
+                raise KVAllocationError(
+                    "restore needs a state slot, none free")
             need_w = (int(sd.window_blob.shape[1])
                       if sd.window_blob is not None else 0)
             if need_w > self.free_window_pages:
@@ -510,7 +563,11 @@ class StateManager:
                 sd.window_pages = [int(p) for p in
                                    self.window_cache.restore_pages(
                                        sd.window_blob)]
-                self._release_window_blob(sd)
+                self._release_side_blob(sd, "window_blob")
+            if sd.state_blob is not None:
+                sd.state_slot = self.state_pool.reserve()
+                self.state_pool.write_slot(sd.state_slot, sd.state_blob)
+                self._release_side_blob(sd, "state_blob")
         # restored pages are private again; if offload unindexed any of
         # them it also disabled this sequence's indexing (broken chain),
         # otherwise the digest chain is intact and indexing continues
@@ -622,6 +679,16 @@ class StateManager:
                 if sd.window_blob is not None:
                     self._pack_blob(arrays, f"windowblob_{uid}",
                                     sd.window_blob)
+            if self.state_pool is not None:
+                # the slot's rows travel beside the pages, read from the
+                # pool or from the host copy a preempted sequence holds
+                blob = (self.state_pool.read_slot(sd.state_slot)
+                        if sd.state_slot >= 0 else sd.state_blob)
+                m["state"] = ("slot" if sd.state_slot >= 0 else
+                              "blob" if blob is not None else "none")
+                if blob is not None:
+                    arrays[f"state_h_{uid}"] = blob.h
+                    arrays[f"state_conv_{uid}"] = blob.conv
             seqs.append(m)
         window_ids = [int(p) for sd in export_seqs.values()
                       for p in sd.window_pages]
@@ -649,7 +716,38 @@ class StateManager:
                 "quantization": cfg.quantization, "planes": cfg.planes}
         if self.window_cache is not None:
             meta["window_layers"] = self.window_cache.cfg.num_layers
+        if self.state_pool is not None:
+            sc = self.state_pool.cfg
+            meta["state"] = [sc.num_layers, sc.d_state, sc.d_inner,
+                             sc.d_conv, np.dtype(sc.state_dtype).name,
+                             np.dtype(sc.conv_dtype).name]
         return meta
+
+    def _check_state_room(self, meta: dict) -> None:
+        """Import half of the state pool, before any mutation: the
+        bundle's live slots need as many free ones here (the retryable
+        :class:`KVAllocationError` otherwise)."""
+        need = sum(1 for m in meta["sequences"]
+                   if m.get("state") == "slot")
+        if need > self.free_state_slots:
+            raise KVAllocationError(
+                f"bundle needs {need} state slots, pool has "
+                f"{self.free_state_slots} free")
+
+    def _import_state_slot(self, sd: SequenceDescriptor, m: dict,
+                           arrays: Dict[str, np.ndarray]) -> None:
+        """One imported sequence's state: onto a fresh slot, or held as
+        the host copy it was."""
+        how = m.get("state", "none")
+        if self.state_pool is None or how == "none":
+            return
+        blob = StateSlotBlob(arrays[f"state_h_{sd.uid}"],
+                             arrays[f"state_conv_{sd.uid}"])
+        if how == "slot":
+            sd.state_slot = self.state_pool.reserve()
+            self.state_pool.write_slot(sd.state_slot, blob)
+        else:
+            self._hold_side_blob(sd, "state_blob", blob)
 
     def _window_mapping(self, meta: dict,
                         arrays: Dict[str, np.ndarray]) -> Dict[int, int]:
@@ -682,8 +780,9 @@ class StateManager:
         sd.window_pages = [mapping[int(p)] for p in m["window_pages"]]
         sd.window_base = int(m["window_base"])
         if m.get("has_window_blob"):
-            self._hold_window_blob(
-                sd, self._unpack_blob(arrays, f"windowblob_{sd.uid}"))
+            self._hold_side_blob(
+                sd, "window_blob",
+                self._unpack_blob(arrays, f"windowblob_{sd.uid}"))
 
     def _check_kv_meta(self, meta: dict) -> None:
         from ..snapshot import SnapshotError
@@ -759,6 +858,7 @@ class StateManager:
                 f"bundle needs {len(old_ids)} KV pages, pool has "
                 f"{alloc.free_pages} free")
         try:
+            self._check_state_room(meta)
             window_mapping = self._window_mapping(meta, arrays)
         except KVAllocationError as e:
             raise SnapshotError(str(e)) from None
@@ -804,6 +904,7 @@ class StateManager:
                 self._offload_blobs += 1
                 self._offload_bytes += sd.host_blob.nbytes
             self._import_window(sd, m, window_mapping, arrays)
+            self._import_state_slot(sd, m, arrays)
             self._seqs[uid] = sd
         if self.prefix_cache is not None:
             for d_hex, p in meta["prefix"]:
@@ -884,6 +985,7 @@ class StateManager:
                 "the decode pool drains")
         # the window group's pages stream whole (nothing of it is shared);
         # a refusal here is still before any mutation
+        self._check_state_room(meta)
         window_mapping = self._window_mapping(meta, arrays)
         # true refcounts per exported page = appearances in the
         # imported block tables (selective bundles carry no parked
@@ -932,6 +1034,7 @@ class StateManager:
                 self._offload_blobs += 1
                 self._offload_bytes += sd.host_blob.nbytes
             self._import_window(sd, m, window_mapping, arrays)
+            self._import_state_slot(sd, m, arrays)
             self._seqs[uid] = sd
         if self.prefix_cache is not None:
             for d_hex, p in meta["prefix"]:
@@ -1054,6 +1157,10 @@ class StateManager:
             raise KVAllocationError(
                 f"window group: {extra_w} pages requested, "
                 f"{self.free_window_pages} free")
+        need_slot = self.state_slots_needed(sd)
+        if need_slot > self.free_state_slots:
+            # pages and a slot, or neither
+            raise KVAllocationError("state pool: no free slot")
         if extra:
             get_fault_injector().maybe_raise(
                 "kv.alloc_oom", KVAllocationError,
@@ -1063,6 +1170,8 @@ class StateManager:
         if extra_w:
             sd.window_pages.extend(
                 int(p) for p in self.window_cache.reserve(extra_w))
+        if need_slot:
+            sd.state_slot = self.state_pool.reserve()
 
     # -- invariants (DS_KV_DEBUG) -------------------------------------------
     def check_invariants(self) -> None:
@@ -1131,10 +1240,40 @@ class StateManager:
                         f"KV invariant: sequence {sd.uid}'s window table "
                         f"covers [{sd.window_base * page}, {end}) with "
                         f"{sd.seen_tokens} tokens committed")
+        if self.state_pool is not None:
+            # the state pool: no slot twice, none lost, none held beside
+            # its own host copy, and every started sequence on the device
+            # has one
+            pool = self.state_pool
+            held = Counter(sd.state_slot for sd in self._seqs.values()
+                           if sd.state_slot >= 0)
+            for slot, n in held.items():
+                if n != 1 or not pool.is_held(slot):
+                    raise RuntimeError(
+                        f"KV invariant: state slot {slot} is held by {n} "
+                        "sequences or not reserved in the pool")
+            if pool.held_slots != len(held) \
+                    or pool.free_slots + len(held) != pool.cfg.num_slots:
+                raise RuntimeError(
+                    f"KV invariant: state pool free({pool.free_slots}) + "
+                    f"sequences' slots({len(held)}) != "
+                    f"total({pool.cfg.num_slots}) (a slot lost?)")
+            for sd in self._seqs.values():
+                if sd.state_blob is not None and sd.state_slot >= 0:
+                    raise RuntimeError(
+                        f"KV invariant: sequence {sd.uid} holds a state "
+                        "slot and its host copy at once")
+                if sd.seen_tokens and sd.state_slot < 0 \
+                        and sd.state_blob is None:
+                    raise RuntimeError(
+                        f"KV invariant: sequence {sd.uid} has "
+                        f"{sd.seen_tokens} tokens committed and no state")
         blobs = [sd.host_blob for sd in self._seqs.values()
                  if sd.host_blob is not None] + [
                      sd.window_blob for sd in self._seqs.values()
-                     if sd.window_blob is not None]
+                     if sd.window_blob is not None] + [
+                     sd.state_blob for sd in self._seqs.values()
+                     if sd.state_blob is not None]
         blob_bytes = sum(b.nbytes for b in blobs)
         if (len(blobs) != self._offload_blobs
                 or blob_bytes != self._offload_bytes):

@@ -41,6 +41,12 @@ class SequenceDescriptor:
     window_base: int = 0
     #: host blob of the window group's pages while preempted
     window_blob: object = None
+    #: the sequence's slot of the state pool (a model with state-space
+    #: layers: its recurrent state and convolution tails, every such
+    #: layer's, live at this index); -1: none held
+    state_slot: int = -1
+    #: host copy of the slot's rows while preempted
+    state_blob: object = None
     #: full prompt token ids, registered at admission when prefix
     #: caching is on — the indexer hashes full prompt pages from these
     #: (generated tokens are never indexed: their values are only

@@ -243,6 +243,8 @@ class _Admission:
         self.free_pages = engine.free_blocks
         #: the window group's free pages (a model of two page groups)
         self.free_window_pages = engine.free_window_blocks
+        #: the state pool's free slots (a model with one)
+        self.free_state_slots = engine.free_state_slots
         self.tokens_left = min(token_budget, sm.max_ragged_batch_size)
         self.seqs_left = sm.max_ragged_sequence_count
         self.tracked_left = (sm.max_tracked_sequences
@@ -259,6 +261,11 @@ class _Admission:
         pages_w = self.engine.window_blocks_needed(uid, n_tokens)
         if pages_w > self.free_window_pages:
             return False
+        # pages and a slot of the state pool, or neither
+        slots = self.engine.state_slots_needed(uid)
+        if slots > self.free_state_slots:
+            return False
+        self.free_state_slots -= slots
         self.free_window_pages -= pages_w
         self.free_pages -= pages
         self.tokens_left -= n_tokens
@@ -1504,6 +1511,17 @@ class FastGenScheduler:
             full, in_window = self._engine.take_attended()
             span.set("attn_tokens_full", full)
             span.set("attn_tokens_window", in_window)
+        if state.state_pool is not None:
+            # the state pool of a model with state-space layers: slots
+            # held, and what this step's two kernels were given (a
+            # one-token row is stepped by the update kernel, a prompt
+            # piece's true tokens by the scan)
+            pool = state.state_pool
+            span.set("ssm_slots_held", pool.held_slots)
+            span.set("ssm_rows_decode", rows - prefill_rows)
+            span.set("ssm_tokens_prefill", prefill_tokens)
+            span.set("ssm_state_bytes",
+                     pool.held_slots * pool.cfg.bytes_per_slot)
         if self._moe_counts is not None:
             # counts of the step drained inside this one (the step
             # before), with that step's tokens as their divisor
@@ -1585,8 +1603,10 @@ class FastGenScheduler:
                         if sd.host_blob is not None else 0)
                 need_w = (sd.window_blob.shape[1] + 1
                           if sd.window_blob is not None else 0)
+                need_s = int(sd.state_blob is not None)
                 if self._engine.free_blocks >= need + 1 \
-                        and self._engine.free_window_blocks >= need_w:
+                        and self._engine.free_window_blocks >= need_w \
+                        and self._engine.free_state_slots >= need_s:
                     self._engine.restore_sequence(uid)
                     get_flight_recorder().record("request.restore",
                                                  uid=uid)

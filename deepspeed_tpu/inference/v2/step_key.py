@@ -305,11 +305,11 @@ def step_avals(model, key: StepKey, kv_aval) -> list:
     row = STEP_KINDS[key.kind]
 
     def segment(S, Q, P, _fresh=None):
-        # a model with two page groups takes the wide table
-        # (ragged/batch.py): the window group's slots and base follow
-        W = model.window_slots(Q)
+        # a model of more than one cache takes the wide table
+        # (ragged/cache_kinds.py): the window group's slots and base,
+        # the state slot follow
         return [sds((S, Q), i32), sds((S,), i32), sds((S,), i32),
-                sds((S, P + W + 1 if W else P), i32)]
+                sds((S, P + model.table.extra(Q)), i32)]
 
     S = rows = key.S
     avals = segment(*key[:3])

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +119,19 @@ class TransformerConfig:
     rope_yarn: Tuple[float, ...] = ()
     # o = concat_n(sigmoid(h Wg)_n * a_n) Wo: one gate a query head
     head_gate: bool = False
+    # state-space (Mamba-1) layers beside attention layers in one model
+    # (models/jamba.py): ``layer_kinds`` names them "ssm".  ssm_state_dim
+    # > 0 says the model has such layers, each a mixer of ssm_expand x
+    # hidden channels with a recurrent state of ssm_state_dim a channel
+    # (held in ssm_state_dtype), a causal convolution over ssm_conv
+    # positions and a time step projected up from ssm_dt_rank.  What a
+    # sequence carries of such a layer is a slot of the state pool
+    # (ops/ssm.py), not pages
+    ssm_state_dim: int = 0
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    ssm_expand: int = 2
+    ssm_state_dtype: Any = jnp.float32
     tie_embeddings: bool = False
     use_bias: bool = False
     dropout: float = 0.0
@@ -165,6 +178,12 @@ class TransformerConfig:
     def held_experts(self) -> int:
         return self.experts_held or self.n_routed_experts
 
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of a state-space mixer (0: the model has none)."""
+        return self.ssm_expand * self.hidden_size if self.ssm_state_dim \
+            else 0
+
     def n_params(self) -> int:
         """Matmul parameters held by this process (norm gains left out):
         of a routed layer the experts held here, not the layer's."""
@@ -182,8 +201,14 @@ class TransformerConfig:
         attn *= l
         if self.layer_kinds:        # a head count (and a gate) a kind
             heads = dict(self.heads_by_kind)
-            attn = sum(2 * e * heads[kind] * d + 2 * e * k * d
-                       + (e * heads[kind] if self.head_gate else 0)
+            di, n, r = self.ssm_inner, self.ssm_state_dim, self.ssm_dt_rank
+            # a state-space mixer: in, x, dt and out projections, the
+            # convolution, A and D (its three small norms left out)
+            mixer = (e * 2 * di + di * (r + 2 * n) + r * di + di + di * e
+                     + di * (self.ssm_conv + 1) + di * n + di)
+            attn = sum(mixer if kind == "ssm" else
+                       2 * e * heads.get(kind, h) * d + 2 * e * k * d
+                       + (e * heads.get(kind, h) if self.head_gate else 0)
                        for kind in self.layer_kinds)
         mlp = e * f * (3 if "gated" in self.activation else 2)
         dense = l
@@ -915,3 +940,22 @@ class CausalLM:
         mask = batch.get("attention_mask")
         return cross_entropy_loss(logits[:, :-1], labels,
                                   mask[:, 1:] if mask is not None else None)
+
+
+def layer_runs(cfg: TransformerConfig
+               ) -> Tuple[List[Tuple[str, int]], int, int]:
+    """(the runs of like layers inside one period of the pattern as
+    (kind, length), whole periods, layers after them).  The period is the
+    shortest the kinds repeat with."""
+    kinds = cfg.layer_kinds
+    period = next((p for p in range(1, len(kinds) + 1)
+                   if all(kinds[i] == kinds[i % p]
+                          for i in range(len(kinds)))), len(kinds))
+    runs: List[Tuple[str, int]] = []
+    for kind in kinds[:period]:
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1)
+        else:
+            runs.append((kind, 1))
+    periods = len(kinds) // period
+    return runs, periods, len(kinds) - periods * period

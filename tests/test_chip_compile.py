@@ -568,3 +568,131 @@ def test_laguna_step_program_moves_no_pool_and_no_expert_stack(
     # the mixed step's 768 tokens hold 180 MB of temporaries (its 8,704
     # expert rows alone 53 MB): under a layer of the experts' stack
     assert compiled.memory_analysis().temp_size_in_bytes < expert_layer
+
+
+# -- the state-space family: its two kernels and its step programs ----------
+
+SSM_LAYERS, SSM_SLOTS, SSM_STATE, SSM_INNER = 26, 256, 16, 5120
+
+
+def _state_pools(chip, layers=SSM_LAYERS):
+    return (chip((layers, SSM_SLOTS + 1, SSM_STATE, SSM_INNER), jnp.float32),
+            chip((layers, SSM_SLOTS + 1, 8, 3 * SSM_INNER // 8),
+                 jnp.bfloat16))
+
+
+@pytest.mark.parametrize("rows,q,kernel", [
+    (256, 1, "ssm_state_update_decode"), (4, 128, "ssm_scan_prefill"),
+    (8, 128, "ssm_scan_prefill"), (2, 64, "ssm_scan_prefill")])
+def test_state_space_kernels(chip, rows, q, kernel):
+    """The update kernel (a row's whole [16, 5120] float32 state a grid
+    step) and the scan kernel (d_inner in blocks under the default VMEM
+    limit) at the published widths, both pools aliased in -> out."""
+    from deepspeed_tpu.ops.ssm import _d_block, ssm_scan
+    f32 = jnp.float32
+    assert _d_block(SSM_INNER, 1) == SSM_INNER
+    assert _d_block(SSM_INNER, 128) == 1280
+    h, conv = _state_pools(chip)
+    compile_for_chip(
+        lambda h, conv, layer, slots, fresh, dt, x, B, C, A, D, tail:
+        ssm_scan(h, conv, layer, slots, fresh, dt, x, B, C, A, D, tail,
+                 use_kernel=True),
+        h, conv, chip((), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.bool_), chip((rows, q, SSM_INNER), f32),
+        chip((rows, q, SSM_INNER), f32), chip((rows, q, SSM_STATE), f32),
+        chip((rows, q, SSM_STATE), f32), chip((SSM_STATE, SSM_INNER), f32),
+        chip((SSM_INNER,), f32), chip((rows, 3, SSM_INNER), jnp.bfloat16),
+        kernel=kernel)
+
+
+JAMBA_STEP_KEYS = {
+    "chain-p40": (256, 1, 40, False, "chain", 256, True),
+    "mixed-p40": (256, 1, 40, False, "mixed", 2, 128, 8, True, True),
+    "sample-fresh": (4, 128, 8, True, "sample", True),
+    # a prefill that is no fresh one (a later piece of a prompt): the scan
+    # from the slot's carried state, the ragged kernel at 20 query heads
+    # over the one KV head (a block of 2,560 query rows)
+    "chunk": (4, 128, 8, False, "sample", True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(JAMBA_STEP_KEYS))
+def test_jamba_step_program_moves_no_pool_and_no_weight_stack(
+        chip, monkeypatch, kind):
+    """The benchmark's cell at published widths and full depth (28 layers:
+    26 Mamba, attention at 7 and 21): the step programs lower for the chip
+    with a group of 20 query heads over 1 KV head, the state-space kernels
+    run under their own names, and nothing the size of a layer of the
+    state pool's small array (the convolution tails: 7.9 MB), let alone of
+    the 2.2 GB state pool, the page pool or a weight stack, is copied,
+    sliced out or re-laid out: the in-place update is held by this.  The
+    temporaries stay under one Mamba layer's weights."""
+    import dataclasses
+    import json
+    import os
+
+    from flax.core import meta
+
+    from benchmark.builders.serve_jamba import source_of
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        JambaInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.jamba import JambaForCausalLM
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "jamba2-3b-serve-28l.json")) as f:
+        config = json.load(f)
+    model = JambaForCausalLM(source_of(config, False))
+    assert model.cfg.layer_kinds.count("ssm") == SSM_LAYERS
+    params = jax.eval_shape(lambda k: meta.unbox(model.init_params(k)),
+                            jax.random.key(0))
+    pages = 2048
+    serve = JambaInferenceModel(model.cfg, params, kv_config=KVCacheConfig(
+        num_layers=2, kv_heads=1, head_dim=128, page_size=PAGE,
+        num_pages=pages))
+    serve.state_config = dataclasses.replace(serve.state_config,
+                                             num_slots=SSM_SLOTS)
+    pool = (chip((2, pages + 1, 2, 1, PAGE, 128), jnp.bfloat16),
+            *_state_pools(chip))
+    assert [tuple(a.shape) for a in pool[1:]] \
+        == list(serve.state_config.shapes())
+    key = StepKey.parse(JAMBA_STEP_KEYS[kind])
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        step_avals(serve, key, pool))
+    # the state slot rides the page table's last column: no new operand,
+    # no new field of the key
+    assert (key.S, key.P + 1) in [a.shape for a in avals[2:]]
+    compiled = jax.jit(step_program(serve, key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    text = compiled.as_text()
+    row = "decode" if key.Q == 1 else "prefill"
+    kernels = [f"kv_write_{row}", "ssm_state_update_decode" if key.Q == 1
+               else "ssm_scan_prefill"]
+    if key.kind == "mixed":
+        kernels += ["ssm_scan_prefill", "kv_write_prefill"]
+    if not key.fresh or key.kind == "mixed":
+        kernels.append("paged_attention_decode" if key.kind == "mixed"
+                       else f"paged_attention_{row}")
+    for kernel in kernels:
+        assert any('custom_call_target="tpu_custom_call"' in line
+                   and kernel in line for line in text.splitlines()), kernel
+    # the smallest thing that must not move: one layer of the conv pool
+    conv_layer = (SSM_SLOTS + 1) * 3 * SSM_INNER * 2
+    kv_layer = (pages + 1) * 2 * PAGE * 128 * 2
+    mamba_layer = 2 * 104_000_000
+    assert conv_layer < kv_layer < mamba_layer
+    moved = [m for m in pool_sized_movers(text, conv_layer)
+             # ONE layer's weights taken out of its kind's stack, mostly
+             # inside the fusion that feeds the product: what a scan over
+             # layers does (the attention layer's 13 MB wq and wo are
+             # copied out, 0.03 ms a step)
+             if not m[2].startswith("bf16[1,")]
+    # activations of the step's 768 tokens, not pools
+    assert all(m[2] in ("f32[512,1,5120]", "bf16[4,131,5120]",
+                        "bf16[8,131,5120]") for m in moved), moved
+    assert stack_shaped_movers(text, params) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < mamba_layer

@@ -576,3 +576,73 @@ def test_a_model_of_two_page_groups_carries_the_window_groups_names():
     kinds = engine_of(cfg, params).model._kind_cfg
     assert _kernel_name(kinds["window"]) == "paged_attention_window"
     assert _kernel_name(kinds["full"]) == "paged_attention"
+
+
+def test_a_model_with_a_state_pool_carries_the_state_pools_names():
+    """What the step of a model with state-space layers (PR 34) adds to the
+    tree: on ``fastgen.step`` the slots held, the rows the update kernel
+    stepped, the true tokens the scan consumed and the bytes the held slots
+    hold, each read by a metric file or a reader of the benchmark or held
+    for a trace by decision; no ``kv.state_slot`` span (reserving a slot is
+    a list pop inside ``engine.admit``: no host time worth a span); and the
+    two kernels under names that ``^ssm_`` finds and no pattern that was
+    here does."""
+    import glob
+    import json
+    import os
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    from deepspeed_tpu.ops.ssm import ssm_scan
+    from test_jamba import engine_of, family, scan_args, sequences_of
+    cfg, params = family()
+    sched = FastGenScheduler(engine_of(cfg, params))
+    telemetry.enable()
+    for uid, p in enumerate(sequences_of((21, 30), seed=2)):
+        sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=8))
+    sched.run_to_completion()
+    recs = [r for r in get_tracer().records()
+            if not r[0].startswith("engine.program")]
+    carried = {key for r in recs if r[0] == "fastgen.step" and r[5]
+               for key in r[5]}
+    new = {"ssm_slots_held", "ssm_rows_decode", "ssm_tokens_prefill",
+           "ssm_state_bytes"}
+    assert new <= carried
+    assert carried - new == {
+        "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
+        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "program"}
+    assert not any(r[0].startswith("kv.state") for r in recs)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    read, patterns = set(), []
+    for path in glob.glob(os.path.join(root, "benchmark", "metrics",
+                                       "*.json")):
+        with open(path) as f:
+            metric = json.load(f)
+        args = metric.get("args", {})
+        read |= {v[5:] for v in (args.get("value", ""),
+                                 args.get("of_value", ""))
+                 if v.startswith("attr:")}
+        if not os.path.basename(path).startswith("ssm_"):
+            patterns += args.get("patterns", [])
+    with open(os.path.join(root, "benchmark", "readers",
+                           "ssm_roofline.py")) as f:
+        text = f.read()
+    read |= {key for key in new if f'"{key}"' in text}
+    # the held slots' bytes: in the span ring for whoever reads a trace
+    # (slots x 9.3 MB at the published widths), by decision no metric
+    assert new - read == {"ssm_state_bytes"}
+
+    def jaxpr(Q):
+        args, _ = scan_args(2, Q)
+        return str(jax.make_jaxpr(lambda kw: ssm_scan(
+            **kw, interpret=True))(args))
+
+    assert "ssm_state_update_decode" in jaxpr(1)
+    assert "ssm_scan_prefill" in jaxpr(8)
+    for name in ("ssm_state_update_decode", "ssm_scan_prefill"):
+        assert re.search("^ssm_", name)
+        assert not any(re.search(p, name) for p in patterns), name
