@@ -16,11 +16,21 @@ and one "head": ``[L, P+1, 1, 1, page, 640]``, so every host codec
                            ``q_abs = [q_n W_kb^K ; q_r ; 0]`` against the
                            plane gives the score, the plane's first 512
                            values are what the probabilities sum.  Q = 1
-                           runs the Pallas kernel ``mla_attention_decode``:
-                           one page fetch serves all heads (a
-                           ``[H, 640] x [640, pages*64]`` matmul a grid
-                           step); Q > 1 with history (a continued chunk,
-                           a speculative row) takes the ``jnp`` gather.
+                           runs the Pallas kernel ``mla_attention_decode``,
+                           a grid over ROWS: inside, a loop over the row's
+                           own live pages, 8 to a ``[512, 640]`` tile,
+                           copied from the pool in HBM page by page to
+                           their offsets of the tile, three tiles in VMEM
+                           (the copies run two tiles ahead, across rows);
+                           one tile serves all heads (a ``[H, 640] x
+                           [640, 512]`` and a ``[H, 512] x [512, 512]``
+                           matmul), a row's last tile only to half its
+                           columns where its live pages end there.  No
+                           step, copy or wait exists for a page slot past
+                           the context: a row costs its context, whatever
+                           the page bucket.  Q > 1 with history (a continued
+                           chunk, a speculative row) takes the ``jnp``
+                           gather.
 * ``mla_fresh_attention`` — the EXPANDED form for a pure prefill, whose
                            context is its own tokens: 192-wide scores,
                            128-wide values, Pallas kernel
@@ -45,9 +55,15 @@ from .paged_attention import (MASK_VALUE, KVPages, kv_write_pages,
 
 LANES = 128
 
-#: pages one decode grid step attends over (8 x 64 tokens): a page a step
-#: leaves the step's fixed cost larger than its work
+#: pages one tile of a decode row's walk holds (8 x 64 tokens): a page a
+#: tile leaves the tile's fixed cost larger than its work
 PAGES_PER_STEP = 8
+
+#: tiles of the walk in VMEM at once: the one under the matmuls and two
+#: being copied.  Contexts differ row by row, so with one tile ahead a
+#: short tile cannot cover a long one's copy (0.66 -> 0.62 ms a call).
+#: The kernel's look-ahead (``ahead``) is written for these two
+TILES_IN_FLIGHT = 3
 
 #: longest pure prefill the one-block kernel takes ([Q, Q] float32 scores
 #: of one head in VMEM); longer pieces take the ``jnp`` form
@@ -79,86 +95,160 @@ def latent_write(kv: jax.Array, layer, plane: jax.Array,
                     use_kernel=False)
 
 
-def _decode_kernel(l_ref, pt_ref, sp_ref, q_ref, *refs, page_size, group,
+def _lanes(x, width):
+    """A lane-replicated ``[H, 128]`` statistic over ``width`` columns:
+    whole lane tiles are repeated, any other width is broadcast from the
+    first column."""
+    if width % LANES == 0:
+        return pltpu.repeat(x, width // LANES, axis=1)
+    return x[:, :1]
+
+
+def _decode_kernel(l_ref, pt_ref, sp_ref, live_ref, q_ref, kv_ref, o_ref, tile,
+                   sem, walked, m_scr, l_scr, acc_scr, *, page_size, group,
                    rank, sm_scale):
-    """One (row, group of pages) grid step of the absorbed decode: all
-    heads of the row against ``group`` planes' pages at once, flash-style
-    running max / denominator / sum across the groups."""
-    pages, (o_ref, m_scr, l_scr, acc_scr) = refs[:group], refs[group:]
-    s, j = pl.program_id(0), pl.program_id(1)
+    """One row of the absorbed decode: all heads of the row against the
+    row's OWN pages (``live_ref``: how many), ``group`` pages a tile,
+    flash-style running max / denominator / sum across the tiles.  The
+    pool stays in HBM; a live page is one copy to its row offset of a
+    ``[group * page, W]`` tile, and the tiles two places ahead in the
+    walk (the row's next ones, then the next rows' first) are in flight
+    under this tile's matmuls.  ``walked`` counts the tiles of the rows
+    before, so the ring of three tiles turns on across rows."""
+    s = pl.program_id(0)
     span = group * page_size
+    layer = l_ref[0]
+
+    def tiles_of(row):
+        return jax.lax.div(live_ref[row] + (group - 1), group)
+
+    def live_in(row, g):
+        """Live pages of tile ``g`` of ``row`` (none, or fewer, past its
+        context or past the last row)."""
+        return jnp.minimum(live_ref[row] - g * group, group)
+
+    def copies(row, g, slot, wait):
+        """Start (or wait for) the copies of the live pages of tile ``g``
+        of ``row``: none past the row's context, none for a row past the
+        last.  A wait only needs a copy of the same size."""
+        def one(i, carry):
+            page = 0 if wait else pt_ref[row, g * group + i]
+            copy = pltpu.make_async_copy(
+                kv_ref.at[layer, page, 0, 0],
+                tile.at[slot, pl.ds(pl.multiple_of(i * page_size, page_size),
+                                    page_size)],
+                sem.at[slot])
+            copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, live_in(row, g), one, 0)
+
+    tiles, tiles_next = tiles_of(s), tiles_of(s + 1)
+
+    def ahead(g):
+        """The place in the walk two tiles after tile ``g`` of this row:
+        a row past the last has no tile, every other at least one."""
+        over = g + (TILES_IN_FLIGHT - 1) - tiles
+        here, next_row = over < 0, over < tiles_next
+        return (jnp.where(here, s, jnp.where(next_row, s + 1, s + 2)),
+                jnp.where(here, over + tiles, jnp.where(next_row, over, 0)))
+
+    @pl.when(s == 0)
+    def _first():
+        # columns of a tile that no copy has reached yet are multiplied by
+        # probabilities of exactly 0: they must hold numbers
+        tile[...] = jnp.zeros_like(tile)
+        walked[0] = 0
+        copies(0, 0, 0, wait=False)
+        copies(*ahead(-1), 1, wait=False)
+
     ctx_len = sp_ref[s] + 1
+    first = walked[0]
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(j * span < ctx_len)
-    def _attend():
-        q = q_ref[:]                                        # [H, W]
-        tile = jnp.concatenate([p[:] for p in pages], axis=0)  # [span, W]
+    def attend(g, slot, width):
+        """The first ``width`` columns of the tile in ``slot``."""
+        q = q_ref[...]                                      # [H, W]
         scores = jax.lax.dot_general(
-            q, tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [H, span]
-        ctx = j * span + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            q, tile[slot, pl.ds(0, width), :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [H, width]
+        ctx = g * span + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
         scores = jnp.where(ctx < ctx_len, scores, MASK_VALUE)
-        m_prev = m_scr[:]
+        m_prev = m_scr[...]                                 # [H, 128]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        pexp = jnp.exp(scores - m_new)
+        pexp = jnp.exp(scores - _lanes(m_new, width))
         alpha = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            pexp.astype(q.dtype), tile[:, :rank],
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, rank) + jnp.dot(
+            pexp.astype(q.dtype), tile[slot, pl.ds(0, width), pl.ds(0, rank)],
             preferred_element_type=jnp.float32)             # [H, rank]
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[:] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-                    ).astype(o_ref.dtype)
+    def walk(g, carry):
+        slot = jax.lax.rem(first + g, TILES_IN_FLIGHT)
+        copies(*ahead(g), jax.lax.rem(slot + (TILES_IN_FLIGHT - 1),
+                                      TILES_IN_FLIGHT), wait=False)
+        copies(s, g, slot, wait=True)
+        # a tile of no more than half its pages live (a row's last) is
+        # multiplied to half its columns; the mask ends the context inside
+        half = live_in(s, g) <= group // 2
+        pl.when(half)(functools.partial(attend, g, slot, span // 2))
+        pl.when(jnp.logical_not(half))(
+            functools.partial(attend, g, slot, span))
+        return carry
+
+    jax.lax.fori_loop(0, tiles, walk, 0)
+    walked[0] = first + tiles
+    o_ref[...] = (acc_scr[...] * _lanes(
+        1.0 / jnp.maximum(l_scr[...], 1e-30), rank)).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("rank", "sm_scale", "interpret"))
 def mla_decode_attention(q_abs: jax.Array, kv: jax.Array, layer,
                          page_table: jax.Array, start_pos: jax.Array, *,
                          rank: int, sm_scale: float,
                          interpret: bool = False) -> jax.Array:
     """Pallas absorbed decode: q_abs ``[S, H, W]`` (one new token a row),
     pool ``[L, P+1, 1, 1, page, W]``; returns ``[S, H, rank]``.  The
-    page ids ride the index maps through scalar prefetch; the pool is
-    passed once per page of a group, each with its own index map, so the
-    pipeline fetches a group's pages side by side."""
+    grid runs over rows; the page ids, the contexts and each row's count
+    of live pages ride scalar prefetch, the pool is left in HBM and the
+    kernel copies each row's live pages itself (``_decode_kernel``), so
+    a row costs what its own context costs whatever the page bucket
+    ``P`` of its step.  The rows run in order: a tile in flight belongs
+    to a later row.  Jitted, so that the kernel's body is traced once a
+    shape and not once a layer stack of every step program that has the
+    shape."""
     S, H, W = q_abs.shape
     page_size = kv.shape[4]
-    P_pages = page_table.shape[1]
-    group = next(g for g in (PAGES_PER_STEP, 4, 2, 1) if P_pages % g == 0)
-
-    def page_spec(g):
-        return pl.BlockSpec(
-            (None, None, None, None, page_size, W),
-            lambda s, j, l, pt, sp: (l[0], pt[s, j * group + g], 0, 0, 0, 0))
-
-    row = pl.BlockSpec((None, H, W), lambda s, j, l, pt, sp: (s, 0, 0))
-    out = pl.BlockSpec((None, H, rank), lambda s, j, l, pt, sp: (s, 0, 0))
+    group = PAGES_PER_STEP
+    start_pos = start_pos.astype(jnp.int32)
+    # no pages for the rows the copies look ahead to past the last one
+    live = jnp.pad(start_pos // page_size + 1, (0, TILES_IN_FLIGHT - 1))
+    row = pl.BlockSpec((None, H, W), lambda s, l, pt, sp, n: (s, 0, 0))
+    out = pl.BlockSpec((None, H, rank), lambda s, l, pt, sp, n: (s, 0, 0))
     return pl.pallas_call(
         functools.partial(_decode_kernel, page_size=page_size, group=group,
                           rank=rank, sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(S, P_pages // group),
-            in_specs=[row] + [page_spec(g) for g in range(group)],
+            num_scalar_prefetch=4, grid=(S,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=out,
-            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, rank), jnp.float32)]),
+            scratch_shapes=[
+                pltpu.VMEM((TILES_IN_FLIGHT, group * page_size, W), kv.dtype),
+                pltpu.SemaphoreType.DMA((TILES_IN_FLIGHT,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+                pltpu.VMEM((H, rank), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((S, H, rank), q_abs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name="mla_attention_decode",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      page_table.astype(jnp.int32), start_pos.astype(jnp.int32), q_abs,
-      *([kv] * group))
+      page_table.astype(jnp.int32), start_pos, live, q_abs, kv)
 
 
 def mla_paged_attention(q_abs: jax.Array, kv: jax.Array, layer,

@@ -67,14 +67,34 @@ def chip(topo):
     jax.config.update("jax_enable_compilation_cache", was_on)
 
 
-def compile_for_chip(fn, *shapes, kernel: str, calls: int = 1):
+def kernel_calls(text: str, kernel: str) -> list:
+    """The lines of a compiled program's text that are Mosaic custom calls
+    of the Pallas kernel named ``kernel``."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and kernel in line]
+
+
+def compile_for_chip(fn, *shapes, kernel: str, calls: int = 1) -> str:
     """Compile ``fn`` and require ``calls`` Mosaic custom calls of the
-    Pallas kernel named ``kernel`` in the executable's text."""
+    Pallas kernel named ``kernel`` in the executable's text, which is
+    returned."""
     text = jax.jit(fn).lower(*shapes).compile().as_text()
-    found = sum(1 for line in text.splitlines()
-                if 'custom_call_target="tpu_custom_call"' in line
-                and kernel in line)
+    found = len(kernel_calls(text, kernel))
     assert found == calls, (kernel, found)
+    return text
+
+
+def scoped_vmem_asked(text: str, kernel: str) -> list:
+    """What the Mosaic custom calls named ``kernel*`` of a compiled
+    program ASK for as their scoped-VMEM limit (``vmem_limit_bytes``): an
+    empty list a call under the default, which is what the program's text
+    shows as ``"scoped_memory_configs":[]`` (beside
+    ``used_scoped_memory_configs``, what the call was given)."""
+    calls = kernel_calls(text, kernel)
+    assert calls, kernel
+    return [re.search(r'"scoped_memory_configs":\[([^\]]*)\]', line).group(1)
+            for line in calls]
 
 
 # -- flash attention (the training default, and serving's fresh prefill) ----
@@ -379,10 +399,16 @@ def _latent_pool(chip, layers=5, pages=MLA_POOL):
     return chip((layers, pages + 1, 1, 1, PAGE, MLA_PLANE), jnp.bfloat16)
 
 
-@pytest.mark.parametrize("rows,pages", [(256, 32), (256, 8), (64, 64)])
+@pytest.mark.parametrize("rows,pages", [(256, 32), (256, 8), (64, 64),
+                                        (256, 64)])
 def test_mla_decode_kernel(chip, rows, pages):
+    """Rows of the cell's step programs at its page buckets; (256, 64) is
+    the bucket the cell's longest rows ask for.  Three ``[512, 640]``
+    tiles, the query, float32 scores and accumulator fit the DEFAULT
+    scoped VMEM: a latent step program that asked for more hung the chip
+    (``PERF.md`` section 6, PR 27)."""
     from deepspeed_tpu.ops.mla_attention import mla_paged_attention
-    compile_for_chip(
+    text = compile_for_chip(
         lambda q, kv, pt, sp, ql: mla_paged_attention(
             q, kv, jnp.int32(2), pt, sp, ql, rank=MLA_RANK, sm_scale=0.07,
             use_kernel=True),
@@ -390,6 +416,7 @@ def test_mla_decode_kernel(chip, rows, pages):
         _latent_pool(chip), chip((rows, pages), jnp.int32),
         chip((rows,), jnp.int32), chip((rows,), jnp.int32),
         kernel="mla_attention_decode")
+    assert scoped_vmem_asked(text, "mla_attention_decode") == [""]
 
 
 @pytest.mark.parametrize("rows,q,kernel", [
@@ -483,6 +510,8 @@ def test_pangu_step_program_moves_no_pool_and_no_expert_stack(
                    "moe_expert_ffn"):
         assert any('custom_call_target="tpu_custom_call"' in line
                    and kernel in line for line in text.splitlines()), kernel
+    # one call a layer stack (scanned), none asking past the default VMEM
+    assert set(scoped_vmem_asked(text, "mla_attention_decode")) == {""}
     expert_layer = 16 * 2048 * 7680 * 2
     assert expert_layer < int(np.prod(pool.shape[1:])) * 2
     assert pool_sized_movers(text, expert_layer) == []

@@ -245,6 +245,86 @@ def test_mla_decode_kernel_merges_groups_of_pages(P, contexts):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+def _walk_case(contexts, P, page, table="exact", padding=(), seed=0):
+    """Rows of one new token each whose contexts (the new token's
+    positions) are ``contexts``, over a pool of ``page``-token pages:
+    ``(args, kw)`` of ``mla_paged_attention``.  A row's table holds its own
+    pages and the null page 0 past them, as ``ragged/batch.py`` fills it;
+    the null page holds NaN, so a fetch of it that reaches the output shows.
+    ``table="full"`` fills every slot with a live page instead (stale ids
+    past the context must not be read either); the rows ``padding`` are a
+    batch's padding rows: position 0 over a table of null pages."""
+    rng = np.random.default_rng(seed)
+    S, H, rank, rope = len(contexts), 4, 24, 8
+    W = mla.plane_width(rank + rope)
+    pool = np.zeros((2, S * P + 1, 1, 1, page, W), np.float32)
+    pool[..., :rank + rope] = rng.normal(size=pool.shape[:-1] + (rank + rope,))
+    pt = (rng.permutation(S * P).reshape(S, P) + 1).astype(np.int32)
+    if table == "exact":
+        for s, c in enumerate(contexts):
+            pt[s, c // page + 1:] = 0
+        pool[:, 0] = np.nan
+    for s in padding:
+        assert contexts[s] == 0
+        pt[s] = 0
+    q = np.zeros((S, 1, H, W), np.float32)
+    q[..., :rank + rope] = rng.normal(size=(S, 1, H, rank + rope))
+    assert max(contexts) < P * page
+    return ((jnp.asarray(q), jnp.asarray(pool), 1, jnp.asarray(pt),
+             jnp.asarray(contexts, jnp.int32), jnp.ones(S, jnp.int32)),
+            dict(rank=rank, sm_scale=0.2))
+
+
+@pytest.mark.parametrize("contexts,P,page,table,padding", [
+    ((0,), 8, 64, "exact", ()),
+    ((0, 0, 0, 0, 0), 16, 64, "exact", ()),
+    ((63, 64, 127, 128), 8, 64, "exact", ()),
+    ((511, 512, 1023, 1024), 24, 64, "exact", ()),
+    ((5, 2047, 70, 1500, 0, 1100), 32, 64, "exact", ()),
+    ((2047, 3, 2047, 3), 32, 64, "full", ()),
+    ((700, 0, 0, 130, 0), 16, 64, "exact", (1, 2, 4)),
+    ((100, 767, 384, 500), 12, 64, "exact", ()),
+    ((40, 79, 5), 5, 16, "full", ()),
+    ((9, 150, 447), 7, 64, "full", ()),
+    ((0, 200, 255, 256), 4, 128, "exact", ()),
+], ids=["one-token", "one-token-rows", "page-edges", "tile-edges",
+        "short-beside-bucket-filling", "alternating-long-short",
+        "padding-rows", "bucket-of-12", "bucket-of-5-pages-of-16",
+        "bucket-of-7", "pages-of-128"])
+def test_mla_decode_kernel_walks_each_rows_own_pages(contexts, P, page, table,
+                                                     padding):
+    """The kernel's work follows a row's own context (``ops/
+    mla_attention.py::_decode_kernel``), so what can go wrong is the
+    walk: a context of one token; contexts ending on a page's or a tile's
+    edge and one token past it; a row of a page or two beside rows that
+    fill the page bucket (the copies run ahead across rows, three tiles
+    in flight); padding rows (``start_pos`` 0 over the null page), whose
+    own output is garbage by contract and which must leave their
+    neighbours' alone; page buckets that are no multiple of the 8 pages
+    a tile holds; and both widths a tile is multiplied to (half its
+    columns where no more of its pages are live, else all)."""
+    args, kw = _walk_case(contexts, P, page, table, padding)
+    want = mla.mla_paged_attention(*args[:1], jnp.nan_to_num(args[1]),
+                                   *args[2:], use_kernel=False, **kw)
+    got = mla.mla_paged_attention(*args, interpret=True, **kw)
+    read = [s for s in range(len(contexts)) if s not in padding]
+    assert np.isfinite(np.asarray(got)[read]).all()
+    np.testing.assert_allclose(np.asarray(got)[read], np.asarray(want)[read],
+                               atol=2e-5)
+
+
+def test_mla_decode_kernel_takes_a_row_for_what_its_context_costs():
+    """The page bucket is not in the work: the same rows under a table of
+    8, 32 and 64 slots give the same numbers, bit for bit (no tile exists
+    for a slot past the context)."""
+    args, kw = _walk_case((5, 300, 511), 64, 64)
+    outs = [np.asarray(mla.mla_paged_attention(
+        *args[:3], args[3][:, :P], *args[4:], interpret=True, **kw))
+        for P in (8, 32, 64)]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_array_equal(outs[0], outs[2])
+
+
 def test_mla_prefill_kernel_interpreted_matches_jnp():
     rng = np.random.default_rng(3)
     q, k = (jnp.asarray(rng.normal(size=(2, 16, 8, 24)), jnp.float32)
