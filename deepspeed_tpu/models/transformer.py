@@ -691,30 +691,45 @@ def _mlp_block(cfg: TransformerConfig, p, x):
     return _constrain(out, BATCH, "seq", None)
 
 
+#: the ``jax.named_scope``s of the training forward: ``embed`` and ``head``
+#: in ``forward``, ``attn`` and ``mlp`` in ``_layer_body``, ``loss`` in
+#: ``CausalLM.loss``.  Each names a module in every phase of a train step
+#: (JAX carries a scope through jvp and transpose); the engine reads them
+#: back from the compiled step (``CausalLM.scopes``,
+#: ``DeepSpeedEngine.step_scope_table``).
+MODULE_SCOPES = ("embed", "attn", "mlp", "head", "loss")
+
+
 def _layer_body(cfg: TransformerConfig, layer_params, x, sin, cos, mask,
                 mlp_fn=None, use_flash: bool = False, attn_bias=None,
                 use_ring: bool = False):
     """Returns (x, aux) — aux is 0 for dense MLPs, the load-balancing loss
     for MoE mlp_fns (accumulated through the layer scan)."""
-    h = _norm_apply(cfg, layer_params["norm1"], x)
-    attn_out = _attention_block(cfg, layer_params["attn"], h, sin, cos,
-                                mask, use_flash=use_flash,
-                                attn_bias=attn_bias, use_ring=use_ring)
+    # the scopes (MODULE_SCOPES) sit around the CALLS: the serving step
+    # imports _mlp_block / _norm_apply / apply_rope, whose bodies stay bare
+    with jax.named_scope("attn"):
+        h = _norm_apply(cfg, layer_params["norm1"], x)
+        attn_out = _attention_block(cfg, layer_params["attn"], h, sin, cos,
+                                    mask, use_flash=use_flash,
+                                    attn_bias=attn_bias, use_ring=use_ring)
     if cfg.parallel_residual:
         # GPT-NeoX: mlp sees ln2(x), both branches add to the SAME input
-        h2 = _norm_apply(cfg, layer_params["norm2"], x)
-        mlp_out = (mlp_fn or _mlp_block)(cfg, layer_params["mlp"], h2)
+        with jax.named_scope("mlp"):
+            h2 = _norm_apply(cfg, layer_params["norm2"], x)
+            mlp_out = (mlp_fn or _mlp_block)(cfg, layer_params["mlp"], h2)
         aux = jnp.zeros((), jnp.float32)
         if isinstance(mlp_out, tuple):
             mlp_out, aux = mlp_out
         return x + attn_out + mlp_out, aux
-    x = x + attn_out
-    h = _norm_apply(cfg, layer_params["norm2"], x)
-    mlp_out = (mlp_fn or _mlp_block)(cfg, layer_params["mlp"], h)
-    aux = jnp.zeros((), jnp.float32)
-    if isinstance(mlp_out, tuple):
-        mlp_out, aux = mlp_out
-    return x + mlp_out, aux
+    with jax.named_scope("attn"):
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        h = _norm_apply(cfg, layer_params["norm2"], x)
+        mlp_out = (mlp_fn or _mlp_block)(cfg, layer_params["mlp"], h)
+        aux = jnp.zeros((), jnp.float32)
+        if isinstance(mlp_out, tuple):
+            mlp_out, aux = mlp_out
+        return x + mlp_out, aux
 
 
 _REMAT_POLICIES = {
@@ -802,17 +817,18 @@ def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
     # the batch/seq constraint below is a cheap local slice (letting XLA
     # derive the output sharding from a vocab/fsdp-sharded table instead
     # triggers an involuntary full remat of the gathered activations).
-    table = _constrain(params["embed"]["tokens"].astype(cfg.dtype))
-    if cfg.sparse_gradients:
-        from ..runtime.sparse_tensor import embedding_lookup
-        x = embedding_lookup(table, input_ids)
-    else:
-        x = table[input_ids]
-    if cfg.pos_emb == "learned":
-        x = x + params["embed"]["positions"].astype(cfg.dtype)[positions]
-    if cfg.embed_layernorm:  # BLOOM word_embeddings_layernorm
-        x = _norm_apply(cfg, params["embed"]["norm"], x)
-    x = _constrain(x, BATCH, "seq", None)
+    with jax.named_scope("embed"):
+        table = _constrain(params["embed"]["tokens"].astype(cfg.dtype))
+        if cfg.sparse_gradients:
+            from ..runtime.sparse_tensor import embedding_lookup
+            x = embedding_lookup(table, input_ids)
+        else:
+            x = table[input_ids]
+        if cfg.pos_emb == "learned":
+            x = x + params["embed"]["positions"].astype(cfg.dtype)[positions]
+        if cfg.embed_layernorm:  # BLOOM word_embeddings_layernorm
+            x = _norm_apply(cfg, params["embed"]["norm"], x)
+        x = _constrain(x, BATCH, "seq", None)
 
     # mask: [B, S(q), S(k)]  (not needed on the flash path — the kernel
     # applies causality blockwise)
@@ -874,15 +890,16 @@ def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
             x, aux = fn(lp, bound(x), sin, cos, mask)
             aux_total = aux_total + aux
 
-    x = _norm_apply(cfg, params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bse,ve->bsv", x, params["embed"]["tokens"].astype(cfg.dtype))
-    else:
-        logits = jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(cfg.dtype))
-    if "lm_head_bias" in params:  # phi family ships an lm_head bias
-        logits = logits + params["lm_head_bias"].astype(cfg.dtype)
-    logits = _constrain(logits, BATCH, "seq", "tensor")
-    logits = logits.astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _norm_apply(cfg, params["final_norm"], x)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bse,ve->bsv", x, params["embed"]["tokens"].astype(cfg.dtype))
+        else:
+            logits = jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(cfg.dtype))
+        if "lm_head_bias" in params:  # phi family ships an lm_head bias
+            logits = logits + params["lm_head_bias"].astype(cfg.dtype)
+        logits = _constrain(logits, BATCH, "seq", "tensor")
+        logits = logits.astype(jnp.float32)
     if return_aux:
         return logits, aux_total
     return logits
@@ -918,6 +935,9 @@ class CausalLM:
     {'input_ids': [B,S] int32, optional 'labels' (default: shifted inputs),
     optional 'attention_mask'}."""
 
+    #: the named scopes ``loss`` enters, for the engine's scope table
+    scopes = MODULE_SCOPES
+
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
 
@@ -931,15 +951,17 @@ class CausalLM:
 
     def loss(self, params, batch, rng=None):
         logits = self.logits(params, batch, rng)
-        if "labels" in batch:
-            labels = batch["labels"]
-            return cross_entropy_loss(logits, labels,
-                                      batch.get("attention_mask"))
-        # next-token prediction: shift
-        labels = batch["input_ids"][:, 1:]
-        mask = batch.get("attention_mask")
-        return cross_entropy_loss(logits[:, :-1], labels,
-                                  mask[:, 1:] if mask is not None else None)
+        with jax.named_scope("loss"):
+            if "labels" in batch:
+                labels = batch["labels"]
+                return cross_entropy_loss(logits, labels,
+                                          batch.get("attention_mask"))
+            # next-token prediction: shift
+            labels = batch["input_ids"][:, 1:]
+            mask = batch.get("attention_mask")
+            return cross_entropy_loss(
+                logits[:, :-1], labels,
+                mask[:, 1:] if mask is not None else None)
 
 
 def layer_runs(cfg: TransformerConfig

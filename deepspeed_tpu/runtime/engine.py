@@ -38,6 +38,7 @@ import json
 import math
 import os
 import time
+import weakref
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import jax
@@ -49,9 +50,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import comm as dist
 from ..parallel.topology import (BATCH_AXES, MeshTopology, TopologyConfig)
-from ..telemetry import get_tracer, trace_span
+from ..telemetry import get_tracer, register_program, trace_span
 from ..telemetry import metrics as tm
 from ..telemetry.flight_recorder import get_flight_recorder
+from ..telemetry.program_scopes import scope_table
 from ..telemetry.state import state as telemetry_state
 from ..telemetry.watchdog import get_watchdog
 from ..utils.logging import log_dist, logger
@@ -66,6 +68,17 @@ from .optimizers import get_optimizer
 from .zero.partitioner import ZeroPartitioner, unbox
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
+
+#: the ``jax.named_scope``s ``_build_train_step::step_fn`` enters -> the phase
+#: of the step each names in the compiled program's metadata.  None: the
+#: scope holds the differentiated model, where JAX's own markers tell
+#: forward, backward and recomputed forward apart.  ``step_scope_table()``
+#: hands them to ``telemetry/program_scopes.py``; ``tests/test_span_tree.py``
+#: holds map and calls together.
+TRAIN_SCOPES = {"train.params": "params", "train.fwd_bwd": None,
+                "train.grad_reduce": "grad_reduce",
+                "train.grad_norm_clip": "grad_norm_clip",
+                "train.optimizer": "optimizer"}
 
 
 class TrainState(struct.PyTreeNode):
@@ -236,6 +249,9 @@ class DeepSpeedEngine:
 
         # -- step compilation ---------------------------------------------
         self._train_step = self._build_train_step()
+        #: [step, placed batch's shapes, its scope table or None] of the
+        #: step that last ran with telemetry on (``step_scope_table``)
+        self._scoped_step: Optional[list] = None
         self._eval_step = self._build_eval_step()
 
         # -- io/observability ---------------------------------------------
@@ -671,8 +687,14 @@ class DeepSpeedEngine:
             # ZeRO: compute params = cast(master) re-sharded to param layout.
             # stage>=1: this IS the post-step allgather of bf16 weights —
             # done in compute dtype so the wire carries 2-byte words.
-            params_c = constrain(cast_for_compute(state.params), param_specs)
+            # The train.* scopes (TRAIN_SCOPES) name the step's phases in
+            # the compiled program's metadata; step_scope_table() reads
+            # them back.
+            with jax.named_scope("train.params"):
+                params_c = constrain(cast_for_compute(state.params),
+                                     param_specs)
 
+            @jax.named_scope("train.fwd_bwd")
             def micro(carry, xs):
                 mb, mb_rng = xs
 
@@ -692,23 +714,28 @@ class DeepSpeedEngine:
                 # backward in a batch-axes-manual region => unreduced
                 # per-shard grads; the scheduler owns the reduction wire
                 mb, mb_rng = xs
-                loss, flat_local, direct = sched.backward(
-                    loss_fn, params_c, mb, mb_rng, state.loss_scale)
+                with jax.named_scope("train.fwd_bwd"):
+                    loss, flat_local, direct = sched.backward(
+                        loss_fn, params_c, mb, mb_rng, state.loss_scale)
                 if sched.overlap:
                     # reduce THIS micro-batch's buckets now: their
                     # collectives overlap the remaining buckets' quantize
                     # work and the next micro-batch's backward
                     acc, resid = carry
-                    flat_red, resid = sched.reduce(flat_local, resid,
-                                                   state.loss_scale)
-                    g = constrain(sched.combine(flat_red, direct), gspecs)
-                    acc = jax.tree.map(jnp.add, acc, g)
+                    with jax.named_scope("train.grad_reduce"):
+                        flat_red, resid = sched.reduce(flat_local, resid,
+                                                       state.loss_scale)
+                        g = constrain(sched.combine(flat_red, direct),
+                                      gspecs)
+                    with jax.named_scope("train.fwd_bwd"):
+                        acc = jax.tree.map(jnp.add, acc, g)
                     return (acc, resid), loss / state.loss_scale
                 # accumulate unreduced; one bucketed reduction at the
                 # gradient-accumulation boundary
                 acc_flat, acc_direct = carry
-                acc_flat = acc_flat + flat_local
-                acc_direct = jax.tree.map(jnp.add, acc_direct, direct)
+                with jax.named_scope("train.fwd_bwd"):
+                    acc_flat = acc_flat + flat_local
+                    acc_direct = jax.tree.map(jnp.add, acc_direct, direct)
                 return (acc_flat, acc_direct), loss / state.loss_scale
 
             if sched is None:
@@ -730,9 +757,11 @@ class DeepSpeedEngine:
                 def scaled_loss(p):
                     l = loss_fn(p, batch, rngs[0])
                     return (l * state.loss_scale).astype(jnp.float32)
-                loss, grads = jax.value_and_grad(scaled_loss)(params_c)
-                grads = constrain(
-                    jax.tree.map(lambda g: g.astype(acc_dtype), grads), gspecs)
+                with jax.named_scope("train.fwd_bwd"):
+                    loss, grads = jax.value_and_grad(scaled_loss)(params_c)
+                    grads = constrain(
+                        jax.tree.map(lambda g: g.astype(acc_dtype), grads),
+                        gspecs)
                 losses = (loss / state.loss_scale)[None]
             elif gas == 1:
                 carry, losses = micro_fn(
@@ -750,16 +779,18 @@ class DeepSpeedEngine:
                 grads, new_residuals = carry
             else:
                 acc_flat, acc_direct = carry
-                flat_red, new_residuals = sched.reduce(
-                    acc_flat, state.comm_residuals, state.loss_scale)
-                grads = constrain(sched.combine(flat_red, acc_direct),
-                                  gspecs)
-            inv = 1.0 / ((1 if fused_mb else gas) * state.loss_scale)
-            grads = jax.tree.map(lambda g: g * inv, grads)
-
-            # global grad norm (over ALL shards; XLA handles cross-device sum)
-            gnorm = optax.global_norm(grads)
-            finite = jnp.isfinite(gnorm)
+                with jax.named_scope("train.grad_reduce"):
+                    flat_red, new_residuals = sched.reduce(
+                        acc_flat, state.comm_residuals, state.loss_scale)
+                    grads = constrain(sched.combine(flat_red, acc_direct),
+                                      gspecs)
+            with jax.named_scope("train.grad_norm_clip"):
+                inv = 1.0 / ((1 if fused_mb else gas) * state.loss_scale)
+                grads = jax.tree.map(lambda g: g * inv, grads)
+                # global grad norm (over ALL shards; XLA handles the
+                # cross-device sum)
+                gnorm = optax.global_norm(grads)
+                finite = jnp.isfinite(gnorm)
             if sched is not None:
                 if fp16 and jax.tree.leaves(new_residuals):
                     # an overflow step quantizes inf gradients (absmax inf
@@ -771,8 +802,9 @@ class DeepSpeedEngine:
                         new_residuals, state.comm_residuals)
                 state = state.replace(comm_residuals=new_residuals)
             if clip > 0:
-                scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grads = jax.tree.map(lambda g: g * scale, grads)
+                with jax.named_scope("train.grad_norm_clip"):
+                    scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * scale, grads)
 
             def do_update(operand):
                 grads, state = operand
@@ -787,25 +819,26 @@ class DeepSpeedEngine:
                 return state.replace(step=state.step + 1, good_steps=jnp.zeros((), jnp.int32),
                                      skipped_steps=state.skipped_steps + 1)
 
-            if fp16:
-                new_state = jax.lax.cond(finite, do_update, skip_update, (grads, state))
-                if dynamic:
-                    # dynamic loss scale update (fp16/loss_scaler.py semantics,
-                    # incl. hysteresis: tolerate hysteresis-1 overflows before
-                    # lowering the scale)
-                    ls = new_state.loss_scale
-                    hy = new_state.hysteresis
-                    halve = (~finite) & (hy <= 1)
-                    hy = jnp.where(~finite & ~halve, hy - 1, hy)
-                    ls = jnp.where(halve, jnp.maximum(ls / 2.0, min_scale), ls)
-                    hy = jnp.where(halve, jnp.asarray(cfg.fp16.hysteresis, jnp.int32), hy)
-                    grow = (new_state.good_steps % scale_window == 0) & (new_state.good_steps > 0)
-                    ls = jnp.where(finite & grow, ls * 2.0, ls)
-                    hy = jnp.where(finite & grow,
-                                   jnp.asarray(cfg.fp16.hysteresis, jnp.int32), hy)
-                    new_state = new_state.replace(loss_scale=ls, hysteresis=hy)
-            else:
-                new_state = do_update((grads, state))
+            with jax.named_scope("train.optimizer"):
+                if fp16:
+                    new_state = jax.lax.cond(finite, do_update, skip_update, (grads, state))
+                    if dynamic:
+                        # dynamic loss scale update (fp16/loss_scaler.py semantics,
+                        # incl. hysteresis: tolerate hysteresis-1 overflows before
+                        # lowering the scale)
+                        ls = new_state.loss_scale
+                        hy = new_state.hysteresis
+                        halve = (~finite) & (hy <= 1)
+                        hy = jnp.where(~finite & ~halve, hy - 1, hy)
+                        ls = jnp.where(halve, jnp.maximum(ls / 2.0, min_scale), ls)
+                        hy = jnp.where(halve, jnp.asarray(cfg.fp16.hysteresis, jnp.int32), hy)
+                        grow = (new_state.good_steps % scale_window == 0) & (new_state.good_steps > 0)
+                        ls = jnp.where(finite & grow, ls * 2.0, ls)
+                        hy = jnp.where(finite & grow,
+                                       jnp.asarray(cfg.fp16.hysteresis, jnp.int32), hy)
+                        new_state = new_state.replace(loss_scale=ls, hysteresis=hy)
+                else:
+                    new_state = do_update((grads, state))
 
             metrics = {
                 "loss": jnp.mean(losses).astype(jnp.float32),
@@ -1092,6 +1125,14 @@ class DeepSpeedEngine:
         return self._shape_batch(batch)
 
     def _train_batch_impl(self, batch, data_iter) -> float:
+        # train.batch spans the whole call, so that every instant between
+        # two device steps lies under a span of the program: one of
+        # train.place_batch, train.step.dispatch, train.step.wait,
+        # train.after_step, or train.batch itself (entry checks, timers)
+        with trace_span("train.batch"):
+            return self._train_batch_spanned(batch, data_iter)
+
+    def _train_batch_spanned(self, batch, data_iter) -> float:
         self._check_not_destroyed()
         batch = self._resolve_batch(batch, data_iter)
 
@@ -1141,24 +1182,27 @@ class DeepSpeedEngine:
         if telemetry_state.enabled:
             get_tracer().set_step(self.global_steps)
             t_batch0 = time.perf_counter()
-        with trace_span("train.batch"), self.topology.mesh:
+        with self.topology.mesh:
             with trace_span("train.place_batch"), \
                     watchdog.track("input_wait"):
                 batch = self._place_batch(batch, microbatched=True)
             self._maybe_profile_flops(batch)
             # the fused step is ONE compiled program (fwd + bwd +
-            # collective flush + optimizer); the float() sync below is
-            # where the host blocks on it, so train.step covers dispatch
-            # + device execution.  Per-phase device attribution comes
-            # from the jax profiler (the span's TraceAnnotation lines
-            # host spans up with the device timeline).  Goodput: the
-            # first global step's wall time is compile+warmup (the jit
-            # trace happens under it), later steps bill the step phase.
+            # collective flush + optimizer): train.step.dispatch is its
+            # call (returns when it is enqueued), train.step.wait the
+            # float() sync where the host blocks on it.  Per-phase device
+            # attribution: the program's named scopes, which
+            # step_scope_table() reads back from its compiled text
+            # (telemetry/program_scopes.py).  Goodput: the first global
+            # step's wall time is compile+warmup (the jit trace happens
+            # under it), later steps bill the step phase.
             with trace_span("train.step"), watchdog.track(
                     "compile" if self.global_steps == 0 else "step"):
-                self.state, metrics, off_grads = self._train_step(
-                    self.state, batch, self._next_rng())
-                loss = float(metrics["loss"])
+                with trace_span("train.step.dispatch"):
+                    self.state, metrics, off_grads = self._train_step(
+                        self.state, batch, self._next_rng())
+                with trace_span("train.step.wait"):
+                    loss = float(metrics["loss"])
             # overflow skip exists only under fp16 loss scaling — the
             # device path updates unconditionally in bf16 mode, and the
             # host must mirror it exactly or the two halves desync
@@ -1168,6 +1212,15 @@ class DeepSpeedEngine:
                         watchdog.track("step"):
                     self._apply_offload_step(off_grads,
                                              float(metrics["applied_lr"]))
+        with trace_span("train.after_step"):
+            self._after_step(batch, loss, metrics, t_batch0, fi, watchdog)
+        return loss
+
+    def _after_step(self, batch, loss, metrics, t_batch0, fi,
+                    watchdog) -> None:
+        """What train_batch does between the loss's fetch and its return:
+        the host's bookkeeping, with the device idle until the next step
+        is dispatched."""
         from ..tools.tensor_logger import record_active
         # iteration stays the caller's (log_iteration/set_iteration)
         record_active("model_inputs", "batch", batch)
@@ -1196,6 +1249,19 @@ class DeepSpeedEngine:
                     watchdog.note_nonfinite("grad_norm",
                                             self.global_steps,
                                             self._last_grad_norm)
+            if self._scoped_step is None \
+                    or self._scoped_step[0] is not self._train_step:
+                # once per built step: which program ran and on what
+                # shapes (the table is filled in by step_scope_table();
+                # nothing is lowered here).  The registry holds the engine
+                # weakly: a dropped engine is not kept alive by its table.
+                self._scoped_step = [self._train_step, jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype, sharding=x.sharding), batch), None]
+                me = weakref.ref(self)
+                register_program(
+                    "train.step",
+                    lambda: me() and me().step_scope_table())
         self.global_steps += 1
         self._maybe_apply_compression()
         self.micro_steps += self.gradient_accumulation_steps()
@@ -1222,7 +1288,6 @@ class DeepSpeedEngine:
         if self.config.wall_clock_breakdown and \
                 self.global_steps % self.config.steps_per_print == 0:
             self.timers.log([TRAIN_BATCH_TIMER])
-        return loss
 
     def _maybe_profile_flops(self, placed_batch) -> None:
         """Print the flops-profiler report at the configured step
@@ -1252,6 +1317,28 @@ class DeepSpeedEngine:
         with self.topology.mesh:
             return self._train_step.lower(self.state, placed_batch,
                                           jax.random.key(0))
+
+    def step_scope_table(self) -> Optional[dict]:
+        """``{instruction: (phase, module)}`` of the train step program
+        that last ran with telemetry on (``telemetry/program_scopes.py``
+        has the format), read from the program's own compiled text; None
+        before such a step.  The compile is a load from the cache the step
+        itself came out of; the table is kept per built step.  Published
+        as ``telemetry.program_table("train.step")``, which evaluates it
+        when somebody reads: never inside ``train_batch``."""
+        noted = self._scoped_step
+        if noted is None or self.state is None:
+            return None
+        if noted[2] is None:
+            step, shapes, _ = noted
+            # the noted step, not whatever ``_train_step`` is by now; a
+            # fixed key, as in ``_lower_placed``
+            with self.topology.mesh:
+                text = step.lower(self.state, shapes,
+                                  jax.random.key(0)).compile().as_text()
+            noted[2] = scope_table(text, TRAIN_SCOPES,
+                                   getattr(self.module, "scopes", ()))
+        return noted[2]
 
     def lower_train_step(self, batch):
         """The fused train step lowered for ``batch`` (a

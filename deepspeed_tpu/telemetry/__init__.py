@@ -31,7 +31,8 @@ from .server import (maybe_start_from_env,  # noqa: F401
                      start_http_server, stop_http_server)
 from .state import state  # noqa: F401
 from .tracer import (SpanTracer, dump_trace,  # noqa: F401
-                     get_tracer, trace_span)
+                     get_tracer, program_table, register_program,
+                     trace_span)
 from .watchdog import Watchdog, get_watchdog  # noqa: F401
 from .flight_recorder import (FlightRecorder,  # noqa: F401
                               dump_postmortem, get_flight_recorder,

@@ -253,6 +253,26 @@ def trace_span(name: str, attrs: Optional[Dict[str, Any]] = None):
     return _Span(name, attrs)
 
 
+#: step programs that can say which scope each of their instructions
+#: belongs to: name -> a thunk giving the table of
+#: ``telemetry/program_scopes.py`` (or None).  Registered by the program
+#: while telemetry is on; evaluated by whoever reads, after the fact.
+_PROGRAMS: Dict[str, Any] = {}
+
+
+def register_program(name: str, thunk) -> None:
+    """Publish a step program's scope table under ``name`` as a thunk:
+    registering lowers, compiles and parses nothing."""
+    _PROGRAMS[name] = thunk
+
+
+def program_table(name: str) -> Optional[Dict[str, Any]]:
+    """The scope table registered under ``name``, evaluated now (the
+    program caches it), or None where nothing was registered."""
+    thunk = _PROGRAMS.get(name)
+    return thunk() if thunk is not None else None
+
+
 def dump_trace(path: str) -> str:
     """Export the process ring buffer as Chrome-trace JSON."""
     return _TRACER.dump(path)

@@ -646,3 +646,50 @@ def test_a_model_with_a_state_pool_carries_the_state_pools_names():
     for name in ("ssm_state_update_decode", "ssm_scan_prefill"):
         assert re.search("^ssm_", name)
         assert not any(re.search(p, name) for p in patterns), name
+
+
+# ---------------------------------------------------------------------------
+# the train step's scopes and spans (PR 37)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("scope, phase", [
+    ("train.params", "params"), ("train.fwd_bwd", None),
+    ("train.grad_reduce", "grad_reduce"),
+    ("train.grad_norm_clip", "grad_norm_clip"),
+    ("train.optimizer", "optimizer")])
+def test_the_train_steps_scopes_are_the_programs_contract(scope, phase):
+    """A scope the step program enters is a name of the map the engine
+    hands to ``telemetry/program_scopes.py``, and the other way round:
+    ``train_update_time_share`` and its four siblings read the phases
+    (inside ``train.fwd_bwd`` JAX's own markers decide)."""
+    import inspect
+
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine, TRAIN_SCOPES
+    assert TRAIN_SCOPES[scope] == phase and len(TRAIN_SCOPES) == 5
+    assert f'named_scope("{scope}")' in inspect.getsource(
+        DeepSpeedEngine._build_train_step)
+
+
+@pytest.mark.parametrize("span", ["train.step.dispatch", "train.step.wait",
+                                  "train.after_step"])
+def test_the_train_steps_spans_reach_the_profilers_trace(span):
+    """The three spans ``train_batch`` gained: recorded by bare name, which
+    the benchmark's trace reduction keeps (``idle_ms_per_step.train`` reads
+    the idle time under ``^train\\.``); ``tests/test_train_scopes.py`` holds
+    the tree."""
+    import inspect
+    import json
+    import os
+    import re
+
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    source = inspect.getsource(DeepSpeedEngine._train_batch_spanned)
+    assert f'trace_span("{span}")' in source
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmark.trace_reduce import HOST_SPAN
+    assert HOST_SPAN.match(span)
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "idle_ms_per_step.train.json")) as f:
+        patterns = json.load(f)["args"]["patterns"]
+    assert any(re.search(p, span) for p in patterns)
