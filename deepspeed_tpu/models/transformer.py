@@ -24,6 +24,7 @@ implementations ``inference/v2/model_implementations/``):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, List, Optional, Tuple
@@ -35,6 +36,7 @@ from flax.core import meta
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ..accelerator import on_tpu
 from ..parallel.topology import BATCH_AXES as BATCH  # batch-dim mesh axes
 
 
@@ -137,7 +139,11 @@ class TransformerConfig:
     dropout: float = 0.0
     scan_layers: bool = True
     remat: bool = True
-    remat_policy: str = "nothing_saveable"
+    # what a layer's checkpoint keeps for the backward (resolve_remat_policy
+    # lists the names).  "auto": the richest rung of REMAT_RUNGS that fits
+    # the memory an engine reports at trace time (remat_budget), and
+    # nothing_saveable where nobody reports any
+    remat_policy: str = "auto"
     # reference activation_checkpointing.partition_activations
     # (checkpointing.py:487): saved layer-boundary residuals are sharded
     # along the sequence dim over the model-parallel axes, 1/(sp*tp)
@@ -620,6 +626,15 @@ def _attention_block(cfg: TransformerConfig, p, x, sin, cos, mask,
     if cfg.pos_emb == "rope":
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
+    # what attention reads, for the policies that keep it (_NAMED_POLICIES):
+    # K and V at their own head count, before any repeat up to H heads, and
+    # all three as the projections left them.  Measured at the train cell's
+    # shapes (PERF.md, PR 38): keeping them in the flash kernels' layout
+    # instead costs 0.6 ms a layer more, keeping K and V repeated 200 MB a
+    # layer more for no time
+    q = checkpoint_name(q, "attn_q")
+    k = checkpoint_name(k, "attn_k")
+    v = checkpoint_name(v, "attn_v")
     if use_ring:
         # ring CP: tokens STAY seq-sharded; no head resharding at all
         q = _constrain(q, BATCH, "seq", None, None)
@@ -656,9 +671,10 @@ def _attention_block(cfg: TransformerConfig, p, x, sin, cos, mask,
         out = flash_dot_product_attention(cfg, q, k, v)
     else:
         out = dot_product_attention(cfg, q, k, v, mask, attn_bias)
-    # named for the save_attn_out remat policy: saving attention outputs
-    # (cheap: [B,S,H,D]) lets the backward skip re-running the flash
-    # kernel while everything else still rematerializes
+    # the output as the projection below reads it.  Where the flash
+    # KERNEL ran, the policies keep its own out and lse instead
+    # (ops/flash_attention.RESIDUAL_NAMES; resolve_remat_policy) and this
+    # one is a transpose away
     out = checkpoint_name(out, "attn_out")
     # Ulysses return leg, staged: go heads-(seq+tensor) -> (S over seq,
     # H over tensor) FIRST — a single plannable all-to-all — so the wo
@@ -722,7 +738,7 @@ def _layer_body(cfg: TransformerConfig, layer_params, x, sin, cos, mask,
             mlp_out, aux = mlp_out
         return x + attn_out + mlp_out, aux
     with jax.named_scope("attn"):
-        x = x + attn_out
+        x = checkpoint_name(x + attn_out, "attn_residual")
     with jax.named_scope("mlp"):
         h = _norm_apply(cfg, layer_params["norm2"], x)
         mlp_out = (mlp_fn or _mlp_block)(cfg, layer_params["mlp"], h)
@@ -738,32 +754,189 @@ _REMAT_POLICIES = {
     "dots_saveable": jax.checkpoint_policies.dots_saveable,
     "dots_with_no_batch_dims_saveable":
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    # save per-layer attention outputs only: the backward never re-runs
-    # the (expensive) flash kernel, everything else rematerializes —
-    # trades B*S*E per layer of HBM for ~30% of the recompute FLOPs
-    "save_attn_out": jax.checkpoint_policies.save_only_these_names(
-        "attn_out"),
 }
 
+#: The policies that keep NAMED values of a layer beside its input, by what
+#: they keep beyond attention's output.  Where the flash kernel ran, "the
+#: output" is the kernel's own ``out`` and ``lse`` in the kernel's layout
+#: (``ops/flash_attention.RESIDUAL_NAMES``): the backward kernels read those,
+#: so keeping the [B,S,H,D] ``attn_out`` instead (what ``save_attn_out`` did
+#: before PR 38) left the recomputed forward with its flash call.
+#:   save_attn_out       the recomputed forward runs no flash kernel (with
+#:                       it) / no probs x V (einsum, ring); 68 MB a layer at
+#:                       Mistral-7B widths and 8,192 tokens a device, for
+#:                       1.7 ms of a layer's 92.6 (forward + backward alone
+#:                       on one v5e chip; PERF.md, PR 38)
+#:   save_attn           and none of the q/k/v projections and ropes: q and k
+#:                       after rope and v are kept too (K/V at their own head
+#:                       count, before the GQA repeat); 169 MB for 4.9 ms
+#:   save_attn_residual  nor the output projection: ``x + attn_out`` is kept
+#:                       too; 236 MB for 6.3 ms.  Left to recompute: the two
+#:                       norms, the MLP's gate and up, the activation
+#: The einsum and ring paths have no ``lse``: they keep q, k, v and the
+#: output, and recompute the scores and the softmax.
+_QKV = ("attn_q", "attn_k", "attn_v")
+_NAMED_POLICIES = {"save_attn_out": (), "save_attn": _QKV,
+                   "save_attn_residual": _QKV + ("attn_residual",)}
 
-def resolve_remat_policy(name: str):
+#: what ``remat_policy="auto"`` chooses among, poorest first
+REMAT_RUNGS = ("nothing_saveable", "save_attn", "save_attn_residual")
+
+
+def resolve_remat_policy(name: str, flash_kernel: bool = False):
     """Remat-policy lookup incl. the host-offload variants backing the
     reference's ``cpu_checkpointing`` (checkpointing.py:487): checkpoints
     are saved to pinned host memory and fetched back for the backward,
-    trading HBM for PCIe/host traffic exactly like the CUDA path."""
+    trading HBM for PCIe/host traffic exactly like the CUDA path.
+    ``flash_kernel``: attention runs the Pallas kernel, whose output has
+    names of its own."""
+    if name == "auto":      # nobody reported a budget (remat_budget)
+        name = REMAT_RUNGS[0]
     if name in _REMAT_POLICIES:
         return _REMAT_POLICIES[name]
+    if flash_kernel:
+        from ..ops.flash_attention import RESIDUAL_NAMES as output
+    else:
+        output = ("attn_out",)
+    if name in _NAMED_POLICIES:
+        return jax.checkpoint_policies.save_only_these_names(
+            *output, *_NAMED_POLICIES[name])
     if name == "offload_attn_out":
         return jax.checkpoint_policies.save_and_offload_only_these_names(
             names_which_can_be_saved=[],
-            names_which_can_be_offloaded=["attn_out"],
+            names_which_can_be_offloaded=list(output),
             offload_src="device", offload_dst="pinned_host")
     if name == "offload_dots":
         return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
             "device", "pinned_host")
     raise ValueError(
         f"unknown remat policy {name!r}; known: "
-        f"{sorted(_REMAT_POLICIES) + ['offload_attn_out', 'offload_dots']}")
+        f"{sorted([*_REMAT_POLICIES, *_NAMED_POLICIES, 'auto', 'offload_attn_out', 'offload_dots'])}")
+
+
+# -- remat_policy="auto": the richest rung the device's memory holds ---------
+
+#: kept free beside everything the rule below reckons, for the compiler's
+#: scheduler: a ZeRO-3 step left with under ~1 GB gathers its weights late.
+#: The train cell (PERF.md, PR 38) with rung 2 compiles to 0.91 GB under the
+#: chip's limit and runs 2.3% SLOWER than with rung 1 (1.35 GB under), which
+#: is the rung this margin lets the rule take there
+REMAT_MARGIN_BYTES = 750_000_000
+
+
+def remat_rung_bytes(cfg: TransformerConfig, tokens: int,
+                     tensor_shards: int = 1) -> Dict[str, int]:
+    """Bytes a layer each rung of REMAT_RUNGS keeps on a device that holds
+    ``tokens`` tokens of a micro-batch, beside the layer's input: q and the
+    output (H heads), k and v (K heads), the float32 log-sum-exp a query
+    head and token; rung 2 the residual after attention too."""
+    c, d = jnp.dtype(cfg.dtype).itemsize, cfg.dims_per_head
+    heads, kv = (-(-n // tensor_shards) for n in (cfg.num_heads, cfg.kv_heads))
+    attn = tokens * (d * (2 * heads + 2 * kv) * c + heads * 4)
+    return dict(zip(REMAT_RUNGS,
+                    (0, attn, attn + tokens * cfg.hidden_size * c)))
+
+
+def remat_working_set(cfg: TransformerConfig, tokens: int,
+                      grads_bytes: int = 0, tensor_shards: int = 1) -> int:
+    """Bytes a train step holds on a device beside its state, its parameter
+    copy and a rung's residuals, reckoned from the shapes: the layers'
+    inputs (what every policy keeps), and the larger of the loss's moment
+    (the logits in float32 and once in the compute dtype; the embedding and
+    the head gathered whole) and a layer's backward (the gradients of the
+    stack, ``grads_bytes``; the layer's recomputed values and their
+    cotangents as far as they live at once, about two MLP-wide and four
+    model-wide arrays; the weights of two layers gathered whole, one in use
+    and one in flight).  Held to the chip compiler's own count for the
+    train cell's step in ``tests/test_chip_compile.py`` (PERF.md, PR 38:
+    2% over it at 12 layers, 6% at 2)."""
+    c, e = jnp.dtype(cfg.dtype).itemsize, cfg.hidden_size
+    f, v = (-(-n // tensor_shards)
+            for n in (cfg.intermediate_size, cfg.vocab_size))
+    boundaries = cfg.num_layers * tokens * e * c
+    loss = tokens * v * (4 + c) + \
+        (1 if cfg.tie_embeddings else 2) * cfg.vocab_size * e * c
+    h, k, d = cfg.num_heads, cfg.kv_heads, cfg.dims_per_head
+    layer_weights = (2 * e * (h + k) * d
+                     + e * f * (3 if "gated" in cfg.activation else 2)) * c
+    layer = grads_bytes + tokens * (2 * f + 4 * e) * c + 2 * layer_weights
+    return boundaries + max(loss, layer)
+
+
+def choose_remat_policy(cfg: TransformerConfig, tokens: int,
+                        budget_bytes: int, tensor_shards: int = 1
+                        ) -> Tuple[str, int]:
+    """(the richest rung of REMAT_RUNGS whose residuals over all layers fit
+    ``budget_bytes``, the bytes a layer it keeps).  No budget: today's."""
+    rungs = remat_rung_bytes(cfg, tokens, tensor_shards)
+    fits = [name for name in REMAT_RUNGS
+            if cfg.num_layers * rungs[name] <= max(budget_bytes, 0)]
+    return fits[-1], rungs[fits[-1]]
+
+
+@dataclasses.dataclass
+class RematBudget:
+    """What an engine sees of ONE device's memory when it builds a train
+    step (bytes), and what the model made of it when the step was traced.
+    ``limit_bytes`` 0: the backend reports no limit (the CPU), and "auto"
+    stays ``nothing_saveable``."""
+    limit_bytes: int = 0
+    state_bytes: int = 0    # what the engine holds there between steps
+    params_bytes: int = 0   # the compute-dtype copy of its parameter shard
+    grads_bytes: int = 0    # the gradients alive while layers run backward
+    # -- written by ``choose`` (None: no trace asked) --
+    policy: Optional[str] = None
+    layer_bytes: int = 0
+    budget_bytes: int = 0   # what was left for residuals, all layers
+
+    def choose(self, cfg: TransformerConfig, tokens: int,
+               tensor_shards: int = 1) -> str:
+        if self.limit_bytes:
+            self.budget_bytes = (
+                self.limit_bytes - self.state_bytes - self.params_bytes
+                - remat_working_set(cfg, tokens, self.grads_bytes,
+                                    tensor_shards) - REMAT_MARGIN_BYTES)
+        self.policy, self.layer_bytes = choose_remat_policy(
+            cfg, tokens, self.budget_bytes, tensor_shards)
+        if self.limit_bytes:
+            from ..utils.logging import log_dist
+            log_dist(
+                f"remat_policy auto -> {self.policy}: {self.layer_bytes} B "
+                f"a layer x {cfg.num_layers} of {self.budget_bytes} B free "
+                f"for residuals ({tokens} tokens a device; limit "
+                f"{self.limit_bytes}, state {self.state_bytes}, parameter "
+                f"copy {self.params_bytes}, gradients {self.grads_bytes}, "
+                f"margin {REMAT_MARGIN_BYTES})", ranks=[0])
+        return self.policy
+
+
+_BUDGET: List[RematBudget] = []
+
+
+@contextlib.contextmanager
+def remat_budget(budget: RematBudget):
+    """While a loss is traced under it, ``remat_policy="auto"`` is
+    ``budget.choose(...)`` on the shapes the trace sees."""
+    _BUDGET.append(budget)
+    try:
+        yield budget
+    finally:
+        _BUDGET.pop()
+
+
+def _layer_policy(cfg: TransformerConfig, rows: int, seq: int,
+                  use_flash: bool):
+    """The checkpoint policy of the layer stack for a [rows, seq] batch."""
+    name = cfg.remat_policy
+    if name == "auto" and _BUDGET:
+        # the share of the batch one device holds, under the ambient mesh
+        mesh, shards, tensor = _ambient_mesh(), 1, 1
+        if mesh is not None:
+            for a in (*BATCH, "seq"):
+                shards *= mesh.shape.get(a, 1)
+            tensor = mesh.shape.get("tensor", 1)
+        name = _BUDGET[-1].choose(cfg, -(-rows * seq // shards), tensor)
+    return resolve_remat_policy(name, flash_kernel=use_flash and on_tpu())
 
 
 def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
@@ -868,6 +1041,7 @@ def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
     def bound(y):
         return _constrain(y, BATCH, part_axes, None) if part_axes else y
 
+    policy = _layer_policy(cfg, b, s, use_flash) if cfg.remat else None
     aux_total = jnp.zeros((), jnp.float32)
     if cfg.scan_layers:
         def scan_body(carry, layer_params):
@@ -875,7 +1049,6 @@ def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
             y, aux = body(layer_params, x, sin, cos, mask)
             return (bound(y), aux_acc + aux), None
         if cfg.remat:
-            policy = resolve_remat_policy(cfg.remat_policy)
             scan_body = jax.checkpoint(scan_body, policy=policy,
                                        prevent_cse=False)
         (x, aux_total), _ = jax.lax.scan(scan_body, (bound(x), aux_total),
@@ -885,8 +1058,7 @@ def forward(cfg: TransformerConfig, params, input_ids: jax.Array,
             lp = params["layers"][f"layer_{i}"]
             fn = body
             if cfg.remat:
-                fn = jax.checkpoint(body, policy=resolve_remat_policy(cfg.remat_policy),
-                                    prevent_cse=False)
+                fn = jax.checkpoint(body, policy=policy, prevent_cse=False)
             x, aux = fn(lp, bound(x), sin, cos, mask)
             aux_total = aux_total + aux
 

@@ -343,8 +343,8 @@ def _flash_attention(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
 def _flash_attention_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                          window):
-    out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                          window)
+    out, lse = _named_residuals(*_flash_fwd(q, k, v, sm_scale, causal, block_q,
+                                            block_k, interpret, window))
     return out, (q, k, v, out, lse)
 
 
@@ -392,3 +392,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                             _fit_block(block_q, q.shape[2]),
                             _fit_block(block_k, k.shape[2]),
                             interpret, window)
+
+
+# ---------------------------------------------------------------------------
+# what a jax.checkpoint around the caller may keep
+# ---------------------------------------------------------------------------
+# (Below the entry point on purpose: serving's step programs carry this
+# file's line numbers in their Mosaic kernels, so lines added above
+# ``flash_attention`` would start one serving process cold.)
+
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+
+#: The forward kernel's two outputs as the backward kernels take them,
+#: ``out`` [B,H,S,D] and ``lse`` [B,H,1,S].  A remat policy that saves both
+#: names (``models/transformer.py::resolve_remat_policy``) leaves the
+#: recomputed forward without the kernel: a ``pallas_call`` is dropped whole
+#: or not at all, so a policy that keeps ``out`` alone, or ``out`` in another
+#: layout, runs it a second time.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
+def _named_residuals(out, lse):
+    return tuple(map(checkpoint_name, (out, lse), RESIDUAL_NAMES))

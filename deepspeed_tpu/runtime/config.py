@@ -578,7 +578,9 @@ class TPUConfig(DeepSpeedConfigModel):
     # scan over homogeneous transformer layers (compile time + remat unit)
     scan_layers: bool = True
     remat: bool = True
-    remat_policy: str = "nothing_saveable"  # maps to jax.checkpoint policies
+    # jax.checkpoint policy of a layer; "auto": the richest that fits the
+    # device's memory (models/transformer.py::REMAT_RUNGS)
+    remat_policy: str = "auto"
     # attention implementation: auto (flash when the mask allows it) |
     # flash (force) | einsum (dense reference path)
     attention_impl: str = "auto"

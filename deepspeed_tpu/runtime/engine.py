@@ -248,6 +248,9 @@ class DeepSpeedEngine:
         self._grad_acc_buffer: List[Any] = []
 
         # -- step compilation ---------------------------------------------
+        #: built step -> the memory it was built under, and what the
+        #: model chose to keep of a layer (``_remat_budget``)
+        self._step_budgets = weakref.WeakKeyDictionary()
         self._train_step = self._build_train_step()
         #: [step, placed batch's shapes, its scope table or None] of the
         #: step that last ran with telemetry on (``step_scope_table``)
@@ -530,26 +533,25 @@ class DeepSpeedEngine:
         # its Partitioned nodes sit exactly where unboxed array leaves sit,
         # so the resulting sharding tree matches the unboxed param treedef.
         master_sh = self.partitioner.master_shardings(self._abstract_params)
-
-        def make_state(p):
-            opt_state = self.optimizer.init(p)
-            return TrainState(
-                step=jnp.zeros((), jnp.int32),
-                params=p,
-                opt_state=opt_state,
-                loss_scale=jnp.asarray(self._initial_loss_scale(), jnp.float32),
-                good_steps=jnp.zeros((), jnp.int32),
-                skipped_steps=jnp.zeros((), jnp.int32),
-                hysteresis=jnp.asarray(self.config.fp16.hysteresis, jnp.int32),
-                comm_residuals=(self.comm_scheduler.init_residuals()
-                                if self.comm_scheduler is not None else ()))
-
-        abstract = jax.eval_shape(make_state, params)
+        abstract = jax.eval_shape(self._make_state, params)
         state_sh = self._state_shardings(abstract, master_sh)
         with self.topology.mesh:
-            state = jax.jit(make_state, out_shardings=state_sh)(params)
+            state = jax.jit(self._make_state, out_shardings=state_sh)(params)
         self._state_shardings_cache = state_sh
         return state
+
+    def _make_state(self, p) -> TrainState:
+        """The train state over master parameters ``p`` (traced)."""
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=p,
+            opt_state=self.optimizer.init(p),
+            loss_scale=jnp.asarray(self._initial_loss_scale(), jnp.float32),
+            good_steps=jnp.zeros((), jnp.int32),
+            skipped_steps=jnp.zeros((), jnp.int32),
+            hysteresis=jnp.asarray(self.config.fp16.hysteresis, jnp.int32),
+            comm_residuals=(self.comm_scheduler.init_residuals()
+                            if self.comm_scheduler is not None else ()))
 
     def _state_shardings(self, abstract_state, master_sh):
         """Shardings for the full TrainState: params & their optimizer
@@ -632,7 +634,6 @@ class DeepSpeedEngine:
         clip = cfg.gradient_clipping
         fp16 = self._fp16_enabled
         compute_dtype = self.compute_dtype
-        loss_fn = self._loss_fn
         optimizer = self.optimizer
         partitioner = self.partitioner
         mesh = self.topology.mesh
@@ -672,6 +673,17 @@ class DeepSpeedEngine:
             return jax.tree.map(
                 lambda x, s: jax.lax.with_sharding_constraint(x, NamedSharding(mesh, s)),
                 tree, specs)
+
+        # The loss is traced under what this engine sees of a device's
+        # memory: a model whose layers are checkpointed under
+        # remat_policy="auto" picks what they keep from it and from the
+        # shapes of the trace, and writes its choice back (step_scope_table)
+        from ..models.transformer import remat_budget
+        budget, model_loss = self._remat_budget(), self._loss_fn
+
+        def loss_fn(params, batch, rng):
+            with remat_budget(budget):
+                return model_loss(params, batch, rng)
 
         # Pipeline mode: the loss_fn consumes the whole [gas, micro, ...]
         # batch in one pipelined evaluation (no outer micro-batch scan).
@@ -880,10 +892,52 @@ class DeepSpeedEngine:
                                   if isinstance(s, NamedSharding) else x),
                     new_state, state_sh)
                 return new_state, metrics, off
-            return jax.jit(constrained_step, donate_argnums=donate)
-        return jax.jit(step_fn,
-                       out_shardings=(state_sh, None, None),
-                       donate_argnums=donate)
+            step = jax.jit(constrained_step, donate_argnums=donate)
+        else:
+            step = jax.jit(step_fn, out_shardings=(state_sh, None, None),
+                           donate_argnums=donate)
+        self._step_budgets[step] = budget
+        return step
+
+    def _remat_budget(self):
+        """One device's memory as this engine sees it when it builds a
+        step (``models/transformer.py::RematBudget``): the device's limit,
+        the state arrays the engine holds there, and what a step makes of
+        every parameter whatever the model does with them: the copy in the
+        compute dtype under the parameters' layout, and the gradients
+        under theirs (in the compute dtype as the backward emits them; the
+        accumulator's too when micro-batches accumulate).  No limit (the
+        CPU): nothing is reckoned."""
+        from ..accelerator import get_accelerator
+        from ..models.transformer import RematBudget
+        limit = get_accelerator().total_memory()
+        if not limit:
+            return RematBudget()
+        mesh = self.topology.mesh
+
+        def on_device(x, sharding, itemsize):
+            return math.prod(sharding.shard_shape(x.shape)) * itemsize
+
+        def under(specs, itemsize):
+            return sum(jax.tree.leaves(jax.tree.map(
+                lambda x, s: on_device(x, NamedSharding(mesh, s), itemsize)
+                if jnp.issubdtype(x.dtype, jnp.floating) else 0,
+                self.state.params, specs)))
+
+        part, cfg = self.partitioner, self.config
+        compute = jnp.dtype(self.compute_dtype).itemsize
+        grads = compute
+        if cfg.gradient_accumulation_steps > 1:
+            grads += jnp.dtype(jnp.float32 if cfg.bf16.accumulate_grads_in_fp32
+                               else self.compute_dtype).itemsize
+        return RematBudget(
+            limit_bytes=limit,
+            state_bytes=sum(on_device(x, x.sharding, x.dtype.itemsize)
+                            for x in jax.tree.leaves(self.state)),
+            params_bytes=under(
+                part.tree_param_specs(self._abstract_params), compute),
+            grads_bytes=under(
+                part.tree_grad_specs(self._abstract_params), grads))
 
     def _batch_leaf_sharding(self, leaf, microbatched: bool) -> NamedSharding:
         """Rank-aware sharding for a batch leaf: batch dim over the batch
@@ -1338,6 +1392,16 @@ class DeepSpeedEngine:
                                   jax.random.key(0)).compile().as_text()
             noted[2] = scope_table(text, TRAIN_SCOPES,
                                    getattr(self.module, "scopes", ()))
+            # what the layers' checkpoint keeps in this program, and why:
+            # the policy the trace took (a configured one, or "auto"'s
+            # choice), the bytes a layer and device it reckoned for it and
+            # the bytes it saw free for all layers' (0: nothing reckoned)
+            budget = self._step_budgets[step]
+            noted[2].update(
+                remat_policy=budget.policy or getattr(
+                    getattr(self.module, "cfg", None), "remat_policy", None),
+                remat_layer_bytes=budget.layer_bytes,
+                remat_budget_bytes=budget.budget_bytes)
         return noted[2]
 
     def lower_train_step(self, batch):

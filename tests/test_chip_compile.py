@@ -725,3 +725,194 @@ def test_jamba_step_program_moves_no_pool_and_no_weight_stack(
                         "bf16[8,131,5120]") for m in moved), moved
     assert stack_shaped_movers(text, params) == []
     assert compiled.memory_analysis().temp_size_in_bytes < mamba_layer
+
+
+# -- the train cell's step: what the layers' checkpoint keeps (PR 38) --------
+
+TRAIN_LAYERS, TRAIN_ROWS = 2, 4
+#: one device's limit under which the rule (models/transformer.py::
+#: RematBudget) buys the richest rung for the two-layer step and no more
+#: than 0.3 GB beyond it (the cell's own twelve layers under the chip's
+#: 15.75 GiB buy rung 1)
+TRAIN_LIMIT = 6_100_000_000
+
+
+@pytest.fixture(scope="module")
+def train_step_programs(topo, chip):
+    """``compiled(policy)`` -> (the compiled train step of the benchmark's
+    train cell at two layers on the described four chips under that
+    ``remat_policy``, its scope table as the engine exports it); each
+    compiled once.  ZeRO-3 over ``{fsdp: 4}``, bf16 under fp32 masters and
+    AdamW, 4 rows x 2048 tokens a chip: ``benchmark/configs/
+    mistral-7b-zero3-fsdp4.json``'s ``train`` block, state as shapes."""
+    from unittest import mock
+
+    from deepspeed_tpu.accelerator import get_accelerator, real_accelerator
+    from deepspeed_tpu.models.llama import LlamaForCausalLM
+    from deepspeed_tpu.parallel.topology import MeshTopology, TopologyConfig
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    from deepspeed_tpu.runtime.zero.partitioner import unbox
+
+    def shapes(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
+
+    class ShapesEngine(DeepSpeedEngine):
+        """An engine whose parameters and state are shapes on the described
+        chips: it builds and lowers its step and runs nothing."""
+
+        def _init_params(self):
+            self._abstract_params = jax.eval_shape(self.module.init_params,
+                                                   self._rng)
+            return self._abstract_params
+
+        def _init_state(self, params):
+            params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                x.shape, self.master_dtype), unbox(params))
+            state = jax.eval_shape(self._make_state, params)
+            self._state_shardings_cache = self._state_shardings(
+                state, self.partitioner.master_shardings(
+                    self._abstract_params))
+            return shapes(state, self._state_shardings_cache)
+
+    done = {}
+
+    def compiled(policy):
+        if policy in done:
+            return done[policy]
+        model = LlamaForCausalLM(
+            "7b", intermediate_size=14336, num_kv_heads=KV_HEADS,
+            sliding_window=4096, num_layers=TRAIN_LAYERS, max_seq_len=SEQ,
+            vocab_size=32000,
+            **({} if policy == "auto" else {"remat_policy": policy}))
+        kept = []
+        real_compile = jax.stages.Lowered.compile
+        with mock.patch.object(real_accelerator, "device_platform",
+                               lambda: "tpu"), \
+                mock.patch.object(type(get_accelerator()), "total_memory",
+                                  lambda self, index=None: TRAIN_LIMIT), \
+                mock.patch.object(
+                    jax.stages.Lowered, "compile",
+                    lambda low: kept.append(real_compile(low)) or kept[-1]):
+            engine = ShapesEngine(
+                model=model, rng=jax.random.key(0),
+                topology=MeshTopology(TopologyConfig(fsdp=4),
+                                      devices=topo.devices),
+                config={"train_micro_batch_size_per_gpu": TRAIN_ROWS,
+                        "gradient_accumulation_steps": 1,
+                        "optimizer": {"type": "adamw",
+                                      "params": {"lr": 1e-4}},
+                        "zero_optimization": {"stage": 3},
+                        "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+                        "checkpoint": {"async_save": False}})
+            ids = np.zeros((1, 4 * TRAIN_ROWS, SEQ), np.int32)
+            # as a step that ran with telemetry on notes itself
+            engine._scoped_step = [engine._train_step, {
+                "input_ids": jax.ShapeDtypeStruct(
+                    ids.shape, ids.dtype,
+                    sharding=engine._batch_leaf_sharding(ids, True))}, None]
+            table = engine.step_scope_table()
+        done[policy] = kept[-1], table
+        return done[policy]
+    return compiled
+
+
+def _train_program_facts(compiled, table):
+    """(flash forward calls by phase, matmul instructions by (phase,
+    module)) of a compiled train step, read through its scope table."""
+    import collections
+    from deepspeed_tpu.telemetry import program_scopes
+    text = compiled.as_text()
+    comps, _ = program_scopes._computations(text)
+    opcode = {name: op for body in comps.values() for name, op, *_ in body}
+    line = {m.group(1): ln for ln in text.splitlines()
+            for m in [_HLO_LINE.match(ln) or re.match(
+                r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", ln)] if m}
+    flash, matmuls = collections.Counter(), collections.Counter()
+    for name, (phase, module) in table["instructions"].items():
+        if kernel_calls(line.get(name, ""), "flash_attention_fwd"):
+            flash[phase] += 1
+        called = re.search(r"calls=%?([\w.\-]+)", line.get(name, ""))
+        ops = [opcode.get(name)] + [
+            op for _, op, *_ in comps.get(called.group(1), [])] \
+            if called else [opcode.get(name)]
+        if any(op in ("dot", "convolution") for op in ops):
+            matmuls[phase, module] += 1
+    return flash, matmuls
+
+
+@pytest.mark.parametrize("policy", ["save_attn_out", "save_attn", "auto"])
+def test_train_step_keeps_what_its_policy_names(train_step_programs, policy):
+    """The compiled step of the train cell (two layers) under each policy
+    that keeps named values, against ``nothing_saveable``: which attention
+    work is left in the recomputed forward, what the residuals cost in the
+    chip compiler's own count against the rule's, and that nothing re-lays
+    a stack of them out."""
+    from deepspeed_tpu.models import transformer as T
+    base, base_table = train_step_programs("nothing_saveable")
+    compiled, table = train_step_programs(policy)
+    assert not table["stale"] and not base_table["stale"]
+
+    # today's default: every layer's forward twice, the flash kernel too
+    flash, matmuls = _train_program_facts(base, base_table)
+    assert (flash["forward"], flash["recompute"]) == (1, 1)
+    assert matmuls["recompute", "attn"] == 4 and matmuls["recompute", "mlp"] == 2
+    assert (base_table["remat_policy"], base_table["remat_layer_bytes"],
+            base_table["remat_budget_bytes"]) == ("nothing_saveable", 0, 0)
+
+    # one flash forward a layer and pass whatever is kept beyond it; the
+    # MLP's gate and up stay recomputed
+    flash, matmuls = _train_program_facts(compiled, table)
+    assert (flash["forward"], flash["recompute"]) == (1, 0)
+    assert matmuls["recompute", "mlp"] == 2
+    assert matmuls["forward", "attn"] == 4 and matmuls["forward", "mlp"] == 3
+    model_cfg = T.TransformerConfig(
+        vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+        num_layers=TRAIN_LAYERS, num_heads=HEADS, num_kv_heads=KV_HEADS)
+    tokens = TRAIN_ROWS * SEQ
+    rungs = T.remat_rung_bytes(model_cfg, tokens)
+    if policy == "save_attn_out":
+        # out and lse alone: the projections and ropes run again
+        assert matmuls["recompute", "attn"] == 4
+        kept = tokens * HEADS * (HEAD_DIM * 2 + 4)
+        assert table["remat_policy"] == "save_attn_out"
+    elif policy == "save_attn":
+        # rung 1: the output projection alone
+        assert matmuls["recompute", "attn"] == 1
+        kept = rungs["save_attn"]
+        assert table["remat_policy"] == "save_attn"
+    else:
+        # "auto" under TRAIN_LIMIT: the richest rung, no attention matmul
+        assert matmuls["recompute", "attn"] == 0
+        kept = rungs["save_attn_residual"]
+        assert (table["remat_policy"], table["remat_layer_bytes"]) \
+            == ("save_attn_residual", kept)
+        need = TRAIN_LAYERS * kept
+        assert need <= table["remat_budget_bytes"] < need + 300_000_000
+
+    # the chip compiler's count grows by what the rule reckons, within a
+    # fifth ...
+    def peak(c):
+        return c.memory_analysis().peak_memory_in_bytes
+    grown = peak(compiled) - peak(base)
+    assert 0.8 * TRAIN_LAYERS * kept <= grown <= 1.2 * TRAIN_LAYERS * kept
+    # ... and the rule's whole reckoning of the step is the compiler's peak
+    # within a fifth (the state, the parameter copy, the working set)
+    held = peak(base) - T.remat_working_set(
+        model_cfg, tokens, grads_bytes=_train_shard_bytes(model_cfg, 2))
+    state = _train_shard_bytes(model_cfg, 12 + 2)
+    assert 0.8 * state <= held <= 1.2 * state
+    # no copy or transpose yields a whole stack of residuals
+    stacks = {f"{TRAIN_LAYERS},{TRAIN_ROWS},{dims}"
+              for h in (HEADS, KV_HEADS)
+              for dims in (f"{SEQ},{h},{HEAD_DIM}", f"{h},{SEQ},{HEAD_DIM}")} \
+        | {f"{TRAIN_LAYERS},{TRAIN_ROWS},{SEQ},{HEADS * HEAD_DIM}",
+           f"{TRAIN_LAYERS},{TRAIN_ROWS},{HEADS},1,{SEQ}"}
+    assert [m for m in pool_sized_movers(compiled.as_text(), 0)
+            if m[1] != "dynamic-update-slice" and "dynamic-update-slice"
+            not in m[0] and m[2][m[2].index("[") + 1:-1] in stacks] == []
+
+
+def _train_shard_bytes(cfg, bytes_a_parameter, shards=4):
+    """Bytes of one device's share of the model's matmul parameters."""
+    return cfg.n_params() // shards * bytes_a_parameter

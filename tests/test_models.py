@@ -1,8 +1,11 @@
 """Model tests: forward shape/dtype, training convergence with ZeRO+TP+SP
 shardings over the 8-device mesh."""
 
+import functools
+
 import numpy as np
 import pytest
+
 import jax
 import jax.numpy as jnp
 
@@ -146,30 +149,150 @@ class TestTraining:
         assert losses[-1] < losses[0]
 
 
-def test_save_attn_out_remat_policy():
-    """The save_attn_out policy must trace and match other policies'
-    loss (remat changes scheduling, not math)."""
-    import dataclasses
-    from deepspeed_tpu.models.llama import LlamaForCausalLM
+# what a layer's checkpoint keeps must not change the mathematics: every
+# policy that saves NAMED values (and "auto" under a budget that buys the
+# richest rung) against nothing_saveable, on every attention path that
+# names them its own way
+ATTENTION_PATHS = {
+    "flash": dict(),                               # the jnp reference on CPU
+    "flash_kernel": dict(),                        # the Pallas kernel, interpreted
+    "einsum": dict(attention_impl="einsum"),
+    "ring": dict(sp_mode="ring"),
+    "unrolled": dict(scan_layers=False),
+    "parallel_residual": dict(parallel_residual=True),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _tiny_llama(scan_layers=True):
     from flax.core import meta
-    m = LlamaForCausalLM("tiny")
-    params = meta.unbox(m.init_params(jax.random.key(0)))
-    ids = np.arange(2 * 16, dtype=np.int32).reshape(2, 16) % m.cfg.vocab_size
+    m = LlamaForCausalLM("tiny", num_layers=2, scan_layers=scan_layers)
+    return m.cfg, meta.unbox(m.init_params(jax.random.key(0)))
 
-    def loss_with(policy):
-        cfg = dataclasses.replace(m.cfg, dtype=jnp.float32,
-                                  remat_policy=policy)
-        def f(p):
-            logits = forward(cfg, p, ids)
-            return jnp.mean(logits ** 2)
-        l, g = jax.value_and_grad(f)(params)
-        return float(l), g
 
-    l_ref, g_ref = loss_with("nothing_saveable")
-    l_new, g_new = loss_with("save_attn_out")
+def _loss_and_grads(path, policy, monkeypatch):
+    import contextlib
+    import dataclasses
+    import importlib
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.models import transformer as T
+    fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+    base, params = _tiny_llama(
+        ATTENTION_PATHS[path].get("scan_layers", True))
+    cfg = dataclasses.replace(base, dtype=jnp.float32, remat_policy=policy,
+                              **ATTENTION_PATHS[path])
+    ids = np.arange(2 * 16, dtype=np.int32).reshape(2, 16) % cfg.vocab_size
+    mesh = contextlib.nullcontext()
+    if path == "ring":
+        from deepspeed_tpu.parallel.topology import (MeshTopology,
+                                                     TopologyConfig)
+        mesh = MeshTopology(TopologyConfig(data=1, seq=2),
+                            devices=jax.devices()[:2]).mesh
+    with monkeypatch.context() as patch:
+        if path == "flash_kernel":
+            # trace what the chip traces: the kernel (interpreted here) and
+            # the names of its own outputs
+            patch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+            patch.setattr(fa, "flash_attention", functools.partial(
+                fa.flash_attention, interpret=True))
+        # a budget that buys every rung: what "auto" makes of it
+        budget = T.RematBudget(budget_bytes=1 << 40)
+        with mesh, (T.remat_budget(budget) if policy == "auto"
+                    else contextlib.nullcontext()):
+            l, g = jax.jit(jax.value_and_grad(
+                lambda p: jnp.mean(forward(cfg, p, ids) ** 2)))(params)
+    if policy == "auto":
+        assert budget.policy == T.REMAT_RUNGS[-1] and budget.layer_bytes > 0
+    return float(l), g
+
+
+_REFERENCE = {}
+
+
+@pytest.mark.parametrize("policy", ["save_attn_out", "save_attn",
+                                    "save_attn_residual", "auto"])
+@pytest.mark.parametrize("path", sorted(ATTENTION_PATHS))
+def test_save_attn_out_remat_policy(path, policy, monkeypatch):
+    """The policies that keep named values must trace on every attention
+    path and match nothing_saveable's loss and gradients (remat changes
+    scheduling, not math)."""
+    if path not in _REFERENCE:
+        _REFERENCE[path] = _loss_and_grads(path, "nothing_saveable",
+                                           monkeypatch)
+    l_ref, g_ref = _REFERENCE[path]
+    l_new, g_new = _loss_and_grads(path, policy, monkeypatch)
     assert abs(l_ref - l_new) < 1e-5
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5), g_ref, g_new)
+
+
+MISTRAL_7B = dict(hidden_size=4096, intermediate_size=14336, num_heads=32,
+                  num_kv_heads=8, num_layers=12, vocab_size=32000)
+
+
+def test_remat_rule_is_a_pure_function_of_shapes_and_memory():
+    """Mistral-7B widths at 8,192 tokens a device (the train cell): the
+    rungs' bytes a layer, the choice by budget, and what overrides it."""
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.llama import llama_config
+    cfg = llama_config("tiny", **MISTRAL_7B)
+    assert cfg.remat_policy == "auto"
+    rungs = T.remat_rung_bytes(cfg, 8192)
+    assert [round(rungs[r] / 1e6) for r in T.REMAT_RUNGS] == [0, 169, 236]
+    # heads shard over 'tensor', the residual after attention does not
+    halves = T.remat_rung_bytes(cfg, 8192, tensor_shards=2)
+    assert halves["save_attn"] * 2 == rungs["save_attn"]
+    assert round(halves["save_attn_residual"] / 1e6) == 169 // 2 + 67 + 1
+
+    # a budget just under a rung's bytes over all layers buys the rung
+    # below, just over buys the rung; none buys today's
+    for below, rung in zip(T.REMAT_RUNGS, T.REMAT_RUNGS[1:]):
+        need = cfg.num_layers * rungs[rung]
+        assert T.choose_remat_policy(cfg, 8192, need - 1) \
+            == (below, rungs[below])
+        assert T.choose_remat_policy(cfg, 8192, need) == (rung, rungs[rung])
+    for none in (0, -5_000_000_000):
+        assert T.choose_remat_policy(cfg, 8192, none) \
+            == ("nothing_saveable", 0)
+
+    # the budget is the limit less what the engine holds, what a step makes
+    # of every parameter, the working set and the margin ...
+    seen = dict(limit_bytes=16_900_000_000, state_bytes=8_640_000_000,
+                params_bytes=1_440_000_000, grads_bytes=1_440_000_000)
+    work = T.remat_working_set(cfg, 8192, seen["grads_bytes"])
+    budget = T.RematBudget(**seen)
+    assert budget.choose(cfg, 8192) in T.REMAT_RUNGS
+    assert budget.budget_bytes == 16_900_000_000 - 8_640_000_000 \
+        - 1_440_000_000 - work - T.REMAT_MARGIN_BYTES
+    assert (budget.policy, budget.layer_bytes) == T.choose_remat_policy(
+        cfg, 8192, budget.budget_bytes)
+    # ... which holds the layers' inputs and grows with the tokens
+    assert work > cfg.num_layers * 8192 * 4096 * 2 + seen["grads_bytes"]
+    assert T.remat_working_set(cfg, 16384, seen["grads_bytes"]) > work
+    # no limit (the CPU): nothing is reckoned, today's policy
+    blind = T.RematBudget(state_bytes=1)
+    assert blind.choose(cfg, 8192) == "nothing_saveable"
+    assert (blind.layer_bytes, blind.budget_bytes) == (0, 0)
+
+
+@pytest.mark.parametrize("configured", ["nothing_saveable", "save_attn_out",
+                                        "dots_saveable"])
+def test_a_configured_remat_policy_is_never_overridden(configured):
+    """Under a budget that buys every rung, a policy somebody wrote down
+    stays: the budget is not even asked."""
+    import dataclasses
+    from deepspeed_tpu.models import transformer as T
+    base, params = _tiny_llama()
+    cfg = dataclasses.replace(base, remat_policy=configured)
+    ids = np.zeros((2, 16), np.int32)
+    budget = T.RematBudget(budget_bytes=1 << 40)
+    with T.remat_budget(budget):
+        jax.eval_shape(jax.grad(lambda p: jnp.mean(forward(cfg, p, ids))),
+                       params)
+        assert budget.policy is None
+        jax.eval_shape(jax.grad(lambda p: jnp.mean(forward(
+            dataclasses.replace(cfg, remat_policy="auto"), p, ids))), params)
+    assert budget.policy == "save_attn_residual"
 
 
 def test_learned_positions_ignore_padding():

@@ -70,11 +70,33 @@ def instructions_of(engine, batch):
 # (a), (b): the scopes are in the compiled program
 # ---------------------------------------------------------------------------
 
+def recomputed_matmuls(ins):
+    """{module: matmuls of the recomputed forward}"""
+    return collections.Counter(m for op, p, m in ins.values()
+                               if p == "recompute"
+                               and op in ("dot", "convolution"))
+
+
 @pytest.mark.parametrize("policy, recomputes", [
-    ("nothing_saveable", True), ("everything_saveable", False)])
+    ("nothing_saveable", True), ("everything_saveable", False),
+    # the rungs of "auto" (PR 38): on the CPU attention is the jnp
+    # reference, which has no log-sum-exp to keep, so the scores are
+    # recomputed; the compile for the described chip
+    # (tests/test_chip_compile.py) holds the kernel's side
+    ("save_attn", True), ("save_attn_residual", True)])
 def test_every_phase_and_module_has_instructions(policy, recomputes):
     ins, table = instructions_of(*engine_of(remat_policy=policy))
     assert not table["stale"]
+    if policy.startswith("save_attn"):
+        # q k v and the output are kept: of attention's six matmuls the
+        # recomputed forward runs the scores and, on rung 1, the output
+        # projection; the MLP's gate and up as under nothing_saveable
+        base = recomputed_matmuls(instructions_of(
+            *engine_of(remat_policy="nothing_saveable"))[0])
+        kept = recomputed_matmuls(ins)
+        assert base["attn"] == 6 and base["mlp"] == kept["mlp"] == 2
+        assert kept["attn"] == {"save_attn": 2, "save_attn_residual": 1}[
+            policy]
     phases = collections.Counter(p for _, p, _ in ins.values())
     modules = collections.Counter(m for _, _, m in ins.values())
     want = {"params", "forward", "backward", "grad_norm_clip", "optimizer"}
@@ -107,6 +129,51 @@ def test_every_phase_and_module_has_instructions(policy, recomputes):
 def test_remat_off_recomputes_nothing():
     ins, _ = instructions_of(*engine_of(remat=False))
     assert not any(p == "recompute" for _, p, _ in ins.values())
+
+
+@pytest.mark.parametrize("configured, limit, chosen", [
+    (None, 1 << 30, "save_attn_residual"),  # "auto" with room for a rung
+    (None, 1 << 20, "nothing_saveable"),    # ... under a limit with none
+    (None, 0, "nothing_saveable"),          # ... no limit reported: the CPU
+    ("nothing_saveable", 1 << 30, "nothing_saveable"),
+    ("dots_saveable", 1 << 30, "dots_saveable")])
+def test_the_table_says_what_the_layers_checkpoint_keeps(
+        monkeypatch, configured, limit, chosen):
+    """The engine hands the model what it sees of a device's memory, the
+    model picks a rung when the step is traced, and the step's table says
+    which, with the bytes a layer and the budget; a configured policy is
+    never overridden and nothing is reckoned for it."""
+    from deepspeed_tpu.accelerator import get_accelerator
+    from deepspeed_tpu.models import transformer as T
+    monkeypatch.setattr(type(get_accelerator()), "total_memory",
+                        lambda self, index=None: limit)
+    engine, batch = engine_of(
+        **({"remat_policy": configured} if configured else {}))
+    telemetry.enable()
+    first = engine.train_batch(batch)
+    table = engine.step_scope_table()
+    assert table["remat_policy"] == chosen
+    auto_with_room = configured is None and limit == 1 << 30
+    if auto_with_room:
+        # 2 rows x 64 tokens a device of the debug Llama
+        cfg = engine.module.cfg
+        assert cfg.remat_policy == "auto"
+        assert table["remat_layer_bytes"] == T.remat_rung_bytes(
+            cfg, 2 * 64)["save_attn_residual"]
+        assert cfg.num_layers * table["remat_layer_bytes"] \
+            <= table["remat_budget_bytes"] < limit
+    else:
+        assert table["remat_layer_bytes"] == 0
+        assert (table["remat_budget_bytes"] == 0) == (
+            configured is not None or not limit)
+    assert any(p == "recompute" for p, _ in table["instructions"].values())
+    # the same step, whatever is kept of it (to bf16's rounding: a kept
+    # value is rounded where a fused one need not be)
+    monkeypatch.setattr(type(get_accelerator()), "total_memory",
+                        lambda self, index=None: 0)
+    telemetry.disable()
+    plain, _ = engine_of(remat_policy="nothing_saveable")
+    assert first == pytest.approx(plain.train_batch(batch), rel=1e-3)
 
 
 # ---------------------------------------------------------------------------
@@ -322,6 +389,10 @@ def test_with_telemetry_on_the_step_has_its_tree_and_its_table(monkeypatch):
     phases = {p for p, _ in table["instructions"].values()}
     assert {"params", "forward", "recompute", "backward", "grad_norm_clip",
             "optimizer"} <= phases
+    # ... and what the layers' checkpoint keeps in this program (PR 38):
+    # "auto" where the backend reports no memory limit is today's
+    assert (table["remat_policy"], table["remat_layer_bytes"],
+            table["remat_budget_bytes"]) == ("nothing_saveable", 0, 0)
     # reading it consumed none of the training's randomness and left the
     # state alone: the next step is the step it would have been
     assert np.isfinite(engine.train_batch(batch))
