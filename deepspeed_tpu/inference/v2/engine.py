@@ -170,9 +170,9 @@ class InferenceEngineV2:
             if max(tp, model.tp_degree) > 1:
                 raise ValueError(
                     "inference/v2/engine.py: a state pool cannot be served "
-                    "under tp_degree > 1 yet (ops/ssm.py's pool and its "
-                    "kernels are not sharded over d_inner) — use "
-                    "tp_degree=1")
+                    "under tp_degree > 1 yet (the pool and the kernels of "
+                    "ops/ssm.py and ops/delta_rule.py are not sharded over "
+                    "d_inner or heads) — use tp_degree=1")
             if (getattr(sv_, "kv_quantization", "none") or "none") != "none":
                 raise ValueError(
                     "inference/v2/engine.py: a model with a state pool has "
@@ -388,6 +388,14 @@ class InferenceEngineV2:
         self._draft_kv = None
         self._draft_seen: Dict[int, int] = {}
         self._attended = (0, 0)
+        #: whether the decode rows' contexts are summed for the step span
+        #: (``take_attended``; the scheduler asks this too): a model whose
+        #: full layers are some of its layers only and whose roofline is
+        #: counted from them (two page groups; delta-rule layers beside
+        #: full ones)
+        self.counts_attended = self._state.window_cache is not None or (
+            model.state_config is not None
+            and model.state_config.kind == "delta")
         #: (previous token vector's length, slots) -> the compiled gather
         #: of a decode segment's token ids (``_form_gathers``)
         self._gathers: Dict[Tuple[int, int], object] = {}
@@ -1129,7 +1137,7 @@ class InferenceEngineV2:
                 table=self._table,
                 scratch_slot=(self._state.state_pool.scratch
                               if self._state.state_pool is not None else 0))
-            if self._state.window_cache is not None and batch.max_q == 1:
+            if self.counts_attended and batch.max_q == 1:
                 # what the decode rows attend in a layer of each kind
                 # (the scheduler's live span carries it: take_attended)
                 ctx = batch.start_pos[:len(batch.uids)] + 1
@@ -1147,7 +1155,8 @@ class InferenceEngineV2:
         """(tokens, tokens inside the window) that the decode rows of the
         steps built since the last call attend, summed over rows: a full
         layer's and a window layer's context (a model of two page
-        groups; (0, 0) otherwise)."""
+        groups, or of delta-rule layers beside full ones, whose window
+        count is 0; (0, 0) otherwise)."""
         out, self._attended = self._attended, (0, 0)
         return out
 

@@ -43,7 +43,7 @@ from ...telemetry.workload_trace import get_workload_trace
 from ...utils.compile_cache import thread_cache_counts
 from .lattice import POWER_LATTICE
 from .ragged import KVCacheConfig, RaggedBatch
-from .ragged.cache_kinds import CACHE_KINDS, TableLayout
+from .ragged.cache_kinds import CACHE_KINDS, TableLayout, slot_kind
 from .ragged.kv_cache import StatePoolConfig
 from .step_key import (STEP_KINDS, StepKey, step_avals, step_program,
                        trunk_params)
@@ -225,9 +225,10 @@ class RaggedInferenceModel:
         #: full layers' K/V in ``kv_config``'s pool, its window layers' in
         #: this one, ``_forward_hidden_kinds``); None: one page group
         self.window_kv_config: Optional[KVCacheConfig] = None
-        #: the state pool (a model with state-space layers: a slot a
-        #: sequence instead of pages, ``ops/ssm.py``); None: every layer
-        #: kind caches pages.  The engine sizes ``num_slots``
+        #: the state pool (a model with state-space or delta-rule layers:
+        #: a slot a sequence instead of pages, of the shape the kind
+        #: declares; ``ops/ssm.py``, ``ops/delta_rule.py``); None: every
+        #: layer kind caches pages.  The engine sizes ``num_slots``
         self.state_config: Optional[StatePoolConfig] = None
         import dataclasses
         # layers by what their kind caches (cache_kinds.py): a page
@@ -240,10 +241,12 @@ class RaggedInferenceModel:
             self.kv_config = dataclasses.replace(
                 self.kv_config, num_layers=layers["full"])
         if layers["slot"]:
+            kind = slot_kind(cfg.layer_kinds)
+            state, tail = CACHE_KINDS[kind].slot_shape(cfg)
             self.state_config = StatePoolConfig(
-                num_layers=layers["slot"], d_state=cfg.ssm_state_dim,
-                d_inner=cfg.ssm_inner, d_conv=cfg.ssm_conv,
-                state_dtype=cfg.ssm_state_dtype, conv_dtype=cfg.dtype)
+                num_layers=layers["slot"], state=state, tail=tail,
+                kind=kind, state_dtype=cfg.ssm_state_dtype,
+                conv_dtype=cfg.dtype)
         if layers["window"]:
             heads = dict(cfg.heads_by_kind)
             self.window_kv_config = window_kv_config or dataclasses.replace(
@@ -893,7 +896,7 @@ class RaggedInferenceModel:
         if cfg.latent_dim:
             return self._forward_hidden_latent(params, kv, segments, cfg,
                                                stats_out)
-        if cfg.ssm_state_dim:
+        if self.state_config is not None:
             return self._forward_hidden_state(params, kv, segments, cfg)
         if cfg.layer_kinds:
             return self._forward_hidden_kinds(params, kv, segments, cfg,
@@ -1489,8 +1492,9 @@ class RaggedInferenceModel:
                 else (x, full, pool, stats))
 
     def _forward_hidden_state(self, params, kv, segments, cfg):
-        """The trunk of a model with state-space layers beside attention
-        layers (a third layer kind): ONE scan over the whole periods of
+        """The trunk of a model with layers that hold a slot of the state
+        pool (state-space "ssm" or delta-rule "delta": ``cache_kinds.py``)
+        beside attention layers: ONE scan over the whole periods of
         the layer pattern whose body is the period's RUNS of like layers,
         each run of several a scan of its own (7 Mamba, the attention
         layer, 6 Mamba: a program holds two Mamba bodies and one
@@ -1522,7 +1526,7 @@ class RaggedInferenceModel:
                                  segments=paged, rows=rows)
         runs, periods, tail = layer_runs(cfg)
         per = {kind: sum(n for k, n in runs if k == kind)
-               for kind in ("full", "ssm")}
+               for kind in dict.fromkeys(cfg.layer_kinds)}
         carry = (x, *kv)
 
         stacks = params["layers"]
@@ -1559,18 +1563,25 @@ class RaggedInferenceModel:
 
     def _layer_body_state(self, carry, lp, *, kind, at, segments, rows,
                           cfg):
-        """One pre-norm layer of kind ``kind`` over (x, the page pool, the
-        state pool's two arrays): the kind's mixer at layer ``at`` of its
-        own pool, then the llama block's SwiGLU."""
+        """One layer of kind ``kind`` over (x, the page pool, the state
+        pool's two arrays): the kind's mixer at layer ``at`` of its own
+        pool, then the llama block's SwiGLU; each behind a norm of its
+        input, or under ``cfg.post_norm`` before a norm of its output."""
         x, pages, h_pool, conv_pool = carry
-        h = self._norm(lp["norm1"], x)
-        if kind == "ssm":
-            out, h_pool, conv_pool = self._ssm_mixer(
+        h = x if cfg.post_norm else self._norm(lp["norm1"], x)
+        if CACHE_KINDS[kind].slot:
+            mixer = {"ssm": self._ssm_mixer, "delta": self._delta_mixer}
+            out, h_pool, conv_pool = mixer[kind](
                 h, h_pool, conv_pool, lp["mixer"], at, segments=segments,
                 rows=rows, cfg=cfg)
         else:
             out, pages = self._attend_plain(h, pages, lp["attn"], at,
                                             segments=segments, cfg=cfg)
+        if cfg.post_norm:
+            x = x + self._norm(lp["norm1"], out.astype(x.dtype))
+            out = self._norm(lp["norm2"],
+                             T._mlp_block(cfg, lp["mlp"], x).astype(x.dtype))
+            return x + out, pages, h_pool, conv_pool
         x = x + out.astype(x.dtype)
         out = T._mlp_block(cfg, lp["mlp"], self._norm(lp["norm2"], x))
         return x + out.astype(x.dtype), pages, h_pool, conv_pool
@@ -1578,15 +1589,21 @@ class RaggedInferenceModel:
     def _attend_plain(self, h, pool, ap, layer, *, segments, cfg):
         """Attention with no positional encoding over ``pool[layer]``:
         the projections once over all tokens (the weights stored as the
-        matrices the products take, heads folded into the columns), the
-        cache write and the paged kernel segment by segment."""
+        matrices the products take, heads folded into the columns; under
+        ``cfg.qk_norm`` q and k normed over their whole width before the
+        page write), the cache write and the paged kernel segment by
+        segment."""
         dtype, d = cfg.dtype, cfg.dims_per_head
 
-        def heads(w):
+        def heads(w, gain=None):
             y = jnp.einsum("sqe,ef->sqf", h, w.astype(dtype))
+            if gain is not None:
+                y = self._norm(gain, y)
             return y.reshape(y.shape[:2] + (-1, d))
 
-        q, k, v = heads(ap["wq"]), heads(ap["wk"]), heads(ap["wv"])
+        q = heads(ap["wq"], ap["q_norm"] if cfg.qk_norm else None)
+        k = heads(ap["wk"], ap["k_norm"] if cfg.qk_norm else None)
+        v = heads(ap["wv"])
         attn = []
         for seg, qs, ks, vs in zip(segments, *(
                 _per_segment(a, segments) for a in (q, k, v))):
@@ -1653,6 +1670,57 @@ class RaggedInferenceModel:
         y = _end_to_end(ys) * jax.nn.silu(z.astype(f32))
         return jnp.einsum("sqd,de->sqe", y.astype(dtype),
                           mp["w_out"].astype(dtype)), h_pool, conv_pool
+
+    def _delta_mixer(self, u, s_pool, conv_pool, mp, layer, *, segments,
+                     rows, cfg):
+        """The gated delta-rule mixer of ``u`` (all tokens of
+        ``segments``): its projections once over all of them; the
+        convolution over q, k and v and the recurrence segment by segment,
+        each row from and to its slot of ``pool[layer]``
+        (``ops/delta_rule.py``; the convolution is ``ops/ssm.py``'s).
+        Returns (output in ``u``'s layout, state pool, conv pool)."""
+        from ...ops.delta_rule import delta_rule
+        from ...ops.ssm import conv_step
+        dtype, f32 = cfg.dtype, jnp.float32
+        H, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+        qkv = jnp.einsum("sqe,ef->sqf", u, mp["w_qkv"].astype(dtype))
+        gate = jnp.einsum("sqe,ef->sqf", u, mp["w_gate"].astype(dtype))
+        ab = jnp.einsum("sqe,fe->sqf", u, mp["w_ab"].astype(dtype),
+                        preferred_element_type=f32)
+        # log alpha and beta a head
+        g = -jnp.exp(mp["A_log"].astype(f32)) * jax.nn.softplus(
+            ab[..., :H] + mp["dt_bias"].astype(f32))
+        beta = jax.nn.sigmoid(ab[..., H:]) \
+            * (2.0 if cfg.delta_neg_eigval else 1.0)
+        def l2(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+        outs = []
+        for (slots, fresh, valid), seg, xs, gs, bs in zip(
+                rows, segments, *(_per_segment(a, segments)
+                                  for a in (qkv, g, beta))):
+            conv, tail = conv_step(conv_pool, layer, slots, fresh,
+                                   seg.q_lens, xs, mp["conv_w"])
+            conv = jax.nn.silu(conv)                        # float32
+            by_head = conv.shape[:2] + (H, -1)
+            q = l2(conv[..., :H * dk].reshape(by_head)) * dk ** -0.5
+            k = l2(conv[..., H * dk:2 * H * dk].reshape(by_head))
+            # a padded position moves nothing: alpha = 1, beta = 0
+            o, s_pool, conv_pool = delta_rule(
+                s_pool, conv_pool, layer, slots, fresh, q, k,
+                conv[..., 2 * H * dk:],
+                jnp.where(valid[..., None], gs, 0.0),
+                jnp.where(valid[..., None], bs, 0.0), tail)
+            outs.append(o)
+        o = _end_to_end(outs)
+        o = o.reshape(o.shape[:2] + (H, dv))
+        y = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + cfg.norm_eps) \
+            * mp["o_norm"]["scale"].astype(f32)
+        y = y.reshape(gate.shape) * jax.nn.silu(gate.astype(f32))
+        return jnp.einsum("sqd,de->sqe", y.astype(dtype),
+                          mp["w_out"].astype(dtype)), s_pool, conv_pool
 
     def _per_shard_heads(self, fn, cfg, n_head_args: int,
                          pool_out: bool = False):

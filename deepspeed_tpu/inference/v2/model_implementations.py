@@ -2,7 +2,8 @@
 
 Reference: ``inference/v2/model_implementations/`` — one directory per
 arch (llama_v2, mistral, mixtral, falcon, opt, phi, qwen, qwen_v2; here
-also bloom, gpt_neox, gpt2, gptj, pangu_ultra_moe, laguna and jamba), each
+also bloom, gpt_neox, gpt2, gptj, pangu_ultra_moe, laguna, jamba and
+olmo_hybrid), each
 a ``DSTransformerModelBase`` subclass hard-coding that family's
 invariants (llama_v2/model.py:22, mistral/model.py, ...), chosen by
 ``engine_factory`` from the checkpoint's ``model_type``.
@@ -174,6 +175,28 @@ class JambaInferenceModel(RaggedInferenceModel):
         super().__init__(cfg, params, **kw)
 
 
+class OlmoHybridInferenceModel(RaggedInferenceModel):
+    """Olmo-Hybrid (``models/olmo_hybrid.py``; no counterpart in the
+    reference): gated delta-rule linear-attention layers and full
+    attention layers in one model, the full layers' K/V in pages and the
+    linear layers' matrix state and convolution tail in one slot of the
+    state pool a sequence, the norm on each sub-layer's output, a Q/K
+    norm over the whole width, no positional encoding, the llama block's
+    SwiGLU in every layer."""
+    MODEL_TYPES = ("olmo_hybrid",)
+
+    def __init__(self, cfg, params, **kw):
+        assert set(cfg.layer_kinds) == {"full", "delta"} \
+            and len(cfg.layer_kinds) == cfg.num_layers, \
+            "olmo_hybrid names a kind for every layer, and has both"
+        assert cfg.delta_heads > 0 and cfg.delta_key_dim > 0 \
+            and cfg.delta_value_dim > 0 and cfg.delta_conv > 1
+        assert cfg.norm == "rmsnorm" and cfg.pos_emb == "none"
+        assert cfg.post_norm and cfg.qk_norm
+        assert cfg.num_heads % cfg.kv_heads == 0
+        super().__init__(cfg, params, **kw)
+
+
 class GPTNeoXInferenceModel(RaggedInferenceModel):
     MODEL_TYPES = ("gpt_neox",)
 
@@ -190,7 +213,7 @@ _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LlamaV2InferenceModel, MistralInferenceModel, MixtralInferenceModel,
     FalconInferenceModel, OPTInferenceModel, PhiInferenceModel,
     Qwen2InferenceModel, BloomInferenceModel, PanguUltraMoEInferenceModel,
-    LagunaInferenceModel, JambaInferenceModel,
+    LagunaInferenceModel, JambaInferenceModel, OlmoHybridInferenceModel,
     GPTNeoXInferenceModel, GPT2InferenceModel, GPTJInferenceModel,
 )
 

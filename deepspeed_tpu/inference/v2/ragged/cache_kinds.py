@@ -22,7 +22,7 @@ model of one page group takes the ``[S, P]`` table it always took.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Sequence
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 from ..step_key import window_slots
 
@@ -34,15 +34,41 @@ class CacheKind:
     group: str = ""
     #: the group's tables give back the pages the window has passed
     windowed: bool = False
-    #: the kind holds a slot of the state pool instead of pages
-    slot: bool = False
+    #: the kind holds a slot of the state pool instead of pages: what a
+    #: slot holds of ONE such layer, from the model's configuration, as
+    #: (the recurrent state ``[rows, width]``, the convolution's tail
+    #: ``[positions, channels]``).  None: the kind caches pages
+    slot_shape: Optional[Callable[[object], Tuple[Tuple[int, int],
+                                                  Tuple[int, int]]]] = None
+
+    @property
+    def slot(self) -> bool:
+        return self.slot_shape is not None
 
 
 CACHE_KINDS: Dict[str, CacheKind] = {
     "full": CacheKind(group="full"),
     "window": CacheKind(group="window", windowed=True),
-    "ssm": CacheKind(slot=True),
+    # Mamba-1 (ops/ssm.py): a diagonal state a channel, the tail of the
+    # mixer's own channels
+    "ssm": CacheKind(slot_shape=lambda cfg: (
+        (cfg.ssm_state_dim, cfg.ssm_inner),
+        (cfg.ssm_conv - 1, cfg.ssm_inner))),
+    # gated delta rule (ops/delta_rule.py): a matrix [dk, dv] a head, the
+    # heads side by side in the minor dim; the tail of q, k AND v
+    "delta": CacheKind(slot_shape=lambda cfg: (
+        (cfg.delta_key_dim, cfg.delta_heads * cfg.delta_value_dim),
+        (cfg.delta_conv - 1, cfg.delta_heads
+         * (2 * cfg.delta_key_dim + cfg.delta_value_dim)))),
 }
+
+
+def slot_kind(kinds: Sequence[str]) -> Optional[str]:
+    """The one kind of ``kinds`` that holds a slot of the state pool (a
+    pool has one slot shape, so a model has at most one such kind)."""
+    found = [k for k in dict.fromkeys(kinds) if CACHE_KINDS[k].slot]
+    assert len(found) <= 1, f"one state pool, one slot kind: {found}"
+    return found[0] if found else None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,17 +105,21 @@ class KVCacheConfig:
 
 @dataclasses.dataclass(frozen=True)
 class StatePoolConfig:
-    """The state pool of a model with state-space layers (``ops/ssm.py``):
-    what such a layer kind declares of its cache.  One slot a sequence
-    holds, for every such layer, the recurrent state ``[d_state, d_inner]``
-    in ``state_dtype`` and the convolution's tail ``[d_conv - 1, d_inner]``
-    in ``conv_dtype``, its rows laid end to end and cut into
+    """The state pool of a model with layers that keep a recurrent state
+    instead of pages (``ops/ssm.py``, ``ops/delta_rule.py``): what such a
+    layer kind declares of its cache (``cache_kinds.CacheKind.slot_shape``).
+    One slot a sequence holds, for every such layer, the recurrent state
+    ``state`` = ``[rows, width]`` in ``state_dtype`` (Mamba-1: ``[d_state,
+    d_inner]``; the gated delta rule: ``[d_k, heads * d_v]``) and the
+    convolution's tail ``tail`` = ``[positions, channels]`` in
+    ``conv_dtype``, its rows laid end to end and cut into
     ``ops/ssm.py::conv_rows`` rows; slot ``num_slots`` is the scratch
-    slot padding rows write to."""
+    slot padding rows write to.  ``kind`` is the layer kind that holds the
+    slots: it names the step span's row and token counts."""
     num_layers: int
-    d_state: int
-    d_inner: int
-    d_conv: int
+    state: Tuple[int, int]
+    tail: Tuple[int, int]
+    kind: str = "ssm"
     num_slots: int = 1
     state_dtype: Any = jnp.float32
     conv_dtype: Any = jnp.bfloat16
@@ -123,16 +127,17 @@ class StatePoolConfig:
     def shapes(self) -> tuple:
         from ....ops.ssm import conv_rows
         lead = (self.num_layers, self.num_slots + 1)
-        width = (self.d_conv - 1) * self.d_inner
+        width = self.tail[0] * self.tail[1]
         rows = conv_rows(width)
-        return (lead + (self.d_state, self.d_inner),
-                lead + (rows, width // rows))
+        return (lead + tuple(self.state), lead + (rows, width // rows))
 
     @property
     def bytes_per_slot(self) -> int:
-        return self.num_layers * self.d_inner * (
-            self.d_state * jnp.dtype(self.state_dtype).itemsize
-            + (self.d_conv - 1) * jnp.dtype(self.conv_dtype).itemsize)
+        return self.num_layers * (
+            self.state[0] * self.state[1]
+            * jnp.dtype(self.state_dtype).itemsize
+            + self.tail[0] * self.tail[1]
+            * jnp.dtype(self.conv_dtype).itemsize)
 
     def total_bytes(self) -> int:
         return self.bytes_per_slot * (self.num_slots + 1)
@@ -345,8 +350,8 @@ def _write_slot(data, slot, rows):
 
 
 class StateSlotBlob:
-    """Host copy of one slot: the rows ``[L, d_state, d_inner]`` and
-    ``[L, rows, (d_conv - 1) * d_inner / rows]`` it held, in the pool's
+    """Host copy of one slot: the rows ``[L, *state]`` and ``[L, rows,
+    tail / rows]`` it held (:class:`StatePoolConfig`), in the pool's
     dtypes."""
 
     __slots__ = ("h", "conv")

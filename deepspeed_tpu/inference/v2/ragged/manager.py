@@ -25,8 +25,9 @@ a matched prefix would skip the prefill of tokens whose window-group K/V
 nobody holds, so nothing is indexed.  A model with one window or none
 has ONE group and this module's paths are what they were.
 
-Sequence state that is not pages: a model with state-space layers
-(``ops/ssm.py``) keeps, a sequence, ONE SLOT of a fixed state pool
+Sequence state that is not pages: a model with state-space or delta-rule
+layers (``ops/ssm.py``, ``ops/delta_rule.py``; the slot's shape is the
+kind's, ``cache_kinds.py``) keeps, a sequence, ONE SLOT of a fixed state pool
 (``kv_cache.StatePool``; ``SequenceDescriptor.state_slot``).  A slot is
 reserved with the sequence's first pages or not at all, released at
 flush, moved to a host copy and back by preempt offload / restore, and
@@ -718,8 +719,8 @@ class StateManager:
             meta["window_layers"] = self.window_cache.cfg.num_layers
         if self.state_pool is not None:
             sc = self.state_pool.cfg
-            meta["state"] = [sc.num_layers, sc.d_state, sc.d_inner,
-                             sc.d_conv, np.dtype(sc.state_dtype).name,
+            meta["state"] = [sc.kind, sc.num_layers, *sc.state, *sc.tail,
+                             np.dtype(sc.state_dtype).name,
                              np.dtype(sc.conv_dtype).name]
         return meta
 

@@ -1512,16 +1512,22 @@ class FastGenScheduler:
             span.set("attn_tokens_full", full)
             span.set("attn_tokens_window", in_window)
         if state.state_pool is not None:
-            # the state pool of a model with state-space layers: slots
-            # held, and what this step's two kernels were given (a
+            # the state pool of a model with state-space or delta-rule
+            # layers: slots held (under the names the pool's first kind
+            # gave them, whatever kind holds the slots), and under the
+            # kind's own name what this step's two kernels were given (a
             # one-token row is stepped by the update kernel, a prompt
-            # piece's true tokens by the scan)
+            # piece's true tokens by the scan or the chunked form)
             pool = state.state_pool
             span.set("ssm_slots_held", pool.held_slots)
-            span.set("ssm_rows_decode", rows - prefill_rows)
-            span.set("ssm_tokens_prefill", prefill_tokens)
+            span.set(f"{pool.cfg.kind}_rows_decode", rows - prefill_rows)
+            span.set(f"{pool.cfg.kind}_tokens_prefill", prefill_tokens)
             span.set("ssm_state_bytes",
                      pool.held_slots * pool.cfg.bytes_per_slot)
+            if self._engine.counts_attended and state.window_cache is None:
+                # the context the decode rows attend in the full layers
+                span.set("attn_tokens_full",
+                         self._engine.take_attended()[0])
         if self._moe_counts is not None:
             # counts of the step drained inside this one (the step
             # before), with that step's tokens as their divisor

@@ -134,6 +134,26 @@ class TransformerConfig:
     ssm_dt_rank: int = 0
     ssm_expand: int = 2
     ssm_state_dtype: Any = jnp.float32
+    # gated delta-rule (linear-attention) layers beside attention layers in
+    # one model (models/olmo_hybrid.py): ``layer_kinds`` names them
+    # "delta".  delta_heads > 0 says the model has such layers, each a
+    # mixer of delta_heads heads with keys of delta_key_dim and values of
+    # delta_value_dim, a causal convolution over delta_conv positions of
+    # q, k and v, and a matrix state [delta_key_dim, delta_value_dim] a
+    # head (held in ssm_state_dtype, the state pool's dtype whatever kind
+    # holds it; ops/delta_rule.py).  delta_neg_eigval: beta = 2 sigmoid(.)
+    # in (0, 2), so the transition's eigenvalue along k lies in (-1, 1)
+    delta_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_conv: int = 4
+    delta_neg_eigval: bool = False
+    # x + N(mixer(x)), x + N(mlp(x)): the norm on the sub-layer's OUTPUT
+    # and none on its input (the OLMo 2 / 3 order)
+    post_norm: bool = False
+    # RMSNorm (a learned gain) over the whole width of q and of k, all
+    # heads together, before the positional encoding and the cache write
+    qk_norm: bool = False
     tie_embeddings: bool = False
     use_bias: bool = False
     dropout: float = 0.0
@@ -212,7 +232,15 @@ class TransformerConfig:
             # convolution, A and D (its three small norms left out)
             mixer = (e * 2 * di + di * (r + 2 * n) + r * di + di + di * e
                      + di * (self.ssm_conv + 1) + di * n + di)
+            # a delta-rule mixer: q, k, v, gate, the two gates a head and
+            # the output projection, the convolution, A and the step's bias
+            dh, qk, dv = (self.delta_heads, self.delta_heads
+                          * self.delta_key_dim, self.delta_heads
+                          * self.delta_value_dim)
+            delta = (e * (2 * qk + 2 * dv + 2 * dh) + dv * e
+                     + self.delta_conv * (2 * qk + dv) + 2 * dh)
             attn = sum(mixer if kind == "ssm" else
+                       delta if kind == "delta" else
                        2 * e * heads.get(kind, h) * d + 2 * e * k * d
                        + (e * heads.get(kind, h) if self.head_gate else 0)
                        for kind in self.layer_kinds)

@@ -574,8 +574,10 @@ def kernel_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
     grid step of the ragged kernel holds, read from the call's shapes.
 
     ``group`` is the largest of ``PAGES_PER_STEP``, 4, 2, 1 that divides
-    the page bucket, ``heads`` the largest of K, K/2, ... 1, such that
-    the step fits ``VMEM_BUDGET``.  A wide group comes before many heads:
+    the page bucket, ``heads`` the largest divisor of K (K, K/2, ... 1
+    for a power of two; 30, 15, 10, 6, ... for 30 heads, where halving
+    stopped at the odd 15 and gave the group up instead) such that the
+    step fits ``VMEM_BUDGET``.  A wide group comes before many heads:
     a grid step's fixed cost is paid per group, and what does not fit as
     heads of one step comes back as a grid dim.  A decode row or a
     speculative row (a few dozen query rows) takes every head and 8
@@ -605,15 +607,12 @@ def kernel_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
     for group in (g for g in (PAGES_PER_STEP, 4, 2, 1)
                   if page_slots % g == 0):
         span = _round_up(group * page_size, 128)
-        heads = kv_heads
-        while True:
-            if (group * (heads * slot_bytes + scale_bytes)
+        for heads in range(kv_heads, 0, -1):
+            if kv_heads % heads == 0 and (
+                    group * (heads * slot_bytes + scale_bytes)
                     + heads * rows * (row_bytes + tiles * span * 4)
                     <= VMEM_BUDGET):
                 return heads, group
-            if heads % 2:
-                break
-            heads //= 2
     return 1, 1
 
 

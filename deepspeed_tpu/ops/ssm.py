@@ -24,7 +24,8 @@ and a layer index and updates ``pool[layer, slot]`` in place.
 * :func:`ssm_scan` — the recurrence, for rows of ``Q`` tokens from each
   row's slot: ``h_t = exp(dt_t (x) A) * h_{t-1} + (dt_t * x_t) (x) B_t``,
   ``y_t = h_t C_t + D * x_t``.  Mamba-1's ``A`` is a full ``[d_inner,
-  d_state]`` diagonal, so a chunk has no matrix form (that is Mamba-2's):
+  d_state]`` diagonal, so a chunk has no matrix form (that takes one decay
+  a head: ``ops/delta_rule.py`` has it, for the gated delta rule):
   the recurrence is elementwise on the vector unit, sequential in ``t``,
   parallel over ``d_inner``.  On a TPU it is a Pallas kernel named
   ``ssm_state_update_decode`` (Q = 1: one token a row, the state read and
@@ -240,13 +241,13 @@ def ssm_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
 
 def conv_step(conv_pool: jax.Array, layer, slots: jax.Array,
               fresh: jax.Array, q_lens: jax.Array, x: jax.Array,
-              w: jax.Array, b: jax.Array
+              w: jax.Array, b: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, jax.Array]:
     """The causal depthwise convolution of ``x`` ``[S, Q, d]`` behind each
     row's tail ``pool[layer, slot]`` (``[d_conv - 1, d]`` laid end to
     end in :func:`conv_rows` rows; zero for a ``fresh`` row), ``w``
     ``[d_conv, d]`` (tap ``k`` weighs the input ``d_conv - 1 - k``
-    positions back), ``b`` ``[d]``.  The tail kept is
+    positions back), ``b`` ``[d]`` (None: no bias).  The tail kept is
     that of the row's TRUE last tokens, ``q_lens`` of them new: padding
     to the ``Q`` bucket does not enter it.  Returns (``conv(x) + b`` in
     float32, the new tails ``[S, d_conv - 1, d]`` for :func:`ssm_scan` to
@@ -256,18 +257,22 @@ def conv_step(conv_pool: jax.Array, layer, slots: jax.Array,
     tail = conv_pool[layer, slots].reshape(S, K - 1, -1)
     tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
     w = w.astype(jnp.float32)
+
+    def bias():
+        return 0.0 if b is None else b.astype(jnp.float32)
+
     if Q == 1:
         # one token a row: taps and shift on [S, d] arrays (a [S, 4, d]
         # concatenation is re-laid out, 10 MB a layer at 256 rows)
         x0 = x[:, 0].astype(tail.dtype)
-        out = b.astype(jnp.float32) + x0.astype(jnp.float32) * w[K - 1] \
+        out = bias() + x0.astype(jnp.float32) * w[K - 1] \
             + sum(tail[:, k].astype(jnp.float32) * w[k]
                   for k in range(K - 1))
         shifted = jnp.concatenate([tail[:, 1:], x0[:, None]], axis=1)
         return out[:, None], jnp.where(q_lens[:, None, None] > 0, shifted,
                                        tail)
     xp = jnp.concatenate([tail, x.astype(tail.dtype)], axis=1)
-    out = b.astype(jnp.float32) + sum(
+    out = bias() + sum(
         xp[:, k:k + Q].astype(jnp.float32) * w[k] for k in range(K))
     idx = q_lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
     return out, jnp.take_along_axis(xp, idx[:, :, None], axis=1)

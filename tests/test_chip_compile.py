@@ -168,6 +168,62 @@ def test_paged_attention(chip, slots, rows, page_slots, heads, window, int8):
         kernel="paged_attention")
 
 
+#: (configuration file, query heads of the kind, blocks at Q = 1 and at a
+#: 128-token prompt row, each at the page buckets 8 and 40): what
+#: ``kernel_blocks`` gives the five serving configurations' attention
+#: layers.  The latent family's kernels (``mla_attention_*``) take no
+#: blocks from it.
+SERVING_BLOCKS = [
+    ("mistral-7b-serve-8l", 32, (8, 8), (4, 8)),
+    ("laguna-s-serve-5l-ep16", 48, (8, 8), (2, 8)),          # full layers
+    ("laguna-s-serve-5l-ep16", 72, (8, 8), (2, 8)),          # window layers
+    ("jamba2-3b-serve-28l", 20, (1, 8), (1, 4)),
+    # 30 KV heads, one query head each: every head does not fit beside 8
+    # pages of 960 KB; halving stopped at the odd 15 and gave a prompt row
+    # (15, 4), the largest divisor that fits keeps the group of 8
+    ("olmo-hybrid-7b-serve-4l", 30, (15, 8), (10, 8)),
+]
+
+
+@pytest.mark.parametrize("name,heads,decode,prompt", SERVING_BLOCKS,
+                         ids=[f"{n}-{h}" for n, h, _, _ in SERVING_BLOCKS])
+def test_kernel_blocks_of_the_serving_configurations(name, heads, decode,
+                                                     prompt):
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as f:
+        c = json.load(f)
+    K, D, page = (c["num_key_value_heads"], c["head_dim"],
+                  c["engine"]["page_size"])
+    assert heads % K == 0 and c["engine"]["kv_dtype"] == "bfloat16"
+    for q, want in ((1, decode), (128, prompt)):
+        for buckets in (8, 40):
+            got = kernel_blocks(q * (heads // K), K, D, page, buckets, 2, 2)
+            assert got == want, (name, q, buckets, got)
+            assert K % got[0] == 0 and buckets % got[1] == 0
+            assert got != (1, 1) or K == 1
+
+
+@pytest.mark.parametrize("slots,rows,page_slots", [
+    (256, 1, 40), (256, 1, 8), (4, 128, 8), (4, 128, 40)],
+    ids=["decode-40", "decode-8", "prompt-8", "prompt-40"])
+def test_paged_attention_at_thirty_kv_heads(chip, slots, rows, page_slots):
+    """One query head a KV head, 30 of them (a page is 960 KB a layer):
+    the blocks ``kernel_blocks`` gives there compile under the default
+    VMEM limit."""
+    compile_for_chip(
+        lambda q, kv, layer, table, start, lens: paged_attention(
+            q, kv, layer, table, start, lens, use_kernel=True,
+            interpret=False),
+        chip((slots, rows, 30, HEAD_DIM), jnp.bfloat16),
+        chip((1, 257, 2, 30, PAGE, HEAD_DIM), jnp.bfloat16),
+        chip((), jnp.int32), chip((slots, page_slots), jnp.int32),
+        chip((slots,), jnp.int32), chip((slots,), jnp.int32),
+        kernel="paged_attention")
+
+
 @pytest.mark.parametrize("slots,rows,int8", [
     (64, 1, False), (16, 5, True), (8, 128, False), (8, 128, True)],
     ids=["decode", "spec-int8", "mixed", "mixed-int8"])
@@ -725,6 +781,133 @@ def test_jamba_step_program_moves_no_pool_and_no_weight_stack(
                         "bf16[8,131,5120]") for m in moved), moved
     assert stack_shaped_movers(text, params) == []
     assert compiled.memory_analysis().temp_size_in_bytes < mamba_layer
+
+
+# -- the delta-rule family: its two kernels and its step programs -----------
+
+DELTA_LAYERS, DELTA_HEADS, DELTA_DK, DELTA_DV = 3, 30, 96, 192
+DELTA_CHANNELS = DELTA_HEADS * (2 * DELTA_DK + DELTA_DV)
+
+
+def _delta_pools(chip):
+    return (chip((DELTA_LAYERS, SSM_SLOTS + 1, DELTA_DK,
+                  DELTA_HEADS * DELTA_DV), jnp.float32),
+            chip((DELTA_LAYERS, SSM_SLOTS + 1, 8, 3 * DELTA_CHANNELS // 8),
+                 jnp.bfloat16))
+
+
+@pytest.mark.parametrize("rows,q,kernel", [
+    (256, 1, "delta_state_update_decode"), (4, 128, "delta_chunk_prefill"),
+    (1, 1024, "delta_chunk_prefill"), (4, 8, "delta_chunk_prefill")])
+def test_delta_rule_kernels(chip, rows, q, kernel):
+    """The update kernel (a row's whole [96, 5760] float32 state a grid
+    step, in and out in two buffers each under the default VMEM limit) and
+    the chunked kernel (6 heads and one chunk a grid step, whatever the
+    row bucket) at the published widths, both pools aliased in -> out."""
+    from deepspeed_tpu.ops.delta_rule import chunk_len, delta_rule
+    f32 = jnp.float32
+    assert [chunk_len(n) for n in (1, 8, 128, 1024, 96)] \
+        == [1, 8, 64, 64, 32]
+    state, conv = _delta_pools(chip)
+    width = DELTA_HEADS * DELTA_DV
+    compile_for_chip(
+        lambda state, conv, layer, slots, fresh, q_, k, v, g, beta, tail:
+        delta_rule(state, conv, layer, slots, fresh, q_, k, v, g, beta,
+                   tail, use_kernel=True),
+        state, conv, chip((), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.bool_),
+        chip((rows, q, DELTA_HEADS, DELTA_DK), f32),
+        chip((rows, q, DELTA_HEADS, DELTA_DK), f32),
+        chip((rows, q, width), f32), chip((rows, q, DELTA_HEADS), f32),
+        chip((rows, q, DELTA_HEADS), f32),
+        chip((rows, 3, DELTA_CHANNELS), jnp.bfloat16), kernel=kernel)
+
+
+#: the window's two programs: a chained decode step, and a mixed step (256
+#: decode rows and two fresh prompts: both delta kernels, both page writes,
+#: the paged kernel at 30 / 30 heads); a prompt row's paged kernel alone is
+#: ``test_paged_attention_at_thirty_kv_heads``
+OLMO_STEP_KEYS = {
+    "chain-p40": (256, 1, 40, False, "chain", 256, True),
+    "mixed-p40": (256, 1, 40, False, "mixed", 2, 128, 8, True, True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(OLMO_STEP_KEYS))
+def test_olmo_hybrid_step_program_moves_no_pool_and_no_weight_stack(
+        chip, monkeypatch, kind):
+    """The benchmark's cell at published widths (one period: 3 delta-rule
+    layers and the full layer at 30 / 30 heads): the step programs lower
+    for the chip, the delta-rule kernels run under their own names, and
+    nothing the size of a layer of the state pool's small array (the
+    convolution tails: 17.8 MB), let alone of the 1.7 GB state pool, the
+    page pool or a weight stack, is copied, sliced out or re-laid out: the
+    in-place update is held by this."""
+    import dataclasses
+    import json
+    import os
+
+    from flax.core import meta
+
+    from benchmark.builders.serve_olmo_hybrid import source_of
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        OlmoHybridInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.olmo_hybrid import OlmoHybridForCausalLM
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-serve-4l.json")) as f:
+        config = json.load(f)
+    model = OlmoHybridForCausalLM(source_of(config, False))
+    assert model.cfg.layer_kinds == ("delta", "delta", "delta", "full")
+    params = jax.eval_shape(lambda k: meta.unbox(model.init_params(k)),
+                            jax.random.key(0))
+    pages = 1024
+    serve = OlmoHybridInferenceModel(
+        model.cfg, params, kv_config=KVCacheConfig(
+            num_layers=1, kv_heads=30, head_dim=128, page_size=PAGE,
+            num_pages=pages))
+    serve.state_config = dataclasses.replace(serve.state_config,
+                                             num_slots=SSM_SLOTS)
+    pool = (chip((1, pages + 1, 2, 30, PAGE, 128), jnp.bfloat16),
+            *_delta_pools(chip))
+    assert [tuple(a.shape) for a in pool[1:]] \
+        == list(serve.state_config.shapes())
+    key = StepKey.parse(OLMO_STEP_KEYS[kind])
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        step_avals(serve, key, pool))
+    assert (key.S, key.P + 1) in [a.shape for a in avals[2:]]
+    compiled = jax.jit(step_program(serve, key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    text = compiled.as_text()
+    row = "decode" if key.Q == 1 else "prefill"
+    kernels = [f"kv_write_{row}", "delta_state_update_decode"
+               if key.Q == 1 else "delta_chunk_prefill"]
+    if key.kind == "mixed":
+        kernels += ["delta_chunk_prefill", "kv_write_prefill"]
+    if not key.fresh or key.kind == "mixed":
+        kernels.append("paged_attention_decode" if key.kind == "mixed"
+                       else f"paged_attention_{row}")
+    for kernel in kernels:
+        assert any('custom_call_target="tpu_custom_call"' in line
+                   and kernel in line for line in text.splitlines()), kernel
+    # the smallest thing that must not move: one layer of the conv pool
+    conv_layer = (SSM_SLOTS + 1) * 3 * DELTA_CHANNELS * 2
+    kv_layer = (pages + 1) * 2 * 30 * PAGE * 128 * 2
+    delta_layer = 2 * 215_000_000
+    assert conv_layer < delta_layer < kv_layer
+    moved = [m for m in pool_sized_movers(text, conv_layer)
+             # ONE layer's weights taken out of its kind's stack, mostly
+             # inside the fusion that feeds the product (what a scan over
+             # layers does)
+             if not m[2].startswith("bf16[1,")]
+    assert moved == [], moved
+    assert stack_shaped_movers(text, params) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < delta_layer
 
 
 # -- the train cell's step: what the layers' checkpoint keeps (PR 38) --------

@@ -693,3 +693,72 @@ def test_the_train_steps_spans_reach_the_profilers_trace(span):
                            "idle_ms_per_step.train.json")) as f:
         patterns = json.load(f)["args"]["patterns"]
     assert any(re.search(p, span) for p in patterns)
+
+
+def test_a_model_with_delta_rule_layers_carries_the_delta_kinds_names():
+    """What the step of a model with gated delta-rule layers (PR 39) adds
+    to the tree: on ``fastgen.step`` the rows the update kernel stepped and
+    the true tokens the chunked form consumed under the KIND's names, the
+    context the decode rows attend in the full layers (which only a model
+    of two page groups wrote before), and the pool's own ``ssm_slots_held``
+    / ``ssm_state_bytes`` under the names they have; each read by a metric
+    file or a reader of the benchmark or held for a trace by decision; and
+    the two kernels under names that ``^delta_`` finds and no pattern that
+    was here does."""
+    import glob
+    import json
+    import os
+    import re
+
+    import jax
+
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    from deepspeed_tpu.ops.delta_rule import delta_rule
+    from test_olmo_hybrid import engine_of, family, rule_args, sequences_of
+    cfg, params = family()
+    sched = FastGenScheduler(engine_of(cfg, params))
+    telemetry.enable()
+    for uid, p in enumerate(sequences_of((21, 30), seed=2)):
+        sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=8))
+    sched.run_to_completion()
+    recs = [r for r in get_tracer().records()
+            if not r[0].startswith("engine.program")]
+    carried = {key for r in recs if r[0] == "fastgen.step" and r[5]
+               for key in r[5]}
+    new = {"delta_rows_decode", "delta_tokens_prefill", "attn_tokens_full"}
+    kept = {"ssm_slots_held", "ssm_state_bytes"}
+    assert new | kept <= carried
+    assert carried - new - kept == {
+        "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
+        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "program"}
+    assert not any(r[0].startswith("kv.state") for r in recs)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    read, patterns = set(), []
+    for path in glob.glob(os.path.join(root, "benchmark", "metrics",
+                                       "*.json")):
+        with open(path) as f:
+            metric = json.load(f)
+        args = metric.get("args", {})
+        read |= {v[5:] for v in (args.get("value", ""),
+                                 args.get("of_value", ""))
+                 if v.startswith("attr:")}
+        if not os.path.basename(path).startswith(("delta_", "hybrid_")):
+            patterns += args.get("patterns", [])
+    with open(os.path.join(root, "benchmark", "readers",
+                           "delta_roofline.py")) as f:
+        text = f.read()
+    read |= {key for key in new if f'"{key}"' in text}
+    # every new attribute is read by the family's reader
+    assert new <= read and "ssm_slots_held" in read
+
+    def jaxpr(Q):
+        args, _ = rule_args(2, Q)
+        return str(jax.make_jaxpr(lambda kw: delta_rule(
+            **kw, interpret=True))(args))
+
+    assert "delta_state_update_decode" in jaxpr(1)
+    assert "delta_chunk_prefill" in jaxpr(8)
+    for name in ("delta_state_update_decode", "delta_chunk_prefill"):
+        assert re.search("^delta_", name)
+        assert not any(re.search(p, name) for p in patterns), name
