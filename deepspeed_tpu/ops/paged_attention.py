@@ -433,6 +433,20 @@ VMEM_BUDGET = 14 * 2 ** 20
 SCORE_TILES = 1
 ALIBI_TILES = 2
 
+#: K/V bytes one grid step fetches, at most, where it holds every KV head
+#: of its page slots (one buffer of them; :func:`kernel_blocks`).  2 MiB is
+#: what 8 slots of a 256 KB page are (8 KV heads x 64 tokens x 128,
+#: bfloat16, K and V): the step the 8-KV-head configurations were fitted
+#: at in PR 28, which they keep.  A page of 30 such heads is 960 KB: 2
+#: slots.  Timed alone at 256 rows (v5e, PERF.md PR 41; ms at contexts
+#: uniform in 100-2,200): (15 heads, 8 slots) 8.87, (30, 4) 7.42, (30, 2)
+#: 6.83, (30, 1) 6.56, and 6.56-6.58 for all three of 30 heads once no
+#: slot fetches the null page: what a wide group costs is `group` fetches
+#: of the null page a row, so a target under 2 MiB would serve wide pages
+#: by 4% and take the 8-head configurations' group of 8 with it (their own
+#: sweep: PERF.md, section 7)
+STEP_BYTES = 2 * 2 ** 20
+
 
 def _decode_kernel(l_ref, pt_ref, sp_ref, *refs, page_size, group, heads,
                    sm_scale, has_alibi, has_scale, window, q_len, groups):
@@ -566,33 +580,20 @@ def _round_up(n: int, tile: int) -> int:
     return -(-n // tile) * tile
 
 
-def kernel_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
-                  page_slots: int, q_itemsize: int, kv_itemsize: int,
-                  has_scale: bool = False,
-                  has_alibi: bool = False) -> Tuple[int, int]:
-    """``(heads, group)``: how many KV heads and how many page slots one
-    grid step of the ragged kernel holds, read from the call's shapes.
+def step_vmem_bytes(heads: int, group: int, rows: int, kv_heads: int,
+                    head_dim: int, page_size: int, q_itemsize: int,
+                    kv_itemsize: int, has_scale: bool = False,
+                    has_alibi: bool = False) -> int:
+    """What a grid step of ``heads`` KV heads and ``group`` page slots
+    holds in VMEM, by :func:`kernel_blocks`' account.
 
-    ``group`` is the largest of ``PAGES_PER_STEP``, 4, 2, 1 that divides
-    the page bucket, ``heads`` the largest divisor of K (K, K/2, ... 1
-    for a power of two; 30, 15, 10, 6, ... for 30 heads, where halving
-    stopped at the odd 15 and gave the group up instead) such that the
-    step fits ``VMEM_BUDGET``.  A wide group comes before many heads:
-    a grid step's fixed cost is paid per group, and what does not fit as
-    heads of one step comes back as a grid dim.  A decode row or a
-    speculative row (a few dozen query rows) takes every head and 8
-    pages, a 128-token chunk of 4 query heads a KV head half the heads,
-    and the largest block ``MAX_KERNEL_Q_ROWS`` admits one head and one
-    page a step: the kernel's form before PR 28, which is also what a
-    shape that fits nowhere gets.
-
-    The account, fitted to what the chip's compiler takes for the
-    batched form (found by lowering ``vmem_limit_bytes`` until it
-    refuses; v5e, PERF.md PR 28) and erring high: the pages and their
-    scale rows, double-buffered; a query row of a head ``row_bytes``
-    (query and output blocks in two buffers, the float32 accumulator and
-    its quotient, the running max and denominator at a lane tile each);
-    and ``SCORE_TILES`` float32 ``[heads, rows, span]`` score tiles
+    Fitted to what the chip's compiler takes for the batched form (found
+    by lowering ``vmem_limit_bytes`` until it refuses; v5e, PERF.md PR
+    28) and erring high: the pages and their scale rows,
+    double-buffered; a query row of a head ``row_bytes`` (query and
+    output blocks in two buffers, the float32 accumulator and its
+    quotient, the running max and denominator at a lane tile each); and
+    ``SCORE_TILES`` float32 ``[heads, rows, span]`` score tiles
     (``ALIBI_TILES`` more under a bias).
     """
     rows = _round_up(rows, 8)
@@ -604,14 +605,61 @@ def kernel_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
     slot_bytes = 2 * 2 * page_size * lanes * kv_itemsize
     scale_bytes = (2 * 2 * _round_up(kv_heads, 8) * _round_up(page_size, 128)
                    * 4 if has_scale else 0)
-    for group in (g for g in (PAGES_PER_STEP, 4, 2, 1)
-                  if page_slots % g == 0):
-        span = _round_up(group * page_size, 128)
+    span = _round_up(group * page_size, 128)
+    return (group * (heads * slot_bytes + scale_bytes)
+            + heads * rows * (row_bytes + tiles * span * 4))
+
+
+def kernel_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
+                  page_slots: int, q_itemsize: int, kv_itemsize: int,
+                  has_scale: bool = False,
+                  has_alibi: bool = False) -> Tuple[int, int]:
+    """``(heads, group)``: how many KV heads and how many page slots one
+    grid step of the ragged kernel holds, read from the call's shapes.
+
+    A grid step is sized by its bytes.  In the pool's layout a page's K
+    and V of ALL heads are one contiguous block, so a step takes every
+    head (one fetch a page) and ``group`` is the widest of
+    ``PAGES_PER_STEP``, 4, 2, 1 that divides the page bucket and keeps
+    the step's pages at or under ``STEP_BYTES``: 8 slots of a 256 KB
+    page (8 KV heads), 2 of a 960 KB one (30 heads), 1 of anything
+    larger.  A wider group of fewer heads moves the same bytes a step and
+    loses (v5e, PERF.md PR 41: 6.95 ms for 4.82 at 256 rows of 740
+    tokens).  Each slot of a group is a buffer of its own and fetches the
+    null page where the row's pages end: ``group`` fetches a row.  A
+    block of SOME heads carries the head block in its index, so the null
+    page is another block for each head block, and a slot that would stay
+    on it from one row to the next fetches it again for each (1.69 of
+    the 2.1 ms).  The rest is the head split itself, two strided pieces
+    a page and two passes over a row (0.55 ms); the arithmetic on dead
+    pages hides behind the fetches.
+
+    Where every head does not fit ``VMEM_BUDGET`` at that group
+    (:func:`step_vmem_bytes`) the step's size is its query rows', not its
+    pages' (a prompt chunk of several query heads a KV head), and the
+    blocks are PR 28's: the widest group first, then the largest divisor
+    of K that fits (K, K/2, ... 1 for a power of two; 30, 15, 10, 6, ...
+    for 30 heads).  A 128-token chunk of 4 query heads a KV head takes
+    half the heads and 8 pages, and the largest block
+    ``MAX_KERNEL_Q_ROWS`` admits one head and one page a step: the
+    kernel's form before PR 28, which is also what a shape that fits
+    nowhere gets.
+    """
+    def fits(heads, group):
+        return step_vmem_bytes(
+            heads, group, rows, kv_heads, head_dim, page_size, q_itemsize,
+            kv_itemsize, has_scale, has_alibi) <= VMEM_BUDGET
+
+    groups = [g for g in (PAGES_PER_STEP, 4, 2, 1) if page_slots % g == 0]
+    # what a step fetches of one page slot: K and V of every head
+    page_bytes = 2 * kv_heads * page_size * head_dim * kv_itemsize
+    by_bytes = next(g for g in groups
+                    if g == 1 or g * page_bytes <= STEP_BYTES)
+    if fits(kv_heads, by_bytes):
+        return kv_heads, by_bytes
+    for group in groups:
         for heads in range(kv_heads, 0, -1):
-            if kv_heads % heads == 0 and (
-                    group * (heads * slot_bytes + scale_bytes)
-                    + heads * rows * (row_bytes + tiles * span * 4)
-                    <= VMEM_BUDGET):
+            if kv_heads % heads == 0 and fits(heads, group):
                 return heads, group
     return 1, 1
 
@@ -645,13 +693,17 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
     of all heads are one contiguous block, so a page is ONE fetch; the
     pool is passed once per page slot of a group, each with its own
     index map, so the pipeline fetches a group's pages side by side.  A
-    group wholly past the row's context (or under its window) is skipped,
-    and costs a grid step and no bytes where its slots hold the null
-    page, as the engine's tables do past a row's pages and under its
-    window (``SequenceDescriptor.page_table``, ``evict_pages_below``):
-    consecutive steps that name the same block fetch nothing.  A table
-    that held real pages there would be attended as correctly, and
-    fetched for nothing.
+    group wholly past the row's context (or under its window) is skipped.
+    Where its slots hold the null page, as the engine's tables do past a
+    row's pages and under its window (``SequenceDescriptor.page_table``,
+    ``evict_pages_below``), consecutive steps that name the same block
+    fetch nothing, so a dead group costs a grid step (0.05-0.09 us at 30
+    KV heads; v5e, PERF.md PR 41) and no bytes FROM THE SECOND ON: a dead
+    slot after a live one fetches the null page once a buffer, ``group``
+    fetches a row of ``heads`` heads of a page each (and again for every
+    block of heads where ``heads`` is not K: :func:`kernel_blocks`).  A
+    table that held real pages there would be attended as correctly, and
+    fetched for nothing in every group of the bucket.
     """
     S, Q, H, D = q.shape
     has_scale = isinstance(kv, KVPages)

@@ -178,10 +178,12 @@ SERVING_BLOCKS = [
     ("laguna-s-serve-5l-ep16", 48, (8, 8), (2, 8)),          # full layers
     ("laguna-s-serve-5l-ep16", 72, (8, 8), (2, 8)),          # window layers
     ("jamba2-3b-serve-28l", 20, (1, 8), (1, 4)),
-    # 30 KV heads, one query head each: every head does not fit beside 8
-    # pages of 960 KB; halving stopped at the odd 15 and gave a prompt row
-    # (15, 4), the largest divisor that fits keeps the group of 8
-    ("olmo-hybrid-7b-serve-4l", 30, (15, 8), (10, 8)),
+    # 30 KV heads, one query head each, a page of 960 KB: a decode step is
+    # sized by its bytes, all 30 heads of 2 slots (1.9 MB; PR 41: timed
+    # alone the quickest of the blocks that fit, (15, 8) before).  A prompt
+    # row's 128 query rows a head leave no room for every head: its blocks
+    # are the rows', the widest group and the largest divisor that fits
+    ("olmo-hybrid-7b-serve-4l", 30, (30, 2), (10, 8)),
 ]
 
 

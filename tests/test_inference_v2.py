@@ -6,6 +6,8 @@ and manager logic) and ``tests/unit/inference/v2/kernels/ragged_ops/``
 decoding reproduces the full-sequence forward exactly.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -417,14 +419,88 @@ class TestRaggedKernelParity:
         (2048, 8, 128, 64, 8, False, (1, 8)),
         (4096, 8, 128, 64, 8, False, (1, 1)),     # MAX_KERNEL_Q_ROWS
         (32, 1, 128, 64, 8, False, (1, 8)),       # multi-query
-        (1, 32, 128, 64, 8, False, (16, 8)),      # K = H: 32 heads' pages
-        (2, 8, 256, 64, 8, False, (8, 8)),
-        (4, 8, 128, 256, 8, False, (4, 8)),       # 1 MiB pages
+        # PR 41: a step is sized by its bytes, every head of a page in it.
+        # K = H = 32: a page is 1 MiB, 2 slots are STEP_BYTES (was (16, 8):
+        # 8 slots of half the heads, 4 MiB a step)
+        (1, 32, 128, 64, 8, False, (32, 2)),
+        # head_dim 256: a 512 KB page, 4 slots (was (8, 8): 4 MiB a step)
+        (2, 8, 256, 64, 8, False, (8, 4)),
+        # 1 MiB pages of 256 tokens: 2 slots of every head (was (4, 8):
+        # half the heads, a page in two strided pieces a head)
+        (4, 8, 128, 256, 8, False, (8, 2)),
+        (1, 30, 128, 64, 40, False, (30, 2)),     # 960 KB pages (Olmo)
+        (1, 30, 128, 64, 5, False, (30, 1)),
+        (128, 30, 128, 64, 8, False, (10, 8)),    # its prompt row: the rows'
     ])
     def test_blocks_follow_the_shapes(self, rows, K, D, page, P, int8,
                                       blocks):
         assert pa.kernel_blocks(rows, K, D, page, P, 2, 1 if int8 else 2,
                                 int8) == blocks
+
+    @pytest.mark.parametrize("alibi", [False, True], ids=["rope", "alibi"])
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("D,page", [(128, 16), (128, 64), (256, 64),
+                                        (128, 256)])
+    @pytest.mark.parametrize("K", [1, 8, 30, 32])
+    def test_a_step_is_sized_by_its_bytes(self, K, D, page, int8, alibi):
+        """The rule's own properties, over pages of 8 KB (one head) to
+        1 MiB and more (32 heads of 256 tokens: 4 MiB): the blocks divide
+        the shapes and fit the account; every head is in the step whenever
+        the step fits at the group the bytes give, and then the step's
+        pages weigh at most ``STEP_BYTES`` unless it holds one."""
+        itemsize = 1 if int8 else 2
+        page_bytes = 2 * K * page * D * itemsize
+        for rows in (1, 4, 20, 128, 512, 4096):
+            for P in (1, 5, 6, 8, 12, 40):
+                heads, group = pa.kernel_blocks(rows, K, D, page, P, 2,
+                                                itemsize, int8, alibi)
+                account = functools.partial(
+                    pa.step_vmem_bytes, rows=rows, kv_heads=K, head_dim=D,
+                    page_size=page, q_itemsize=2, kv_itemsize=itemsize,
+                    has_scale=int8, has_alibi=alibi)
+                case = (rows, P, heads, group)
+                assert K % heads == 0 and P % group == 0, case
+                assert group in (8, 4, 2, 1), case
+                assert ((heads, group) == (1, 1)
+                        or account(heads, group) <= pa.VMEM_BUDGET), case
+                by_bytes = max(g for g in (8, 4, 2, 1) if P % g == 0
+                               and (g == 1 or g * page_bytes
+                                    <= pa.STEP_BYTES))
+                if account(K, by_bytes) <= pa.VMEM_BUDGET:
+                    assert (heads, group) == (K, by_bytes), case
+                    assert (group == 1
+                            or group * page_bytes <= pa.STEP_BYTES), case
+                else:       # the query rows size the step: PR 28's blocks,
+                    # the widest group first, then the most heads that fit
+                    assert all(
+                        account(h, g) > pa.VMEM_BUDGET
+                        for g in (8, 4, 2, 1) if P % g == 0
+                        for h in range(1, K + 1) if K % h == 0
+                        and (g > group or (g == group and h > heads))), case
+
+    @pytest.mark.parametrize("Q", [1, 128], ids=["decode", "prompt-row"])
+    @pytest.mark.parametrize("P", [8, 40])
+    def test_thirty_kv_heads_of_one_query_head(self, P, Q):
+        """Olmo's full layer (30 / 30 heads, a 960 KB page) under the
+        blocks the rule gives it, against the dense reference: contexts
+        that end inside a group of 2 slots (its second slot the null
+        page), at a group's edge, inside the first page and in the
+        bucket's last page, tables null-padded."""
+        K, D, page = 30, 128, 64
+        span = 2 * page
+        ctxs = (max((P // 4) * span + 10, Q + 1), max(P // 4, 1) * span,
+                max(Q, 3), P * page - 2)
+        q, pool, table, start = _ragged_inputs(Q, K, 1, D, page, P, ctxs,
+                                               "bf16")
+        assert _blocks_of(q, pool, P) == ((30, 2) if Q == 1 else (10, 8))
+        got = pa.paged_decode_attention(q, pool, LAYER, table, start,
+                                        interpret=True)
+        lens = jnp.full(start.shape, Q, jnp.int32)
+        want = pa.attention_reference(
+            q.astype(jnp.float32),
+            *pa.paged_context(pool, LAYER, table, jnp.float32), start, lens)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=2e-2, atol=2e-2)
 
 
 def _placed_by_hand(pool, layer, k_new, v_new, table, start, q_lens):

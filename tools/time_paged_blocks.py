@@ -1,0 +1,190 @@
+#!/usr/bin/env python3
+"""Time ``paged_decode_attention`` alone on the chip under every
+``(heads, group)`` that fits ``VMEM_BUDGET``, with ``kernel_blocks``
+overridden by hand: the sweep that ``STEP_BYTES`` is set from (PERF.md,
+PR 41).
+
+    chiprun -- python3 tools/time_paged_blocks.py --kv-heads 30 \
+        --out chiprun_out/blocks.json
+
+One row of output a (page bucket, blocks): ms a call (``--calls`` calls back
+to back on the host's clock) at each context, under three tables whose
+live slots are the same and whose slots past a row's context hold
+
+* ``null``:   the null page, as the engine's tables do;
+* ``repeat``: the page the slot's buffer already holds (the live page
+  ``group`` slots back), so that a dead slot fetches nothing: ``null``
+  less ``repeat`` is what the null page's fetches cost;
+* ``real``:   pages of their own, fetched in every group of the bucket.
+
+A context of 1 token is one live group a row and the rest of the bucket
+dead: a grid step's fixed cost.  Contexts at a group's edge (512, 1024)
+against one page past it (576) split a last group's cost into its fetch
+and its arithmetic.  Every output is compared with the first blocks'.
+"""
+import argparse
+import json
+import os
+import sys
+import threading
+import time
+from unittest import mock
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.ops import paged_attention as pa
+
+
+def tables(ctxs, page, P, group, pages):
+    """The three page tables ``[S, P]`` of the module's docstring."""
+    S = len(ctxs)
+    live = -(-np.asarray(ctxs) // page)                     # pages a row
+    per_row = pages // S
+    own = 1 + (np.arange(S)[:, None] * per_row + np.arange(P)[None]) % pages
+    dead = np.arange(P)[None] >= live[:, None]
+    null = np.where(dead, 0, own)
+    repeat = null.copy()
+    for p in range(group, P):       # left to right: the value cascades
+        repeat[:, p] = np.where(dead[:, p], repeat[:, p - group],
+                                repeat[:, p])
+    return {"null": null, "repeat": repeat, "real": own}
+
+
+def candidates(args, rows, P):
+    """Blocks that divide the shapes and fit the account, the rule's own
+    first."""
+    out = [pa.kernel_blocks(rows, args.kv_heads, args.head_dim, args.page,
+                            P, 2, 2)]
+    for heads in range(args.kv_heads, args.min_heads - 1, -1):
+        for group in (8, 4, 2, 1):
+            if (args.kv_heads % heads == 0 and P % group == 0
+                    and (heads, group) not in out
+                    and pa.step_vmem_bytes(
+                        heads, group, rows, args.kv_heads, args.head_dim,
+                        args.page, 2, 2) <= pa.VMEM_BUDGET):
+                out.append((heads, group))
+    return out
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--kv-heads", type=int, default=30)
+    ap.add_argument("--q-per-kv", type=int, default=1)
+    ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--page", type=int, default=64)
+    ap.add_argument("--rows", type=int, default=256)
+    ap.add_argument("--q-len", type=int, default=1)
+    ap.add_argument("--pages", type=int, default=8960)
+    ap.add_argument("--buckets", type=int, nargs="+", default=[8, 40])
+    ap.add_argument("--contexts", type=int, nargs="+",
+                    default=[1, 100, 400, 512, 576, 640, 740, 1024, 1300,
+                             2100])
+    ap.add_argument("--mix", type=int, nargs=2, default=[100, 2200],
+                    help="a last column of contexts uniform in this range")
+    ap.add_argument("--min-heads", type=int, default=5)
+    ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=41)
+    ap.add_argument("--interpret", action="store_true",
+                    help="the CPU rehearsal (tiny shapes)")
+    ap.add_argument("--out", default="chiprun_out/paged_blocks.json")
+    args = ap.parse_args()
+
+    K, G, D, page, S, Q = (args.kv_heads, args.q_per_kv, args.head_dim,
+                           args.page, args.rows, args.q_len)
+    rng = np.random.default_rng(args.seed)
+    key = jax.random.PRNGKey(args.seed)
+    # a pool of distinct pages from one random block, tiled (its values
+    # are read, not judged: parity is the tests')
+    block = jax.random.normal(key, (1, 257, 2, K, page, D), jnp.bfloat16)
+    # (one program, whole blocks: a concatenation or a slice of the result
+    # would hold a second pool)
+    reps = -(-(args.pages + 1) // 257)
+    pool = jax.jit(lambda b: jnp.broadcast_to(
+        b[:, None], (1, reps) + b.shape[1:]).reshape(
+            (1, reps * 257) + b.shape[2:]))(block)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (S, Q, K * G, D),
+                          jnp.bfloat16)
+    device = jax.devices()[0]
+    print(f"device {device.platform} {device.device_kind}; pool "
+          f"{pool.nbytes / 1e9:.2f} GB, a page {pool[0, 0].nbytes} B",
+          flush=True)
+
+    # a call that hangs must not hold the chip (PERF.md, PR 27)
+    beat = [time.monotonic()]
+
+    def watchdog():
+        while True:
+            time.sleep(5)
+            if time.monotonic() - beat[0] > 100:
+                print("watchdog: 100 s in one call", flush=True)
+                os._exit(3)
+    threading.Thread(target=watchdog, daemon=True).start()
+
+    def ms_a_call(run, *operands):
+        out = run(*operands)                    # warm
+        out.block_until_ready()
+        beat[0] = time.monotonic()
+        t0 = time.perf_counter()
+        for _ in range(args.calls):
+            out = run(*operands)
+        out.block_until_ready()
+        ms = (time.perf_counter() - t0) * 1e3 / args.calls
+        beat[0] = time.monotonic()
+        return ms, out
+
+    results = []
+    for P in args.buckets:
+        cap = P * page
+        ctx_sets = {str(c): np.full(S, c) for c in args.contexts
+                    if Q <= c <= cap}
+        lo, hi = max(args.mix[0], Q), min(args.mix[1], cap)
+        ctx_sets["mix"] = rng.integers(lo, hi + 1, S)
+        first = {}
+        for heads, group in candidates(args, Q * G, P):
+            fn = jax.jit(lambda q, kv, table, start:
+                         pa.paged_decode_attention(
+                             q, kv, 0, table, start,
+                             interpret=args.interpret))
+            t0 = time.monotonic()
+            try:
+                with mock.patch.object(pa, "kernel_blocks",
+                                       lambda *a, **k: (heads, group)):
+                    run = fn.lower(q, pool, jnp.zeros((S, P), jnp.int32),
+                                   jnp.zeros((S,), jnp.int32)).compile()
+            except Exception as e:      # the chip's compiler refused it
+                print(f"P={P} ({heads}, {group}): refused: "
+                      f"{str(e).splitlines()[0][:200]}", flush=True)
+                continue
+            beat[0] = time.monotonic()
+            row = {"bucket": P, "heads": heads, "group": group,
+                   "step_bytes": 2 * heads * group * page * D * 2,
+                   "compile_s": round(beat[0] - t0, 3), "ms": {},
+                   "max_abs_diff": 0.0}
+            for name, ctxs in ctx_sets.items():
+                start = jnp.asarray(ctxs - Q, jnp.int32)
+                for kind, table in tables(ctxs, page, P, group,
+                                          args.pages).items():
+                    ms, out = ms_a_call(run, q, pool,
+                                        jnp.asarray(table, jnp.int32), start)
+                    row["ms"].setdefault(name, {})[kind] = round(ms, 4)
+                    if kind == "null":      # one answer whatever the blocks
+                        ref = first.setdefault(name, out)
+                        row["max_abs_diff"] = max(
+                            row["max_abs_diff"], float(jnp.max(jnp.abs(
+                                out.astype(jnp.float32)
+                                - ref.astype(jnp.float32)))))
+            results.append(row)
+            print(json.dumps(row), flush=True)
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump({"device": device.device_kind, "args": vars(args),
+                   "rows": results}, f, indent=1)
+    print(json.dumps({"ok": True, "rows": len(results)}))
+
+
+if __name__ == "__main__":
+    main()
