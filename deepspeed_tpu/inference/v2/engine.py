@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from ...ops.paged_attention import slots_held
 from ...telemetry import metrics as tm
 from ...telemetry import trace_span
 from ...utils.comms_logging import serving_counters
@@ -396,6 +397,10 @@ class InferenceEngineV2:
         self.counts_attended = self._state.window_cache is not None or (
             model.state_config is not None
             and model.state_config.kind == "delta")
+        #: the table of the newest decode segment's true rows, for the
+        #: step span's page-slot counts (``take_slots_held``, which does
+        #: the counting: only a live span asks)
+        self._decode_table: Optional[np.ndarray] = None
         #: (previous token vector's length, slots) -> the compiled gather
         #: of a decode segment's token ids (``_form_gathers``)
         self._gathers: Dict[Tuple[int, int], object] = {}
@@ -1144,6 +1149,8 @@ class InferenceEngineV2:
                 self._attended = (
                     int(ctx.sum()),
                     int(np.minimum(ctx, self._state.window).sum()))
+            if batch.max_q == 1:
+                self._decode_table = batch.page_table[:len(batch.uids)]
             nbytes = (batch.q_lens.nbytes + batch.start_pos.nbytes
                       + batch.page_table.nbytes)
             if h2d_tokens:
@@ -1159,6 +1166,31 @@ class InferenceEngineV2:
         count is 0; (0, 0) otherwise)."""
         out, self._attended = self._attended, (0, 0)
         return out
+
+    def take_slots_held(self) -> Optional[Tuple[int, int]]:
+        """(held, live) page slots of the newest decode segment built
+        since the last call, summed over its page groups: the slots that
+        hold a page, and the dead ones after them for which the paged
+        kernel's index maps name the block their buffer holds, where the
+        null page cost a fetch (``ops/paged_attention.py::fetch_table``,
+        counted by its ``slots_held`` at the group a decode row's call
+        takes).  (0, 0) for a step without decode rows; None for the
+        latent kind, whose kernel walks a row's own pages."""
+        table, self._decode_table = self._decode_table, None
+        if self._model.cfg.latent_dim:
+            return None
+        if table is None:
+            return 0, 0
+        parts = (self._table.split(table, 1) if self._table is not None
+                 else {"full": table})
+        held = live = 0
+        for kind in ("full", "window"):
+            if kind in parts:
+                group = self._model.decode_page_group(
+                    parts[kind].shape[1], kind)
+                held_here, live_here = slots_held(parts[kind], group)
+                held, live = held + held_here, live + live_here
+        return held, live
 
     def _prev_len(self, prev_tokens) -> int:
         """A chain key's ``prev_len`` for the previous step's token

@@ -1783,3 +1783,26 @@ class RaggedInferenceModel:
             return max_new_tokens, need
         tokens = capacity + max_new_pages * page
         return max(tokens, 0), max_new_pages
+
+    def decode_page_group(self, page_slots: int, kind: str = "full") -> int:
+        """Page slots a grid step of the paged K/V kernel holds for a
+        decode row of a ``kind`` layer over a table of ``page_slots``
+        (``ops/paged_attention.py::kernel_blocks`` at the shapes of the
+        call a tp shard makes): the group the step span's page-slot
+        counts are taken at (``engine.take_slots_held``).  (Down here:
+        lines added above a kernel's call move the callers' line numbers
+        that Mosaic writes into every step program.)"""
+        from ...ops.paged_attention import kernel_blocks
+        cfg = (self._kind_cfg[kind] if self.window_kv_config is not None
+               else self.cfg)
+        kv = self.window_kv_config if kind == "window" else self.kv_config
+        alibi = cfg.pos_emb == "alibi"
+        tp = self.tp_degree
+        if alibi or cfg.kv_heads % tp or cfg.num_heads % tp:
+            tp = 1                  # not split by head: _per_shard_heads
+        itemsize = jnp.dtype(cfg.dtype).itemsize
+        return kernel_blocks(
+            cfg.num_heads // cfg.kv_heads, kv.kv_heads // tp, kv.head_dim,
+            kv.page_size, page_slots, itemsize,
+            1 if kv.quantized else jnp.dtype(kv.dtype).itemsize,
+            kv.quantized, alibi)[1]

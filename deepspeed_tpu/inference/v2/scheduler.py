@@ -1497,6 +1497,13 @@ class FastGenScheduler:
                 ("program", self._engine.model.last_program
                  if path != "idle" else "idle")):
             span.set(key, value)
+        slots = self._engine.take_slots_held()
+        if slots is not None:
+            # page slots of the step's decode rows under the paged
+            # kernel's fetch table: held / (held + live) is the share of
+            # the rows' page fetches that the null page used to be
+            span.set("kv_slots_held", slots[0])
+            span.set("kv_slots_live", slots[1])
         state = self._engine.state_manager
         if state.window_cache is not None:
             # the window group of a model with two page groups: what its

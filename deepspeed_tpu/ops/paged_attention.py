@@ -625,14 +625,14 @@ def kernel_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
     page (8 KV heads), 2 of a 960 KB one (30 heads), 1 of anything
     larger.  A wider group of fewer heads moves the same bytes a step and
     loses (v5e, PERF.md PR 41: 6.95 ms for 4.82 at 256 rows of 740
-    tokens).  Each slot of a group is a buffer of its own and fetches the
-    null page where the row's pages end: ``group`` fetches a row.  A
-    block of SOME heads carries the head block in its index, so the null
-    page is another block for each head block, and a slot that would stay
-    on it from one row to the next fetches it again for each (1.69 of
-    the 2.1 ms).  The rest is the head split itself, two strided pieces
-    a page and two passes over a row (0.55 ms); the arithmetic on dead
-    pages hides behind the fetches.
+    tokens).  Each slot of a group is a buffer of its own, and under
+    tables that name the null page where a row's pages end it fetched
+    that page once a row: ``group`` fetches a row, and with a block of
+    SOME heads, whose index carries the head block, once more for each
+    head block (1.69 of the 2.1 ms; :func:`fetch_table` now keeps such a
+    slot on the block it holds).  The rest is the head split itself, two
+    strided pieces a page and two passes over a row (0.55 ms); the
+    arithmetic on dead pages hides behind the fetches.
 
     Where every head does not fit ``VMEM_BUDGET`` at that group
     (:func:`step_vmem_bytes`) the step's size is its query rows', not its
@@ -693,17 +693,21 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
     of all heads are one contiguous block, so a page is ONE fetch; the
     pool is passed once per page slot of a group, each with its own
     index map, so the pipeline fetches a group's pages side by side.  A
-    group wholly past the row's context (or under its window) is skipped.
-    Where its slots hold the null page, as the engine's tables do past a
-    row's pages and under its window (``SequenceDescriptor.page_table``,
-    ``evict_pages_below``), consecutive steps that name the same block
-    fetch nothing, so a dead group costs a grid step (0.05-0.09 us at 30
-    KV heads; v5e, PERF.md PR 41) and no bytes FROM THE SECOND ON: a dead
-    slot after a live one fetches the null page once a buffer, ``group``
-    fetches a row of ``heads`` heads of a page each (and again for every
-    block of heads where ``heads`` is not K: :func:`kernel_blocks`).  A
-    table that held real pages there would be attended as correctly, and
-    fetched for nothing in every group of the bucket.
+    group wholly past the row's context (or under its window) is skipped,
+    and what a row sees is decided by POSITION (``start_pos``, the
+    window), never by page id.  ``page_table`` is the engine's: the null
+    page past a row's pages and under its window
+    (``SequenceDescriptor.page_table``, ``evict_pages_below``).  The
+    index maps read :func:`fetch_table` of it instead: a slot that holds
+    nothing for the row names the block its buffer already holds, and
+    the pipeline, which copies nothing when consecutive steps name the
+    same block, fetches nothing for it, where the null page cost a fetch
+    a slot and row (0.31 of a 2.56 ms call at 8 KV heads, 0.27 of 6.83
+    at 30; v5e, PERF.md PR 41).  A dead group still costs its grid step
+    (0.05-0.09 us at 30 KV heads).  The borrowed page's columns are
+    masked like the null page's, so outputs are the same to the bit; a
+    table of real pages in dead slots would be attended as correctly,
+    and fetched for nothing in every group of the bucket.
     """
     S, Q, H, D = q.shape
     has_scale = isinstance(kv, KVPages)
@@ -779,9 +783,55 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
         name=name + ("_decode" if Q == 1 else "_prefill"),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      page_table.astype(jnp.int32), start_pos.astype(jnp.int32), *inputs)
+      fetch_table(page_table.astype(jnp.int32), group),
+      start_pos.astype(jnp.int32), *inputs)
     out = out.reshape(S, K, Q, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(S, Q, H, D)
+
+
+def fetch_table(page_table: jax.Array, group: int) -> jax.Array:
+    """The table the ragged kernel's index maps READ, from the engine's
+    ``[S, P]`` table and the kernel's page group: a slot that holds the
+    null page (id 0: past the row's pages, or under its window) takes the
+    page of the nearest live slot BEFORE it in its column of the
+    ``[S, P // group, group]`` view (``p - group``, ``p - 2 * group``,
+    ...), which is the block that slot's pipeline buffer already holds,
+    so the pipeline fetches nothing for it.  Live slots are never
+    altered, and a dead slot with no live one before it keeps the null
+    page (a whole table where a row is one group: nothing comes before).
+
+    "The last page that is not null" is associative, so the columns are
+    scanned by doubling: ``log2(P // group)`` shifts and selects over
+    ``[S, P]`` integers, which the compiler fuses, shares between the
+    unrolled layers of a page group and lifts out of a scanned stack
+    (``tests/test_chip_compile.py``).  (A running maximum and a gather
+    say the same and cost 0.03-0.16 ms a call on a v5e, a third of what
+    the rule saves: PERF.md PR 44.)  For READS only: the cache write,
+    eviction, the dense gather (:func:`paged_context`) and everything on
+    the host keep the engine's table, since a dead slot must never be
+    written through a borrowed page id."""
+    P = page_table.shape[1]
+    shift = group
+    with jax.named_scope("fetch_table"):
+        while shift < P:
+            # the table ``shift`` slots to the right, nulls moving in
+            before = jax.lax.pad(page_table, jnp.zeros((), page_table.dtype),
+                                 ((0, 0, 0), (shift, -shift, 0)))
+            page_table = jax.lax.select(page_table != 0, page_table, before)
+            shift *= 2
+    return page_table
+
+
+def slots_held(page_table: np.ndarray, group: int) -> Tuple[int, int]:
+    """(held, live) page slots of a host table ``[S, P]`` under
+    :func:`fetch_table`: ``held`` counts the dead slots of a group with a
+    live slot that name a borrowed block (each a fetch of the null page
+    the row no longer makes), ``live`` the slots that hold a page."""
+    S, P = page_table.shape
+    live = (page_table != 0).reshape(S, max(P // group, 1), -1)
+    seen = np.logical_or.accumulate(live, axis=1)
+    held = ~live & seen & live.any(axis=2, keepdims=True)
+    return int(held.sum()), int(live.sum())
 
 
 def gather_last(x: jax.Array, q_lens: jax.Array) -> jax.Array:

@@ -32,9 +32,12 @@ from deepspeed_tpu.ops.fused_optimizer import (fused_adamw_flat,
                                                fused_lamb_flat,
                                                fused_lion_flat)
 from deepspeed_tpu.ops.normalization import layernorm, rmsnorm
+from deepspeed_tpu.ops import paged_attention as paged_ops
 from deepspeed_tpu.ops.paged_attention import (MAX_KERNEL_Q_ROWS, KVPages,
                                                kernel_blocks,
-                                               paged_attention, write_kv)
+                                               paged_attention,
+                                               paged_decode_attention,
+                                               write_kv)
 from deepspeed_tpu.ops.quantization import (dequantize_blockwise,
                                             quantize_blockwise)
 
@@ -206,6 +209,116 @@ def test_kernel_blocks_of_the_serving_configurations(name, heads, decode,
             assert got == want, (name, q, buckets, got)
             assert K % got[0] == 0 and buckets % got[1] == 0
             assert got != (1, 1) or K == 1
+
+
+def fetch_table_steps(page_slots: int, group: int) -> int:
+    """Selects ``fetch_table`` makes of a table ``page_slots`` wide under a
+    page group of ``group``: one a doubling of the shift."""
+    return max(int(np.ceil(np.log2(page_slots / group))), 0)
+
+
+def fetch_table_selects(text: str) -> int:
+    """The selects of ``fetch_table`` (its operations run under a scope of
+    that name) that a compiled program's text holds, inside fusions too."""
+    return sum(" select(" in line and "fetch_table/" in line
+               for line in text.splitlines())
+
+
+def asked_of_fetch_table(monkeypatch) -> list:
+    """(page slots, group) of every call of ``fetch_table`` from here on,
+    as they are traced."""
+    asked, rule = [], paged_ops.fetch_table
+    monkeypatch.setattr(
+        paged_ops, "fetch_table", lambda table, group: asked.append(
+            (table.shape[1], group)) or rule(table, group))
+    return asked
+
+
+def mosaic_texts(lowered_text: str) -> list:
+    """The Mosaic modules of a lowered program's Pallas calls, as text with
+    their source locations dropped (a caller's moved line is no change of
+    the kernel)."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    texts = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           lowered_text):
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            texts.append(module.operation.get_asm(enable_debug_info=False))
+    return texts
+
+
+#: (KV heads, query heads, window) of the serving configurations' attention
+#: layers, and per (Q, page slots) the first 16 hex digits of the SHA-256
+#: of the kernel's Mosaic text at 256 decode rows / 4 prompt rows of 128
+#: over a ``[2, 257, 2, K, 64, 128]`` bfloat16 pool.  Read on PR 41's tree
+#: and unmoved by PR 44 (the fetch table changes what the index maps are
+#: GIVEN, nothing of the kernel): a change here is a change of the kernel,
+#: and every cached step program that holds it forms anew.
+KERNEL_TEXTS = {
+    "mistral": (8, 32, None, {
+        (1, 8): "d87ed520d42599dd", (1, 40): "4346f45a471ff353",
+        (128, 8): "bd540c30e7a0837d", (128, 40): "94034f8d4b1da81c"}),
+    "laguna-full": (8, 48, None, {
+        (1, 8): "9437ebfb0c3f7669", (1, 40): "60c94cc8b06ec047",
+        (128, 8): "988b9cdb8d1b436b", (128, 40): "f0e95fe0e59fba59"}),
+    # the window group's own table too: 16 slots a decode row, 18 a
+    # 128-token prompt row
+    "laguna-window": (8, 72, 512, {
+        (1, 8): "b5e728ee121cef50", (1, 40): "bcda121c25ac0b4a",
+        (128, 8): "41a4a1bead0ed216", (128, 40): "2b23d6777eb45ecb",
+        (1, 16): "aa27fecb0685e40d", (128, 18): "cf9073bcfb3b90e6"}),
+    "jamba": (1, 20, None, {
+        (1, 8): "19cd7c44e5e89967", (1, 40): "ef1e12b50a457918",
+        (128, 8): "d7f4616537d90c19", (128, 40): "c0e67a71ec666752"}),
+    "olmo": (30, 30, None, {
+        (1, 8): "cf278955ef408b12", (1, 40): "2ae4c60734b977b2",
+        (128, 8): "625d87dc1a61f275", (128, 40): "d328baadb1ce3cb9"}),
+}
+
+
+@pytest.mark.parametrize("name,rows,page_slots", [
+    (name, rows, slots) for name, shape in KERNEL_TEXTS.items()
+    for rows, slots in shape[3]],
+    ids=lambda v: str(v))
+def test_the_fetch_table_leaves_the_kernels_text_alone(
+        chip, monkeypatch, name, rows, page_slots):
+    """The paged kernel's lowered text for the described chip is the
+    parent's at every pinned shape, and is the same whether its index
+    maps read the fetch table or the engine's table as given: the rule
+    is integer operations BESIDE the kernel."""
+    import hashlib
+    K, heads, window, digests = KERNEL_TEXTS[name]
+    kernel = "paged_attention_window" if window else "paged_attention"
+
+    def texts():
+        lowered = jax.jit(
+            lambda q, kv, table, start: paged_decode_attention(
+                q, kv, 1, table, start, window=window, name=kernel)
+        ).lower(chip((256 if rows == 1 else 4, rows, heads, 128),
+                     jnp.bfloat16),
+                chip((2, 257, 2, K, PAGE, 128), jnp.bfloat16),
+                chip((256 if rows == 1 else 4, page_slots), jnp.int32),
+                chip((256 if rows == 1 else 4,), jnp.int32)).as_text()
+        return lowered, mosaic_texts(lowered)
+
+    program, under_rule = texts()
+    monkeypatch.setattr(paged_ops, "fetch_table", lambda table, group: table)
+    as_given, without = texts()
+    assert len(under_rule) == 1 and under_rule == without
+    group = kernel_blocks(rows * (heads // K), K, 128, PAGE, page_slots,
+                          2, 2)[1]
+    # ... and the rule is in the program wherever a row is several groups
+    assert (program.count("stablehlo.select")
+            - as_given.count("stablehlo.select")
+            == fetch_table_steps(page_slots, group))
+    assert hashlib.sha256(under_rule[0].encode()).hexdigest()[:16] \
+        == digests[rows, page_slots]
 
 
 @pytest.mark.parametrize("slots,rows,page_slots", [
@@ -390,6 +503,9 @@ def test_step_program_leaves_the_pool_in_place(chip, monkeypatch, kind, int8,
     text = compiled.as_text()
     assert pool_sized_movers(text, layer_bytes) == []
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    # a page bucket of 8 is one page group: the kernel's index maps read
+    # the engine's table, and the program holds nothing of the fetch table
+    assert fetch_table_selects(text) == 0
     if scan_layers:
         # one trunk pass: no stacked weight is re-laid out for a second loop
         assert stack_shaped_movers(text, params) == []
@@ -636,9 +752,19 @@ def test_laguna_step_program_moves_no_pool_and_no_expert_stack(
         step_avals(serve, key, pool))
     # the window group's table rides the page table: 16 slots and a base
     assert (key.S, key.P + 16 + 1) in [a.shape for a in avals[2:]]
+    asked = asked_of_fetch_table(monkeypatch)
     compiled = jax.jit(step_program(serve, key),
                        donate_argnums=(1,)).lower(*avals).compile()
     text = compiled.as_text()
+    if key.kind == "chain":
+        # the fetch table of each page group at the group the engine
+        # counts a step's page slots at, and computed ONCE a page group:
+        # shared by the group's layers, lifted out of their scan
+        groups = {(key.P, serve.decode_page_group(key.P, "full")),
+                  (16, serve.decode_page_group(16, "window"))}
+        assert set(asked) == groups == {(key.P, 8), (16, 8)}
+        assert fetch_table_selects(text) == sum(
+            fetch_table_steps(*g) for g in groups)
     row = "decode" if key.Q == 1 else "prefill"
     for kernel in (f"paged_attention_{row}", f"paged_attention_window_{row}",
                    f"kv_write_{row}", "moe_expert_ffn"):
@@ -753,9 +879,16 @@ def test_jamba_step_program_moves_no_pool_and_no_weight_stack(
     # the state slot rides the page table's last column: no new operand,
     # no new field of the key
     assert (key.S, key.P + 1) in [a.shape for a in avals[2:]]
+    asked = asked_of_fetch_table(monkeypatch)
     compiled = jax.jit(step_program(serve, key),
                        donate_argnums=(1,)).lower(*avals).compile()
     text = compiled.as_text()
+    if key.kind == "chain":
+        group = serve.decode_page_group(key.P)
+        assert set(asked) == {(key.P, group)} == {(40, 8)}
+        # once for each of the two attention layers, which sit in two
+        # different periods of the scanned stack
+        assert fetch_table_selects(text) <= 2 * fetch_table_steps(40, 8)
     row = "decode" if key.Q == 1 else "prefill"
     kernels = [f"kv_write_{row}", "ssm_state_update_decode" if key.Q == 1
                else "ssm_scan_prefill"]
@@ -883,9 +1016,14 @@ def test_olmo_hybrid_step_program_moves_no_pool_and_no_weight_stack(
         lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
         step_avals(serve, key, pool))
     assert (key.S, key.P + 1) in [a.shape for a in avals[2:]]
+    asked = asked_of_fetch_table(monkeypatch)
     compiled = jax.jit(step_program(serve, key),
                        donate_argnums=(1,)).lower(*avals).compile()
     text = compiled.as_text()
+    if key.kind == "chain":
+        group = serve.decode_page_group(key.P)
+        assert set(asked) == {(key.P, group)} == {(40, 2)}
+        assert fetch_table_selects(text) == fetch_table_steps(40, 2)
     row = "decode" if key.Q == 1 else "prefill"
     kernels = [f"kv_write_{row}", "delta_state_update_decode"
                if key.Q == 1 else "delta_chunk_prefill"]

@@ -7,6 +7,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "tools"))
@@ -30,14 +31,20 @@ def test_tables_differ_only_past_a_rows_context():
             assert t["repeat"][s, p] == want
 
 
-def test_the_tool_runs_on_the_cpu(tmp_path):
+@pytest.mark.parametrize("window", [None, 16], ids=["full", "window"])
+def test_the_tool_runs_on_the_cpu(tmp_path, window):
+    """End to end in interpret mode, a full layer's call and a window
+    layer's (``--window``: a table ``--buckets`` slots wide whatever the
+    context): the five columns of every context, the rule inside the call
+    (``program``) as correct as the tables as given."""
     out = tmp_path / "blocks.json"
     done = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "time_paged_blocks.py"),
          "--kv-heads", "4", "--head-dim", "32", "--page", "8", "--rows", "3",
          "--pages", "300", "--buckets", "4", "--contexts", "1", "20",
          "--mix", "3", "30", "--min-heads", "2", "--calls", "1",
-         "--interpret", "--out", str(out)],
+         "--interpret", "--out", str(out)]
+        + (["--window", str(window)] if window else []),
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
         text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
@@ -47,5 +54,6 @@ def test_the_tool_runs_on_the_cpu(tmp_path):
         (h, g) for h in (4, 2) for g in (4, 2, 1)}
     for r in rows:
         assert set(r["ms"]) == {"1", "20", "mix"}
-        assert set(r["ms"]["mix"]) == {"null", "repeat", "real"}
+        assert set(r["ms"]["mix"]) == {"null", "repeat", "carry", "real",
+                                       "program"}
         assert r["max_abs_diff"] < 2e-2

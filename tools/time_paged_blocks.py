@@ -8,19 +8,36 @@ PR 41).
         --out chiprun_out/blocks.json
 
 One row of output a (page bucket, blocks): ms a call (``--calls`` calls back
-to back on the host's clock) at each context, under three tables whose
+to back on the host's clock) at each context, under four tables whose
 live slots are the same and whose slots past a row's context hold
 
 * ``null``:   the null page, as the engine's tables do;
 * ``repeat``: the page the slot's buffer already holds (the live page
-  ``group`` slots back), so that a dead slot fetches nothing: ``null``
-  less ``repeat`` is what the null page's fetches cost;
+  ``group`` slots back), so that a dead slot fetches nothing: the
+  program's own ``fetch_table`` of the ``null`` table, so ``null`` less
+  ``repeat`` is what the null page's fetches cost;
+* ``carry``:  ``repeat`` carried across rows as well: a dead slot with no
+  live one before it in its row keeps the previous row's block (a short
+  row's first group).  The program does NOT do this: ``repeat`` less
+  ``carry`` is what it would be worth;
 * ``real``:   pages of their own, fetched in every group of the bucket.
+
+These four reach the kernel's index maps AS GIVEN (``fetch_table`` is
+switched off around the call's lowering); ``program`` is the ``null`` table
+through the call as the step programs make it, the rule and its integer
+operations inside: it should read ``repeat``'s time.
 
 A context of 1 token is one live group a row and the rest of the bucket
 dead: a grid step's fixed cost.  Contexts at a group's edge (512, 1024)
 against one page past it (576) split a last group's cost into its fetch
 and its arithmetic.  Every output is compared with the first blocks'.
+
+``--window 512 --buckets 16`` times a window layer's call (Laguna's: 72
+query heads over 8 KV heads): the table is the window group's, ``--buckets``
+slots wide, holding a row's pages from the first one its window still
+reaches (at most 10 of the 16 at a window of 512 tokens and pages of 64),
+and ``start_pos`` counts from that page, as ``model.py::_by_group`` rebases
+it.
 """
 import argparse
 import json
@@ -40,18 +57,31 @@ from deepspeed_tpu.ops import paged_attention as pa
 
 
 def tables(ctxs, page, P, group, pages):
-    """The three page tables ``[S, P]`` of the module's docstring."""
+    """The four page tables ``[S, P]`` of the module's docstring, for rows
+    that hold ``ctxs`` tokens from their first slot on."""
     S = len(ctxs)
     live = -(-np.asarray(ctxs) // page)                     # pages a row
     per_row = pages // S
     own = 1 + (np.arange(S)[:, None] * per_row + np.arange(P)[None]) % pages
-    dead = np.arange(P)[None] >= live[:, None]
-    null = np.where(dead, 0, own)
-    repeat = null.copy()
-    for p in range(group, P):       # left to right: the value cascades
-        repeat[:, p] = np.where(dead[:, p], repeat[:, p - group],
-                                repeat[:, p])
-    return {"null": null, "repeat": repeat, "real": own}
+    null = np.where(np.arange(P)[None] >= live[:, None], 0, own)
+    repeat = np.asarray(pa.fetch_table(jnp.asarray(null, jnp.int32), group))
+    # the columns of all rows end to end: the last live slot so far
+    columns = null.reshape(-1, group)
+    at = np.where(columns != 0, np.arange(len(columns))[:, None], 0)
+    carry = np.take_along_axis(columns, np.maximum.accumulate(at, axis=0),
+                               axis=0).reshape(S, P)
+    return {"null": null, "repeat": repeat, "carry": carry, "real": own}
+
+
+def held_from(ctxs, page, window, Q):
+    """Tokens a window group's table holds for rows of ``ctxs`` tokens
+    whose last ``Q`` are the queries: everything from the page the FIRST
+    query's window starts in (all of it without a window)."""
+    ctxs = np.asarray(ctxs)
+    if window is None:
+        return ctxs
+    base = np.maximum(ctxs - Q - window + 1, 0) // page
+    return ctxs - base * page
 
 
 def candidates(args, rows, P):
@@ -85,6 +115,9 @@ def main():
                              2100])
     ap.add_argument("--mix", type=int, nargs=2, default=[100, 2200],
                     help="a last column of contexts uniform in this range")
+    ap.add_argument("--window", type=int, default=None,
+                    help="a window layer's call: tables rebased to the "
+                         "window's first page, --buckets slots wide")
     ap.add_argument("--min-heads", type=int, default=5)
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=41)
@@ -137,24 +170,36 @@ def main():
         return ms, out
 
     results = []
+    name = "paged_attention_window" if args.window else "paged_attention"
     for P in args.buckets:
-        cap = P * page
+        # a window table holds a window and the pages at its two ends
+        # whatever the context
+        cap = P * page if args.window is None else max(args.contexts)
         ctx_sets = {str(c): np.full(S, c) for c in args.contexts
                     if Q <= c <= cap}
         lo, hi = max(args.mix[0], Q), min(args.mix[1], cap)
         ctx_sets["mix"] = rng.integers(lo, hi + 1, S)
         first = {}
         for heads, group in candidates(args, Q * G, P):
-            fn = jax.jit(lambda q, kv, table, start:
-                         pa.paged_decode_attention(
-                             q, kv, 0, table, start,
-                             interpret=args.interpret))
+            def call(q, kv, table, start):
+                return pa.paged_decode_attention(
+                    q, kv, 0, table, start, window=args.window,
+                    interpret=args.interpret, name=name)
+            shapes = (q, pool, jnp.zeros((S, P), jnp.int32),
+                      jnp.zeros((S,), jnp.int32))
             t0 = time.monotonic()
             try:
                 with mock.patch.object(pa, "kernel_blocks",
                                        lambda *a, **k: (heads, group)):
-                    run = fn.lower(q, pool, jnp.zeros((S, P), jnp.int32),
-                                   jnp.zeros((S,), jnp.int32)).compile()
+                    program = jax.jit(call).lower(*shapes)
+                    # the tables as given: the rule off.  (Another
+                    # function object: jit keeps a function's trace, and
+                    # would hand back the one with the rule inside.)
+                    with mock.patch.object(pa, "fetch_table",
+                                           lambda table, group: table):
+                        run = jax.jit(lambda *a: call(*a)).lower(*shapes)
+                    assert P <= group or program.as_text() != run.as_text()
+                    program, run = program.compile(), run.compile()
             except Exception as e:      # the chip's compiler refused it
                 print(f"P={P} ({heads}, {group}): refused: "
                       f"{str(e).splitlines()[0][:200]}", flush=True)
@@ -164,15 +209,19 @@ def main():
                    "step_bytes": 2 * heads * group * page * D * 2,
                    "compile_s": round(beat[0] - t0, 3), "ms": {},
                    "max_abs_diff": 0.0}
-            for name, ctxs in ctx_sets.items():
-                start = jnp.asarray(ctxs - Q, jnp.int32)
-                for kind, table in tables(ctxs, page, P, group,
-                                          args.pages).items():
-                    ms, out = ms_a_call(run, q, pool,
-                                        jnp.asarray(table, jnp.int32), start)
-                    row["ms"].setdefault(name, {})[kind] = round(ms, 4)
+            for ctx_name, ctxs in ctx_sets.items():
+                held = held_from(ctxs, page, args.window, Q)
+                start = jnp.asarray(held - Q, jnp.int32)
+                kinds = tables(held, page, P, group, args.pages)
+                for kind, table in (*kinds.items(),
+                                    ("program", kinds["null"])):
+                    ms, out = ms_a_call(
+                        program if kind == "program" else run, q, pool,
+                        jnp.asarray(table, jnp.int32), start)
+                    row["ms"].setdefault(ctx_name, {})[kind] = round(ms, 4)
                     if kind == "null":      # one answer whatever the blocks
-                        ref = first.setdefault(name, out)
+                        ref = first.setdefault(ctx_name, out)
+                    if kind in ("null", "program"):
                         row["max_abs_diff"] = max(
                             row["max_abs_diff"], float(jnp.max(jnp.abs(
                                 out.astype(jnp.float32)
