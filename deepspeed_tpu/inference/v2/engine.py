@@ -1167,30 +1167,36 @@ class InferenceEngineV2:
         out, self._attended = self._attended, (0, 0)
         return out
 
-    def take_slots_held(self) -> Optional[Tuple[int, int]]:
-        """(held, live) page slots of the newest decode segment built
-        since the last call, summed over its page groups: the slots that
-        hold a page, and the dead ones after them for which the paged
-        kernel's index maps name the block their buffer holds, where the
-        null page cost a fetch (``ops/paged_attention.py::fetch_table``,
-        counted by its ``slots_held`` at the group a decode row's call
-        takes).  (0, 0) for a step without decode rows; None for the
-        latent kind, whose kernel walks a row's own pages."""
+    def take_slots_held(self) -> Optional[Tuple[int, int, int]]:
+        """(held, live, bucket) page slots of the newest decode segment
+        built since the last call, summed over its page groups.  ``live``
+        are the slots that hold a page and ``bucket`` all the slots of
+        the rows' tables (rows x table width): ``live / bucket`` is the
+        share of the page bucket that the decode kernel's walk visits
+        (``ops/paged_attention.py::paged_walk_attention``), the rest what
+        a grid over the bucket stepped over.  ``held`` are the dead slots
+        after a live one for which the GRID form's index maps (int8
+        pages, ALiBi) name the block their buffer holds, where the null
+        page cost a fetch (``fetch_table``, counted by its ``slots_held``
+        at the group a decode row's call takes).  All 0 for a step
+        without decode rows; None for the latent kind, whose tables no
+        K/V kernel reads."""
         table, self._decode_table = self._decode_table, None
         if self._model.cfg.latent_dim:
             return None
         if table is None:
-            return 0, 0
+            return 0, 0, 0
         parts = (self._table.split(table, 1) if self._table is not None
                  else {"full": table})
-        held = live = 0
+        held = live = bucket = 0
         for kind in ("full", "window"):
             if kind in parts:
                 group = self._model.decode_page_group(
                     parts[kind].shape[1], kind)
                 held_here, live_here = slots_held(parts[kind], group)
                 held, live = held + held_here, live + live_here
-        return held, live
+                bucket += parts[kind].size
+        return held, live, bucket
 
     def _prev_len(self, prev_tokens) -> int:
         """A chain key's ``prev_len`` for the previous step's token

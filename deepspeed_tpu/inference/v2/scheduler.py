@@ -1499,11 +1499,13 @@ class FastGenScheduler:
             span.set(key, value)
         slots = self._engine.take_slots_held()
         if slots is not None:
-            # page slots of the step's decode rows under the paged
-            # kernel's fetch table: held / (held + live) is the share of
-            # the rows' page fetches that the null page used to be
+            # page slots of the step's decode rows: live / bucket is the
+            # share of the page bucket the decode kernel's walk visits;
+            # held / (held + live) the share of page fetches that the null
+            # page used to be under the grid form's fetch table
             span.set("kv_slots_held", slots[0])
             span.set("kv_slots_live", slots[1])
+            span.set("kv_slots_bucket", slots[2])
         state = self._engine.state_manager
         if state.window_cache is not None:
             # the window group of a model with two page groups: what its

@@ -23,15 +23,15 @@ slice taken out and stacked back costs two layer-sized copies a layer,
 and a scatter with window dims between its indexed dims a re-layout of
 the layer each way (PERF.md, PR 25).
 
-``paged_decode_attention`` is the Pallas ragged kernel: a ``(slot, block
-of kv heads, group of pages)`` grid whose index maps read the page table
-via scalar prefetch: each live KV page is DMA'd HBM->VMEM once, whole (K
-and V of all its heads are one contiguous block), the gathered context
-never materializes, and ``kernel_blocks`` reads a step's heads and page
-slots from the call's shapes.  Q=1 is the decode step; Q>1 rows carry
-chunks with per-row causal limits (Ragged Paged Attention, 2604.15464).
-The jnp formulation is the semantics ground truth and the CPU/CI path;
-``paged_attention`` auto-selects.
+``paged_decode_attention`` is the Pallas ragged kernel, in two forms.  A
+decode step (Q=1) WALKS each row's own live pages: a grid over rows, the
+pool left in HBM, a ring of page tiles copied ahead of the matmuls.  Q>1
+rows (chunks with per-row causal limits: Ragged Paged Attention,
+2604.15464), int8 pages and ALiBi keep a ``(slot, block of kv heads,
+group of pages)`` grid whose index maps read the page table.  Either way a
+live KV page is DMA'd HBM->VMEM once, whole (K and V of all its heads are
+one contiguous block).  The jnp formulation is the semantics ground truth
+and the CPU/CI path; ``paged_attention`` auto-selects.
 """
 
 from __future__ import annotations
@@ -448,6 +448,53 @@ ALIBI_TILES = 2
 STEP_BYTES = 2 * 2 ** 20
 
 
+def _attend_tile(q, plane, ctx0, start, m_scr, l_scr, acc_scr, *, sm_scale,
+                 window, groups, bias=None, scales=None):
+    """Flash-style attention of a row's queries against ONE tile of its
+    context, all KV heads a batched contraction: the arithmetic of both
+    paged kernels (the grid form's step, a chunk of the walk's tile).
+
+    q : [heads, rows, D]  row r = q_idx * G + g, so its causal limit is
+                          ``start + r // G + 1``
+    plane(i) : [heads, span, D]  the tile's K (0) or V (1) plane; column
+                          c is context position ``ctx0() + c`` (both
+                          evaluated where the arithmetic reaches them:
+                          the grid form's text is PR 41's to the letter,
+                          ``tests/test_chip_compile.py``)
+    ``m_scr`` / ``l_scr`` / ``acc_scr`` ``[heads, rows, 1 | D]`` carry the
+    running max / denominator / weighted sum in float32.  ``bias(ctx)``
+    adds to the scores; ``scales(i)`` ``[heads, 1, span]`` multiply the
+    score (0) and the probability (1) columns (int8 pages)."""
+    k = plane(0)
+    rows, span = q.shape[1], k.shape[1]
+    scores = jax.lax.dot_general(                         # [heads, rows, span]
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * sm_scale
+    if scales is not None:
+        scores = scores * scales(0)
+    ctx = ctx0() + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+    if bias is not None:
+        scores = scores + bias(ctx)
+    # per-row causal limit: row r is query index r // G
+    ctx_len = start + 1 + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, span), 0) // groups
+    keep = ctx < ctx_len
+    if window is not None:
+        keep &= ctx >= ctx_len - window
+    scores = jnp.where(keep[None], scores, MASK_VALUE)
+    m_prev = m_scr[:]                                      # [heads, rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
+    pexp = jnp.exp(scores - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    m_scr[:] = m_new
+    l_scr[:] = l_scr[:] * alpha + jnp.sum(pexp, axis=2, keepdims=True)
+    if scales is not None:
+        pexp = pexp * scales(1)
+    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        pexp.astype(q.dtype), plane(1), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+
+
 def _decode_kernel(l_ref, pt_ref, sp_ref, *refs, page_size, group, heads,
                    sm_scale, has_alibi, has_scale, window, q_len, groups):
     """One (slot, block of kv heads, group of pages) grid step of
@@ -528,39 +575,20 @@ def _decode_kernel(l_ref, pt_ref, sp_ref, *refs, page_size, group, heads,
 
     @pl.when(live)
     def _attend():
-        q = q_ref[:]                                       # [heads, Q*G, D]
-        scores = jax.lax.dot_general(                      # [heads, Q*G, span]
-            q, plane(0), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * sm_scale
-        if has_scale:
-            scores = scores * scales(0)
-        ctx = j * span + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 1)
+        bias = None
         if has_alibi:  # additive bias linear in the absolute key position
             # row r = q_idx * G + g: split the row dim so the per-head
             # slope is a plain broadcast (Mosaic lowers reshapes and
             # rank-2 iota; it rejects 1-D iota and in-kernel gathers)
-            pos = ctx.astype(jnp.float32).reshape(q_len, groups, span)
-            scores = scores + by_head(lambda k: (
-                slopes_ref[pl.ds(k, 1), :][:, :, None] * pos
-            ).reshape(rows, span))
-        # per-row causal limit: row r is query index r // G
-        ctx_len = start + 1 + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, span), 0) // groups
-        keep = ctx < ctx_len
-        if window is not None:
-            keep &= ctx >= ctx_len - window
-        scores = jnp.where(keep[None], scores, MASK_VALUE)
-        m_prev = m_scr[:]                                  # [heads, Q*G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
-        pexp = jnp.exp(scores - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(pexp, axis=2, keepdims=True)
-        if has_scale:
-            pexp = pexp * scales(1)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            pexp.astype(q.dtype), plane(1), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+            def bias(ctx):
+                pos = ctx.astype(jnp.float32).reshape(q_len, groups, span)
+                return by_head(lambda k: (
+                    slopes_ref[pl.ds(k, 1), :][:, :, None] * pos
+                ).reshape(rows, span))
+        _attend_tile(q_ref[:], plane, lambda: j * span, start, m_scr, l_scr,
+                     acc_scr, sm_scale=sm_scale, window=window,
+                     groups=groups, bias=bias,
+                     scales=scales if has_scale else None)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
@@ -578,6 +606,13 @@ def _flatten_context(pages: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 def _round_up(n: int, tile: int) -> int:
     return -(-n // tile) * tile
+
+
+def _row_bytes(lanes: int, q_itemsize: int) -> int:
+    """VMEM a query row of one KV head costs a step of either form: the
+    query and output blocks in two buffers, the float32 accumulator and
+    its quotient, the running max and denominator at a lane tile each."""
+    return 2 * 2 * lanes * q_itemsize + 2 * lanes * 4 + 3 * 128 * 4
 
 
 def step_vmem_bytes(heads: int, group: int, rows: int, kv_heads: int,
@@ -598,7 +633,7 @@ def step_vmem_bytes(heads: int, group: int, rows: int, kv_heads: int,
     """
     rows = _round_up(rows, 8)
     lanes = _round_up(head_dim, 128)
-    row_bytes = 2 * 2 * lanes * q_itemsize + 2 * lanes * 4 + 3 * 128 * 4
+    row_bytes = _row_bytes(lanes, q_itemsize)
     tiles = SCORE_TILES + (ALIBI_TILES if has_alibi else 0)
     # one page slot of one head, K and V, in two buffers; the slot's
     # scale rows come whole whatever the step's heads
@@ -676,17 +711,50 @@ def paged_decode_attention(q: jax.Array, kv: jax.Array, layer,
     TPU-native counterpart of the reference's blocked_flash atoms
     (``inference/v2/kernels/ragged_ops/atom_builder/`` splits sequences
     into KV blocks per thread block; here a group of whole pages IS the
-    block and the page table drives the BlockSpec index maps through
-    scalar prefetch).  Q = 1 is the classic decode step; Q > 1 rows carry
-    prefill chunks with per-row causal limits, so one launch serves a
-    fused mixed prefill+decode ragged batch (the single-kernel serving
-    formulation of Ragged Paged Attention, arxiv 2604.15464).
+    block and the page table names them through scalar prefetch).
 
     q: [S, Q, H, D]; kv: [L, num_pages+1, 2, K, page_size, D] with
-    ``layer`` an int32 scalar (a third scalar-prefetch operand: the
-    index maps address ``pool[layer, page]``, so no layer is ever
-    sliced out of the pool); page_table: [S, P]; start_pos: [S].
-    Returns [S, Q, H, D].
+    ``layer`` an int32 scalar (a scalar-prefetch operand: the kernels
+    address ``pool[layer, page]``, so no layer is ever sliced out of the
+    pool); page_table: [S, P]; start_pos: [S].  Returns [S, Q, H, D].
+
+    Two forms, chosen from what the call can see.  A decode step (Q = 1,
+    plain pages, no bias) is a WALK over each row's own live pages
+    (:func:`paged_walk_attention`): a row costs its context whatever the
+    page bucket.  Prompt chunks and speculative rows (Q > 1), int8 pages
+    (:class:`KVPages`) and ALiBi keep the grid over the bucket
+    (:func:`paged_grid_attention`), which no cell times, as does a page
+    too large for the walk's tiles (:func:`walk_blocks`).  Both carry
+    the kernel name ``name + "_decode"`` / ``"_prefill"``."""
+    S, Q, H, D = q.shape
+    has_scale = isinstance(kv, KVPages)
+    if Q == 1 and not has_scale and alibi_slopes is None:
+        K, page_size = kv.shape[3:5]
+        group, sub = walk_blocks(H // K, K, D, page_size, page_table.shape[1],
+                                 q.dtype.itemsize, kv.dtype.itemsize)
+        if group:
+            return paged_walk_attention(
+                q, kv, layer, page_table, start_pos, group=group, sub=sub,
+                sm_scale=float(sm_scale if sm_scale is not None
+                               else 1.0 / np.sqrt(D)),
+                window=window, interpret=interpret, name=name)
+    return paged_grid_attention(
+        q, kv, layer, page_table, start_pos, sm_scale=sm_scale,
+        alibi_slopes=alibi_slopes, window=window, interpret=interpret,
+        name=name)
+
+
+def paged_grid_attention(q: jax.Array, kv: jax.Array, layer,
+                         page_table: jax.Array, start_pos: jax.Array, *,
+                         sm_scale: float | None = None,
+                         alibi_slopes: Optional[jax.Array] = None,
+                         window: Optional[int] = None,
+                         interpret: bool = False,
+                         name: str = "paged_attention") -> jax.Array:
+    """The grid form of :func:`paged_decode_attention`, for any Q: Q > 1
+    rows carry prefill chunks with per-row causal limits, so one launch
+    serves a fused mixed prefill+decode ragged batch (the single-kernel
+    serving formulation of Ragged Paged Attention, arxiv 2604.15464).
 
     Grid ``(S, K // heads, P // group)`` with ``heads`` and ``group``
     from :func:`kernel_blocks`.  In the pool's layout one page's K and V
@@ -832,6 +900,220 @@ def slots_held(page_table: np.ndarray, group: int) -> Tuple[int, int]:
     seen = np.logical_or.accumulate(live, axis=1)
     held = ~live & seen & live.any(axis=2, keepdims=True)
     return int(held.sum()), int(live.sum())
+
+
+#: tiles of a decode row's walk in VMEM at once: the one under the matmuls
+#: and two being copied (``ops/mla_attention.py``: with one tile ahead a
+#: short tile cannot cover a long one's copy).  The walk's look-ahead
+#: (``ahead``) is written for these two
+TILES_IN_FLIGHT = 3
+
+#: K/V bytes one chunk of a walk's tile is attended at.  A chunk's
+#: arithmetic is one dependent chain (scores, max, exp, sum, weighted
+#: sum: ~0.5 us whatever its width), so a chunk must hold at least the
+#: bytes that take the memory as long to deliver, and a row's last tile
+#: is cut to whole chunks.  512 KB is 2 slots of a 256 KB page (8 KV
+#: heads) and 16 of a 32 KB one (1 KV head, where chunks of 2 slots read
+#: 1.31 ms for 0.49: v5e, PERF.md PR 45)
+CHUNK_BYTES = 512 * 2 ** 10
+
+
+def walk_blocks(rows: int, kv_heads: int, head_dim: int, page_size: int,
+                page_slots: int, q_itemsize: int,
+                kv_itemsize: int) -> Tuple[int, int]:
+    """``(group, sub)``: the page slots a tile of the decode walk holds
+    and the page slots a chunk of it is attended at, read from the call's
+    shapes.  Both are sized by bytes alone: a tile is ``STEP_BYTES`` of
+    whole pages (every KV head of a page is one copy), at least one page,
+    no more than a row's table holds and no more than ``VMEM_BUDGET``
+    leaves for ``TILES_IN_FLIGHT`` tiles beside the row's blocks and a
+    chunk's scores; a chunk is ``CHUNK_BYTES`` of them and divides the
+    tile.  Neither has to divide the page bucket.  ``(0, 0)`` where not
+    even one page a tile fits: the call keeps the grid form, which
+    splits a page by head."""
+    lanes = _round_up(head_dim, 128)
+    page_bytes = 2 * kv_heads * _round_up(page_size, 8) * lanes * kv_itemsize
+    sub = min(max(CHUNK_BYTES // page_bytes, 1), page_slots)
+    # the row's blocks and three float32 score tiles of a chunk
+    fixed = kv_heads * _round_up(rows, 8) * (
+        _row_bytes(lanes, q_itemsize)
+        + 3 * _round_up(sub * page_size, 128) * 4)
+    group = min(max(STEP_BYTES // page_bytes, 1),
+                (VMEM_BUDGET - fixed) // (TILES_IN_FLIGHT * page_bytes),
+                _round_up(page_slots, sub))
+    if group < 1:
+        return 0, 0
+    sub = min(sub, group)
+    return group - group % sub, sub
+
+
+def _walk_kernel(l_ref, pt_ref, sp_ref, first_ref, live_ref, q_ref, kv_ref,
+                 o_ref, tile, sem, walked, m_scr, l_scr, acc_scr, *,
+                 page_size, group, sub, sm_scale, window, groups):
+    """One decode row of the walk: all KV heads of the row against the
+    row's OWN pages, slots ``first_ref[s]`` on of its table,
+    ``live_ref[s]`` of them, ``group`` to a tile.  The pool stays in HBM;
+    a live page is ONE copy (K and V of every head) to its place in a
+    tile, laid out ``[2, K, sub * page, D]`` a chunk so that a chunk's K
+    and V planes are read as they lie, and the tiles two places ahead in
+    the walk (the row's next ones, then the next rows' first) are in
+    flight under this tile's matmuls.  A tile is attended chunk by chunk
+    as far as its live pages reach (:func:`_attend_tile`); no step, copy
+    or wait exists for a slot outside the row's range.  ``walked`` counts
+    the tiles of the rows before, so the ring of three tiles turns on
+    across rows (``ops/mla_attention.py::_decode_kernel`` is the same
+    walk over one plane)."""
+    s = pl.program_id(0)
+    chunks = group // sub
+    layer = l_ref[0]
+
+    def tiles_of(row):
+        return jax.lax.div(live_ref[row] + (group - 1), group)
+
+    def live_in(row, g):
+        """Live pages of tile ``g`` of ``row`` (none, or fewer, past its
+        range or past the last row)."""
+        return jnp.minimum(live_ref[row] - g * group, group)
+
+    def copies(row, g, slot, wait):
+        """Start (or wait for) the copies of the live pages of tile ``g``
+        of ``row``: none past the row's range, none for a row past the
+        last.  A wait only needs a copy of the same size."""
+        base = 0 if wait else first_ref[row] + g * group
+
+        def one(i, carry):
+            page = 0 if wait else pt_ref[row, base + i]
+            copy = pltpu.make_async_copy(
+                kv_ref.at[layer, page],
+                tile.at[slot * chunks + jax.lax.div(i, sub), :, :,
+                        pl.ds(pl.multiple_of(jax.lax.rem(i, sub) * page_size,
+                                             page_size), page_size)],
+                sem.at[slot])
+            copy.wait() if wait else copy.start()
+            return carry
+
+        return jax.lax.fori_loop(0, live_in(row, g), one, 0)
+
+    tiles, tiles_next = tiles_of(s), tiles_of(s + 1)
+
+    def ahead(g):
+        """The place in the walk two tiles after tile ``g`` of this row:
+        a row past the last has no tile, every other at least one."""
+        over = g + (TILES_IN_FLIGHT - 1) - tiles
+        here, next_row = over < 0, over < tiles_next
+        pick = jax.lax.select       # (``jnp.where`` is a jit of its own:
+        # a kernel in 33-59 step programs pays for every trace of it)
+        return (pick(here, s, pick(next_row, s + 1, s + 2)),
+                pick(here, over + tiles,
+                     pick(next_row, over, jnp.zeros_like(over))))
+
+    @pl.when(s == 0)
+    def _first():
+        # columns of a tile that no copy has reached yet are multiplied by
+        # probabilities of exactly 0: they must hold numbers
+        tile[...] = jnp.zeros_like(tile)
+        walked[0] = 0
+        # the walk's first two tiles: ``ahead(-2)`` is tile 0 of row 0
+        jax.lax.fori_loop(0, TILES_IN_FLIGHT - 1, lambda k, carry: copies(
+            *ahead(k - (TILES_IN_FLIGHT - 1)), k, wait=False), 0)
+
+    start = sp_ref[s]
+    first_tile = walked[0]
+    column = first_ref[s] * page_size       # of the row's first live slot
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def walk(g, carry):
+        slot = jax.lax.rem(first_tile + g, TILES_IN_FLIGHT)
+        copies(*ahead(g), jax.lax.rem(slot + (TILES_IN_FLIGHT - 1),
+                                      TILES_IN_FLIGHT), wait=False)
+        copies(s, g, slot, wait=True)
+
+        def attend(c, carry):
+            at = slot * chunks + c
+            _attend_tile(q_ref[...], lambda i: tile[at, i], lambda: (
+                column + (g * group + c * sub) * page_size), start,
+                m_scr, l_scr, acc_scr, sm_scale=sm_scale, window=window,
+                groups=groups)
+            return carry
+
+        # a row's last tile: only the chunks its live pages reach
+        jax.lax.fori_loop(0, jax.lax.div(live_in(s, g) + (sub - 1), sub),
+                          attend, 0)
+        return carry
+
+    jax.lax.fori_loop(0, tiles, walk, 0)
+    walked[0] = first_tile + tiles
+    o_ref[...] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "group", "sub", "sm_scale", "window", "interpret", "name"))
+def paged_walk_attention(q: jax.Array, kv: jax.Array, layer,
+                         page_table: jax.Array, start_pos: jax.Array, *,
+                         group: int, sub: int, sm_scale: float,
+                         window: Optional[int] = None,
+                         interpret: bool = False,
+                         name: str = "paged_attention") -> jax.Array:
+    """The decode step of :func:`paged_decode_attention` as a walk: q
+    ``[S, 1, H, D]`` (one new token a row) over the pool ``[L, P+1, 2, K,
+    page, D]``.  The grid runs over ROWS, in order; the page table, the
+    contexts and each row's live range ride scalar prefetch, the pool is
+    left in HBM and the kernel copies each row's live pages itself
+    (:func:`_walk_kernel`), so a row costs what its own context costs
+    whatever the page bucket ``P`` of its step, which is only the table's
+    width here.  A row's live pages are slots ``first .. last`` of ITS
+    table, from positions alone: ``last = start_pos // page``, ``first``
+    the page its window starts in (0 without one; a window group's
+    rebased table and a full table that holds nulls under the window read
+    the same), never from a page id.  Jitted, so that the kernel's body
+    is traced once a shape and not once a layer of every step program
+    that has the shape."""
+    S, _, H, D = q.shape
+    K, page_size = kv.shape[3:5]
+    G = H // K
+    chunks = group // sub
+    start_pos = start_pos.astype(jnp.int32)
+    zero = jnp.zeros_like(start_pos)
+
+    def page_of(pos, top):      # (``lax``: ``jnp`` forms are jits of their
+        # own, traced in every step program; a negative position is slot 0)
+        return jax.lax.clamp(zero, jax.lax.div(pos, zero + page_size), top)
+
+    last = page_of(start_pos, zero + (page_table.shape[1] - 1))
+    first = zero if window is None else page_of(start_pos + (1 - window), last)
+    # no pages for the rows the copies look ahead to past the last one
+    first, live = (jax.lax.pad(x, jnp.int32(0),
+                               [(0, TILES_IN_FLIGHT - 1, 0)])
+                   for x in (first, last - first + 1))
+    row = pl.BlockSpec((None, K, G, D), lambda s, *_: (s, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_walk_kernel, page_size=page_size, group=group,
+                          sub=sub, sm_scale=sm_scale, window=window,
+                          groups=G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(S,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((TILES_IN_FLIGHT * chunks, 2, K, sub * page_size,
+                            D), kv.dtype),
+                pltpu.SemaphoreType.DMA((TILES_IN_FLIGHT,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((K, G, 1), jnp.float32),
+                pltpu.VMEM((K, G, 1), jnp.float32),
+                pltpu.VMEM((K, G, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((S, K, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        # the grid form's names: a trace's shares and rooflines match them
+        name=name + "_decode",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), page_table.astype(jnp.int32),
+      start_pos, first, live, q.reshape(S, K, G, D), kv)
+    return out.reshape(S, 1, H, D)
 
 
 def gather_last(x: jax.Array, q_lens: jax.Array) -> jax.Array:

@@ -36,8 +36,8 @@ from deepspeed_tpu.ops import paged_attention as paged_ops
 from deepspeed_tpu.ops.paged_attention import (MAX_KERNEL_Q_ROWS, KVPages,
                                                kernel_blocks,
                                                paged_attention,
-                                               paged_decode_attention,
-                                               write_kv)
+                                               paged_grid_attention,
+                                               walk_blocks, write_kv)
 from deepspeed_tpu.ops.quantization import (dequantize_blockwise,
                                             quantize_blockwise)
 
@@ -190,25 +190,72 @@ SERVING_BLOCKS = [
 ]
 
 
-@pytest.mark.parametrize("name,heads,decode,prompt", SERVING_BLOCKS,
-                         ids=[f"{n}-{h}" for n, h, _, _ in SERVING_BLOCKS])
-def test_kernel_blocks_of_the_serving_configurations(name, heads, decode,
-                                                     prompt):
+def _attention_shape(name: str) -> tuple:
+    """(KV heads, head dim, page size) of a serving configuration of the
+    benchmark, whose pages are bfloat16."""
     import json
     import os
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            name + ".json")) as f:
         c = json.load(f)
-    K, D, page = (c["num_key_value_heads"], c["head_dim"],
-                  c["engine"]["page_size"])
-    assert heads % K == 0 and c["engine"]["kv_dtype"] == "bfloat16"
+    assert c["engine"]["kv_dtype"] == "bfloat16"
+    return (c["num_key_value_heads"], c["head_dim"],
+            c["engine"]["page_size"])
+
+
+@pytest.mark.parametrize("name,heads,decode,prompt", SERVING_BLOCKS,
+                         ids=[f"{n}-{h}" for n, h, _, _ in SERVING_BLOCKS])
+def test_kernel_blocks_of_the_serving_configurations(name, heads, decode,
+                                                     prompt):
+    K, D, page = _attention_shape(name)
+    assert heads % K == 0
     for q, want in ((1, decode), (128, prompt)):
         for buckets in (8, 40):
             got = kernel_blocks(q * (heads // K), K, D, page, buckets, 2, 2)
             assert got == want, (name, q, buckets, got)
             assert K % got[0] == 0 and buckets % got[1] == 0
             assert got != (1, 1) or K == 1
+
+
+#: (id, configuration file, query heads of the kind, decode rows, table
+#: width, window, the walk's (tile, chunk) in page slots): the decode call
+#: of each serving configuration's attention layers at its cell's rows and
+#: page bucket.  A tile is ``STEP_BYTES`` and a chunk ``CHUNK_BYTES`` of
+#: whole pages whatever the bucket: 8 and 2 slots of a 256 KB page, 2 and
+#: 1 of a 960 KB one, the table's 40 (rounded up to chunks of 16) of a
+#: 32 KB one
+SERVING_WALKS = [
+    ("mistral-64", "mistral-7b-serve-8l", 32, 64, 8, None, (8, 2)),
+    ("mistral-256", "mistral-7b-serve-8l", 32, 256, 8, None, (8, 2)),
+    ("laguna-full", "laguna-s-serve-5l-ep16", 48, 256, 40, None, (8, 2)),
+    ("laguna-window", "laguna-s-serve-5l-ep16", 72, 256, 16, 512, (8, 2)),
+    ("jamba", "jamba2-3b-serve-28l", 20, 256, 40, None, (48, 16)),
+    ("olmo", "olmo-hybrid-7b-serve-4l", 30, 256, 40, None, (2, 1)),
+]
+
+
+@pytest.mark.parametrize("name,heads,rows,page_slots,window,blocks",
+                         [c[1:] for c in SERVING_WALKS],
+                         ids=[c[0] for c in SERVING_WALKS])
+def test_the_decode_walk_at_the_serving_shapes(chip, name, heads, rows,
+                                               page_slots, window, blocks):
+    """A decode step's call lowers for the chip as the walk (PR 45) at the
+    five serving shapes, under the default scoped-VMEM limit, with the
+    pool left where it is (an ``ANY`` operand: no pipelined page)."""
+    K, D, page = _attention_shape(name)
+    assert walk_blocks(heads // K, K, D, page, page_slots, 2, 2) == blocks
+    kernel = "paged_attention_window" if window else "paged_attention"
+    text = compile_for_chip(
+        lambda q, kv, layer, table, start, lens: paged_attention(
+            q, kv, layer, table, start, lens, use_kernel=True,
+            window=window, interpret=False, name=kernel),
+        chip((rows, 1, heads, D), jnp.bfloat16),
+        chip((2, 257, 2, K, page, D), jnp.bfloat16), chip((), jnp.int32),
+        chip((rows, page_slots), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.int32), kernel=kernel + "_decode")
+    assert walk_calls(text) == 1
+    assert scoped_vmem_asked(text, kernel + "_decode") == [""]
 
 
 def fetch_table_steps(page_slots: int, group: int) -> int:
@@ -232,6 +279,17 @@ def asked_of_fetch_table(monkeypatch) -> list:
         paged_ops, "fetch_table", lambda table, group: asked.append(
             (table.shape[1], group)) or rule(table, group))
     return asked
+
+
+def walk_calls(text: str) -> int:
+    """The Mosaic custom calls of a compiled program's text that are the
+    decode walk (``paged_walk_attention``): a paged attention kernel whose
+    pool operand stays where it is (``pl.ANY``: the kernel copies a row's
+    pages itself), so it has no pipelined page operand: its operands are
+    the five prefetched scalars, the queries and the pool."""
+    return sum(len(re.findall(r"%[\w.-]+", line.split("custom-call(")[1]
+                              .split(")")[0])) == 7
+               for line in kernel_calls(text, "paged_attention"))
 
 
 def mosaic_texts(lowered_text: str) -> list:
@@ -288,17 +346,19 @@ KERNEL_TEXTS = {
     ids=lambda v: str(v))
 def test_the_fetch_table_leaves_the_kernels_text_alone(
         chip, monkeypatch, name, rows, page_slots):
-    """The paged kernel's lowered text for the described chip is the
-    parent's at every pinned shape, and is the same whether its index
-    maps read the fetch table or the engine's table as given: the rule
-    is integer operations BESIDE the kernel."""
+    """The grid form's lowered text for the described chip is the
+    parent's at every pinned shape (at Q = 1 too, which int8 pages and
+    ALiBi still take: PR 45 moved its arithmetic into a function both
+    forms call and changed nothing of it), and is the same whether its
+    index maps read the fetch table or the engine's table as given: the
+    rule is integer operations BESIDE the kernel."""
     import hashlib
     K, heads, window, digests = KERNEL_TEXTS[name]
     kernel = "paged_attention_window" if window else "paged_attention"
 
     def texts():
         lowered = jax.jit(
-            lambda q, kv, table, start: paged_decode_attention(
+            lambda q, kv, table, start: paged_grid_attention(
                 q, kv, 1, table, start, window=window, name=kernel)
         ).lower(chip((256 if rows == 1 else 4, rows, heads, 128),
                      jnp.bfloat16),
@@ -757,14 +817,11 @@ def test_laguna_step_program_moves_no_pool_and_no_expert_stack(
                        donate_argnums=(1,)).lower(*avals).compile()
     text = compiled.as_text()
     if key.kind == "chain":
-        # the fetch table of each page group at the group the engine
-        # counts a step's page slots at, and computed ONCE a page group:
-        # shared by the group's layers, lifted out of their scan
-        groups = {(key.P, serve.decode_page_group(key.P, "full")),
-                  (16, serve.decode_page_group(16, "window"))}
-        assert set(asked) == groups == {(key.P, 8), (16, 8)}
-        assert fetch_table_selects(text) == sum(
-            fetch_table_steps(*g) for g in groups)
+        # decode rows alone: both page groups' calls walk each row's own
+        # pages (PR 45), and the program holds nothing of the fetch table
+        assert asked == [] and fetch_table_selects(text) == 0
+        assert walk_calls(text) == len(
+            kernel_calls(text, "paged_attention"))
     row = "decode" if key.Q == 1 else "prefill"
     for kernel in (f"paged_attention_{row}", f"paged_attention_window_{row}",
                    f"kv_write_{row}", "moe_expert_ffn"):
@@ -884,11 +941,10 @@ def test_jamba_step_program_moves_no_pool_and_no_weight_stack(
                        donate_argnums=(1,)).lower(*avals).compile()
     text = compiled.as_text()
     if key.kind == "chain":
-        group = serve.decode_page_group(key.P)
-        assert set(asked) == {(key.P, group)} == {(40, 8)}
-        # once for each of the two attention layers, which sit in two
-        # different periods of the scanned stack
-        assert fetch_table_selects(text) <= 2 * fetch_table_steps(40, 8)
+        # decode rows alone: the walk (PR 45), nothing of the fetch table
+        assert asked == [] and fetch_table_selects(text) == 0
+        assert walk_calls(text) == len(
+            kernel_calls(text, "paged_attention"))
     row = "decode" if key.Q == 1 else "prefill"
     kernels = [f"kv_write_{row}", "ssm_state_update_decode" if key.Q == 1
                else "ssm_scan_prefill"]
@@ -1021,9 +1077,10 @@ def test_olmo_hybrid_step_program_moves_no_pool_and_no_weight_stack(
                        donate_argnums=(1,)).lower(*avals).compile()
     text = compiled.as_text()
     if key.kind == "chain":
-        group = serve.decode_page_group(key.P)
-        assert set(asked) == {(key.P, group)} == {(40, 2)}
-        assert fetch_table_selects(text) == fetch_table_steps(40, 2)
+        # decode rows alone: the walk (PR 45), nothing of the fetch table
+        assert asked == [] and fetch_table_selects(text) == 0
+        assert walk_calls(text) == len(
+            kernel_calls(text, "paged_attention"))
     row = "decode" if key.Q == 1 else "prefill"
     kernels = [f"kv_write_{row}", "delta_state_update_decode"
                if key.Q == 1 else "delta_chunk_prefill"]

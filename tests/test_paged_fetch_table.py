@@ -126,10 +126,10 @@ def _pool(key, pages, K, page, D, int8=False, layers=2):
 
 def test_only_the_kernels_index_maps_read_the_fetch_table(monkeypatch):
     """The cache write (scatter and kernel), the dense gather and
-    ``paged_context`` never ask for the fetch table; the kernel asks once,
-    with the group its blocks have; and what the write leaves in the pool
-    is what the engine's table says (a dead slot's borrowed page is not
-    written)."""
+    ``paged_context`` never ask for the fetch table, nor does a decode
+    step's walk; the grid form asks once, with the group its blocks have;
+    and what the write leaves in the pool is what the engine's table says
+    (a dead slot's borrowed page is not written)."""
     asked = []
     rule = pa.fetch_table
     monkeypatch.setattr(pa, "fetch_table",
@@ -153,12 +153,15 @@ def test_only_the_kernels_index_maps_read_the_fetch_table(monkeypatch):
     pa.paged_context(pool, 1, table)
     dense = pa.paged_attention(q, pool, 1, table, start, lens,
                                use_kernel=False)
+    walk = pa.paged_attention(q, pool, 1, table, start, lens,
+                              use_kernel=True, interpret=True)
     assert asked == []
-    kernel = pa.paged_attention(q, pool, 1, table, start, lens,
-                                use_kernel=True, interpret=True)
+    kernel = pa.paged_grid_attention(q, pool, 1, table, start,
+                                     interpret=True)
     assert asked == [8]
-    np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense),
-                               rtol=2e-5, atol=2e-5)
+    for got in (walk, kernel):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                                   rtol=2e-5, atol=2e-5)
 
 
 #: (id, Q, query heads a KV head, what else the call has): every kind of
@@ -214,7 +217,7 @@ def test_kernel_outputs_under_the_fetch_table_are_bit_identical(
         slopes = 2.0 ** -np.arange(1, K * G + 1, dtype=np.float32)
 
     def run():
-        return np.asarray(pa.paged_decode_attention(
+        return np.asarray(pa.paged_grid_attention(
             q, pool, 1, jnp.asarray(table), start, window=window,
             alibi_slopes=slopes, interpret=True))
 
@@ -231,11 +234,13 @@ def test_kernel_outputs_under_the_fetch_table_are_bit_identical(
 
 
 def test_the_step_span_counts_the_decode_rows_page_slots():
-    """``fastgen.step`` carries ``kv_slots_live`` / ``kv_slots_held`` of
-    its decode rows over both page groups of a model that has two: the
-    slots that hold a page, and none held while a row's table is one page
-    group (nothing comes before it), some once the full group's table is
-    two groups wide and the second is part dead."""
+    """``fastgen.step`` carries ``kv_slots_live`` / ``kv_slots_held`` /
+    ``kv_slots_bucket`` of its decode rows over both page groups of a
+    model that has two: the slots that hold a page, and none held while a
+    row's table is one page group (nothing comes before it), some once the
+    full group's table is two groups wide and the second is part dead;
+    and the slots of the rows' tables, of which the decode kernel's walk
+    visits the live ones alone."""
     from deepspeed_tpu import telemetry
     from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
     from deepspeed_tpu.telemetry import get_tracer
@@ -243,12 +248,19 @@ def test_the_step_span_counts_the_decode_rows_page_slots():
     cfg, params = family()
     engine = engine_of(cfg, params)
     sched = FastGenScheduler(engine)
-    telemetry.enable()
-    for uid, p in enumerate(sequences_of((21, 30), seed=2)):
-        sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=40))
-    sched.run_to_completion()
-    steps = [r[5] for r in get_tracer().records()
-             if r[0] == "fastgen.step" and r[5]]
+    telemetry.set_enabled(True)
+    try:
+        # this run's records alone: the ring is the process's, and a model
+        # of one page group that served before leaves steps without the
+        # window group's counts
+        mark = len(get_tracer().records())
+        for uid, p in enumerate(sequences_of((21, 30), seed=2)):
+            sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=40))
+        sched.run_to_completion()
+        steps = [r[5] for r in get_tracer().records()[mark:]
+                 if r[0] == "fastgen.step" and r[5]]
+    finally:
+        telemetry.set_enabled(False)
     assert engine.model.decode_page_group(16, "full") == 8
     decode = [s for s in steps if s["rows"] > s["prefill_rows"]]
     assert decode and all(s["kv_slots_live"] > 0 for s in decode)
@@ -258,7 +270,12 @@ def test_the_step_span_counts_the_decode_rows_page_slots():
         assert s["kv_slots_held"] == 0 or s["kv_pages_reserved"] > 8
         assert s["kv_slots_live"] <= (s["kv_pages_reserved"]
                                       + s["kv_pages_reserved_window"] + 2)
+        # rows x (the full group's table width + the window group's)
+        rows = s["rows"] - s["prefill_rows"]
+        assert s["kv_slots_bucket"] % rows == 0
+        assert s["kv_slots_live"] < s["kv_slots_bucket"]
     # a row's 9th page: one live slot of its second group, seven held
     assert any(s["kv_slots_held"] == 7 for s in decode)
-    assert all(s["kv_slots_held"] == s["kv_slots_live"] == 0
+    assert all(s["kv_slots_held"] == s["kv_slots_live"]
+               == s["kv_slots_bucket"] == 0
                for s in steps if s["rows"] == s["prefill_rows"])

@@ -304,7 +304,8 @@ def test_every_attribute_on_the_serving_path_has_a_metric_that_reads_it():
     assert carried == {"path", "rows", "prefill_rows", "prefill_tokens",
                        "tokens", "budget", "kv_pages_reserved",
                        "kv_tokens_held", "new_tokens", "trunk_passes",
-                       "program", "kv_slots_held", "kv_slots_live"}
+                       "program", "kv_slots_held", "kv_slots_live",
+                       "kv_slots_bucket"}
     # the engagement counter of the one-pass mixed step (PR 30): held in
     # the span ring for whoever reads a trace, by decision no metric
     carried.remove("trunk_passes")
@@ -316,7 +317,9 @@ def test_every_attribute_on_the_serving_path_has_a_metric_that_reads_it():
     # (PR 44): what share of a step's page fetches the null page used to
     # be; the rooflines and time shares that were here read the effect,
     # the pair is held beside them, by decision no metric
-    carried -= {"kv_slots_held", "kv_slots_live"}
+    # (PR 45: and the slots of the rows' tables, so that live / bucket is
+    # the share of the bucket the decode kernel's walk visits)
+    carried -= {"kv_slots_held", "kv_slots_live", "kv_slots_bucket"}
     read = set()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for path in glob.glob(os.path.join(root, "benchmark", "metrics",
@@ -542,7 +545,7 @@ def test_a_model_of_two_page_groups_carries_the_window_groups_names():
         "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
         "program", "moe_pairs_here", "moe_expert_load_max",
         "moe_experts_touched", "moe_tokens", "kv_slots_held",
-        "kv_slots_live"}
+        "kv_slots_live", "kv_slots_bucket"}
     read = set()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for path in glob.glob(os.path.join(root, "benchmark", "metrics",
@@ -620,7 +623,7 @@ def test_a_model_with_a_state_pool_carries_the_state_pools_names():
     assert carried - new == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
         "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
-        "program", "kv_slots_held", "kv_slots_live"}
+        "program", "kv_slots_held", "kv_slots_live", "kv_slots_bucket"}
     assert not any(r[0].startswith("kv.state") for r in recs)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     read, patterns = set(), []
@@ -737,7 +740,7 @@ def test_a_model_with_delta_rule_layers_carries_the_delta_kinds_names():
     assert carried - new - kept == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
         "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
-        "program", "kv_slots_held", "kv_slots_live"}
+        "program", "kv_slots_held", "kv_slots_live", "kv_slots_bucket"}
     assert not any(r[0].startswith("kv.state") for r in recs)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     read, patterns = set(), []

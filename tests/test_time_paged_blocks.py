@@ -36,19 +36,28 @@ def test_the_tool_runs_on_the_cpu(tmp_path, window):
     """End to end in interpret mode, a full layer's call and a window
     layer's (``--window``: a table ``--buckets`` slots wide whatever the
     context): the five columns of every context, the rule inside the call
-    (``program``) as correct as the tables as given."""
+    (``program``) as correct as the tables as given, and the decode walk's
+    rows beside the grid form's."""
     out = tmp_path / "blocks.json"
     done = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "time_paged_blocks.py"),
          "--kv-heads", "4", "--head-dim", "32", "--page", "8", "--rows", "3",
          "--pages", "300", "--buckets", "4", "--contexts", "1", "20",
          "--mix", "3", "30", "--min-heads", "2", "--calls", "1",
-         "--interpret", "--out", str(out)]
+         "--walk", "3,1", "--interpret", "--out", str(out)]
         + (["--window", str(window)] if window else []),
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
         text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
     rows = json.loads(out.read_text())["rows"]
+    # the decode walk's rows (PR 45): the rule's own tile, then --walk's
+    walks = [r for r in rows if r.get("form") == "walk"]
+    rows = [r for r in rows if r.get("form") != "walk"]
+    assert [(r["group"], r["sub"]) for r in walks] == [(4, 4), (3, 1)]
+    for r in walks:
+        assert set(r["ms"]) == {"1", "20", "mix"}
+        assert set(r["ms"]["mix"]) == {"walk"}
+        assert r["max_abs_diff"] < 2e-2
     assert (rows[0]["heads"], rows[0]["group"]) == (4, 4)   # the rule's own
     assert {(r["heads"], r["group"]) for r in rows} == {
         (h, g) for h in (4, 2) for g in (4, 2, 1)}

@@ -32,6 +32,14 @@ dead: a grid step's fixed cost.  Contexts at a group's edge (512, 1024)
 against one page past it (576) split a last group's cost into its fetch
 and its arithmetic.  Every output is compared with the first blocks'.
 
+Those rows are the GRID form (``paged_grid_attention``: prompt chunks,
+int8 pages, ALiBi).  A decode row's call is the WALK over each row's own
+pages (``paged_walk_attention``, PR 45): one ``walk`` row a ``(group, sub)``
+of ``--walk`` (the rule's own, ``walk_blocks``, first), timed under the
+``null`` table, the only one it can tell from the others (it reads no slot
+past a row's range).  ``--grid rule`` times the grid form at the rule's
+blocks alone, as the walk's yardstick.
+
 ``--window 512 --buckets 16`` times a window layer's call (Laguna's: 72
 query heads over 8 KV heads): the table is the window group's, ``--buckets``
 slots wide, holding a row's pages from the first one its window still
@@ -119,6 +127,11 @@ def main():
                     help="a window layer's call: tables rebased to the "
                          "window's first page, --buckets slots wide")
     ap.add_argument("--min-heads", type=int, default=5)
+    ap.add_argument("--grid", choices=["all", "rule", "none"], default="all",
+                    help="the grid form's blocks to time: every one that "
+                         "fits, the rule's own, none")
+    ap.add_argument("--walk", nargs="*", default=[], metavar="GROUP,SUB",
+                    help="tiles of the walk to time beside the rule's own")
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--seed", type=int, default=41)
     ap.add_argument("--interpret", action="store_true",
@@ -169,6 +182,10 @@ def main():
         beat[0] = time.monotonic()
         return ms, out
 
+    def apart(out, ref):
+        return float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                     - ref.astype(jnp.float32))))
+
     results = []
     name = "paged_attention_window" if args.window else "paged_attention"
     for P in args.buckets:
@@ -180,9 +197,11 @@ def main():
         lo, hi = max(args.mix[0], Q), min(args.mix[1], cap)
         ctx_sets["mix"] = rng.integers(lo, hi + 1, S)
         first = {}
-        for heads, group in candidates(args, Q * G, P):
+        grid = candidates(args, Q * G, P)
+        for heads, group in {"all": grid, "rule": grid[:1],
+                             "none": []}[args.grid]:
             def call(q, kv, table, start):
-                return pa.paged_decode_attention(
+                return pa.paged_grid_attention(
                     q, kv, 0, table, start, window=args.window,
                     interpret=args.interpret, name=name)
             shapes = (q, pool, jnp.zeros((S, P), jnp.int32),
@@ -222,10 +241,43 @@ def main():
                     if kind == "null":      # one answer whatever the blocks
                         ref = first.setdefault(ctx_name, out)
                     if kind in ("null", "program"):
-                        row["max_abs_diff"] = max(
-                            row["max_abs_diff"], float(jnp.max(jnp.abs(
-                                out.astype(jnp.float32)
-                                - ref.astype(jnp.float32)))))
+                        row["max_abs_diff"] = max(row["max_abs_diff"],
+                                                  apart(out, ref))
+            results.append(row)
+            print(json.dumps(row), flush=True)
+        # (0, 0): a page too large for the walk's tiles keeps the grid form
+        walks = [pa.walk_blocks(G, K, D, page, P, 2, 2)] if Q == 1 else []
+        walks += [b for b in (tuple(map(int, w.split(","))) for w in args.walk)
+                  if walks and b not in walks]
+        for group, sub in (b for b in walks if walks[0][0]):
+            t0 = time.monotonic()
+            try:
+                run = jax.jit(lambda q, kv, table, start: (
+                    pa.paged_walk_attention(
+                        q, kv, 0, table, start, group=group, sub=sub,
+                        sm_scale=float(D) ** -0.5, window=args.window,
+                        interpret=args.interpret, name=name))).lower(
+                    q, pool, jnp.zeros((S, P), jnp.int32),
+                    jnp.zeros((S,), jnp.int32)).compile()
+            except Exception as e:      # the chip's compiler refused it
+                print(f"P={P} walk ({group}, {sub}): refused: "
+                      f"{str(e).splitlines()[0][:200]}", flush=True)
+                continue
+            beat[0] = time.monotonic()
+            row = {"bucket": P, "form": "walk", "group": group, "sub": sub,
+                   "tile_bytes": 2 * K * group * page * D * 2,
+                   "compile_s": round(beat[0] - t0, 3), "ms": {},
+                   "max_abs_diff": 0.0}
+            for ctx_name, ctxs in ctx_sets.items():
+                held = held_from(ctxs, page, args.window, Q)
+                table = tables(held, page, P, 1, args.pages)["null"]
+                ms, out = ms_a_call(run, q, pool,
+                                    jnp.asarray(table, jnp.int32),
+                                    jnp.asarray(held - Q, jnp.int32))
+                row["ms"][ctx_name] = {"walk": round(ms, 4)}
+                if ctx_name in first:
+                    row["max_abs_diff"] = max(row["max_abs_diff"],
+                                              apart(out, first[ctx_name]))
             results.append(row)
             print(json.dumps(row), flush=True)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
