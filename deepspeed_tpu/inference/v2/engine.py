@@ -383,9 +383,9 @@ class InferenceEngineV2:
         # never prefix-indexed (index_prefix only sees the target
         # pool), so a shared prefix page can hold stale draft KV —
         # that degrades accept rate until catch-up, never correctness.
-        #: the wide table's layout (ragged/cache_kinds.py); None for a
-        #: model of one page group, whose table is the [S, P] it was
-        self._table = model.table if model.table.extra(1) else None
+        #: the segment table's layout (ragged/cache_kinds.py): a model of
+        #: one page group's is the [S, P] it was
+        self._table = model.table
         self._draft_kv = None
         self._draft_seen: Dict[int, int] = {}
         self._attended = (0, 0)
@@ -1186,8 +1186,7 @@ class InferenceEngineV2:
             return None
         if table is None:
             return 0, 0, 0
-        parts = (self._table.split(table, 1) if self._table is not None
-                 else {"full": table})
+        parts = self._table.split(table, 1)
         held = live = bucket = 0
         for kind in ("full", "window"):
             if kind in parts:

@@ -20,6 +20,7 @@ the KV cache is donated so decoding is allocation-free on device.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import functools
 import os
 import threading
@@ -32,10 +33,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...models import transformer as T
+from ...moe import held
+from ...ops.delta_rule import delta_rule
 from ...ops.mla_attention import (latent_write, mla_fresh_attention,
                                   mla_paged_attention, plane_width)
 from ...ops.paged_attention import (KVPages, gather_last, token_positions,
                                     write_kv)
+from ...ops.ssm import conv_step, ssm_scan
 from ...telemetry import get_tracer
 from ...telemetry import metrics as tm
 from ...telemetry.watchdog import get_watchdog
@@ -117,6 +121,90 @@ def _positions(segments: Sequence[Segment]) -> jax.Array:
                         for seg in segments])
 
 
+def _true_positions(seg: Segment) -> jax.Array:
+    """``[S, Q]``: which of a segment's positions hold a token."""
+    return (jnp.arange(seg.token_ids.shape[1], dtype=jnp.int32)[None, :]
+            < seg.q_lens[:, None])
+
+
+class Pass(NamedTuple):
+    """What one pass of the trunk hands each of its layers
+    (``_forward_hidden`` makes it once)."""
+    cfg: T.TransformerConfig
+    #: by layer kind that caches pages: the segments under the table of
+    #: the kind's page group (the window group's rebased)
+    by_group: Dict[str, List[Segment]]
+    #: a segment's (state slot, starts from zeros, true positions)
+    rows: List[Tuple[jax.Array, jax.Array, jax.Array]]
+    #: by layer kind under rope: (sin, cos)
+    ropes: Dict[str, Tuple[jax.Array, jax.Array]]
+    #: held experts: of every token of the pass, whether it is a true one
+    valid: Optional[jax.Array]
+
+
+class Mixer(NamedTuple):
+    """How the layers of one kind mix tokens (:data:`MIXERS`, below the
+    model): ``run(model, h, pools, weights, layer, *, kind, ctx) ->
+    (output, pools)``; the key of its ``weights`` in a layer's tree; which
+    ``pools`` it writes, by their place in what the engine hands in (one
+    pool; full and window; pages, ``h``, ``conv``)."""
+    run: Callable
+    weights: str
+    pools: Tuple[int, ...]
+
+
+def _write_then_attend(segments: Sequence[Segment], new, pool, write, fresh,
+                       paged):
+    """The half of an attention mixer that runs segment by segment, over
+    ``new`` (arrays over all tokens) taken apart: each segment in turn
+    writes its new tokens (``write(pool, seg, *parts)``) and attends,
+    ``fresh(*parts)`` over them alone where every row starts at position
+    0 (None: no such module), else ``paged(pool, seg, *parts)``.  Returns
+    (the outputs end to end, the pool)."""
+    out = []
+    for seg, *parts in zip(segments, *(
+            _per_segment(a, segments) for a in new)):
+        pool = write(pool, seg, *parts)
+        out.append(fresh(*parts) if seg.fresh and fresh is not None
+                   else paged(pool, seg, *parts))
+    return _end_to_end(out), pool
+
+
+# q, k and v by head, ``[S, Q, heads, d]``, from either format the families
+# store the projections in (the parameters' format: no weight is re-laid)
+
+def _qkv_by_axis(h, ap, cfg, rope, norm):
+    """Weights with the heads as an axis, ``[e, heads, d]`` (the seed
+    families; what ``quantize_weights`` packs)."""
+    dtype = cfg.dtype
+    q = jnp.einsum("sqe,ehd->sqhd", h, T._wval(ap["wq"], dtype))
+    k = jnp.einsum("sqe,ekd->sqkd", h, T._wval(ap["wk"], dtype))
+    v = jnp.einsum("sqe,ekd->sqkd", h, T._wval(ap["wv"], dtype))
+    if cfg.use_bias or cfg.qkv_bias:
+        q = q + ap["bq"].astype(dtype)
+        k = k + ap["bk"].astype(dtype)
+        v = v + ap["bv"].astype(dtype)
+    if rope is not None:
+        q, k = T.apply_rope(q, *rope), T.apply_rope(k, *rope)
+    return q, k, v
+
+
+def _qkv_folded(h, ap, cfg, rope, norm):
+    """Weights stored as the matrices the products take, the heads folded
+    into their columns (head n = columns n*d .. n*d + d - 1); under
+    ``cfg.qk_norm`` q and k normed over their whole width."""
+    def heads(w, gain=None, rotate=False):
+        y = jnp.einsum("sqe,ef->sqf", h, w.astype(cfg.dtype))
+        if gain is not None:
+            y = norm(gain, y)
+        y = y.reshape(y.shape[:2] + (-1, cfg.dims_per_head))
+        return T.apply_rope(y, *rope) if rotate and rope is not None else y
+
+    return (heads(ap["wq"], ap["q_norm"] if cfg.qk_norm else None, True),
+            heads(ap["wk"], ap["k_norm"] if cfg.qk_norm else None, True),
+            heads(ap["wv"]))
+
+
 def _rebox_from_cfg(cfg: T.TransformerConfig, params):
     """Attach logical-axis metadata to an UNBOXED param tree (HF imports
     arrive as plain arrays) by zipping with the model family's own
@@ -192,23 +280,10 @@ class RaggedInferenceModel:
             def mlp_fn(c, p, x, _moe=moe_cfg):
                 return moe_forward(_moe, p, x, is_training=False)
         self.mlp_fn = mlp_fn
-        # implementation chosen through the registry/heuristics seam
+        # implementations chosen through the registry/heuristics seam
         # (reference heuristics.instantiate_attention); attention_impl
         # pins a named implementation, None lets the heuristic pick
         from .modules import instantiate
-        if cfg.latent_dim:
-            # the latent kind: its cache plane and its step
-            # (_attend_latent) are its own; the K/V modules do not apply
-            self._attention = None
-            self._fresh_attention = mla_fresh_attention
-        else:
-            self._attention = instantiate("ragged_attention", cfg,
-                                          name=attention_impl)
-            try:
-                self._fresh_attention = instantiate(
-                    "fresh_prefill_attention", cfg)
-            except (KeyError, ValueError):
-                self._fresh_attention = None
         self._norm_impl = instantiate("norm", cfg)
         self._norm = self._norm_impl
         self._embed = instantiate("embedding", cfg)
@@ -223,14 +298,13 @@ class RaggedInferenceModel:
             head_dim=cfg.dims_per_head, dtype=cfg.dtype))
         #: the window group's cache (a model of two attention kinds: its
         #: full layers' K/V in ``kv_config``'s pool, its window layers' in
-        #: this one, ``_forward_hidden_kinds``); None: one page group
+        #: this one); None: one page group
         self.window_kv_config: Optional[KVCacheConfig] = None
         #: the state pool (a model with state-space or delta-rule layers:
         #: a slot a sequence instead of pages, of the shape the kind
         #: declares; ``ops/ssm.py``, ``ops/delta_rule.py``); None: every
         #: layer kind caches pages.  The engine sizes ``num_slots``
         self.state_config: Optional[StatePoolConfig] = None
-        import dataclasses
         # layers by what their kind caches (cache_kinds.py): a page
         # group's name, or "slot"
         layers = collections.Counter(
@@ -248,23 +322,37 @@ class RaggedInferenceModel:
                 kind=kind, state_dtype=cfg.ssm_state_dtype,
                 conv_dtype=cfg.dtype)
         if layers["window"]:
-            heads = dict(cfg.heads_by_kind)
             self.window_kv_config = window_kv_config or dataclasses.replace(
                 self.kv_config, num_layers=layers["window"])
-            # each kind's attention modules, over its own head count,
-            # window and kernel name
+        #: the attention modules, held ONE way: by layer kind (a model of
+        #: one kind holds ``{"full": ...}``).  ``_kind_cfg``: what each
+        #: kind's were built from (beside a window group the full kind
+        #: has no window, and each kind its own head count and kernel
+        #: name); ``_attention``: the paged module; ``_fresh_attention``:
+        #: the one for a pure prefill (None: ALiBi has none).  The latent
+        #: kind's cache plane and modules are its own (mla_attention.py)
+        self._kind_cfg: Dict[str, T.TransformerConfig] = {}
+        self._attention = {"latent": mla_paged_attention}
+        self._fresh_attention = {"latent": mla_fresh_attention}
+        if not cfg.latent_dim:
+            heads = dict(cfg.heads_by_kind)
             self._kind_cfg = {
-                "full": dataclasses.replace(
-                    cfg, num_heads=heads["full"], sliding_window=None),
-                "window": dataclasses.replace(
-                    cfg, num_heads=heads["window"])}
-            self._attention_of = {
-                kind: instantiate("ragged_attention", kc,
-                                  name=attention_impl)
+                kind: cfg if not layers["window"] else dataclasses.replace(
+                    cfg, num_heads=heads[kind], sliding_window=(
+                        cfg.sliding_window if CACHE_KINDS[kind].windowed
+                        else None))
+                for kind in ("full", "window")
+                if kind == "full" or layers[kind]}
+            self._attention = {
+                kind: instantiate("ragged_attention", kc, name=attention_impl)
                 for kind, kc in self._kind_cfg.items()}
-            self._fresh_of = {
-                kind: instantiate("fresh_prefill_attention", kc)
-                for kind, kc in self._kind_cfg.items()}
+            self._fresh_attention = {}
+            for kind, kc in self._kind_cfg.items():
+                try:
+                    self._fresh_attention[kind] = instantiate(
+                        "fresh_prefill_attention", kc)
+                except (KeyError, ValueError):
+                    self._fresh_attention[kind] = None
         #: which mesh axis shards heads/ffn/vocab (and the KV head dim):
         #: the serving ``tp`` axis when present, else the training-side
         #: ``tensor`` axis.  None until a mesh is applied.
@@ -508,7 +596,7 @@ class RaggedInferenceModel:
     def has_fresh(self) -> bool:
         """Whether pure-prefill batches have their own attention path
         (without one the key's fresh flag is inert: ALiBi)."""
-        return self._fresh_attention is not None
+        return any(self._fresh_attention.values())
 
     # dslint: hot-path
     def run_step(self, key: StepKey, kv, batches: Sequence[RaggedBatch],
@@ -553,11 +641,6 @@ class RaggedInferenceModel:
         return TableLayout.of(self.cfg.layer_kinds or ("full",),
                               self.cfg.sliding_window,
                               self.kv_config.page_size)
-
-    def window_slots(self, Q: int) -> int:
-        """Slots of the window group's table in a segment of ``Q`` tokens
-        a row (``step_key.window_slots``); 0 for a model of one group."""
-        return self.table.window_slots(Q)
 
     @property
     def last_trunk_passes(self) -> int:
@@ -876,31 +959,24 @@ class RaggedInferenceModel:
 
     def _forward_hidden(self, params, kv, segments: Sequence[Segment],
                         cfg=None, stats_out: Optional[list] = None):
-        """The shared trunk of every step kind: embed -> layers -> final
-        norm, ONE pass of the weights over all tokens of all
-        ``segments`` (:func:`_end_to_end`); only the page write and
-        attention run segment by segment, inside each layer.  Returns
-        (x, new kv), ``x`` in :func:`_end_to_end`'s layout ([S, Q, E]
-        for one segment) — the step kinds differ only in which positions
-        they unembed (last-token gather for the logits/sample kinds,
-        EVERY position for the spec verify).
-        ``cfg`` overrides the trunk geometry (the model-drafted spec
-        path runs the DRAFT trunk — same family, fewer layers — through
-        the same embed/norm/attention modules); None = the target.
-        ``stats_out``: a list that receives the held-experts counts
-        (:attr:`step_tail`) of the pass, for the kinds that carry them."""
+        """The ONE trunk of every step kind and every family: embed -> the
+        layers (:meth:`_layer_loop`) -> final norm, ONE pass of the weights
+        over all tokens of all ``segments`` (:func:`_end_to_end`); only
+        the cache write and attention (or the recurrence) run segment by
+        segment, inside each layer.  ``kv`` is what the engine hands in,
+        in its order: one pool, or (full group's pool, window group's),
+        or (page pool, the state pool's ``h``, its ``conv``).  Returns (x,
+        new kv), ``x`` in :func:`_end_to_end`'s layout ([S, Q, E] for one
+        segment): the step kinds differ only in which positions they
+        unembed (each row's last for the logits/sample kinds, EVERY one
+        for the spec verify).  ``cfg`` overrides the trunk geometry (the
+        DRAFT trunk of model-drafted speculation: same family and modules,
+        fewer layers); None = the target.  ``stats_out``: a list that
+        receives the held-experts counts (:attr:`step_tail`) of the pass."""
         cfg = cfg if cfg is not None else self.cfg
         passes = getattr(self._forming, "passes", None)
         if passes is not None:          # a program is being traced
             passes[id(cfg)] = passes.get(id(cfg), 0) + 1
-        if cfg.latent_dim:
-            return self._forward_hidden_latent(params, kv, segments, cfg,
-                                               stats_out)
-        if self.state_config is not None:
-            return self._forward_hidden_state(params, kv, segments, cfg)
-        if cfg.layer_kinds:
-            return self._forward_hidden_kinds(params, kv, segments, cfg,
-                                              stats_out)
         x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
                         _end_to_end([seg.token_ids for seg in segments]))
         pos = _positions(segments)
@@ -909,25 +985,180 @@ class RaggedInferenceModel:
             x = x + params["embed"]["positions"].astype(cfg.dtype)[safe]
         if cfg.embed_layernorm:  # BLOOM word_embeddings_layernorm
             x = self._norm(params["embed"]["norm"], x)
-        sin, cos = (T.rope_table(cfg, pos) if cfg.pos_emb == "rope"
-                    else (None, None))
+        pools = kv if type(kv) is tuple else (kv,)
+        # the page group of each attending kind (the latent plane lies in
+        # the one page group)
+        group_of = {kind: CACHE_KINDS[kind].group if kind in CACHE_KINDS
+                    else "full" for kind in dict.fromkeys(T.layer_kinds(cfg))
+                    if MIXERS[kind].weights == "attn"}
+        valid = _end_to_end([_true_positions(seg) for seg in segments]
+                            ).reshape(-1) if self.step_tail else None
+        # each segment's wide table (ragged/batch.py) taken apart ONCE
+        # into what each cache kind's layers read
+        by_group: Dict[str, List[Segment]] = {k: [] for k in group_of}
+        rows = []
+        for seg in segments:
+            parts = self.table.split(seg.page_table, seg.token_ids.shape[1])
+            for kind, group in group_of.items():
+                start = seg.start_pos
+                if group == "window":
+                    # the window group's short table starts at the page of
+                    # absolute index ``base``, so its rows are REBASED by
+                    # ``base`` pages: the cache write and attention use
+                    # positions only to find a token's slot and to mask,
+                    # which depend on differences of positions alone (the
+                    # rope is applied before, from the absolute positions)
+                    start = start - parts["base"] * self.kv_config.page_size
+                by_group[kind].append(
+                    seg._replace(page_table=parts[group], start_pos=start))
+            if "slot" in parts:     # the pool's last slot is the scratch one
+                rows.append((
+                    jnp.clip(parts["slot"], 0, pools[1].shape[1] - 1),
+                    seg.start_pos == 0, _true_positions(seg)))
+        ropes = {kind: self.rope_table(cfg, kind, pos) for kind in group_of
+                 } if cfg.pos_emb == "rope" else {}
+        ctx = Pass(cfg, by_group, rows, ropes, valid)
+        # the pools are the loop's CARRY beside the activations (and the
+        # held-experts counts, last): as scanned xs/ys a pool would be
+        # sliced out and stacked back, two layer-sized copies a layer and
+        # a pool-sized one after the loop
+        carry = (x, *pools)
+        if self.step_tail:
+            carry += (jnp.zeros((3,), jnp.int32),)
+        x, *pools = self._layer_loop(carry, params, ctx)
+        if self.step_tail:
+            stats = pools.pop()
+            if stats_out is not None:
+                stats_out.append(stats)
+        return (self._norm(params["final_norm"], x),
+                tuple(pools) if type(kv) is tuple else pools[0])
 
-        body = functools.partial(self._layer_body, segments=segments,
-                                 sin=sin, cos=cos, cfg=cfg)
-        # the pool (plain array or KVPages pair) is the loop's CARRY:
-        # as scanned xs/ys it would be sliced out and stacked back, two
-        # layer-sized copies a layer and a pool-sized one after the loop
-        layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-        if cfg.scan_layers:
-            (x, kv), _ = jax.lax.scan(
-                lambda carry, xs: (body(*carry, *xs), None),
-                (x, kv), (params["layers"], layers))
+    def rope_table(self, cfg, kind: str, positions):
+        """(sin, cos) of the ``kind`` layers' rope, as ``T.apply_rope``
+        takes them.  A family with ropes of its own names them in its
+        class (``model_implementations.py``)."""
+        if kind == "latent":            # a latent head's rotated dims alone
+            cfg = dataclasses.replace(cfg, head_dim=cfg.qk_rope_head_dim,
+                                      rope_pct=1.0)
+        return T.rope_table(cfg, positions)
+
+    def _layer_loop(self, carry, params, ctx: Pass):
+        """The ONE loop over the layers, along the layer pattern
+        (``transformer.layer_runs``): the leading layers that stand
+        outside it, ONE scan over its whole periods whose body is the
+        period's runs of like layers, the tail unrolled; a family of one
+        kind is a pattern of period 1.  A layer writes and reads its own
+        kind's pools at its index among the layers of its kind, a routed
+        one the held experts' stack at its index past the leading layers.
+        The weights stay where the family's ``init_params`` puts them
+        (the layouts: ``docs/DESIGN.md``, "A layer kind is an entry"),
+        and the tree says, read ONCE below, which of two ways reaches a
+        layer's: (a) it is handed them, a tree of its own or the scan's
+        OPERAND, stacked over the periods; (b) ``layers`` is ``{kind: the
+        kind's flat stack}``: the scans run over a COUNTER and take a
+        layer out by index, as a scan takes its operand's (a stack of
+        periods scanned as an operand would have each period's run sliced
+        out whole), a run of several a scan of its own (7 Mamba, the
+        attention layer, 6 Mamba: a program holds two Mamba bodies and
+        one attention body, not fourteen)."""
+        cfg, i32 = ctx.cfg, jnp.int32
+        kinds = T.layer_kinds(cfg)
+        leading, runs, periods, tail = T.layer_runs(cfg)
+        period = sum(n for _, n in runs)
+        per = collections.Counter(kinds[leading:leading + period])
+        start = collections.Counter(kinds[:leading])
+        # the tree's layout, read HERE and nowhere below
+        layers = params.get("layers") or {}
+        flat = layers if layers and set(layers) <= set(MIXERS) else None
+        if not flat:    # (a): every layer of a period under its own name
+            runs = [(kind, 1) for kind, n in runs for _ in range(n)]
+        stacks = {} if flat else params.get("periods") or {"l0": layers}
+        dense, lone = params.get("dense_layers", {}), params.get("tail", {})
+        # the leading layers are ONE stack (then all of one kind)
+        stacked = bool(leading and not flat and "l0" not in dense)
+        # the routed layers' held experts are ONE stack, addressed by the
+        # kernel through a layer's index: scanned with the layers, each
+        # layer's 1.5 GB would be sliced out for the custom call
+        experts = params.get("experts")
+        moe = stacks.get("l0", {}).get("moe", {})
+        if "experts" in moe:
+            experts = moe["experts"]
+            stacks = {"l0": dict(stacks["l0"], moe={
+                k: v for k, v in moe.items() if k != "experts"})}
+
+        # where the layers of period ``p`` stand: ``index`` among their
+        # kind's (``met``: the kind's before it in the period), ``routed``
+        # among the routed layers, counted from ``first``.  In full ``start[
+        # kind] + p * per[kind] + met`` and ``p * period + j`` from 0; each
+        # trunk that became this loop left out the terms that were nothing
+        # to it, and every program keeps its text (``ROADMAP.md`` D16)
+        first = 0
+        if periods and period == 1:
+            # the scan's counter runs over the kind's layers themselves
+            first = start[kinds[leading]]
+
+            def index(kind, p, met):
+                return p
+
+            def routed(p, j):
+                return p
         else:
-            for i in range(cfg.num_layers):
-                x, kv = body(x, kv, params["layers"][f"layer_{i}"],
-                             layers[i])
+            def index(kind, p, met):
+                at = p * per[kind]
+                return (start[kind] + at if leading else at) + met
 
-        return self._norm(params["final_norm"], x), kv
+            def routed(p, j):
+                return p * period + j if experts is not None else None
+
+        def layer(carry, kind, at, lp=None, routed=None):
+            """Layer ``at`` of its kind, ``lp`` its weights (a)."""
+            if flat:                                        # (b)
+                at = i32(at)
+                lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                    a, at, 0, keepdims=False), flat[kind])
+            return self._layer_body(carry, lp, kind=kind, at=at, ctx=ctx,
+                                    routed=routed, experts=(experts, first))
+
+        def outside(carry, i, lp):
+            """Layer ``i``, one that no scan runs over."""
+            return layer(carry, kinds[i], kinds[:i].count(kinds[i]), lp,
+                         i - leading)
+
+        def one_period(carry, xs):
+            lps, p = xs
+            met = collections.Counter()
+            for j, (kind, n) in enumerate(runs):
+                at = index(kind, p, met[kind])
+                if n > 1:
+                    carry, _ = jax.lax.scan(
+                        lambda c, m, kind=kind, at=at: (
+                            layer(c, kind, at + m), None),
+                        carry, jnp.arange(n, dtype=i32))
+                else:
+                    carry = layer(carry, kind, at, lps.get(f"l{j}"),
+                                  routed(p, j))
+                met[kind] += n
+            return carry, None
+
+        if stacked:     # a scan of its own, whatever pattern stands behind
+            carry, _ = jax.lax.scan(        # (``0 +``: the text again)
+                lambda c, xs: (layer(c, kinds[0], xs[1], xs[0]), None),
+                carry, (dense, 0 + jnp.arange(leading, dtype=i32)))
+        else:
+            for i in range(leading):
+                carry = outside(carry, i, dense.get(f"l{i}"))
+        counter = jnp.arange(periods, dtype=i32)
+        if periods and period == 1 and leading:
+            counter = first + counter
+        if periods and cfg.scan_layers:
+            carry, _ = jax.lax.scan(one_period, carry, (stacks, counter))
+        else:
+            for p in range(periods):
+                carry, _ = one_period(carry, (
+                    {"l0": layers[f"layer_{p}"]}, counter[p]))
+        for n, i in enumerate(range(cfg.num_layers - tail, cfg.num_layers)):
+            carry = outside(carry, i, lone.get(f"l{n}"))
+        return carry
 
     # dslint: hot-path
     def _step_impl(self, params, kv, token_ids, q_lens, start_pos,
@@ -1164,151 +1395,123 @@ class RaggedInferenceModel:
                          Segment(p_tok, p_ql, p_sp, p_pt, fresh_p)],
             rng, temps, top_ks, top_ps, row_uids, row_pos, greedy_only)
 
-    def _layer_body(self, x, kv, lp, layer, *, segments, sin, cos,
-                    cfg=None):
-        """One transformer layer over ``x`` (all tokens of
-        ``segments``, :func:`_end_to_end`) and the whole pool ``kv``, of
-        which each segment in turn writes and reads layer ``layer`` (an
-        int32 scalar) in place.  Returns (x, kv)."""
-        cfg = cfg if cfg is not None else self.cfg
+    def _layer_body(self, carry, lp, *, kind, at, ctx: Pass, routed,
+                    experts):
+        """ONE layer of kind ``kind`` over (x, the pools in the engine's
+        order, the held-experts counts where the model has them), the same
+        out: the kind's mixer (:data:`MIXERS`) at layer ``at`` of its own
+        pools, then the feed-forward (``routed``: where the layer stands
+        among the routed ones, counted from the number that ``experts``,
+        the held experts' stack, comes with), each behind a norm of its
+        input, under ``cfg.post_norm`` before one of its output, under
+        ``cfg.sandwich_norm`` between both."""
+        cfg = ctx.cfg
+        x, *rest = carry
+        mixer = MIXERS[kind]
+        h = x if cfg.post_norm else self._norm(lp["norm1"], x)
+        out, written = mixer.run(
+            self, h, [rest[i] for i in mixer.pools], lp[mixer.weights], at,
+            kind=kind, ctx=ctx)
+        for i, pool in zip(mixer.pools, written):
+            rest[i] = pool
+        if cfg.sandwich_norm:
+            out = self._norm(lp["norm1_post"], out)
+        if cfg.post_norm:
+            out = self._norm(lp["norm1"], out.astype(x.dtype))
+        feed = functools.partial(
+            self._feed_forward, lp, ctx=ctx, routed=routed, experts=experts,
+            counts=rest[-1] if self.step_tail else None)
+        if cfg.parallel_residual:
+            mlp_out, counts = feed(self._norm(lp["norm2"], x))
+            x = x + out.astype(x.dtype) + mlp_out.astype(x.dtype)
+        else:
+            x = x + out.astype(x.dtype)
+            out, counts = feed(
+                x if cfg.post_norm else self._norm(lp["norm2"], x))
+            if cfg.sandwich_norm:
+                out = self._norm(lp["norm2_post"], out.astype(x.dtype))
+            if cfg.post_norm:
+                out = self._norm(lp["norm2"], out.astype(x.dtype))
+            x = x + out.astype(x.dtype)
+        if counts is not None:
+            rest[-1] = counts
+        return (x, *rest)
+
+    def _feed_forward(self, lp, h, *, ctx: Pass, routed, experts, counts):
+        """A layer's feed-forward: the dense block (or the self-wired
+        ``mlp_fn``, its MoE aux dropped), or the routed layer's held share
+        (``moe/held.py``) plus the shared expert and the pass's three
+        counts brought up to date.  Returns (output, counts)."""
+        cfg = ctx.cfg
+        if "moe" not in lp:
+            out = (self.mlp_fn or T._mlp_block)(cfg, lp["mlp"], h)
+            return (out[0] if isinstance(out, tuple) else out), counts
+        mp, (stack, first) = lp["moe"], experts
+        S, Q, E = h.shape
+        h2 = h.reshape(S * Q, E)
+        chosen, weights = held.ROUTERS[cfg.router_scoring](
+            h2, mp["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
+            cfg.norm_topk_prob)
+        out, pairs = held.held_experts_ffn(
+            h2, chosen, weights, stack, cfg.experts_first,
+            layer=routed - first if first else routed, valid=ctx.valid)
+        out = out.reshape(S, Q, E)
+        if "shared" in mp:
+            out = out + T._mlp_block(cfg, mp["shared"], h)
+        return out, jnp.stack([counts[0] + jnp.sum(pairs),
+                               jnp.maximum(counts[1], jnp.max(pairs)),
+                               counts[2] + jnp.sum(pairs > 0)])
+
+    def _kv_mixer(self, h, pools, ap, layer, *, kind, ctx: Pass):
+        """K/V attention of ``h`` (all tokens of the segments) over
+        ``pool[layer]``, the "full" and the "window" kind alike: the
+        projections once over all tokens (with the biases, the
+        whole-width Q/K norm and the kind's rope the configuration has),
+        the cache write and the kernel segment by segment, each head's
+        output through its sigmoid gate under ``cfg.head_gate``."""
+        cfg, kc, (pool,) = ctx.cfg, self._kind_cfg[kind], pools
         dtype = cfg.dtype
-        h = self._norm(lp["norm1"], x)
-        ap = lp["attn"]
-        q = jnp.einsum("sqe,ehd->sqhd", h, T._wval(ap["wq"], dtype))
-        k = jnp.einsum("sqe,ekd->sqkd", h, T._wval(ap["wk"], dtype))
-        v = jnp.einsum("sqe,ekd->sqkd", h, T._wval(ap["wv"], dtype))
-        if cfg.use_bias or cfg.qkv_bias:
-            q = q + ap["bq"].astype(dtype)
-            k = k + ap["bk"].astype(dtype)
-            v = v + ap["bv"].astype(dtype)
-        if cfg.pos_emb == "rope":
-            q = T.apply_rope(q, sin, cos)
-            k = T.apply_rope(k, sin, cos)
-        attn = []
-        for seg, qs, ks, vs in zip(segments, *(
-                _per_segment(a, segments) for a in (q, k, v))):
-            kv = self._per_shard_heads(
-                _write_new_kv, cfg, 2, pool_out=True)(
-                ks, vs, kv, layer, seg.page_table, seg.start_pos,
-                seg.q_lens)
-            if seg.fresh and self._fresh_attention is not None:
-                # pure prefill: every slot's context IS its own new
-                # tokens — flash over [S(batch), H, Q, D], no paged
-                # gather at all (reference blocked_flash prefill atoms);
-                # padding-tail rows are garbage but only feed rows that
-                # logits_gather ignores and KV slots the null page
-                # swallows
-                attn.append(self._per_shard_heads(
-                    self._fresh_attention, cfg, 3)(qs, ks, vs))
-            else:
-                attn.append(self._per_shard_heads(self._attention, cfg, 1)(
-                    qs, kv, layer, seg.page_table, seg.start_pos,
-                    seg.q_lens))
-        attn = _end_to_end(attn)
+        folded = getattr(ap["wq"], "ndim", 3) == 2
+        q, k, v = (_qkv_folded if folded else _qkv_by_axis)(
+            h, ap, cfg, ctx.ropes.get(kind), self._norm)
+        write = self._per_shard_heads(_write_new_kv, kc, 2, pool_out=True)
+        # pure prefill: every slot's context IS its own new tokens: flash
+        # over [S(batch), H, Q, D], no paged gather (reference
+        # blocked_flash prefill atoms); padding-tail rows are garbage but
+        # only feed rows that logits_gather ignores and KV slots the null
+        # page swallows
+        fresh = self._fresh_attention[kind] and self._per_shard_heads(
+            self._fresh_attention[kind], kc, 3)
+        paged = self._per_shard_heads(self._attention[kind], kc, 1)
+        attn, pool = _write_then_attend(
+            ctx.by_group[kind], (q, k, v), pool,
+            lambda pool, seg, qs, ks, vs: write(
+                ks, vs, pool, layer, seg.page_table, seg.start_pos,
+                seg.q_lens),
+            fresh,
+            lambda pool, seg, qs, ks, vs: paged(
+                qs, pool, layer, seg.page_table, seg.start_pos, seg.q_lens))
+        if cfg.head_gate:
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "sqe,eh->sqh", h, ap["wgate"].astype(dtype),
+                preferred_element_type=jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate[..., None]).astype(dtype)
+        if folded:
+            return jnp.einsum("sqf,fe->sqe",
+                              attn.reshape(attn.shape[:2] + (-1,)),
+                              ap["wo"].astype(dtype)), (pool,)
         out = jnp.einsum("sqhd,hde->sqe", attn, T._wval(ap["wo"], dtype))
         if cfg.use_bias:
             out = out + ap["bo"].astype(dtype)
-        if cfg.parallel_residual:
-            h2 = self._norm(lp["norm2"], x)
-            mlp_out = (self.mlp_fn or T._mlp_block)(cfg, lp["mlp"], h2)
-            if isinstance(mlp_out, tuple):                  # MoE aux dropped
-                mlp_out = mlp_out[0]
-            return x + out.astype(x.dtype) + mlp_out.astype(x.dtype), kv
-        x = x + out.astype(x.dtype)
-        h = self._norm(lp["norm2"], x)
-        mlp_out = (self.mlp_fn or T._mlp_block)(cfg, lp["mlp"], h)
-        if isinstance(mlp_out, tuple):                      # MoE aux dropped
-            mlp_out = mlp_out[0]
-        return x + mlp_out.astype(x.dtype), kv
+        return out, (pool,)
 
-    def _forward_hidden_latent(self, params, kv, segments, cfg, stats_out):
-        """The trunk of the latent kind: a stack of dense layers, then a
-        stack of routed ones, each its own scan, the pool's layer index
-        running on through both.  The carry holds the held-experts
-        counts beside the activations and the pool."""
-        x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
-                        _end_to_end([seg.token_ids for seg in segments]))
-        pos = _positions(segments)
-        d = cfg.qk_rope_head_dim
-        freqs = cfg.rope_theta ** (
-            -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-        angles = pos[..., None].astype(jnp.float32) * freqs
-        valid = _end_to_end([
-            jnp.arange(seg.token_ids.shape[1], dtype=jnp.int32)[None, :]
-            < seg.q_lens[:, None] for seg in segments]).reshape(-1)
-        body = functools.partial(
-            self._layer_body_latent, segments=segments,
-            sin=jnp.sin(angles), cos=jnp.cos(angles), cfg=cfg, valid=valid)
-        carry = (x, kv, jnp.zeros((3,), jnp.int32))
-        base = 0
-        for name in ("dense_layers", "layers"):
-            if name not in params:
-                continue
-            stack = params[name]
-            n = jax.tree.leaves(stack)[0].shape[0]
-            layers = base + jnp.arange(n, dtype=jnp.int32)
-            experts = None
-            if "moe" in stack:
-                # the held experts' weights stay one stack, addressed by
-                # the kernel through the layer's index: scanned, each
-                # layer's 1.5 GB would be sliced out for the custom call
-                experts = stack["moe"]["experts"]
-                stack = dict(stack, moe={k: v for k, v in
-                                         stack["moe"].items()
-                                         if k != "experts"})
-            carry, _ = jax.lax.scan(
-                lambda c, xs, experts=experts, base=base: (
-                    body(*c, *xs, experts=experts, stack_base=base), None),
-                carry, (stack, layers))
-            base += n
-        x, kv, stats = carry
-        if stats_out is not None:
-            stats_out.append(stats)
-        return self._norm(params["final_norm"], x), kv
-
-    def _layer_body_latent(self, x, kv, stats, lp, layer, *, segments,
-                           sin, cos, cfg, valid, experts=None,
-                           stack_base=0):
-        """One layer of the latent kind over ``x``, the pool and the
-        counts: sandwich norms, latent attention, then the dense MLP or
-        the routed layer's held share plus the shared expert."""
-        attn, kv = self._attend_latent(
-            self._norm(lp["norm1"], x), kv, lp["attn"], layer,
-            segments=segments, sin=sin, cos=cos, cfg=cfg)
-        if cfg.sandwich_norm:
-            attn = self._norm(lp["norm1_post"], attn)
-        x = x + attn.astype(x.dtype)
-        h = self._norm(lp["norm2"], x)
-        if "moe" in lp:
-            from ...moe.held import held_experts_ffn, route_sigmoid_topk
-            mp = lp["moe"]
-            S, Q, E = h.shape
-            h2 = h.reshape(S * Q, E)
-            chosen, weights = route_sigmoid_topk(
-                h2, mp["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
-                cfg.norm_topk_prob)
-            out, counts = held_experts_ffn(
-                h2, chosen, weights, experts, cfg.experts_first,
-                layer=layer - stack_base, valid=valid)
-            out = out.reshape(S, Q, E)
-            if "shared" in mp:
-                out = out + T._mlp_block(cfg, mp["shared"], h)
-            stats = jnp.stack([stats[0] + jnp.sum(counts),
-                               jnp.maximum(stats[1], jnp.max(counts)),
-                               stats[2] + jnp.sum(counts > 0)])
-        else:
-            out = T._mlp_block(cfg, lp["mlp"], h)
-        if cfg.sandwich_norm:
-            out = self._norm(lp["norm2_post"], out.astype(x.dtype))
-        return x + out.astype(x.dtype), kv, stats
-
-    def _attend_latent(self, h, kv, ap, layer, *, segments, sin, cos, cfg):
-        """Latent attention of ``h`` (all tokens of ``segments``): the
+    def _latent_mixer(self, h, pools, ap, layer, *, kind, ctx: Pass):
+        """Latent attention of ``h`` (all tokens of the segments): the
         projections once over all of them, then each segment writes its
         new tokens' ``[c ; k_r]`` planes into ``pool[layer]`` and
         attends — expanded (192-wide scores, 128-wide values) for a pure
-        prefill, absorbed over the paged planes otherwise.  Returns
-        (output in ``h``'s layout, pool)."""
+        prefill, absorbed over the paged planes otherwise."""
+        cfg, (kv,), (sin, cos) = ctx.cfg, pools, ctx.ropes[kind]
         dtype = cfg.dtype
         dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
         scale = float(dn + cfg.qk_rope_head_dim) ** -0.5
@@ -1323,311 +1526,39 @@ class RaggedInferenceModel:
         pad = kv.shape[-1] - rkv - k_r.shape[-1]
         plane = jnp.concatenate([c, k_r, jnp.zeros((S, Q, pad), dtype)], -1)
         w_k, w_v = ap["wkv_b_k"].astype(dtype), ap["wkv_b_v"].astype(dtype)
-        out = []
-        for seg, plane_s, cs, k_rs, q_ns, q_rs in zip(segments, *(
-                _per_segment(a, segments)
-                for a in (plane, c, k_r, q_n, q_r))):
-            kv = latent_write(kv, layer, plane_s, seg.page_table,
-                              seg.start_pos, seg.q_lens)
-            if seg.fresh:
-                k_n = jnp.einsum("sqr,rhd->sqhd", cs, w_k)
-                k = jnp.concatenate([k_n, jnp.broadcast_to(
-                    k_rs[:, :, None, :],
-                    k_n.shape[:3] + k_rs.shape[-1:])], -1)
-                out.append(mla_fresh_attention(
-                    jnp.concatenate([q_ns, q_rs], -1), k,
-                    jnp.einsum("sqr,rhd->sqhd", cs, w_v), sm_scale=scale))
-            else:
-                q_abs = jnp.concatenate(
-                    [jnp.einsum("sqhd,rhd->sqhr", q_ns, w_k), q_rs,
-                     jnp.zeros(q_rs.shape[:3] + (pad,), dtype)], -1)
-                ctx = mla_paged_attention(q_abs, kv, layer, seg.page_table,
-                                          seg.start_pos, seg.q_lens,
-                                          rank=rkv, sm_scale=scale)
-                out.append(jnp.einsum("sqhr,rhd->sqhd", ctx, w_v))
-        out = _end_to_end(out)
-        return jnp.einsum("sqhd,hde->sqe", out, ap["wo"].astype(dtype)), kv
 
-    def _by_group(self, seg: Segment) -> Dict[str, Segment]:
-        """A segment of a model with two page groups, as each group's
-        layers take it: the wide table (``ragged/batch.py``) apart.  The
-        window group's short table starts at the page of absolute index
-        ``base``, so its rows are REBASED by ``base`` pages: the cache
-        write and attention use positions only to find a token's slot
-        and to mask, which depend on differences of positions alone (the
-        rope is applied before, from the absolute positions)."""
-        parts = self.table.split(seg.page_table, seg.token_ids.shape[1])
-        return {"full": seg._replace(page_table=parts["full"]),
-                "window": seg._replace(
-                    page_table=parts["window"],
-                    start_pos=seg.start_pos
-                    - parts["base"] * self.kv_config.page_size)}
+        def fresh(plane_s, cs, k_rs, q_ns, q_rs):
+            k_n = jnp.einsum("sqr,rhd->sqhd", cs, w_k)
+            k = jnp.concatenate([k_n, jnp.broadcast_to(
+                k_rs[:, :, None, :], k_n.shape[:3] + k_rs.shape[-1:])], -1)
+            return self._fresh_attention[kind](
+                jnp.concatenate([q_ns, q_rs], -1), k,
+                jnp.einsum("sqr,rhd->sqhd", cs, w_v), sm_scale=scale)
 
-    def _forward_hidden_kinds(self, params, kv, segments, cfg, stats_out):
-        """The trunk of a model whose layers are of two attention kinds
-        (``models/laguna.py``): the leading dense layers, then ONE scan
-        over the whole periods of the layer pattern whose body is the
-        period's layers in order, then the tail; still one pass of the
-        weights over all tokens.  ``kv`` is the pair (full group's pool,
-        window group's pool): the carry holds both beside the activations
-        and the held-experts counts, and a layer writes and reads its own
-        group's pool at its index in the group."""
-        from ...models.laguna import layer_plan, rope_table
-        x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
-                        _end_to_end([seg.token_ids for seg in segments]))
-        pos = _positions(segments)
-        valid = _end_to_end([
-            jnp.arange(seg.token_ids.shape[1], dtype=jnp.int32)[None, :]
-            < seg.q_lens[:, None] for seg in segments]).reshape(-1)
-        groups = [self._by_group(seg) for seg in segments]
-        kinds = cfg.layer_kinds
-        body = functools.partial(
-            self._layer_body_kinds, cfg=cfg, valid=valid,
-            segments={kind: [g[kind] for g in groups]
-                      for kind in ("full", "window")},
-            ropes={kind: rope_table(cfg, kind, pos)
-                   for kind in ("full", "window")},
-            experts=params.get("experts"))
-        dense, period, periods = layer_plan(cfg)
-        carry = (x, *kv, jnp.zeros((3,), jnp.int32))
-        at = {"full": 0, "window": 0}    # the next layer's index in its group
-        for i in range(dense):
-            carry = body(carry, params["dense_layers"][f"l{i}"],
-                         kind=kinds[i], at=at[kinds[i]])
-            at[kinds[i]] += 1
-        if periods:
-            pattern = kinds[dense:dense + period]
-            per = {kind: pattern.count(kind) for kind in at}
+        def paged(kv, seg, plane_s, cs, k_rs, q_ns, q_rs):
+            q_abs = jnp.concatenate(
+                [jnp.einsum("sqhd,rhd->sqhr", q_ns, w_k), q_rs,
+                 jnp.zeros(q_rs.shape[:3] + (pad,), dtype)], -1)
+            ctx_ = self._attention[kind](
+                q_abs, kv, layer, seg.page_table, seg.start_pos, seg.q_lens,
+                rank=rkv, sm_scale=scale)
+            return jnp.einsum("sqhr,rhd->sqhd", ctx_, w_v)
 
-            def one_period(carry, xs, at=dict(at)):
-                lps, p = xs
-                met = dict.fromkeys(per, 0)
-                for j, kind in enumerate(pattern):
-                    carry = body(carry, lps[f"l{j}"], kind=kind,
-                                 at=at[kind] + p * per[kind] + met[kind],
-                                 routed=p * period + j)
-                    met[kind] += 1
-                return carry, None
+        out, kv = _write_then_attend(
+            ctx.by_group[kind], (plane, c, k_r, q_n, q_r), kv,
+            lambda kv, seg, plane_s, *_: latent_write(
+                kv, layer, plane_s, seg.page_table, seg.start_pos,
+                seg.q_lens), fresh, paged)
+        return jnp.einsum("sqhd,hde->sqe", out, ap["wo"].astype(dtype)), (kv,)
 
-            carry, _ = jax.lax.scan(
-                one_period, carry,
-                (params["periods"], jnp.arange(periods, dtype=jnp.int32)))
-            for kind in at:
-                at[kind] += periods * per[kind]
-        first = dense + periods * period
-        for i in range(first, cfg.num_layers):
-            carry = body(carry, params["tail"][f"l{i - first}"],
-                         kind=kinds[i], at=at[kinds[i]], routed=i - dense)
-            at[kinds[i]] += 1
-        x, full, window, stats = carry
-        if stats_out is not None:
-            stats_out.append(stats)
-        return self._norm(params["final_norm"], x), (full, window)
-
-    def _layer_body_kinds(self, carry, lp, *, kind, at, routed=None,
-                          segments, ropes, cfg, valid, experts):
-        """One layer of kind ``kind`` over (x, both pools, the counts):
-        attention with the kind's head count, rope and page group (layer
-        ``at`` of the group's pool), each head's output through its
-        sigmoid gate, then the dense MLP or routed layer ``routed``'s held
-        share plus the shared expert."""
-        x, full, window, stats = carry
-        pool = full if kind == "full" else window
-        dtype = cfg.dtype
-        h = self._norm(lp["norm1"], x)
-        ap = lp["attn"]
-        sin, cos = ropes[kind]
-        d = cfg.dims_per_head
-
-        def heads(w):
-            """``h W`` by head, ``[S, Q, heads, d]`` (the weights are
-            stored with the heads folded into their columns)."""
-            y = jnp.einsum("sqe,ef->sqf", h, w.astype(dtype))
-            return y.reshape(y.shape[:2] + (-1, d))
-
-        q = T.apply_rope(heads(ap["wq"]), sin, cos)
-        k = T.apply_rope(heads(ap["wk"]), sin, cos)
-        v = heads(ap["wv"])
-        segs, attn = segments[kind], []
-        for seg, qs, ks, vs in zip(segs, *(
-                _per_segment(a, segs) for a in (q, k, v))):
-            pool = write_kv(pool, at, ks, vs, seg.page_table, seg.start_pos,
-                            seg.q_lens)
-            if seg.fresh:
-                attn.append(self._fresh_of[kind](qs, ks, vs))
-            else:
-                attn.append(self._attention_of[kind](
-                    qs, pool, at, seg.page_table, seg.start_pos,
-                    seg.q_lens))
-        attn = _end_to_end(attn)
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "sqe,eh->sqh", h, ap["wgate"].astype(dtype),
-            preferred_element_type=jnp.float32))
-        attn = (attn.astype(jnp.float32) * gate[..., None]).astype(dtype)
-        x = x + jnp.einsum(
-            "sqf,fe->sqe", attn.reshape(attn.shape[:2] + (-1,)),
-            ap["wo"].astype(dtype)).astype(x.dtype)
-        h = self._norm(lp["norm2"], x)
-        if "moe" in lp:
-            from ...moe.held import ROUTERS, held_experts_ffn
-            mp = lp["moe"]
-            S, Q, E = h.shape
-            h2 = h.reshape(S * Q, E)
-            chosen, weights = ROUTERS[cfg.router_scoring](
-                h2, mp["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
-                cfg.norm_topk_prob)
-            out, counts = held_experts_ffn(
-                h2, chosen, weights, experts, cfg.experts_first,
-                layer=routed, valid=valid)
-            out = out.reshape(S, Q, E)
-            if "shared" in mp:
-                out = out + T._mlp_block(cfg, mp["shared"], h)
-            stats = jnp.stack([stats[0] + jnp.sum(counts),
-                               jnp.maximum(stats[1], jnp.max(counts)),
-                               stats[2] + jnp.sum(counts > 0)])
-        else:
-            out = T._mlp_block(cfg, lp["mlp"], h)
-        x = x + out.astype(x.dtype)
-        return ((x, pool, window, stats) if kind == "full"
-                else (x, full, pool, stats))
-
-    def _forward_hidden_state(self, params, kv, segments, cfg):
-        """The trunk of a model with layers that hold a slot of the state
-        pool (state-space "ssm" or delta-rule "delta": ``cache_kinds.py``)
-        beside attention layers: ONE scan over the whole periods of
-        the layer pattern whose body is the period's RUNS of like layers,
-        each run of several a scan of its own (7 Mamba, the attention
-        layer, 6 Mamba: a program holds two Mamba bodies and one
-        attention body, not fourteen), then the tail; still one pass of
-        the weights over all tokens.  Every scan runs over a COUNTER and
-        takes its layer out of the kind's one flat stack by index, as a
-        scan takes its operand's: a stack of periods scanned as an
-        operand would have each period's run sliced out whole.  ``kv`` is
-        (the attention layers' page pool, the state pool's ``h``, its
-        ``conv``): the carry holds all three beside the activations, and
-        a layer writes and reads its own kind's pool at its index among
-        the layers of its kind.
-        A row's slot rides the table's last column."""
-        layer_runs = T.layer_runs
-        x = self._embed(params["embed"]["tokens"].astype(cfg.dtype),
-                        _end_to_end([seg.token_ids for seg in segments]))
-        scratch = kv[1].shape[1] - 1
-        paged, rows = [], []
-        for seg in segments:
-            Q = seg.token_ids.shape[1]
-            parts = self.table.split(seg.page_table, Q)
-            paged.append(seg._replace(page_table=parts["full"]))
-            # (slot, starts from zeros, which of its Q positions are true)
-            rows.append((jnp.clip(parts["slot"], 0, scratch),
-                         seg.start_pos == 0,
-                         jnp.arange(Q, dtype=jnp.int32)[None, :]
-                         < seg.q_lens[:, None]))
-        body = functools.partial(self._layer_body_state, cfg=cfg,
-                                 segments=paged, rows=rows)
-        runs, periods, tail = layer_runs(cfg)
-        per = {kind: sum(n for k, n in runs if k == kind)
-               for kind in dict.fromkeys(cfg.layer_kinds)}
-        carry = (x, *kv)
-
-        stacks = params["layers"]
-
-        def layer(carry, kind, at):
-            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-                a, at, 0, keepdims=False), stacks[kind])
-            return body(carry, lp, kind=kind, at=at)
-
-        def one_period(carry, p):
-            met = dict.fromkeys(per, 0)
-            for kind, n in runs:
-                first = p * per[kind] + met[kind]
-                if n == 1:
-                    carry = layer(carry, kind, first)
-                else:
-                    carry, _ = jax.lax.scan(
-                        lambda c, m, kind=kind, first=first: (
-                            layer(c, kind, first + m), None),
-                        carry, jnp.arange(n, dtype=jnp.int32))
-                met[kind] += n
-            return carry, None
-
-        if periods:
-            carry, _ = jax.lax.scan(
-                one_period, carry, jnp.arange(periods, dtype=jnp.int32))
-        at = {kind: periods * n for kind, n in per.items()}
-        for m in range(tail):
-            kind = cfg.layer_kinds[cfg.num_layers - tail + m]
-            carry = layer(carry, kind, jnp.int32(at[kind]))
-            at[kind] += 1
-        x, *kv = carry
-        return self._norm(params["final_norm"], x), tuple(kv)
-
-    def _layer_body_state(self, carry, lp, *, kind, at, segments, rows,
-                          cfg):
-        """One layer of kind ``kind`` over (x, the page pool, the state
-        pool's two arrays): the kind's mixer at layer ``at`` of its own
-        pool, then the llama block's SwiGLU; each behind a norm of its
-        input, or under ``cfg.post_norm`` before a norm of its output."""
-        x, pages, h_pool, conv_pool = carry
-        h = x if cfg.post_norm else self._norm(lp["norm1"], x)
-        if CACHE_KINDS[kind].slot:
-            mixer = {"ssm": self._ssm_mixer, "delta": self._delta_mixer}
-            out, h_pool, conv_pool = mixer[kind](
-                h, h_pool, conv_pool, lp["mixer"], at, segments=segments,
-                rows=rows, cfg=cfg)
-        else:
-            out, pages = self._attend_plain(h, pages, lp["attn"], at,
-                                            segments=segments, cfg=cfg)
-        if cfg.post_norm:
-            x = x + self._norm(lp["norm1"], out.astype(x.dtype))
-            out = self._norm(lp["norm2"],
-                             T._mlp_block(cfg, lp["mlp"], x).astype(x.dtype))
-            return x + out, pages, h_pool, conv_pool
-        x = x + out.astype(x.dtype)
-        out = T._mlp_block(cfg, lp["mlp"], self._norm(lp["norm2"], x))
-        return x + out.astype(x.dtype), pages, h_pool, conv_pool
-
-    def _attend_plain(self, h, pool, ap, layer, *, segments, cfg):
-        """Attention with no positional encoding over ``pool[layer]``:
-        the projections once over all tokens (the weights stored as the
-        matrices the products take, heads folded into the columns; under
-        ``cfg.qk_norm`` q and k normed over their whole width before the
-        page write), the cache write and the paged kernel segment by
-        segment."""
-        dtype, d = cfg.dtype, cfg.dims_per_head
-
-        def heads(w, gain=None):
-            y = jnp.einsum("sqe,ef->sqf", h, w.astype(dtype))
-            if gain is not None:
-                y = self._norm(gain, y)
-            return y.reshape(y.shape[:2] + (-1, d))
-
-        q = heads(ap["wq"], ap["q_norm"] if cfg.qk_norm else None)
-        k = heads(ap["wk"], ap["k_norm"] if cfg.qk_norm else None)
-        v = heads(ap["wv"])
-        attn = []
-        for seg, qs, ks, vs in zip(segments, *(
-                _per_segment(a, segments) for a in (q, k, v))):
-            pool = write_kv(pool, layer, ks, vs, seg.page_table,
-                            seg.start_pos, seg.q_lens)
-            if seg.fresh and self._fresh_attention is not None:
-                attn.append(self._fresh_attention(qs, ks, vs))
-            else:
-                attn.append(self._attention(
-                    qs, pool, layer, seg.page_table, seg.start_pos,
-                    seg.q_lens))
-        attn = _end_to_end(attn)
-        return jnp.einsum("sqf,fe->sqe",
-                          attn.reshape(attn.shape[:2] + (-1,)),
-                          ap["wo"].astype(dtype)), pool
-
-    def _ssm_mixer(self, u, h_pool, conv_pool, mp, layer, *, segments,
-                   rows, cfg):
-        """The Mamba mixer of ``u`` (all tokens of ``segments``): its four
+    def _ssm_mixer(self, u, pools, mp, layer, *, kind, ctx: Pass):
+        """The Mamba mixer of ``u`` (all tokens of the segments): its four
         projections once over all of them; the convolution and the
         recurrence segment by segment, each row from and to its slot of
         ``pool[layer]`` (``ops/ssm.py``).  Returns (output in ``u``'s
-        layout, h pool, conv pool)."""
-        from ...ops.ssm import conv_step, ssm_scan
+        layout, (h pool, conv pool))."""
+        cfg, segments, rows, (h_pool, conv_pool) = (
+            ctx.cfg, ctx.by_group["full"], ctx.rows, pools)
         dtype, f32 = cfg.dtype, jnp.float32
         d, n, r = cfg.ssm_inner, cfg.ssm_state_dim, cfg.ssm_dt_rank
         xz = jnp.einsum("sqe,ef->sqf", u, mp["w_in"].astype(dtype))
@@ -1669,18 +1600,17 @@ class RaggedInferenceModel:
             ys.append(y)
         y = _end_to_end(ys) * jax.nn.silu(z.astype(f32))
         return jnp.einsum("sqd,de->sqe", y.astype(dtype),
-                          mp["w_out"].astype(dtype)), h_pool, conv_pool
+                          mp["w_out"].astype(dtype)), (h_pool, conv_pool)
 
-    def _delta_mixer(self, u, s_pool, conv_pool, mp, layer, *, segments,
-                     rows, cfg):
-        """The gated delta-rule mixer of ``u`` (all tokens of
-        ``segments``): its projections once over all of them; the
+    def _delta_mixer(self, u, pools, mp, layer, *, kind, ctx: Pass):
+        """The gated delta-rule mixer of ``u`` (all tokens of the
+        segments): its projections once over all of them; the
         convolution over q, k and v and the recurrence segment by segment,
         each row from and to its slot of ``pool[layer]``
         (``ops/delta_rule.py``; the convolution is ``ops/ssm.py``'s).
-        Returns (output in ``u``'s layout, state pool, conv pool)."""
-        from ...ops.delta_rule import delta_rule
-        from ...ops.ssm import conv_step
+        Returns (output in ``u``'s layout, (state pool, conv pool))."""
+        cfg, segments, rows, (s_pool, conv_pool) = (
+            ctx.cfg, ctx.by_group["full"], ctx.rows, pools)
         dtype, f32 = cfg.dtype, jnp.float32
         H, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
         qkv = jnp.einsum("sqe,ef->sqf", u, mp["w_qkv"].astype(dtype))
@@ -1720,7 +1650,7 @@ class RaggedInferenceModel:
             * mp["o_norm"]["scale"].astype(f32)
         y = y.reshape(gate.shape) * jax.nn.silu(gate.astype(f32))
         return jnp.einsum("sqd,de->sqe", y.astype(dtype),
-                          mp["w_out"].astype(dtype)), s_pool, conv_pool
+                          mp["w_out"].astype(dtype)), (s_pool, conv_pool)
 
     def _per_shard_heads(self, fn, cfg, n_head_args: int,
                          pool_out: bool = False):
@@ -1793,8 +1723,7 @@ class RaggedInferenceModel:
         lines added above a kernel's call move the callers' line numbers
         that Mosaic writes into every step program.)"""
         from ...ops.paged_attention import kernel_blocks
-        cfg = (self._kind_cfg[kind] if self.window_kv_config is not None
-               else self.cfg)
+        cfg = self._kind_cfg[kind]
         kv = self.window_kv_config if kind == "window" else self.kv_config
         alibi = cfg.pos_emb == "alibi"
         tp = self.tp_degree
@@ -1806,3 +1735,15 @@ class RaggedInferenceModel:
             kv.page_size, page_slots, itemsize,
             1 if kv.quantized else jnp.dtype(kv.dtype).itemsize,
             kv.quantized, alibi)[1]
+
+
+#: a layer kind is an entry here, one in ``cache_kinds.py::CACHE_KINDS``
+#: (what it caches; the latent plane lies in the one page pool) and its
+#: kernel
+MIXERS: Dict[str, Mixer] = {
+    "full": Mixer(RaggedInferenceModel._kv_mixer, "attn", (0,)),
+    "window": Mixer(RaggedInferenceModel._kv_mixer, "attn", (1,)),
+    "latent": Mixer(RaggedInferenceModel._latent_mixer, "attn", (0,)),
+    "ssm": Mixer(RaggedInferenceModel._ssm_mixer, "mixer", (1, 2)),
+    "delta": Mixer(RaggedInferenceModel._delta_mixer, "mixer", (1, 2)),
+}

@@ -153,6 +153,12 @@ class LagunaInferenceModel(RaggedInferenceModel):
             == (cfg.num_layers - cfg.first_k_dense, held), \
             "expert weights do not match the routed layers or experts_held"
 
+    def rope_table(self, cfg, kind, positions):
+        """YaRN over part of a full head's dims, a plain rope of its own
+        base over all of a window head's."""
+        from ...models.laguna import rope_table
+        return rope_table(cfg, kind, positions)
+
 
 class JambaInferenceModel(RaggedInferenceModel):
     """Jamba (``models/jamba.py``; no counterpart in the reference):

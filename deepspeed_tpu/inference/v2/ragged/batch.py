@@ -17,7 +17,7 @@ the table is WIDE (``cache_kinds.TableLayout``): the full group's table,
 then the window group's short table (``W`` slots, slot j = the page of
 absolute index ``base + j``) and ``base`` itself, then the row's slot of
 the state pool: they ride the operand the programs already take, and
-``RaggedInferenceModel`` takes it apart (``_by_group``).
+``RaggedInferenceModel`` takes it apart (``_forward_hidden``).
 
 ``S`` (sequence slots), ``Q`` (max new tokens per sequence) and ``P``
 (max pages per sequence) are bucketed by the engine's lattice (powers of
@@ -33,6 +33,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .cache_kinds import TableLayout
 from .sequence import SequenceDescriptor
 
 
@@ -89,7 +90,8 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
                 fresh_supported: bool = True,
                 min_q: int = 1,
                 start_pos: Optional[Sequence[int]] = None,
-                table=None, scratch_slot: int = 0) -> RaggedBatch:
+                table: TableLayout = TableLayout(),
+                scratch_slot: int = 0) -> RaggedBatch:
     """Pack (descriptor, new-token) pairs into a bucketed RaggedBatch.
 
     Callers must already have reserved KV pages on each descriptor
@@ -110,8 +112,8 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
     descriptor's committed length (the draft catch-up re-feeds committed
     history from where the draft pool stopped).
 
-    ``table``: the model's :class:`..cache_kinds.TableLayout` where it
-    has more than one cache; the table is then the wide one of the module
+    ``table``: the model's :class:`..cache_kinds.TableLayout`; where it
+    has more than one cache the table is the wide one of the module
     docstring.  ``scratch_slot``: the state pool's scratch slot, which the
     padding rows name.
     """
@@ -125,10 +127,9 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
     token_ids = np.zeros((S, Q), dtype=np.int32)
     q_lens = np.zeros(S, dtype=np.int32)
     starts = np.zeros(S, dtype=np.int32)
-    W = table.window_slots(Q) if table is not None else 0
-    page_table = np.zeros((S, P + (table.extra(Q) if table is not None
-                                   else 0)), dtype=np.int32)
-    if table is not None and table.state:
+    W = table.window_slots(Q)
+    page_table = np.zeros((S, P + table.extra(Q)), dtype=np.int32)
+    if table.state:
         page_table[:, -1] = scratch_slot
     uids = []
     for i, (sd, toks) in enumerate(zip(seqs, tokens)):
@@ -146,7 +147,7 @@ def build_batch(seqs: Sequence[SequenceDescriptor],
                     "kept up with the context)")
             page_table[i, P:P + len(live)] = live
             page_table[i, P + W] = sd.window_base
-        if table is not None and table.state:
+        if table.state:
             page_table[i, -1] = sd.state_slot
         uids.append(sd.uid)
     fresh = fresh_supported and Q > 1 and not any(start_pos)

@@ -5,7 +5,7 @@ none: every layer is "full").  A kind keeps, a sequence, either a PAGE
 PLANE of a page group (its K/V at every position the kind still attends)
 or a SLOT of the state pool (a recurrent state that does not grow with the
 context).  The three places that have to agree on that read it here: the
-model when it takes a segment's table apart (``model.py::_by_group``), the
+model when it takes a segment's table apart (``model.py::_forward_hidden``), the
 host when it builds the table (``batch.py::build_batch``) and the state
 manager when it decides which resources a sequence reserves
 (``manager.py::StateManager``).
@@ -104,6 +104,8 @@ class TableLayout:
         """A table's parts by name: ``full`` ``[S, P]``, and where the
         model has them ``window`` ``[S, W]``, ``base`` ``[S]``, ``slot``
         ``[S]``."""
+        if not self.extra(Q):           # one page group: the table as it is
+            return {"full": table}
         W = self.window_slots(Q)
         P = table.shape[1] - self.extra(Q)
         parts = {"full": table[:, :P]}

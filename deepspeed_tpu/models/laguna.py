@@ -60,7 +60,8 @@ import jax
 import jax.numpy as jnp
 
 from .pangu_moe import _gain, _mlp_init, _normal, _stack
-from .transformer import CausalLM, TransformerConfig, _boxed
+from .transformer import (CausalLM, TransformerConfig, _boxed,
+                          layer_runs)
 
 KINDS = {"full_attention": "full", "sliding_attention": "window"}
 
@@ -135,18 +136,6 @@ def laguna_config(source: Dict[str, Any], *, experts_first: int = 0,
             source.get("moe_routed_scaling_factor", 1.0)),
         norm_topk_prob=bool(source.get("norm_topk_prob", True)),
         first_k_dense=len(dense), dtype=dtype)
-
-
-def layer_plan(cfg: TransformerConfig) -> Tuple[int, int, int]:
-    """(leading dense layers, layers of one period of the pattern that
-    follows them, whole periods): the layers after the periods are the
-    tail.  The period is the shortest that the kinds repeat with."""
-    kinds, dense = cfg.layer_kinds, cfg.first_k_dense
-    rest = kinds[dense:]
-    period = next((p for p in range(1, len(rest) + 1)
-                   if all(rest[i] == rest[i % p] for i in range(len(rest)))),
-                  1)
-    return dense, period, len(rest) // period
 
 
 def group_layers(cfg: TransformerConfig) -> Dict[str, int]:
@@ -258,7 +247,8 @@ def init_laguna_params(cfg: TransformerConfig, rng) -> Dict[str, Any]:
     dtype = cfg.dtype
     e, v = cfg.hidden_size, cfg.vocab_size
     keys = jax.random.split(rng, 4)
-    dense, period, periods = layer_plan(cfg)
+    dense, runs, periods, _ = layer_runs(cfg)
+    period = sum(n for _, n in runs)
     params: Dict[str, Any] = {
         "embed": {"tokens": _boxed(
             jax.random.normal(keys[0], (v, e), dtype)

@@ -1164,20 +1164,31 @@ class CausalLM:
                 mask[:, 1:] if mask is not None else None)
 
 
+def layer_kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """Every layer's kind (``inference/v2/ragged/cache_kinds.py``): the
+    configuration's own list, or for a model that names none one kind for
+    all its layers, "latent" under latent attention and else "full"."""
+    return cfg.layer_kinds or (
+        ("latent" if cfg.latent_dim else "full",) * cfg.num_layers)
+
+
 def layer_runs(cfg: TransformerConfig
-               ) -> Tuple[List[Tuple[str, int]], int, int]:
-    """(the runs of like layers inside one period of the pattern as
-    (kind, length), whole periods, layers after them).  The period is the
-    shortest the kinds repeat with."""
-    kinds = cfg.layer_kinds
-    period = next((p for p in range(1, len(kinds) + 1)
-                   if all(kinds[i] == kinds[i % p]
-                          for i in range(len(kinds)))), len(kinds))
+               ) -> Tuple[int, List[Tuple[str, int]], int, int]:
+    """(the leading layers that stand outside the pattern,
+    ``cfg.first_k_dense``; the runs of like layers inside one period of the
+    pattern that follows them as (kind, length); whole periods; layers
+    after them).  The period is the shortest the kinds repeat with."""
+    kinds = layer_kinds(cfg)
+    leading = min(cfg.first_k_dense, len(kinds))
+    rest = kinds[leading:]
+    period = next((p for p in range(1, len(rest) + 1)
+                   if all(rest[i] == rest[i % p]
+                          for i in range(len(rest)))), 1)
     runs: List[Tuple[str, int]] = []
-    for kind in kinds[:period]:
+    for kind in rest[:period]:
         if runs and runs[-1][0] == kind:
             runs[-1] = (kind, runs[-1][1] + 1)
         else:
             runs.append((kind, 1))
-    periods = len(kinds) // period
-    return runs, periods, len(kinds) - periods * period
+    periods = len(rest) // period
+    return leading, runs, periods, len(rest) - periods * period

@@ -60,8 +60,12 @@ def latent(serving=KEYED):
 
 
 def two_group(serving=KEYED):
+    # three layers hold both page groups and the routed feed-forward (a
+    # leading dense full layer, two window layers): the schedules are
+    # compared here, and a step program of five costs half as much again
     from test_laguna import SOURCE, engine_of, family
-    return engine_of(*family(), serving=serving), SOURCE["vocab_size"]
+    return (engine_of(*family(num_hidden_layers=3), serving=serving),
+            SOURCE["vocab_size"])
 
 
 FAMILIES = {"dense": dense, "latent": latent, "two_group": two_group}
@@ -107,9 +111,21 @@ def free_pages(engine):
 # (a) the streams and the pages of the drained-first schedule
 # ---------------------------------------------------------------------------
 
+def one_bucket_a_dimension(engine, slots=4, tokens=64, pages=16):
+    """Serve under a lattice of one top a dimension (what the supply's
+    four callers, 48-token budget and 74-token sequences fit): the
+    schedules are the thing compared here, not the bucket rule, and every
+    distinct bucket is a step program to form (two dozen of them under the
+    power-of-two default, a dozen so)."""
+    from deepspeed_tpu.inference.v2.lattice import BucketLattice
+    engine._lattice = engine.model.lattice = BucketLattice(
+        s_tops=(slots,), q_tops=(tokens,), p_tops=(pages,))
+
+
 @pytest.mark.parametrize("name", sorted(FAMILIES))
 def test_streams_and_pages_equal_the_drained_first_schedule(name):
     engine, vocab = FAMILIES[name]()
+    one_bucket_a_dimension(engine)
     at_rest = free_pages(engine)
     # one engine, so one set of compiled programs; the scheduler's
     # ``serving`` view says whether a step may run ahead of the drain

@@ -48,10 +48,14 @@ sys.path.insert(0, os.path.join(
 def _fleet_hygiene():
     """Every test starts with telemetry off and clean fleet-observatory
     singletons (the test_telemetry hygiene convention)."""
+    from deepspeed_tpu.telemetry.watchdog import get_watchdog
     telemetry.disable()
     get_timeseries().disable()
     get_slo_evaluator().reset()
     get_federation().clear()
+    # /healthz reads the watchdog's verdict too: a storm an earlier test of
+    # this worker left behind answers 503 before the SLO block is looked at
+    get_watchdog().reset()
     yield
     telemetry.disable()
     get_timeseries().disable()

@@ -956,7 +956,7 @@ class TestModuleRegistry:
         cfg = llama_config("debug")
         params = fmeta.unbox(init_params(cfg, jax.random.key(0)))
         m = RaggedInferenceModel(cfg, params, attention_impl="dense_gather")
-        assert callable(m._attention)
+        assert callable(m._attention["full"])
 
 
 # ---------------------------------------------------------------------------
@@ -1160,19 +1160,23 @@ class TestSlidingWindowServing:
 
 class TestPrecompileLattice:
     def test_precompile_covers_serving_and_strict_catches_misses(self):
-        eng, _, _ = _tiny_engine(num_pages=64, max_batch=256, max_seqs=4)
-        keys = eng.precompile(max_prompt=32, strict=True)
+        # a small lattice (two slots, prompts to 16 tokens, one page
+        # bucket: a fifth of the step programs max_prompt=32 over four
+        # slots and 256 new tokens would form)
+        eng, _, _ = _tiny_engine(num_pages=64, max_batch=64, max_seqs=2)
+        keys = eng.precompile(max_prompt=16, max_new_tokens=16, strict=True)
         assert keys, "empty precompile lattice"
         # every serving shape below the bounds must now dispatch without
         # a fresh compile: run prefill + decode inside strict mode
         rng = np.random.default_rng(0)
-        p1 = rng.integers(0, 100, 20)
+        p1 = rng.integers(0, 100, 12)
         p2 = rng.integers(0, 100, 5)
         logits = eng.put([1, 2], [p1, p2])
         assert logits.shape[0] == 2
         eng.put([1], [np.array([7])])  # decode bucket
         # a shape OUTSIDE the lattice raises instead of compiling
-        big = rng.integers(0, 100, 64)  # prompt > max_prompt bucket
+        eng.flush(2)                    # (two slots: make room)
+        big = rng.integers(0, 100, 32)  # prompt > max_prompt bucket
         with pytest.raises(RuntimeError, match="not precompiled"):
             eng.put([3], [big])
         eng.model.strict_shapes = False
@@ -1198,7 +1202,7 @@ class TestFreshPrefillFlash:
             f"no fresh bucket compiled: {keys}"
 
         eng2 = build()
-        eng2.model._fresh_attention = None  # force paged path
+        eng2.model._fresh_attention = {"full": None}  # force paged path
         logits2 = eng2.put([1], [np.asarray(prompt)])
         np.testing.assert_allclose(np.asarray(logits), np.asarray(logits2),
                                    rtol=2e-5, atol=2e-5)

@@ -123,9 +123,9 @@ def test_layer_pattern_from_period_and_offset():
     assert [i for i, k in enumerate(cfg.layer_kinds) if k == "full"] \
         == [7, 21]
     assert cfg.layer_kinds.count("ssm") == 26
-    assert layer_runs(cfg) == ([("ssm", 7), ("full", 1), ("ssm", 6)], 2, 0)
+    assert layer_runs(cfg) == (0, [("ssm", 7), ("full", 1), ("ssm", 6)], 2, 0)
     assert layer_runs(jamba_config(dict(SOURCE, num_hidden_layers=10))) \
-        == ([("ssm", 1), ("full", 1), ("ssm", 2)], 2, 2)
+        == (0, [("ssm", 1), ("full", 1), ("ssm", 2)], 2, 2)
     assert cfg.pos_emb == "none" and cfg.tie_embeddings
     assert cfg.dims_per_head == 16 and cfg.ssm_inner == 128
 

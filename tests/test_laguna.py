@@ -23,6 +23,7 @@ from deepspeed_tpu.inference.v2.ragged.blocked_allocator import (
 from deepspeed_tpu.inference.v2.step_key import window_slots
 from deepspeed_tpu.models import laguna, laguna_reference as reference
 from deepspeed_tpu.models.laguna import LagunaForCausalLM
+from deepspeed_tpu.models.transformer import layer_runs
 from deepspeed_tpu.moe import held
 
 WINDOW, PAGE = 32, 8
@@ -122,14 +123,26 @@ def test_served_logits_match_the_plain_reference(layers, lengths, prompts):
     reference's full forward: contexts cross the window (32) and page
     boundaries (8), and window pages are released on the way."""
     cfg, params = family(num_hidden_layers=layers)
-    assert laguna.layer_plan(cfg) == {5: (1, 4, 1), 8: (1, 4, 1),
-                                      10: (1, 4, 2)}[layers]
+    period = [("window", 3), ("full", 1)]
+    assert layer_runs(cfg) == {5: (1, period, 1, 0), 8: (1, period, 1, 3),
+                               10: (1, period, 2, 1)}[layers]
     worst, engine = served_logit_error(cfg, params, None, lengths, prompts)
     assert worst < 2e-5
     state = engine.state_manager
     assert state.window_pages_released > 0
     sd = state.get_sequence(0)
     assert sd.window_base > 0 and len(sd.window_pages) < len(sd.pages)
+
+
+def test_a_pattern_of_one_kind_behind_leading_layers_of_the_same_kind():
+    """Two leading dense layers (full, window) and then window layers
+    alone: a pattern of period 1 whose scan starts at the window group's
+    SECOND layer and at the held experts' FIRST."""
+    cfg, params = family(num_hidden_layers=4, mlp_only_layers=[0, 1],
+                         mlp_layer_types=["dense"] * 2 + ["sparse"] * 46)
+    assert layer_runs(cfg) == (2, [("window", 1)], 2, 0)
+    worst, _ = served_logit_error(cfg, params, None, (45,), (9,))
+    assert worst < 2e-5
 
 
 def planted(cfg, fault):
@@ -301,7 +314,7 @@ def test_implementation_for_laguna_and_what_it_refuses():
     assert model.kv_config.num_layers == 2
     assert model.window_kv_config.num_layers == 3
     assert model.step_tail == 3
-    assert model.window_slots(1) == window_slots(WINDOW, 64, 1) == 8
+    assert model.table.window_slots(1) == window_slots(WINDOW, 64, 1) == 8
     with pytest.raises(ValueError, match="int8"):
         engine_of(cfg, params, serving=ServingOptimizationConfig(
             kv_quantization="int8"))
@@ -472,7 +485,7 @@ def test_a_model_of_one_group_has_no_window_pool():
     engine.put([0], [np.arange(50, dtype=np.int32)])
     sd = state.get_sequence(0)
     assert sd.window_pages == [] and sd.pages[0] == 0   # evicted in place
-    assert engine.model.window_slots(1) == 0
+    assert engine.model.table.window_slots(1) == 0
     assert model.cfg.layer_kinds == ()
 
 

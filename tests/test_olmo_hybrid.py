@@ -299,7 +299,7 @@ def test_layer_pattern_and_sizes_from_the_sources_keys():
     issue reckons (2,211,840 B of state and 69,120 B of tail a layer)."""
     cfg = olmo_hybrid_config(SOURCE)
     assert cfg.layer_kinds == ("delta", "delta", "delta", "full") * 2
-    assert layer_runs(cfg) == ([("delta", 3), ("full", 1)], 2, 0)
+    assert layer_runs(cfg) == (0, [("delta", 3), ("full", 1)], 2, 0)
     assert cfg.pos_emb == "none" and not cfg.tie_embeddings
     assert cfg.post_norm and cfg.qk_norm and cfg.delta_neg_eigval
     assert cfg.dims_per_head == 16 and cfg.kv_heads == 4

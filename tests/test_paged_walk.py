@@ -27,7 +27,7 @@ WALKS = [
     # the rule's own tile: wider than the table, one tile a row
     ("K8-P8-rule", 8, 3, 8, None, None, None),
     # a window group's short table, ``start_pos`` counted from its first
-    # page (``model.py::_by_group``): the live pages in its first slots
+    # page (``model.py::_forward_hidden``): the live pages in its first slots
     ("window-rebased", 8, 2, 16, (3, 1), 40, "rebased"),
     # a full table under a sliding window: nulls below the window's page
     ("window-nulls", 1, 4, 40, (4, 2), 40, "nulls"),

@@ -66,34 +66,52 @@ def prompts_of(lengths, seed=0):
             for n in lengths]
 
 
+def reference_logits(params, token_ids, sizes):
+    """``reference.forward``'s logits; of a model without a routed layer
+    (whose pairs ``forward`` cannot stack) from the reference's own layers
+    in ``forward``'s order."""
+    if "layers" in params:
+        return reference.forward(params, token_ids, sizes)[0]
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"]["tokens"].astype(jnp.float32)[token_ids]
+        for lp in reference.layers_of(params):
+            x, _ = reference.layer(x, lp, sizes)
+        x = reference.rms_norm(x, params["final_norm"]["scale"],
+                               sizes["eps"])
+        return x @ params["lm_head"].astype(jnp.float32)
+
+
 def served_logit_error(cfg, params, ref_cfg=None, ref_params=None,
                        lengths=(21, 40), steps=3):
     """Largest relative rms difference of a served logits row (the last
     prompt position, then ``steps`` teacher-forced decode steps through
-    the latent cache) against the plain reference's full forward."""
+    the latent cache) against the plain reference's forward.  The
+    reference runs ONCE a sequence, over the prompt and the forced tokens
+    (it is causal: row ``p - 1`` is the forward of the first ``p`` tokens;
+    run eagerly, every new length compiles each of its operations anew)."""
     sizes = reference.sizes_of(ref_cfg or cfg)
     ref_params = params if ref_params is None else ref_params
     engine = engine_of(cfg, params)
     seqs = prompts_of(lengths)
     rng = np.random.default_rng(1)
     uids = list(range(len(seqs)))
+    forced = [[int(rng.integers(0, SOURCE["vocab_size"])) for _ in seqs]
+              for _ in range(steps)]
+    want = [np.asarray(reference_logits(
+        ref_params, jnp.asarray(list(seq) + [nxt[i] for nxt in forced]),
+        sizes)) for i, seq in enumerate(seqs)]
 
-    def worst(logits):
+    def worst(logits, step):
         out = 0.0
         for i, seq in enumerate(seqs):
-            want = np.asarray(reference.forward(
-                ref_params, jnp.asarray(seq), sizes)[0][-1])
-            got = np.asarray(logits[i])
-            out = max(out, float(np.sqrt(np.mean((got - want) ** 2)
-                                         / np.mean(want ** 2))))
+            got, row = np.asarray(logits[i]), want[i][len(seq) - 1 + step]
+            out = max(out, float(np.sqrt(np.mean((got - row) ** 2)
+                                         / np.mean(row ** 2))))
         return out
 
-    err = worst(engine.put(uids, seqs))
-    for _ in range(steps):
-        nxt = [int(rng.integers(0, SOURCE["vocab_size"])) for _ in seqs]
-        for seq, tok in zip(seqs, nxt):
-            seq.append(tok)
-        err = max(err, worst(engine.put(uids, [[t] for t in nxt])))
+    err = worst(engine.put(uids, seqs), 0)
+    for step, nxt in enumerate(forced):
+        err = max(err, worst(engine.put(uids, [[t] for t in nxt]), step + 1))
     return err
 
 
@@ -108,6 +126,19 @@ def test_served_logits_match_the_plain_reference(lengths):
     assert served_logit_error(cfg, params, lengths=lengths) < 2e-5
 
 
+@pytest.mark.parametrize("dense", [0, 2, 3],
+                         ids=["none", "two", "every_layer"])
+def test_served_logits_with_any_number_of_leading_dense_layers(dense):
+    """The leading dense layers are a stack of their own, the routed ones
+    another: either may be missing (``first_k_dense_replace`` 0, or every
+    layer of a 3-layer cut under the published 3), and the pool's layer
+    index and the held experts' run on through both."""
+    cfg, params = family(first_k_dense_replace=dense)
+    assert ("dense_layers" in params, "layers" in params) \
+        == (dense > 0, dense < 3)
+    assert served_logit_error(cfg, params, lengths=(16,), steps=1) < 2e-5
+
+
 def test_greedy_through_the_scheduler_matches_the_reference():
     """The fused sample / chain step programs (whose token vector carries
     the held-experts counts past its rows) decode what the reference's
@@ -120,11 +151,12 @@ def test_greedy_through_the_scheduler_matches_the_reference():
     out = sched.run_to_completion()
     sizes = reference.sizes_of(cfg)
     for uid, p in enumerate(prompts):
-        seq = list(p)
-        for tok in out[uid]:
-            logits = reference.forward(params, jnp.asarray(seq), sizes)[0]
-            assert int(jnp.argmax(logits[-1])) == tok
-            seq.append(tok)
+        # the reference once a sequence (it is causal: the row before a
+        # token is the forward of what precedes it)
+        logits = reference.forward(
+            params, jnp.asarray(list(p) + out[uid][:-1]), sizes)[0]
+        for n, tok in enumerate(out[uid]):
+            assert int(jnp.argmax(logits[len(p) - 1 + n])) == tok
     assert sched.last_moe_counts is not None \
         and len(sched.last_moe_counts) == 3
 

@@ -275,17 +275,20 @@ class TestContracts:
             sched.step()
 
     def test_strict_lattice_zero_on_path_compiles(self):
+        # a small lattice (36 step programs to form under the mesh, not
+        # 93: two slots, prompts of up to 4 tokens); the third request
+        # waits for a slot
         eng = _engine(serving=_sv(tp=2, keyed_sampling=True),
-                      max_seqs=4, max_batch=64)
-        eng.precompile(max_prompt=16, max_new_tokens=8, sampling=True,
+                      max_seqs=2, max_batch=16)
+        eng.precompile(max_prompt=4, max_new_tokens=4, sampling=True,
                        strict=True)
         before = tm.FASTGEN_COMPILE_ON_PATH.value
         rng = np.random.default_rng(8)
-        prompts = [rng.integers(0, 128, n) for n in (12, 7, 15)]
-        params = [SamplingParams(max_new_tokens=6),
+        prompts = [rng.integers(0, 128, n) for n in (4, 3, 4)]
+        params = [SamplingParams(max_new_tokens=4),
                   SamplingParams(temperature=0.8, top_k=16,
-                                 max_new_tokens=6),
-                  SamplingParams(max_new_tokens=6)]
+                                 max_new_tokens=4),
+                  SamplingParams(max_new_tokens=4)]
         _run(eng, prompts, params)    # strict: any on-path miss raises
         assert tm.FASTGEN_COMPILE_ON_PATH.value == before
 

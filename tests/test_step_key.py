@@ -306,3 +306,104 @@ def test_precompile_keys_skips_what_names_no_program(parts):
     assert eng.compiled_keys(dispatched_only=False) == [
         (1, 1, 8, False, "sample", True)]
     assert not eng.has_kind("chain", "spec")
+
+
+# -- the seam of the step program's trunk: a layer kind is an entry ----------
+
+def _layer_kinds():
+    from deepspeed_tpu.inference.v2.model import MIXERS
+    from deepspeed_tpu.inference.v2.ragged.cache_kinds import CACHE_KINDS
+    return sorted(set(CACHE_KINDS) | set(MIXERS) | {"latent"})
+
+
+@pytest.mark.parametrize("kind", _layer_kinds())
+def test_every_layer_kind_has_a_mixer_and_every_mixer_a_known_kind(kind):
+    """``model.py::MIXERS`` has the keys of ``CACHE_KINDS`` plus the latent
+    kind (whose plane lies in the one page pool), each entry names a method
+    of the model, and the pools it writes are the ones its kind caches in:
+    the state pool's two arrays, the window group's pool, or the first."""
+    from deepspeed_tpu.inference.v2.model import MIXERS
+    from deepspeed_tpu.inference.v2.ragged.cache_kinds import CACHE_KINDS
+    assert kind in MIXERS and (kind in CACHE_KINDS or kind == "latent")
+    mixer = MIXERS[kind]
+    assert getattr(RaggedInferenceModel, mixer.run.__name__) is mixer.run
+    cache = CACHE_KINDS.get(kind)
+    assert mixer.pools == ((1, 2) if cache is not None and cache.slot else
+                           (1,) if cache is not None and cache.windowed
+                           else (0,))
+    assert mixer.weights == ("mixer" if mixer.pools == (1, 2) else "attn")
+
+
+def test_the_step_program_imports_no_family_by_name():
+    """``model.py`` is every family's trunk: of ``deepspeed_tpu/models`` it
+    imports the shared core alone (a family's own functions reach it
+    through the family's class in ``model_implementations.py``)."""
+    import ast
+    path = os.path.join(ROOT, "deepspeed_tpu", "inference", "v2", "model.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    families = {name[:-3] for name in os.listdir(
+        os.path.join(ROOT, "deepspeed_tpu", "models"))
+        if name.endswith(".py")} - {"__init__", "transformer"}
+    assert {"laguna", "jamba", "olmo_hybrid", "pangu_moe"} <= families
+    named = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            module = (node.module or "").split(".")
+            named |= set(module) | ({a.name for a in node.names}
+                                    if module[-1:] == ["models"] else set())
+        elif isinstance(node, ast.Import):
+            named |= {part for a in node.names for part in a.name.split(".")}
+    assert "transformer" in named and not named & families
+
+
+def _pattern_cfg(kinds=(), leading=0, layers=0, latent=False):
+    from deepspeed_tpu.models.transformer import TransformerConfig
+    return TransformerConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        num_layers=layers or len(kinds), num_heads=4,
+        layer_kinds=tuple(kinds), first_k_dense=leading,
+        heads_by_kind=tuple((k, 4) for k in dict.fromkeys(kinds)),
+        **(dict(kv_lora_rank=8, qk_rope_head_dim=4, qk_nope_head_dim=4,
+                v_head_dim=8, q_lora_rank=8) if latent else {}))
+
+
+F, W = "full", "window"
+PATTERNS = {
+    # (kinds, leading, layers, latent) -> (leading, runs, periods, tail)
+    # a family of one kind is a pattern of period 1
+    "seed": (((), 0, 4, False), (0, [(F, 1)], 4, 0)),
+    "latent": (((), 1, 3, True), (1, [("latent", 1)], 2, 0)),
+    "latent-all-leading": (((), 5, 3, True), (3, [], 0, 0)),
+    # the cases ``models/laguna.py::layer_plan`` had: a leading dense
+    # layer, then (window x 3, full) once, with a tail, twice with a tail
+    "leading-one-period": (((F, W, W, W, F), 1, 0, False),
+                           (1, [(W, 3), (F, 1)], 1, 0)),
+    "leading-period-tail": (((F, W, W, W, F, W, W, W), 1, 0, False),
+                            (1, [(W, 3), (F, 1)], 1, 3)),
+    "leading-two-periods-tail": (((F,) + (W, W, W, F) * 2 + (W,), 1, 0,
+                                  False), (1, [(W, 3), (F, 1)], 2, 1)),
+    # the leading layers stand OUTSIDE the pattern: counted into it, the
+    # same list has another period
+    "no-leading": (((F, W, W, W, F), 0, 0, False),
+                   (0, [(F, 1), (W, 3)], 1, 1)),
+    "runs": ((("ssm",) * 2 + (F,) + ("ssm",) * 3, 0, 0, False),
+             (0, [("ssm", 2), (F, 1), ("ssm", 1)], 1, 2)),
+    "runs-and-tail": ((("delta", "delta", F) * 2 + ("delta",), 0, 0, False),
+                      (0, [("delta", 2), (F, 1)], 2, 1)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PATTERNS))
+def test_the_period_finder(name):
+    """``models/transformer.py::layer_runs``: the ONE search for the
+    shortest period of a layer pattern, the leading layers outside it."""
+    from deepspeed_tpu.models.transformer import layer_kinds, layer_runs
+    given, want = PATTERNS[name]
+    cfg = _pattern_cfg(*given)
+    assert layer_runs(cfg) == want
+    leading, runs, periods, tail = want
+    period = [kind for kind, n in runs for _ in range(n)]
+    kinds = layer_kinds(cfg)
+    assert len(kinds) == cfg.num_layers
+    assert list(kinds[leading:cfg.num_layers - tail]) == period * periods

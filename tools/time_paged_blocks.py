@@ -44,7 +44,7 @@ blocks alone, as the walk's yardstick.
 query heads over 8 KV heads): the table is the window group's, ``--buckets``
 slots wide, holding a row's pages from the first one its window still
 reaches (at most 10 of the 16 at a window of 512 tokens and pages of 64),
-and ``start_pos`` counts from that page, as ``model.py::_by_group`` rebases
+and ``start_pos`` counts from that page, as ``model.py::_forward_hidden`` rebases
 it.
 """
 import argparse
