@@ -1399,16 +1399,16 @@ class RaggedInferenceModel:
                     experts):
         """ONE layer of kind ``kind`` over (x, the pools in the engine's
         order, the held-experts counts where the model has them), the same
-        out: the kind's mixer (:data:`MIXERS`) at layer ``at`` of its own
-        pools, then the feed-forward (``routed``: where the layer stands
-        among the routed ones, counted from the number that ``experts``,
-        the held experts' stack, comes with), each behind a norm of its
-        input, under ``cfg.post_norm`` before one of its output, under
-        ``cfg.sandwich_norm`` between both."""
+        out: the kind's mixer (:data:`MIXERS`) at layer ``at`` of its pools,
+        then the feed-forward (``routed``: the layer's place among the routed
+        ones, from the number ``experts`` comes with; HERE is where a router
+        reads), each behind a norm of its input (``post_norm``: its output)."""
         cfg = ctx.cfg
         x, *rest = carry
         mixer = MIXERS[kind]
         h = x if cfg.post_norm else self._norm(lp["norm1"], x)
+        plan = (self._route(lp, h, ctx, layout=True) if "moe" in lp
+                and cfg.router_reads == "mixer" else None)
         out, written = mixer.run(
             self, h, [rest[i] for i in mixer.pools], lp[mixer.weights], at,
             kind=kind, ctx=ctx)
@@ -1420,7 +1420,7 @@ class RaggedInferenceModel:
             out = self._norm(lp["norm1"], out.astype(x.dtype))
         feed = functools.partial(
             self._feed_forward, lp, ctx=ctx, routed=routed, experts=experts,
-            counts=rest[-1] if self.step_tail else None)
+            counts=rest[-1] if self.step_tail else None, plan=plan)
         if cfg.parallel_residual:
             mlp_out, counts = feed(self._norm(lp["norm2"], x))
             x = x + out.astype(x.dtype) + mlp_out.astype(x.dtype)
@@ -1437,11 +1437,11 @@ class RaggedInferenceModel:
             rest[-1] = counts
         return (x, *rest)
 
-    def _feed_forward(self, lp, h, *, ctx: Pass, routed, experts, counts):
+    def _feed_forward(self, lp, h, *, ctx, routed, experts, counts, plan):
         """A layer's feed-forward: the dense block (or the self-wired
         ``mlp_fn``, its MoE aux dropped), or the routed layer's held share
-        (``moe/held.py``) plus the shared expert and the pass's three
-        counts brought up to date.  Returns (output, counts)."""
+        (``moe/held.py``; ``plan``: :meth:`_route`'s from before the mixer)
+        plus the shared expert, the pass's counts: (output, counts)."""
         cfg = ctx.cfg
         if "moe" not in lp:
             out = (self.mlp_fn or T._mlp_block)(cfg, lp["mlp"], h)
@@ -1449,12 +1449,12 @@ class RaggedInferenceModel:
         mp, (stack, first) = lp["moe"], experts
         S, Q, E = h.shape
         h2 = h.reshape(S * Q, E)
-        chosen, weights = held.ROUTERS[cfg.router_scoring](
-            h2, mp["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
-            cfg.norm_topk_prob)
+        # routed here, unless the router read the mixer's input (``plan``)
+        chosen, weights, rows = plan or self._route(lp, h2, ctx)
         out, pairs = held.held_experts_ffn(
-            h2, chosen, weights, stack, cfg.experts_first,
-            layer=routed - first if first else routed, valid=ctx.valid)
+            h2, chosen, weights, stack, cfg.experts_first, plan=rows,
+            layer=routed - first if first else routed, valid=ctx.valid,
+            act=cfg.expert_act)
         out = out.reshape(S, Q, E)
         if "shared" in mp:
             out = out + T._mlp_block(cfg, mp["shared"], h)
@@ -1735,6 +1735,25 @@ class RaggedInferenceModel:
             kv.page_size, page_slots, itemsize,
             1 if kv.quantized else jnp.dtype(kv.dtype).itemsize,
             kv.quantized, alibi)[1]
+
+    def _route(self, lp, h, ctx: Pass, layout: bool = False):
+        """The routing of ``h`` ([S, Q, E], or its tokens [T, E]) over all
+        experts of layer ``lp``: (experts [T, k], weights [T, k], rows).
+        ``layout``: ``rows`` is the held experts' row layout
+        (``moe/held.py::plan_rows``), made HERE so that nothing of the
+        routing waits for what runs before the feed-forward (a router that
+        reads the mixer's input: ``_layer_body``); else None, and
+        ``held_experts_ffn`` makes its own, as the programs that route
+        behind the mixer always had it."""
+        cfg = ctx.cfg
+        chosen, weights = held.ROUTERS[cfg.router_scoring](
+            h if h.ndim == 2 else h.reshape(-1, h.shape[-1]),
+            lp["moe"]["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
+            cfg.norm_topk_prob)
+        rows = held.plan_rows(chosen, ctx.valid, cfg.experts_first,
+                              cfg.held_experts, cfg.n_routed_experts) \
+            if layout else None
+        return chosen, weights, rows
 
 
 #: a layer kind is an entry here, one in ``cache_kinds.py::CACHE_KINDS``

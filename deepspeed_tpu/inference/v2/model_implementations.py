@@ -2,8 +2,8 @@
 
 Reference: ``inference/v2/model_implementations/`` — one directory per
 arch (llama_v2, mistral, mixtral, falcon, opt, phi, qwen, qwen_v2; here
-also bloom, gpt_neox, gpt2, gptj, pangu_ultra_moe, laguna, jamba and
-olmo_hybrid), each
+also bloom, gpt_neox, gpt2, gptj, pangu_ultra_moe, laguna, jamba,
+olmo_hybrid and smallthinker), each
 a ``DSTransformerModelBase`` subclass hard-coding that family's
 invariants (llama_v2/model.py:22, mistral/model.py, ...), chosen by
 ``engine_factory`` from the checkpoint's ``model_type``.
@@ -215,12 +215,53 @@ class GPTJInferenceModel(RaggedInferenceModel):
     MODEL_TYPES = ("gptj",)
 
 
+class SmallThinkerInferenceModel(RaggedInferenceModel):
+    """SmallThinker (``models/smallthinker.py``; no counterpart in the
+    reference): global layers without a positional encoding and window
+    layers under rope in one model over two page groups at ONE head
+    count, every layer routed from its ATTENTION block's input
+    (``cfg.router_reads``) over ReLU-gated experts (``cfg.expert_act``),
+    of which this process holds ``experts_held`` (all of them in the
+    family's serving cut), no shared expert, no dense layer."""
+    MODEL_TYPES = ("smallthinker",)
+
+    def __init__(self, cfg, params, **kw):
+        assert set(cfg.layer_kinds) == {"full", "window"} \
+            and len(cfg.layer_kinds) == cfg.num_layers, \
+            "smallthinker names a kind for every layer, and has both"
+        assert cfg.sliding_window and cfg.nope_kinds == ("full",)
+        assert cfg.norm == "rmsnorm" and cfg.pos_emb == "rope"
+        assert len({h for _, h in cfg.heads_by_kind}) == 1 \
+            and cfg.num_heads % cfg.kv_heads == 0 and not cfg.head_gate
+        assert cfg.router_reads == "mixer" and cfg.expert_act == "relu"
+        assert cfg.n_routed_experts >= cfg.moe_top_k >= 1
+        assert not cfg.first_k_dense and not cfg.n_shared_experts
+        held = cfg.held_experts
+        assert 0 <= cfg.experts_first \
+            and cfg.experts_first + held <= cfg.n_routed_experts, \
+            "the experts held here lie outside the router's outputs"
+        super().__init__(cfg, params, **kw)
+        experts = self.params.get("experts")
+        assert experts is None or experts["wg"].shape[:2] \
+            == (cfg.num_layers, held), \
+            "expert weights do not match the layers or experts_held"
+
+    def rope_table(self, cfg, kind, positions):
+        """A plain rope over all of a window head's dims; none for a
+        kind of ``cfg.nope_kinds`` (``_kv_mixer`` leaves q and k as
+        projected where a kind's entry is None)."""
+        if kind in cfg.nope_kinds:
+            return None
+        return RaggedInferenceModel.rope_table(self, cfg, kind, positions)
+
+
 _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LlamaV2InferenceModel, MistralInferenceModel, MixtralInferenceModel,
     FalconInferenceModel, OPTInferenceModel, PhiInferenceModel,
     Qwen2InferenceModel, BloomInferenceModel, PanguUltraMoEInferenceModel,
     LagunaInferenceModel, JambaInferenceModel, OlmoHybridInferenceModel,
     GPTNeoXInferenceModel, GPT2InferenceModel, GPTJInferenceModel,
+    SmallThinkerInferenceModel,
 )
 
 

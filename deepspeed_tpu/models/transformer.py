@@ -77,25 +77,22 @@ class TransformerConfig:
     # expert weights and forward needs a routed mlp_fn
     moe_num_experts: int = 0
     moe_top_k: int = 2
-    # latent attention (MLA; models/pangu_moe.py): kv_lora_rank > 0
-    # selects it.  The cache holds one [c ; k_r] plane of kv_lora_rank +
-    # qk_rope_head_dim values a token; scores are qk_nope_head_dim +
-    # qk_rope_head_dim wide, values v_head_dim
+    # latent attention (MLA; models/pangu_moe.py): kv_lora_rank > 0 selects
+    # it.  The cache holds one [c ; k_r] plane of kv_lora_rank + qk_rope_head_dim
+    # values a token; scores are qk_nope + qk_rope_head_dim wide, values v_head_dim
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # x + N2(Attn(N1 x)), x + N4(FFN(N3 x)): a norm after each sub-layer
-    # too, inside the residual branch
+    # x + N2(Attn(N1 x)), x + N4(FFN(N3 x)): a norm after each sub-layer too
     sandwich_norm: bool = False
     # a routed layer of another kind than moe_num_experts' (moe/held.py):
-    # sigmoid scores over n_routed_experts, the moe_top_k largest
-    # normalised and scaled, every expert a gated MLP of
-    # moe_intermediate_size beside n_shared_experts always-on ones.  This
-    # process holds experts [experts_first, experts_first + experts_held)
-    # (one chip of an expert-parallel group; 0 = all).  The first
-    # first_k_dense layers keep the dense MLP.
+    # scores over n_routed_experts, the moe_top_k largest normalised and
+    # scaled, every expert a gated MLP of moe_intermediate_size beside
+    # n_shared_experts always-on ones.  Held here (one chip of an expert-
+    # parallel group): experts_first .. + experts_held (0 = all); the
+    # first first_k_dense layers keep the dense MLP.
     n_routed_experts: int = 0
     experts_held: int = 0
     experts_first: int = 0
@@ -104,22 +101,25 @@ class TransformerConfig:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     first_k_dense: int = 0
-    # "sigmoid" (the scores themselves) or "softmax" over all experts,
-    # before the top-k, its normalisation and the scaling
+    # router_scoring: "sigmoid" | "softmax" over all experts, before the
+    # top-k.  router_reads: "ffn" (the feed-forward's normed input) |
+    # "mixer" (the mixer's).  expert_act: "silu" | "relu" (moe/held.py)
     router_scoring: str = "sigmoid"
+    router_reads: str = "ffn"
+    expert_act: str = "silu"
     # layers of two attention kinds in one model (models/laguna.py): one
     # entry a layer, "full" or "window" (sliding_window wide); () = every
-    # layer of one kind.  Each kind has its own count of query heads
-    # (heads_by_kind, num_heads = the full kind's), its own rope (the
-    # window kind: window_rope_theta over all dims; the full kind:
-    # rope_theta over rope_pct of them, under rope_yarn = (factor,
-    # original positions, beta_fast, beta_slow, attention_factor) if set)
-    # and its own page group in the cache (inference/v2/ragged).
+    # layer of one kind.  A kind has its own query heads (heads_by_kind),
+    # page group (inference/v2/ragged) and rope (window: window_rope_theta,
+    # all dims; full: rope_theta over rope_pct of them under rope_yarn =
+    # (factor, original positions, beta_fast, beta_slow, attention_factor));
+    # a kind of nope_kinds has no positional encoding (models/smallthinker.py)
     layer_kinds: Tuple[str, ...] = ()
     heads_by_kind: Tuple[Tuple[str, int], ...] = ()
     window_rope_theta: float = 0.0
     rope_yarn: Tuple[float, ...] = ()
-    # o = concat_n(sigmoid(h Wg)_n * a_n) Wo: one gate a query head
+    nope_kinds: Tuple[str, ...] = ()
+    # one gate a query head: o = concat_n(sigmoid(h Wg)_n * a_n) Wo
     head_gate: bool = False
     # state-space (Mamba-1) layers beside attention layers in one model
     # (models/jamba.py): ``layer_kinds`` names them "ssm".  ssm_state_dim

@@ -76,11 +76,14 @@ def _largest(scores: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     return jnp.stack(vals, -1), jnp.stack(idxs, -1)
 
 
-def row_tile(tokens: int) -> int:
+def row_tile(tokens: int, per_expert: float = 0.0) -> int:
     """Rows of one grouped-matmul tile: a decode step's experts see a
     handful of pairs each, a prefill piece's a few dozen.  One tile per
-    expert streams that expert's weights once."""
-    return 32 if tokens <= 256 else 64
+    expert streams that expert's weights once, and every further tile of
+    an expert streams them AGAIN: so 32 rows only while they are twice
+    ``per_expert``, the pairs an expert sees under an even routing (0:
+    the caller does not know how many experts were scored)."""
+    return 32 if tokens <= 256 and per_expert <= 16 else 64
 
 
 def _plan(experts, valid, first: int, held: int, tm: int):
@@ -128,12 +131,12 @@ def _rows_bound(pairs: int, held: int, tm: int) -> int:
     return -(-(pairs + held * (tm - 1)) // tm) * tm
 
 
-def _ffn_kernel(l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
+def _ffn_kernel(act, l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
                 o_ref, acc_ref):
     """One (row tile, slice of the expert width) grid step: the tile's
-    rows through ``tf`` columns of its expert's gate and up projections
-    and the matching rows of its down projection, summed over the
-    slices in float32."""
+    rows through ``tf`` columns of its expert's gate (under ``act``) and up
+    projections and the matching rows of its down projection, summed over
+    the slices in float32."""
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -148,7 +151,7 @@ def _ffn_kernel(l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
                                    preferred_element_type=jnp.float32)
         up = jax.lax.dot_general(x, wu_ref[:], dims,
                                  preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(gate) * up).astype(x.dtype)        # [tm, tf]
+        h = (act(gate) * up).astype(x.dtype)                # [tm, tf]
         acc_ref[:] += jnp.dot(h, wd_ref[:],
                               preferred_element_type=jnp.float32)
 
@@ -159,9 +162,9 @@ def _ffn_kernel(l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
 
 def grouped_expert_ffn(x_rows: jax.Array, tile_expert: jax.Array,
                        used: jax.Array, layer, wg: jax.Array, wu: jax.Array,
-                       wd: jax.Array, *, tm: int,
+                       wd: jax.Array, *, tm: int, act: str = "silu",
                        interpret: bool = False) -> jax.Array:
-    """The grouped SwiGLU over expert-sorted rows ``[M, e]``: row tile
+    """The grouped gated MLP over expert-sorted rows ``[M, e]``: row tile
     ``i`` belongs to expert ``tile_expert[i]`` of layer ``layer`` of the
     stacked weights ``[L, held, F, e]``; tiles from ``used`` on are
     skipped (their output rows are never read).  The layer is an index
@@ -187,7 +190,7 @@ def grouped_expert_ffn(x_rows: jax.Array, tile_expert: jax.Array,
 
     w_spec = pl.BlockSpec((None, None, tf, e), weights)
     return pl.pallas_call(
-        _ffn_kernel,
+        functools.partial(_ffn_kernel, ACTS[act]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(M // tm, nf),
             in_specs=[pl.BlockSpec((tm, e), rows), w_spec, w_spec, w_spec],
@@ -203,9 +206,9 @@ def grouped_expert_ffn(x_rows: jax.Array, tile_expert: jax.Array,
       wg, wu, wd)
 
 
-def _grouped_reference(x_rows, tile_expert, used, layer, wg, wu, wd, *, tm):
-    """The kernel's arithmetic in ``jnp`` (the CPU path): every row tile
-    against its expert's weights, float32 accumulation."""
+def _grouped_reference(x_rows, tile_expert, used, layer, wg, wu, wd, *, tm,
+                       act: str = "silu"):
+    """The kernel's arithmetic in ``jnp`` (the CPU path)."""
     wg, wu, wd = wg[layer], wu[layer], wd[layer]
     M, e = x_rows.shape
     xt = x_rows.reshape(M // tm, tm, e)
@@ -214,7 +217,7 @@ def _grouped_reference(x_rows, tile_expert, used, layer, wg, wu, wd, *, tm):
                       preferred_element_type=f32)
     up = jnp.einsum("nte,nfe->ntf", xt, wu[tile_expert],
                     preferred_element_type=f32)
-    h = (jax.nn.silu(gate) * up).astype(x_rows.dtype)
+    h = (ACTS[act](gate) * up).astype(x_rows.dtype)
     out = jnp.einsum("ntf,nfe->nte", h, wd[tile_expert],
                      preferred_element_type=f32)
     live = (jnp.arange(M // tm) < used[0])[:, None, None]
@@ -225,15 +228,15 @@ def held_experts_ffn(x2d: jax.Array, experts: jax.Array,
                      weights: jax.Array, params, first: int, *,
                      layer=None, valid: Optional[jax.Array] = None,
                      use_kernel: Optional[bool] = None,
-                     interpret: bool = False
+                     interpret: bool = False, plan=None, act: str = "silu"
                      ) -> Tuple[jax.Array, jax.Array]:
     """``sum_i w_i E_i(x)`` over the chosen experts that are held here.
 
-    x2d [T, e]; experts / weights [T, k] from :func:`route_sigmoid_topk`;
+    x2d [T, e]; experts / weights [T, k] from a router of :data:`ROUTERS`;
     params ``{"wg", "wu", "wd"}`` each ``[held, F, e]``, or the layers'
     stack ``[L, held, F, e]`` with ``layer`` the one to use (an int32
-    scalar: the layer loop's counter); ``valid`` [T] marks real tokens (a
-    padding row routes nowhere and is not counted).
+    scalar: the layer loop's counter); ``valid`` [T] marks real tokens;
+    ``plan``: :func:`plan_rows` made earlier; ``act``: the gate's (ACTS).
     Returns (partial result [T, e] in x2d's dtype, pairs per held expert
     [held] int32)."""
     T, e = x2d.shape
@@ -245,8 +248,9 @@ def held_experts_ffn(x2d: jax.Array, experts: jax.Array,
         valid = jnp.ones(T, bool)
     if use_kernel is None:
         use_kernel = interpret or on_tpu()
-    tm = row_tile(T)
-    row_token, dest, tile_expert, used, counts = _plan(
+    # a plan made ahead brings its tile: its rows over its tiles
+    tm = plan[0].shape[0] // plan[2].shape[0] if plan else row_tile(T)
+    row_token, dest, tile_expert, used, counts = plan or _plan(
         experts, valid, first, held, tm)
     ffn = (functools.partial(grouped_expert_ffn, interpret=interpret)
            if use_kernel else _grouped_reference)
@@ -255,7 +259,8 @@ def held_experts_ffn(x2d: jax.Array, experts: jax.Array,
     # quarter of the bound with a fallback to all of it was 0.10 ms of
     # 2.56 faster a layer call at 256 tokens: not worth a second kernel
     # in every step program; PERF.md, PR 27)
-    y_rows = ffn(x2d[row_token], tile_expert, used, layer, wg, wu, wd, tm=tm)
+    y_rows = ffn(x2d[row_token], tile_expert, used, layer, wg, wu, wd, tm=tm,
+                 act=act)
     rows = row_token.shape[0]
     picked = y_rows[jnp.minimum(dest, rows - 1)].astype(jnp.float32)
     out = jnp.sum(jnp.where((dest < rows)[..., None],
@@ -263,13 +268,14 @@ def held_experts_ffn(x2d: jax.Array, experts: jax.Array,
     return out.astype(x2d.dtype), counts
 
 
-def dense_held_reference(x2d, experts, weights, params, first: int):
+def dense_held_reference(x2d, experts, weights, params, first: int,
+                         act: str = "silu"):
     """Ground truth for tests: every held expert over every token, masked
     by the routing, float32."""
     f32 = jnp.float32
     x = x2d.astype(f32)
     wg, wu, wd = (params[n].astype(f32) for n in ("wg", "wu", "wd"))
-    h = jax.nn.silu(jnp.einsum("te,xfe->xtf", x, wg)) \
+    h = ACTS[act](jnp.einsum("te,xfe->xtf", x, wg)) \
         * jnp.einsum("te,xfe->xtf", x, wu)
     out = jnp.einsum("xtf,xfe->xte", h, wd)                 # [held, T, e]
     held = wg.shape[0]
@@ -298,3 +304,24 @@ def route_softmax_topk(x2d: jax.Array, w_router: jax.Array, top_k: int,
 
 #: the scoring functions a configuration names (``router_scoring``)
 ROUTERS = {"sigmoid": route_sigmoid_topk, "softmax": route_softmax_topk}
+
+
+#: the gate's activation a configuration names (``expert_act``): SwiGLU's
+#: and the ReLU of a ReGLU expert.  Static in the kernel: a name is one
+#: Mosaic text
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def plan_rows(experts: jax.Array, valid: Optional[jax.Array], first: int,
+              held: int, scored: int = 0):
+    """:func:`held_experts_ffn`'s row layout for ``experts`` [T, k], made
+    where the routing is known, which may be before the layer's mixer (a
+    router that reads the mixer's input): ``held_experts_ffn(...,
+    plan=...)`` then only gathers, multiplies and scatters.  ``scored``:
+    the experts the router chose among, which sizes the tile
+    (:func:`row_tile`) by the pairs an expert sees."""
+    T, k = experts.shape
+    if valid is None:
+        valid = jnp.ones(T, bool)
+    return _plan(experts, valid, first, held,
+                 row_tile(T, T * k / scored if scored else 0.0))

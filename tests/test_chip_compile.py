@@ -187,6 +187,10 @@ SERVING_BLOCKS = [
     # row's 128 query rows a head leave no room for every head: its blocks
     # are the rows', the widest group and the largest divisor that fits
     ("olmo-hybrid-7b-serve-4l", 30, (30, 2), (10, 8)),
+    # 4 KV heads, 7 query heads each, a page of 128 KB: every head of 8
+    # slots a decode step (1 MB), half the heads of 8 a prompt row's
+    # (both kinds of layer: one head count)
+    ("smallthinker-21b-serve-8l", 28, (4, 8), (2, 8)),
 ]
 
 
@@ -232,6 +236,13 @@ SERVING_WALKS = [
     ("laguna-window", "laguna-s-serve-5l-ep16", 72, 256, 16, 512, (8, 2)),
     ("jamba", "jamba2-3b-serve-28l", 20, 256, 40, None, (48, 16)),
     ("olmo", "olmo-hybrid-7b-serve-4l", 30, 256, 40, None, (2, 1)),
+    # a 128 KB page: 16 slots a tile, 4 a chunk; the window group's table
+    # is 72 slots at a window of 4,096 (65 live pages and the one being
+    # filled, in whole groups of 8), of which the walk visits the live ones
+    ("smallthinker-full", "smallthinker-21b-serve-8l", 28, 256, 40, None,
+     (16, 4)),
+    ("smallthinker-window", "smallthinker-21b-serve-8l", 28, 256, 72, 4096,
+     (16, 4)),
 ]
 
 
@@ -1296,3 +1307,130 @@ def test_train_step_keeps_what_its_policy_names(train_step_programs, policy):
 def _train_shard_bytes(cfg, bytes_a_parameter, shards=4):
     """Bytes of one device's share of the model's matmul parameters."""
     return cfg.n_params() // shards * bytes_a_parameter
+
+
+# -- the smallthinker family: the expert kernel's gate, its step programs ----
+
+#: the first 16 hex digits of the SHA-256 of ``moe_expert_ffn``'s Mosaic
+#: text at 256 tokens, by (family's shapes, the gate's activation): the
+#: SiLU texts are the parent's of PR 47 (the activation became a static
+#: argument of the kernel and changed nothing of it), and every cached
+#: step program of the latent and the laguna family still holds them
+EXPERT_KERNEL_TEXTS = {
+    # layers, experts held, expert width, hidden, experts a token
+    "pangu": ((4, 16, 2048, 7680, 8), {"silu": "cebe6ed906b36757",
+                                        "relu": "5814e84485a130ef"}),
+    "smallthinker": ((8, 64, 768, 2560, 6), {"silu": "f2eb75d02b7f2ce8",
+                                             "relu": "537dda43313e3771"}),
+}
+
+
+@pytest.mark.parametrize("name,act", [
+    (name, act) for name, (_, digests) in EXPERT_KERNEL_TEXTS.items()
+    for act in digests])
+def test_the_gate_is_static_in_the_expert_kernels_text(chip, name, act):
+    import hashlib
+
+    from deepspeed_tpu.moe.held import held_experts_ffn
+    (L, E, F, e, k), digests = EXPERT_KERNEL_TEXTS[name]
+    stack = {n: chip((L, E, F, e), jnp.bfloat16) for n in ("wg", "wu", "wd")}
+    lowered = jax.jit(lambda x, ex, w, p, l: held_experts_ffn(
+        x, ex, w, p, 0, layer=l, use_kernel=True, act=act)).lower(
+        chip((256, e), jnp.bfloat16), chip((256, k), jnp.int32),
+        chip((256, k), jnp.float32), stack, chip((), jnp.int32)).as_text()
+    text, = mosaic_texts(lowered)
+    assert ("maximumf" in text) == (act == "relu")
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digests[act]
+
+
+#: the cell's chained, mixed and drain programs, and both page buckets of
+#: its lattice: one program a kind (a compile is 10-30 s of a suite near
+#: its limit: the ramp's mixed step stands for the bucket of 8; the other
+#: three programs of kind x bucket hold the same calls at the other width)
+SMALLTHINKER_STEP_KEYS = {
+    "chain-p40": (256, 1, 40, False, "chain", 256, True),
+    "mixed-p8": (256, 1, 8, False, "mixed", 4, 128, 8, True, True),
+    "drain-p40": (16, 1, 40, False, "chain", 32, True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(SMALLTHINKER_STEP_KEYS))
+def test_smallthinker_step_program_moves_no_pool_and_no_expert_stack(
+        chip, monkeypatch, kind):
+    """The benchmark's cell at published widths (all eight layers: two
+    periods, all 64 experts a layer): the step programs lower for the chip
+    with a GQA group of 7 over 4 KV heads in both page groups, the window
+    group's calls under a name of their own at a 72-slot table, the ReLU
+    expert kernel over 64 experts x 12 width slices, and neither group's
+    pool nor the experts' stack (eight layers of 755 MB) is copied, sliced
+    out or re-laid out."""
+    import json
+    import os
+
+    from flax.core import meta
+
+    from benchmark.builders.serve_smallthinker import source_of
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        SmallThinkerInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.smallthinker import SmallThinkerForCausalLM
+    from deepspeed_tpu.moe.held import _rows_bound, row_tile
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "smallthinker-21b-serve-8l.json")) as f:
+        config = json.load(f)
+    model = SmallThinkerForCausalLM(source_of(config, False))
+    assert model.cfg.layer_kinds == ("full", "window", "window",
+                                     "window") * 2
+    params = jax.eval_shape(lambda k: meta.unbox(model.init_params(k)),
+                            jax.random.key(0))
+    assert params["experts"]["wg"].shape == (8, 64, 768, 2560)
+    # 1,536 pairs of a 256-row step, 24 an expert: tiles of 64 rows (an
+    # expert's second tile streams its weights again), a bound of 5,568
+    # rows however the pairs fall; 3,520 under the tile of a router that
+    # scores 256 experts
+    assert row_tile(256, 256 * 6 / 64) == 64 and row_tile(256) == 32
+    assert _rows_bound(256 * 6, 64, 64) == 5568
+    assert _rows_bound(256 * 6, 64, 32) == 3520
+    pages = {"full": 1024, "window": 512}
+    serve = SmallThinkerInferenceModel(
+        model.cfg, params,
+        kv_config=KVCacheConfig(num_layers=2, kv_heads=4, head_dim=128,
+                                page_size=PAGE, num_pages=pages["full"]),
+        window_kv_config=KVCacheConfig(
+            num_layers=6, kv_heads=4, head_dim=128, page_size=PAGE,
+            num_pages=pages["window"]))
+    pool = (chip((2, pages["full"] + 1, 2, 4, PAGE, 128), jnp.bfloat16),
+            chip((6, pages["window"] + 1, 2, 4, PAGE, 128), jnp.bfloat16))
+    key = StepKey.parse(SMALLTHINKER_STEP_KEYS[kind])
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        step_avals(serve, key, pool))
+    # the window group's table rides the page table: 72 slots and a base
+    assert (key.S, key.P + 72 + 1) in [a.shape for a in avals[2:]]
+    asked = asked_of_fetch_table(monkeypatch)
+    compiled = jax.jit(step_program(serve, key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    text = compiled.as_text()
+    if key.kind == "chain":
+        # decode rows alone: both page groups' calls walk each row's own
+        # pages, and the program holds nothing of the fetch table
+        assert asked == [] and fetch_table_selects(text) == 0
+        assert walk_calls(text) == len(
+            kernel_calls(text, "paged_attention"))
+    for kernel in ("paged_attention_decode", "paged_attention_window_decode",
+                   "kv_write_decode", "moe_expert_ffn"):
+        assert any('custom_call_target="tpu_custom_call"' in line
+                   and kernel in line for line in text.splitlines()), kernel
+    assert set(scoped_vmem_asked(text, "moe_expert_ffn")) == {""}
+    # the smallest thing that must not move: a layer of the window pool
+    # (67 MB here; a layer of the experts' stack is 755 MB)
+    layer_bytes = (pages["window"] + 1) * 2 * 4 * PAGE * 128 * 2
+    expert_layer = 64 * 3 * 768 * 2560 * 2
+    assert layer_bytes < expert_layer
+    assert pool_sized_movers(text, layer_bytes) == []
+    assert stack_shaped_movers(text, params) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < expert_layer

@@ -771,3 +771,107 @@ def test_a_model_with_delta_rule_layers_carries_the_delta_kinds_names():
     for name in ("delta_state_update_decode", "delta_chunk_prefill"):
         assert re.search("^delta_", name)
         assert not any(re.search(p, name) for p in patterns), name
+
+
+def test_a_model_that_holds_every_expert_carries_the_names_that_were_here():
+    """What the step of the SmallThinker family (PR 47: two page groups at
+    one head count, every expert of every layer held, the router ahead of
+    attention) carries on ``fastgen.step``: exactly what a model of two
+    page groups with held experts carried before it, and nothing new; the
+    nine metric names it adds read those attributes (every expert held:
+    a step's pairs are all here and ``moe_held_pair_share.whole`` reads
+    100%); its kernels run under the names that ``^moe_expert_ffn`` and
+    ``^paged_attention_window`` find, and no pattern that was here matches
+    anything new."""
+    import glob
+    import json
+    import os
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    from deepspeed_tpu.inference.v2.modules import _kernel_name
+    from deepspeed_tpu.moe import held
+    from test_smallthinker import engine_of, family, sequences_of
+    cfg, params = family(num_hidden_layers=4)
+    engine = engine_of(cfg, params)
+    sched = FastGenScheduler(engine)
+    telemetry.enable()
+    for uid, p in enumerate(sequences_of((21, 30), seed=2)):
+        sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=12))
+    sched.run_to_completion()
+    recs = [r for r in get_tracer().records()
+            if not r[0].startswith("engine.program")]
+    steps = [r[5] for r in recs if r[0] == "fastgen.step" and r[5]]
+    carried = {key for attrs in steps for key in attrs}
+    assert carried == {
+        "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
+        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "program", "moe_pairs_here", "moe_expert_load_max",
+        "moe_experts_touched", "moe_tokens", "kv_slots_held",
+        "kv_slots_live", "kv_slots_bucket", "kv_pages_reserved_window",
+        "kv_tokens_held_window", "kv_pages_released_window",
+        "attn_tokens_full", "attn_tokens_window"}
+    counted = [a for a in steps if a.get("moe_tokens")]
+    # every expert is held: 3 pairs a token in each of the 4 layers; under
+    # the window a window layer holds what a global layer holds
+    assert counted and all(a["moe_pairs_here"] == a["moe_tokens"] * 3 * 4
+                           for a in counted)
+    assert all(0 < a["moe_experts_touched"] <= 8 * 4 for a in counted)
+    assert all(a["kv_tokens_held_window"] == a["kv_tokens_held"]
+               and a["attn_tokens_window"] == a["attn_tokens_full"]
+               for a in steps)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    cell = "serve.reason-moe-closed256"
+    mine = [m["name"] for m in spec["per_layer"]
+            if m.get("workloads") == [cell]]
+    assert mine == [
+        "moe_experts_touched_share", "gqa7_attention_roofline",
+        "window_attn_time_share.w4096", "kv_window_held_share.w4096",
+        "kv_window_pages_peak_share.w4096", "moe_held_pair_share.whole",
+        "moe_expert_load_imbalance.whole", "moe_expert_time_share.whole",
+        "moe_expert_roofline.whole"]
+    read, new_patterns, old_patterns = set(), [], []
+    for path in glob.glob(os.path.join(root, "benchmark", "metrics",
+                                       "*.json")):
+        with open(path) as f:
+            args = json.load(f).get("args", {})
+        name = os.path.basename(path)[:-len(".json")]
+        if name in mine:
+            read |= {v[5:] for v in (args.get("value", ""),
+                                     args.get("of_value", ""))
+                     if v.startswith("attr:")}
+            new_patterns += args.get("patterns", [])
+        else:
+            old_patterns += args.get("patterns", [])
+    with open(os.path.join(root, "benchmark", "readers",
+                           "smallthinker_roofline.py")) as f:
+        text = f.read()
+    read |= {key for key in carried if f'"{key}"' in text}
+    assert read == {
+        "moe_experts_touched", "moe_pairs_here", "moe_tokens",
+        "moe_expert_load_max", "kv_tokens_held_window", "kv_tokens_held",
+        "kv_pages_reserved_window", "attn_tokens_full",
+        "attn_tokens_window"}
+    # no new kernel, no new name: the patterns the cell's metrics read
+    # were all here, and find this family's kernels
+    assert set(new_patterns) == {"^moe_expert_ffn", "^paged_attention",
+                                 "^paged_attention_window"} \
+        <= set(old_patterns)
+    kinds = engine.model._kind_cfg
+    assert _kernel_name(kinds["window"]) == "paged_attention_window"
+    assert _kernel_name(kinds["full"]) == "paged_attention"
+    assert kinds["full"].num_heads == kinds["window"].num_heads == 14
+    x = jnp.zeros((8, 128), jnp.float32)
+    stack = {n: jnp.zeros((4, 32, 128), jnp.float32)
+             for n in ("wg", "wu", "wd")}
+    chosen = jnp.zeros((8, 2), jnp.int32)
+    jaxpr = str(jax.make_jaxpr(lambda: held.held_experts_ffn(
+        x, chosen, jnp.ones((8, 2)), stack, 0, interpret=True,
+        act="relu"))())
+    assert re.search(r"name=moe_expert_ffn\b", jaxpr) or \
+        "moe_expert_ffn" in jaxpr
