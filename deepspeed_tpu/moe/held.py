@@ -52,12 +52,12 @@ def route_sigmoid_topk(x2d: jax.Array, w_router: jax.Array, top_k: int,
     return experts.astype(jnp.int32), top * scaling
 
 
-#: columns of the expert width one grid step takes.  With 32 or 64 rows a
-#: tile the kernel's blocks (rows in and out, the float32 sum, three
-#: double-buffered weight slices) stay under 12 MB: inside the chip's
-#: default scoped VMEM.  A kernel that asked for more (slices of 256, a
-#: 96 MB limit) ran alone and HUNG the chip inside a mixed step program,
-#: whose other operations hold VMEM of their own (PERF.md, PR 27).
+#: columns of the expert width one work item of the kernel takes.  With 32
+#: or 64 rows a tile the kernel's scratch (rows in and out, the float32
+#: sum, the ring of weight slices: ``RING_BYTES``) stays under 12 MB: inside
+#: the chip's default scoped VMEM.  A kernel that asked for more (slices of
+#: 256, a 96 MB limit) ran alone and HUNG the chip inside a mixed step
+#: program, whose other operations hold VMEM of their own (PERF.md, PR 27).
 FF_SLICE = 64
 
 
@@ -131,75 +131,158 @@ def _rows_bound(pairs: int, held: int, tm: int) -> int:
     return -(-(pairs + held * (tm - 1)) // tm) * tm
 
 
+#: bytes of VMEM the ring of weight slices may take.  Beside a 64-row
+#: tile's blocks at the widest hidden size served (7,680: two tiles of rows
+#: in, one out, the float32 sum, 4.9 MB) it keeps the kernel under the
+#: default scoped VMEM: a kernel that asked for more HUNG the chip inside a
+#: mixed step program (PERF.md, PR 27)
+RING_BYTES = 6 * 2 ** 20
+
+#: sets of slices past which a longer ring buys nothing (v5e, PERF.md PR 48:
+#: two sets read 0.6% over three at 328 KB a slice and the same at 393 and
+#: 983 KB; four and six read what three do)
+RING_SETS = 3
+
+
+def width_slice(F: int) -> int:
+    """Columns of an expert width ``F`` one work item takes."""
+    return next(t for t in (FF_SLICE, 128, F) if F % t == 0)
+
+
+def ring_sets(tf: int, e: int, itemsize: int) -> int:
+    """Sets (gate, up, down) of ``[tf, e]`` weight slices in the kernel's
+    ring, from their bytes: as many as :data:`RING_BYTES` hold, two at
+    least (one under the matmuls, one on its way), :data:`RING_SETS` at
+    most.  2 at Pangu's 983 KB a slice, 3 at 393 and 328 KB."""
+    return max(2, min(RING_BYTES // (3 * tf * e * itemsize), RING_SETS))
+
+
 def _ffn_kernel(act, l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                o_ref, acc_ref):
-    """One (row tile, slice of the expert width) grid step: the tile's
+                o_ref, rows_in, rows_out, ring, acc, sem_in, sem_out, sem_w,
+                *, tm, tf, nf, sets):
+    """The walk over the row tiles IN USE, ``used_ref[0]`` of them: every
+    operand stays in HBM and the kernel copies what it multiplies.  A work
+    item is (row tile ``i``, slice ``j`` of the expert width): the tile's
     rows through ``tf`` columns of its expert's gate (under ``act``) and up
-    projections and the matching rows of its down projection, summed over
-    the slices in float32."""
-    i, j = pl.program_id(0), pl.program_id(1)
+    projections and the matching rows of its down projection, a tile's
+    slices summed in float32 in the order ``j = 0 .. nf - 1``.  The three
+    slices of an item are one set of the ring; the set an item leaves is
+    filled with the item ``sets`` places on in the walk BEFORE the next
+    item's copies are waited for, across tile boundaries, so the memory
+    always has a set queued behind the one on its way.  The next tile's
+    rows are copied under this tile's slices and a tile's result is written
+    back under the next tile's.  No step, copy, zeroing or write-back
+    exists for a tile past ``used``: its output rows are left as they were
+    (``ops/paged_attention.py::_walk_kernel`` and ``ops/mla_attention.py::
+    _decode_kernel`` are the same walk over pages)."""
+    layer, used = l_ref[0], used_ref[0]
+    items = used * nf
 
-    @pl.when(j == 0)
-    def _zero():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def weights(s, wait):
+        """Start (or wait for) the three copies of work item ``s``.  A
+        wait only needs a copy of the same size."""
+        i, j = jax.lax.div(s, nf), jax.lax.rem(s, nf)
+        expert = 0 if wait else te_ref[i]
+        at = pl.ds(0 if wait else pl.multiple_of(j * tf, tf), tf)
+        slot = jax.lax.rem(s, sets)
+        for k, w_ref in enumerate((wg_ref, wu_ref, wd_ref)):
+            copy = pltpu.make_async_copy(w_ref.at[layer, expert, at],
+                                         ring.at[slot, k], sem_w.at[slot])
+            copy.wait() if wait else copy.start()
 
-    @pl.when(i < used_ref[0])
-    def _tile():
-        x = x_ref[:]                                        # [tm, e]
-        dims = (((1,), (1,)), ((), ()))                     # x @ w.T
-        gate = jax.lax.dot_general(x, wg_ref[:], dims,
-                                   preferred_element_type=jnp.float32)
-        up = jax.lax.dot_general(x, wu_ref[:], dims,
-                                 preferred_element_type=jnp.float32)
-        h = (act(gate) * up).astype(x.dtype)                # [tm, tf]
-        acc_ref[:] += jnp.dot(h, wd_ref[:],
-                              preferred_element_type=jnp.float32)
+    def rows(i, wait):
+        at = pl.ds(0 if wait else pl.multiple_of(i * tm, tm), tm)
+        slot = jax.lax.rem(i, 2)
+        copy = pltpu.make_async_copy(x_ref.at[at], rows_in.at[slot],
+                                     sem_in.at[slot])
+        copy.wait() if wait else copy.start()
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _out():
-        o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+    def result(i):
+        return pltpu.make_async_copy(
+            rows_out, o_ref.at[pl.ds(pl.multiple_of(i * tm, tm), tm)],
+            sem_out.at[0])
+
+    @pl.when(used > 0)
+    def _first():
+        rows(0, wait=False)
+
+    for s in range(sets):
+        pl.when(s < items)(lambda: weights(s, wait=False))
+
+    def tile(i, carry):
+        pl.when(i + 1 < used)(lambda: rows(i + 1, wait=False))
+        rows(i, wait=True)
+        tile_rows = rows_in.at[jax.lax.rem(i, 2)]
+        acc[...] = jnp.zeros_like(acc)
+
+        def piece(j, carry):
+            s = i * nf + j
+            slot = jax.lax.rem(s, sets)
+            weights(s, wait=True)
+            x = tile_rows[...]                                # [tm, e]
+            dims = (((1,), (1,)), ((), ()))                 # x @ w.T
+            gate = jax.lax.dot_general(x, ring[slot, 0], dims,
+                                       preferred_element_type=jnp.float32)
+            up = jax.lax.dot_general(x, ring[slot, 1], dims,
+                                     preferred_element_type=jnp.float32)
+            h = (act(gate) * up).astype(x.dtype)            # [tm, tf]
+            acc[...] += jnp.dot(h, ring[slot, 2],
+                                preferred_element_type=jnp.float32)
+            pl.when(s + sets < items)(
+                lambda: weights(s + sets, wait=False))
+            return carry
+
+        jax.lax.fori_loop(0, nf, piece, 0)
+        pl.when(i > 0)(lambda: result(i - 1).wait())
+        rows_out[...] = acc[...].astype(rows_out.dtype)
+        result(i).start()
+        return carry
+
+    jax.lax.fori_loop(0, used, tile, 0)
+    pl.when(used > 0)(lambda: result(used - 1).wait())
 
 
+@functools.partial(jax.jit, static_argnames=("tm", "act", "interpret"))
 def grouped_expert_ffn(x_rows: jax.Array, tile_expert: jax.Array,
                        used: jax.Array, layer, wg: jax.Array, wu: jax.Array,
                        wd: jax.Array, *, tm: int, act: str = "silu",
                        interpret: bool = False) -> jax.Array:
     """The grouped gated MLP over expert-sorted rows ``[M, e]``: row tile
     ``i`` belongs to expert ``tile_expert[i]`` of layer ``layer`` of the
-    stacked weights ``[L, held, F, e]``; tiles from ``used`` on are
-    skipped (their output rows are never read).  The layer is an index
-    of the block maps, as in the cache kernels: a layer's weights sliced
-    out of the stack for a custom call would be copied, 1.5 GB a layer
-    at the published widths."""
+    stacked weights ``[L, held, F, e]``.  The kernel walks the ``used``
+    tiles in use and nothing else (:func:`_ffn_kernel`): the rows of a tile
+    from ``used`` on are NOT WRITTEN and hold whatever the buffer held, a
+    NaN as soon as anything; a caller reads them only to drop them.  The
+    layer is an index of the copies, as in the cache kernels: a layer's
+    weights sliced out of the stack for a custom call would be copied,
+    1.5 GB a layer at the published widths.  The ring of weight slices is
+    sized by bytes from the shapes (:func:`ring_sets`).  Jitted, as the
+    attention walks are: the body is traced once a shape and lowered once a
+    step program, not once a layer of a period (un-jitted, a program of
+    four routed layers a period took 0.3-0.7 s longer to form than the
+    grid form's; PERF.md, PR 48)."""
     M, e = x_rows.shape
     F = wg.shape[2]
-    tf = next(t for t in (FF_SLICE, 128, F) if F % t == 0)
-    nf = F // tf
-
-    def frozen(i, j, used):
-        # a skipped tile keeps the block of the step before it
-        live = i < used[0]
-        return jnp.where(live, i, jnp.maximum(used[0] - 1, 0)), \
-            jnp.where(live, j, nf - 1)
-
-    def rows(i, j, l, te, used):
-        return frozen(i, j, used)[0], 0
-
-    def weights(i, j, l, te, used):
-        return l[0], te[i], frozen(i, j, used)[1], 0
-
-    w_spec = pl.BlockSpec((None, None, tf, e), weights)
+    tf = width_slice(F)
+    sets = ring_sets(tf, e, x_rows.dtype.itemsize)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_ffn_kernel, ACTS[act]),
+        functools.partial(_ffn_kernel, ACTS[act], tm=tm, tf=tf, nf=F // tf,
+                          sets=sets),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(M // tm, nf),
-            in_specs=[pl.BlockSpec((tm, e), rows), w_spec, w_spec, w_spec],
-            out_specs=pl.BlockSpec((tm, e),
-                                   lambda i, j, l, te, used: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((tm, e), jnp.float32)]),
+            num_scalar_prefetch=3, grid=(1,),
+            in_specs=[hbm, hbm, hbm, hbm], out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((2, tm, e), x_rows.dtype),
+                pltpu.VMEM((tm, e), x_rows.dtype),
+                pltpu.VMEM((sets, 3, tf, e), x_rows.dtype),
+                pltpu.VMEM((tm, e), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SemaphoreType.DMA((sets,))]),
         out_shape=jax.ShapeDtypeStruct((M, e), x_rows.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name="moe_expert_ffn",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, used, x_rows,
@@ -255,10 +338,11 @@ def held_experts_ffn(x2d: jax.Array, experts: jax.Array,
     ffn = (functools.partial(grouped_expert_ffn, interpret=interpret)
            if use_kernel else _grouped_reference)
     # the bound that always holds is 16 times what even routing sends
-    # here; its unused tiles cost their grid steps and no weights (a
-    # quarter of the bound with a fallback to all of it was 0.10 ms of
-    # 2.56 faster a layer call at 256 tokens: not worth a second kernel
-    # in every step program; PERF.md, PR 27)
+    # here: the kernel walks the tiles in use and leaves the rows of every
+    # other tile UNWRITTEN (the ``jnp`` form zeroes them).  They are only
+    # ever read as the clamped row of a pair that is not here, which the
+    # ``where`` below drops: a NaN there must not pass, and a product with
+    # it would (tests/test_held_walk.py poisons them)
     y_rows = ffn(x2d[row_token], tile_expert, used, layer, wg, wu, wd, tm=tm,
                  act=act)
     rows = row_token.shape[0]

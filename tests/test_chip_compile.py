@@ -704,6 +704,46 @@ def test_moe_expert_ffn_kernel(chip, tokens):
         kernel="moe_expert_ffn")
 
 
+#: sets of weight slices in the expert kernel's ring, by served family (the
+#: families' shapes: ``tools/time_expert_tiles.py::FAMILIES``)
+EXPERT_RING_SETS = {"pangu": 2, "laguna": 3, "smallthinker": 3}
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_RING_SETS))
+def test_the_expert_kernels_walk_fits_the_default_vmem(chip, name):
+    """The three served families' call at 384 tokens (a mixed step's, tiles
+    of 64 rows): the walk compiles for the chip without asking for more
+    scoped VMEM than the default, its ring is the bytes' (``ring_sets``) and
+    its scratch stays under 12 MB: a kernel that asked for more hung the
+    chip inside a mixed step program (PERF.md, PR 27)."""
+    import os
+    import sys
+
+    from deepspeed_tpu.moe import held
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    from time_expert_tiles import FAMILIES
+    E, scored, k, F, e, act, told = FAMILIES[name]
+    sets = EXPERT_RING_SETS[name]
+    assert held.ring_sets(held.FF_SLICE, e, 2) == sets
+    # two tiles of rows in, one out, the float32 sum, the ring
+    assert (3 * 2 + 4) * 64 * e + sets * 3 * held.FF_SLICE * e * 2 \
+        < 12 * 10 ** 6
+    stack = {n: chip((2, E, F, e), jnp.bfloat16) for n in ("wg", "wu", "wd")}
+
+    def layer(x, ex, w, p, l):
+        plan = held.plan_rows(ex, None, 0, E, scored if told else 0)
+        assert plan[0].shape[0] // plan[2].shape[0] == 64
+        return held.held_experts_ffn(x, ex, w, p, 0, layer=l, plan=plan,
+                                     use_kernel=True, act=act)
+
+    text = compile_for_chip(
+        layer, chip((384, e), jnp.bfloat16), chip((384, k), jnp.int32),
+        chip((384, k), jnp.float32), stack, chip((), jnp.int32),
+        kernel="moe_expert_ffn")
+    assert set(scoped_vmem_asked(text, "moe_expert_ffn")) == {""}
+
+
 PANGU_STEP_KEYS = {
     "chain": (256, 1, 32, False, "chain", 256, True),
     "mixed": (256, 1, 64, False, "mixed", 4, 128, 8, True, True),
@@ -1312,16 +1352,17 @@ def _train_shard_bytes(cfg, bytes_a_parameter, shards=4):
 # -- the smallthinker family: the expert kernel's gate, its step programs ----
 
 #: the first 16 hex digits of the SHA-256 of ``moe_expert_ffn``'s Mosaic
-#: text at 256 tokens, by (family's shapes, the gate's activation): the
-#: SiLU texts are the parent's of PR 47 (the activation became a static
-#: argument of the kernel and changed nothing of it), and every cached
-#: step program of the latent and the laguna family still holds them
+#: text at 256 tokens, by (family's shapes, the gate's activation), read on
+#: PR 48's tree (the walk over the tiles in use; PR 47's grid form read
+#: cebe6ed9, 5814e844, f2eb75d0, 537dda43): a change here is a change of
+#: the kernel, and every cached step program of the three families that
+#: hold experts forms anew
 EXPERT_KERNEL_TEXTS = {
     # layers, experts held, expert width, hidden, experts a token
-    "pangu": ((4, 16, 2048, 7680, 8), {"silu": "cebe6ed906b36757",
-                                        "relu": "5814e84485a130ef"}),
-    "smallthinker": ((8, 64, 768, 2560, 6), {"silu": "f2eb75d02b7f2ce8",
-                                             "relu": "537dda43313e3771"}),
+    "pangu": ((4, 16, 2048, 7680, 8), {"silu": "a5beb69507223dbe",
+                                        "relu": "57081945c75492fe"}),
+    "smallthinker": ((8, 64, 768, 2560, 6), {"silu": "324490e0d18cadc8",
+                                             "relu": "f589a1d7259b47ba"}),
 }
 
 

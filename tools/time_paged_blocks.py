@@ -48,10 +48,10 @@ and ``start_pos`` counts from that page, as ``model.py::_forward_hidden`` rebase
 it.
 """
 import argparse
+import functools
 import json
 import os
 import sys
-import threading
 import time
 from unittest import mock
 
@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import chip_timing
 from deepspeed_tpu.ops import paged_attention as pa
 
 
@@ -159,28 +160,9 @@ def main():
           f"{pool.nbytes / 1e9:.2f} GB, a page {pool[0, 0].nbytes} B",
           flush=True)
 
-    # a call that hangs must not hold the chip (PERF.md, PR 27)
-    beat = [time.monotonic()]
-
-    def watchdog():
-        while True:
-            time.sleep(5)
-            if time.monotonic() - beat[0] > 100:
-                print("watchdog: 100 s in one call", flush=True)
-                os._exit(3)
-    threading.Thread(target=watchdog, daemon=True).start()
-
-    def ms_a_call(run, *operands):
-        out = run(*operands)                    # warm
-        out.block_until_ready()
-        beat[0] = time.monotonic()
-        t0 = time.perf_counter()
-        for _ in range(args.calls):
-            out = run(*operands)
-        out.block_until_ready()
-        ms = (time.perf_counter() - t0) * 1e3 / args.calls
-        beat[0] = time.monotonic()
-        return ms, out
+    beat = chip_timing.start_watchdog()
+    ms_a_call = functools.partial(chip_timing.ms_a_call, calls=args.calls,
+                                  beat=beat)
 
     def apart(out, ref):
         return float(jnp.max(jnp.abs(out.astype(jnp.float32)
