@@ -403,18 +403,68 @@ def rope_table(cfg: TransformerConfig, positions: jax.Array) -> Tuple[jax.Array,
     return jnp.sin(angles), jnp.cos(angles)
 
 
+def _rotate_pairs(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
+    """``x [B,S,H,rot]`` rotated pair by pair, ``(x[2i], x[2i+1])`` by the
+    angle of ``sin[..., i]`` / ``cos[..., i]`` (``[B,S,rot/2]``), in fp32:
+    ONE elementwise pass over ``x`` in the layout it arrives in,
+
+        out = x * cos2 + swap(x) * sin2
+
+    with ``cos2 = (c0, c0, c1, c1, ...)``, ``sin2 = (-s0, +s0, -s1, +s1,
+    ...)`` and ``swap(x)[2i] = x[2i+1]``, ``swap(x)[2i+1] = x[2i]``.  The
+    swap is a product with a constant 0/1 permutation matrix accumulated in
+    fp32, which is exact (each output is one input times 1.0, plus zeros):
+    one pass of the matrix unit for a bfloat16 ``x``, the highest precision
+    for anything wider.  Term for term it is ``x1*cos - x2*sin``,
+    ``x2*cos + x1*sin`` of the strided-pair form (``x[..., 0::2]``,
+    ``x[..., 1::2]``, ``stack``), which the chip's compiler answers with a
+    pair-major float32 layout and six passes over HBM (docs/DESIGN.md,
+    "Why the rope's pair swap is a product")."""
+    rot = x.shape[-1]
+    cos2 = jnp.repeat(cos, 2, axis=-1)[:, :, None, :]
+    sin2 = (jnp.repeat(sin, 2, axis=-1)
+            * jnp.tile(jnp.asarray([-1.0, 1.0], jnp.float32), rot // 2)
+            )[:, :, None, :]
+    lanes = np.arange(rot)
+    swap = jnp.asarray(lanes[:, None] == (lanes ^ 1)[None, :])
+    wide = x.dtype != jnp.bfloat16
+    operand = x.astype(jnp.float32) if wide else x
+    swapped = jnp.einsum(
+        "bshd,de->bshe", operand, swap.astype(operand.dtype),
+        precision=jax.lax.Precision.HIGHEST if wide else None,
+        preferred_element_type=jnp.float32)
+    out = x.astype(jnp.float32) * cos2 + swapped * sin2
+    return out.astype(x.dtype)
+
+
+def _rope_rotation_fwd(x, sin, cos):
+    return _rotate_pairs(x, sin, cos), (sin, cos)
+
+
+def _rope_rotation_bwd(tables, g):
+    # the rotation by the opposite angle, written the same way: the
+    # cotangent in ITS dtype goes through the permutation.  (Autodiff of
+    # _rotate_pairs would transpose the product into float32 x bfloat16 at
+    # one pass and round ``g * sin2`` to bfloat16 before the add.)  The
+    # tables come from integer positions and take no cotangent
+    sin, cos = tables
+    return _rotate_pairs(g, -sin, cos), jnp.zeros_like(sin), \
+        jnp.zeros_like(cos)
+
+
+# the rules call the plain function: forward mode over the backward
+# (runtime/eigenvalue.py) cannot pass through a custom_vjp
+_rope_rotation = jax.custom_vjp(_rotate_pairs)
+_rope_rotation.defvjp(_rope_rotation_fwd, _rope_rotation_bwd)
+
+
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
     """x: [B,S,H,D]; interleaved-pair rotation in fp32.  When the rope
     table covers fewer than D/2 frequencies (partial rotary,
     ``rope_pct < 1``), only the leading ``2*n_freq`` dims rotate and the
     tail passes through (GPT-NeoX ``rotary_pct`` semantics)."""
     rot = 2 * sin.shape[-1]
-    head = x[..., :rot].astype(jnp.float32)
-    x1, x2 = head[..., 0::2], head[..., 1::2]
-    sin, cos = sin[:, :, None, :], cos[:, :, None, :]
-    r1 = x1 * cos - x2 * sin
-    r2 = x2 * cos + x1 * sin
-    out = jnp.stack([r1, r2], axis=-1).reshape(head.shape).astype(x.dtype)
+    out = _rope_rotation(x[..., :rot], sin, cos)
     if rot == x.shape[-1]:
         return out
     return jnp.concatenate([out, x[..., rot:]], axis=-1)

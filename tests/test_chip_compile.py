@@ -473,6 +473,14 @@ MOVERS = ("copy", "transpose", "dynamic-slice", "dynamic-update-slice")
 _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
              "u64": 8}
+
+
+def _array_bytes(dtype: str, dims: str) -> int:
+    """Bytes of an HLO array type ``dtype[dims]``."""
+    return _ITEMSIZE[dtype] * int(np.prod(
+        [int(d) for d in dims.split(",") if d] or [1]))
+
+
 _HLO_LINE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*([a-z0-9]+)"
                        r"\[([\d,]*)\](?:\{[^}]*\})?\s+([\w\-]+)\(")
 
@@ -487,8 +495,7 @@ def pool_sized_movers(text: str, floor: int) -> list:
         if not m or m.group(2) not in _ITEMSIZE:
             continue
         name, dtype, dims, opcode = m.groups()
-        size = _ITEMSIZE[dtype] * int(np.prod(
-            [int(d) for d in dims.split(",") if d] or [1]))
+        size = _array_bytes(dtype, dims)
         moves = opcode in MOVERS or (
             opcode == "fusion" and any(w in name for w in MOVERS))
         if moves and size >= floor:
@@ -1166,6 +1173,10 @@ TRAIN_LAYERS, TRAIN_ROWS = 2, 4
 #: than 0.3 GB beyond it (the cell's own twelve layers under the chip's
 #: 15.75 GiB buy rung 1)
 TRAIN_LIMIT = 6_100_000_000
+#: the products a layer's rope adds to attention's matmuls in a pass that
+#: runs it: the pair swap of q and of k (models/transformer.py::
+#: _rotate_pairs)
+ROPE_SWAPS = 2
 
 
 @pytest.fixture(scope="module")
@@ -1284,10 +1295,14 @@ def test_train_step_keeps_what_its_policy_names(train_step_programs, policy):
     compiled, table = train_step_programs(policy)
     assert not table["stale"] and not base_table["stale"]
 
-    # today's default: every layer's forward twice, the flash kernel too
+    # today's default: every layer's forward twice, the flash kernel too;
+    # attention's matmuls are the four projections and the rope's pair swap
+    # of q and of k (PR 49: a product with a 0/1 permutation, fused with
+    # the rotation)
     flash, matmuls = _train_program_facts(base, base_table)
     assert (flash["forward"], flash["recompute"]) == (1, 1)
-    assert matmuls["recompute", "attn"] == 4 and matmuls["recompute", "mlp"] == 2
+    assert matmuls["recompute", "attn"] == 4 + ROPE_SWAPS
+    assert matmuls["recompute", "mlp"] == 2
     assert (base_table["remat_policy"], base_table["remat_layer_bytes"],
             base_table["remat_budget_bytes"]) == ("nothing_saveable", 0, 0)
 
@@ -1296,7 +1311,8 @@ def test_train_step_keeps_what_its_policy_names(train_step_programs, policy):
     flash, matmuls = _train_program_facts(compiled, table)
     assert (flash["forward"], flash["recompute"]) == (1, 0)
     assert matmuls["recompute", "mlp"] == 2
-    assert matmuls["forward", "attn"] == 4 and matmuls["forward", "mlp"] == 3
+    assert matmuls["forward", "attn"] == 4 + ROPE_SWAPS
+    assert matmuls["forward", "mlp"] == 3
     model_cfg = T.TransformerConfig(
         vocab_size=32000, hidden_size=4096, intermediate_size=14336,
         num_layers=TRAIN_LAYERS, num_heads=HEADS, num_kv_heads=KV_HEADS)
@@ -1304,11 +1320,12 @@ def test_train_step_keeps_what_its_policy_names(train_step_programs, policy):
     rungs = T.remat_rung_bytes(model_cfg, tokens)
     if policy == "save_attn_out":
         # out and lse alone: the projections and ropes run again
-        assert matmuls["recompute", "attn"] == 4
+        assert matmuls["recompute", "attn"] == 4 + ROPE_SWAPS
         kept = tokens * HEADS * (HEAD_DIM * 2 + 4)
         assert table["remat_policy"] == "save_attn_out"
     elif policy == "save_attn":
-        # rung 1: the output projection alone
+        # rung 1: the output projection alone (q and k are kept as the rope
+        # left them: no swap runs again)
         assert matmuls["recompute", "attn"] == 1
         kept = rungs["save_attn"]
         assert table["remat_policy"] == "save_attn"
@@ -1347,6 +1364,124 @@ def test_train_step_keeps_what_its_policy_names(train_step_programs, policy):
 def _train_shard_bytes(cfg, bytes_a_parameter, shards=4):
     """Bytes of one device's share of the model's matmul parameters."""
     return cfg.n_params() // shards * bytes_a_parameter
+
+
+# -- the attention block: what moves outside its matmuls and kernels (PR 49) -
+
+_HLO_SHAPE = re.compile(r"\b(%s)\[([\d,]*)\]" % "|".join(_ITEMSIZE))
+
+
+def bytes_outside_matmuls(text: str) -> list:
+    """``(bytes, name, opcode)``, most first, of every instruction of a
+    compiled program's ENTRY computation that is neither a matmul fusion
+    (one whose computation holds a ``dot`` or a ``convolution``) nor a
+    Mosaic call: the bytes of its operands and of its result, what it moves
+    through HBM at the least.  Left out: what leaves no event (parameters,
+    constants, tuples, bitcasts, a ``ConcatBitcast``); an async pair counts
+    once, at its ``-done``, as its result read and written."""
+    from deepspeed_tpu.telemetry import program_scopes
+
+    def nbytes(shapes):
+        return sum(_array_bytes(*shape)
+                   for shape in _HLO_SHAPE.findall(shapes))
+
+    comps, entry = program_scopes._computations(text)
+    line = {m.group(1): m.group(2) for m in map(
+        program_scopes._INSTRUCTION.match, text.splitlines()) if m}
+    result = {i.name: nbytes(line[i.name].split(f" {i.opcode}(")[0])
+              for i in comps[entry]}
+    found = []
+    for i in comps[entry]:
+        if i.opcode in program_scopes._SILENT or i.opcode.endswith("-start"):
+            continue
+        if i.opcode == "custom-call" and re.search(
+                r'custom_call_target="(tpu_custom_call|ConcatBitcast)"',
+                line[i.name]):
+            continue
+        if any(op in ("dot", "convolution")
+               for _, op, *_ in comps.get(i.fused, [])):
+            continue
+        moved = 2 * result[i.name] if i.opcode.endswith("-done") else \
+            result[i.name] + sum(result.get(o, 0) for o in i.mentions)
+        found.append((moved, i.name, i.opcode))
+    return sorted(found, reverse=True)
+
+
+def test_bytes_outside_matmuls_reads_a_program_text():
+    text = """
+%fused_dot (a: bf16[8,128], b: bf16[128,128]) -> f32[8,128] {
+  %a = bf16[8,128]{1,0} parameter(0)
+  %b = bf16[128,128]{1,0} parameter(1)
+  ROOT %dot.1 = f32[8,128]{1,0} dot(%a, %b), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+}
+
+%fused_add (a: f32[8,128]) -> f32[8,128] {
+  %a.1 = f32[8,128]{1,0} parameter(0)
+  ROOT %add.1 = f32[8,128]{1,0} add(%a.1, %a.1)
+}
+
+ENTRY %main (x: bf16[8,128], w: bf16[128,128]) -> f32[8,128] {
+  %x = bf16[8,128]{1,0} parameter(0)
+  %w = bf16[128,128]{1,0} parameter(1)
+  %fusion.1 = f32[8,128]{1,0} fusion(%x, %w), kind=kOutput, calls=%fused_dot
+  %copy-start = (bf16[8,128]{1,0:S(1)}, bf16[8,128]{1,0}, u32[]) copy-start(%x)
+  %copy-done = bf16[8,128]{1,0:S(1)} copy-done(%copy-start)
+  %kernel.2 = f32[8,128]{1,0} custom-call(%fusion.1), custom_call_target="tpu_custom_call"
+  %copy.3 = f32[8,128]{0,1} copy(%kernel.2)
+  ROOT %fusion.2 = f32[8,128]{1,0} fusion(%copy.3), kind=kLoop, calls=%fused_add
+}
+"""
+    assert bytes_outside_matmuls(text) == [
+        (8192, "fusion.2", "fusion"), (8192, "copy.3", "copy"),
+        (4096, "copy-done", "copy-done")]
+
+
+def test_attention_block_moves_little_outside_its_matmuls(chip, monkeypatch):
+    """One attention block, forward and backward at the train cell's shapes
+    (``[4, 2048, 4096]`` bfloat16, 32 / 8 heads of 128, the flash kernels):
+    what its program moves through HBM outside matmuls and kernels.  Under
+    the strided-pair rope (``x[..., 0::2]``, ``jnp.stack``) that was 5.41
+    GB a layer: float32 copies of q with the pair index as the MAJOR
+    dimension, gathered and scattered by ``kCustom`` fusions.  With the
+    pair swap a product fused with the rotation 0.88 GB are left: the GQA
+    repeat, the group sum of dK / dV, the activation's prefetch."""
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.models import transformer as T
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    cfg = T.TransformerConfig(
+        vocab_size=32000, hidden_size=HEADS * HEAD_DIM,
+        intermediate_size=14336, num_layers=1, num_heads=HEADS,
+        num_kv_heads=KV_HEADS, max_seq_len=SEQ, sliding_window=4096,
+        dtype=jnp.bfloat16)
+    hidden = HEADS * HEAD_DIM
+    x = chip((TRAIN_ROWS, SEQ, hidden), jnp.bfloat16)
+    params = {"wq": chip((hidden, HEADS, HEAD_DIM), jnp.bfloat16),
+              "wk": chip((hidden, KV_HEADS, HEAD_DIM), jnp.bfloat16),
+              "wv": chip((hidden, KV_HEADS, HEAD_DIM), jnp.bfloat16),
+              "wo": chip((HEADS, HEAD_DIM, hidden), jnp.bfloat16)}
+
+    def block(params, x, positions, g):
+        def attend(params, x):
+            sin, cos = T.rope_table(cfg, positions)
+            return T._attention_block(cfg, params, x, sin, cos, None,
+                                      use_flash=True)
+        out, vjp = jax.vjp(attend, params, x)
+        return out, vjp(g)
+
+    text = jax.jit(block).lower(
+        params, x, chip((TRAIN_ROWS, SEQ), jnp.int32), x).compile().as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                   "flash_attention_bwd_dq"):
+        assert len(kernel_calls(text, kernel)) == 1, kernel
+    moved = bytes_outside_matmuls(text)
+    assert sum(m[0] for m in moved) < 1_200_000_000, moved[:12]
+    # no gather or scatter of the lane dimension's pairs ...
+    assert [ln for ln in text.splitlines() if "kind=kCustom" in ln] == []
+    # ... and no float32 re-layout of q (or of its cotangent)
+    q_bytes = 4 * TRAIN_ROWS * SEQ * HEADS * HEAD_DIM
+    assert [m for m in pool_sized_movers(text, q_bytes)
+            if m[1] == "copy" and m[2].startswith("f32")] == []
 
 
 # -- the smallthinker family: the expert kernel's gate, its step programs ----

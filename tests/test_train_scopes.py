@@ -88,13 +88,16 @@ def test_every_phase_and_module_has_instructions(policy, recomputes):
     ins, table = instructions_of(*engine_of(remat_policy=policy))
     assert not table["stale"]
     if policy.startswith("save_attn"):
-        # q k v and the output are kept: of attention's six matmuls the
+        # q k v and the output are kept: of attention's eight matmuls (the
+        # four projections, the scores' two, and since PR 49 the rope's
+        # pair swap of q and of k, a product with a 0/1 permutation) the
         # recomputed forward runs the scores and, on rung 1, the output
-        # projection; the MLP's gate and up as under nothing_saveable
+        # projection: q and k are kept AFTER the rope and are not swapped
+        # again; the MLP's gate and up as under nothing_saveable
         base = recomputed_matmuls(instructions_of(
             *engine_of(remat_policy="nothing_saveable"))[0])
         kept = recomputed_matmuls(ins)
-        assert base["attn"] == 6 and base["mlp"] == kept["mlp"] == 2
+        assert base["attn"] == 8 and base["mlp"] == kept["mlp"] == 2
         assert kept["attn"] == {"save_attn": 2, "save_attn_residual": 1}[
             policy]
     phases = collections.Counter(p for _, p, _ in ins.values())
