@@ -140,17 +140,19 @@ class Pass(NamedTuple):
     ropes: Dict[str, Tuple[jax.Array, jax.Array]]
     #: held experts: of every token of the pass, whether it is a true one
     valid: Optional[jax.Array]
+    #: where each pool stands in the carry, by what it is (``pool_names``)
+    places: Dict[str, int] = {}
 
 
 class Mixer(NamedTuple):
     """How the layers of one kind mix tokens (:data:`MIXERS`, below the
     model): ``run(model, h, pools, weights, layer, *, kind, ctx) ->
     (output, pools)``; the key of its ``weights`` in a layer's tree; which
-    ``pools`` it writes, by their place in what the engine hands in (one
-    pool; full and window; pages, ``h``, ``conv``)."""
+    ``pools`` it writes, by what they are (``RaggedInferenceModel.
+    pool_names``: "pages", "window", "state", "conv")."""
     run: Callable
     weights: str
-    pools: Tuple[int, ...]
+    pools: Tuple[str, ...]
 
 
 def _write_then_attend(segments: Sequence[Segment], new, pool, write, fresh,
@@ -643,6 +645,18 @@ class RaggedInferenceModel:
                               self.kv_config.page_size)
 
     @property
+    def pool_names(self) -> Tuple[str, ...]:
+        """What the engine hands a step program as ``kv``, in its order
+        (``engine._pool``), each by what it is: the one page group's pool
+        ("pages": K/V by head, or the latent plane), then where the model
+        has them the window group's pool, then the state pool's two
+        arrays.  A mixer names the pools it writes by these names
+        (:data:`MIXERS`)."""
+        return ("pages",) \
+            + (("window",) if self.window_kv_config is not None else ()) \
+            + (("state", "conv") if self.state_config is not None else ())
+
+    @property
     def last_trunk_passes(self) -> int:
         """Trunk passes of the newest dispatch's program (the
         ``fastgen.step`` span's ``trunk_passes``)."""
@@ -986,6 +1000,7 @@ class RaggedInferenceModel:
         if cfg.embed_layernorm:  # BLOOM word_embeddings_layernorm
             x = self._norm(params["embed"]["norm"], x)
         pools = kv if type(kv) is tuple else (kv,)
+        places = {name: i for i, name in enumerate(self.pool_names)}
         # the page group of each attending kind (the latent plane lies in
         # the one page group)
         group_of = {kind: CACHE_KINDS[kind].group if kind in CACHE_KINDS
@@ -1013,11 +1028,12 @@ class RaggedInferenceModel:
                     seg._replace(page_table=parts[group], start_pos=start))
             if "slot" in parts:     # the pool's last slot is the scratch one
                 rows.append((
-                    jnp.clip(parts["slot"], 0, pools[1].shape[1] - 1),
+                    jnp.clip(parts["slot"], 0,
+                             pools[places["state"]].shape[1] - 1),
                     seg.start_pos == 0, _true_positions(seg)))
         ropes = {kind: self.rope_table(cfg, kind, pos) for kind in group_of
                  } if cfg.pos_emb == "rope" else {}
-        ctx = Pass(cfg, by_group, rows, ropes, valid)
+        ctx = Pass(cfg, by_group, rows, ropes, valid, places)
         # the pools are the loop's CARRY beside the activations (and the
         # held-experts counts, last): as scanned xs/ys a pool would be
         # sliced out and stacked back, two layer-sized copies a layer and
@@ -1060,7 +1076,12 @@ class RaggedInferenceModel:
         periods scanned as an operand would have each period's run sliced
         out whole), a run of several a scan of its own (7 Mamba, the
         attention layer, 6 Mamba: a program holds two Mamba bodies and
-        one attention body, not fourteen)."""
+        one attention body, not fourteen); or (c) ``runs`` is ``{r<j>:
+        run j's layers, stacked}``: every layer behind the leading ones in
+        maximal runs of like kinds, a run of several ONE scan over its
+        stack as the operand (a family that holds ONE period whose layers
+        differ in their trees: under (a) it would be a body a layer).
+        Which family takes which, and why three: ``docs/DESIGN.md``."""
         cfg, i32 = ctx.cfg, jnp.int32
         kinds = T.layer_kinds(cfg)
         leading, runs, periods, tail = T.layer_runs(cfg)
@@ -1070,6 +1091,7 @@ class RaggedInferenceModel:
         # the tree's layout, read HERE and nowhere below
         layers = params.get("layers") or {}
         flat = layers if layers and set(layers) <= set(MIXERS) else None
+        in_runs = params.get("runs")                        # (c)
         if not flat:    # (a): every layer of a period under its own name
             runs = [(kind, 1) for kind, n in runs for _ in range(n)]
         stacks = {} if flat else params.get("periods") or {"l0": layers}
@@ -1147,6 +1169,24 @@ class RaggedInferenceModel:
         else:
             for i in range(leading):
                 carry = outside(carry, i, dense.get(f"l{i}"))
+        if in_runs is not None:
+            # (c): one body a run in the program, not one a layer (a step
+            # program of seven bodies was 56 MB of code and never fitted
+            # the compile cache)
+            at = leading
+            for j, (kind, n) in enumerate(T.kind_runs(kinds[leading:])):
+                stack, met = in_runs[f"r{j}"], kinds[:at].count(kind)
+                if n > 1:
+                    carry, _ = jax.lax.scan(
+                        lambda c, xs, kind=kind, met=met, at=at: (layer(
+                            c, kind, met + xs[1], xs[0],
+                            at - leading + xs[1]), None),
+                        carry, (stack, jnp.arange(n, dtype=i32)))
+                else:
+                    carry = layer(carry, kind, met, jax.tree.map(
+                        lambda a: a[0], stack), at - leading)
+                at += n
+            return carry
         counter = jnp.arange(periods, dtype=i32)
         if periods and period == 1 and leading:
             counter = first + counter
@@ -1409,10 +1449,11 @@ class RaggedInferenceModel:
         h = x if cfg.post_norm else self._norm(lp["norm1"], x)
         plan = (self._route(lp, h, ctx, layout=True) if "moe" in lp
                 and cfg.router_reads == "mixer" else None)
+        held_at = [ctx.places[name] for name in mixer.pools]
         out, written = mixer.run(
-            self, h, [rest[i] for i in mixer.pools], lp[mixer.weights], at,
+            self, h, [rest[i] for i in held_at], lp[mixer.weights], at,
             kind=kind, ctx=ctx)
-        for i, pool in zip(mixer.pools, written):
+        for i, pool in zip(held_at, written):
             rest[i] = pool
         if cfg.sandwich_norm:
             out = self._norm(lp["norm1_post"], out)
@@ -1515,9 +1556,12 @@ class RaggedInferenceModel:
         dtype = cfg.dtype
         dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
         scale = float(dn + cfg.qk_rope_head_dim) ** -0.5
-        cq = self._norm(ap["q_norm"], jnp.einsum(
-            "sqe,er->sqr", h, ap["wq_a"].astype(dtype)))
-        q = jnp.einsum("sqr,rhd->sqhd", cq, ap["wq_b"].astype(dtype))
+        if "wq_a" in ap:
+            cq = self._norm(ap["q_norm"], jnp.einsum(
+                "sqe,er->sqr", h, ap["wq_a"].astype(dtype)))
+            q = jnp.einsum("sqr,rhd->sqhd", cq, ap["wq_b"].astype(dtype))
+        else:       # no low-rank query (``q_lora_rank`` null): no norm
+            q = jnp.einsum("sqe,ehd->sqhd", h, ap["wq"].astype(dtype))
         q_n, q_r = q[..., :dn], T.apply_rope(q[..., dn:], sin, cos)
         ckr = jnp.einsum("sqe,er->sqr", h, ap["wkv_a"].astype(dtype))
         c = self._norm(ap["kv_norm"], ckr[..., :rkv])
@@ -1609,10 +1653,8 @@ class RaggedInferenceModel:
         each row from and to its slot of ``pool[layer]``
         (``ops/delta_rule.py``; the convolution is ``ops/ssm.py``'s).
         Returns (output in ``u``'s layout, (state pool, conv pool))."""
-        cfg, segments, rows, (s_pool, conv_pool) = (
-            ctx.cfg, ctx.by_group["full"], ctx.rows, pools)
-        dtype, f32 = cfg.dtype, jnp.float32
-        H, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+        cfg, dtype, f32 = ctx.cfg, ctx.cfg.dtype, jnp.float32
+        H = cfg.delta_heads
         qkv = jnp.einsum("sqe,ef->sqf", u, mp["w_qkv"].astype(dtype))
         gate = jnp.einsum("sqe,ef->sqf", u, mp["w_gate"].astype(dtype))
         ab = jnp.einsum("sqe,fe->sqf", u, mp["w_ab"].astype(dtype),
@@ -1622,32 +1664,8 @@ class RaggedInferenceModel:
             ab[..., :H] + mp["dt_bias"].astype(f32))
         beta = jax.nn.sigmoid(ab[..., H:]) \
             * (2.0 if cfg.delta_neg_eigval else 1.0)
-        def l2(a):
-            return a * jax.lax.rsqrt(
-                jnp.sum(a * a, -1, keepdims=True) + 1e-6)
-
-        outs = []
-        for (slots, fresh, valid), seg, xs, gs, bs in zip(
-                rows, segments, *(_per_segment(a, segments)
-                                  for a in (qkv, g, beta))):
-            conv, tail = conv_step(conv_pool, layer, slots, fresh,
-                                   seg.q_lens, xs, mp["conv_w"])
-            conv = jax.nn.silu(conv)                        # float32
-            by_head = conv.shape[:2] + (H, -1)
-            q = l2(conv[..., :H * dk].reshape(by_head)) * dk ** -0.5
-            k = l2(conv[..., H * dk:2 * H * dk].reshape(by_head))
-            # a padded position moves nothing: alpha = 1, beta = 0
-            o, s_pool, conv_pool = delta_rule(
-                s_pool, conv_pool, layer, slots, fresh, q, k,
-                conv[..., 2 * H * dk:],
-                jnp.where(valid[..., None], gs, 0.0),
-                jnp.where(valid[..., None], bs, 0.0), tail)
-            outs.append(o)
-        o = _end_to_end(outs)
-        o = o.reshape(o.shape[:2] + (H, dv))
-        y = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
-                              + cfg.norm_eps) \
-            * mp["o_norm"]["scale"].astype(f32)
+        y, s_pool, conv_pool = self._delta_recurrence(
+            qkv, g, beta, pools, mp, layer, ctx)
         y = y.reshape(gate.shape) * jax.nn.silu(gate.astype(f32))
         return jnp.einsum("sqd,de->sqe", y.astype(dtype),
                           mp["w_out"].astype(dtype)), (s_pool, conv_pool)
@@ -1749,20 +1767,94 @@ class RaggedInferenceModel:
         chosen, weights = held.ROUTERS[cfg.router_scoring](
             h if h.ndim == 2 else h.reshape(-1, h.shape[-1]),
             lp["moe"]["router"], cfg.moe_top_k, cfg.routed_scaling_factor,
-            cfg.norm_topk_prob)
+            cfg.norm_topk_prob, **(dict(
+                bias=lp["moe"]["router_bias"], groups=cfg.router_groups,
+                keep=cfg.router_topk_groups) if cfg.router_groups else {}))
         rows = held.plan_rows(chosen, ctx.valid, cfg.experts_first,
                               cfg.held_experts, cfg.n_routed_experts) \
             if layout else None
         return chosen, weights, rows
 
 
+    def _delta_recurrence(self, qkv, g, beta, pools, mp, layer, ctx: Pass):
+        """What the delta-rule mixers share behind their projections: the
+        convolution over q, k and v and the recurrence segment by segment,
+        each row from and to its slot of ``pool[layer]``
+        (``ops/delta_rule.py`` under ``g``: one decay a head ``[.., H]`` or
+        a key channel ``[.., H, dk]``; the convolution is ``ops/ssm.py``'s),
+        then the norm over each head's values.  Returns (y ``[.., H, dv]``
+        float32, state pool, conv pool)."""
+        cfg, rows, (s_pool, conv_pool) = ctx.cfg, ctx.rows, pools
+        # the segments under any page group's table: rows and lengths
+        segments = next(iter(ctx.by_group.values()))
+        f32 = jnp.float32
+        H, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+
+        def l2(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+        outs = []
+        for (slots, fresh, valid), seg, xs, gs, bs in zip(
+                rows, segments, *(_per_segment(a, segments)
+                                  for a in (qkv, g, beta))):
+            conv, tail = conv_step(conv_pool, layer, slots, fresh,
+                                   seg.q_lens, xs, mp["conv_w"])
+            conv = jax.nn.silu(conv)                        # float32
+            by_head = conv.shape[:2] + (H, -1)
+            q = l2(conv[..., :H * dk].reshape(by_head)) * dk ** -0.5
+            k = l2(conv[..., H * dk:2 * H * dk].reshape(by_head))
+            # a padded position moves nothing: alpha = 1, beta = 0
+            o, s_pool, conv_pool = delta_rule(
+                s_pool, conv_pool, layer, slots, fresh, q, k,
+                conv[..., 2 * H * dk:],
+                jnp.where(valid[(...,) + (None,) * (gs.ndim - 2)], gs, 0.0),
+                jnp.where(valid[..., None], bs, 0.0), tail)
+            outs.append(o)
+        o = _end_to_end(outs)
+        o = o.reshape(o.shape[:2] + (H, dv))
+        return o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                 + cfg.norm_eps) \
+            * mp["o_norm"]["scale"].astype(f32), s_pool, conv_pool
+
+    def _kda_mixer(self, u, pools, mp, layer, *, kind, ctx: Pass):
+        """The Kimi-delta (KDA) mixer of ``u`` (all tokens of the
+        segments): :meth:`_delta_mixer`'s shape with ONE DECAY A KEY
+        CHANNEL, ``g = lower * sigmoid(exp(A_log) (W_f u + dt_bias))`` in
+        ``(lower, 0)`` (the bounded gate), ``beta = sigmoid``, and one
+        sigmoid gate a head on the normed output; the recurrence is
+        ``ops/delta_rule.py``'s under ``g`` ``[.., H, dk]``.  Returns
+        (output in ``u``'s layout, (state pool, conv pool))."""
+        cfg, dtype, f32 = ctx.cfg, ctx.cfg.dtype, jnp.float32
+        H, dk = cfg.delta_heads, cfg.delta_key_dim
+        qkv = jnp.einsum("sqe,ef->sqf", u, mp["w_qkv"].astype(dtype))
+        f = jnp.einsum("sqe,fe->sqf", u, mp["w_f"].astype(dtype),
+                       preferred_element_type=f32) + mp["dt_bias"].astype(f32)
+        bg = jnp.einsum("sqe,fe->sqf", u, mp["w_bg"].astype(dtype),
+                        preferred_element_type=f32)
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            jnp.exp(mp["A_log"].astype(f32))[:, None]
+            * f.reshape(f.shape[:2] + (H, dk)))
+        beta, gate = jax.nn.sigmoid(bg[..., :H]), jax.nn.sigmoid(bg[..., H:])
+
+        y, s_pool, conv_pool = self._delta_recurrence(
+            qkv, g, beta, pools, mp, layer, ctx)
+        y = y * gate[..., None]
+        return jnp.einsum("sqd,de->sqe",
+                          y.reshape(y.shape[:2] + (-1,)).astype(dtype),
+                          mp["w_out"].astype(dtype)), (s_pool, conv_pool)
+
+
 #: a layer kind is an entry here, one in ``cache_kinds.py::CACHE_KINDS``
-#: (what it caches; the latent plane lies in the one page pool) and its
-#: kernel
+#: (what it caches) and its kernel
 MIXERS: Dict[str, Mixer] = {
-    "full": Mixer(RaggedInferenceModel._kv_mixer, "attn", (0,)),
-    "window": Mixer(RaggedInferenceModel._kv_mixer, "attn", (1,)),
-    "latent": Mixer(RaggedInferenceModel._latent_mixer, "attn", (0,)),
-    "ssm": Mixer(RaggedInferenceModel._ssm_mixer, "mixer", (1, 2)),
-    "delta": Mixer(RaggedInferenceModel._delta_mixer, "mixer", (1, 2)),
+    "full": Mixer(RaggedInferenceModel._kv_mixer, "attn", ("pages",)),
+    "window": Mixer(RaggedInferenceModel._kv_mixer, "attn", ("window",)),
+    "latent": Mixer(RaggedInferenceModel._latent_mixer, "attn", ("pages",)),
+    "ssm": Mixer(RaggedInferenceModel._ssm_mixer, "mixer",
+                 ("state", "conv")),
+    "delta": Mixer(RaggedInferenceModel._delta_mixer, "mixer",
+                   ("state", "conv")),
+    "kda": Mixer(RaggedInferenceModel._kda_mixer, "mixer",
+                 ("state", "conv")),
 }

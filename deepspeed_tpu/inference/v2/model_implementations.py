@@ -23,6 +23,8 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple, Type
 
+import jax
+
 from .model import RaggedInferenceModel
 
 
@@ -255,13 +257,64 @@ class SmallThinkerInferenceModel(RaggedInferenceModel):
         return RaggedInferenceModel.rope_table(self, cfg, kind, positions)
 
 
+class BailingHybridInferenceModel(RaggedInferenceModel):
+    """Ling-3.0 (``models/bailing_hybrid.py``; no counterpart in the
+    reference): Kimi-delta (KDA) linear-attention layers and latent
+    attention layers in one model, the latent layers' planes in pages and
+    the KDA layers' matrix state and convolution tail in one slot of the
+    state pool a sequence, leading dense layers, then routed layers behind
+    a grouped, biased router of which this process holds ``experts_held``
+    experts beside the shared expert."""
+    MODEL_TYPES = ("bailing_hybrid",)
+    #: a callable ``(experts [T, k])`` that every routed layer of a program
+    #: TRACED while it is set calls, in layer order, with what its router
+    #: chose (a host callback: such a program is never cached).  For a
+    #: comparison that must follow the served routing, since a near-tie of
+    #: 512 scores falls either way under bfloat16 (the benchmark's probe
+    #: hands the record to its reference); None, as it is served: no trace
+    #: of it in a program.
+    routing_sink = None
+
+    def _route(self, lp, h, ctx, layout: bool = False):
+        out = super()._route(lp, h, ctx, layout)
+        if self.routing_sink is not None:
+            jax.debug.callback(self.routing_sink, out[0], ordered=True)
+        return out
+
+    def __init__(self, cfg, params, **kw):
+        assert set(cfg.layer_kinds) <= {"kda", "latent"} \
+            and "latent" in cfg.layer_kinds \
+            and len(cfg.layer_kinds) == cfg.num_layers, \
+            "bailing_hybrid names a kind for every layer, a latent one too"
+        assert cfg.kv_lora_rank > 0 and cfg.qk_rope_head_dim > 0 \
+            and not cfg.q_lora_rank
+        assert cfg.delta_heads > 0 and cfg.delta_key_dim > 0 \
+            and cfg.delta_value_dim > 0 and cfg.delta_conv > 1
+        assert cfg.kda_lower_bound < 0
+        assert cfg.norm == "rmsnorm" and cfg.pos_emb == "rope"
+        assert cfg.router_scoring == "sigmoid_grouped" \
+            and cfg.n_routed_experts % cfg.router_groups == 0 \
+            and 1 <= cfg.router_topk_groups <= cfg.router_groups
+        assert cfg.n_routed_experts >= cfg.moe_top_k >= 1
+        held = cfg.held_experts
+        assert 0 <= cfg.experts_first \
+            and cfg.experts_first + held <= cfg.n_routed_experts, \
+            "the experts held here lie outside the router's outputs"
+        assert 0 <= cfg.first_k_dense <= cfg.num_layers
+        super().__init__(cfg, params, **kw)
+        experts = self.params.get("experts")
+        assert experts is None or experts["wg"].shape[:2] \
+            == (cfg.num_layers - cfg.first_k_dense, held), \
+            "expert weights do not match the routed layers or experts_held"
+
+
 _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LlamaV2InferenceModel, MistralInferenceModel, MixtralInferenceModel,
     FalconInferenceModel, OPTInferenceModel, PhiInferenceModel,
     Qwen2InferenceModel, BloomInferenceModel, PanguUltraMoEInferenceModel,
     LagunaInferenceModel, JambaInferenceModel, OlmoHybridInferenceModel,
     GPTNeoXInferenceModel, GPT2InferenceModel, GPTJInferenceModel,
-    SmallThinkerInferenceModel,
+    SmallThinkerInferenceModel, BailingHybridInferenceModel,
 )
 
 

@@ -16,7 +16,9 @@ The wide table of a segment, for a model of more than one cache::
                          | the window table's base page | the state slot
 
 each part present only where the model has a kind that needs it, so a
-model of one page group takes the ``[S, P]`` table it always took.
+model of one page group takes the ``[S, P]`` table it always took.  The
+full group's pages are K/V by head or, under latent attention, the latent
+plane's: a row may carry a latent plane's pages AND a state slot.
 """
 
 from __future__ import annotations
@@ -46,20 +48,30 @@ class CacheKind:
         return self.slot_shape is not None
 
 
+def _matrix_slot(cfg):
+    """A matrix ``[dk, dv]`` a head, the heads side by side in the minor
+    dim; the convolution's tail of q, k AND v."""
+    return ((cfg.delta_key_dim, cfg.delta_heads * cfg.delta_value_dim),
+            (cfg.delta_conv - 1, cfg.delta_heads
+             * (2 * cfg.delta_key_dim + cfg.delta_value_dim)))
+
+
 CACHE_KINDS: Dict[str, CacheKind] = {
     "full": CacheKind(group="full"),
+    # latent attention (ops/mla_attention.py): one plane a token in the
+    # one page group's pool, in place of K and V by head
+    "latent": CacheKind(group="full"),
     "window": CacheKind(group="window", windowed=True),
     # Mamba-1 (ops/ssm.py): a diagonal state a channel, the tail of the
     # mixer's own channels
     "ssm": CacheKind(slot_shape=lambda cfg: (
         (cfg.ssm_state_dim, cfg.ssm_inner),
         (cfg.ssm_conv - 1, cfg.ssm_inner))),
-    # gated delta rule (ops/delta_rule.py): a matrix [dk, dv] a head, the
-    # heads side by side in the minor dim; the tail of q, k AND v
-    "delta": CacheKind(slot_shape=lambda cfg: (
-        (cfg.delta_key_dim, cfg.delta_heads * cfg.delta_value_dim),
-        (cfg.delta_conv - 1, cfg.delta_heads
-         * (2 * cfg.delta_key_dim + cfg.delta_value_dim)))),
+    # gated delta rule (ops/delta_rule.py): one decay a head
+    "delta": CacheKind(slot_shape=_matrix_slot),
+    # Kimi-delta (ops/delta_rule.py): the same slot, stepped by another
+    # update rule (one decay a key channel)
+    "kda": CacheKind(slot_shape=_matrix_slot),
 }
 
 

@@ -102,9 +102,13 @@ class TransformerConfig:
     norm_topk_prob: bool = True
     first_k_dense: int = 0
     # router_scoring: "sigmoid" | "softmax" over all experts, before the
-    # top-k.  router_reads: "ffn" (the feed-forward's normed input) |
-    # "mixer" (the mixer's).  expert_act: "silu" | "relu" (moe/held.py)
+    # top-k; "sigmoid_grouped": the top-k among the router_topk_groups best
+    # of router_groups expert groups, chosen by score + a selection bias.
+    # router_reads: "ffn" (the feed-forward's normed input) | "mixer" (the
+    # mixer's).  expert_act: "silu" | "relu" (moe/held.py)
     router_scoring: str = "sigmoid"
+    router_groups: int = 0
+    router_topk_groups: int = 0
     router_reads: str = "ffn"
     expert_act: str = "silu"
     # layers of two attention kinds in one model (models/laguna.py): one
@@ -148,6 +152,12 @@ class TransformerConfig:
     delta_value_dim: int = 0
     delta_conv: int = 4
     delta_neg_eigval: bool = False
+    # Kimi-delta (KDA) layers (models/bailing_hybrid.py): ``layer_kinds``
+    # names them "kda".  The delta_* sizes above are theirs; the decay is
+    # one a KEY CHANNEL, exp(kda_lower_bound * sigmoid(.)) (the bounded
+    # gate: what lets a chunk of 16 tokens take a matrix form), and one
+    # sigmoid gate a head scales the normed output
+    kda_lower_bound: float = 0.0
     # x + N(mixer(x)), x + N(mlp(x)): the norm on the sub-layer's OUTPUT
     # and none on its input (the OLMo 2 / 3 order)
     post_norm: bool = False
@@ -217,10 +227,11 @@ class TransformerConfig:
         h, k, d = self.num_heads, self.kv_heads, self.dims_per_head
         attn = e * h * d + 2 * e * k * d + h * d * e
         if self.kv_lora_rank:
-            attn = (e * self.q_lora_rank
-                    + self.q_lora_rank * h * (self.qk_nope_head_dim
-                                              + self.qk_rope_head_dim)
-                    + e * self.latent_dim
+            attn = ((e + h * (self.qk_nope_head_dim + self.qk_rope_head_dim))
+                    * self.q_lora_rank if self.q_lora_rank
+                    else e * h * (self.qk_nope_head_dim
+                                  + self.qk_rope_head_dim)) + (
+                    e * self.latent_dim
                     + self.kv_lora_rank * h * (self.qk_nope_head_dim
                                                + self.v_head_dim)
                     + h * self.v_head_dim * e)
@@ -239,8 +250,16 @@ class TransformerConfig:
                           * self.delta_value_dim)
             delta = (e * (2 * qk + 2 * dv + 2 * dh) + dv * e
                      + self.delta_conv * (2 * qk + dv) + 2 * dh)
+            # a KDA mixer: q, k, v, the decay gate a key channel, beta and
+            # the output gate a head, the output projection, the
+            # convolution, A a head and the gate's bias a channel
+            kda = (e * (3 * qk + dv + 2 * dh) + dv * e
+                   + self.delta_conv * (2 * qk + dv) + dh + qk)
+            latent = attn // l
             attn = sum(mixer if kind == "ssm" else
                        delta if kind == "delta" else
+                       kda if kind == "kda" else
+                       latent if kind == "latent" else
                        2 * e * heads.get(kind, h) * d + 2 * e * k * d
                        + (e * heads.get(kind, h) if self.head_gate else 0)
                        for kind in self.layer_kinds)
@@ -1222,6 +1241,17 @@ def layer_kinds(cfg: TransformerConfig) -> Tuple[str, ...]:
         ("latent" if cfg.latent_dim else "full",) * cfg.num_layers)
 
 
+def kind_runs(kinds) -> List[Tuple[str, int]]:
+    """The maximal runs of like kinds in ``kinds``, as (kind, length)."""
+    runs: List[Tuple[str, int]] = []
+    for kind in kinds:
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1] + 1)
+        else:
+            runs.append((kind, 1))
+    return runs
+
+
 def layer_runs(cfg: TransformerConfig
                ) -> Tuple[int, List[Tuple[str, int]], int, int]:
     """(the leading layers that stand outside the pattern,
@@ -1234,11 +1264,6 @@ def layer_runs(cfg: TransformerConfig
     period = next((p for p in range(1, len(rest) + 1)
                    if all(rest[i] == rest[i % p]
                           for i in range(len(rest)))), 1)
-    runs: List[Tuple[str, int]] = []
-    for kind in rest[:period]:
-        if runs and runs[-1][0] == kind:
-            runs[-1] = (kind, runs[-1][1] + 1)
-        else:
-            runs.append((kind, 1))
+    runs = kind_runs(rest[:period])
     periods = len(rest) // period
     return leading, runs, periods, len(rest) - periods * period

@@ -38,7 +38,8 @@ def route_sigmoid_topk(x2d: jax.Array, w_router: jax.Array, top_k: int,
     """Sigmoid scores over ALL experts in float32, the ``top_k`` largest,
     their scores normalised over the chosen ones and scaled:
     ``w_i = scaling * s_i / (sum_{j in I} s_j + 1e-20)``.  No groups, no
-    selection bias.  Returns (experts [T, k] int32, weights [T, k] f32).
+    selection bias (:func:`route_sigmoid_grouped` has both).  Returns
+    (experts [T, k] int32, weights [T, k] f32).
 
     The product is taken at HIGHEST precision: a bf16 pass can swap the
     k-th and (k+1)-th expert of a token, which changes its output by a
@@ -386,8 +387,35 @@ def route_softmax_topk(x2d: jax.Array, w_router: jax.Array, top_k: int,
     return experts.astype(jnp.int32), top * scaling
 
 
+def route_sigmoid_grouped(x2d: jax.Array, w_router: jax.Array, top_k: int,
+                          scaling: float, norm_topk: bool = True, *,
+                          bias: jax.Array, groups: int, keep: int
+                          ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`route_sigmoid_topk` with expert groups and a selection bias
+    (the deepseek_v3 lineage's ``noaux_tc``): ``c = s + bias`` CHOOSES, a
+    group of ``E / groups`` neighbouring experts scores the sum of its two
+    largest ``c``, the ``top_k`` largest ``c`` inside the ``keep`` best
+    groups are taken, and their weights come from ``s`` (not ``c``),
+    normalised over the chosen ones and scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x2d.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    T, E = scores.shape
+    choice = (scores + bias.astype(jnp.float32)).reshape(T, groups, -1)
+    _, kept = _largest(jnp.sum(_largest(choice, 2)[0], axis=-1), keep)
+    in_kept = jnp.any(
+        kept[:, :, None] == jnp.arange(groups, dtype=jnp.int32), axis=1)
+    _, experts = _largest(jnp.where(in_kept[:, :, None], choice,
+                                    -jnp.inf).reshape(T, E), top_k)
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), top * scaling
+
+
 #: the scoring functions a configuration names (``router_scoring``)
-ROUTERS = {"sigmoid": route_sigmoid_topk, "softmax": route_softmax_topk}
+ROUTERS = {"sigmoid": route_sigmoid_topk, "softmax": route_softmax_topk,
+           "sigmoid_grouped": route_sigmoid_grouped}
 
 
 #: the gate's activation a configuration names (``expert_act``): SwiGLU's

@@ -48,6 +48,17 @@ differences of the running sum of ``g = log alpha``, never as a quotient.
   ``lax.scan`` over positions, is the semantics ground truth and the CPU
   path.
 
+A DECAY A KEY CHANNEL (Kimi-delta, KDA: ``g`` of one more dimension, ``[..,
+H, dk]``) is the same recurrence with ``Diag(alpha_t)`` over the state's
+rows in place of the scalar, ``S_t = (I - beta_t k_t k_t^T) Diag(alpha_t)
+S_{t-1} + beta_t k_t v_t^T``; the head-scalar rule is its broadcast case.
+The same two kernels serve it, under the names ``kda_state_update_decode``
+and ``kda_chunk_prefill``: the update kernel takes ``alpha`` as it takes
+the keys (``[dk, H]`` columns spread over a head's lanes); the ratios of
+the chunk form differ by channel and move INSIDE the products
+(:func:`_chunk_channel`), which bounds a chunk at
+:data:`MAX_CHANNEL_CHUNK` tokens under a gate bounded below.
+
 A ``fresh`` row (position 0) starts from a zero state whatever its slot
 held.  A padded position has ``g = 0`` and ``beta = 0`` (``alpha = 1``,
 ``u = 0``) and moves nothing.  The convolution is ``ops/ssm.py::conv_step``
@@ -70,6 +81,11 @@ from ..accelerator import on_tpu
 #: ``2 log2(C)`` products of ``C x C`` matrices, which pass the chunk's
 #: other products in cost above 64
 MAX_CHUNK = 64
+#: tokens of one chunk under a decay a key channel, at most:
+#: :func:`_chunk_channel` takes ``exp(-G)`` of the running sum of ``g``,
+#: which stays inside float32 only while ``C * max |g| < 88``.  16 is what
+#: a gate bounded below by -5 allows (KDA's ``kda_lower_bound``)
+MAX_CHANNEL_CHUNK = 16
 #: the shortest chunk the prefill kernel takes (a sublane tile of its
 #: transposes); a shorter row bucket is walked token by token
 MIN_CHUNK = 8
@@ -83,11 +99,11 @@ _NT = (((1,), (1,)), ((), ()))          # a @ b^T
 _TN = (((0,), (0,)), ((), ()))          # a^T @ b
 
 
-def chunk_len(Q: int) -> int:
+def chunk_len(Q: int, most: int = MAX_CHUNK) -> int:
     """Tokens a chunk of the matrix form takes of a row bucket of ``Q``:
-    the largest power of two up to :data:`MAX_CHUNK` that divides it."""
+    the largest power of two up to ``most`` that divides it."""
     C = 1
-    while C * 2 <= MAX_CHUNK and Q % (C * 2) == 0:
+    while C * 2 <= most and Q % (C * 2) == 0:
         C *= 2
     return C
 
@@ -97,6 +113,15 @@ def _lane_groups(heads: int, dv: int) -> list:
     tiles, ascending; ``[heads]`` (the whole minor dim) where none is."""
     return [n for n in range(1, heads)
             if heads % n == 0 and (n * dv) % 128 == 0] or [heads]
+
+
+def _decode_group(heads: int, dv: int) -> int:
+    """Heads of one lane group of the decode kernel: the smallest of
+    :func:`_lane_groups` wider than one lane tile where there is one (the
+    chip's compiler refuses the kernel's dynamic one-row loads beside a
+    group of exactly 128 lanes: "dynamic load with unaligned indices")."""
+    groups = _lane_groups(heads, dv)
+    return next((n for n in groups if n * dv > 128), groups[0])
 
 
 def delta_rule_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
@@ -114,7 +139,9 @@ def delta_rule_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
 
     def step(s, inp):
         q_t, k_t, v_t, g_t, b_t = inp     # [S,H,dk] x2, [S,H,dv], [S,H] x2
-        s = s * jnp.exp(g_t)[:, None, :, None]
+        # [S, H]: one decay a head; [S, H, dk]: one a key channel (KDA)
+        s = s * (jnp.exp(g_t)[:, None, :, None] if g_t.ndim == 2
+                 else jnp.exp(g_t).swapaxes(1, 2)[..., None])
         u = b_t[..., None] * (v_t - ein("shk,skhv->shv", k_t, s))
         s = s + ein("shk,shv->skhv", k_t, u)
         return s, ein("shk,skhv->shv", q_t, s)
@@ -127,6 +154,16 @@ def delta_rule_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
                 s.reshape(S, dk, H * dv).astype(state_pool.dtype)),
             conv_pool.at[layer, slots].set(
                 new_tail.reshape((-1,) + conv_pool.shape[2:])))
+
+
+def _unit_lower_inverse(n, diagonal):
+    """``(I - N)^-1 = (I + N)(I + N^2)(I + N^4)...`` for a strictly lower
+    triangular ``n`` ``[C, C]`` (``diagonal``: the mask ``r == c``)."""
+    t, p = jnp.where(diagonal, 1.0, n), n
+    for _ in range(max(n.shape[0].bit_length() - 2, 0)):
+        p = jnp.dot(p, p, **_HP)
+        t = t + jnp.dot(t, p, **_HP)
+    return t
 
 
 def _chunk(s0, q, k, v, g_row, b_row, g_col, b_col):
@@ -143,11 +180,8 @@ def _chunk(s0, q, k, v, g_row, b_row, g_col, b_col):
     kk = jax.lax.dot_general(k, k, _NT, **_HP)
     qk = jax.lax.dot_general(q, k, _NT, **_HP)
     # (I + A)^-1 = (I + N)(I + N^2)(I + N^4)..., N = -A strictly lower
-    n = jnp.where(r > c, -(b_col * ratio) * kk, 0.0)
-    t, p = jnp.where(r == c, 1.0, n), n
-    for _ in range(max(C.bit_length() - 2, 0)):
-        p = jnp.dot(p, p, **_HP)
-        t = t + jnp.dot(t, p, **_HP)
+    t = _unit_lower_inverse(
+        jnp.where(r > c, -(b_col * ratio) * kk, 0.0), r == c)
     decay = jnp.exp(g_col)
     u = jnp.dot(t, b_col * (v - decay * jnp.dot(k, s0, **_HP)), **_HP)
     o = decay * jnp.dot(q, s0, **_HP) \
@@ -157,6 +191,38 @@ def _chunk(s0, q, k, v, g_row, b_row, g_col, b_col):
     g_last = g_row[:, C - 1:C]
     s_new = jnp.exp(jnp.broadcast_to(g_last, (1, s0.shape[1]))) * s0 \
         + jax.lax.dot_general(k * jnp.exp(g_last - g_col), u, _TN, **_HP)
+    return o, s_new
+
+
+def _chunk_channel(s0, q, k, v, G, b_col):
+    """:func:`_chunk` under a decay a KEY CHANNEL (KDA): ``G`` ``[C, dk]``
+    the running sum of ``g = log alpha`` inside the chunk.  The ratios
+    ``gamma_t / gamma_i`` differ by channel, so they move INSIDE the
+    products: with ``K+ = K e^G``, ``K- = K e^-G``, ``Q+ = Q e^G``::
+
+        A = diag(beta) tril(K+ K-^T, -1)
+        (I + A) U = diag(beta) (V - K+ S_0)
+        O   = Q+ S_0 + tril(Q+ K-^T) U
+        S_C = Diag(e^{G_C}) S_0 + (K- e^{G_C})^T U
+
+    ``e^-G`` is why a chunk is :data:`MAX_CHANNEL_CHUNK` tokens at most; a
+    masked product above the diagonal is at most ``dk e^{|G_C|}``, finite
+    there too."""
+    C = q.shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    grow, shrink = jnp.exp(G), jnp.exp(-G)
+    kp, km, qp = k * grow, k * shrink, q * grow
+    kk = jax.lax.dot_general(kp, km, _NT, **_HP)
+    qk = jax.lax.dot_general(qp, km, _NT, **_HP)
+    t = _unit_lower_inverse(jnp.where(r > c, -b_col * kk, 0.0), r == c)
+    u = jnp.dot(t, b_col * (v - jnp.dot(kp, s0, **_HP)), **_HP)
+    o = jnp.dot(qp, s0, **_HP) \
+        + jnp.dot(jnp.where(r >= c, qk, 0.0), u, **_HP)
+    # e^{G_C} a key channel: a column over the state's rows
+    last = jnp.broadcast_to(G.T[:, C - 1:C], s0.shape)
+    s_new = jnp.exp(last) * s0 + jax.lax.dot_general(
+        km * grow[C - 1:C], u, _TN, **_HP)
     return o, s_new
 
 
@@ -182,18 +248,22 @@ def delta_chunk_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
     f32 = jnp.float32
     S, Q, H, dk = q.shape
     dv = v.shape[-1] // H
-    C = chunk_len(Q)
+    channel = g.ndim == 4
+    C = chunk_len(Q, MAX_CHANNEL_CHUNK if channel else MAX_CHUNK)
     s0 = state_pool[layer, slots].astype(f32).reshape(S, dk, H, dv)
     s0 = jnp.where(fresh[:, None, None, None], 0.0, s0).transpose(0, 2, 1, 3)
-    rows = _chunk_rows(g, beta, C)                       # [S, H, n, 8, C]
+    rows = _chunk_rows(jnp.zeros_like(beta) if channel else g, beta, C)
 
     def chunks(a):                                       # [S,Q,H,x]->[n,S,H,C,x]
         return a.astype(f32).reshape(S, Q // C, C, H, -1).transpose(
             1, 0, 3, 2, 4)
 
-    def one(s, q_, k_, v_, rc):
-        o, s = _chunk(s, q_, k_, v_, rc[0:1], rc[1:2], rc[0][:, None],
-                      rc[1][:, None])
+    def one(s, q_, k_, v_, rc, G=None):
+        if channel:
+            o, s = _chunk_channel(s, q_, k_, v_, G, rc[1][:, None])
+        else:
+            o, s = _chunk(s, q_, k_, v_, rc[0:1], rc[1:2], rc[0][:, None],
+                          rc[1][:, None])
         return s, o
 
     def step(s, inp):
@@ -201,7 +271,8 @@ def delta_chunk_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
 
     s, o = jax.lax.scan(step, s0, (
         chunks(q), chunks(k), chunks(v.reshape(S, Q, H, dv)),
-        rows.transpose(2, 0, 1, 3, 4)))
+        rows.transpose(2, 0, 1, 3, 4))
+        + ((jnp.cumsum(chunks(g), axis=3),) if channel else ()))
     o = o.transpose(1, 0, 3, 2, 4).reshape(S, Q, H * dv)
     return (o, state_pool.at[layer, slots].set(
         s.transpose(0, 2, 1, 3).reshape(S, dk, H * dv).astype(
@@ -212,7 +283,7 @@ def delta_chunk_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
 
 def _decode_kernel(l_ref, slot_ref, fresh_ref, qT_ref, kT_ref, v_ref, a_ref,
                    b_ref, tail_ref, s_ref, conv_ref, o_ref, sout_ref,
-                   tout_ref, *, heads, dv, group):
+                   tout_ref, *, heads, dv, group, channel=False):
     """One row: its whole state ``[dk, H * dv]`` read, stepped once and
     written back to the same address (the pool is aliased input ->
     output).  The state is walked in lane groups of ``group`` heads (whole
@@ -220,7 +291,9 @@ def _decode_kernel(l_ref, slot_ref, fresh_ref, qT_ref, kT_ref, v_ref, a_ref,
     key and query columns ``[dk, 1]`` are spread over its ``dv`` lanes by a
     select, and the two contractions over ``dk`` are sublane sums.  ``v``,
     ``alpha`` and ``beta`` come spread over the lanes already, as ``[8,
-    H * dv]`` blocks of 8 rows (``ops/ssm.py``'s decode form)."""
+    H * dv]`` blocks of 8 rows (``ops/ssm.py``'s decode form); under
+    ``channel`` (a decay a key channel, KDA) ``alpha`` comes as the keys
+    do, ``[dk, H]``, and scales the state's rows one by one."""
     del l_ref, slot_ref, conv_ref
     s = pl.program_id(0)
     tout_ref[...] = tail_ref[...]
@@ -240,7 +313,8 @@ def _decode_kernel(l_ref, slot_ref, fresh_ref, qT_ref, kT_ref, v_ref, a_ref,
         lanes = slice(p * width, (p + 1) * width)
         row = (pl.ds(r, 1), lanes)
         st = s_ref[:, lanes].astype(jnp.float32)
-        st = jnp.where(fresh, jnp.zeros_like(st), st) * a_ref[row]
+        st = jnp.where(fresh, jnp.zeros_like(st), st) * (
+            spread(a_ref, p * group) if channel else a_ref[row])
         key = spread(kT_ref, p * group)
         u = b_ref[row] * (v_ref[row]
                           - jnp.sum(key * st, axis=0, keepdims=True))
@@ -254,7 +328,9 @@ def delta_state_update_decode(state_pool, conv_pool, layer, slots, fresh, q,
                               k, v, g, beta, new_tail, *,
                               interpret: bool = False):
     """Pallas form of :func:`delta_rule_reference` at ``Q = 1``, in
-    place."""
+    place; under a decay a key channel (``g`` ``[S, 1, H, dk]``) the kernel
+    is named ``kda_state_update_decode``."""
+    channel = g.ndim == 4
     S, _, H, dk = q.shape
     W = v.shape[-1]
     dv = W // H
@@ -274,10 +350,10 @@ def delta_state_update_decode(state_pool, conv_pool, layer, slots, fresh, q,
                         lambda s, l, sl, fr: (l[0], sl[s], 0, 0))
     o, state_pool, conv_pool = pl.pallas_call(
         functools.partial(_decode_kernel, heads=H, dv=dv,
-                          group=_lane_groups(H, dv)[0]),
+                          group=_decode_group(H, dv), channel=channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(S,),
-            in_specs=[cols, cols, token, token, token,
+            in_specs=[cols, cols, token, cols if channel else token, token,
                       pl.BlockSpec((None, rows, width),
                                    lambda s, l, sl, fr: (s, 0, 0)),
                       state, pl.BlockSpec(memory_space=pl.ANY)],
@@ -290,28 +366,34 @@ def delta_state_update_decode(state_pool, conv_pool, layer, slots, fresh, q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         # ``^delta_`` finds both kernels and no pattern of the attention,
-        # cache-write or state-space kernels does (benchmark/metrics)
-        name="delta_state_update_decode",
+        # cache-write or state-space kernels does (benchmark/metrics);
+        # ``^kda_`` the two under a decay a key channel
+        name="kda_state_update_decode" if channel
+        else "delta_state_update_decode",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
       fresh.astype(jnp.int32), q[:, 0].astype(f32).swapaxes(1, 2),
       k[:, 0].astype(f32).swapaxes(1, 2), v.astype(f32).reshape(S, W),
-      lanes(jnp.exp(g.astype(f32))), lanes(beta),
+      jnp.exp(g[:, 0].astype(f32)).swapaxes(1, 2) if channel
+      else lanes(jnp.exp(g.astype(f32))), lanes(beta),
       new_tail.astype(conv_pool.dtype).reshape(S, rows, width), state_pool,
       conv_pool)
     return o.reshape(S, 1, W), state_pool, conv_pool
 
 
 def _prefill_kernel(l_ref, slot_ref, fresh_ref, q_ref, k_ref, v_ref,
-                    rows_ref, tail_ref, s_ref, conv_ref, o_ref, sout_ref,
-                    tout_ref, *, heads, dv):
+                    rows_ref, *rest, heads, dv):
     """One (row, block of ``heads`` heads, chunk) grid step: the chunk's
     matrix form (:func:`_chunk`) a head, from and to the block's state in
     ``sout_ref``, which stays in VMEM across the row's chunks (the
     innermost grid dim) and goes back to the row's slot after the last.
     The first chunk takes the state from the slot, or zeros for a fresh
-    row."""
-    del l_ref, slot_ref, conv_ref
+    row.  ``rest``: (tail, state, conv pool; o, state, tail out), behind
+    the running sums ``G`` ``[heads, C, dk]`` under a decay a key channel
+    (:func:`_chunk_channel`)."""
+    del l_ref, slot_ref
+    g_ref = rest[0] if len(rest) == 7 else None
+    tail_ref, s_ref, _, o_ref, sout_ref, tout_ref = rest[-6:]
     s, j, c = (pl.program_id(i) for i in range(3))
 
     @pl.when(c == 0)
@@ -327,9 +409,14 @@ def _prefill_kernel(l_ref, slot_ref, fresh_ref, q_ref, k_ref, v_ref,
         lanes = slice(h * dv, (h + 1) * dv)
         rc = rows_ref[h]                                    # [8, C]
         cols = rc.T
-        o, st = _chunk(sout_ref[:, lanes].astype(jnp.float32), q_ref[h],
-                       k_ref[h], v_ref[:, lanes], rc[0:1], rc[1:2],
-                       cols[:, 0:1], cols[:, 1:2])
+        if g_ref is None:
+            o, st = _chunk(sout_ref[:, lanes].astype(jnp.float32), q_ref[h],
+                           k_ref[h], v_ref[:, lanes], rc[0:1], rc[1:2],
+                           cols[:, 0:1], cols[:, 1:2])
+        else:
+            o, st = _chunk_channel(
+                sout_ref[:, lanes].astype(jnp.float32), q_ref[h], k_ref[h],
+                v_ref[:, lanes], g_ref[h], cols[:, 1:2])
         o_ref[:, lanes] = o
         sout_ref[:, lanes] = st.astype(sout_ref.dtype)
 
@@ -337,13 +424,16 @@ def _prefill_kernel(l_ref, slot_ref, fresh_ref, q_ref, k_ref, v_ref,
 def delta_chunk_prefill(state_pool, conv_pool, layer, slots, fresh, q, k, v,
                         g, beta, new_tail, *, interpret: bool = False):
     """Pallas form of :func:`delta_rule_reference` at ``Q > 1``: the
-    chunked matrix form, in place."""
+    chunked matrix form, in place; under a decay a key channel (``g``
+    ``[S, Q, H, dk]``) the kernel is named ``kda_chunk_prefill`` and a
+    chunk is :data:`MAX_CHANNEL_CHUNK` tokens at most."""
+    channel = g.ndim == 4
     S, Q, H, dk = q.shape
     W = v.shape[-1]
     dv = W // H
     rows, width = conv_pool.shape[2:]
     f32 = jnp.float32
-    C = chunk_len(Q)
+    C = chunk_len(Q, MAX_CHANNEL_CHUNK if channel else MAX_CHUNK)
     assert C >= MIN_CHUNK, (Q, C)
     groups = _lane_groups(H, dv)
     hb = max([n for n in groups if n <= MAX_HEAD_BLOCK] or groups[:1])
@@ -364,6 +454,7 @@ def delta_chunk_prefill(state_pool, conv_pool, layer, slots, fresh, q, k, v,
                       pl.BlockSpec((None, hb, None, 8, C),
                                    lambda s, j, c, l, sl, fr:
                                    (s, j, c, 0, 0)),
+                      *([heads] if channel else []),
                       pl.BlockSpec((None, rows, width),
                                    lambda s, j, c, l, sl, fr: (s, 0, 0)),
                       state, pl.BlockSpec(memory_space=pl.ANY)],
@@ -371,15 +462,19 @@ def delta_chunk_prefill(state_pool, conv_pool, layer, slots, fresh, q, k, v,
         out_shape=[jax.ShapeDtypeStruct((S, Q, W), f32),
                    jax.ShapeDtypeStruct(state_pool.shape, state_pool.dtype),
                    jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)],
-        # operands count the 3 prefetched
-        input_output_aliases={8: 1, 9: 2},
+        # operands count the 3 prefetched (and the running sums)
+        input_output_aliases={9: 1, 10: 2} if channel else {8: 1, 9: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3),
-        name="delta_chunk_prefill",
+        name="kda_chunk_prefill" if channel else "delta_chunk_prefill",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
       fresh.astype(jnp.int32), q.astype(f32).swapaxes(1, 2),
-      k.astype(f32).swapaxes(1, 2), v.astype(f32), _chunk_rows(g, beta, C),
+      k.astype(f32).swapaxes(1, 2), v.astype(f32),
+      *((_chunk_rows(jnp.zeros_like(beta), beta, C), jnp.cumsum(
+          g.astype(f32).reshape(S, Q // C, C, H, dk), axis=2).reshape(
+              S, Q, H, dk).swapaxes(1, 2)) if channel
+        else (_chunk_rows(g, beta, C),)),
       new_tail.astype(conv_pool.dtype).reshape(S, rows, width), state_pool,
       conv_pool)
     return o, state_pool, conv_pool
@@ -401,7 +496,9 @@ def delta_rule(state_pool: jax.Array, conv_pool: jax.Array, layer,
     fresh  : [S] bool, the row starts from a zero state
     q, k   : [S, Q, H, dk], l2-normalised (``q`` scaled by dk ** -0.5)
     v      : [S, Q, H * dv]
-    g      : [S, Q, H] = log alpha <= 0, 0 at padded positions
+    g      : [S, Q, H] = log alpha <= 0, 0 at padded positions; or [S,
+             Q, H, dk], one decay a KEY CHANNEL (KDA: the kernels
+             ``kda_*``), then >= -88 / :data:`MAX_CHANNEL_CHUNK` a token
     beta   : [S, Q, H], 0 at padded positions
     new_tail : [S, K - 1, channels], ``ops/ssm.py::conv_step``'s
     Returns (o [S, Q, H * dv] float32, the updated state pool, the updated

@@ -1610,3 +1610,142 @@ def test_smallthinker_step_program_moves_no_pool_and_no_expert_stack(
     assert pool_sized_movers(text, layer_bytes) == []
     assert stack_shaped_movers(text, params) == []
     assert compiled.memory_analysis().temp_size_in_bytes < expert_layer
+
+
+# -- the Kimi-delta (KDA) family: its two kernels and its step programs ------
+
+KDA_LAYERS, KDA_HEADS, KDA_D = 6, 32, 128
+KDA_CHANNELS = 3 * KDA_HEADS * KDA_D
+
+
+def _kda_pools(chip):
+    return (chip((KDA_LAYERS, SSM_SLOTS + 1, KDA_D, KDA_HEADS * KDA_D),
+                 jnp.float32),
+            chip((KDA_LAYERS, SSM_SLOTS + 1, 8, 3 * KDA_CHANNELS // 8),
+                 jnp.bfloat16))
+
+
+@pytest.mark.parametrize("rows,q,kernel", [
+    (256, 1, "kda_state_update_decode"), (4, 128, "kda_chunk_prefill"),
+    (1, 1024, "kda_chunk_prefill"), (4, 8, "kda_chunk_prefill")])
+def test_kda_kernels(chip, rows, q, kernel):
+    """The delta rule's kernels under a decay a KEY CHANNEL at the published
+    widths (32 heads of [128, 128]): the update kernel (a row's whole [128,
+    4096] float32 state a grid step, walked in lane groups of two heads: a
+    group of one lane tile does not compile) and the chunked kernel (8
+    heads and one chunk of 16 a grid step), under names of their own, both
+    pools aliased in -> out."""
+    from deepspeed_tpu.ops.delta_rule import (MAX_CHANNEL_CHUNK, _decode_group,
+                                              chunk_len, delta_rule)
+    f32 = jnp.float32
+    assert [chunk_len(n, MAX_CHANNEL_CHUNK) for n in (1, 8, 128, 1024, 96)] \
+        == [1, 8, 16, 16, 16]
+    assert _decode_group(KDA_HEADS, KDA_D) == 2
+    assert _decode_group(DELTA_HEADS, DELTA_DV) == 2    # as it always was
+    state, conv = _kda_pools(chip)
+    heads = (rows, q, KDA_HEADS, KDA_D)
+    compile_for_chip(
+        lambda state, conv, layer, slots, fresh, q_, k, v, g, beta, tail:
+        delta_rule(state, conv, layer, slots, fresh, q_, k, v, g, beta,
+                   tail, use_kernel=True),
+        state, conv, chip((), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.bool_), chip(heads, f32), chip(heads, f32),
+        chip((rows, q, KDA_HEADS * KDA_D), f32), chip(heads, f32),
+        chip((rows, q, KDA_HEADS), f32),
+        chip((rows, 3, KDA_CHANNELS), jnp.bfloat16), kernel=kernel)
+
+
+#: the window's two programs: a chained decode step, and a mixed step (256
+#: decode rows and the lattice's 4-row prompt segment: both KDA kernels,
+#: both latent writes, the latent decode and prefill kernels at 32 heads,
+#: the held experts); and one of the PROBE's own, a plain forward formed
+#: under ``routing_sink`` (a host callback a routed layer)
+LING_STEP_KEYS = {
+    "chain-p40": (256, 1, 40, False, "chain", 256, True),
+    "mixed-p40": (256, 1, 40, False, "mixed", 4, 128, 8, True, True),
+    "probe-p40": (256, 1, 40, False),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(LING_STEP_KEYS))
+def test_ling_step_program_moves_no_pool_and_no_weight_stack(
+        chip, monkeypatch, kind):
+    """The benchmark's cell at published widths (the dense KDA layer and
+    one period: KDA x 3, the latent layer, KDA x 2; 32 of 512 experts held):
+    the step programs lower for the chip with a latent page pool AND a state
+    pool in one carry, the KDA kernels run under their own names, and
+    nothing the size of a layer of the conv pool (18.9 MB), let alone of
+    the 3.2 GB state pool, the latent pool or a layer of the held experts'
+    stack, is copied, sliced out or re-laid out."""
+    import dataclasses
+    import json
+    import os
+
+    from flax.core import meta
+
+    from benchmark.builders.serve_bailing_hybrid import source_of
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        BailingHybridInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.bailing_hybrid import BailingHybridForCausalLM
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ling-3.0-flash-serve-7l-ep16.json")) as f:
+        config = json.load(f)
+    model = BailingHybridForCausalLM(source_of(config, False),
+                                     first_layer=config["first_layer"])
+    assert model.cfg.layer_kinds == ("kda",) * 4 + ("latent", "kda", "kda")
+    params = jax.eval_shape(lambda k: meta.unbox(model.init_params(k)),
+                            jax.random.key(0))
+    pages = 1024
+    serve = BailingHybridInferenceModel(
+        model.cfg, params, kv_config=KVCacheConfig(
+            num_layers=1, kv_heads=1, head_dim=MLA_PLANE, planes=1,
+            page_size=PAGE, num_pages=pages))
+    serve.state_config = dataclasses.replace(serve.state_config,
+                                             num_slots=SSM_SLOTS)
+    assert serve.pool_names == ("pages", "state", "conv")
+    pool = (_latent_pool(chip, 1, pages), *_kda_pools(chip))
+    assert [tuple(a.shape) for a in pool[1:]] \
+        == list(serve.state_config.shapes())
+    key = StepKey.parse(LING_STEP_KEYS[kind])
+    heard = []
+    if kind.startswith("probe"):
+        serve.routing_sink = heard.append
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        step_avals(serve, key, pool))
+    assert (key.S, key.P + 1) in [a.shape for a in avals[2:]]
+    compiled = jax.jit(step_program(serve, key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    text = compiled.as_text()
+    # the record of the routing is in the probe's programs and in no other
+    assert ("xla_ffi_python_cpu_callback" in text or "host" in text.lower()
+            and "callback" in text.lower()) == kind.startswith("probe"), kind
+    kernels = ["kda_state_update_decode", "mla_attention_decode",
+               "latent_write_decode", "moe_expert_ffn"]
+    if key.kind == "mixed":
+        kernels += ["kda_chunk_prefill", "latent_write_prefill"]
+    for kernel in kernels:
+        assert kernel_calls(text, kernel), kernel
+    assert not kernel_calls(text, "delta_")
+    assert set(scoped_vmem_asked(text, "kda_")) == {""}
+    # the smallest thing that must not move: one layer of the conv pool
+    conv_layer = (SSM_SLOTS + 1) * 3 * KDA_CHANNELS * 2
+    expert_layer = 32 * 3 * 768 * 2560 * 2
+    assert conv_layer < expert_layer
+    # what the chip's compiler re-lays out for the 768-token products of a
+    # mixed step (and not for a decode step's 256): two thirds of a KDA
+    # layer's w_qkv and its w_f / w_out, 42 and 31 MB, six layers: 0.44 GB
+    # a mixed step, counted in PERF.md; never a pool, never the experts
+    relaid = ("bf16[8192,2560]", "bf16[768,8,2560]") \
+        if key.kind == "mixed" else ()
+    moved = [m for m in pool_sized_movers(text, conv_layer)
+             # ONE period's layer taken out of its stack of one period
+             if not m[2].startswith("bf16[1,") and m[2] not in relaid]
+    assert moved == [], moved
+    assert stack_shaped_movers(text, params) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < expert_layer

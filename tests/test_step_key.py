@@ -318,20 +318,24 @@ def _layer_kinds():
 
 @pytest.mark.parametrize("kind", _layer_kinds())
 def test_every_layer_kind_has_a_mixer_and_every_mixer_a_known_kind(kind):
-    """``model.py::MIXERS`` has the keys of ``CACHE_KINDS`` plus the latent
-    kind (whose plane lies in the one page pool), each entry names a method
-    of the model, and the pools it writes are the ones its kind caches in:
-    the state pool's two arrays, the window group's pool, or the first."""
+    """``model.py::MIXERS`` has the keys of ``CACHE_KINDS`` (the latent kind
+    among them since PR 50: its plane lies in the one page group's pool),
+    each entry names a method of the model, and the pools it writes, BY
+    NAME (``RaggedInferenceModel.pool_names``), are the ones its kind caches
+    in: the state pool's two arrays, the window group's pool, or the
+    pages."""
     from deepspeed_tpu.inference.v2.model import MIXERS
     from deepspeed_tpu.inference.v2.ragged.cache_kinds import CACHE_KINDS
     assert kind in MIXERS and (kind in CACHE_KINDS or kind == "latent")
     mixer = MIXERS[kind]
     assert getattr(RaggedInferenceModel, mixer.run.__name__) is mixer.run
     cache = CACHE_KINDS.get(kind)
-    assert mixer.pools == ((1, 2) if cache is not None and cache.slot else
-                           (1,) if cache is not None and cache.windowed
-                           else (0,))
-    assert mixer.weights == ("mixer" if mixer.pools == (1, 2) else "attn")
+    assert mixer.pools == (("state", "conv") if cache is not None
+                           and cache.slot else
+                           ("window",) if cache is not None and cache.windowed
+                           else ("pages",))
+    assert mixer.weights == ("mixer" if mixer.pools == ("state", "conv")
+                             else "attn")
 
 
 def test_the_step_program_imports_no_family_by_name():
