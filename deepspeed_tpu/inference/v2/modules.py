@@ -156,8 +156,6 @@ def _fresh_flash(cfg):
     [Q, C] score materialization (reference blocked_flash prefill atoms,
     inference/v2/kernels/ragged_ops/).  Off-TPU the kernel falls back to
     the dense reference with identical semantics."""
-    import jax.numpy as jnp
-
     from ...ops.flash_attention import flash_attention
     window = getattr(cfg, "sliding_window", None)
     block_q = getattr(cfg, "flash_block_q", 512)
@@ -167,10 +165,6 @@ def _fresh_flash(cfg):
         qf = q.transpose(0, 2, 1, 3)        # [S, H, Q, D]
         kf = k_rot.transpose(0, 2, 1, 3)    # [S, K, Q, D]
         vf = v.transpose(0, 2, 1, 3)
-        groups = qf.shape[1] // kf.shape[1]
-        if groups > 1:
-            kf = jnp.repeat(kf, groups, axis=1)
-            vf = jnp.repeat(vf, groups, axis=1)
         out = flash_attention(qf, kf, vf, causal=True, window=window,
                               block_q=block_q, block_k=block_k)
         return out.transpose(0, 2, 1, 3)

@@ -515,8 +515,8 @@ def flash_dot_product_attention(cfg: TransformerConfig, q, kv_k, kv_v) -> jax.Ar
     fused attention kernels (csrc/transformer/ softmax+attention CUDA) on
     the training path: no [B,H,S,S] score tensor ever reaches HBM.
 
-    GQA folds kv heads up to H per shard.  Under a >1-device mesh the
-    kernel runs inside shard_map (batch over the batch axes, heads over
+    K/V reach the kernel at their own head count (GQA).  Under a >1-device
+    mesh the kernel runs inside shard_map (batch over the batch axes, heads over
     'seq'+'tensor' — the Ulysses layout), since GSPMD cannot partition a
     pallas_call on its own.
     """
@@ -527,10 +527,8 @@ def flash_dot_product_attention(cfg: TransformerConfig, q, kv_k, kv_v) -> jax.Ar
     vf = kv_v.transpose(0, 2, 1, 3)
 
     def per_shard(qs, ks, vs):
-        groups = qs.shape[1] // ks.shape[1]
-        if groups > 1:
-            ks = jnp.repeat(ks, groups, axis=1)
-            vs = jnp.repeat(vs, groups, axis=1)
+        # K/V at their own head count: the kernels read a query head's
+        # K/V by head // groups and sum dK / dV over a group
         return flash_attention(qs, ks, vs, causal=True,
                                block_q=cfg.flash_block_q,
                                block_k=cfg.flash_block_k,

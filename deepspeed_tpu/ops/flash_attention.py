@@ -6,10 +6,16 @@ TPU-native replacement for the reference's fused attention kernels
 blockwise softmax with running max/denominator so the S x S score matrix
 never materializes in HBM.
 
-Layout: q, k, v are [B, H, S, D] (callers fold GQA groups into H).
-Causal masking skips fully-masked k-blocks.  Backward is the standard
-two-kernel flash backward (dkv sweep over q-blocks, dq sweep over
-k-blocks) with the delta = rowsum(dO * O) precomputation.
+Layout: q is [B, H, S, D], k and v are [B, K, S, D] at their own head
+count (H % K == 0): the kernels index a query head's K/V block by
+``head // groups`` and sum dK / dV over a group in float32, so no caller
+repeats K and V.  Causal / sliding-window masking skips fully-masked
+blocks and builds the mask only where the band's edge crosses a block: a
+row of up to ``UNROLL_BLOCKS`` blocks has its pairs written out, a longer
+one walks in one loop (``_each_block``, ``_walk``).  Backward is the
+two-kernel flash backward (dkv sweep over the q blocks of a k block and
+over the group's query heads, dq sweep over k blocks) with the
+``delta = rowsum(dO * O)`` precomputation.
 
 On a TPU the kernel is the only path (a lowering error propagates);
 on CPU (the tests) the public entry point selects the jnp reference
@@ -58,6 +64,105 @@ def _band_keep(q_idx_base, k_idx_base, block_q, block_k, causal, window,
     return keep
 
 
+def _band_inside(q_idx_base, k_idx_base, block_q, block_k, causal, window):
+    """Whether ``_band_keep`` of the block at these (static) bases is all
+    true: its first row may see its last column, and its last row still
+    has its first column in the window."""
+    inside = True
+    if causal:
+        inside &= q_idx_base >= k_idx_base + block_k - 1
+    if window is not None:
+        inside &= q_idx_base + block_q - 1 - k_idx_base < window
+    return inside
+
+
+#: A walk of at most this many blocks is WRITTEN OUT (``_each_block``): on the
+#: chip a loop's body does not overlap what stands around it, and the same
+#: pairs in straight line run the forward a third faster (PERF.md, PR 51);
+#: 4 blocks are at most 10 causal pairs a kernel, and more is program text
+#: that every step program which carries the kernel compiles.
+UNROLL_BLOCKS = 4
+
+
+def _each_block(idx, blocks, emit):
+    """``emit(idx)`` for a kernel whose grid axis of ``blocks`` blocks is at
+    ``idx``: up to UNROLL_BLOCKS, ``emit(i)`` with ``i`` a Python int under
+    ``idx == i``, so that the block's walk has static bounds and
+    ``_walk`` writes it out; beyond, ``emit`` of the traced index."""
+    if blocks == 1:
+        emit(0)
+    elif blocks <= UNROLL_BLOCKS:
+        for i in range(blocks):
+            pl.when(idx == i)(functools.partial(emit, i))
+    else:
+        emit(idx)
+
+
+def _k_range(q_idx, block_q, block_k, seq_k, causal, window):
+    """The k blocks ``lo .. hi`` that some row of q block ``q_idx`` may see
+    (Python ints for a static index): up to the block the diagonal
+    crosses, from the block in which row 0's window starts (blocks under it
+    are fully masked and skipped: the flash win for long sliding-window
+    rows)."""
+    static = isinstance(q_idx, int)
+    lo, hi = (0 if static else jnp.int32(0)), pl.cdiv(seq_k, block_k)
+    if causal:
+        rows = (q_idx + 1) * block_q
+        hi = (min if static else jnp.minimum)(
+            hi, rows // block_k + (rows % block_k != 0))
+    if window is not None:
+        lo = (max if static else jnp.maximum)(
+            lo, (q_idx * block_q - window + 1) // block_k)
+    return lo, hi
+
+
+def _q_range(k_idx, block_q, block_k, seq_q, causal, window):
+    """``_k_range`` for the q blocks that see k block ``k_idx``: from the
+    first on or under its diagonal to the one that holds the last row with
+    its first column in the window (k_pos_max + window - 1)."""
+    static = isinstance(k_idx, int)
+    lo, hi = (0 if static else jnp.int32(0)), pl.cdiv(seq_q, block_q)
+    if causal:
+        lo = (k_idx * block_k) // block_q
+    if window is not None:
+        last = k_idx * block_k + block_k - 1 + window - 1
+        hi = (min if static else jnp.minimum)(hi, last // block_q + 1)
+    return lo, hi
+
+
+def _walk(body, lo, hi, carry, inside, traced):
+    """``body(masked, i, carry)`` over the blocks ``lo .. hi`` in rising
+    order, the mask built only where the band's edge crosses a block.
+
+    Static bounds (``_each_block``): written out, block ``i`` masked unless
+    ``inside(i)``.  Traced bounds follow ``traced`` (``_traced_masks``):
+    "first" / "last": that block is the ONE of the walk that the diagonal
+    crosses; the others lie wholly inside the band and run bare in one
+    loop, and the diagonal's block is masked in straight line outside it
+    (a loop of its own costs more than the masks it saves).  A bool: one
+    loop that masks every block, or none."""
+    if isinstance(lo, int) and isinstance(hi, int):
+        for i in range(lo, hi):
+            carry = body(not inside(i), i, carry)
+        return carry
+    bare = functools.partial(body, False)
+    if traced == "first":
+        return jax.lax.fori_loop(lo + 1, hi, bare, body(True, lo, carry))
+    if traced == "last":
+        return body(True, hi - 1, jax.lax.fori_loop(lo, hi - 1, bare, carry))
+    return jax.lax.fori_loop(lo, hi, functools.partial(body, traced), carry)
+
+
+def _traced_masks(side, block_q, block_k, causal, window):
+    """``_walk``'s ``traced`` for a walk whose diagonal block comes ``side``
+    ("first" / "last"): ``side`` where the band is the causal one alone and
+    the blocks are square, else whether there is a band at all (a window
+    that binds, unlike blocks: every block masked, as before PR 51)."""
+    if causal and window is None and block_q == block_k:
+        return side
+    return causal or window is not None
+
+
 # ---------------------------------------------------------------------------
 # reference (and CPU fallback)
 # ---------------------------------------------------------------------------
@@ -66,8 +171,12 @@ def mha_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
                   window: Optional[int] = None):
     """[B,H,S,D] attention in fp32 softmax — semantics ground truth.
     ``window``: sliding-window size incl. self (HF Mistral semantics:
-    position t attends to (t - window, t])."""
+    position t attends to (t - window, t]).  K and V of fewer heads are
+    repeated to the query heads (GQA: head h reads K/V head h // groups)."""
     d = q.shape[-1]
+    groups = q.shape[1] // k.shape[1]
+    if groups > 1:
+        k, v = (jnp.repeat(x, groups, axis=1) for x in (k, v))
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(d)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     s_q, s_k = scores.shape[-2:]
@@ -89,72 +198,69 @@ def mha_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
-                block_k, seq_k, window):
-    q_idx = pl.program_id(2)
+                block_k, seq_k, blocks_q, window):
     block_q = q_ref.shape[0]
     d = q_ref.shape[1]
     q = q_ref[:]  # [block_q, d]
+    band = (block_q, block_k, causal, window)
 
-    num_k = pl.cdiv(seq_k, block_k)
-    if causal:
-        # highest k block that intersects this q block's diagonal
-        num_k = jnp.minimum(num_k, (q_idx + 1) * block_q // block_k
-                            + ((q_idx + 1) * block_q % block_k != 0))
-    k_lo = jnp.int32(0)
-    if window is not None:
-        # first k block any row of this q block can see: row 0's window
-        # start is q_idx*block_q - window + 1 (blocks below it are fully
-        # masked and skipped — the flash win for long sliding-window seqs)
-        k_lo = jnp.maximum(
-            jnp.int32(0), (q_idx * block_q - window + 1) // block_k)
+    def block(q_idx):
+        def body(masked, ki, carry):
+            m_prev, l_prev, acc = carry
+            k = k_ref[pl.ds(ki * block_k, block_k), :]  # [block_k, d]
+            v = v_ref[pl.ds(ki * block_k, block_k), :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
+            if masked:
+                s = jnp.where(_band_keep(q_idx * block_q, ki * block_k, *band),
+                              s, DEFAULT_MASK_VALUE)
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc
 
-    m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+        m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((block_q, 1), jnp.float32)
+        acc0 = jnp.zeros((block_q, d), jnp.float32)
+        m, l, acc = _walk(
+            body, *_k_range(q_idx, block_q, block_k, seq_k, causal, window),
+            (m0, l0, acc0),
+            lambda ki: _band_inside(q_idx * block_q, ki * block_k, *band),
+            _traced_masks("last", *band))
+        l = jnp.maximum(l, 1e-30)
+        o_ref[:] = (acc / l).astype(o_ref.dtype)
+        lse_ref[:] = (m + jnp.log(l)).T  # [1, block_q] lane-major row
 
-    def body(ki, carry):
-        m_prev, l_prev, acc = carry
-        k = k_ref[pl.ds(ki * block_k, block_k), :]  # [block_k, d]
-        v = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        if causal or window is not None:
-            s = jnp.where(_band_keep(q_idx * block_q, ki * block_k, block_q,
-                                     block_k, causal, window),
-                          s, DEFAULT_MASK_VALUE)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
-
-    m, l, acc = jax.lax.fori_loop(k_lo, num_k, body, (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[:] = (acc / l).astype(o_ref.dtype)
-    lse_ref[:] = (m + jnp.log(l)).T  # [1, block_q] lane-major row
+    _each_block(pl.program_id(2), blocks_q, block)
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
+    groups = h // k.shape[1]
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     grid = (b, h, pl.cdiv(s_q, block_q))
 
     kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                               block_k=block_k, seq_k=s_k, window=window)
+                               block_k=block_k, seq_k=s_k, blocks_q=grid[2],
+                               window=window)
+    # a query head's K/V block is its group's: consecutive heads of a group
+    # name the block the buffer holds, which is not fetched again
+    kv_spec = pl.BlockSpec((None, None, s_k, d),
+                           lambda bi, hi, qi: (bi, hi // groups, 0, 0))
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, None, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
+            kv_spec, kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((None, None, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
@@ -176,146 +282,172 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
 # ---------------------------------------------------------------------------
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, sm_scale, causal, block_q, seq_q,
-                    window):
-    k_idx = pl.program_id(2)
+                    dk_ref, dv_ref, *group_sum, sm_scale, causal, block_q,
+                    seq_q, blocks_k, window, groups):
+    """Grid (batch, KV head, k block, group member): dK and dV of a k block
+    summed over the q blocks on or under its diagonal and, the blocks
+    resident across the innermost axis, over the group's query heads, in
+    float32."""
+    member = pl.program_id(3)
     block_k = k_ref.shape[0]
     d = k_ref.shape[1]
     k = k_ref[:]
     v = v_ref[:]
+    band = (block_q, block_k, causal, window)
 
-    num_q = pl.cdiv(seq_q, block_q)
-    q0 = jnp.int32(0)
-    if causal:
-        q0 = (k_idx * block_k) // block_q  # first q block on/under diagonal
-    if window is not None:
-        # last q that sees this k block: k_pos_max + window - 1
-        q_hi_pos = k_idx * block_k + block_k - 1 + window - 1
-        num_q = jnp.minimum(num_q, q_hi_pos // block_q + 1)
+    def block(k_idx):
+        def body(masked, qi, carry):
+            # k-major (transposed) score tile [bk, bq]: the per-query lse /
+            # delta rows broadcast along sublanes and every matmul below is
+            # a plain (non-transposed-lhs) MXU product
+            dk, dv = carry
+            rows = pl.ds(qi * block_q, block_q)
+            q = q_ref[rows, :]
+            do = do_ref[rows, :]
+            lse = lse_ref[:, rows]      # [1, bq]
+            delta = delta_ref[:, rows]
+            st = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                st = jnp.where(_band_keep(qi * block_q, k_idx * block_k,
+                                          *band, k_major=True),
+                               st, DEFAULT_MASK_VALUE)
+            pt = jnp.exp(st - lse)  # [bk, bq]
+            dv = dv + jax.lax.dot_general(
+                pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            dst = pt * (dpt - delta) * sm_scale
+            dk = dk + jax.lax.dot_general(
+                dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return dk, dv
 
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
+        dk0 = jnp.zeros((block_k, d), jnp.float32)
+        dv0 = jnp.zeros((block_k, d), jnp.float32)
+        dk, dv = _walk(
+            body, *_q_range(k_idx, block_q, block_k, seq_q, causal, window),
+            (dk0, dv0),
+            lambda qi: _band_inside(qi * block_q, k_idx * block_k, *band),
+            _traced_masks("first", *band))
+        if groups == 1:
+            dk_ref[:] = dk.astype(dk_ref.dtype)
+            dv_ref[:] = dv.astype(dv_ref.dtype)
+            return
+        dk_acc, dv_acc = group_sum      # heads in rising order
 
-    def body(qi, carry):
-        # k-major (transposed) score tile [bk, bq]: the per-query lse /
-        # delta rows broadcast along sublanes and every matmul below is
-        # a plain (non-transposed-lhs) MXU product
-        dk, dv = carry
-        q = q_ref[pl.ds(qi * block_q, block_q), :]
-        do = do_ref[pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[:, pl.ds(qi * block_q, block_q)]      # [1, bq]
-        delta = delta_ref[:, pl.ds(qi * block_q, block_q)]
-        st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * sm_scale
-        if causal or window is not None:
-            st = jnp.where(_band_keep(qi * block_q, k_idx * block_k, block_q,
-                                      block_k, causal, window, k_major=True),
-                           st, DEFAULT_MASK_VALUE)
-        pt = jnp.exp(st - lse)  # [bk, bq]
-        dv = dv + jax.lax.dot_general(
-            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dst = pt * (dpt - delta) * sm_scale
-        dk = dk + jax.lax.dot_general(
-            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk, dv
+        @pl.when(member == 0)
+        def _():
+            dk_acc[:] = dk
+            dv_acc[:] = dv
 
-    dk, dv = jax.lax.fori_loop(q0, num_q, body, (dk0, dv0))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+        @pl.when(member > 0)
+        def _():
+            dk_acc[:] += dk
+            dv_acc[:] += dv
+
+        @pl.when(member == groups - 1)
+        def _():
+            dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+    _each_block(pl.program_id(2), blocks_k, block)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, sm_scale, causal, block_k, seq_k, window):
-    q_idx = pl.program_id(2)
+                   dq_ref, *, sm_scale, causal, block_k, seq_k, blocks_q,
+                   window):
     block_q = q_ref.shape[0]
     d = q_ref.shape[1]
     q = q_ref[:]
     do = do_ref[:]
     lse = lse_ref[:].T      # [1, bq] row -> [bq, 1] column, once per block
     delta = delta_ref[:].T
+    band = (block_q, block_k, causal, window)
 
-    num_k = pl.cdiv(seq_k, block_k)
-    if causal:
-        num_k = jnp.minimum(num_k, (q_idx + 1) * block_q // block_k
-                            + ((q_idx + 1) * block_q % block_k != 0))
-    k_lo = jnp.int32(0)
-    if window is not None:
-        k_lo = jnp.maximum(
-            jnp.int32(0), (q_idx * block_q - window + 1) // block_k)
+    def block(q_idx):
+        def body(masked, ki, dq):
+            k = k_ref[pl.ds(ki * block_k, block_k), :]
+            v = v_ref[pl.ds(ki * block_k, block_k), :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                s = jnp.where(_band_keep(q_idx * block_q, ki * block_k, *band),
+                              s, DEFAULT_MASK_VALUE)
+            p = jnp.exp(s - lse)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * sm_scale
+            return dq + jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    dq0 = jnp.zeros((block_q, d), jnp.float32)
+        dq = _walk(
+            body, *_k_range(q_idx, block_q, block_k, seq_k, causal, window),
+            jnp.zeros((block_q, d), jnp.float32),
+            lambda ki: _band_inside(q_idx * block_q, ki * block_k, *band),
+            _traced_masks("last", *band))
+        dq_ref[:] = dq.astype(dq_ref.dtype)
 
-    def body(ki, dq):
-        k = k_ref[pl.ds(ki * block_k, block_k), :]
-        v = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal or window is not None:
-            s = jnp.where(_band_keep(q_idx * block_q, ki * block_k, block_q,
-                                     block_k, causal, window),
-                          s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    dq = jax.lax.fori_loop(k_lo, num_k, body, dq0)
-    dq_ref[:] = dq.astype(dq_ref.dtype)
+    _each_block(pl.program_id(2), blocks_q, block)
 
 
 def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret, window):
     q, k, v, out, lse = res
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    kv_heads, s_k = k.shape[1:3]
+    groups = h // kv_heads
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
+    blocks_q, blocks_k = pl.cdiv(s_q, block_q), pl.cdiv(s_k, block_k)
     # same lane-major [B, H, 1, S] row layout as lse
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, :, None, :]
 
+    def head(bi, ki, gi, mi):
+        return (bi, ki * groups + mi, 0, 0)
+
+    whole_q = pl.BlockSpec((None, None, s_q, d), head)
+    row_q = pl.BlockSpec((None, None, 1, s_q), head)
+    block_kv = pl.BlockSpec((None, None, block_k, d),
+                            lambda bi, ki, gi, mi: (bi, ki, gi, 0))
     dkv_kernel = functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale,
                                    causal=causal, block_q=block_q, seq_q=s_q,
-                                   window=window)
+                                   blocks_k=blocks_k, window=window,
+                                   groups=groups)
+    # the member axis innermost: dK / dV blocks stay resident across it and
+    # a long row needs no whole [S, d] accumulators
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(b, h, pl.cdiv(s_k, block_k)),
-        in_specs=[
-            pl.BlockSpec((None, None, s_q, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((None, None, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((None, None, s_q, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, 1, s_q), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, 1, s_q), lambda bi, hi, ki: (bi, hi, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((None, None, block_k, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-        ],
+        grid=(b, kv_heads, blocks_k, groups),
+        in_specs=[whole_q, block_kv, block_kv, whole_q, row_q, row_q],
+        out_specs=[block_kv, block_kv],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32)] * 2
+        if groups > 1 else [],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
         name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q, k, v, g, lse, delta)
 
     dq_kernel = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale,
                                   causal=causal, block_k=block_k, seq_k=s_k,
-                                  window=window)
+                                  blocks_q=blocks_q, window=window)
+    kv_spec = pl.BlockSpec((None, None, s_k, d),
+                           lambda bi, hi, qi: (bi, hi // groups, 0, 0))
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(b, h, pl.cdiv(s_q, block_q)),
+        grid=(b, h, blocks_q),
         in_specs=[
             pl.BlockSpec((None, None, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, s_k, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
+            kv_spec, kv_spec,
             pl.BlockSpec((None, None, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((None, None, 1, block_q), lambda bi, hi, qi: (bi, hi, 0, qi)),
             pl.BlockSpec((None, None, 1, block_q), lambda bi, hi, qi: (bi, hi, 0, qi)),
@@ -377,12 +509,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: int = 512,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
-    """Blockwise attention, [B,H,S,D].  GQA callers fold groups into H or
-    repeat kv.  ``interpret=None`` (the default) compiles the kernel on
-    a TPU and computes the jnp reference on CPU; an explicit bool always
-    runs the kernel (True = Pallas interpreter, the tests' parity mode)."""
+    """Blockwise attention, q [B,H,S,D], k and v [B,K,S,D] with H % K == 0
+    (GQA: query head h attends K/V head h // (H // K)).  ``interpret=None``
+    (the default) compiles the kernel on a TPU and computes the jnp
+    reference on CPU; an explicit bool always runs the kernel (True =
+    Pallas interpreter, the tests' parity mode)."""
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(q.shape[-1])
+    if causal and window is not None and window >= k.shape[2]:
+        window = None       # q - k < seq <= window: it cannot bind
     if interpret is None:
         if not on_tpu():
             return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,

@@ -113,15 +113,46 @@ def test_flash_forward(chip, batch, heads):
         *_qkv(chip, batch, heads), kernel="flash_attention_fwd")
 
 
-@pytest.mark.parametrize("window", [None, 4096, 1024])
-def test_flash_forward_and_backward(chip, window):
+#: the default scoped VMEM limit: what a kernel that asks for no limit of its
+#: own may use (PERF.md, PR 27: a kernel that asks for more hangs the chip)
+DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def flash_kernels(text: str) -> dict:
+    """name -> the scoped VMEM the chip's compiler gave it, for the flash
+    kernels' Mosaic custom calls of a compiled program's text."""
+    found = {}
+    for line in kernel_calls(text, "flash_attention"):
+        name = re.search(r"(flash_attention_\w+?)\)*/pallas_call", line)
+        used = re.search(r'"used_scoped_memory_configs":\[[^\]]*"size":"(\d+)"',
+                         line)
+        found[name.group(1)] = int(used.group(1))
+    return found
+
+
+@pytest.mark.parametrize("kv_heads,seq,window", [
+    (32, SEQ, None), (8, SEQ, None), (8, SEQ, 4096), (8, SEQ, 1024),
+    (8, 2560, None), (8, 2 * SEQ, None), (2, SEQ, None)])
+def test_flash_forward_and_backward(chip, kv_heads, seq, window):
+    """The forward and its backward with K/V at their own head count, at
+    the train cell's row (four blocks: every walk written out), at five and
+    eight blocks (walks in loops) and at 16 query heads a KV head.  Every
+    name begins as the benchmark's readers expect
+    (``benchmark/metrics/flash_*.json``), no kernel asks for a VMEM limit
+    and each stays under the default one."""
     def grads(q, k, v):
         return jax.grad(lambda q, k, v: flash_attention(
             q, k, v, window=window, interpret=False
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv",
-                   "flash_attention_bwd_dq"):
-        compile_for_chip(grads, *_qkv(chip), kernel=kernel)
+    q = chip((2, HEADS, seq, HEAD_DIM), jnp.bfloat16)
+    kv = chip((2, kv_heads, seq, HEAD_DIM), jnp.bfloat16)
+    text = jax.jit(grads).lower(q, kv, kv).compile().as_text()
+    kernels = flash_kernels(text)
+    assert sorted(kernels) == ["flash_attention_bwd_dkv",
+                               "flash_attention_bwd_dq",
+                               "flash_attention_fwd"]
+    assert scoped_vmem_asked(text, "flash_attention") == [""] * len(kernels)
+    assert max(kernels.values()) < DEFAULT_SCOPED_VMEM, kernels
 
 
 def test_flash_short_unaligned_sequence(chip):
@@ -1443,8 +1474,10 @@ def test_attention_block_moves_little_outside_its_matmuls(chip, monkeypatch):
     the strided-pair rope (``x[..., 0::2]``, ``jnp.stack``) that was 5.41
     GB a layer: float32 copies of q with the pair index as the MAJOR
     dimension, gathered and scattered by ``kCustom`` fusions.  With the
-    pair swap a product fused with the rotation 0.88 GB are left: the GQA
-    repeat, the group sum of dK / dV, the activation's prefetch."""
+    pair swap a product fused with the rotation 0.88 GB were left: the GQA
+    repeat, the group sum of dK / dV, the activation's prefetch; without
+    the repeat and the group sum (PR 51: the flash kernels take K/V at
+    their own head count) 0.68 GB."""
     from deepspeed_tpu.accelerator import real_accelerator
     from deepspeed_tpu.models import transformer as T
 
@@ -1471,11 +1504,15 @@ def test_attention_block_moves_little_outside_its_matmuls(chip, monkeypatch):
 
     text = jax.jit(block).lower(
         params, x, chip((TRAIN_ROWS, SEQ), jnp.int32), x).compile().as_text()
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv",
-                   "flash_attention_bwd_dq"):
-        assert len(kernel_calls(text, kernel)) == 1, kernel
+    assert sorted(flash_kernels(text)) == [
+        "flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+        "flash_attention_fwd"]
     moved = bytes_outside_matmuls(text)
-    assert sum(m[0] for m in moved) < 1_200_000_000, moved[:12]
+    assert sum(m[0] for m in moved) < 800_000_000, moved[:12]
+    # K and V reach the kernels at their own head count (PR 51): nothing
+    # repeats them to the query heads or sums dK / dV over a group
+    group = f"[{TRAIN_ROWS},{KV_HEADS},{HEADS // KV_HEADS},{SEQ},{HEAD_DIM}]"
+    assert group not in text
     # no gather or scatter of the lane dimension's pairs ...
     assert [ln for ln in text.splitlines() if "kind=kCustom" in ln] == []
     # ... and no float32 re-layout of q (or of its cotangent)

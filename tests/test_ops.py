@@ -1,6 +1,8 @@
 """Kernel numeric-parity tests (reference tests/unit/ops/*): Pallas kernels
 in interpret mode vs jnp ground truth."""
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -133,6 +135,164 @@ class TestFlashAttention:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-3, rtol=5e-3)
+
+
+#: (query heads a KV head, window, tokens).  Blocks of 128 through the public
+#: entry: 384 tokens are three blocks (a walk that is written out), 640 five
+#: (a walk in a loop), 128 one, and 200 a length ``_fit_block`` turns into
+#: one block; a window of 4,096 cannot bind, 200 binds and crosses block
+#: edges inside a block, 128 binds on a block's edge, 40 and 72 bind inside
+#: a call of one block.
+BAND_CASES = [
+    (1, None, 384), (4, None, 384), (8, None, 384), (4, 4096, 384),
+    (4, 200, 384), (1, 200, 384), (8, 128, 384), (1, 128, 384),
+    (4, None, 640), (1, 128, 640), (4, 200, 640), (8, 4096, 640),
+    (4, None, 128), (4, 40, 128), (8, 4096, 128), (4, None, 200),
+    (4, 72, 200),
+]
+
+
+class TestFlashBandOnce:
+    """PR 51: the mask only on the blocks the band's edge crosses, K and V
+    at their own head count."""
+
+    @pytest.mark.parametrize("groups,window,seq", BAND_CASES)
+    def test_forward_and_gradients(self, groups, window, seq, monkeypatch):
+        import importlib
+        fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+        kv_heads = 8 // groups if groups > 1 else 2
+        q, w = (rand(1, kv_heads * groups, seq, 32, seed=s) for s in (1, 4))
+        k, v = (rand(1, kv_heads, seq, 32, seed=s) for s in (2, 3))
+        names = []
+        real = fa.pl.pallas_call
+        monkeypatch.setattr(fa.pl, "pallas_call", lambda *a, **kw: (
+            names.append(kw["name"]), real(*a, **kw))[1])
+
+        def attend(q, k, v):
+            return flash_attention(q, k, v, block_q=128, block_k=128,
+                                   window=window, interpret=True)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) * w).sum()
+        out = attend(q, k, v)
+        got = jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v)
+        assert sorted(set(names)) == [
+            "flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+            "flash_attention_fwd"]
+        def reference(q, k, v):     # K and V repeated to the query heads
+            return mha_reference(q, *(jnp.repeat(x, groups, axis=1)
+                                      for x in (k, v)), window=window)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(reference(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        want = jax.grad(loss(reference), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4)
+        # the mask built on EVERY block (one loop, as before PR 51) changes
+        # no bit of the forward.  (At a scale of 1: the CPU's compiler
+        # contracts ``dot * scale - max`` into one fused multiply-add where
+        # no select stands between them, which rounds once for twice; that
+        # is the interpreter's compiler, not the kernel's arithmetic.)
+        def unscaled(q, k, v):
+            return flash_attention(q, k, v, block_q=128, block_k=128,
+                                   window=window, sm_scale=1.0,
+                                   interpret=True)
+        bare = unscaled(q * 32 ** -0.5, k, v)
+        monkeypatch.setattr(fa, "_band_inside", lambda *a: False)
+        monkeypatch.setattr(fa, "_traced_masks", lambda *a: True)
+        np.testing.assert_array_equal(
+            np.asarray(unscaled(q * 32 ** -0.5, k, v)), np.asarray(bare))
+        np.testing.assert_allclose(np.asarray(bare), np.asarray(out),
+                                   atol=2e-6, rtol=2e-6)
+
+    @pytest.mark.parametrize("seq,window,block_k,masks,loops,bare", [
+        (384, None, 128, 3, 0, 0), (384, 4096, 128, 3, 0, 0),
+        (384, 256, 128, 4, 0, 0), (128, 40, 128, 1, 0, 0),
+        (200, None, 128, 1, 0, 0), (640, None, 128, 1, 1, 1),
+        (640, 200, 128, 1, 1, 0), (1280, None, 256, 1, 1, 0)])
+    def test_mask_is_built_where_the_bands_edge_crosses(
+            self, seq, window, block_k, masks, loops, bare, monkeypatch):
+        """The forward's text.  Up to four blocks a row the pairs are
+        written out and the mask is built on those the band's edge crosses:
+        of three q blocks' six pairs the three on the diagonal (a window of
+        4,096 over 384 tokens cannot bind; one of 256 crosses one more
+        pair), and a call of ONE block (serving's fresh prefill) is its one
+        masked pair, without a loop.  Longer rows walk in ONE loop: bare
+        under the causal band alone, the diagonal's block masked outside
+        it; a window that binds and unlike blocks mask in the loop, as
+        before."""
+        import importlib
+        fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+        seen = {"masks": 0, "loops": 0, "bare": 0}
+        real_keep, real_loop = fa._band_keep, jax.lax.fori_loop
+
+        def keep(*a, **kw):
+            seen["masks"] += 1
+            return real_keep(*a, **kw)
+
+        def loop(lo, hi, body, carry):
+            before = seen["masks"]
+            out = real_loop(lo, hi, body, carry)
+            seen["loops"] += 1
+            seen["bare"] += seen["masks"] == before
+            return out
+        monkeypatch.setattr(fa, "_band_keep", keep)
+        monkeypatch.setattr(fa.jax.lax, "fori_loop", loop)
+        q = rand(1, 2, seq, 32)
+        flash_attention(q, q, q, block_q=128, block_k=block_k, window=window,
+                        interpret=True)
+        assert (seen["masks"], seen["loops"], seen["bare"]) \
+            == (masks, loops, bare)
+
+    def test_a_block_inside_the_band_is_one_whose_mask_is_all_true(self):
+        """``_band_inside`` against ``_band_keep`` itself, for blocks of
+        unlike sizes and windows on, beside and across block edges; and the
+        ranges a walk visits hold every block with a true entry."""
+        from deepspeed_tpu.ops.flash_attention import (_band_inside,
+                                                       _band_keep, _k_range,
+                                                       _q_range)
+        seq = 768
+        for bq, bk in ((128, 128), (256, 128), (128, 256)):
+            nq, nk = seq // bq, seq // bk
+            for causal, window in ((True, None), (True, 128), (True, 200),
+                                   (True, 300), (True, 1), (False, 200),
+                                   (True, 4096)):
+                keeps = [[np.asarray(_band_keep(qi * bq, ki * bk, bq, bk,
+                                                causal, window))
+                          for ki in range(nk)] for qi in range(nq)]
+                for qi in range(nq):
+                    lo, hi = _k_range(qi, bq, bk, seq, causal, window)
+                    for ki in range(nk):
+                        assert _band_inside(qi * bq, ki * bk, bq, bk, causal,
+                                            window) == keeps[qi][ki].all()
+                        assert (lo <= ki < hi) or not keeps[qi][ki].any()
+                for ki in range(nk):
+                    lo, hi = _q_range(ki, bq, bk, seq, causal, window)
+                    assert all((lo <= qi < hi) or not keeps[qi][ki].any()
+                               for qi in range(nq))
+
+    @pytest.mark.parametrize("policy,forwards", [("nothing_saveable", 2),
+                                                 ("save_attn", 1)])
+    def test_checkpoint_policy_finds_the_kernels_residuals(self, policy,
+                                                           forwards):
+        """``flash_out`` / ``flash_lse`` are still the names of the forward
+        kernel's outputs: a policy that keeps them leaves the recomputed
+        forward without the kernel (K/V at 2 heads under 8 query heads)."""
+        from deepspeed_tpu.models.transformer import resolve_remat_policy
+        q = rand(1, 8, 128, 32, seed=1)
+        k, v = rand(1, 2, 128, 32, seed=2), rand(1, 2, 128, 32, seed=3)
+
+        @functools.partial(jax.checkpoint, policy=resolve_remat_policy(
+            policy, flash_kernel=True))
+        def layer(q, k, v):
+            return flash_attention(q * 2, k, v, interpret=True).sum()
+        text = str(jax.make_jaxpr(jax.grad(layer, argnums=(0, 1, 2)))(
+            q, k, v))
+        assert text.count("name=flash_attention_fwd") == forwards
+        assert text.count("name=flash_attention_bwd_dkv") == 1
+        assert text.count("name=flash_attention_bwd_dq") == 1
 
 
 class TestFusedAdam:

@@ -25,14 +25,14 @@ def start_watchdog(limit: float = 100.0) -> list:
 
 def ms_a_call(run, *operands, calls: int, beat: list):
     """(ms a call over ``calls`` calls of ``run(*operands)`` back to back
-    after one warm call, the last result)."""
-    out = run(*operands)                    # warm
-    out.block_until_ready()
+    after one warm call, the last result: an array or a tree of them)."""
+    import jax
+    out = jax.block_until_ready(run(*operands))     # warm
     beat[0] = time.monotonic()
     t0 = time.perf_counter()
     for _ in range(calls):
         out = run(*operands)
-    out.block_until_ready()
+    jax.block_until_ready(out)
     ms = (time.perf_counter() - t0) * 1e3 / calls
     beat[0] = time.monotonic()
     return ms, out
