@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -28,6 +29,7 @@ import numpy as np
 from ...ops.paged_attention import slots_held
 from ...telemetry import metrics as tm
 from ...telemetry import trace_span
+from ...telemetry.watchdog import StepMeter, install_collector
 from ...utils.comms_logging import serving_counters
 from .config import RaggedInferenceEngineConfig
 from .lattice import (POWER_LATTICE, BucketLattice, enumerate_lattice_keys,
@@ -389,6 +391,11 @@ class InferenceEngineV2:
         self._draft_kv = None
         self._draft_seen: Dict[int, int] = {}
         self._attended = (0, 0)
+        #: the host's clocks around a serving step (ISSUE 52): the
+        #: scheduler brackets the step, ``_build_batch`` and ``_dispatch``
+        #: add their phases; the process's collector hook goes in with it
+        self.step_meter = StepMeter()
+        install_collector()
         #: whether the decode rows' contexts are summed for the step span
         #: (``take_attended``; the scheduler asks this too): a model whose
         #: full layers are some of its layers only and whose roofline is
@@ -1133,6 +1140,7 @@ class InferenceEngineV2:
         array is not an input of the chained program); ``min_q`` floors
         the Q bucket (spec steps pad to the one spec bucket);
         ``start_pos`` as ``build_batch`` takes it."""
+        t_build = time.perf_counter()
         with trace_span("engine.build_batch"):
             batch = build_batch(
                 descs, tokens, self._model.kv_config.page_size,
@@ -1156,7 +1164,8 @@ class InferenceEngineV2:
             if h2d_tokens:
                 nbytes += batch.token_ids.nbytes
             serving_counters.record_h2d(nbytes)
-            return batch
+        self.step_meter.build += time.perf_counter() - t_build
+        return batch
 
     def take_attended(self) -> Tuple[int, int]:
         """(tokens, tokens inside the window) that the decode rows of the
@@ -1302,14 +1311,20 @@ class InferenceEngineV2:
                            self._prev_len(prev[0]) if prev else 0)
         serving_counters.record_program(h2d_bytes=h2d)
         pool = self._pool(row.trunk)
-        with trace_span("engine.dispatch"):
+        t_dispatch = time.perf_counter()
+        with trace_span("engine.dispatch") as span:
             if prev is not None and not row.chained:
                 batches[0].token_ids = self._gather_tokens(
                     *prev, batches[0].token_ids)
                 prev = None
-            out = model.run_step(key, pool, batches, sampling, prev)
+            # a live span is told what it prepared (this gather, the
+            # executable's lookup, the operands) and what the executable's
+            # call took (h2d of the host arrays, the enqueue)
+            out = model.run_step(key, pool, batches, sampling, prev,
+                                 span=span)
             out, pool = out if row.output else (None, out)
             self._put_pool(row.trunk, pool)
+        self.step_meter.dispatch += time.perf_counter() - t_dispatch
         return out
 
     def put(self, batch_uids: Sequence[int],

@@ -603,7 +603,7 @@ class RaggedInferenceModel:
     # dslint: hot-path
     def run_step(self, key: StepKey, kv, batches: Sequence[RaggedBatch],
                  sampling: Optional[tuple] = None,
-                 prev: Optional[tuple] = None):
+                 prev: Optional[tuple] = None, span=None):
         """Run the step program of ``key``: the one call every dispatch
         makes.  ``kv`` is the pool (or pair) of the kind's trunk, donated;
         ``batches`` the key's segments in order; ``prev`` a chain key's
@@ -614,7 +614,10 @@ class RaggedInferenceModel:
         reads (padding, mid-prefill) may pass anything for it — its draw
         is garbage nobody consumes.  Returns what the program returns:
         ``(output, new kv)``, or the new pool alone where the kind has
-        no output (``STEP_KINDS``)."""
+        no output (``STEP_KINDS``).  ``span``: the caller's open
+        ``engine.dispatch`` span; a live one is told ``prepare_ms`` (its
+        start to the executable's call) and ``call_ms`` (the call: h2d of
+        the host arrays and the enqueue), a dead one costs no clock."""
         step = self._get_step(key)
         operands = []
         for b in batches:
@@ -633,8 +636,14 @@ class RaggedInferenceModel:
                         "for every sampling-capable step")
                 operands += (jnp.asarray(row_uids, jnp.int32),
                              jnp.asarray(row_pos, jnp.int32))
-        return step(trunk_params(self, STEP_KINDS[key.kind].trunk), kv,
-                    *operands)
+        params = trunk_params(self, STEP_KINDS[key.kind].trunk)
+        if span is None or not span.live:
+            return step(params, kv, *operands)
+        called = time.perf_counter()
+        out = step(params, kv, *operands)
+        span.set("prepare_ms", (called - span.t0) * 1e3)
+        span.set("call_ms", (time.perf_counter() - called) * 1e3)
+        return out
 
     @property
     def table(self) -> TableLayout:

@@ -82,7 +82,7 @@ from ...telemetry.flight_recorder import get_flight_recorder
 from ...telemetry.memory import get_memory_ledger
 from ...telemetry.state import state as _telemetry
 from ...telemetry.timeseries import get_timeseries
-from ...telemetry.watchdog import get_watchdog
+from ...telemetry.watchdog import get_collector
 from ...telemetry.workload_trace import get_workload_trace
 from ...utils.comms_logging import serving_counters
 from .engine import InferenceEngineV2
@@ -92,6 +92,9 @@ from .snapshot import (SNAPSHOT_VERSION, SnapshotError,
                        maybe_install_drain_handler, read_bundle,
                        write_bundle)
 from .spec import NgramDrafter
+
+#: the process's collector hook: told which loop is stepping
+_collector = get_collector()
 
 
 @dataclasses.dataclass
@@ -207,6 +210,9 @@ class _Inflight:
     the (uid, output row, request) triples of its SAMPLED rows."""
     tokens_dev: jax.Array
     rows: List[Tuple[int, int, Request]]
+    #: tokens charged to the budget by the step (the divisor of its
+    #: held-experts counts); None for a step dispatched with telemetry off
+    step_tokens: Optional[int] = None
 
 
 #: a decode row's place in ``batch_tokens`` where its token id is a row of
@@ -344,7 +350,10 @@ class FastGenScheduler:
         #: drained, and the tokens of the step they belong to
         self._token_tail = int(getattr(engine.model, "step_tail", 0))
         self._moe_counts = None
-        self._moe_tokens = 0
+        self._moe_tokens: Optional[int] = None
+        #: the host's clocks around every step, telemetry on or off
+        #: (telemetry/watchdog.py::StepMeter; the engine adds its phases)
+        self._meter = engine.step_meter
         #: ``StateManager.window_pages_released`` at the last live span
         self._window_released = 0
         #: (pairs here, fullest expert's pairs, experts touched) of the
@@ -891,14 +900,18 @@ class FastGenScheduler:
     # dslint: hot-path
     def _drain_impl(self, on_token) -> Dict[int, int]:
         inf, self._inflight = self._inflight, None
+        meter, t = self._meter, time.perf_counter()
         with trace_span("fastgen.drain.wait"):
             # the host blocked on the device: not host work
             toks = np.asarray(inf.tokens_dev)   # dslint: d2h [S] int32
+        t, then = time.perf_counter(), t
+        meter.wait += t - then
         serving_counters.record_d2h(toks.nbytes)
         if self._token_tail:
             # the held-experts counts ride the token vector's tail
             self._moe_counts = self.last_moe_counts = \
                 toks[-self._token_tail:]
+            self._moe_tokens = inf.step_tokens
         out: Dict[int, int] = {}
         with trace_span("fastgen.drain.deliver"):
             for uid, row, req in inf.rows:
@@ -911,6 +924,7 @@ class FastGenScheduler:
                 if self._deliver_token(req, int(toks[row]), out,
                                        on_token):
                     self._finish_request(req)
+        meter.deliver += time.perf_counter() - t
         return out
 
     # -- double buffer: which steps are dispatched ahead of the drain -------
@@ -1276,8 +1290,10 @@ class FastGenScheduler:
         if _telemetry.enabled:
             self._step_shape = ("spec", len(uids), 0, 0,
                                 sum(len(t) for t in toks))
+        t = time.perf_counter()
         with trace_span("fastgen.drain.wait"):
             av = np.asarray(out_dev)        # dslint: d2h [S, 2] int32
+        self._meter.wait += time.perf_counter() - t
         serving_counters.record_d2h(av.nbytes)
         out: Dict[int, int] = {}
         committed: List[int] = []
@@ -1346,8 +1362,10 @@ class FastGenScheduler:
         if _telemetry.enabled:
             self._step_shape = ("draft", len(uids), 0, 0,
                                 sum(len(t) for t in toks))
+        t = time.perf_counter()
         with trace_span("fastgen.drain.wait"):
             av = np.asarray(out_dev)        # dslint: d2h [S, 2+k] int32
+        self._meter.wait += time.perf_counter() - t
         serving_counters.record_d2h(av.nbytes)
         out: Dict[int, int] = {}
         committed: List[int] = []
@@ -1430,6 +1448,15 @@ class FastGenScheduler:
             # drain_and_snapshot, restore elsewhere.
             raise InjectedPreemptionFault(
                 "injected preemption between scheduler steps")
+        # the collector's spans are this loop's from here on, and the
+        # host's clocks bracket the step whether or not telemetry is on
+        # (ISSUE 52): ``meter.end`` feeds the EWMA anomaly detector
+        # (ISSUE 5: a recompile or a KV thrash shows up as a step-time
+        # spike) and leaves one ``fastgen.stall`` record where the step
+        # or the gap before it paused
+        _collector.loop = "fastgen"
+        meter = self._meter
+        meter.begin()
         try:
             if _telemetry.enabled:
                 # spans from this step (and everything nested under it)
@@ -1439,19 +1466,13 @@ class FastGenScheduler:
                 # writes
                 self._step_ordinal += 1
                 get_tracer().set_step(self._step_ordinal)
-                t0 = time.perf_counter()
                 with trace_span("fastgen.step") as span:
                     out = self._step_impl(on_token)
                     if span.live:
                         self._note_step(span)
-                step_ms = (time.perf_counter() - t0) * 1e3
-                tm.FASTGEN_STEP_MS.observe(step_ms)
-                # EWMA anomaly detector (ISSUE 5): a recompile or a KV
-                # thrash shows up here as a step-time spike
-                get_watchdog().observe_step_time(
-                    "fastgen", step_ms, step=self._step_ordinal)
             else:
                 out = self._step_impl(on_token)
+            meter.end(self.last_step_scheduled, self._step_ordinal)
         except Exception as e:
             # crash forensics (ISSUE 5): leave a postmortem bundle
             # before the exception leaves the step loop; never masks it
@@ -1538,14 +1559,16 @@ class FastGenScheduler:
                 span.set("attn_tokens_full",
                          self._engine.take_attended()[0])
         if self._moe_counts is not None:
-            # counts of the step drained inside this one (the step
-            # before), with that step's tokens as their divisor
-            span.set("moe_pairs_here", int(self._moe_counts[0]))
-            span.set("moe_expert_load_max", int(self._moe_counts[1]))
-            span.set("moe_experts_touched", int(self._moe_counts[2]))
-            span.set("moe_tokens", self._moe_tokens)
+            # counts of the step drained inside this one, only beside
+            # that step's own tokens as their divisor (``_Inflight.
+            # step_tokens``): a step dispatched with telemetry off took
+            # no count of its tokens, and its counts go on no span
+            if self._moe_tokens is not None:
+                span.set("moe_pairs_here", int(self._moe_counts[0]))
+                span.set("moe_expert_load_max", int(self._moe_counts[1]))
+                span.set("moe_experts_touched", int(self._moe_counts[2]))
+                span.set("moe_tokens", self._moe_tokens)
             self._moe_counts = None
-        self._moe_tokens = tokens
 
     def _match_prefix_once(self, req: Request, adm: _Admission) -> None:
         """One-shot prefix-cache lookup before first admission: cached
@@ -1794,13 +1817,17 @@ class FastGenScheduler:
         # step k drains while the device runs it; the plan falls back to
         # the drain where it finds nothing to run or no page for a row
         slot = self._inflight_rows() if spec_drained is None else None
+        meter, t = self._meter, time.perf_counter()
         plan = self._plan_step(slot) if slot is not None else None
+        meter.admission += time.perf_counter() - t
         ahead = plan is not None and bool(plan.uids)
         out_prev = spec_drained     # step k's tokens, once it drained
         if not ahead:
             if out_prev is None:
                 out_prev = self._drain(on_token)
+            t = time.perf_counter()
             plan = self._plan_step(None)
+            meter.admission += time.perf_counter() - t
         uids, tokens, reqs = plan.uids, plan.tokens, plan.reqs
         advances, new_admits = plan.advances, plan.new_admits
 
@@ -1877,7 +1904,10 @@ class FastGenScheduler:
             inflight = _Inflight(
                 tokens_dev=toks,
                 rows=[(uids[i], rowmap[i], reqs[i])
-                      for i in sampled_rows])
+                      for i in sampled_rows],
+                step_tokens=(self._step_shape[4]
+                             if self._step_shape is not _IDLE_STEP
+                             else None))
             if ahead:
                 # the host sync overlaps the device executing the new step
                 out_prev = self._drain(on_token)

@@ -55,7 +55,7 @@ from ..telemetry import metrics as tm
 from ..telemetry.flight_recorder import get_flight_recorder
 from ..telemetry.program_scopes import scope_table
 from ..telemetry.state import state as telemetry_state
-from ..telemetry.watchdog import get_watchdog
+from ..telemetry.watchdog import get_watchdog, install_collector
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER,
                            STEP_GLOBAL_TIMER, TRAIN_BATCH_TIMER,
@@ -286,6 +286,8 @@ class DeepSpeedEngine:
         # crash with telemetry off should still identify what ran); the
         # lifecycle event is enabled-gated inside record()
         self._monitor_write_warned = False
+        #: the process's ``gc.callbacks`` hook (ISSUE 52): hooked once
+        self._collector = install_collector()
         recorder = get_flight_recorder()
         recorder.set_config("runtime", self.config)
         recorder.record(
@@ -1183,6 +1185,8 @@ class DeepSpeedEngine:
         # two device steps lies under a span of the program: one of
         # train.place_batch, train.step.dispatch, train.step.wait,
         # train.after_step, or train.batch itself (entry checks, timers)
+        # (the collector's spans are this loop's from here on: train.gc)
+        self._collector.loop = "train"
         with trace_span("train.batch"):
             return self._train_batch_spanned(batch, data_iter)
 

@@ -7,7 +7,7 @@ registry against docs/DESIGN.md's metric table in tier-1.
 
 Naming convention: ``ds_<area>_<name>`` with area one of
 {serving, comm, kv, train, fastgen, chaos, fleet, slo, telemetry,
-pool, disagg, journey, mem};
+pool, disagg, journey, mem, host};
 counters end in ``_total``.
 """
 
@@ -108,6 +108,15 @@ TRAIN_ANOMALY = registry.counter(
 TRAIN_MONITOR_DROP = registry.counter(
     "ds_train_monitor_drop_total",
     "monitor write batches dropped because a writer raised")
+
+# -- the host's pauses (ISSUE 52): counted with telemetry off too -----------
+HOST_GC_SECONDS = registry.counter(
+    "ds_host_gc_seconds_total",
+    "seconds the process spent in Python's cyclic collector")
+FASTGEN_STALL = registry.counter(
+    "ds_fastgen_stall_total",
+    "serving steps (or gaps between two) that passed the step-time rule "
+    "and 50 ms: one fastgen.stall record and one warning line each")
 
 # -- goodput accounting (callback gauges fed by the watchdog) ----------------
 TRAIN_GOODPUT_RATIO = registry.gauge(

@@ -23,16 +23,26 @@ host (no new device syncs):
   naming the uncovered ``(S, Q, P, fresh, kind)`` keys — the failure
   mode the AOT bucket lattice exists to prevent, now measured.
 
+- **the host's pauses** (ISSUE 52) — one ``gc.callbacks`` hook a
+  process (:class:`HostCollector`: seconds and counts always, a span a
+  collection with telemetry on) and one ``fastgen.stall`` record a
+  paused serving step (:class:`StepMeter`, :meth:`Watchdog.
+  observe_serving_step`), written with telemetry off too: the step-time
+  detector above, fed on every step, with a floor of 50 ms.
+
 Disabled-path contract: every per-step entry point reads
 ``state.enabled`` first and returns — the same one-attribute-read cost
-bound the spans keep (the recompile counters are the one exception:
-like ``ServingCounters`` they count unconditionally, because a compile
-is ~10^7× their cost and a storm must be visible even telemetry-off).
+bound the spans keep.  Two exceptions count unconditionally: the
+recompile counters (like ``ServingCounters``: a compile is ~10^7× their
+cost and a storm must be visible even telemetry-off) and the serving
+step's meter (a few clock reads a step: the pauses it is for fall into
+the runs that are measured with telemetry off).
 """
 
 from __future__ import annotations
 
 import collections
+import gc
 import os
 import threading
 import time
@@ -40,9 +50,13 @@ from typing import Any, Dict, Optional
 
 from .state import state
 from . import metrics as tm
+from .tracer import get_tracer
 
 #: process start reference for /healthz uptime
 _T0 = time.monotonic()
+#: the meter's three clocks: the wall, this thread's CPU, the process's
+_now, _thread_cpu, _process_cpu = (time.perf_counter, time.thread_time,
+                                   time.process_time)
 
 
 class _KindState:
@@ -110,6 +124,183 @@ class _NullTrack:
 
 
 _NULL_TRACK = _NullTrack()
+
+
+class HostCollector:
+    """The process's one ``gc.callbacks`` hook (:func:`install_collector`,
+    when an engine is built).  Always, telemetry off too: seconds spent in
+    the collector, collections and full collections, from two clock reads
+    a collection; a :class:`StepMeter` reads them before and after a step.
+    With telemetry on every collection is a span, nested under the span
+    open on its thread and mirrored into the profiler's trace from the
+    ``start`` to the ``stop`` callback: ``fastgen.gc`` or ``train.gc`` by
+    the loop that stepped last (``loop``, one attribute write at a step's
+    entry; no span before any loop has stepped).  Collections do not
+    nest, so one start stamp and one open span serve the process."""
+    __slots__ = ("seconds", "collections", "full", "loop", "_t0", "_span")
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.collections = 0
+        self.full = 0
+        self.loop: Optional[str] = None
+        self._t0 = 0.0
+        self._span = None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            if state.enabled and self.loop is not None:
+                self._span = get_tracer().span(self.loop + ".gc")
+                self._span.__enter__()
+            self._t0 = _now()
+            return
+        seconds = _now() - self._t0
+        self.seconds += seconds
+        self.collections += 1
+        if info["generation"] == 2:
+            self.full += 1
+        tm.HOST_GC_SECONDS.inc(seconds)
+        span, self._span = self._span, None
+        if span is not None:
+            span.set("generation", info["generation"])
+            span.set("collected", info["collected"])
+            span.__exit__(None, None, None)
+
+
+#: process-wide singleton
+_COLLECTOR = HostCollector()
+
+
+def get_collector() -> HostCollector:
+    return _COLLECTOR
+
+
+def install_collector() -> HostCollector:
+    """Hook the collector's callbacks, once a process."""
+    if _COLLECTOR not in gc.callbacks:
+        gc.callbacks.append(_COLLECTOR)
+    return _COLLECTOR
+
+
+#: a serving step under this is no stall, whatever the mean: no sound step
+#: of any cell passes it, every pause seen starts at 86 ms (PERF.md §7)
+STALL_FLOOR_MS = 50.0
+#: seconds between two readings of the CPU clocks' baseline
+CPU_BASELINE_S = 0.25
+#: the record of a paused serving step in the span ring
+STALL_SPAN = "fastgen.stall"
+#: the phases of a serving step, marked where their spans stand
+STALL_PHASES = ("admission", "build", "dispatch", "wait", "deliver")
+
+
+class StepMeter:
+    """What one serving loop's step cost the host, taken with telemetry
+    off too: the wall, the collector's accumulators, the step programs
+    formed on the path, the seconds of five phases (added by the scheduler
+    and the engine where their spans stand) and the gap since the last
+    step returned: wall-clock reads only, a few microseconds a step.
+    ``begin`` / ``end`` bracket ``FastGenScheduler.step``; ``end`` hands
+    the meter to :meth:`Watchdog.observe_serving_step`, which writes one
+    ``fastgen.stall`` record where the step, or the gap before it, passed
+    the step-time rule.
+
+    The two CPU clocks are system calls (5.7 us each on the benchmark's
+    host, in 10 ms ticks), so no sound step reads them: a baseline is read
+    once in :data:`CPU_BASELINE_S`, and a paused stretch's CPU time is
+    what the clocks gained since the baseline less what the sound steps
+    since then account for (:meth:`pause_clocks`)."""
+    __slots__ = STALL_PHASES + (
+        "steps", "t0", "t1", "between_s", "gc_s0", "gc_n0", "gc_full0",
+        "programs0", "gap", "base_t", "base_cpu", "base_proc",
+        "busy_since", "others_rate")
+
+    def __init__(self):
+        for phase in STALL_PHASES:
+            setattr(self, phase, 0.0)
+        #: steps begun, live or not (a stall's line counts by it)
+        self.steps = 0
+        self.t0 = self.t1 = self.between_s = 0.0
+        self.gc_s0, self.gc_n0, self.gc_full0 = 0.0, 0, 0
+        self.programs0 = 0
+        #: the gap's own EWMA: the rule of the step's stream, held apart
+        self.gap = _KindState()
+        #: the CPU clocks' baseline, this thread's seconds of sound steps
+        #: since (a sound step runs but for its wait), and the other
+        #: threads' CPU seconds a second over the last whole baseline
+        self.base_t = self.base_cpu = self.base_proc = 0.0
+        self.busy_since = self.others_rate = 0.0
+
+    def begin(self) -> None:
+        self.admission = self.build = self.dispatch = 0.0
+        self.wait = self.deliver = 0.0
+        now = _now()
+        if self.steps:
+            self.between_s = now - self.t1
+        else:
+            # the clocks and the accumulators open with the first step
+            self.base_t, self.base_cpu, self.base_proc = (
+                now, _thread_cpu(), _process_cpu())
+            self._mark_collector()
+        self.steps += 1
+        self.programs0 = tm.FASTGEN_COMPILE_ON_PATH.value
+        self.t0 = now
+
+    def end(self, rows: int, step: int) -> None:
+        self.t1 = now = _now()
+        if not _WATCHDOG.observe_serving_step(self, rows, step):
+            self.busy_since += now - self.t0 - self.wait + self.between_s
+            if now - self.base_t >= CPU_BASELINE_S:
+                cpu, proc = _thread_cpu(), _process_cpu()
+                self.others_rate = ((proc - self.base_proc)
+                                    - (cpu - self.base_cpu)) \
+                    / (now - self.base_t)
+                self.base_t, self.base_cpu, self.base_proc = now, cpu, proc
+                self.busy_since = 0.0
+        # what the collector did from here on belongs to the next step's
+        # record: the gap before it, and the step
+        self._mark_collector()
+
+    def _mark_collector(self) -> None:
+        self.gc_s0, self.gc_n0, self.gc_full0 = (
+            _COLLECTOR.seconds, _COLLECTOR.collections, _COLLECTOR.full)
+
+    def pause_clocks(self):
+        """``(this thread's, the other threads')`` CPU seconds of the
+        stretch that just paused (the gap and the step): what the clocks
+        gained since the baseline, less the sound steps' own running time
+        since then and the other threads' usual rate.  Read once a stall;
+        the reading is the next baseline."""
+        cpu, proc = _thread_cpu(), _process_cpu()
+        mine = cpu - self.base_cpu
+        sound_s = self.t0 - self.between_s - self.base_t
+        others = (proc - self.base_proc) - mine \
+            - self.others_rate * max(sound_s, 0.0)
+        mine -= self.busy_since
+        self.base_t, self.base_cpu, self.base_proc = self.t1, cpu, proc
+        self.busy_since = 0.0
+        return max(mine, 0.0), max(others, 0.0)
+
+
+def stall_cause(lost_ms: float, wait_ms: float, programs: int,
+                gc_ms: float, cpu_ms: float, proc_cpu_ms: float) -> str:
+    """One word for a stall, from its own numbers, in this order: the
+    device (the wait for the step's tokens is at least half of what was
+    lost), a step program formed on the path, the collector, another
+    thread (the process's CPU time less this thread's), this thread's
+    Python (its CPU time), else time off the CPU: the thread neither ran
+    nor waited for the device."""
+    half = 0.5 * lost_ms
+    if wait_ms >= half:
+        return "device"
+    if programs:
+        return "compile"
+    if gc_ms >= half:
+        return "gc"
+    if proc_cpu_ms - cpu_ms >= half:
+        return "other_thread"
+    if cpu_ms >= half:
+        return "python"
+    return "offcpu"
 
 
 class Watchdog:
@@ -181,6 +372,56 @@ class Watchdog:
         self._record_event("watchdog.overflow_skip", at_step=step)
 
     # -- step-time anomaly detector ------------------------------------------
+    def _sample(self, w: _KindState, ms: float):
+        """THE step-time rule, on one stream's state (under the lock):
+        ``(the mean the sample passed, first of its storm)`` where it is
+        anomalous, else ``(None, False)`` and the sample joins the EWMA."""
+        w.last_ms = ms
+        mean = w.mean_ms
+        anomalous = (
+            w.n >= self.warmup and mean > 0.0
+            and ms > mean * self.threshold
+            and ms - mean > self.min_delta_ms)
+        if not anomalous:
+            d = ms - mean
+            w.mean_ms = mean + self.alpha * d
+            w.dev_ms += self.alpha * (abs(d) - w.dev_ms)
+            w.n += 1
+            if w.in_storm:
+                w.calm += 1
+                if w.calm >= self.calm_steps:
+                    w.in_storm = False
+            return None, False
+        w.anomalies += 1
+        w.last_anomaly_ms = ms
+        first_of_storm = not w.in_storm
+        w.in_storm = True
+        w.calm = 0
+        return mean, first_of_storm
+
+    def _stream(self, kind: str) -> _KindState:
+        w = self._kinds.get(kind)
+        if w is None:
+            w = self._kinds[kind] = _KindState()
+        return w
+
+    def _announce(self, kind: str, ms: float, step: int, mean: float,
+                  first_of_storm: bool) -> None:
+        """An anomaly's counter, flight event, warning (once a storm) and
+        span-ring dump: with telemetry on only."""
+        tm.TRAIN_ANOMALY.inc()
+        self._record_event("watchdog.anomaly", stream=kind,
+                           at_step=step, ms=round(ms, 3),
+                           ewma_ms=round(mean, 3))
+        if first_of_storm:
+            self._logger().warning(
+                "watchdog: %s step %d took %.1fms vs EWMA %.1fms "
+                "(>%.1fx) — step-time anomaly storm begins; further "
+                "anomalies count in ds_train_anomaly_total without "
+                "logging until %d normal steps pass",
+                kind, step, ms, mean, self.threshold, self.calm_steps)
+            self._dump_anomaly_trace(kind, step)
+
     # dslint: disabled-path
     def observe_step_time(self, kind: str, ms: float,
                           step: int = 0) -> None:
@@ -199,42 +440,100 @@ class Watchdog:
                 # calm_steps finite steps (a still-NaN'ing run keeps
                 # re-raising it every step)
                 self._nonfinite_recent -= 1
-            w = self._kinds.get(kind)
-            if w is None:
-                w = self._kinds[kind] = _KindState()
-            w.last_ms = ms
-            anomalous = (
-                w.n >= self.warmup and w.mean_ms > 0.0
-                and ms > w.mean_ms * self.threshold
-                and ms - w.mean_ms > self.min_delta_ms)
-            if not anomalous:
-                d = ms - w.mean_ms
-                w.mean_ms += self.alpha * d
-                w.dev_ms += self.alpha * (abs(d) - w.dev_ms)
-                w.n += 1
-                if w.in_storm:
-                    w.calm += 1
-                    if w.calm >= self.calm_steps:
-                        w.in_storm = False
-                return
-            w.anomalies += 1
-            w.last_anomaly_ms = ms
-            first_of_storm = not w.in_storm
-            w.in_storm = True
-            w.calm = 0
-            mean = w.mean_ms
-        tm.TRAIN_ANOMALY.inc()
-        self._record_event("watchdog.anomaly", stream=kind,
-                           at_step=step, ms=round(ms, 3),
-                           ewma_ms=round(mean, 3))
-        if first_of_storm:
-            self._logger().warning(
-                "watchdog: %s step %d took %.1fms vs EWMA %.1fms "
-                "(>%.1fx) — step-time anomaly storm begins; further "
-                "anomalies count in ds_train_anomaly_total without "
-                "logging until %d normal steps pass",
-                kind, step, ms, mean, self.threshold, self.calm_steps)
-            self._dump_anomaly_trace(kind, step)
+            mean, first_of_storm = self._sample(self._stream(kind), ms)
+        if mean is not None:
+            self._announce(kind, ms, step, mean, first_of_storm)
+
+    def observe_serving_step(self, meter: "StepMeter", rows: int,
+                             step: int = 0) -> bool:
+        """One serving step's meter, telemetry on or off: its wall feeds
+        the ``fastgen`` stream (the one detector: the anomaly verdict of
+        ``observe_step_time`` rides it while telemetry is on), the gap
+        before it the meter's own stream under the same rule.  Where
+        either passes the rule AND :data:`STALL_FLOOR_MS`, ONE
+        ``fastgen.stall`` record goes into the span ring after the fact,
+        the same fields into one warning line, and
+        ``ds_fastgen_stall_total`` counts it.  True where it stalled."""
+        if not self.enabled:
+            return False
+        wall_ms = (meter.t1 - meter.t0) * 1e3
+        between_ms = meter.between_s * 1e3
+        with self._lock:
+            mean, first_of_storm = self._sample(self._stream("fastgen"),
+                                                wall_ms)
+            gap_mean, _ = self._sample(meter.gap, between_ms)
+        if state.enabled:
+            tm.FASTGEN_STEP_MS.observe(wall_ms)
+            if mean is not None:
+                self._announce("fastgen", wall_ms, step, mean,
+                               first_of_storm)
+        if mean is not None and wall_ms < STALL_FLOOR_MS:
+            mean = None
+        if gap_mean is not None and between_ms < STALL_FLOOR_MS:
+            gap_mean = None
+        if mean is None and gap_mean is None:
+            return False
+        self._note_stall(meter, rows, wall_ms, between_ms, mean, gap_mean)
+        return True
+
+    def _note_stall(self, meter: "StepMeter", rows: int,
+                    wall_ms: float, between_ms: float,
+                    mean: Optional[float],
+                    gap_mean: Optional[float]) -> None:
+        """The record of one paused step (PERF.md §3 names each field);
+        its line counts the loop's steps, live or not (``meter.steps``)."""
+        phase_ms, phase = max(
+            (getattr(meter, p) * 1e3, p) for p in STALL_PHASES)
+        lost_ms = ((wall_ms - mean if mean is not None else 0.0)
+                   + (between_ms - gap_mean if gap_mean is not None
+                      else 0.0))
+        wait_ms = meter.wait * 1e3
+        gc_ms = (_COLLECTOR.seconds - meter.gc_s0) * 1e3
+        programs = tm.FASTGEN_COMPILE_ON_PATH.value - meter.programs0
+        mine_ms, others_ms = (1e3 * s for s in meter.pause_clocks())
+        in_gap = mean is None or (gap_mean is not None
+                                  and between_ms > phase_ms)
+        # the clocks gained over the gap AND the step: the half that did
+        # not pause ran as a sound one does (all of the gap, the step but
+        # for its wait), the rest is the paused half's
+        if in_gap:
+            cpu_ms = wall_ms - wait_ms
+            between_cpu_ms = min(max(mine_ms - cpu_ms, 0.0), between_ms)
+        else:
+            between_cpu_ms = between_ms
+            cpu_ms = min(max(mine_ms - between_ms, 0.0), wall_ms - wait_ms)
+        proc_cpu_ms = cpu_ms + (0.0 if in_gap else others_ms)
+        between_proc_cpu_ms = between_cpu_ms + (others_ms if in_gap else 0.0)
+        if in_gap:
+            # the pause fell between two steps, in the caller's loop: its
+            # cause is read from the gap's clocks (no wait, no program)
+            phase, phase_ms = "between", between_ms
+            cause = stall_cause(lost_ms, 0.0, 0, gc_ms, between_cpu_ms,
+                                between_proc_cpu_ms)
+        else:
+            cause = stall_cause(lost_ms, wait_ms, programs, gc_ms, cpu_ms,
+                                proc_cpu_ms)
+        fields = {
+            "wall_ms": wall_ms, "between_ms": between_ms,
+            "ewma_ms": mean if mean is not None else gap_mean,
+            "lost_ms": lost_ms, "phase": phase, "phase_ms": phase_ms,
+            "wait_ms": wait_ms, "cpu_ms": cpu_ms,
+            "proc_cpu_ms": proc_cpu_ms,
+            "offcpu_ms": max(wall_ms - wait_ms - cpu_ms, 0.0),
+            "between_cpu_ms": between_cpu_ms,
+            "between_proc_cpu_ms": between_proc_cpu_ms,
+            "gc_ms": gc_ms,
+            "gc_n": _COLLECTOR.collections - meter.gc_n0,
+            "gc_gen2": _COLLECTOR.full - meter.gc_full0,
+            "programs": programs, "rows": rows, "cause": cause}
+        fields = {k: round(v, 3) if isinstance(v, float) else v
+                  for k, v in fields.items()}
+        tm.FASTGEN_STALL.inc()
+        get_tracer().record(STALL_SPAN, meter.t0, meter.t1 - meter.t0,
+                            fields)
+        self._logger().warning(
+            "watchdog: fastgen.stall step=%d %s", meter.steps,
+            " ".join(f"{k}={v}" for k, v in fields.items()))
 
     # -- memory-drift detector (ISSUE 20) ------------------------------------
     # dslint: disabled-path
@@ -297,7 +596,6 @@ class Watchdog:
                             f"anomaly_{kind}_step{step}.json")
         try:
             os.makedirs(self.postmortem_dir, exist_ok=True)
-            from .tracer import get_tracer
             get_tracer().dump(path)
             self._logger().warning(
                 "watchdog: span ring dumped to %s", path)

@@ -3,6 +3,8 @@ tree per scheduler step with its counts, three spans per request from one
 set of always-on stamps, and a record of every step program formed —
 written whether or not telemetry is on."""
 
+import functools
+import gc
 import sys
 import threading
 import time
@@ -153,6 +155,9 @@ class TestSpanIds:
 
 DISPATCH_CHILDREN = ["engine.admit", "engine.build_batch",
                      "engine.dispatch", "engine.commit"]
+#: records that come when they come, not with a step's shape: a program
+#: formed, a collection (wherever the interpreter starts one), a stall
+NOT_OF_THE_TREE = ("engine.program", "fastgen.gc", "fastgen.stall")
 
 
 def serve(n_req=3, prompt=12, new=5, before_enable=0):
@@ -177,7 +182,7 @@ def serve(n_req=3, prompt=12, new=5, before_enable=0):
     out = sched.run_to_completion()
     assert all(len(out[u]) == new for u in range(n_req))
     return sched, [r for r in get_tracer().records()
-                   if not r[0].startswith("engine.program")]
+                   if not r[0].startswith(NOT_OF_THE_TREE)]
 
 
 class TestStepTree:
@@ -210,7 +215,13 @@ class TestStepTree:
         # (the token gather of the decode segment is a warm call too)
         call = next(k for k in children_of(recs, dispatch[6])
                     if k[0] == "engine.dispatch")
-        assert call[5] is None and children_of(recs, call[6]) == []
+        assert children_of(recs, call[6]) == []
+        # what it prepared and what it called, as attributes: no child
+        # (``dispatch_ms_per_step`` reads the span's self time); the rest
+        # of the span is the pool's return
+        assert set(call[5]) == {"prepare_ms", "call_ms"}
+        assert 0 < call[5]["prepare_ms"] + call[5]["call_ms"] \
+            <= call[2] * 1e3
 
     def test_a_step_with_nothing_in_flight_keeps_the_fused_path(self):
         # the first step has no step to run ahead of: ``path=fused``,
@@ -305,7 +316,36 @@ def test_every_attribute_on_the_serving_path_has_a_metric_that_reads_it():
                        "tokens", "budget", "kv_pages_reserved",
                        "kv_tokens_held", "new_tokens", "trunk_passes",
                        "program", "kv_slots_held", "kv_slots_live",
-                       "kv_slots_bucket"}
+                       "kv_slots_bucket", "prepare_ms", "call_ms"}
+    # a paused step's one record and a collection's span (ISSUE 52), fed
+    # by hand: a step of 80 ms after ten of 1 ms, a full collection
+    from deepspeed_tpu.telemetry.watchdog import (StepMeter, get_collector,
+                                                  get_watchdog,
+                                                  install_collector)
+    meter, wd = StepMeter(), get_watchdog()
+    wd.reset()
+    get_tracer().clear()
+    meter.begin()                   # the CPU clocks' baseline
+    for wall in [0.001] * 10 + [0.08]:
+        meter.t1 = meter.t0 + wall
+        wd.observe_serving_step(meter, rows=4)
+    install_collector().loop = "fastgen"
+    gc.collect()
+    get_collector().loop = None
+    stall, = [r for r in get_tracer().records() if r[0] == "fastgen.stall"]
+    spans = {r[0]: set(r[5]) for r in get_tracer().records()
+             if r[0] in ("fastgen.stall", "fastgen.gc")}
+    assert spans["fastgen.gc"] == {"generation", "collected"}
+    # the stall's sums are metrics (``stall_lost_ms.serve``, ``stall_gc_ms.
+    # serve``, ``stall_offcpu_ms.serve``; the record itself ``stall_steps.
+    # serve``); the rest of the record is the line an operator reads
+    # (docs/DESIGN.md) and PERF.md section 7's table, the collection's pair
+    # is for whoever reads a trace: by decision no metric
+    carried |= spans["fastgen.stall"] - {
+        "wall_ms", "between_ms", "ewma_ms", "phase", "phase_ms", "wait_ms",
+        "cpu_ms", "proc_cpu_ms", "between_cpu_ms", "between_proc_cpu_ms",
+        "gc_n", "gc_gen2", "programs", "rows", "cause"}
+    assert {"lost_ms", "gc_ms", "offcpu_ms"} <= carried
     # the engagement counter of the one-pass mixed step (PR 30): held in
     # the span ring for whoever reads a trace, by decision no metric
     carried.remove("trunk_passes")
@@ -344,8 +384,89 @@ def test_a_step_with_telemetry_off_takes_no_counts():
     while sched.has_work:
         sched.step()
         assert sched._step_shape is _IDLE_STEP
+    # (a program formed on the path may be a stall too: the one record a
+    # step leaves with telemetry off)
     assert [r for r in get_tracer().records()
-            if not r[0].startswith("engine.program")] == []
+            if not r[0].startswith(NOT_OF_THE_TREE)] == []
+
+
+def test_a_collection_is_a_span_under_the_span_that_was_open(monkeypatch):
+    """With telemetry on a collection inside ``engine.dispatch`` is a
+    ``fastgen.gc`` span that names the dispatch as its parent, so the
+    dispatch's SELF time (``dispatch_ms_per_step``) no longer holds the
+    collector; one inside the delivery names the delivery."""
+    from deepspeed_tpu.inference.v2.model import RaggedInferenceModel
+    run_step = RaggedInferenceModel.run_step
+
+    forced = []
+
+    def collecting(self, *args, **kwargs):
+        if telemetry.enabled() and not forced:
+            forced.append(gc.collect())     # once: a full one is slow
+        return run_step(self, *args, **kwargs)
+
+    monkeypatch.setattr(RaggedInferenceModel, "run_step", collecting)
+    serve()
+    recs = get_tracer().records()
+    ids = by_id(recs)
+    full = [r for r in recs if r[0] == "fastgen.gc"
+            and r[5]["generation"] == 2 and r[7] in ids
+            and ids[r[7]][0] == "engine.dispatch"]
+    assert len(full) == 1 and full[0][5]["collected"] >= 0
+    for r in full:
+        parent = ids[r[7]]
+        assert parent[1] <= r[1] and r[1] + r[2] <= parent[1] + parent[2]
+        # the dispatch's self time is its duration less the collection
+        assert self_s(recs, parent) <= parent[2] - r[2] + 1e-9
+        # and what it prepared holds the collection, what it called not
+        assert parent[5]["prepare_ms"] >= r[2] * 1e3
+        assert parent[5]["call_ms"] <= (parent[2] - r[2]) * 1e3
+    # every collection of the run is nested where it fell, or a root
+    assert all(r[7] is None or r[7] in ids for r in recs
+               if r[0] == "fastgen.gc")
+
+
+def test_a_paused_step_leaves_one_record_with_telemetry_off():
+    """The tiny engine, telemetry off: a step whose delivery sleeps 80 ms
+    leaves one ``fastgen.stall`` in the ring (``phase=deliver``, off the
+    CPU) and nothing else new; a sleep in the caller's loop one with
+    ``phase=between``."""
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    from deepspeed_tpu.telemetry.watchdog import get_watchdog
+    assert not telemetry.enabled()
+    sched = FastGenScheduler(_slo_engine())
+    sched.submit(0, list(range(12)),
+                 SamplingParams(max_new_tokens=40, temperature=0.0))
+    seen = []
+
+    def on_token(uid, tok):
+        seen.append(tok)
+        if len(seen) == 30:
+            time.sleep(0.08)
+
+    for _ in range(4):              # the programs form here
+        sched.step(on_token)
+    get_watchdog().reset()
+    get_tracer().clear()
+    base = tm.FASTGEN_STALL.value
+    while sched.has_work:
+        if len(seen) == 35:
+            seen.append(None)
+            time.sleep(0.08)
+        sched.step(on_token)
+    recs = get_tracer().records()
+    assert [r[0] for r in recs] == ["fastgen.stall"] * 2
+    assert tm.FASTGEN_STALL.value == base + 2
+    in_step, between = (r[5] for r in recs)
+    assert (in_step["phase"], in_step["cause"]) == ("deliver", "offcpu")
+    assert in_step["wall_ms"] >= 80 > in_step["cpu_ms"] + in_step["wait_ms"]
+    assert in_step["offcpu_ms"] >= 70 and in_step["programs"] == 0
+    assert (between["phase"], between["cause"]) == ("between", "offcpu")
+    assert between["between_ms"] >= 80 > between["wall_ms"]
+    for a in (in_step, between):
+        assert a["cpu_ms"] <= a["wall_ms"] - a["wait_ms"] + 1
+        assert a["gc_ms"] <= a["wall_ms"] + a["between_ms"]
+        assert a["rows"] == 1 and a["lost_ms"] >= 70
 
 
 class TestRequestSpans:
@@ -404,7 +525,7 @@ class TestRequestSpans:
         assert 0 < req.submit_s <= req.admit_s <= req.first_token_s \
             <= req.token_s
         assert [r for r in get_tracer().records()
-                if not r[0].startswith("engine.program")] == []
+                if not r[0].startswith(NOT_OF_THE_TREE)] == []
 
     def test_a_failed_request_closes_the_span_that_was_open(self):
         from deepspeed_tpu.inference.v2 import (FastGenScheduler,
@@ -773,6 +894,51 @@ def test_a_model_with_delta_rule_layers_carries_the_delta_kinds_names():
         assert not any(re.search(p, name) for p in patterns), name
 
 
+@functools.lru_cache(maxsize=None)
+def every_expert_held():
+    """The tiny SmallThinker engine (every expert of its 4 layers held, 3
+    pairs a token), two prompts served: one step with telemetry OFF, then
+    it is switched on between two steps.  Built once for the tests below:
+    ``(engine, the ring's records but the programs')``."""
+    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
+    from test_smallthinker import engine_of, family, sequences_of
+    cfg, params = family(num_hidden_layers=4)
+    engine = engine_of(cfg, params)
+    sched = FastGenScheduler(engine)
+    for uid, p in enumerate(sequences_of((21, 30), seed=2)):
+        sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=12))
+    sched.step()
+    telemetry.enable()
+    sched.run_to_completion()
+    return engine, [r for r in get_tracer().records()
+                    if not r[0].startswith(NOT_OF_THE_TREE)]
+
+
+def test_held_experts_counts_go_beside_their_own_steps_tokens():
+    """``ROADMAP.md`` B15: the first live step after telemetry is switched
+    on drains the counts of a step that took no count of its tokens; they
+    go on no span (they used to go beside a stale ``moe_tokens``, 0 in a
+    fresh process, and ``moe_held_pair_share.whole`` read over 100%).
+    Every later span pairs a step's counts with that step's own tokens:
+    with every expert held, the pairs are exactly tokens x 3 x 4 layers."""
+    _, recs = every_expert_held()
+    steps = sorted((r for r in recs if r[0] == "fastgen.step"),
+                   key=lambda r: r[1])
+    first, later = steps[0][5], [r[5] for r in steps[1:]]
+    assert first["tokens"] > 0 and not [k for k in first if "moe_" in k]
+    counted = [a for a in later if "moe_pairs_here" in a]
+    assert len(counted) >= 10
+    assert all(a["moe_tokens"] > 0 for a in counted)
+    pairs = sum(a["moe_pairs_here"] for a in counted)
+    assert pairs / (sum(a["moe_tokens"] for a in counted) * 3 * 4) <= 1.0
+    assert pairs == sum(a["moe_tokens"] for a in counted) * 3 * 4
+    # each span's divisor is the step BEFORE it: the step it drains
+    tokens = [a["tokens"] for a in [first] + later]
+    for at, a in enumerate(later, start=1):
+        if "moe_tokens" in a:
+            assert a["moe_tokens"] == tokens[at - 1]
+
+
 def test_a_model_that_holds_every_expert_carries_the_names_that_were_here():
     """What the step of the SmallThinker family (PR 47: two page groups at
     one head count, every expert of every layer held, the router ahead of
@@ -791,19 +957,9 @@ def test_a_model_that_holds_every_expert_carries_the_names_that_were_here():
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.inference.v2 import FastGenScheduler, SamplingParams
     from deepspeed_tpu.inference.v2.modules import _kernel_name
     from deepspeed_tpu.moe import held
-    from test_smallthinker import engine_of, family, sequences_of
-    cfg, params = family(num_hidden_layers=4)
-    engine = engine_of(cfg, params)
-    sched = FastGenScheduler(engine)
-    telemetry.enable()
-    for uid, p in enumerate(sequences_of((21, 30), seed=2)):
-        sched.submit(uid, p.tolist(), SamplingParams(max_new_tokens=12))
-    sched.run_to_completion()
-    recs = [r for r in get_tracer().records()
-            if not r[0].startswith("engine.program")]
+    engine, recs = every_expert_held()
     steps = [r[5] for r in recs if r[0] == "fastgen.step" and r[5]]
     carried = {key for attrs in steps for key in attrs}
     assert carried == {

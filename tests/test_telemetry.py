@@ -530,7 +530,12 @@ class TestSchedulerTelemetry:
         assert tm.FASTGEN_STEP_MS.count == 0
         # the only records a disabled scheduler leaves are the step
         # programs it formed (ISSUE 24: written whatever the switch says)
-        assert all(r[0].startswith("engine.program")
+        # and one ``fastgen.stall`` a step that paused (ISSUE 52: here a
+        # step that formed its program on the path, ``cause=compile``)
+        stalls = [r for r in get_tracer().records()
+                  if r[0] == "fastgen.stall"]
+        assert all(r[5]["cause"] == "compile" for r in stalls)
+        assert all(r[0].startswith(("engine.program", "fastgen.stall"))
                    for r in get_tracer().records())
 
     def test_train_batch_spans_and_monitor_snapshot(self, tmp_path):

@@ -11,10 +11,12 @@ monitor-write drop counter, the ``DS_POSTMORTEM_ON_EXIT`` handler, and
 the disabled-path overhead bound for every new instrumentation site.
 """
 
+import gc
 import json
 import math
 import os
 import signal
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -271,6 +273,230 @@ class TestAnomalyDetector:
         wd.observe_step_time("train", 10.0, step=0)
         wd.observe_step_time("train", 500.0, step=1)  # warmup: ignored
         assert tm.TRAIN_ANOMALY.value == base
+
+
+# ---------------------------------------------------------------------------
+# the host's pauses (ISSUE 52): the collector's hook, one record a stall
+# ---------------------------------------------------------------------------
+
+def _warm(meter, n=10):
+    """``n`` empty steps on the real clocks: the stream's mean is a few
+    microseconds, so the floor of 50 ms is what a step has to pass."""
+    for _ in range(n):
+        meter.begin()
+        meter.end(4, 0)
+
+
+def _paused(meter, pause):
+    """One metered step that runs ``pause(meter)``; the records it left."""
+    _warm(meter)
+    get_tracer().clear()
+    meter.begin()
+    pause(meter)
+    meter.end(4, 0)
+    return [r[5] for r in get_tracer().records() if r[0] == "fastgen.stall"]
+
+
+def _collect(meter):
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < 0.08:
+        gc.collect()
+
+
+def _spin(meter):
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < 0.08:
+        pass
+
+
+def _sleep_beside_a_spinning_thread(meter):
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(1000))
+    other = threading.Thread(target=spin)
+    other.start()
+    time.sleep(0.1)
+    stop.set()
+    other.join()
+
+
+def _wait_for_the_device(meter):
+    t0 = time.perf_counter()
+    time.sleep(0.08)                # as the drain's d2h blocks
+    meter.wait += time.perf_counter() - t0
+
+
+class TestStallRecord:
+    @pytest.mark.parametrize("pause, cause", [
+        (_collect, "gc"), (lambda m: time.sleep(0.08), "offcpu"),
+        (_spin, "python"), (_sleep_beside_a_spinning_thread, "other_thread"),
+        (_wait_for_the_device, "device")],
+        ids=["gc", "offcpu", "python", "other_thread", "device"])
+    def test_a_pause_is_one_record_that_names_its_cause(self, pause, cause,
+                                                        warn_log):
+        from deepspeed_tpu.telemetry.watchdog import (StepMeter,
+                                                      install_collector)
+        install_collector()
+        assert not telemetry.enabled()
+        base = tm.FASTGEN_STALL.value
+        stall, = _paused(StepMeter(), pause)
+        half = 0.5 * stall["lost_ms"]
+        if cause == "python" and stall["cpu_ms"] < half \
+                or cause == "other_thread" \
+                and stall["proc_cpu_ms"] - stall["cpu_ms"] < half:
+            # a loaded host took the core from the spinning thread for
+            # most of the pause: by the record's own numbers that IS time
+            # off the CPU, and the rule has to say so
+            cause = "offcpu"
+        assert stall["cause"] == cause, stall
+        assert stall["wall_ms"] >= 80 and stall["lost_ms"] >= 79
+        assert stall["cpu_ms"] <= stall["wall_ms"] - stall["wait_ms"] + 1
+        assert stall["gc_ms"] <= stall["wall_ms"] + stall["between_ms"]
+        if cause == "gc":
+            assert stall["gc_gen2"] >= 1 and stall["gc_ms"] >= 40
+        if cause == "other_thread":
+            assert stall["proc_cpu_ms"] - stall["cpu_ms"] >= half
+        if cause == "device":
+            assert stall["wait_ms"] >= 80 > stall["offcpu_ms"]
+        # counted, and ONE line with the record's fields; telemetry is off:
+        # no anomaly verdict, no flight event, nothing else in the ring
+        assert tm.FASTGEN_STALL.value == base + 1
+        line, = [w for w in warn_log if "fastgen.stall" in w]
+        assert f"cause={cause}" in line and "wall_ms=" in line
+        assert tm.TRAIN_ANOMALY.value == 0
+        assert get_flight_recorder().events() == []
+        assert [r[0] for r in get_tracer().records()] == ["fastgen.stall"]
+
+    def test_the_rule_is_three_times_the_mean_and_fifty_ms(self):
+        """Fed by hand at 10 ms a step: 2.9x the mean is sound; 3.1x is the
+        detector's anomaly and, under 50 ms, no stall; 60 ms is one."""
+        from deepspeed_tpu.telemetry.watchdog import StepMeter
+        wd, meter = get_watchdog(), StepMeter()
+        meter.begin()               # the CPU clocks' baseline
+
+        def step(ms):
+            meter.t1 = meter.t0 + ms / 1e3
+            wd.observe_serving_step(meter, rows=4)
+            return [r for r in get_tracer().records()
+                    if r[0] == "fastgen.stall"]
+
+        for _ in range(30):         # the mean starts at 0: 9.99 by now
+            assert step(10.0) == []
+        assert step(29.0) == []
+        assert wd._kinds["fastgen"].anomalies == 0
+        assert step(45.0) == [] and wd._kinds["fastgen"].anomalies == 1
+        rec, = step(60.0)
+        assert rec[5]["wall_ms"] == 60.0 and rec[2] == pytest.approx(0.06)
+        assert 45.0 < rec[5]["lost_ms"] < 50.0 < rec[5]["offcpu_ms"]
+        # anomalous samples do not move the mean: it holds the sound ones
+        assert 10.0 < wd._kinds["fastgen"].mean_ms < 14.0
+        # the detector is one: with telemetry on the same sample is the
+        # anomaly verdict AND the stall, counted once each
+        telemetry.enable()
+        base = (tm.TRAIN_ANOMALY.value, tm.FASTGEN_STALL.value)
+        assert len(step(70.0)) == 2
+        assert (tm.TRAIN_ANOMALY.value, tm.FASTGEN_STALL.value) \
+            == (base[0] + 1, base[1] + 1)
+        assert wd._kinds["fastgen"].anomalies == 3
+        events = [e for e in get_flight_recorder().events()
+                  if e["kind"] == "watchdog.anomaly"]
+        assert len(events) == 1 and events[0]["stream"] == "fastgen"
+
+    def test_a_pause_between_two_steps_reads_phase_between(self):
+        from deepspeed_tpu.telemetry.watchdog import StepMeter
+        meter = StepMeter()
+        _warm(meter)
+        get_tracer().clear()
+        time.sleep(0.08)            # the caller's loop
+        _warm(meter, 1)
+        stall, = [r[5] for r in get_tracer().records()]
+        assert (stall["phase"], stall["cause"]) == ("between", "offcpu")
+        assert stall["between_ms"] >= 80 > 50 > stall["wall_ms"]
+        assert stall["lost_ms"] >= 79 and stall["between_cpu_ms"] < 40
+
+    def test_a_watchdog_switched_off_judges_nothing(self):
+        from deepspeed_tpu.telemetry.watchdog import StepMeter
+        get_watchdog().enabled = False
+        assert _paused(StepMeter(), lambda m: time.sleep(0.06)) == []
+        assert get_watchdog()._kinds == {}
+
+    def test_the_meter_costs_microseconds_a_step(self):
+        """What every serving step pays with telemetry off (measured:
+        ``tools/step_meter_cost.py``, CHANGES.md; the bound here leaves a
+        loaded CI host its noise)."""
+        from deepspeed_tpu.telemetry.watchdog import StepMeter
+        meter, n = StepMeter(), 20_000
+        _warm(meter, 100)
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                meter.begin()
+                t = time.perf_counter()
+                meter.admission += time.perf_counter() - t
+                t = time.perf_counter()
+                meter.wait += time.perf_counter() - t
+                meter.end(4, 0)
+            best = min(best, (time.perf_counter() - t0) / n)
+        assert best < 20e-6, f"{best * 1e6:.2f} us a step"
+
+
+class TestCollectorHook:
+    def test_hooked_once_and_counts_with_telemetry_off(self):
+        from deepspeed_tpu.telemetry.watchdog import (get_collector,
+                                                      install_collector)
+        hook = install_collector()
+        assert install_collector() is hook is get_collector()
+        assert gc.callbacks.count(hook) == 1
+        hook.loop = "fastgen"
+        before = (hook.seconds, hook.collections, hook.full,
+                  tm.HOST_GC_SECONDS.value)
+        gc.collect()
+        gc.collect(0)
+        assert hook.collections == before[1] + 2
+        assert hook.full == before[2] + 1
+        assert hook.seconds > before[0]
+        assert tm.HOST_GC_SECONDS.value - before[3] \
+            == pytest.approx(hook.seconds - before[0])
+        assert get_tracer().records() == []      # no span while off
+
+    @pytest.mark.parametrize("loop", ["fastgen", "train"])
+    def test_a_collection_is_a_span_of_the_loop_that_stepped(self, loop):
+        from deepspeed_tpu.telemetry.watchdog import install_collector
+        hook = install_collector()
+        telemetry.enable()
+        hook.loop = None
+        gc.collect(0)               # before any loop stepped: no name
+        assert get_tracer().records() == []
+        hook.loop = loop
+        with trace_span(loop + ".outer") as outer:
+            gc.collect(1)
+        gc.collect(0)               # between two steps: a root
+        hook.loop = None
+        inner, parent, root = get_tracer().records()
+        assert inner[0] == root[0] == loop + ".gc"
+        assert inner[7] == outer.id == parent[6] and root[7] is None
+        assert inner[5]["generation"] == 1 and root[5]["generation"] == 0
+        assert inner[5]["collected"] >= 0
+        assert parent[1] <= inner[1] \
+            and inner[1] + inner[2] <= parent[1] + parent[2]
+        # the name passes the filter of the benchmark's trace reduction
+        import re
+        assert re.match(r"^(bench\.|fastgen\.|engine\.|train\.|serving\.|"
+                        r"zero\.|sched\.|kv\.)", inner[0])
+
+    def test_the_loops_tell_the_hook_who_steps(self, train_engine,
+                                               serving_engine):
+        from deepspeed_tpu.inference.v2 import FastGenScheduler
+        from deepspeed_tpu.telemetry.watchdog import get_collector
+        hook = get_collector()
+        assert gc.callbacks.count(hook) == 1     # an engine was built
+        train_engine.train_batch(batch=_train_batch_arrays(train_engine))
+        assert hook.loop == "train"
+        FastGenScheduler(serving_engine).step()
+        assert hook.loop == "fastgen"
 
 
 # ---------------------------------------------------------------------------

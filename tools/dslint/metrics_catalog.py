@@ -32,7 +32,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 AREAS = ("serving", "comm", "kv", "train", "fastgen", "chaos",
          "fleet", "slo", "telemetry", "pool", "disagg", "journey",
-         "mem")
+         "mem", "host")
 NAME_RE = re.compile(
     r"^ds_(%s)_[a-z][a-z0-9_]*$" % "|".join(AREAS))
 
