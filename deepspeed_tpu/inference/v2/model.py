@@ -67,6 +67,32 @@ def serving_peak_flops() -> Optional[float]:
     return _device_peak_flops()
 
 
+def serving_tokens_at_ridge(params: Any) -> Optional[float]:
+    """Tokens a step carries where the device stops waiting for the
+    weights and starts waiting for its matrix unit: a step streams every
+    weight once (``bytes_per_weight`` each, the served tree's own mean:
+    2 in bf16, near 1 under weight-only fp8 / int8) and multiplies each
+    token by it (2 FLOPs), so ``peak_flops * bytes_per_weight / (2 *
+    hbm_bytes_per_s)``: 240 for bf16 weights on a v5e.  Under it a
+    further token rides the stream for nearly nothing, past it every
+    token costs its full time.  From the published tables alone (no
+    ``DS_PEAK_FLOPS``: that one sizes a gauge, this one a schedule);
+    None where the device has no entry in either, and the scheduler
+    then admits as if there were no ridge."""
+    from ...profiling.flops_profiler import (_device_hbm_bytes_per_s,
+                                             _device_peak_flops)
+    peak, hbm = _device_peak_flops(), _device_hbm_bytes_per_s()
+    if not peak or not hbm:
+        return None
+    weights = [w for w in jax.tree.leaves(params)
+               if getattr(w, "ndim", 0) >= 2]
+    elements = sum(int(w.size) for w in weights)
+    if not elements:
+        return None
+    nbytes = sum(int(w.size) * w.dtype.itemsize for w in weights)
+    return peak * (nbytes / elements) / (2.0 * hbm)
+
+
 def utilization(flops_per_s: float, peak: Optional[float]) -> float:
     """``flops_per_s / peak``; 0.0 where the device has no peak."""
     return flops_per_s / peak if peak else 0.0

@@ -3,10 +3,15 @@
 The reference keeps this in the MII project and engine_v2 only exposes
 the ``query/can_schedule/put/flush`` contract (engine_v2.py:158-251);
 SURVEY §3.4 calls for the scheduler in-repo.  Policy (Dynamic SplitFuse,
-FastGen blog): every step fills a fixed token budget — running decodes
-first (one token each), then prompt *chunks* from admitted requests, so
-long prompts are split across steps and fused with decodes, keeping
-per-step latency flat.
+FastGen blog): every step takes running decodes first (one token each),
+then prompt *chunks* from admitted requests up to a fixed token budget,
+so long prompts are split across steps and fused with decodes, keeping
+per-step latency flat.  The budget is the ceiling; how far a step is
+filled under it follows the device's ridge (ISSUE 53, ``_plan_step``):
+a step that streams its weights for fewer decode rows than the ridge
+takes pending requests only while its padded tokens stay at or under the
+ridge, and a request it leaves out rides the next step, which admits it
+whatever it costs (``Request.passed_over``).
 
 Admission runs on incremental page/token/sequence counters (O(1) per
 candidate) rather than re-validating the whole batch through
@@ -86,6 +91,7 @@ from ...telemetry.watchdog import get_collector
 from ...telemetry.workload_trace import get_workload_trace
 from ...utils.comms_logging import serving_counters
 from .engine import InferenceEngineV2
+from .model import serving_tokens_at_ridge
 from .ragged.blocked_allocator import KVAllocationError, NULL_PAGE
 from .sampling import SamplingParams, sample
 from .snapshot import (SNAPSHOT_VERSION, SnapshotError,
@@ -159,6 +165,10 @@ class Request:
     #: SAME journey object, and queues again on the survivor
     journey: Optional[object] = None
     journey_admitted: bool = False
+    #: steps whose plan stopped for the ridge while this request was
+    #: pending (ISSUE 53); at ``_PASS_OVER_BOUND`` the next step that
+    #: plans admits it whatever the step then costs
+    passed_over: int = 0
 
     @property
     def prefill_remaining(self) -> int:
@@ -237,6 +247,9 @@ class _StepPlan:
     #: requests moved pending -> running this step — returned to pending
     #: on a failed dispatch (their engine sequence may not exist yet)
     new_admits: List[Request] = dataclasses.field(default_factory=list)
+    #: the pending requests a plan that stopped for the ridge left out;
+    #: marked (``Request.passed_over``) only once the plan is the step's
+    held: List[Request] = dataclasses.field(default_factory=list)
 
 
 class _Admission:
@@ -293,6 +306,12 @@ def _group_key(p: SamplingParams) -> tuple:
 class FastGenScheduler:
     """Drives an InferenceEngineV2 with the SplitFuse policy."""
 
+    #: how often the ridge may leave one pending request out (ISSUE 53):
+    #: the one constant of the rule.  A request waits at most this many
+    #: steps longer for its first token than under admit-all; PERF.md
+    #: section 6 has the step histogram that chose it
+    _PASS_OVER_BOUND = 1
+
     def __init__(self, engine: InferenceEngineV2,
                  token_budget: Optional[int] = None,
                  rng: Optional[jax.Array] = None,
@@ -345,6 +364,14 @@ class FastGenScheduler:
         self._rng = rng
         self.last_step_scheduled = 0
         self._step_shape = _IDLE_STEP
+        #: tokens a step carries at the device's ridge, from its published
+        #: peaks and the served weights' bytes; None: the device has none
+        #: and every step is planned as if there were no ridge
+        self._ridge = serving_tokens_at_ridge(engine.model.params)
+        #: (pending requests the step considered, of them held back for
+        #: the ridge): the ``fastgen.step`` span's ``prompt_offers`` /
+        #: ``prompts_held``
+        self._step_prompts = (0, 0)
         #: counts past the rows of a sampled-token vector (a model with
         #: held experts: RaggedInferenceModel.step_tail), the last ones
         #: drained, and the tokens of the step they belong to
@@ -1506,6 +1533,10 @@ class FastGenScheduler:
                 ("prefill_rows", prefill_rows),
                 ("prefill_tokens", prefill_tokens), ("tokens", tokens),
                 ("budget", self._budget),
+                # pending requests the step considered, and those of them
+                # the ridge left to the next step
+                ("prompt_offers", self._step_prompts[0]),
+                ("prompts_held", self._step_prompts[1]),
                 ("kv_pages_reserved", pages), ("kv_tokens_held", held),
                 # how often the step's (last) program streams its
                 # weights: 1 for every kind; 2 would be a mixed step
@@ -1615,6 +1646,22 @@ class FastGenScheduler:
                                - (alloc.free_pages
                                   + alloc.parked_pages))
 
+    def _padded_tokens(self, decode_rows: int,
+                       pieces: Sequence[int]) -> int:
+        """What the device pays for a step of ``decode_rows`` one-token
+        rows and prompt ``pieces`` (their lengths), by the engine's own
+        lattice: the one-token segment's row bucket, plus the prompt
+        segment's row bucket times the bucket of its longest piece (a
+        piece of one token is a row of the first segment:
+        ``engine.step_sample``)."""
+        lat = self._engine.model.lattice
+        long = [n for n in pieces if n > 1]
+        ones = decode_rows + len(pieces) - len(long)
+        padded = lat.bucket_s(ones) if ones else 0
+        if long:
+            padded += lat.bucket_s(len(long)) * lat.bucket_q(max(long))
+        return padded
+
     # dslint: hot-path
     def _plan_step(self, slot: Optional[Dict[int, int]]
                    ) -> Optional[_StepPlan]:
@@ -1699,6 +1746,8 @@ class FastGenScheduler:
                 # token-array length) was AOT-lowered
                 return None
 
+            decode_rows = len(uids)
+
             # 2. continue partial prefills, then admit pending, chunked
             # to budget
             def try_prefill(req: Request, is_new: bool) -> bool:
@@ -1761,8 +1810,32 @@ class FastGenScheduler:
                 except Exception as e:
                     self._fail_request(req, "poisoned",
                                        f"{type(e).__name__}: {e}")
+            # the ridge (ISSUE 53): a step with fewer decode rows than the
+            # device's ridge streams its weights for them, and tokens that
+            # join ride the stream until the padded count passes the
+            # ridge; from there each pays in full, and the next step, which
+            # runs for the decode rows anyway, carries it for less.  With
+            # no decode row that step would exist for the prompt alone, and
+            # past the ridge a prompt costs the same in any step: both are
+            # planned as without a ridge
+            ridge = self._ridge
+            if not decode_rows or ridge is None \
+                    or self._padded_tokens(decode_rows, ()) >= ridge:
+                ridge = None
             while self._pending and adm.tokens_left > 0:
                 req = self._pending[0]
+                if ridge is not None and plan.advances \
+                        and req.passed_over < self._PASS_OVER_BOUND \
+                        and self._padded_tokens(
+                            decode_rows,
+                            [n for _, n in plan.advances]
+                            + [min(req.prefill_remaining,
+                                   adm.tokens_left)]) > ridge:
+                    # held BEFORE anything is asked of the engine for it
+                    # (no prefix match, no tracked sequence, no page), and
+                    # with it every request that waits behind it
+                    plan.held = list(self._pending)
+                    break
                 try:
                     admitted = try_prefill(req, is_new=True)
                 except Exception as e:
@@ -1782,6 +1855,7 @@ class FastGenScheduler:
         serving_counters.record_step()
         self._preempted_this_step = False
         self._step_shape = _IDLE_STEP
+        self._step_prompts = (0, 0)
         self._expire_requests()
 
         spec_drained: Optional[Dict[int, int]] = None
@@ -1839,6 +1913,16 @@ class FastGenScheduler:
             # automatically once the pool frees up
             self._preempt_largest()
             return out_prev
+
+        if new_admits or plan.held:
+            # the plan is the step's from here: what it left out for the
+            # ridge has been passed over once (``_degrade_oom`` takes it
+            # back with the admissions)
+            for req in plan.held:
+                req.passed_over += 1
+            self._step_prompts = (len(new_admits) + len(plan.held),
+                                  len(plan.held))
+            serving_counters.record_prompt_offers(*self._step_prompts)
 
         sampled_rows = [i for i, r in enumerate(reqs)
                         if r.prefill_remaining == 0]
@@ -1898,7 +1982,7 @@ class FastGenScheduler:
                 # ladder, retry next step
                 if ahead:
                     out_prev = self._drain(on_token)
-                self._degrade_oom(e, advances, new_admits)
+                self._degrade_oom(e, advances, new_admits, plan.held)
                 return out_prev
             self._oom_streak = 0
             inflight = _Inflight(
@@ -1934,7 +2018,7 @@ class FastGenScheduler:
                 logits = self._engine.put(uids, tokens, do_checks=False,
                                           fused=put_fused)
             except KVAllocationError as e:
-                self._degrade_oom(e, advances, new_admits)
+                self._degrade_oom(e, advances, new_admits, plan.held)
                 return out_prev
             self._oom_streak = 0
             groups: Dict[tuple, List[int]] = {}
@@ -2174,7 +2258,8 @@ class FastGenScheduler:
 
     def _degrade_oom(self, exc: Exception,
                      advances: List[Tuple[Request, int]],
-                     new_admits: List[Request]) -> None:
+                     new_admits: List[Request],
+                     held: Sequence[Request] = ()) -> None:
         """KV allocation failed mid-dispatch: degrade instead of
         crashing the step loop.  The failed step's prompt advances are
         rolled back (no token is silently skipped), then the ladder
@@ -2184,6 +2269,9 @@ class FastGenScheduler:
         structured "oom" error."""
         for req, chunk in advances:
             req.prompt_sent -= chunk
+        # the step that passed ``held`` over for the ridge never ran
+        for req in held:
+            req.passed_over -= 1
         for req in reversed(new_admits):
             # an admit whose engine sequence never materialized goes
             # back to the front of the queue (reversed re-insertion at
@@ -2243,6 +2331,7 @@ class FastGenScheduler:
                 subsystems=bd["subsystems"], rungs=rungs)
         self.last_step_scheduled = 0
         self._step_shape = _IDLE_STEP
+        self._step_prompts = (0, 0)
 
     # -- live engine snapshot / deterministic restore (ISSUE 8) --------------
     def close(self) -> None:

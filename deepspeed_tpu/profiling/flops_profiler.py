@@ -41,16 +41,32 @@ PEAK_FLOPS = {
 }
 
 
-def device_peak_flops() -> float:
-    """Published peak of the device JAX computes on; an unknown
-    ``device_kind`` is an error, never a default."""
+# Peak HBM bytes/s per chip (the same pages' "HBM bandwidth"), keyed as
+# ``PEAK_FLOPS``.  A device that is not here has no bandwidth, and so no
+# ridge: nothing is sized by an assumed chip's.
+HBM_BYTES_PER_S = {
+    "TPU v4": 1200e9,
+    "TPU v5 lite": 819e9,
+    "TPU v5e": 819e9,
+    "TPU v5p": 2765e9,
+    "TPU v6e": 1640e9,
+}
+
+
+def _published(table: Dict[str, float], what: str, where: str) -> float:
     kind = str(jax.devices()[0].device_kind)
-    for name, peak in PEAK_FLOPS.items():
+    for name, peak in table.items():
         if name.lower() in kind.lower():
             return peak
     raise LookupError(
-        f"no published peak FLOP/s for device_kind {kind!r} — add it to "
-        "profiling.flops_profiler.PEAK_FLOPS with its source")
+        f"no published {what} for device_kind {kind!r} — add it to "
+        f"profiling.flops_profiler.{where} with its source")
+
+
+def device_peak_flops() -> float:
+    """Published peak of the device JAX computes on; an unknown
+    ``device_kind`` is an error, never a default."""
+    return _published(PEAK_FLOPS, "peak FLOP/s", "PEAK_FLOPS")
 
 
 def _device_peak_flops() -> Optional[float]:
@@ -58,6 +74,16 @@ def _device_peak_flops() -> Optional[float]:
     measured FLOP/s only, no utilization)."""
     try:
         return device_peak_flops()
+    except LookupError:
+        return None
+
+
+def _device_hbm_bytes_per_s() -> Optional[float]:
+    """The published HBM bandwidth of the device JAX computes on, or
+    None where it has none."""
+    try:
+        return _published(HBM_BYTES_PER_S, "HBM bandwidth",
+                          "HBM_BYTES_PER_S")
     except LookupError:
         return None
 

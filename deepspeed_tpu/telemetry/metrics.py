@@ -41,6 +41,12 @@ SERVING_PREFIX_EVICTED_PAGES = registry.counter(
     "prefix-cache pages LRU-evicted under pool pressure")
 SERVING_PREFILL_TOKENS = registry.counter(
     "ds_serving_prefill_tokens_total", "prompt tokens actually prefilled")
+SERVING_PROMPT_OFFERS = registry.counter(
+    "ds_serving_prompt_offers_total",
+    "pending requests a step considered: admitted, or held for the ridge")
+SERVING_PROMPTS_HELD = registry.counter(
+    "ds_serving_prompts_held_total",
+    "pending requests a step left to the next one for the device's ridge")
 
 # -- gradient-collective wire plan (CollectiveScheduler) --------------------
 COMM_BUCKET_COUNT = registry.gauge(

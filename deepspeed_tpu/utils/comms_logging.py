@@ -55,7 +55,8 @@ class ServingCounters:
             tm.SERVING_PROGRAMS, tm.SERVING_STEPS, tm.SERVING_H2D_BYTES,
             tm.SERVING_D2H_BYTES, tm.SERVING_LOGITS_BYTES,
             tm.SERVING_PREFIX_LOOKUP_TOKENS, tm.SERVING_PREFIX_HIT_TOKENS,
-            tm.SERVING_PREFIX_EVICTED_PAGES, tm.SERVING_PREFILL_TOKENS)
+            tm.SERVING_PREFIX_EVICTED_PAGES, tm.SERVING_PREFILL_TOKENS,
+            tm.SERVING_PROMPT_OFFERS, tm.SERVING_PROMPTS_HELD)
 
     def reset(self) -> None:
         for c in self._counters:
@@ -98,6 +99,14 @@ class ServingCounters:
     def prefill_tokens(self) -> int:
         return tm.SERVING_PREFILL_TOKENS.value
 
+    @property
+    def prompt_offers(self) -> int:
+        return tm.SERVING_PROMPT_OFFERS.value
+
+    @property
+    def prompts_held(self) -> int:
+        return tm.SERVING_PROMPTS_HELD.value
+
     def record_step(self) -> None:
         tm.SERVING_STEPS.inc()
 
@@ -125,6 +134,10 @@ class ServingCounters:
     def record_prefill(self, num_tokens: int) -> None:
         tm.SERVING_PREFILL_TOKENS.inc(int(num_tokens))
 
+    def record_prompt_offers(self, offers: int, held: int) -> None:
+        tm.SERVING_PROMPT_OFFERS.inc(int(offers))
+        tm.SERVING_PROMPTS_HELD.inc(int(held))
+
     def snapshot(self) -> Dict[str, Any]:
         steps = max(self.steps, 1)
         return {
@@ -142,6 +155,8 @@ class ServingCounters:
                 if self.prefix_lookup_tokens else 0.0,
             "prefix_evicted_pages": self.prefix_evicted_pages,
             "prefill_tokens": self.prefill_tokens,
+            "prompt_offers": self.prompt_offers,
+            "prompts_held": self.prompts_held,
         }
 
 

@@ -313,7 +313,8 @@ def test_every_attribute_on_the_serving_path_has_a_metric_that_reads_it():
     _, recs = serve(n_req=4, prompt=20, new=4, before_enable=2)
     carried = {key for r in recs if r[5] for key in r[5]}
     assert carried == {"path", "rows", "prefill_rows", "prefill_tokens",
-                       "tokens", "budget", "kv_pages_reserved",
+                       "tokens", "budget", "prompt_offers", "prompts_held",
+                       "kv_pages_reserved",
                        "kv_tokens_held", "new_tokens", "trunk_passes",
                        "program", "kv_slots_held", "kv_slots_live",
                        "kv_slots_bucket", "prepare_ms", "call_ms"}
@@ -663,7 +664,8 @@ def test_a_model_of_two_page_groups_carries_the_window_groups_names():
     assert new <= carried
     assert carried - new == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
-        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "budget", "prompt_offers", "prompts_held", "kv_pages_reserved",
+        "kv_tokens_held", "trunk_passes",
         "program", "moe_pairs_here", "moe_expert_load_max",
         "moe_experts_touched", "moe_tokens", "kv_slots_held",
         "kv_slots_live", "kv_slots_bucket"}
@@ -743,7 +745,8 @@ def test_a_model_with_a_state_pool_carries_the_state_pools_names():
     assert new <= carried
     assert carried - new == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
-        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "budget", "prompt_offers", "prompts_held", "kv_pages_reserved",
+        "kv_tokens_held", "trunk_passes",
         "program", "kv_slots_held", "kv_slots_live", "kv_slots_bucket"}
     assert not any(r[0].startswith("kv.state") for r in recs)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -860,7 +863,8 @@ def test_a_model_with_delta_rule_layers_carries_the_delta_kinds_names():
     assert new | kept <= carried
     assert carried - new - kept == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
-        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "budget", "prompt_offers", "prompts_held", "kv_pages_reserved",
+        "kv_tokens_held", "trunk_passes",
         "program", "kv_slots_held", "kv_slots_live", "kv_slots_bucket"}
     assert not any(r[0].startswith("kv.state") for r in recs)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -964,7 +968,8 @@ def test_a_model_that_holds_every_expert_carries_the_names_that_were_here():
     carried = {key for attrs in steps for key in attrs}
     assert carried == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
-        "budget", "kv_pages_reserved", "kv_tokens_held", "trunk_passes",
+        "budget", "prompt_offers", "prompts_held", "kv_pages_reserved",
+        "kv_tokens_held", "trunk_passes",
         "program", "moe_pairs_here", "moe_expert_load_max",
         "moe_experts_touched", "moe_tokens", "kv_slots_held",
         "kv_slots_live", "kv_slots_bucket", "kv_pages_reserved_window",
