@@ -39,7 +39,7 @@ from ...ops.mla_attention import (latent_write, mla_fresh_attention,
                                   mla_paged_attention, plane_width)
 from ...ops.paged_attention import (KVPages, gather_last, token_positions,
                                     write_kv)
-from ...ops.ssm import conv_step, ssm_scan
+from ...ops.ssm import conv_step, ssd_scan, ssm_scan
 from ...telemetry import get_tracer
 from ...telemetry import metrics as tm
 from ...telemetry.watchdog import get_watchdog
@@ -1164,8 +1164,15 @@ class RaggedInferenceModel:
                 at = p * per[kind]
                 return (start[kind] + at if leading else at) + met
 
+            # a routed layer's place among the routed ones of its period
+            # (every layer of it, or under ``half_blocks`` the "ffn" ones)
+            place = {j: n for n, j in enumerate(
+                j for j, (kind, _) in enumerate(runs)
+                if kind == "ffn" or not cfg.half_blocks)}
+
             def routed(p, j):
-                return p * period + j if experts is not None else None
+                return p * len(place) + place[j] \
+                    if experts is not None and j in place else None
 
         def layer(carry, kind, at, lp=None, routed=None):
             """Layer ``at`` of its kind, ``lp`` its weights (a)."""
@@ -1179,7 +1186,8 @@ class RaggedInferenceModel:
         def outside(carry, i, lp):
             """Layer ``i``, one that no scan runs over."""
             return layer(carry, kinds[i], kinds[:i].count(kinds[i]), lp,
-                         i - leading)
+                         kinds[leading:i].count("ffn") if cfg.half_blocks
+                         else i - leading)
 
         def one_period(carry, xs):
             lps, p = xs
@@ -1477,19 +1485,19 @@ class RaggedInferenceModel:
         out: the kind's mixer (:data:`MIXERS`) at layer ``at`` of its pools,
         then the feed-forward (``routed``: the layer's place among the routed
         ones, from the number ``experts`` comes with; HERE is where a router
-        reads), each behind a norm of its input (``post_norm``: its output)."""
+        reads), each behind a norm of its input (``post_norm``: its output).
+        Under ``cfg.half_blocks`` a layer is ONE of the two
+        (:meth:`_half_block`)."""
         cfg = ctx.cfg
         x, *rest = carry
         mixer = MIXERS[kind]
+        if cfg.half_blocks:
+            return self._half_block(x, rest, lp, mixer, kind=kind, at=at,
+                                    ctx=ctx, routed=routed, experts=experts)
         h = x if cfg.post_norm else self._norm(lp["norm1"], x)
         plan = (self._route(lp, h, ctx, layout=True) if "moe" in lp
                 and cfg.router_reads == "mixer" else None)
-        held_at = [ctx.places[name] for name in mixer.pools]
-        out, written = mixer.run(
-            self, h, [rest[i] for i in held_at], lp[mixer.weights], at,
-            kind=kind, ctx=ctx)
-        for i, pool in zip(held_at, written):
-            rest[i] = pool
+        out = self._mix(mixer, h, rest, lp, at, kind, ctx)
         if cfg.sandwich_norm:
             out = self._norm(lp["norm1_post"], out)
         if cfg.post_norm:
@@ -1530,7 +1538,7 @@ class RaggedInferenceModel:
         out, pairs = held.held_experts_ffn(
             h2, chosen, weights, stack, cfg.experts_first, plan=rows,
             layer=routed - first if first else routed, valid=ctx.valid,
-            act=cfg.expert_act)
+            act=cfg.expert_act, tile=cfg.moe_row_tile)
         out = out.reshape(S, Q, E)
         if "shared" in mp:
             out = out + T._mlp_block(cfg, mp["shared"], h)
@@ -1806,10 +1814,40 @@ class RaggedInferenceModel:
                 bias=lp["moe"]["router_bias"], groups=cfg.router_groups,
                 keep=cfg.router_topk_groups) if cfg.router_groups else {}))
         rows = held.plan_rows(chosen, ctx.valid, cfg.experts_first,
-                              cfg.held_experts, cfg.n_routed_experts) \
-            if layout else None
+                              cfg.held_experts, cfg.n_routed_experts,
+                              cfg.moe_row_tile) if layout else None
         return chosen, weights, rows
 
+
+    def _half_block(self, x, rest, lp, mixer, *, kind, at, ctx: Pass, routed,
+                    experts):
+        """:meth:`_layer_body` for a model whose layers are ONE sub-layer
+        each (``cfg.half_blocks``): ``x + sub(norm(x))``, ``sub`` the kind's
+        mixer, or for a kind that has none (``MIXERS[kind].run`` None: it
+        caches nothing) the feed-forward.  One norm, one residual: the
+        other half is not traced."""
+        h = self._norm(lp["norm1"], x)
+        if mixer.run is None:
+            out, counts = self._feed_forward(
+                lp, h, ctx=ctx, routed=routed, experts=experts,
+                counts=rest[-1] if self.step_tail else None, plan=None)
+            if counts is not None:
+                rest[-1] = counts
+        else:
+            out = self._mix(mixer, h, rest, lp, at, kind, ctx)
+        return (x + out.astype(x.dtype), *rest)
+
+    def _mix(self, mixer, h, rest, lp, at, kind, ctx: Pass):
+        """``mixer`` over ``h`` at layer ``at`` of the pools it names, which
+        it finds in ``rest`` (the carry behind ``x``) and leaves there as
+        written; returns its output."""
+        held_at = [ctx.places[name] for name in mixer.pools]
+        out, written = mixer.run(
+            self, h, [rest[i] for i in held_at], lp[mixer.weights], at,
+            kind=kind, ctx=ctx)
+        for i, pool in zip(held_at, written):
+            rest[i] = pool
+        return out
 
     def _delta_recurrence(self, qkv, g, beta, pools, mp, layer, ctx: Pass):
         """What the delta-rule mixers share behind their projections: the
@@ -1879,6 +1917,48 @@ class RaggedInferenceModel:
                           y.reshape(y.shape[:2] + (-1,)).astype(dtype),
                           mp["w_out"].astype(dtype)), (s_pool, conv_pool)
 
+    def _ssd_mixer(self, u, pools, mp, layer, *, kind, ctx: Pass):
+        """The Mamba-2 mixer of ``u`` (all tokens of the segments): its ONE
+        in projection (``[z | x B C | dt]``) once over all of them; the
+        convolution over x, B and C and the recurrence segment by segment,
+        each row from and to its slot of ``pool[layer]`` (``ops/ssm.py::
+        ssd_scan``); then the gate BEFORE the norm, ``y silu(z)``, an
+        RMSNorm over each of the ``ssm_groups`` groups of channels under a
+        gain over all of them, and the output projection.  Returns (output
+        in ``u``'s layout, (h pool, conv pool))."""
+        cfg, rows, (h_pool, conv_pool) = ctx.cfg, ctx.rows, pools
+        segments = next(iter(ctx.by_group.values()))
+        dtype, f32 = cfg.dtype, jnp.float32
+        d, H, G = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_groups
+        gn = G * cfg.ssm_state_dim
+        zxd = jnp.einsum("sqe,ef->sqf", u, mp["w_in"].astype(dtype),
+                         preferred_element_type=f32)
+        z, xbc = zxd[..., :d], zxd[..., d:2 * d + 2 * gn]
+        dt = jax.nn.softplus(zxd[..., 2 * d + 2 * gn:]
+                             + mp["dt_bias"].astype(f32))
+        A = -jnp.exp(mp["A_log"].astype(f32))
+        ys = []
+        for (slots, fresh, valid), seg, xs, dts in zip(
+                rows, segments, *(_per_segment(a, segments)
+                                  for a in (xbc, dt))):
+            conv, tail = conv_step(conv_pool, layer, slots, fresh,
+                                   seg.q_lens, xs, mp["conv_w"],
+                                   mp["conv_b"])
+            conv = jax.nn.silu(conv)                        # float32
+            # a padded position moves nothing: exp(0) = 1, dt x = 0
+            y, h_pool, conv_pool = ssd_scan(
+                h_pool, conv_pool, layer, slots, fresh,
+                jnp.where(valid[..., None], dts, 0.0), conv[..., :d],
+                conv[..., d:d + gn], conv[..., d + gn:], A, mp["D"], tail)
+            ys.append(y)
+        y = _end_to_end(ys) * jax.nn.silu(z)
+        y = y.reshape(y.shape[:2] + (G, d // G))
+        y = (y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                               + cfg.norm_eps)).reshape(z.shape) \
+            * mp["norm"]["scale"].astype(f32)
+        return jnp.einsum("sqd,de->sqe", y.astype(dtype),
+                          mp["w_out"].astype(dtype)), (h_pool, conv_pool)
+
 
 #: a layer kind is an entry here, one in ``cache_kinds.py::CACHE_KINDS``
 #: (what it caches) and its kernel
@@ -1892,4 +1972,8 @@ MIXERS: Dict[str, Mixer] = {
                    ("state", "conv")),
     "kda": Mixer(RaggedInferenceModel._kda_mixer, "mixer",
                  ("state", "conv")),
+    "ssd": Mixer(RaggedInferenceModel._ssd_mixer, "mixer",
+                 ("state", "conv")),
+    # a feed-forward alone (``cfg.half_blocks``): no mixer, no pool
+    "ffn": Mixer(None, "", ()),
 }

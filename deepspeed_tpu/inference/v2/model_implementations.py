@@ -257,7 +257,25 @@ class SmallThinkerInferenceModel(RaggedInferenceModel):
         return RaggedInferenceModel.rope_table(self, cfg, kind, positions)
 
 
-class BailingHybridInferenceModel(RaggedInferenceModel):
+class _RoutingSink:
+    """A served routed family whose comparison must follow the routing that
+    was served (a near-tie of hundreds of scores falls either way under
+    bfloat16): mixed in before ``RaggedInferenceModel``."""
+    #: a callable ``(experts [T, k])`` that every routed layer of a program
+    #: TRACED while it is set calls, in layer order, with what its router
+    #: chose (a host callback: such a program is never cached).  The
+    #: benchmark's probe hands the record to its reference; None, as it is
+    #: served: no trace of it in a program.
+    routing_sink = None
+
+    def _route(self, lp, h, ctx, layout: bool = False):
+        out = super()._route(lp, h, ctx, layout)
+        if self.routing_sink is not None:
+            jax.debug.callback(self.routing_sink, out[0], ordered=True)
+        return out
+
+
+class BailingHybridInferenceModel(_RoutingSink, RaggedInferenceModel):
     """Ling-3.0 (``models/bailing_hybrid.py``; no counterpart in the
     reference): Kimi-delta (KDA) linear-attention layers and latent
     attention layers in one model, the latent layers' planes in pages and
@@ -266,20 +284,6 @@ class BailingHybridInferenceModel(RaggedInferenceModel):
     a grouped, biased router of which this process holds ``experts_held``
     experts beside the shared expert."""
     MODEL_TYPES = ("bailing_hybrid",)
-    #: a callable ``(experts [T, k])`` that every routed layer of a program
-    #: TRACED while it is set calls, in layer order, with what its router
-    #: chose (a host callback: such a program is never cached).  For a
-    #: comparison that must follow the served routing, since a near-tie of
-    #: 512 scores falls either way under bfloat16 (the benchmark's probe
-    #: hands the record to its reference); None, as it is served: no trace
-    #: of it in a program.
-    routing_sink = None
-
-    def _route(self, lp, h, ctx, layout: bool = False):
-        out = super()._route(lp, h, ctx, layout)
-        if self.routing_sink is not None:
-            jax.debug.callback(self.routing_sink, out[0], ordered=True)
-        return out
 
     def __init__(self, cfg, params, **kw):
         assert set(cfg.layer_kinds) <= {"kda", "latent"} \
@@ -308,6 +312,42 @@ class BailingHybridInferenceModel(RaggedInferenceModel):
             "expert weights do not match the routed layers or experts_held"
 
 
+class NemotronHInferenceModel(_RoutingSink, RaggedInferenceModel):
+    """Nemotron-H (``models/nemotron_h.py``; no counterpart in the
+    reference): every layer ONE sub-layer behind one norm and one residual
+    (``cfg.half_blocks``): Mamba-2 mixers (a state and a convolution tail in
+    one slot of the state pool a sequence), attention layers without a
+    positional encoding (K/V in pages) and routed feed-forward layers that
+    cache NOTHING, behind a biased sigmoid router over two-matrix relu^2
+    experts of which this process holds ``experts_held`` beside the shared
+    expert."""
+    MODEL_TYPES = ("nemotron_h",)
+
+    def __init__(self, cfg, params, **kw):
+        assert set(cfg.layer_kinds) <= {"ssd", "ffn", "full"} \
+            and "full" in cfg.layer_kinds \
+            and len(cfg.layer_kinds) == cfg.num_layers, \
+            "nemotron_h names a kind for every layer, an attention one too"
+        assert cfg.half_blocks and cfg.norm == "rmsnorm" \
+            and cfg.pos_emb == "none" and not cfg.first_k_dense
+        assert cfg.ssm_heads > 0 and cfg.ssm_head_dim > 0 \
+            and cfg.ssm_heads % cfg.ssm_groups == 0 and cfg.ssm_conv > 1
+        assert cfg.num_heads % cfg.kv_heads == 0
+        assert cfg.expert_act == "relu2" and cfg.activation == "relu2" \
+            and cfg.router_scoring == "sigmoid_grouped" \
+            and cfg.router_groups == 1
+        assert cfg.n_routed_experts >= cfg.moe_top_k >= 1
+        held = cfg.held_experts
+        assert 0 <= cfg.experts_first \
+            and cfg.experts_first + held <= cfg.n_routed_experts, \
+            "the experts held here lie outside the router's outputs"
+        super().__init__(cfg, params, **kw)
+        experts = self.params.get("experts")
+        assert experts is None or ("wg" not in experts and experts[
+            "wu"].shape[:2] == (cfg.layer_kinds.count("ffn"), held)), \
+            "expert weights do not match the routed layers or experts_held"
+
+
 _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LlamaV2InferenceModel, MistralInferenceModel, MixtralInferenceModel,
     FalconInferenceModel, OPTInferenceModel, PhiInferenceModel,
@@ -315,6 +355,7 @@ _IMPLEMENTATIONS: Tuple[Type[RaggedInferenceModel], ...] = (
     LagunaInferenceModel, JambaInferenceModel, OlmoHybridInferenceModel,
     GPTNeoXInferenceModel, GPT2InferenceModel, GPTJInferenceModel,
     SmallThinkerInferenceModel, BailingHybridInferenceModel,
+    NemotronHInferenceModel,
 )
 
 

@@ -2,9 +2,10 @@
 
 A model names a kind for every layer (``TransformerConfig.layer_kinds``;
 none: every layer is "full").  A kind keeps, a sequence, either a PAGE
-PLANE of a page group (its K/V at every position the kind still attends)
+PLANE of a page group (its K/V at every position the kind still attends),
 or a SLOT of the state pool (a recurrent state that does not grow with the
-context).  The three places that have to agree on that read it here: the
+context), or NOTHING (a layer that is a feed-forward alone: no group, no
+slot, no column of the table, no reservation).  The three places that have to agree on that read it here: the
 model when it takes a segment's table apart (``model.py::_forward_hidden``), the
 host when it builds the table (``batch.py::build_batch``) and the state
 manager when it decides which resources a sequence reserves
@@ -72,6 +73,14 @@ CACHE_KINDS: Dict[str, CacheKind] = {
     # Kimi-delta (ops/delta_rule.py): the same slot, stepped by another
     # update rule (one decay a key channel)
     "kda": CacheKind(slot_shape=_matrix_slot),
+    # Mamba-2 (ops/ssm.py::ssd_scan): a head's [P, N] state at its lanes of
+    # [N, H P], the tail of x, B and C
+    "ssd": CacheKind(slot_shape=lambda cfg: (
+        (cfg.ssm_state_dim, cfg.ssm_inner),
+        (cfg.ssm_conv - 1,
+         cfg.ssm_inner + 2 * cfg.ssm_groups * cfg.ssm_state_dim))),
+    # a feed-forward alone (a model of ``half_blocks``): caches nothing
+    "ffn": CacheKind(),
 }
 
 

@@ -1585,6 +1585,10 @@ class FastGenScheduler:
             span.set(f"{pool.cfg.kind}_tokens_prefill", prefill_tokens)
             span.set("ssm_state_bytes",
                      pool.held_slots * pool.cfg.bytes_per_slot)
+            # the layers those bytes are spread over, whatever the kind: a
+            # step's state bytes become a roofline without the model's
+            # configuration
+            span.set(f"{pool.cfg.kind}_layers", pool.cfg.num_layers)
             if self._engine.counts_attended and state.window_cache is None:
                 # the context the decode rows attend in the full layers
                 span.set("attn_tokens_full",

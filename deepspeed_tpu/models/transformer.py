@@ -105,12 +105,18 @@ class TransformerConfig:
     # top-k; "sigmoid_grouped": the top-k among the router_topk_groups best
     # of router_groups expert groups, chosen by score + a selection bias.
     # router_reads: "ffn" (the feed-forward's normed input) | "mixer" (the
-    # mixer's).  expert_act: "silu" | "relu" (moe/held.py)
+    # mixer's).  expert_act: "silu" | "relu", the gate's of a three-matrix
+    # expert, or "relu2", a two-matrix expert's own (moe/held.py::ACTS)
     router_scoring: str = "sigmoid"
     router_groups: int = 0
     router_topk_groups: int = 0
     router_reads: str = "ffn"
     expert_act: str = "silu"
+    # rows of the held experts' grouped-matmul tile where the family knows
+    # better than moe/held.py::row_tile's rule for an even routing (0: the
+    # rule).  Every further tile of ONE expert streams its weights again,
+    # so a hot expert costs by its tiles
+    moe_row_tile: int = 0
     # layers of two attention kinds in one model (models/laguna.py): one
     # entry a layer, "full" or "window" (sliding_window wide); () = every
     # layer of one kind.  A kind has its own query heads (heads_by_kind),
@@ -138,6 +144,20 @@ class TransformerConfig:
     ssm_dt_rank: int = 0
     ssm_expand: int = 2
     ssm_state_dtype: Any = jnp.float32
+    # Mamba-2 (SSD) layers (models/nemotron_h.py): ``layer_kinds`` names
+    # them "ssd".  ssm_heads > 0 says the model has such layers, each a
+    # mixer of ssm_heads heads of ssm_head_dim channels (ssm_inner = their
+    # product, whatever ssm_expand says) with ONE decay a head and step, B
+    # and C shared by the heads of each of ssm_groups groups, a state of
+    # ssm_state_dim a channel and a convolution over ssm_conv positions of
+    # x, B and C (ops/ssm.py::ssd_scan)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    # every layer is ONE sub-layer behind one norm and one residual
+    # (models/nemotron_h.py): a mixer kind has no feed-forward behind it,
+    # and the kind "ffn" is a feed-forward alone (no mixer, no cache)
+    half_blocks: bool = False
     # gated delta-rule (linear-attention) layers beside attention layers in
     # one model (models/olmo_hybrid.py): ``layer_kinds`` names them
     # "delta".  delta_heads > 0 says the model has such layers, each a
@@ -217,6 +237,8 @@ class TransformerConfig:
     @property
     def ssm_inner(self) -> int:
         """Channels of a state-space mixer (0: the model has none)."""
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_head_dim
         return self.ssm_expand * self.hidden_size if self.ssm_state_dim \
             else 0
 
@@ -255,8 +277,16 @@ class TransformerConfig:
             # convolution, A a head and the gate's bias a channel
             kda = (e * (3 * qk + dv + 2 * dh) + dv * e
                    + self.delta_conv * (2 * qk + dv) + dh + qk)
+            # a Mamba-2 mixer: the one in projection (z, x, B, C, dt), the
+            # output projection, the convolution and its bias, A, D and
+            # the step's bias a head
+            xbc = di + 2 * self.ssm_groups * n
+            ssd = (e * (di + xbc + self.ssm_heads) + di * e
+                   + xbc * (self.ssm_conv + 1) + 3 * self.ssm_heads)
             latent = attn // l
             attn = sum(mixer if kind == "ssm" else
+                       ssd if kind == "ssd" else
+                       0 if kind == "ffn" else
                        delta if kind == "delta" else
                        kda if kind == "kda" else
                        latent if kind == "latent" else
@@ -271,6 +301,13 @@ class TransformerConfig:
             routed = (l - dense) * (
                 e * self.n_routed_experts
                 + 3 * e * self.moe_intermediate_size
+                * (self.held_experts + self.n_shared_experts))
+        if self.half_blocks:        # the "ffn" layers alone, all routed
+            dense = 0
+            routed = self.layer_kinds.count("ffn") * (
+                e * self.n_routed_experts
+                + (2 if self.expert_act == "relu2" else 3) * e
+                * self.moe_intermediate_size
                 * (self.held_experts + self.n_shared_experts))
         return (attn + dense * mlp + routed
                 + v * e * (1 if self.tie_embeddings else 2))
@@ -496,6 +533,8 @@ def _activation(cfg: TransformerConfig, gate, up):
         return jax.nn.gelu(gate) * up
     if cfg.activation == "relu":
         return jax.nn.relu(up)
+    if cfg.activation == "relu2":       # squared ReLU, no gate
+        return jnp.square(jax.nn.relu(up))
     if cfg.activation == "gelu_exact":  # HF "gelu" = erf, not tanh approx
         return jax.nn.gelu(up, approximate=False)
     return jax.nn.gelu(up)

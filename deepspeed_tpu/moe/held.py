@@ -4,8 +4,10 @@ One chip of an expert-parallel group holds ``held`` of a layer's ``E``
 routed experts (``first .. first + held - 1``).  The router still scores
 every token over all ``E`` experts and picks its ``k`` largest; the
 token-expert pairs that fall to experts held here are sorted by expert,
-padded per expert to whole row tiles and run through ONE grouped SwiGLU
-matmul (the Pallas kernel ``moe_expert_ffn``); pairs of experts held
+padded per expert to whole row tiles and run through ONE grouped matmul
+(the Pallas kernel ``moe_expert_ffn``: a gated expert of three matrices,
+``down(act(gate x) * up x)``, or an ungated one of two, ``down(act(up
+x))``, by the activation's name: :data:`ACTS`); pairs of experts held
 elsewhere add nothing.  The result is this chip's partial sum — the
 shares of all chips, the shared expert counted once, add up to the
 uncut layer (``tests/test_pangu_moe.py``).  Nothing here stands in for
@@ -15,8 +17,9 @@ the other chips or their exchange.
 nothing may be dropped: at 256 experts sixteen times the useful work.
 
 Expert weights are stored ``[held, F, e]`` for all three projections
-(gate and up out-major, down in-major), so every block the kernel
-streams is ``tf`` whole rows of the hidden width: contiguous in HBM.
+(gate and up out-major, down in-major; an ungated expert has no ``wg``), so
+every block the kernel streams is ``tf`` whole rows of the hidden width:
+contiguous in HBM.
 """
 
 from __future__ import annotations
@@ -150,23 +153,27 @@ def width_slice(F: int) -> int:
     return next(t for t in (FF_SLICE, 128, F) if F % t == 0)
 
 
-def ring_sets(tf: int, e: int, itemsize: int) -> int:
-    """Sets (gate, up, down) of ``[tf, e]`` weight slices in the kernel's
-    ring, from their bytes: as many as :data:`RING_BYTES` hold, two at
-    least (one under the matmuls, one on its way), :data:`RING_SETS` at
-    most.  2 at Pangu's 983 KB a slice, 3 at 393 and 328 KB."""
-    return max(2, min(RING_BYTES // (3 * tf * e * itemsize), RING_SETS))
+def ring_sets(tf: int, e: int, itemsize: int, matrices: int = 3) -> int:
+    """Sets (gate, up, down; up, down of an ungated expert: ``matrices``)
+    of ``[tf, e]`` weight slices in the kernel's ring, from their bytes: as
+    many as :data:`RING_BYTES` hold, two at least (one under the matmuls,
+    one on its way), :data:`RING_SETS` at most.  2 at Pangu's 983 KB a
+    slice, 3 at 393 and 328 KB."""
+    return max(2, min(RING_BYTES // (matrices * tf * e * itemsize),
+                      RING_SETS))
 
 
-def _ffn_kernel(act, l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                o_ref, rows_in, rows_out, ring, acc, sem_in, sem_out, sem_w,
-                *, tm, tf, nf, sets):
+def _ffn_kernel(act, l_ref, te_ref, used_ref, x_ref, *refs, tm, tf, nf,
+                sets, gated=True):
     """The walk over the row tiles IN USE, ``used_ref[0]`` of them: every
     operand stays in HBM and the kernel copies what it multiplies.  A work
     item is (row tile ``i``, slice ``j`` of the expert width): the tile's
     rows through ``tf`` columns of its expert's gate (under ``act``) and up
     projections and the matching rows of its down projection, a tile's
-    slices summed in float32 in the order ``j = 0 .. nf - 1``.  The three
+    slices summed in float32 in the order ``j = 0 .. nf - 1``; an expert
+    that is not ``gated`` has no gate projection, ``act`` is its up
+    projection's, and an item copies TWO slices (``refs``: the weights,
+    three or two, then the output and the scratch).  The
     slices of an item are one set of the ring; the set an item leaves is
     filled with the item ``sets`` places on in the walk BEFORE the next
     item's copies are waited for, across tile boundaries, so the memory
@@ -176,17 +183,19 @@ def _ffn_kernel(act, l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
     exists for a tile past ``used``: its output rows are left as they were
     (``ops/paged_attention.py::_walk_kernel`` and ``ops/mla_attention.py::
     _decode_kernel`` are the same walk over pages)."""
+    w_refs, (o_ref, rows_in, rows_out, ring, acc, sem_in, sem_out,
+             sem_w) = refs[:-8], refs[-8:]
     layer, used = l_ref[0], used_ref[0]
     items = used * nf
 
     def weights(s, wait):
-        """Start (or wait for) the three copies of work item ``s``.  A
-        wait only needs a copy of the same size."""
+        """Start (or wait for) the copies of work item ``s``.  A wait only
+        needs a copy of the same size."""
         i, j = jax.lax.div(s, nf), jax.lax.rem(s, nf)
         expert = 0 if wait else te_ref[i]
         at = pl.ds(0 if wait else pl.multiple_of(j * tf, tf), tf)
         slot = jax.lax.rem(s, sets)
-        for k, w_ref in enumerate((wg_ref, wu_ref, wd_ref)):
+        for k, w_ref in enumerate(w_refs):
             copy = pltpu.make_async_copy(w_ref.at[layer, expert, at],
                                          ring.at[slot, k], sem_w.at[slot])
             copy.wait() if wait else copy.start()
@@ -222,12 +231,14 @@ def _ffn_kernel(act, l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
             weights(s, wait=True)
             x = tile_rows[...]                                # [tm, e]
             dims = (((1,), (1,)), ((), ()))                 # x @ w.T
-            gate = jax.lax.dot_general(x, ring[slot, 0], dims,
-                                       preferred_element_type=jnp.float32)
-            up = jax.lax.dot_general(x, ring[slot, 1], dims,
+            if gated:
+                gate = jax.lax.dot_general(
+                    x, ring[slot, 0], dims,
+                    preferred_element_type=jnp.float32)
+            up = jax.lax.dot_general(x, ring[slot, len(w_refs) - 2], dims,
                                      preferred_element_type=jnp.float32)
-            h = (act(gate) * up).astype(x.dtype)            # [tm, tf]
-            acc[...] += jnp.dot(h, ring[slot, 2],
+            h = (act(gate) * up if gated else act(up)).astype(x.dtype)
+            acc[...] += jnp.dot(h, ring[slot, len(w_refs) - 1],
                                 preferred_element_type=jnp.float32)
             pl.when(s + sets < items)(
                 lambda: weights(s + sets, wait=False))
@@ -245,12 +256,14 @@ def _ffn_kernel(act, l_ref, te_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
 
 @functools.partial(jax.jit, static_argnames=("tm", "act", "interpret"))
 def grouped_expert_ffn(x_rows: jax.Array, tile_expert: jax.Array,
-                       used: jax.Array, layer, wg: jax.Array, wu: jax.Array,
-                       wd: jax.Array, *, tm: int, act: str = "silu",
-                       interpret: bool = False) -> jax.Array:
-    """The grouped gated MLP over expert-sorted rows ``[M, e]``: row tile
+                       used: jax.Array, layer, wg: Optional[jax.Array],
+                       wu: jax.Array, wd: jax.Array, *, tm: int,
+                       act: str = "silu", interpret: bool = False
+                       ) -> jax.Array:
+    """The grouped MLP over expert-sorted rows ``[M, e]``: row tile
     ``i`` belongs to expert ``tile_expert[i]`` of layer ``layer`` of the
-    stacked weights ``[L, held, F, e]``.  The kernel walks the ``used``
+    stacked weights ``[L, held, F, e]`` (``wg`` None under an ungated
+    ``act``).  The kernel walks the ``used``
     tiles in use and nothing else (:func:`_ffn_kernel`): the rows of a tile
     from ``used`` on are NOT WRITTEN and hold whatever the buffer held, a
     NaN as soon as anything; a caller reads them only to drop them.  The
@@ -263,20 +276,22 @@ def grouped_expert_ffn(x_rows: jax.Array, tile_expert: jax.Array,
     four routed layers a period took 0.3-0.7 s longer to form than the
     grid form's; PERF.md, PR 48)."""
     M, e = x_rows.shape
-    F = wg.shape[2]
+    F = wu.shape[2]
     tf = width_slice(F)
-    sets = ring_sets(tf, e, x_rows.dtype.itemsize)
+    mats = [w for w in (wg, wu, wd) if w is not None]
+    assert (wg is None) == (act in UNGATED), act
+    sets = ring_sets(tf, e, x_rows.dtype.itemsize, len(mats))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_ffn_kernel, ACTS[act], tm=tm, tf=tf, nf=F // tf,
-                          sets=sets),
+                          sets=sets, gated=wg is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(1,),
-            in_specs=[hbm, hbm, hbm, hbm], out_specs=hbm,
+            in_specs=[hbm] * (1 + len(mats)), out_specs=hbm,
             scratch_shapes=[
                 pltpu.VMEM((2, tm, e), x_rows.dtype),
                 pltpu.VMEM((tm, e), x_rows.dtype),
-                pltpu.VMEM((sets, 3, tf, e), x_rows.dtype),
+                pltpu.VMEM((sets, len(mats), tf, e), x_rows.dtype),
                 pltpu.VMEM((tm, e), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((1,)),
@@ -287,21 +302,24 @@ def grouped_expert_ffn(x_rows: jax.Array, tile_expert: jax.Array,
         name="moe_expert_ffn",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, used, x_rows,
-      wg, wu, wd)
+      *mats)
 
 
 def _grouped_reference(x_rows, tile_expert, used, layer, wg, wu, wd, *, tm,
                        act: str = "silu"):
     """The kernel's arithmetic in ``jnp`` (the CPU path)."""
-    wg, wu, wd = wg[layer], wu[layer], wd[layer]
+    wu, wd = wu[layer], wd[layer]
     M, e = x_rows.shape
     xt = x_rows.reshape(M // tm, tm, e)
     f32 = jnp.float32
-    gate = jnp.einsum("nte,nfe->ntf", xt, wg[tile_expert],
-                      preferred_element_type=f32)
     up = jnp.einsum("nte,nfe->ntf", xt, wu[tile_expert],
                     preferred_element_type=f32)
-    h = (ACTS[act](gate) * up).astype(x_rows.dtype)
+    if wg is None:
+        h = ACTS[act](up).astype(x_rows.dtype)
+    else:
+        gate = jnp.einsum("nte,nfe->ntf", xt, wg[layer][tile_expert],
+                          preferred_element_type=f32)
+        h = (ACTS[act](gate) * up).astype(x_rows.dtype)
     out = jnp.einsum("ntf,nfe->nte", h, wd[tile_expert],
                      preferred_element_type=f32)
     live = (jnp.arange(M // tm) < used[0])[:, None, None]
@@ -312,28 +330,35 @@ def held_experts_ffn(x2d: jax.Array, experts: jax.Array,
                      weights: jax.Array, params, first: int, *,
                      layer=None, valid: Optional[jax.Array] = None,
                      use_kernel: Optional[bool] = None,
-                     interpret: bool = False, plan=None, act: str = "silu"
-                     ) -> Tuple[jax.Array, jax.Array]:
+                     interpret: bool = False, plan=None, act: str = "silu",
+                     tile: int = 0) -> Tuple[jax.Array, jax.Array]:
     """``sum_i w_i E_i(x)`` over the chosen experts that are held here.
 
     x2d [T, e]; experts / weights [T, k] from a router of :data:`ROUTERS`;
     params ``{"wg", "wu", "wd"}`` each ``[held, F, e]``, or the layers'
     stack ``[L, held, F, e]`` with ``layer`` the one to use (an int32
-    scalar: the layer loop's counter); ``valid`` [T] marks real tokens;
-    ``plan``: :func:`plan_rows` made earlier; ``act``: the gate's (ACTS).
+    scalar: the layer loop's counter), no ``wg`` under an ungated ``act``;
+    ``valid`` [T] marks real tokens; ``plan``: :func:`plan_rows` made
+    earlier; ``act``: the gate's, or an ungated expert's own (ACTS);
+    ``tile``: the rows of a tile where no plan brings them (0:
+    :func:`row_tile`).
     Returns (partial result [T, e] in x2d's dtype, pairs per held expert
     [held] int32)."""
     T, e = x2d.shape
-    wg, wu, wd = (params[n].astype(x2d.dtype) for n in ("wg", "wu", "wd"))
+    wg, wu, wd = (None if n == "wg" and act in UNGATED
+                  else params[n].astype(x2d.dtype)
+                  for n in ("wg", "wu", "wd"))
     if layer is None:
-        wg, wu, wd, layer = wg[None], wu[None], wd[None], 0
-    held = wg.shape[1]
+        wg, wu, wd, layer = (wg if wg is None else wg[None], wu[None],
+                             wd[None], 0)
+    held = wu.shape[1]
     if valid is None:
         valid = jnp.ones(T, bool)
     if use_kernel is None:
         use_kernel = interpret or on_tpu()
     # a plan made ahead brings its tile: its rows over its tiles
-    tm = plan[0].shape[0] // plan[2].shape[0] if plan else row_tile(T)
+    tm = plan[0].shape[0] // plan[2].shape[0] if plan \
+        else tile or row_tile(T)
     row_token, dest, tile_expert, used, counts = plan or _plan(
         experts, valid, first, held, tm)
     ffn = (functools.partial(grouped_expert_ffn, interpret=interpret)
@@ -359,11 +384,12 @@ def dense_held_reference(x2d, experts, weights, params, first: int,
     by the routing, float32."""
     f32 = jnp.float32
     x = x2d.astype(f32)
-    wg, wu, wd = (params[n].astype(f32) for n in ("wg", "wu", "wd"))
-    h = ACTS[act](jnp.einsum("te,xfe->xtf", x, wg)) \
-        * jnp.einsum("te,xfe->xtf", x, wu)
+    wu, wd = params["wu"].astype(f32), params["wd"].astype(f32)
+    h = jnp.einsum("te,xfe->xtf", x, wu)
+    h = ACTS[act](h) if act in UNGATED else ACTS[act](jnp.einsum(
+        "te,xfe->xtf", x, params["wg"].astype(f32))) * h
     out = jnp.einsum("xtf,xfe->xte", h, wd)                 # [held, T, e]
-    held = wg.shape[0]
+    held = wu.shape[0]
     gate = jnp.sum(
         jnp.where((experts - first)[..., None] == jnp.arange(held),
                   weights[..., None], 0.0), axis=1)         # [T, held]
@@ -418,22 +444,27 @@ ROUTERS = {"sigmoid": route_sigmoid_topk, "softmax": route_softmax_topk,
            "sigmoid_grouped": route_sigmoid_grouped}
 
 
-#: the gate's activation a configuration names (``expert_act``): SwiGLU's
-#: and the ReLU of a ReGLU expert.  Static in the kernel: a name is one
-#: Mosaic text
-ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+#: the activation a configuration names (``expert_act``): the GATE's of a
+#: three-matrix expert (SwiGLU's, and the ReLU of a ReGLU expert), or, of
+#: the names in :data:`UNGATED`, the up projection's of a two-matrix expert
+#: ``down(act(up x))`` that has no gate (relu2: ``relu(.)^2``).  Static in
+#: the kernel: a name is one Mosaic text
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+        "relu2": lambda up: jnp.square(jax.nn.relu(up))}
+UNGATED = frozenset({"relu2"})
 
 
 def plan_rows(experts: jax.Array, valid: Optional[jax.Array], first: int,
-              held: int, scored: int = 0):
+              held: int, scored: int = 0, tile: int = 0):
     """:func:`held_experts_ffn`'s row layout for ``experts`` [T, k], made
     where the routing is known, which may be before the layer's mixer (a
     router that reads the mixer's input): ``held_experts_ffn(...,
     plan=...)`` then only gathers, multiplies and scatters.  ``scored``:
     the experts the router chose among, which sizes the tile
-    (:func:`row_tile`) by the pairs an expert sees."""
+    (:func:`row_tile`) by the pairs an expert sees; ``tile``: the tile's
+    rows, from a caller that knows them (0: that rule)."""
     T, k = experts.shape
     if valid is None:
         valid = jnp.ones(T, bool)
     return _plan(experts, valid, first, held,
-                 row_tile(T, T * k / scored if scored else 0.0))
+                 tile or row_tile(T, T * k / scored if scored else 0.0))

@@ -1,4 +1,5 @@
-"""Selective state-space (Mamba-1) sequence state for ragged serving.
+"""Selective state-space sequence state for ragged serving: Mamba-1's
+diagonal recurrence and Mamba-2's scalar-decay (SSD) one.
 
 A Mamba layer carries, a sequence, a recurrent state ``h`` of
 ``[d_state, d_inner]`` float32 and the last ``d_conv - 1`` inputs of its
@@ -8,10 +9,10 @@ pool (``inference/v2/ragged/kv_cache.py::StatePool``): one slot a
 sequence,
 
     h    : [L_ssm, slots + 1, d_state, d_inner]      float32
-    conv : [L_ssm, slots + 1, 8, (d_conv - 1) * d_inner / 8] bfloat16
+    conv : [L_ssm, slots + 1, 8, (d_conv - 1) * channels / 8] bfloat16
 
 ``d_inner`` minor (5120 is 40 lane tiles, 16 is not one); the tail's
-``[d_conv - 1, d_inner]`` rows laid end to end and cut into 8 rows
+``[d_conv - 1, channels]`` rows laid end to end and cut into 8 rows
 (:func:`conv_rows`), because a second-minor dim of 3 is one the chip's
 compiler lays out one way at a program's edge and another inside its loop
 (two copies of the pool a step; ``tests/test_chip_compile.py``), and 8 rows
@@ -21,23 +22,33 @@ pools have their null page).  Both pools are donated at the jit boundary
 and carried through the layer loop; every op here takes the WHOLE pool
 and a layer index and updates ``pool[layer, slot]`` in place.
 
-* :func:`ssm_scan` — the recurrence, for rows of ``Q`` tokens from each
-  row's slot: ``h_t = exp(dt_t (x) A) * h_{t-1} + (dt_t * x_t) (x) B_t``,
-  ``y_t = h_t C_t + D * x_t``.  Mamba-1's ``A`` is a full ``[d_inner,
-  d_state]`` diagonal, so a chunk has no matrix form (that takes one decay
-  a head: ``ops/delta_rule.py`` has it, for the gated delta rule):
-  the recurrence is elementwise on the vector unit, sequential in ``t``,
-  parallel over ``d_inner``.  On a TPU it is a Pallas kernel named
-  ``ssm_state_update_decode`` (Q = 1: one token a row, the state read and
-  written once) or ``ssm_scan_prefill`` (Q > 1), both pools aliased input
-  -> output, the slot ids riding the BlockSpec index maps through scalar
-  prefetch.  The same call writes the row's new convolution tail into
-  ``conv[layer, slot]`` (an XLA scatter did it row by row: 30 KB a row in
-  1.1 us, a fifth of the decode step, PERF.md PR 34).  The jnp form is the
-  semantics ground truth and the CPU path.
+* :func:`ssm_scan` — Mamba-1's recurrence, for rows of ``Q`` tokens from
+  each row's slot: ``h_t = exp(dt_t (x) A) * h_{t-1} + (dt_t * x_t) (x)
+  B_t``, ``y_t = h_t C_t + D * x_t``.  Mamba-1's ``A`` is a full
+  ``[d_inner, d_state]`` diagonal, one decay a (channel, state) PAIR, so a
+  chunk has no matrix form: the recurrence is elementwise on the vector
+  unit, sequential in ``t``, parallel over ``d_inner``.  On a TPU it is a
+  Pallas kernel named ``ssm_state_update_decode`` (Q = 1: one token a row,
+  the state read and written once) or ``ssm_scan_prefill`` (Q > 1), both
+  pools aliased input -> output, the slot ids riding the BlockSpec index
+  maps through scalar prefetch.  The same call writes the row's new
+  convolution tail into ``conv[layer, slot]`` (an XLA scatter did it row by
+  row: 30 KB a row in 1.1 us, a fifth of the decode step, PERF.md PR 34).
+  The jnp form is the semantics ground truth and the CPU path.
+* :func:`ssd_scan` — Mamba-2's: ``H`` heads of ``P`` channels (``d_inner =
+  H P``), ONE scalar decay a head and step, ``a_t = exp(dt_t A)``, and
+  ``B``, ``C`` shared by the ``H / G`` heads of a group: ``S_t = a_t
+  S_{t-1} + (dt_t x_t) (x) B_t``, ``y_t = S_t C_t + D x_t``, a head's state
+  ``[P, N]`` held as the slot's lanes ``h P .. h P + P - 1`` of ``[N, H
+  P]``.  One decay a head is what gives a chunk a MATRIX form (the gated
+  delta rule's chunk in ``ops/delta_rule.py`` rests on the same): inside a
+  chunk ``Y = (L o (C B^T)) (dt X)`` with ``L_ts = prod_{s<r<=t} a_r``, on
+  the matrix unit.  Kernels ``ssd_state_update_decode`` (Q = 1) and
+  ``ssd_chunk_prefill`` (Q > 1), the pools, the tail and the ``fresh``
+  rule as above.
 * :func:`conv_step` — the depthwise causal convolution over the slot's
   tail (one XLA gather, 30 KB a row and layer) and the new tokens, and
-  the tail of the row's TRUE last tokens for :func:`ssm_scan` to write.
+  the tail of the row's TRUE last tokens for the scan to write.
 
 A row that starts at position 0 (``fresh``) starts from a zero state and
 a zero tail whatever its slot held: a reused slot is zeroed by the
@@ -56,6 +67,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..accelerator import on_tpu
+from .delta_rule import _HP, _NT, _TN, chunk_len
 
 #: bytes of one grid step's token blocks (dt, x and y, two buffers each)
 #: that :func:`_d_block` keeps a prefill step under: far inside the
@@ -276,3 +288,277 @@ def conv_step(conv_pool: jax.Array, layer, slots: jax.Array,
         xp[:, k:k + Q].astype(jnp.float32) * w[k] for k in range(K))
     idx = q_lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
     return out, jnp.take_along_axis(xp, idx[:, :, None], axis=1)
+
+
+# -- Mamba-2 (SSD): one scalar decay a head, B and C a group of heads ---------
+
+#: tokens a chunk of the matrix form takes at most: the published
+#: ``chunk_size``.  A grid step holds ONE group's lanes of the state (``[N,
+#: H P / G]``: 256 KB at the published widths) and the chunk's blocks of
+#: that group, under 2 MB in all: far inside the default scoped VMEM
+#: (:data:`TOKEN_BLOCK_BUDGET`'s note), so nothing asks for a shorter chunk
+SSD_CHUNK = 128
+#: a row bucket shorter than this is walked by the ``jnp`` form (a sublane
+#: tile of positions is the least a chunk's blocks can be)
+SSD_MIN_CHUNK = 8
+
+
+def ssd_chunk_len(Q: int) -> int:
+    """Tokens a chunk takes of a row bucket of ``Q``: the largest power of
+    two up to :data:`SSD_CHUNK` that divides it (the delta rule's rule)."""
+    return chunk_len(Q, SSD_CHUNK)
+
+
+def ssd_scan_reference(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C,
+                       A, D, new_tail):
+    """Mamba-2's recurrence as a plain ``lax.scan`` over positions (module
+    docstring): (y ``[S, Q, H P]`` float32, the h pool, the conv pool)."""
+    f32 = jnp.float32
+    S, Q, H = dt.shape
+    N = h_pool.shape[2]
+    P, G = x.shape[-1] // H, B.shape[-1] // N
+    h0 = h_pool[layer, slots].astype(f32).reshape(S, N, H, P)
+    h0 = jnp.where(fresh[:, None, None, None], 0.0, h0)
+    A, D = A.astype(f32), D.astype(f32)
+
+    def heads(a):                   # [S, G N] -> [S, N, H]: a head's group's
+        return jnp.repeat(a.reshape(S, G, N), H // G, axis=1).swapaxes(1, 2)
+
+    def step(h, inp):
+        dt_t, x_t, b_t, c_t = inp           # [S, H] [S, H, P] [S, G N] x2
+        h = jnp.exp(dt_t * A)[:, None, :, None] * h \
+            + heads(b_t)[..., None] * (dt_t[..., None] * x_t)[:, None]
+        return h, jnp.sum(h * heads(c_t)[..., None], axis=1) \
+            + D[:, None] * x_t
+
+    h, ys = jax.lax.scan(step, h0, (
+        dt.astype(f32).swapaxes(0, 1),
+        x.astype(f32).reshape(S, Q, H, P).swapaxes(0, 1),
+        B.astype(f32).swapaxes(0, 1), C.astype(f32).swapaxes(0, 1)))
+    return (ys.swapaxes(0, 1).reshape(S, Q, H * P),
+            h_pool.at[layer, slots].set(
+                h.reshape(S, N, H * P).astype(h_pool.dtype)),
+            conv_pool.at[layer, slots].set(
+                new_tail.reshape((-1,) + conv_pool.shape[2:])))
+
+
+def _ssd_decode_kernel(l_ref, slot_ref, fresh_ref, a_ref, x_ref, b_ref,
+                       c_ref, tail_ref, h_ref, conv_ref, y_ref, hout_ref,
+                       tout_ref, *, groups):
+    """One row: its whole state ``[N, H P]`` read, stepped once and written
+    back to the same address (the pool is aliased input -> output), walked
+    a GROUP's lanes at a time: the group's ``B`` and ``C`` are columns
+    ``[N, 1]`` of ``b_ref`` / ``c_ref`` (``[N, G]``, the state dim on
+    sublanes as in ``h``) spread over its lanes, the decay ``a`` and ``dt
+    x`` come spread over the lanes already, as ``[8, H P]`` blocks of 8
+    rows (:func:`_ssm_kernel`'s decode form)."""
+    del l_ref, slot_ref, conv_ref
+    s = pl.program_id(0)
+    tout_ref[...] = tail_ref[...]
+    r = s % a_ref.shape[0]
+    fresh = fresh_ref[s] > 0
+    width = h_ref.shape[1] // groups
+    for g in range(groups):
+        lanes = slice(g * width, (g + 1) * width)
+        row = (pl.ds(r, 1), lanes)
+        h = h_ref[:, lanes].astype(jnp.float32)
+        h = jnp.where(fresh, jnp.zeros_like(h), h) * a_ref[row] \
+            + b_ref[:, g:g + 1] * x_ref[row]
+        y_ref[row] = jnp.sum(h * c_ref[:, g:g + 1], axis=0, keepdims=True)
+        hout_ref[:, lanes] = h.astype(hout_ref.dtype)
+
+
+def _ssd_chunk_kernel(l_ref, slot_ref, fresh_ref, x_ref, cs_ref, b_ref, c_ref,
+                      tail_ref, h_ref, conv_ref, y_ref, hout_ref, tout_ref,
+                      *, heads, P):
+    """One (row, group, chunk) grid step: the chunk's matrix form for the
+    ``heads`` heads of one group, from and to the group's lanes of the
+    state in ``hout_ref``, which stays in VMEM across the row's chunks (the
+    innermost grid dim) and goes back to the row's slot after the last; the
+    first chunk takes it from the slot, or zeros for a fresh row.  ``cs_ref``
+    ``[heads, C]``: the running sums of ``log a = dt A`` inside the chunk, a
+    head a row.  With ``M = tril(C B^T)`` (the group's) and ``L_ts = exp(cs_t
+    - cs_s)`` (a head's), head ``h``::
+
+        Y_h  = (L_h o M) (dt X)_h + exp(cs) o (C S_h)
+        S_h' = exp(cs_last) S_h + (B o exp(cs_last - cs))^T (dt X)_h
+
+    A head is ``P`` = 64 lanes, half a lane tile: the heads are taken a
+    PAIR a tile, each head's product over the tile's 128 lanes with the
+    other head's lanes of ``dt X`` zeroed, and the pair's two summed."""
+    del l_ref, slot_ref, conv_ref
+    s, j, c = (pl.program_id(i) for i in range(3))
+    f32 = jnp.float32
+
+    @pl.when(c == 0)
+    def _start():
+        st = h_ref[...]
+        hout_ref[...] = jnp.where(fresh_ref[s] > 0, jnp.zeros_like(st), st)
+
+    @pl.when((c == 0) & (j == 0))
+    def _tail():
+        tout_ref[...] = tail_ref[...]
+
+    Cn = x_ref.shape[0]
+    rc = cs_ref[...]                                        # [heads, C]
+    cols = rc.T                                             # [C, heads]
+    b, cm = b_ref[...], c_ref[...]                          # [C, N]
+    r_i = jax.lax.broadcasted_iota(jnp.int32, (Cn, Cn), 0)
+    c_i = jax.lax.broadcasted_iota(jnp.int32, (Cn, Cn), 1)
+    lower = r_i >= c_i
+    m = jnp.where(lower, jax.lax.dot_general(cm, b, _NT, **_HP), 0.0)
+    per = max(128 // P, 1)                                  # heads a tile
+    tile = per * P
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+    for p in range(heads // per):
+        lanes = slice(p * tile, (p + 1) * tile)
+        xp = x_ref[:, lanes]                                # [C, tile]
+        st = hout_ref[:, lanes].astype(f32)                 # [N, tile]
+        y = jnp.zeros((Cn, tile), f32)
+        new = jnp.zeros_like(st)
+        grow = jnp.zeros((Cn, tile), f32)                   # exp(cs), by lane
+        keep = jnp.zeros((1, tile), f32)                    # exp(cs_last)
+        for k in range(per):
+            h = p * per + k
+            mine = (lane >= k * P) & (lane < (k + 1) * P)
+            col, row = cols[:, h:h + 1], rc[h:h + 1]        # [C, 1] [1, C]
+            last = row[:, Cn - 1:Cn]                        # [1, 1]
+            xh = jnp.where(mine, xp, 0.0)
+            ratio = jnp.where(lower, jnp.exp(jnp.minimum(col - row, 0.0)),
+                              0.0)
+            y = y + jnp.dot(ratio * m, xh, **_HP)
+            new = new + jax.lax.dot_general(
+                b * jnp.exp(last - col), xh, _TN, **_HP)
+            grow = jnp.where(mine, jnp.exp(col), grow)
+            keep = jnp.where(mine, jnp.exp(jnp.broadcast_to(
+                last, (1, tile))), keep)
+        y_ref[:, lanes] = y + grow * jnp.dot(cm, st, **_HP)
+        hout_ref[:, lanes] = (keep * st + new).astype(hout_ref.dtype)
+
+
+def ssd_scan_kernel(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C, A,
+                    D, new_tail, *, interpret: bool = False):
+    """Pallas form of :func:`ssd_scan_reference`, in place: the update
+    kernel at ``Q = 1``, the chunked matrix form behind it.  What is
+    elementwise over the tokens (the decay, ``dt x``, the running sums,
+    ``D x``) is XLA's, around the call."""
+    S, Q, H = dt.shape
+    W = x.shape[-1]
+    P, N = W // H, h_pool.shape[2]
+    G = B.shape[-1] // N
+    rows, width = conv_pool.shape[2:]
+    f32 = jnp.float32
+    dt, x = dt.astype(f32), x.astype(f32)
+    la = dt * A.astype(f32)                                 # log a, [S, Q, H]
+    dtx = (dt[..., None] * x.reshape(S, Q, H, P)).reshape(S, Q, W)
+    skip = jnp.repeat(D.astype(f32), P) * x
+    prefetch = (jnp.asarray(layer, jnp.int32).reshape(1),
+                slots.astype(jnp.int32), fresh.astype(jnp.int32))
+    tail_in = new_tail.astype(conv_pool.dtype).reshape(S, rows, width)
+    out_shape = [None, jax.ShapeDtypeStruct(h_pool.shape, h_pool.dtype),
+                 jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)]
+    if Q == 1:
+        rb = min(8, S)
+        assert S % rb == 0, "row buckets are powers of two"
+        token = pl.BlockSpec((rb, W), lambda s, l, sl, fr: (s // rb, 0))
+        cols = pl.BlockSpec((None, N, G), lambda s, l, sl, fr: (s, 0, 0))
+        state = pl.BlockSpec((None, None, N, W),
+                             lambda s, l, sl, fr: (l[0], sl[s], 0, 0))
+        tail = pl.BlockSpec((None, None, rows, width),
+                            lambda s, l, sl, fr: (l[0], sl[s], 0, 0))
+        out_shape[0] = jax.ShapeDtypeStruct((S, W), f32)
+        y, h_pool, conv_pool = pl.pallas_call(
+            functools.partial(_ssd_decode_kernel, groups=G),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(S,),
+                in_specs=[token, token, cols, cols,
+                          pl.BlockSpec((None, rows, width),
+                                       lambda s, l, sl, fr: (s, 0, 0)),
+                          state, pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=[token, state, tail]),
+            out_shape=out_shape,
+            # operands count the 3 prefetched
+            input_output_aliases={8: 1, 9: 2},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            # ``^ssd_`` finds both kernels and no pattern of the attention,
+            # cache-write, Mamba-1 or delta-rule kernels does
+            name="ssd_state_update_decode",
+            interpret=interpret,
+        )(*prefetch, jnp.repeat(jnp.exp(la[:, 0]), P, axis=-1), dtx[:, 0],
+          B.astype(f32).reshape(S, G, N).swapaxes(1, 2),
+          C.astype(f32).reshape(S, G, N).swapaxes(1, 2), tail_in, h_pool,
+          conv_pool)
+        return y.reshape(S, 1, W) + skip, h_pool, conv_pool
+
+    Cn = ssd_chunk_len(Q)
+    hg, gw = H // G, W // G
+    # the running sums of log a inside each chunk, a head a row:
+    # [S, G, chunks, H / G, C]
+    cs = jnp.cumsum(la.reshape(S, Q // Cn, Cn, G, hg), axis=2).transpose(
+        0, 3, 1, 4, 2)
+    token = pl.BlockSpec((None, Cn, gw),
+                         lambda s, j, c, l, sl, fr: (s, c, j))
+    group = pl.BlockSpec((None, Cn, N), lambda s, j, c, l, sl, fr: (s, c, j))
+    state = pl.BlockSpec((None, None, N, gw),
+                         lambda s, j, c, l, sl, fr: (l[0], sl[s], 0, j))
+    tail = pl.BlockSpec((None, None, rows, width),
+                        lambda s, j, c, l, sl, fr: (l[0], sl[s], 0, 0))
+    out_shape[0] = jax.ShapeDtypeStruct((S, Q, W), f32)
+    y, h_pool, conv_pool = pl.pallas_call(
+        functools.partial(_ssd_chunk_kernel, heads=hg, P=P),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(S, G, Q // Cn),
+            in_specs=[token,
+                      pl.BlockSpec((None, None, None, hg, Cn),
+                                   lambda s, j, c, l, sl, fr:
+                                   (s, j, c, 0, 0)),
+                      group, group,
+                      pl.BlockSpec((None, rows, width),
+                                   lambda s, j, c, l, sl, fr: (s, 0, 0)),
+                      state, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[token, state, tail]),
+        out_shape=out_shape,
+        input_output_aliases={8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
+        name="ssd_chunk_prefill",
+        interpret=interpret,
+    )(*prefetch, dtx, cs, B.astype(f32), C.astype(f32), tail_in, h_pool,
+      conv_pool)
+    return y + skip, h_pool, conv_pool
+
+
+def ssd_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
+             slots: jax.Array, fresh: jax.Array, dt: jax.Array,
+             x: jax.Array, B: jax.Array, C: jax.Array, A: jax.Array,
+             D: jax.Array, new_tail: jax.Array, *,
+             use_kernel: Optional[bool] = None, interpret: bool = False
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``Q`` steps of Mamba-2's recurrence for ``S`` rows from their
+    slots, and the rows' new convolution tails written.
+
+    h_pool : [L, slots + 1, N, H P], the state's dtype (head ``h``'s
+             ``[N, P]`` at lanes ``h P ..``)
+    conv_pool : [L, slots + 1, rows, (K - 1) * (H P + 2 G N) / rows]
+    layer  : int32 scalar (the layer's index among the Mamba-2 layers)
+    slots  : [S] int32, the scratch slot for a row with nothing to step
+    fresh  : [S] bool, the row starts from a zero state
+    dt     : [S, Q, H] (after the softplus, 0 at padded positions)
+    x      : [S, Q, H P];  B, C : [S, Q, G N], head ``h`` reads group
+             ``h // (H / G)``
+    A      : [H] = ``-exp(A_log)``;  D : [H]
+    new_tail : [S, K - 1, H P + 2 G N], :func:`conv_step`'s
+    Returns (y [S, Q, H P] float32, the updated h pool, the updated conv
+    pool).  ``use_kernel`` None = auto (on TPU, or anywhere with
+    ``interpret=True``); a row bucket whose chunk would be shorter than
+    :data:`SSD_MIN_CHUNK` is walked by the reference."""
+    if use_kernel is None:
+        use_kernel = interpret or on_tpu()
+    Q = dt.shape[1]
+    if not use_kernel or (Q > 1 and ssd_chunk_len(Q) < SSD_MIN_CHUNK):
+        impl = ssd_scan_reference
+    else:
+        impl = functools.partial(ssd_scan_kernel, interpret=interpret)
+    return impl(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C, A, D,
+                new_tail)

@@ -1786,3 +1786,159 @@ def test_ling_step_program_moves_no_pool_and_no_weight_stack(
     assert moved == [], moved
     assert stack_shaped_movers(text, params) == []
     assert compiled.memory_analysis().temp_size_in_bytes < expert_layer
+
+
+# -- the Mamba-2 (SSD) family: its two kernels and its step programs ---------
+
+SSD_LAYERS, SSD_HEADS, SSD_P, SSD_GROUPS, SSD_STATE = 6, 64, 64, 8, 128
+SSD_INNER = SSD_HEADS * SSD_P
+SSD_CHANNELS = SSD_INNER + 2 * SSD_GROUPS * SSD_STATE
+
+
+def _ssd_pools(chip):
+    return (chip((SSD_LAYERS, SSM_SLOTS + 1, SSD_STATE, SSD_INNER),
+                 jnp.float32),
+            chip((SSD_LAYERS, SSM_SLOTS + 1, 8, 3 * SSD_CHANNELS // 8),
+                 jnp.bfloat16))
+
+
+@pytest.mark.parametrize("rows,q,kernel", [
+    (256, 1, "ssd_state_update_decode"), (4, 128, "ssd_chunk_prefill"),
+    (1, 1024, "ssd_chunk_prefill"), (4, 8, "ssd_chunk_prefill")])
+def test_ssd_kernels(chip, rows, q, kernel):
+    """Mamba-2's kernels at the published widths (64 heads of 64 over a
+    state of 128, B and C in 8 groups): the update kernel (a row's whole
+    [128, 4096] float32 state a grid step, walked a group's 512 lanes at a
+    time) and the chunked kernel (one group's lanes and one chunk of up to
+    128 tokens a grid step, the heads a pair a lane tile), both pools
+    aliased in -> out, under the default scoped-VMEM limit."""
+    from deepspeed_tpu.ops.ssm import ssd_chunk_len, ssd_scan
+    f32 = jnp.float32
+    assert [ssd_chunk_len(n) for n in (1, 8, 128, 1024, 192, 72)] \
+        == [1, 8, 128, 128, 64, 8]
+    h, conv = _ssd_pools(chip)
+    text = compile_for_chip(
+        lambda h, conv, layer, slots, fresh, dt, x, B, C, A, D, tail:
+        ssd_scan(h, conv, layer, slots, fresh, dt, x, B, C, A, D, tail,
+                 use_kernel=True),
+        h, conv, chip((), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.bool_), chip((rows, q, SSD_HEADS), f32),
+        chip((rows, q, SSD_INNER), f32),
+        chip((rows, q, SSD_GROUPS * SSD_STATE), f32),
+        chip((rows, q, SSD_GROUPS * SSD_STATE), f32),
+        chip((SSD_HEADS,), f32), chip((SSD_HEADS,), f32),
+        chip((rows, 3, SSD_CHANNELS), jnp.bfloat16), kernel=kernel)
+    assert set(scoped_vmem_asked(text, "ssd_")) == {""}
+
+
+#: the window's two programs: a chained decode step, and a mixed step (256
+#: decode rows and the lattice's 4-row prompt segment: both Mamba-2 kernels,
+#: both page writes, the paged kernels at 32 query heads over 2 KV heads,
+#: the two-matrix held experts).  (The probe's own programs, formed under
+#: ``routing_sink``, are the Ling test's mechanism: a compile is 10 s of a
+#: suite near its limit)
+NEMOTRON_STEP_KEYS = {
+    "chain-p40": (256, 1, 40, False, "chain", 256, True),
+    "mixed-p40": (256, 1, 40, False, "mixed", 4, 128, 8, True, True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(NEMOTRON_STEP_KEYS))
+def test_nemotron_step_program_moves_no_pool_and_no_expert_stack(
+        chip, monkeypatch, kind):
+    """The benchmark's cell at published widths (published layers 6-19:
+    two periods of E M E M E M *, 16 of 128 experts held): the step programs
+    lower for the chip with layers that are a mixer OR a feed-forward alone,
+    a page pool of the TWO attention layers AND a state pool in one carry,
+    the Mamba-2 kernels under their own names, the expert kernel over TWO
+    matrices, and nothing the size of a layer of the conv pool (9.5 MB), let
+    alone of the 3.2 GB state pool, the page pool or a layer of the held
+    experts' stack, is copied, sliced out or re-laid out."""
+    import dataclasses
+    import json
+    import os
+
+    from flax.core import meta
+
+    from benchmark.builders.serve_nemotron_h import source_of
+    from deepspeed_tpu.accelerator import real_accelerator
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        NemotronHInferenceModel)
+    from deepspeed_tpu.inference.v2.ragged import KVCacheConfig
+    from deepspeed_tpu.models.nemotron_h import NemotronHForCausalLM
+    from deepspeed_tpu.moe.held import _rows_bound, row_tile
+
+    monkeypatch.setattr(real_accelerator, "device_platform", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-nano-serve-14l-ep8.json")) as f:
+        config = json.load(f)
+    model = NemotronHForCausalLM(source_of(config, False),
+                                 first_layer=config["first_layer"])
+    assert model.cfg.layer_kinds == ("ffn", "ssd", "ffn", "ssd", "ffn",
+                                     "ssd", "full") * 2
+    params = jax.eval_shape(lambda k: meta.unbox(model.init_params(k)),
+                            jax.random.key(0))
+    assert sorted(params["periods"]) == [f"l{j}" for j in range(7)]
+    assert set(params["experts"]) == {"wu", "wd"}
+    assert params["experts"]["wu"].shape == (6, 16, 1856, 2688)
+    # 1,536 pairs of a 256-row step over 128 scored: 12 an expert, for which
+    # the rule gives tiles of 32 rows; the family says 64 (rows that route
+    # alike: ``models/nemotron_h.py::ROW_TILE``)
+    assert row_tile(256, 256 * 6 / 128) == 32
+    assert model.cfg.moe_row_tile == 64
+    pages = 1024
+    serve = NemotronHInferenceModel(
+        model.cfg, params, kv_config=KVCacheConfig(
+            num_layers=2, kv_heads=2, head_dim=128, page_size=PAGE,
+            num_pages=pages))
+    serve.state_config = dataclasses.replace(serve.state_config,
+                                             num_slots=SSM_SLOTS)
+    assert serve.pool_names == ("pages", "state", "conv")
+    pool = (chip((2, pages + 1, 2, 2, PAGE, 128), jnp.bfloat16),
+            *_ssd_pools(chip))
+    assert [tuple(a.shape) for a in pool[1:]] \
+        == list(serve.state_config.shapes())
+    key = StepKey.parse(NEMOTRON_STEP_KEYS[kind])
+    avals = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype) if hasattr(a, "shape") else a,
+        step_avals(serve, key, pool))
+    assert (key.S, key.P + 1) in [a.shape for a in avals[2:]]
+    compiled = jax.jit(step_program(serve, key),
+                       donate_argnums=(1,)).lower(*avals).compile()
+    text = compiled.as_text()
+    # no record of the routing in a program of the window
+    assert "xla_ffi_python_cpu_callback" not in text
+    kernels = ["ssd_state_update_decode", "paged_attention_decode",
+               "kv_write_decode", "moe_expert_ffn"]
+    if key.kind == "mixed":
+        kernels += ["ssd_chunk_prefill"]
+    for kernel in kernels:
+        assert kernel_calls(text, kernel), kernel
+    assert not kernel_calls(text, "ssm_") and not kernel_calls(text, "kda_")
+    # a period's three routed layers and three Mamba-2 layers each have a
+    # body of their own in the period's scan: three calls each, not six
+    assert len(kernel_calls(text, "moe_expert_ffn")) == 3
+    assert len(kernel_calls(text, "ssd_state_update_decode")) == 3
+    assert set(scoped_vmem_asked(text, "ssd_")) == {""}
+    assert set(scoped_vmem_asked(text, "moe_expert_ffn")) == {""}
+    # the smallest thing that must not move: one layer of the conv pool
+    conv_layer = (SSM_SLOTS + 1) * 3 * SSD_CHANNELS * 2
+    expert_layer = 16 * 2 * 1856 * 2688 * 2
+    tokens = key.padded_tokens
+    rows_bound = _rows_bound(tokens * 6, 16, model.cfg.moe_row_tile)
+    assert conv_layer < expert_layer
+    moved = [m for m in pool_sized_movers(text, conv_layer)
+             # ONE period's layer taken out of its stack of two periods,
+             # inside the fusion of the product that reads it
+             if not m[2].startswith("bf16[1,")
+             # the held experts' gathered rows, the bound that holds however
+             # the pairs fall (activations: ``held._rows_bound``)
+             and m[2] != f"bf16[{rows_bound},2688]"
+             # a mixed step's 768 tokens: the experts' picked rows a pair,
+             # and the Mamba-2 output re-laid by group for its gated norm
+             # (12.6 MB of activations a layer: PERF.md section 7)
+             and m[2] not in ("bf16[768,6,2688]", "f32[96,8,8,512]")]
+    assert moved == [], moved
+    assert stack_shaped_movers(text, params) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < expert_layer

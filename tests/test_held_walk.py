@@ -64,10 +64,12 @@ def stack_of(rng, n_held):
 
 
 def act_of(used: str, tokens: int, flip: int) -> str:
-    """Both activations over the cases, one a case (a case is seconds of
-    the interpreter), the two tests opposite."""
-    return sorted(held.ACTS)[(USED.index(used) + sorted(TILES).index(tokens)
-                              + flip) % 2]
+    """Every activation over the cases (the two gates' and the ungated
+    expert's own), one a case (a case is seconds of the interpreter), the
+    two tests a step apart."""
+    acts = sorted(held.ACTS)
+    return acts[(USED.index(used) + sorted(TILES).index(tokens) + flip)
+                % len(acts)]
 
 
 @pytest.mark.parametrize("tokens", sorted(TILES))
@@ -82,7 +84,8 @@ def test_the_kernel_matches_the_jnp_form_on_the_tiles_in_use(used, tokens):
         experts, jnp.ones(tokens, bool), 8, n_held, tm)
     assert int(n_used[0]) == in_use and tile_expert.shape[0] == bound
     operands = (x[row_token], tile_expert, n_used, jnp.int32(LAYER),
-                stack["wg"], stack["wu"], stack["wd"])
+                None if act in held.UNGATED else stack["wg"], stack["wu"],
+                stack["wd"])
     got = held.grouped_expert_ffn(*operands, tm=tm, act=act, interpret=True)
     want = held._grouped_reference(*operands, tm=tm, act=act)
     live = in_use * tm

@@ -476,7 +476,8 @@ def test_the_benchmarks_metric_reads_the_share_back():
                          how["args"]) is None
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
-    entry = spec["per_layer"][-1]
+    entry = next(m for m in spec["per_layer"]
+                 if m["name"] == "prompt_held_share")
     tail = next(m for m in spec["end_to_end"] if m["name"] == "itl_p95_ms")
     assert entry["name"] == "prompt_held_share"
     assert entry["workloads"] == tail["workloads"]

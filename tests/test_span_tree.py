@@ -741,7 +741,7 @@ def test_a_model_with_a_state_pool_carries_the_state_pools_names():
     carried = {key for r in recs if r[0] == "fastgen.step" and r[5]
                for key in r[5]}
     new = {"ssm_slots_held", "ssm_rows_decode", "ssm_tokens_prefill",
-           "ssm_state_bytes"}
+           "ssm_state_bytes", "ssm_layers"}
     assert new <= carried
     assert carried - new == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",
@@ -765,9 +765,13 @@ def test_a_model_with_a_state_pool_carries_the_state_pools_names():
                            "ssm_roofline.py")) as f:
         text = f.read()
     read |= {key for key in new if f'"{key}"' in text}
-    # the held slots' bytes: in the span ring for whoever reads a trace
-    # (slots x 9.3 MB at the published widths), by decision no metric
-    assert new - read == {"ssm_state_bytes"}
+    # the held slots' bytes and the layers they are spread over (PR 54:
+    # ``<kind>_layers`` for every slot kind): in the span ring for whoever
+    # reads a trace (slots x 9.3 MB at the published widths), by decision
+    # no metric
+    assert new - read == {"ssm_state_bytes", "ssm_layers"}
+    assert {r[5]["ssm_layers"] for r in recs if r[0] == "fastgen.step"
+            and r[5]} == {cfg.layer_kinds.count("ssm")}
 
     def jaxpr(Q):
         args, _ = scan_args(2, Q)
@@ -859,7 +863,7 @@ def test_a_model_with_delta_rule_layers_carries_the_delta_kinds_names():
     carried = {key for r in recs if r[0] == "fastgen.step" and r[5]
                for key in r[5]}
     new = {"delta_rows_decode", "delta_tokens_prefill", "attn_tokens_full"}
-    kept = {"ssm_slots_held", "ssm_state_bytes"}
+    kept = {"ssm_slots_held", "ssm_state_bytes", "delta_layers"}
     assert new | kept <= carried
     assert carried - new - kept == {
         "path", "rows", "prefill_rows", "prefill_tokens", "tokens",

@@ -328,8 +328,13 @@ def test_every_layer_kind_has_a_mixer_and_every_mixer_a_known_kind(kind):
     from deepspeed_tpu.inference.v2.ragged.cache_kinds import CACHE_KINDS
     assert kind in MIXERS and (kind in CACHE_KINDS or kind == "latent")
     mixer = MIXERS[kind]
-    assert getattr(RaggedInferenceModel, mixer.run.__name__) is mixer.run
     cache = CACHE_KINDS.get(kind)
+    if mixer.run is None:
+        # a feed-forward alone (PR 54): no mixer, and it caches nothing
+        assert (mixer.weights, mixer.pools) == ("", ())
+        assert not cache.slot and not cache.group and not cache.windowed
+        return
+    assert getattr(RaggedInferenceModel, mixer.run.__name__) is mixer.run
     assert mixer.pools == (("state", "conv") if cache is not None
                            and cache.slot else
                            ("window",) if cache is not None and cache.windowed
