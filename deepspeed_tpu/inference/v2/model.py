@@ -1653,9 +1653,9 @@ class RaggedInferenceModel:
         conv, tails = [], []
         for xs, seg, (slots, fresh, _) in zip(
                 _per_segment(x, segments), segments, rows):
-            out, tail = conv_step(conv_pool, layer, slots, fresh,
-                                  seg.q_lens, xs, mp["conv_w"],
-                                  mp["conv_b"])
+            out, conv_pool, tail = conv_step(
+                conv_pool, layer, slots, fresh, seg.q_lens, xs,
+                mp["conv_w"], mp["conv_b"])
             conv.append(jax.nn.silu(out))
             tails.append(tail)
         x = _end_to_end(conv)                               # float32
@@ -1871,8 +1871,9 @@ class RaggedInferenceModel:
         for (slots, fresh, valid), seg, xs, gs, bs in zip(
                 rows, segments, *(_per_segment(a, segments)
                                   for a in (qkv, g, beta))):
-            conv, tail = conv_step(conv_pool, layer, slots, fresh,
-                                   seg.q_lens, xs, mp["conv_w"])
+            conv, conv_pool, tail = conv_step(
+                conv_pool, layer, slots, fresh, seg.q_lens, xs,
+                mp["conv_w"])
             conv = jax.nn.silu(conv)                        # float32
             by_head = conv.shape[:2] + (H, -1)
             q = l2(conv[..., :H * dk].reshape(by_head)) * dk ** -0.5
@@ -1941,9 +1942,9 @@ class RaggedInferenceModel:
         for (slots, fresh, valid), seg, xs, dts in zip(
                 rows, segments, *(_per_segment(a, segments)
                                   for a in (xbc, dt))):
-            conv, tail = conv_step(conv_pool, layer, slots, fresh,
-                                   seg.q_lens, xs, mp["conv_w"],
-                                   mp["conv_b"])
+            conv, conv_pool, tail = conv_step(
+                conv_pool, layer, slots, fresh, seg.q_lens, xs,
+                mp["conv_w"], mp["conv_b"])
             conv = jax.nn.silu(conv)                        # float32
             # a padded position moves nothing: exp(0) = 1, dt x = 0
             y, h_pool, conv_pool = ssd_scan(

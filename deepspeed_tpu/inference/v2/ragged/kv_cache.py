@@ -112,9 +112,12 @@ class StatePoolConfig:
     ``state`` = ``[rows, width]`` in ``state_dtype`` (Mamba-1: ``[d_state,
     d_inner]``; the gated delta rule: ``[d_k, heads * d_v]``) and the
     convolution's tail ``tail`` = ``[positions, channels]`` in
-    ``conv_dtype``, its rows laid end to end and cut into
-    ``ops/ssm.py::conv_rows`` rows; slot ``num_slots`` is the scratch
-    slot padding rows write to.  ``kind`` is the layer kind that holds the
+    ``conv_dtype``, its rows laid end to end, oldest first, and cut into
+    rows of one lane tile (``ops/ssm.py::conv_slot_shape``, which says why
+    and what a width that is no whole number of lane tiles gets; the row
+    count is rounded up to a sublane tile, as the chip pads it anyway, so
+    ``bytes_per_slot`` counts the tail's own values); slot ``num_slots`` is
+    the scratch slot padding rows are sent to.  ``kind`` is the layer kind that holds the
     slots: it names the step span's row and token counts."""
     num_layers: int
     state: Tuple[int, int]
@@ -125,11 +128,10 @@ class StatePoolConfig:
     conv_dtype: Any = jnp.bfloat16
 
     def shapes(self) -> tuple:
-        from ....ops.ssm import conv_rows
+        from ....ops.ssm import conv_slot_shape
         lead = (self.num_layers, self.num_slots + 1)
-        width = self.tail[0] * self.tail[1]
-        rows = conv_rows(width)
-        return (lead + tuple(self.state), lead + (rows, width // rows))
+        return (lead + tuple(self.state),
+                lead + conv_slot_shape(self.tail[0] * self.tail[1]))
 
     @property
     def bytes_per_slot(self) -> int:

@@ -9,7 +9,8 @@ the context: they live in one slot of the state pool
 (``inference/v2/ragged/kv_cache.py::StatePool``),
 
     state : [L_delta, slots + 1, d_k, H * d_v]                 float32
-    conv  : [L_delta, slots + 1, 8, (conv - 1) * H * (2 d_k + d_v) / 8]
+    conv  : [L_delta, slots + 1, rows, 128]  (``ops/ssm.py::conv_slot_shape``
+            of (conv - 1) * H * (2 d_k + d_v) values)
 
 head ``h``'s state the lanes ``[h d_v, (h + 1) d_v)`` of the minor dim (30
 x 192 = 5760 is 45 lane tiles), the scratch slot last.  Per token, with
@@ -37,16 +38,18 @@ triangular, so ``(I + A)^-1 = (I - A)(I + A^2)(I + A^4)...``, ``log2 C``
 squarings (:func:`_chunk`).  The ratios are taken as ``exp`` of
 differences of the running sum of ``g = log alpha``, never as a quotient.
 
-* :func:`delta_rule` — ``Q`` tokens a row from and to each row's slot, the
-  new convolution tail written by the same call.  On a TPU a Pallas kernel
-  named ``delta_state_update_decode`` (Q = 1: the row's whole state read,
+* :func:`delta_rule` — ``Q`` tokens a row from and to each row's slot, a
+  prompt's new convolution tail written by the same call (a decode row's
+  is written by the convolution, ``ops/ssm.py::conv_step``).  On a TPU a
+  Pallas kernel named ``delta_state_update_decode`` (Q = 1: the row's
+  whole state read,
   decayed, corrected by the rank-one term, read out and written back in
   place, on the vector unit: one token is no matmul) or
   ``delta_chunk_prefill`` (Q > 1: the matrix form, chunk by chunk, the
-  state carried in the output block), both pools aliased input -> output,
-  slot ids by scalar prefetch.  :func:`delta_rule_reference`, the plain
-  ``lax.scan`` over positions, is the semantics ground truth and the CPU
-  path.
+  state carried in the output block), the pools they write aliased input
+  -> output, slot ids by scalar prefetch.  :func:`delta_rule_reference`,
+  the plain ``lax.scan`` over positions, is the semantics ground truth and
+  the CPU path.
 
 A DECAY A KEY CHANNEL (Kimi-delta, KDA: ``g`` of one more dimension, ``[..,
 H, dk]``) is the same recurrence with ``Diag(alpha_t)`` over the state's
@@ -149,11 +152,11 @@ def delta_rule_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
     s, o = jax.lax.scan(step, s0, tuple(
         a.astype(f32).swapaxes(0, 1)
         for a in (q, k, v.reshape(S, Q, H, dv), g, beta)))
+    from .ssm import write_tails
     return (o.swapaxes(0, 1).reshape(S, Q, H * dv),
             state_pool.at[layer, slots].set(
                 s.reshape(S, dk, H * dv).astype(state_pool.dtype)),
-            conv_pool.at[layer, slots].set(
-                new_tail.reshape((-1,) + conv_pool.shape[2:])))
+            write_tails(conv_pool, layer, slots, new_tail))
 
 
 def _unit_lower_inverse(n, diagonal):
@@ -274,16 +277,16 @@ def delta_chunk_reference(state_pool, conv_pool, layer, slots, fresh, q, k,
         rows.transpose(2, 0, 1, 3, 4))
         + ((jnp.cumsum(chunks(g), axis=3),) if channel else ()))
     o = o.transpose(1, 0, 3, 2, 4).reshape(S, Q, H * dv)
+    from .ssm import write_tails
     return (o, state_pool.at[layer, slots].set(
         s.transpose(0, 2, 1, 3).reshape(S, dk, H * dv).astype(
             state_pool.dtype)),
-        conv_pool.at[layer, slots].set(
-            new_tail.reshape((-1,) + conv_pool.shape[2:])))
+        write_tails(conv_pool, layer, slots, new_tail))
 
 
 def _decode_kernel(l_ref, slot_ref, fresh_ref, qT_ref, kT_ref, v_ref, a_ref,
-                   b_ref, tail_ref, s_ref, conv_ref, o_ref, sout_ref,
-                   tout_ref, *, heads, dv, group, channel=False):
+                   b_ref, s_ref, o_ref, sout_ref, *, heads, dv, group,
+                   channel=False):
     """One row: its whole state ``[dk, H * dv]`` read, stepped once and
     written back to the same address (the pool is aliased input ->
     output).  The state is walked in lane groups of ``group`` heads (whole
@@ -293,10 +296,10 @@ def _decode_kernel(l_ref, slot_ref, fresh_ref, qT_ref, kT_ref, v_ref, a_ref,
     ``alpha`` and ``beta`` come spread over the lanes already, as ``[8,
     H * dv]`` blocks of 8 rows (``ops/ssm.py``'s decode form); under
     ``channel`` (a decay a key channel, KDA) ``alpha`` comes as the keys
-    do, ``[dk, H]``, and scales the state's rows one by one."""
-    del l_ref, slot_ref, conv_ref
+    do, ``[dk, H]``, and scales the state's rows one by one.  The row's
+    convolution tail is not this kernel's (``conv_step`` wrote it)."""
+    del l_ref, slot_ref
     s = pl.program_id(0)
-    tout_ref[...] = tail_ref[...]
     r = s % v_ref.shape[0]
     fresh = fresh_ref[s] > 0
     width = group * dv
@@ -330,11 +333,11 @@ def delta_state_update_decode(state_pool, conv_pool, layer, slots, fresh, q,
     """Pallas form of :func:`delta_rule_reference` at ``Q = 1``, in
     place; under a decay a key channel (``g`` ``[S, 1, H, dk]``) the kernel
     is named ``kda_state_update_decode``."""
+    assert new_tail is None, "a decode row's tail is conv_step's"
     channel = g.ndim == 4
     S, _, H, dk = q.shape
     W = v.shape[-1]
     dv = W // H
-    rows, width = conv_pool.shape[2:]
     f32 = jnp.float32
     rb = min(8, S)
     assert S % rb == 0, "row buckets are powers of two"
@@ -346,23 +349,18 @@ def delta_state_update_decode(state_pool, conv_pool, layer, slots, fresh, q,
     token = pl.BlockSpec((rb, W), lambda s, l, sl, fr: (s // rb, 0))
     state = pl.BlockSpec((None, None, dk, W),
                          lambda s, l, sl, fr: (l[0], sl[s], 0, 0))
-    tail = pl.BlockSpec((None, None, rows, width),
-                        lambda s, l, sl, fr: (l[0], sl[s], 0, 0))
-    o, state_pool, conv_pool = pl.pallas_call(
+    o, state_pool = pl.pallas_call(
         functools.partial(_decode_kernel, heads=H, dv=dv,
                           group=_decode_group(H, dv), channel=channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(S,),
             in_specs=[cols, cols, token, cols if channel else token, token,
-                      pl.BlockSpec((None, rows, width),
-                                   lambda s, l, sl, fr: (s, 0, 0)),
-                      state, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[token, state, tail]),
+                      state],
+            out_specs=[token, state]),
         out_shape=[jax.ShapeDtypeStruct((S, W), f32),
-                   jax.ShapeDtypeStruct(state_pool.shape, state_pool.dtype),
-                   jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)],
+                   jax.ShapeDtypeStruct(state_pool.shape, state_pool.dtype)],
         # operands count the 3 prefetched
-        input_output_aliases={9: 1, 10: 2},
+        input_output_aliases={8: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         # ``^delta_`` finds both kernels and no pattern of the attention,
@@ -375,9 +373,7 @@ def delta_state_update_decode(state_pool, conv_pool, layer, slots, fresh, q,
       fresh.astype(jnp.int32), q[:, 0].astype(f32).swapaxes(1, 2),
       k[:, 0].astype(f32).swapaxes(1, 2), v.astype(f32).reshape(S, W),
       jnp.exp(g[:, 0].astype(f32)).swapaxes(1, 2) if channel
-      else lanes(jnp.exp(g.astype(f32))), lanes(beta),
-      new_tail.astype(conv_pool.dtype).reshape(S, rows, width), state_pool,
-      conv_pool)
+      else lanes(jnp.exp(g.astype(f32))), lanes(beta), state_pool)
     return o.reshape(S, 1, W), state_pool, conv_pool
 
 
@@ -427,6 +423,7 @@ def delta_chunk_prefill(state_pool, conv_pool, layer, slots, fresh, q, k, v,
     chunked matrix form, in place; under a decay a key channel (``g``
     ``[S, Q, H, dk]``) the kernel is named ``kda_chunk_prefill`` and a
     chunk is :data:`MAX_CHANNEL_CHUNK` tokens at most."""
+    from .ssm import tails_to_slots
     channel = g.ndim == 4
     S, Q, H, dk = q.shape
     W = v.shape[-1]
@@ -475,8 +472,7 @@ def delta_chunk_prefill(state_pool, conv_pool, layer, slots, fresh, q, k, v,
           g.astype(f32).reshape(S, Q // C, C, H, dk), axis=2).reshape(
               S, Q, H, dk).swapaxes(1, 2)) if channel
         else (_chunk_rows(g, beta, C),)),
-      new_tail.astype(conv_pool.dtype).reshape(S, rows, width), state_pool,
-      conv_pool)
+      tails_to_slots(conv_pool, new_tail), state_pool, conv_pool)
     return o, state_pool, conv_pool
 
 
@@ -490,7 +486,8 @@ def delta_rule(state_pool: jax.Array, conv_pool: jax.Array, layer,
     slots, and the rows' new convolution tails written.
 
     state_pool : [L, slots + 1, dk, H * dv], the state's dtype
-    conv_pool  : [L, slots + 1, rows, (K - 1) * channels / rows]
+    conv_pool  : [L, slots + 1, rows, lanes] (``ops/ssm.py::
+             conv_slot_shape``)
     layer  : int32 scalar (the layer's index among the delta layers)
     slots  : [S] int32, the scratch slot for a row with nothing to step
     fresh  : [S] bool, the row starts from a zero state
@@ -500,7 +497,9 @@ def delta_rule(state_pool: jax.Array, conv_pool: jax.Array, layer,
              Q, H, dk], one decay a KEY CHANNEL (KDA: the kernels
              ``kda_*``), then >= -88 / :data:`MAX_CHANNEL_CHUNK` a token
     beta   : [S, Q, H], 0 at padded positions
-    new_tail : [S, K - 1, channels], ``ops/ssm.py::conv_step``'s
+    new_tail : [S, K - 1, channels], ``ops/ssm.py::conv_step``'s; None
+             for decode rows (it wrote them: the conv pool is returned as
+             it came)
     Returns (o [S, Q, H * dv] float32, the updated state pool, the updated
     conv pool).  ``use_kernel`` None = auto (on TPU, or anywhere with
     ``interpret=True``); a row bucket shorter than :data:`MIN_CHUNK` is

@@ -9,18 +9,26 @@ pool (``inference/v2/ragged/kv_cache.py::StatePool``): one slot a
 sequence,
 
     h    : [L_ssm, slots + 1, d_state, d_inner]      float32
-    conv : [L_ssm, slots + 1, 8, (d_conv - 1) * channels / 8] bfloat16
+    conv : [L_ssm, slots + 1, (d_conv - 1) * channels / 128, 128] bfloat16
 
 ``d_inner`` minor (5120 is 40 lane tiles, 16 is not one); the tail's
-``[d_conv - 1, channels]`` rows laid end to end and cut into 8 rows
-(:func:`conv_rows`), because a second-minor dim of 3 is one the chip's
-compiler lays out one way at a program's edge and another inside its loop
-(two copies of the pool a step; ``tests/test_chip_compile.py``), and 8 rows
-of whole lane tiles are a block the kernel can write.  The last slot is
-the scratch slot that rows with nothing to write are sent to (as the page
-pools have their null page).  Both pools are donated at the jit boundary
-and carried through the layer loop; every op here takes the WHOLE pool
-and a layer index and updates ``pool[layer, slot]`` in place.
+``[d_conv - 1, channels]`` rows laid end to end, oldest first, and cut
+into ROWS OF ONE LANE TILE (:func:`conv_slot_shape`: 120 rows at 5,120
+channels; the row count rounded up to a sublane tile, 270 -> 272 at
+11,520, which is what the chip pads it to anyway).  A second-minor dim of
+3 is one the chip's compiler lays out one way at a program's edge and
+another inside its loop (two copies of the pool a step;
+``tests/test_chip_compile.py``); rows of ONE tile are what lets
+:func:`conv_tail_decode` put eight SEQUENCES on a register's sublanes by a
+strided load (a slot's row ``n`` of eight slots copied side by side), so
+that a decode row's tail is convolved and shifted where it lies, with no
+gather and no re-layout.  A bfloat16 row pair ``(2n, 2n + 1)`` is one
+row of 32-bit words: a tap is a whole number of them at every width
+that is a multiple of 256.  The last slot is the scratch slot that rows
+with nothing to write are sent to (as the page pools have their null
+page).  Both pools are donated at the jit boundary and carried through
+the layer loop; every op here takes the WHOLE pool and a layer index and
+updates ``pool[layer, slot]`` in place.
 
 * :func:`ssm_scan` — Mamba-1's recurrence, for rows of ``Q`` tokens from
   each row's slot: ``h_t = exp(dt_t (x) A) * h_{t-1} + (dt_t * x_t) (x)
@@ -31,10 +39,12 @@ and a layer index and updates ``pool[layer, slot]`` in place.
   Pallas kernel named ``ssm_state_update_decode`` (Q = 1: one token a row,
   the state read and written once) or ``ssm_scan_prefill`` (Q > 1), both
   pools aliased input -> output, the slot ids riding the BlockSpec index
-  maps through scalar prefetch.  The same call writes the row's new
-  convolution tail into ``conv[layer, slot]`` (an XLA scatter did it row by
-  row: 30 KB a row in 1.1 us, a fifth of the decode step, PERF.md PR 34).
-  The jnp form is the semantics ground truth and the CPU path.
+  maps through scalar prefetch.  The scan kernel's call also writes the
+  row's new convolution tail into ``conv[layer, slot]`` (an XLA scatter
+  did it row by row: 30 KB a row in 1.1 us, a fifth of the decode step,
+  PERF.md PR 34); the update kernel has no tail to carry
+  (:func:`conv_step` wrote it).  The jnp form is the semantics ground truth
+  and the CPU path.
 * :func:`ssd_scan` — Mamba-2's: ``H`` heads of ``P`` channels (``d_inner =
   H P``), ONE scalar decay a head and step, ``a_t = exp(dt_t A)``, and
   ``B``, ``C`` shared by the ``H / G`` heads of a group: ``S_t = a_t
@@ -47,8 +57,12 @@ and a layer index and updates ``pool[layer, slot]`` in place.
   ``ssd_chunk_prefill`` (Q > 1), the pools, the tail and the ``fresh``
   rule as above.
 * :func:`conv_step` — the depthwise causal convolution over the slot's
-  tail (one XLA gather, 30 KB a row and layer) and the new tokens, and
-  the tail of the row's TRUE last tokens for the scan to write.
+  tail and the new tokens.  At one token a row (a decode row) ONE kernel,
+  ``conv_tail_decode``, reads each row's tail from its slot, convolves,
+  and writes the tail shifted by the new input back to the slot: the
+  tail's bytes move twice (PERF.md PR 55).  At ``Q > 1`` the tails are
+  one XLA gather and the tail of the row's TRUE last tokens goes to the
+  scan kernel to write.
 
 A row that starts at position 0 (``fresh``) starts from a zero state and
 a zero tail whatever its slot held: a reused slot is zeroed by the
@@ -88,9 +102,44 @@ def _d_block(d: int, Q: int) -> int:
     return 128
 
 
-def conv_rows(width: int) -> int:
-    """Rows a slot's convolution tail of ``width`` values is cut into."""
-    return 8 if width % 8 == 0 else 1
+def conv_slot_shape(width: int) -> Tuple[int, int]:
+    """``(rows, lanes)`` a slot's convolution tail of ``width`` values is
+    laid out in (module docstring): rows of one lane tile, a whole number
+    of sublane tiles of them (the last rows past ``width`` are padding
+    nothing reads); a width that is no whole number of lane tiles (a debug
+    model's) is cut into 8 rows, or left as one."""
+    if width % 128 == 0:
+        return -(-width // (128 * 8)) * 8, 128
+    rows = 8 if width % 8 == 0 else 1
+    return rows, width // rows
+
+
+def slot_tails(slots_block: jax.Array, positions: int,
+               channels: int) -> jax.Array:
+    """Slots' tails as they lie in the pool ``[S, rows, lanes]`` ->
+    ``[S, positions, channels]``."""
+    S = slots_block.shape[0]
+    return slots_block.reshape(S, -1)[:, :positions * channels].reshape(
+        S, positions, channels)
+
+
+def tails_to_slots(conv_pool: jax.Array, tails: jax.Array) -> jax.Array:
+    """Tails ``[S, positions, channels]`` as ``conv_pool``'s slots hold
+    them, ``[S, rows, lanes]`` in the pool's dtype."""
+    S = tails.shape[0]
+    rows, lanes = conv_pool.shape[2:]
+    flat = tails.astype(conv_pool.dtype).reshape(S, -1)
+    return jnp.pad(flat, ((0, 0), (0, rows * lanes - flat.shape[1]))
+                   ).reshape(S, rows, lanes)
+
+
+def write_tails(conv_pool, layer, slots, new_tail):
+    """The conv pool with the rows' new tails at their slots, the jnp form;
+    the pool as it is where there are none (``None``: a decode row's was
+    written by :func:`conv_step`)."""
+    if new_tail is None:
+        return conv_pool
+    return conv_pool.at[layer, slots].set(tails_to_slots(conv_pool, new_tail))
 
 
 def ssm_scan_reference(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C,
@@ -111,24 +160,27 @@ def ssm_scan_reference(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C,
         a.astype(f32).swapaxes(0, 1) for a in (dt, x, B, C)))
     return (ys.swapaxes(0, 1),
             h_pool.at[layer, slots].set(h.astype(h_pool.dtype)),
-            conv_pool.at[layer, slots].set(
-                new_tail.reshape((-1,) + conv_pool.shape[2:])))
+            write_tails(conv_pool, layer, slots, new_tail))
 
 
 def _ssm_kernel(l_ref, slot_ref, fresh_ref, dt_ref, x_ref, b_ref, c_ref,
-                a_ref, d_ref, tail_ref, h_ref, conv_ref, y_ref, hout_ref,
-                tout_ref, *, q_len):
+                a_ref, d_ref, *rest, q_len):
     """One (row, block of ``d_inner``) grid step: the row's state block
     ``[N, blk]`` read, ``q_len`` steps of the recurrence, the state
     written back to the same address (the pool is aliased input ->
     output, so nothing else of it moves).  ``b_ref`` / ``c_ref`` hold B
     and C as ``[N, Q]`` (the state dim on sublanes, as in ``h``): step
-    ``t`` takes its column by a masked lane sum.  The row's new
-    convolution tail goes to ``conv[layer, slot]`` whole (that pool is
-    aliased too and never read here)."""
-    del l_ref, slot_ref, conv_ref
+    ``t`` takes its column by a masked lane sum.  ``rest``: (state; y,
+    state out), or with new tails to write (tail, state, conv pool; y,
+    state, tail out): the row's new convolution tail goes to ``conv[layer,
+    slot]`` whole (that pool is aliased too and never read here)."""
+    del l_ref, slot_ref
     s = pl.program_id(0)
-    tout_ref[...] = tail_ref[...]
+    if len(rest) == 3:
+        h_ref, y_ref, hout_ref = rest
+    else:
+        tail_ref, h_ref, _, y_ref, hout_ref, tout_ref = rest
+        tout_ref[...] = tail_ref[...]
     f32 = jnp.float32
     h = h_ref[...].astype(f32)
     h = jnp.where(fresh_ref[s] > 0, jnp.zeros_like(h), h)
@@ -168,7 +220,6 @@ def ssm_scan_kernel(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C,
                     A_t, D, new_tail, *, interpret: bool = False):
     """Pallas form of :func:`ssm_scan_reference`, in place."""
     S, Q, d = x.shape
-    rows, width = conv_pool.shape[2:]
     N = A_t.shape[0]
     blk = _d_block(d, Q)
     f32 = jnp.float32
@@ -186,26 +237,33 @@ def ssm_scan_kernel(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C,
     cols = pl.BlockSpec((None, N, Q), lambda s, j, l, sl, fr: (s, 0, 0))
     state = pl.BlockSpec((None, None, N, blk),
                          lambda s, j, l, sl, fr: (l[0], sl[s], 0, j))
-    tail = pl.BlockSpec((None, None, rows, width),
-                        lambda s, j, l, sl, fr: (l[0], sl[s], 0, 0))
-    y, h_pool, conv_pool = pl.pallas_call(
+    in_specs = [tokens, tokens, cols, cols,
+                pl.BlockSpec((N, blk), lambda s, j, l, sl, fr: (0, j)),
+                pl.BlockSpec((1, blk), lambda s, j, l, sl, fr: (0, j))]
+    operands = [dt.astype(f32), x.astype(f32), B.astype(f32).swapaxes(1, 2),
+                C.astype(f32).swapaxes(1, 2), A_t.astype(f32),
+                D.astype(f32).reshape(1, d)]
+    pools, pool_specs = [h_pool], [state]
+    if new_tail is not None:
+        rows, width = conv_pool.shape[2:]
+        in_specs.append(pl.BlockSpec((None, rows, width),
+                                     lambda s, j, l, sl, fr: (s, 0, 0)))
+        operands.append(tails_to_slots(conv_pool, new_tail))
+        pools.append(conv_pool)
+        pool_specs.append(pl.BlockSpec(
+            (None, None, rows, width),
+            lambda s, j, l, sl, fr: (l[0], sl[s], 0, 0)))
+    first = 3 + len(operands)           # operands count the 3 prefetched
+    y, *pools = pl.pallas_call(
         functools.partial(_ssm_kernel, q_len=Q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(S, d // blk),
-            in_specs=[tokens, tokens, cols, cols,
-                      pl.BlockSpec((N, blk),
-                                   lambda s, j, l, sl, fr: (0, j)),
-                      pl.BlockSpec((1, blk),
-                                   lambda s, j, l, sl, fr: (0, j)),
-                      pl.BlockSpec((None, rows, width),
-                                   lambda s, j, l, sl, fr: (s, 0, 0)),
-                      state, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[tokens, state, tail]),
-        out_shape=[jax.ShapeDtypeStruct(y_shape, f32),
-                   jax.ShapeDtypeStruct(h_pool.shape, h_pool.dtype),
-                   jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)],
-        # operands count the 3 prefetched
-        input_output_aliases={10: 1, 11: 2},
+            in_specs=in_specs + [state] + [
+                pl.BlockSpec(memory_space=pl.ANY)] * (len(pools) - 1),
+            out_specs=[tokens] + pool_specs),
+        out_shape=[jax.ShapeDtypeStruct(y_shape, f32)] + [
+            jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pools],
+        input_output_aliases={first + i: 1 + i for i in range(len(pools))},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         # ``^ssm_`` finds both and no pattern of the attention or cache
@@ -213,12 +271,10 @@ def ssm_scan_kernel(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C,
         name="ssm_state_update_decode" if Q == 1 else "ssm_scan_prefill",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
-      fresh.astype(jnp.int32), dt.astype(f32), x.astype(f32),
-      B.astype(f32).swapaxes(1, 2), C.astype(f32).swapaxes(1, 2),
-      A_t.astype(f32), D.astype(f32).reshape(1, d),
-      new_tail.astype(conv_pool.dtype).reshape(S, rows, width), h_pool,
-      conv_pool)
-    return y.reshape(S, Q, d), h_pool, conv_pool
+      fresh.astype(jnp.int32), *operands, *pools)
+    if new_tail is not None:
+        conv_pool = pools[1]
+    return y.reshape(S, Q, d), pools[0], conv_pool
 
 
 def ssm_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
@@ -231,7 +287,7 @@ def ssm_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
     the rows' new convolution tails written.
 
     h_pool : [L, slots + 1, N, d], the state's dtype
-    conv_pool : [L, slots + 1, rows, (K - 1) * d / rows]
+    conv_pool : [L, slots + 1, rows, lanes] (:func:`conv_slot_shape`)
     layer  : int32 scalar (the layer loop's counter among the state
              layers, or a constant)
     slots  : [S] int32, the scratch slot for a row with nothing to step
@@ -239,7 +295,8 @@ def ssm_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
     dt, x  : [S, Q, d] (``dt`` after the softplus, 0 at padded positions)
     B, C   : [S, Q, N]
     A_t    : [N, d] = ``-exp(A_log)`` transposed;  D : [d]
-    new_tail : [S, K - 1, d], :func:`conv_step`'s
+    new_tail : [S, K - 1, d], :func:`conv_step`'s; None for decode rows
+             (it wrote them: the conv pool is returned as it came)
     Returns (y [S, Q, d] float32, the updated h pool, the updated conv
     pool).  ``use_kernel`` None = auto (on TPU, or anywhere with
     ``interpret=True``)."""
@@ -251,22 +308,272 @@ def ssm_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
                 D, new_tail)
 
 
+#: bytes of VMEM one call of :func:`conv_tail_decode` may hold (two steps'
+#: tails in and out, the rows' blocks twice): inside the default scoped
+#: limit of 16 MiB (:data:`TOKEN_BLOCK_BUDGET`'s note)
+CONV_STEP_BYTES = 10 * 2 ** 20
+#: sequences of one grid step of it, at most
+CONV_STEP_ROWS = 32
+
+
+def _conv_tail_kernel(l_ref, slot_ref, fresh_ref, ql_ref, x_ref, w_ref, b_ref,
+                      pool_ref, out_ref, pout_ref, tin, tout, rsem, wsem, *,
+                      rows, has_bias):
+    """One grid step: ``rb`` sequences' convolution at one new token each,
+    their tails read from and written back to their slots of the pool,
+    which stays in HBM (aliased input -> output).  A slot is ``rows`` rows
+    of one lane tile, the ``K - 1`` taps end to end, oldest first; it is
+    ONE copy to its place in ``tin`` and one from ``tout``, the next
+    step's slots in flight under this step's arithmetic and the last
+    step's on their way back (two buffers each way).  The arithmetic takes
+    EIGHT sequences at a time on a register's sublanes (sixteen on two's
+    where the inputs are 16-bit: a tile of theirs): row ``n`` of their
+    slots is one strided load (the slots lie ``held`` rows apart).
+    A 16-bit pool is read as 32-bit words, a word row the row pair ``(2n,
+    2n + 1)``, low half first: a tap is a whole number of word rows, so
+    the shift by one token moves whole words and only the newest tap is
+    packed.  A ``fresh`` row's slot is zeroed where it landed; a row with
+    ``q_lens == 0`` writes nothing back."""
+    del pool_ref      # aliased: read and written through ``pout_ref``
+    g, steps = pl.program_id(0), pl.num_programs(0)
+    layer = l_ref[0]
+    rb, c = x_ref.shape
+    K = w_ref.shape[0]
+    # sequences the arithmetic takes at a time: a register's sublanes, two
+    # registers' where the inputs come in 16 bits (a tile of theirs)
+    group = min(8 * 4 // x_ref.dtype.itemsize, rb)
+    held = tin.shape[1] // rb                   # rows a slot has in VMEM
+    pack = 4 // tin.dtype.itemsize              # values of a 32-bit word
+    words, held_w = rows // pack, held // pack
+    span = 128 * pack                           # channels of a word row
+    tap_w = c // span                           # word rows of one tap
+    f32, u32 = jnp.float32, jnp.uint32
+
+    def reads(step, buf, wait):
+        def one(r, carry):
+            copy = pltpu.make_async_copy(
+                pout_ref.at[layer, slot_ref[step * rb + r]],
+                tin.at[buf, pl.ds(pl.multiple_of(r * held, held), rows)],
+                rsem.at[buf])
+            copy.wait() if wait else copy.start()
+            return carry
+        jax.lax.fori_loop(0, rb, one, 0)
+
+    def writes(step, buf, wait):
+        def one(r, carry):
+            row = step * rb + r
+
+            @pl.when(ql_ref[row] > 0)
+            def _():
+                src = tout.at[buf, pl.ds(pl.multiple_of(r * held_w, held_w),
+                                         words)]
+                copy = pltpu.make_async_copy(
+                    src.bitcast(tin.dtype) if pack > 1 else src,
+                    pout_ref.at[layer, slot_ref[row]], wsem.at[buf])
+                copy.wait() if wait else copy.start()
+            return carry
+        jax.lax.fori_loop(0, rb, one, 0)
+
+    buf = jax.lax.rem(g, 2)
+
+    @pl.when(g == 0)
+    def _first():
+        reads(0, 0, wait=False)
+
+    @pl.when(g + 1 < steps)
+    def _ahead():
+        reads(g + 1, 1 - buf, wait=False)
+
+    reads(g, buf, wait=True)
+
+    @pl.when(g >= 2)
+    def _drain():           # ``tout[buf]`` is the step before last's
+        writes(g - 2, buf, wait=True)
+
+    def zero_fresh(r, carry):
+        @pl.when(fresh_ref[g * rb + r] > 0)
+        def _():
+            tin[buf, pl.ds(pl.multiple_of(r * held, held), held), :] = \
+                jnp.zeros((held, 128), tin.dtype)
+        return carry
+    jax.lax.fori_loop(0, rb, zero_fresh, 0)
+
+    tin_w = tin.bitcast(u32) if pack > 1 else tin   # [2, rb * held_w, 128]
+
+    def halves(word):
+        """The float32 values of a word row, low half first."""
+        if pack == 1:
+            return [word.astype(f32)]
+        return [pltpu.bitcast(word << 16, f32),
+                pltpu.bitcast(word & jnp.uint32(0xffff0000), f32)]
+
+    def one_group(k, carry):
+        first = pl.multiple_of(k * group, group)
+        seqs = pl.ds(first, group)
+
+        def word_rows(n):
+            return pl.ds(first * held_w + n, group, stride=held_w)
+
+        def one_word_row(s, carry):
+            """Word row ``s`` of every tap: ``span`` channels."""
+            old = [tin_w[buf, word_rows(j * tap_w + s), :]
+                   for j in range(K - 1)]
+            taps = [halves(word) for word in old]
+            new = []
+            for h in range(pack):
+                lanes = pl.ds(pl.multiple_of(s * span + h * 128, 128), 128)
+                # conv_step's jnp form, term by term
+                x0 = x_ref[seqs, lanes].astype(tin.dtype).astype(f32)
+                new.append(x0)
+                acc = x0 * w_ref[K - 1:K, lanes]
+                if has_bias:
+                    acc = b_ref[:, lanes] + acc
+                conv = taps[0][h] * w_ref[0:1, lanes]
+                for j in range(1, K - 1):
+                    conv = conv + taps[j][h] * w_ref[j:j + 1, lanes]
+                out_ref[seqs, lanes] = acc + conv
+            for j in range(1, K - 1):
+                tout[buf, word_rows((j - 1) * tap_w + s), :] = old[j]
+            tout[buf, word_rows((K - 2) * tap_w + s), :] = \
+                new[0].astype(tout.dtype) if pack == 1 else \
+                (pltpu.bitcast(new[0], u32) >> 16) | pltpu.bitcast(new[1], u32)
+            return carry
+
+        jax.lax.fori_loop(0, tap_w, one_word_row, 0)
+        for n in range((K - 1) * tap_w, words):     # the padding: zeros
+            tout[buf, word_rows(n), :] = jnp.zeros((group, 128), tout.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, rb // group, one_group, 0)
+    writes(g, buf, wait=False)
+
+    @pl.when(g == steps - 1)
+    def _last():
+        writes(g, buf, wait=True)
+
+        @pl.when(g >= 1)
+        def _():
+            writes(g - 1, 1 - buf, wait=True)
+
+
+def _held_rows(rows: int) -> int:
+    """Rows a slot of ``rows`` rows has in the kernel's buffers: whole
+    tiles of a 16-bit dtype, an ODD number of them.  The strided loads
+    step from slot to slot: at 128 rows apart (5,120 channels: 8 tiles)
+    eight sequences' rows fell on the same banks and a call took 51.6 us
+    for 39.4 at 144 (PERF.md PR 55); 6, 9, 17 and 18 tiles read alike."""
+    return (-(-rows // 16) | 1) * 16
+
+
+def conv_step_rows(S: int, rows: int, c: int, K: int, itemsize: int) -> int:
+    """Sequences of one grid step of :func:`conv_tail_decode`, from the
+    call's shapes: the largest power of two up to :data:`CONV_STEP_ROWS`
+    whose buffers stay under :data:`CONV_STEP_BYTES`."""
+    held = _held_rows(rows)
+    rb = min(S, CONV_STEP_ROWS)
+    while rb > 8 and (4 * rb * held * 128 * itemsize + 16 * rb * c
+                      + 8 * (K + 1) * c) > CONV_STEP_BYTES:
+        rb //= 2
+    return rb
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def conv_tail_decode(conv_pool, layer, slots, fresh, q_lens, x, w, b=None, *,
+                     interpret: bool = False):
+    """:func:`conv_step` at one token a row, in place
+    (:func:`_conv_tail_kernel`): ``x`` ``[S, c]``; returns (``conv(x) + b``
+    ``[S, c]`` float32, the conv pool with the rows' tails shifted).
+    Jitted, so that the kernel's body is traced once a shape and not once
+    a segment of every step program that has the shape."""
+    S, c = x.shape
+    K = w.shape[0]
+    rows = conv_pool.shape[2]
+    itemsize = conv_pool.dtype.itemsize
+    rb = conv_step_rows(S, rows, c, K, itemsize)
+    assert S % rb == 0, "row buckets are powers of two"
+    held = _held_rows(rows)
+    f32 = jnp.float32
+    bias = jnp.zeros((c,), f32) if b is None else b
+    seqs = pl.BlockSpec((rb, c), lambda g, *_: (g, 0))
+
+    def whole(n):
+        return pl.BlockSpec((n, c), lambda g, *_: (0, 0))
+
+    return pl.pallas_call(
+        functools.partial(_conv_tail_kernel, rows=rows,
+                          has_bias=b is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(S // rb,),
+            in_specs=[seqs, whole(K), whole(1),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[seqs, pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[
+                pltpu.VMEM((2, rb * held, 128), conv_pool.dtype),
+                pltpu.VMEM((2, rb * held * itemsize // 4, 128),
+                           jnp.uint32 if itemsize < 4 else conv_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((S, c), f32),
+                   jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)],
+        # operands count the 4 prefetched
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        # no pattern of the readers' finds it: not the update kernels'
+        # (``^ssm_``, ``^ssd_``, ``^delta_``, ``^kda_``), not the copies'
+        # (``^copy[._]``, ``dynamic-slice``), not the attention or cache
+        # write kernels' (benchmark/metrics)
+        name="conv_tail_decode",
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+      fresh.astype(jnp.int32), q_lens.astype(jnp.int32),
+      # as it comes where it is in the pool's dtype already (the rounding
+      # to it is the identity) and whole tiles of it a grid step
+      x if x.dtype == conv_pool.dtype and rb % (32 // itemsize) == 0
+      else x.astype(f32),
+      w.astype(f32), bias.astype(f32).reshape(1, c), conv_pool)
+
+
+def _conv_kernel_takes(conv_pool, c: int, K: int) -> bool:
+    """Whether a slot's layout is one :func:`_conv_tail_kernel` reads:
+    rows of one lane tile, a tap a whole number of 32-bit word rows."""
+    rows, lanes = conv_pool.shape[2:]
+    itemsize = conv_pool.dtype.itemsize
+    return (lanes == 128 and K > 1 and itemsize in (2, 4)
+            and c % (128 * 4 // itemsize) == 0
+            and rows * 128 >= (K - 1) * c)
+
+
 def conv_step(conv_pool: jax.Array, layer, slots: jax.Array,
               fresh: jax.Array, q_lens: jax.Array, x: jax.Array,
-              w: jax.Array, b: Optional[jax.Array] = None
-              ) -> Tuple[jax.Array, jax.Array]:
+              w: jax.Array, b: Optional[jax.Array] = None, *,
+              use_kernel: Optional[bool] = None, interpret: bool = False
+              ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """The causal depthwise convolution of ``x`` ``[S, Q, d]`` behind each
-    row's tail ``pool[layer, slot]`` (``[d_conv - 1, d]`` laid end to
-    end in :func:`conv_rows` rows; zero for a ``fresh`` row), ``w``
+    row's tail ``pool[layer, slot]`` (``[d_conv - 1, d]`` laid end to end
+    in :func:`conv_slot_shape`; zero for a ``fresh`` row), ``w``
     ``[d_conv, d]`` (tap ``k`` weighs the input ``d_conv - 1 - k``
     positions back), ``b`` ``[d]`` (None: no bias).  The tail kept is
     that of the row's TRUE last tokens, ``q_lens`` of them new: padding
     to the ``Q`` bucket does not enter it.  Returns (``conv(x) + b`` in
-    float32, the new tails ``[S, d_conv - 1, d]`` for :func:`ssm_scan` to
-    write)."""
-    S, Q, _ = x.shape
+    float32, the conv pool, the new tails).  At ``Q == 1`` the pool comes
+    back with the rows' tails shifted by their new input (a row with
+    ``q_lens == 0`` is at the scratch slot, whose content is nobody's) and
+    there are no new tails (None); on a TPU that is
+    :func:`conv_tail_decode` (``use_kernel`` None = auto: there, or
+    anywhere with ``interpret=True``), else the jnp form below, which is
+    the ground truth of both.  At ``Q > 1`` the pool comes back as it is
+    and the new tails ``[S, d_conv - 1, d]`` are for the scan to write."""
+    S, Q, c = x.shape
     K = w.shape[0]
-    tail = conv_pool[layer, slots].reshape(S, K - 1, -1)
+    if use_kernel is None:
+        use_kernel = interpret or on_tpu()
+    if Q == 1 and use_kernel and _conv_kernel_takes(conv_pool, c, K):
+        out, conv_pool = conv_tail_decode(
+            conv_pool, layer, slots, fresh, q_lens, x[:, 0], w, b,
+            interpret=interpret)
+        return out[:, None], conv_pool, None
+    tail = slot_tails(conv_pool[layer, slots], K - 1, c)
     tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
     w = w.astype(jnp.float32)
 
@@ -274,20 +581,19 @@ def conv_step(conv_pool: jax.Array, layer, slots: jax.Array,
         return 0.0 if b is None else b.astype(jnp.float32)
 
     if Q == 1:
-        # one token a row: taps and shift on [S, d] arrays (a [S, 4, d]
-        # concatenation is re-laid out, 10 MB a layer at 256 rows)
+        # one token a row: taps and shift on [S, d] arrays
         x0 = x[:, 0].astype(tail.dtype)
         out = bias() + x0.astype(jnp.float32) * w[K - 1] \
             + sum(tail[:, k].astype(jnp.float32) * w[k]
                   for k in range(K - 1))
         shifted = jnp.concatenate([tail[:, 1:], x0[:, None]], axis=1)
-        return out[:, None], jnp.where(q_lens[:, None, None] > 0, shifted,
-                                       tail)
+        return out[:, None], write_tails(conv_pool, layer, slots, jnp.where(
+            q_lens[:, None, None] > 0, shifted, tail)), None
     xp = jnp.concatenate([tail, x.astype(tail.dtype)], axis=1)
     out = bias() + sum(
         xp[:, k:k + Q].astype(jnp.float32) * w[k] for k in range(K))
     idx = q_lens[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-    return out, jnp.take_along_axis(xp, idx[:, :, None], axis=1)
+    return out, conv_pool, jnp.take_along_axis(xp, idx[:, :, None], axis=1)
 
 
 # -- Mamba-2 (SSD): one scalar decay a head, B and C a group of heads ---------
@@ -338,23 +644,21 @@ def ssd_scan_reference(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C,
     return (ys.swapaxes(0, 1).reshape(S, Q, H * P),
             h_pool.at[layer, slots].set(
                 h.reshape(S, N, H * P).astype(h_pool.dtype)),
-            conv_pool.at[layer, slots].set(
-                new_tail.reshape((-1,) + conv_pool.shape[2:])))
+            write_tails(conv_pool, layer, slots, new_tail))
 
 
 def _ssd_decode_kernel(l_ref, slot_ref, fresh_ref, a_ref, x_ref, b_ref,
-                       c_ref, tail_ref, h_ref, conv_ref, y_ref, hout_ref,
-                       tout_ref, *, groups):
+                       c_ref, h_ref, y_ref, hout_ref, *, groups):
     """One row: its whole state ``[N, H P]`` read, stepped once and written
     back to the same address (the pool is aliased input -> output), walked
     a GROUP's lanes at a time: the group's ``B`` and ``C`` are columns
     ``[N, 1]`` of ``b_ref`` / ``c_ref`` (``[N, G]``, the state dim on
     sublanes as in ``h``) spread over its lanes, the decay ``a`` and ``dt
     x`` come spread over the lanes already, as ``[8, H P]`` blocks of 8
-    rows (:func:`_ssm_kernel`'s decode form)."""
-    del l_ref, slot_ref, conv_ref
+    rows (:func:`_ssm_kernel`'s decode form).  The row's convolution tail
+    is not this kernel's (:func:`conv_step` wrote it)."""
+    del l_ref, slot_ref
     s = pl.program_id(0)
-    tout_ref[...] = tail_ref[...]
     r = s % a_ref.shape[0]
     fresh = fresh_ref[s] > 0
     width = h_ref.shape[1] // groups
@@ -446,7 +750,6 @@ def ssd_scan_kernel(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C, A,
     W = x.shape[-1]
     P, N = W // H, h_pool.shape[2]
     G = B.shape[-1] // N
-    rows, width = conv_pool.shape[2:]
     f32 = jnp.float32
     dt, x = dt.astype(f32), x.astype(f32)
     la = dt * A.astype(f32)                                 # log a, [S, Q, H]
@@ -454,31 +757,24 @@ def ssd_scan_kernel(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C, A,
     skip = jnp.repeat(D.astype(f32), P) * x
     prefetch = (jnp.asarray(layer, jnp.int32).reshape(1),
                 slots.astype(jnp.int32), fresh.astype(jnp.int32))
-    tail_in = new_tail.astype(conv_pool.dtype).reshape(S, rows, width)
-    out_shape = [None, jax.ShapeDtypeStruct(h_pool.shape, h_pool.dtype),
-                 jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)]
     if Q == 1:
+        assert new_tail is None, "a decode row's tail is conv_step's"
         rb = min(8, S)
         assert S % rb == 0, "row buckets are powers of two"
         token = pl.BlockSpec((rb, W), lambda s, l, sl, fr: (s // rb, 0))
         cols = pl.BlockSpec((None, N, G), lambda s, l, sl, fr: (s, 0, 0))
         state = pl.BlockSpec((None, None, N, W),
                              lambda s, l, sl, fr: (l[0], sl[s], 0, 0))
-        tail = pl.BlockSpec((None, None, rows, width),
-                            lambda s, l, sl, fr: (l[0], sl[s], 0, 0))
-        out_shape[0] = jax.ShapeDtypeStruct((S, W), f32)
-        y, h_pool, conv_pool = pl.pallas_call(
+        y, h_pool = pl.pallas_call(
             functools.partial(_ssd_decode_kernel, groups=G),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3, grid=(S,),
-                in_specs=[token, token, cols, cols,
-                          pl.BlockSpec((None, rows, width),
-                                       lambda s, l, sl, fr: (s, 0, 0)),
-                          state, pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=[token, state, tail]),
-            out_shape=out_shape,
+                in_specs=[token, token, cols, cols, state],
+                out_specs=[token, state]),
+            out_shape=[jax.ShapeDtypeStruct((S, W), f32),
+                       jax.ShapeDtypeStruct(h_pool.shape, h_pool.dtype)],
             # operands count the 3 prefetched
-            input_output_aliases={8: 1, 9: 2},
+            input_output_aliases={7: 1},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             # ``^ssd_`` finds both kernels and no pattern of the attention,
@@ -487,10 +783,13 @@ def ssd_scan_kernel(h_pool, conv_pool, layer, slots, fresh, dt, x, B, C, A,
             interpret=interpret,
         )(*prefetch, jnp.repeat(jnp.exp(la[:, 0]), P, axis=-1), dtx[:, 0],
           B.astype(f32).reshape(S, G, N).swapaxes(1, 2),
-          C.astype(f32).reshape(S, G, N).swapaxes(1, 2), tail_in, h_pool,
-          conv_pool)
+          C.astype(f32).reshape(S, G, N).swapaxes(1, 2), h_pool)
         return y.reshape(S, 1, W) + skip, h_pool, conv_pool
 
+    rows, width = conv_pool.shape[2:]
+    tail_in = tails_to_slots(conv_pool, new_tail)
+    out_shape = [None, jax.ShapeDtypeStruct(h_pool.shape, h_pool.dtype),
+                 jax.ShapeDtypeStruct(conv_pool.shape, conv_pool.dtype)]
     Cn = ssd_chunk_len(Q)
     hg, gw = H // G, W // G
     # the running sums of log a inside each chunk, a head a row:
@@ -540,7 +839,8 @@ def ssd_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
 
     h_pool : [L, slots + 1, N, H P], the state's dtype (head ``h``'s
              ``[N, P]`` at lanes ``h P ..``)
-    conv_pool : [L, slots + 1, rows, (K - 1) * (H P + 2 G N) / rows]
+    conv_pool : [L, slots + 1, rows, lanes] (:func:`conv_slot_shape` of
+             (K - 1) * (H P + 2 G N))
     layer  : int32 scalar (the layer's index among the Mamba-2 layers)
     slots  : [S] int32, the scratch slot for a row with nothing to step
     fresh  : [S] bool, the row starts from a zero state
@@ -548,7 +848,8 @@ def ssd_scan(h_pool: jax.Array, conv_pool: jax.Array, layer,
     x      : [S, Q, H P];  B, C : [S, Q, G N], head ``h`` reads group
              ``h // (H / G)``
     A      : [H] = ``-exp(A_log)``;  D : [H]
-    new_tail : [S, K - 1, H P + 2 G N], :func:`conv_step`'s
+    new_tail : [S, K - 1, H P + 2 G N], :func:`conv_step`'s; None for
+             decode rows (it wrote them)
     Returns (y [S, Q, H P] float32, the updated h pool, the updated conv
     pool).  ``use_kernel`` None = auto (on TPU, or anywhere with
     ``interpret=True``); a row bucket whose chunk would be shorter than

@@ -34,7 +34,7 @@ from deepspeed_tpu.moe import held
 from deepspeed_tpu.ops.delta_rule import (MAX_CHANNEL_CHUNK, chunk_len,
                                           delta_chunk_reference, delta_rule,
                                           delta_rule_reference)
-from deepspeed_tpu.ops.ssm import conv_rows
+from deepspeed_tpu.ops.ssm import conv_slot_shape
 
 PAGE = 8
 SOURCE = dict(
@@ -147,13 +147,13 @@ def rule_args(S, Q, H=4, dk=16, dv=16, L=2, slots=5, seed=0, lower=-5.0,
               near_bound=True):
     ks = jax.random.split(jax.random.key(seed), 8)
     K, ch = 4, H * (2 * dk + dv)
-    rows = conv_rows((K - 1) * ch)
 
     def l2(a):
         return a / jnp.sqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
 
     state = jax.random.normal(ks[0], (L, slots + 1, dk, H * dv), jnp.float32)
-    conv = jnp.zeros((L, slots + 1, rows, (K - 1) * ch // rows), jnp.float32)
+    conv = jnp.zeros((L, slots + 1) + conv_slot_shape((K - 1) * ch),
+                     jnp.float32)
     q = l2(jax.random.normal(ks[1], (S, Q, H, dk))) * dk ** -0.5
     k = l2(jax.random.normal(ks[2], (S, Q, H, dk)))
     v = jax.random.normal(ks[3], (S, Q, H * dv))
@@ -161,7 +161,8 @@ def rule_args(S, Q, H=4, dk=16, dv=16, L=2, slots=5, seed=0, lower=-5.0,
     g = lower * jax.nn.sigmoid(
         jax.random.normal(ks[4], (S, Q, H, dk)) * 3 + (4 if near_bound else -2))
     beta = jax.nn.sigmoid(jax.random.normal(ks[5], (S, Q, H)))
-    tail = jax.random.normal(ks[6], (S, K - 1, ch))
+    # a decode row's tail is the convolution's to write (``conv_step``)
+    tail = jax.random.normal(ks[6], (S, K - 1, ch)) if Q > 1 else None
     return (state, conv, 1, jnp.arange(S, dtype=jnp.int32) % slots,
             jnp.arange(S) % 2 == 0, q, k, v, g, beta, tail)
 

@@ -934,10 +934,34 @@ def test_laguna_step_program_moves_no_pool_and_no_expert_stack(
 SSM_LAYERS, SSM_SLOTS, SSM_STATE, SSM_INNER = 26, 256, 16, 5120
 
 
+def _conv_pool(chip, layers, channels):
+    """A family's conv pool: three taps a slot, in the pool's own layout."""
+    from deepspeed_tpu.ops.ssm import conv_slot_shape
+    return chip((layers, SSM_SLOTS + 1) + conv_slot_shape(3 * channels),
+                jnp.bfloat16)
+
+
 def _state_pools(chip, layers=SSM_LAYERS):
     return (chip((layers, SSM_SLOTS + 1, SSM_STATE, SSM_INNER), jnp.float32),
-            chip((layers, SSM_SLOTS + 1, 8, 3 * SSM_INNER // 8),
-                 jnp.bfloat16))
+            _conv_pool(chip, layers, SSM_INNER))
+
+
+def _tail(chip, rows, q, channels):
+    """A prompt's new tails; a decode row has none to hand on (its tail is
+    ``conv_step``'s to write)."""
+    return None if q == 1 else chip((rows, 3, channels), jnp.bfloat16)
+
+
+def _tails_floor(key, conv_layer: int, channels: int) -> int:
+    """The size from which nothing may move outside a kernel in a family's
+    step program: a layer of its conv pool; in a CHAIN program (decode rows
+    alone) the tails of HALF its 256 rows, since those are read, convolved
+    and shifted where they lie (``conv_tail_decode``, PERF.md PR 55): the
+    gather, the two re-layouts and the shifted copy that stood around the
+    update kernels each moved twice that, 256 / 257 of a layer."""
+    if key.kind != "chain":
+        return conv_layer
+    return SSM_SLOTS // 2 * 3 * channels * 2
 
 
 @pytest.mark.parametrize("rows,q,kernel", [
@@ -960,8 +984,32 @@ def test_state_space_kernels(chip, rows, q, kernel):
         chip((rows,), jnp.bool_), chip((rows, q, SSM_INNER), f32),
         chip((rows, q, SSM_INNER), f32), chip((rows, q, SSM_STATE), f32),
         chip((rows, q, SSM_STATE), f32), chip((SSM_STATE, SSM_INNER), f32),
-        chip((SSM_INNER,), f32), chip((rows, 3, SSM_INNER), jnp.bfloat16),
+        chip((SSM_INNER,), f32), _tail(chip, rows, q, SSM_INNER),
         kernel=kernel)
+
+
+@pytest.mark.parametrize("channels", [5120, 6144, 11520, 12288],
+                         ids=["jamba", "nemotron", "olmo-hybrid", "ling"])
+def test_conv_tail_decode(chip, channels, rows=SSM_SLOTS):
+    """A decode segment's convolution at the four state-holding cells'
+    channel counts: ONE kernel over the conv pool in place (aliased in ->
+    out, left in HBM), under the default scoped-VMEM limit.  (That nothing
+    of the tails' size moves around it is held on the chain programs.)"""
+    from deepspeed_tpu.ops.ssm import conv_step, conv_step_rows
+    f32 = jnp.float32
+    conv = _conv_pool(chip, 3, channels)
+    # 32 sequences a grid step where their buffers fit, 16 past 10 MB
+    assert conv_step_rows(rows, conv.shape[2], channels, 4, 2) \
+        == (32 if channels < 11520 else 16)
+    text = compile_for_chip(
+        lambda conv, layer, slots, fresh, q_lens, x, w, b:
+        conv_step(conv, layer, slots, fresh, q_lens, x, w, b,
+                  use_kernel=True)[:2],
+        conv, chip((), jnp.int32), chip((rows,), jnp.int32),
+        chip((rows,), jnp.bool_), chip((rows,), jnp.int32),
+        chip((rows, 1, channels), f32), chip((4, channels), f32),
+        chip((channels,), f32), kernel="conv_tail_decode")
+    assert set(scoped_vmem_asked(text, "conv_tail_decode")) == {""}
 
 
 JAMBA_STEP_KEYS = {
@@ -1037,6 +1085,8 @@ def test_jamba_step_program_moves_no_pool_and_no_weight_stack(
     row = "decode" if key.Q == 1 else "prefill"
     kernels = [f"kv_write_{row}", "ssm_state_update_decode" if key.Q == 1
                else "ssm_scan_prefill"]
+    if key.Q == 1:
+        kernels.append("conv_tail_decode")
     if key.kind == "mixed":
         kernels += ["ssm_scan_prefill", "kv_write_prefill"]
     if not key.fresh or key.kind == "mixed":
@@ -1050,7 +1100,8 @@ def test_jamba_step_program_moves_no_pool_and_no_weight_stack(
     kv_layer = (pages + 1) * 2 * PAGE * 128 * 2
     mamba_layer = 2 * 104_000_000
     assert conv_layer < kv_layer < mamba_layer
-    moved = [m for m in pool_sized_movers(text, conv_layer)
+    moved = [m for m in pool_sized_movers(text, _tails_floor(
+        key, conv_layer, SSM_INNER))
              # ONE layer's weights taken out of its kind's stack, mostly
              # inside the fusion that feeds the product: what a scan over
              # layers does (the attention layer's 13 MB wq and wo are
@@ -1072,8 +1123,7 @@ DELTA_CHANNELS = DELTA_HEADS * (2 * DELTA_DK + DELTA_DV)
 def _delta_pools(chip):
     return (chip((DELTA_LAYERS, SSM_SLOTS + 1, DELTA_DK,
                   DELTA_HEADS * DELTA_DV), jnp.float32),
-            chip((DELTA_LAYERS, SSM_SLOTS + 1, 8, 3 * DELTA_CHANNELS // 8),
-                 jnp.bfloat16))
+            _conv_pool(chip, DELTA_LAYERS, DELTA_CHANNELS))
 
 
 @pytest.mark.parametrize("rows,q,kernel", [
@@ -1100,7 +1150,7 @@ def test_delta_rule_kernels(chip, rows, q, kernel):
         chip((rows, q, DELTA_HEADS, DELTA_DK), f32),
         chip((rows, q, width), f32), chip((rows, q, DELTA_HEADS), f32),
         chip((rows, q, DELTA_HEADS), f32),
-        chip((rows, 3, DELTA_CHANNELS), jnp.bfloat16), kernel=kernel)
+        _tail(chip, rows, q, DELTA_CHANNELS), kernel=kernel)
 
 
 #: the window's two programs: a chained decode step, and a mixed step (256
@@ -1173,6 +1223,8 @@ def test_olmo_hybrid_step_program_moves_no_pool_and_no_weight_stack(
     row = "decode" if key.Q == 1 else "prefill"
     kernels = [f"kv_write_{row}", "delta_state_update_decode"
                if key.Q == 1 else "delta_chunk_prefill"]
+    if key.Q == 1:
+        kernels.append("conv_tail_decode")
     if key.kind == "mixed":
         kernels += ["delta_chunk_prefill", "kv_write_prefill"]
     if not key.fresh or key.kind == "mixed":
@@ -1186,7 +1238,8 @@ def test_olmo_hybrid_step_program_moves_no_pool_and_no_weight_stack(
     kv_layer = (pages + 1) * 2 * 30 * PAGE * 128 * 2
     delta_layer = 2 * 215_000_000
     assert conv_layer < delta_layer < kv_layer
-    moved = [m for m in pool_sized_movers(text, conv_layer)
+    moved = [m for m in pool_sized_movers(text, _tails_floor(
+        key, conv_layer, DELTA_CHANNELS))
              # ONE layer's weights taken out of its kind's stack, mostly
              # inside the fusion that feeds the product (what a scan over
              # layers does)
@@ -1658,8 +1711,7 @@ KDA_CHANNELS = 3 * KDA_HEADS * KDA_D
 def _kda_pools(chip):
     return (chip((KDA_LAYERS, SSM_SLOTS + 1, KDA_D, KDA_HEADS * KDA_D),
                  jnp.float32),
-            chip((KDA_LAYERS, SSM_SLOTS + 1, 8, 3 * KDA_CHANNELS // 8),
-                 jnp.bfloat16))
+            _conv_pool(chip, KDA_LAYERS, KDA_CHANNELS))
 
 
 @pytest.mark.parametrize("rows,q,kernel", [
@@ -1689,7 +1741,7 @@ def test_kda_kernels(chip, rows, q, kernel):
         chip((rows,), jnp.bool_), chip(heads, f32), chip(heads, f32),
         chip((rows, q, KDA_HEADS * KDA_D), f32), chip(heads, f32),
         chip((rows, q, KDA_HEADS), f32),
-        chip((rows, 3, KDA_CHANNELS), jnp.bfloat16), kernel=kernel)
+        _tail(chip, rows, q, KDA_CHANNELS), kernel=kernel)
 
 
 #: the window's two programs: a chained decode step, and a mixed step (256
@@ -1762,8 +1814,9 @@ def test_ling_step_program_moves_no_pool_and_no_weight_stack(
     # the record of the routing is in the probe's programs and in no other
     assert ("xla_ffi_python_cpu_callback" in text or "host" in text.lower()
             and "callback" in text.lower()) == kind.startswith("probe"), kind
-    kernels = ["kda_state_update_decode", "mla_attention_decode",
-               "latent_write_decode", "moe_expert_ffn"]
+    kernels = ["kda_state_update_decode", "conv_tail_decode",
+               "mla_attention_decode", "latent_write_decode",
+               "moe_expert_ffn"]
     if key.kind == "mixed":
         kernels += ["kda_chunk_prefill", "latent_write_prefill"]
     for kernel in kernels:
@@ -1780,7 +1833,13 @@ def test_ling_step_program_moves_no_pool_and_no_weight_stack(
     # a mixed step, counted in PERF.md; never a pool, never the experts
     relaid = ("bf16[8192,2560]", "bf16[768,8,2560]") \
         if key.kind == "mixed" else ()
-    moved = [m for m in pool_sized_movers(text, conv_layer)
+    if key.kind == "chain":
+        # over half the decode rows' tails (9.4 MB) and under a layer of
+        # the conv pool: the held experts' gathered rows and the 256
+        # tokens' picked rows a pair, activations of the routed layers
+        relaid = ("bf16[3040,2560]", "bf16[256,8,2560]")
+    moved = [m for m in pool_sized_movers(text, _tails_floor(
+        key, conv_layer, KDA_CHANNELS))
              # ONE period's layer taken out of its stack of one period
              if not m[2].startswith("bf16[1,") and m[2] not in relaid]
     assert moved == [], moved
@@ -1798,8 +1857,7 @@ SSD_CHANNELS = SSD_INNER + 2 * SSD_GROUPS * SSD_STATE
 def _ssd_pools(chip):
     return (chip((SSD_LAYERS, SSM_SLOTS + 1, SSD_STATE, SSD_INNER),
                  jnp.float32),
-            chip((SSD_LAYERS, SSM_SLOTS + 1, 8, 3 * SSD_CHANNELS // 8),
-                 jnp.bfloat16))
+            _conv_pool(chip, SSD_LAYERS, SSD_CHANNELS))
 
 
 @pytest.mark.parametrize("rows,q,kernel", [
@@ -1827,7 +1885,7 @@ def test_ssd_kernels(chip, rows, q, kernel):
         chip((rows, q, SSD_GROUPS * SSD_STATE), f32),
         chip((rows, q, SSD_GROUPS * SSD_STATE), f32),
         chip((SSD_HEADS,), f32), chip((SSD_HEADS,), f32),
-        chip((rows, 3, SSD_CHANNELS), jnp.bfloat16), kernel=kernel)
+        _tail(chip, rows, q, SSD_CHANNELS), kernel=kernel)
     assert set(scoped_vmem_asked(text, "ssd_")) == {""}
 
 
@@ -1909,8 +1967,8 @@ def test_nemotron_step_program_moves_no_pool_and_no_expert_stack(
     text = compiled.as_text()
     # no record of the routing in a program of the window
     assert "xla_ffi_python_cpu_callback" not in text
-    kernels = ["ssd_state_update_decode", "paged_attention_decode",
-               "kv_write_decode", "moe_expert_ffn"]
+    kernels = ["ssd_state_update_decode", "conv_tail_decode",
+               "paged_attention_decode", "kv_write_decode", "moe_expert_ffn"]
     if key.kind == "mixed":
         kernels += ["ssd_chunk_prefill"]
     for kernel in kernels:
@@ -1928,7 +1986,8 @@ def test_nemotron_step_program_moves_no_pool_and_no_expert_stack(
     tokens = key.padded_tokens
     rows_bound = _rows_bound(tokens * 6, 16, model.cfg.moe_row_tile)
     assert conv_layer < expert_layer
-    moved = [m for m in pool_sized_movers(text, conv_layer)
+    moved = [m for m in pool_sized_movers(text, _tails_floor(
+        key, conv_layer, SSD_CHANNELS))
              # ONE period's layer taken out of its stack of two periods,
              # inside the fusion of the product that reads it
              if not m[2].startswith("bf16[1,")
@@ -1938,7 +1997,10 @@ def test_nemotron_step_program_moves_no_pool_and_no_expert_stack(
              # a mixed step's 768 tokens: the experts' picked rows a pair,
              # and the Mamba-2 output re-laid by group for its gated norm
              # (12.6 MB of activations a layer: PERF.md section 7)
-             and m[2] not in ("bf16[768,6,2688]", "f32[96,8,8,512]")]
+             and m[2] not in ("bf16[768,6,2688]", "f32[96,8,8,512]")
+             # a chain step's 256 tokens' picked rows a pair: over half
+             # the decode rows' tails (4.7 MB), under a conv pool's layer
+             and not (key.kind == "chain" and m[2] == "bf16[256,6,2688]")]
     assert moved == [], moved
     assert stack_shaped_movers(text, params) == []
     assert compiled.memory_analysis().temp_size_in_bytes < expert_layer

@@ -5,6 +5,7 @@ slots through every codec of ``StateManager``, what is refused at build,
 and the step's spans."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,8 +28,9 @@ from deepspeed_tpu.inference.v2.ragged.cache_kinds import (CACHE_KINDS,
 from deepspeed_tpu.models import jamba_reference as reference
 from deepspeed_tpu.models.jamba import JambaForCausalLM, jamba_config
 from deepspeed_tpu.models.transformer import layer_runs
-from deepspeed_tpu.ops.ssm import (conv_step, ssm_scan, ssm_scan_kernel,
-                                   ssm_scan_reference)
+from deepspeed_tpu.ops.ssm import (conv_slot_shape, conv_step, slot_tails,
+                                   ssm_scan, ssm_scan_kernel,
+                                   ssm_scan_reference, write_tails)
 
 PAGE = 8
 SOURCE = dict(
@@ -248,7 +250,9 @@ def scan_args(S, Q, d=256, N=8, L=3, slots=6, seed=0, q_lens=None):
         h_pool=jnp.asarray(rng.normal(size=(L, slots + 1, N, d)), f32),
         conv_pool=jnp.asarray(
             rng.normal(size=(L, slots + 1, 8, 3 * d // 8)), f32),
-        new_tail=jnp.asarray(rng.normal(size=(S, 3, d)), f32),
+        # a decode row's tail is the convolution's to write (``conv_step``)
+        new_tail=jnp.asarray(rng.normal(size=(S, 3, d)), f32) if Q > 1
+        else None,
         layer=jnp.int32(1),
         slots=jnp.asarray(rng.permutation(slots)[:S], jnp.int32),
         fresh=jnp.asarray(rng.integers(0, 2, S).astype(bool)),
@@ -280,6 +284,10 @@ def test_the_state_space_kernel_against_the_plain_scan(S, Q, d):
                      (got_conv, args["conv_pool"])):
         assert np.array_equal(np.asarray(got)[~touched],
                               np.asarray(was)[~touched])
+    if Q == 1:
+        # the update kernel carries no tail: the conv pool is no operand
+        assert got_conv is args["conv_pool"]
+        return
     # the rows' new tails, laid end to end, are what their slots hold
     assert np.array_equal(
         np.asarray(got_conv)[1, np.asarray(args["slots"])].reshape(S, 3, d),
@@ -318,7 +326,8 @@ def test_the_conv_tail_kept_is_that_of_the_true_last_tokens():
     x = jnp.asarray(rng.normal(size=(S, Q, d)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(K, d)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(d,)), jnp.float32)
-    out, new = conv_step(pool, 1, slots, fresh, q_lens, x, w, b)
+    out, same, new = conv_step(pool, 1, slots, fresh, q_lens, x, w, b)
+    assert same is pool                 # the scan writes a prompt's tails
     for i in range(S):
         old = np.zeros((K - 1, d)) if fresh[i] else \
             np.asarray(pool[1, slots[i]]).reshape(K - 1, d)
@@ -329,6 +338,138 @@ def test_the_conv_tail_kept_is_that_of_the_true_last_tokens():
             np.testing.assert_allclose(out[i, t], want, rtol=1e-5,
                                        atol=1e-5)
         np.testing.assert_allclose(new[i], seq[n:n + K - 1])
+
+
+# -- a decode row's tail: convolved and shifted where it lies ----------------
+
+def tail_args(S, c, dtype=jnp.bfloat16, K=4, seed=0, idle=(),
+              x_dtype=jnp.float32):
+    """A conv pool of ``S`` + 2 slots and a scratch slot in the pool's own
+    layout, ``S`` rows at distinct slots but the ``idle`` ones (``q_lens ==
+    0``), which SHARE the scratch slot; every fourth row ``fresh``."""
+    rng = np.random.default_rng(seed)
+    scratch = S + 2
+    pool = jnp.asarray(rng.normal(size=(2, scratch + 1)
+                                  + conv_slot_shape((K - 1) * c)), dtype)
+    slots = rng.permutation(scratch)[:S]
+    q_lens = np.ones(S, np.int32)
+    q_lens[list(idle)] = 0
+    slots[list(idle)] = scratch
+    return dict(
+        conv_pool=pool, layer=jnp.int32(1),
+        slots=jnp.asarray(slots, jnp.int32),
+        fresh=jnp.asarray(np.arange(S) % 4 == 1),
+        q_lens=jnp.asarray(q_lens),
+        x=jnp.asarray(rng.normal(size=(S, 1, c)), x_dtype),
+        w=jnp.asarray(rng.normal(size=(K, c)), jnp.float32),
+        b=jnp.asarray(rng.normal(size=(c,)), jnp.float32))
+
+
+#: the jnp form as a step program holds it: one jitted computation (taken
+#: operation by operation the CPU rounds each product before its sum)
+conv_step_jnp = jax.jit(functools.partial(conv_step, use_kernel=False))
+
+
+@pytest.mark.parametrize("S", [1, 8, 256])
+@pytest.mark.parametrize("c", [5120, 6144, 11520, 12288])
+def test_the_decode_convolution_is_the_jnp_form_bit_for_bit(c, S):
+    """``conv_step`` at one token a row through ``conv_tail_decode``
+    (interpret mode) at the four families' channel counts: the output and
+    every slot but the scratch slot equal the jnp form's, with fresh rows
+    (a zero tail whatever the slot held) and rows with nothing to write that
+    share the scratch slot; no other slot and no other layer moves.  At
+    5,120 channels the inputs come in the pool's dtype, as that family's
+    projection gives them (sixteen sequences at a time, where a grid step
+    has as many)."""
+    args = tail_args(S, c, idle=(0, 3, 5) if S > 1 else (), seed=c + S,
+                     x_dtype=jnp.bfloat16 if c == 5120 else jnp.float32)
+    if c == 11520:              # 270 rows of one lane tile, held as 272
+        assert args["conv_pool"].shape[2:] == (272, 128)
+    want_out, want_pool, _ = conv_step_jnp(**args)
+    out, pool, tail = conv_step(**args, interpret=True)
+    assert tail is None and np.array_equal(out, want_out)
+    true_slots = np.ones(pool.shape[1], bool)
+    true_slots[-1] = False
+    assert np.array_equal(np.asarray(pool)[:, true_slots],
+                          np.asarray(want_pool)[:, true_slots])
+    written = np.zeros(pool.shape[:2], bool)
+    written[1, np.asarray(args["slots"])] = True
+    assert np.array_equal(np.asarray(pool)[~written],
+                          np.asarray(args["conv_pool"])[~written])
+    # a true row's slot holds its old taps but the oldest, then the input
+    K, live = 4, np.flatnonzero(np.asarray(args["q_lens"]))
+    got = np.asarray(slot_tails(pool[1, args["slots"][live]], K - 1, c))
+    was = np.array(slot_tails(
+        args["conv_pool"][1, args["slots"][live]], K - 1, c))
+    was[np.asarray(args["fresh"])[live]] = 0
+    assert np.array_equal(got[:, :-1], was[:, 1:])
+    assert np.array_equal(got[:, -1], np.asarray(
+        args["x"][live, 0].astype(pool.dtype)))
+
+
+@pytest.mark.parametrize("dtype,kernel", [
+    (jnp.float32, True), (jnp.bfloat16, False)], ids=["kernel-f32", "jnp"])
+def test_decode_steps_continue_a_prompts_tail(dtype, kernel):
+    """A prompt through the ``Q > 1`` branch (its tails written as the scan
+    writes them), then four decode steps: the outputs and the tails left in
+    the slots are those of ONE call over all the tokens, so the order of
+    the taps survives the hand-over between the two branches."""
+    S, c, K, steps = 2, 512, 4, 4
+    args = tail_args(S, c, dtype=dtype, seed=7)
+    rng = np.random.default_rng(8)
+    n = np.asarray([8, 3])                              # the prompts' tokens
+    tokens = jnp.asarray(rng.normal(size=(S, 16, c)), dtype)
+    shared = {k: args[k] for k in ("layer", "slots", "fresh", "w", "b")}
+
+    def prompt(q_lens, Q):
+        out, pool, tails = conv_step(args["conv_pool"], x=tokens[:, :Q],
+                                     q_lens=jnp.asarray(q_lens), **shared)
+        return out, write_tails(pool, 1, args["slots"], tails)
+
+    whole, want_pool = prompt(n + steps, 16)
+    _, pool = prompt(n, 8)
+    for t in range(steps):
+        x = jnp.stack([tokens[i, n[i] + t] for i in range(S)])[:, None]
+        out, pool, _ = conv_step(
+            pool, x=x, q_lens=jnp.ones(S, jnp.int32),
+            **dict(shared, fresh=jnp.zeros(S, bool)),
+            use_kernel=kernel, interpret=kernel)
+        for i in range(S):
+            np.testing.assert_allclose(out[i, 0], whole[i, n[i] + t],
+                                       rtol=2e-6, atol=2e-6)
+    assert np.array_equal(pool, want_pool)
+
+
+def test_a_tail_moved_to_another_slot_continues_bit_for_bit():
+    """``read_slot`` -> ``write_slot`` into ANOTHER slot (an offload and a
+    restore, a hand-over): the blob carries the taps as they lie, and the
+    decode steps behind it read what the steps at the old slot read."""
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import (StatePool,
+                                                            StatePoolConfig)
+    c, K = 256, 4
+    store = StatePool(StatePoolConfig(num_layers=2, state=(8, 128),
+                                      tail=(K - 1, c), num_slots=4))
+    assert store.data[1].shape == (2, 5, 8, 128)
+    rng = np.random.default_rng(3)
+    w = jnp.asarray(rng.normal(size=(K, c)), jnp.float32)
+    xs = jnp.asarray(rng.normal(size=(6, 1, 1, c)), jnp.float32)
+    old, new = store.reserve(), store.reserve()
+
+    def step(t, slot, fresh=False):
+        h, conv = store.data
+        out, conv, _ = conv_step(
+            conv, 1, jnp.asarray([slot], jnp.int32), jnp.asarray([fresh]),
+            jnp.ones(1, jnp.int32), xs[t], w, interpret=True)
+        store.data = (h, conv)
+        return np.asarray(out)
+
+    for t in range(3):
+        step(t, old, fresh=t == 0)
+    store.write_slot(new, store.read_slot(old))
+    for t in range(3, 6):
+        assert np.array_equal(step(t, old), step(t, new))
+        assert np.array_equal(store.read_slot(old).conv,
+                              store.read_slot(new).conv)
 
 
 def test_the_kernel_is_chosen_by_the_platform_alone():
@@ -541,7 +682,7 @@ def test_implementation_for_jamba_and_what_it_refuses():
     assert state.prefix_cache is None and state.tiers is None
     assert state.state_pool.cfg.num_slots == 8
     assert [a.shape for a in state.state_pool.data] \
-        == [(6, 9, 8, 128), (6, 9, 8, 3 * 128 // 8)]
+        == [(6, 9, 8, 128), (6, 9, 8, 128)]    # 3 rows of a tile, held as 8
     assert state.state_pool.data[0].dtype == jnp.float32
 
     def build(**serving):

@@ -30,7 +30,7 @@ from deepspeed_tpu.models.nemotron_h import (NemotronHForCausalLM,
                                              nemotron_h_config)
 from deepspeed_tpu.models.transformer import layer_runs
 from deepspeed_tpu.moe import held
-from deepspeed_tpu.ops.ssm import (conv_rows, ssd_chunk_len, ssd_scan,
+from deepspeed_tpu.ops.ssm import (conv_slot_shape, ssd_chunk_len, ssd_scan,
                                    ssd_scan_reference)
 
 PAGE = 8
@@ -147,9 +147,9 @@ def scan_args(S, Q, H=4, P=64, G=2, N=16, L=2, slots=5, seed=0):
     ks = jax.random.split(jax.random.key(seed), 8)
     K, W = 4, H * P
     ch = W + 2 * G * N
-    rows = conv_rows((K - 1) * ch)
     state = jax.random.normal(ks[0], (L, slots + 1, N, W), jnp.float32)
-    conv = jnp.zeros((L, slots + 1, rows, (K - 1) * ch // rows), jnp.float32)
+    conv = jnp.zeros((L, slots + 1) + conv_slot_shape((K - 1) * ch),
+                     jnp.float32)
     # steps from a thousandth to a few: a head's decay over a chunk runs
     # from nothing to e^-100 and less
     dt = jax.nn.softplus(jax.random.normal(ks[1], (S, Q, H)) * 2 - 2)
@@ -159,7 +159,8 @@ def scan_args(S, Q, H=4, P=64, G=2, N=16, L=2, slots=5, seed=0):
     B = jax.random.normal(ks[3], (S, Q, G * N))
     C = jax.random.normal(ks[4], (S, Q, G * N))
     A = -jnp.exp(jax.random.uniform(ks[5], (H,), minval=0.0, maxval=2.77))
-    tail = jax.random.normal(ks[6], (S, K - 1, ch))
+    # a decode row's tail is the convolution's to write (``conv_step``)
+    tail = jax.random.normal(ks[6], (S, K - 1, ch)) if Q > 1 else None
     return (state, conv, 1, jnp.arange(S, dtype=jnp.int32) % slots,
             jnp.arange(S) % 2 == 0, dt, x, B, C, A, jnp.ones((H,)), tail)
 
